@@ -253,8 +253,9 @@ class ComputationGraph(KStepExecutorMixin):
         def loss_fn(p):
             return self._loss(p, state, batch, rng, training=True)
 
-        (loss, new_state), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params)
+        with self._mesh_scope():
+            (loss, new_state), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params)
         from deeplearning4j_tpu.train.gradnorm import (
             apply_gradient_normalization)
         layer_cfgs = {n: v[0] for n, v in self.conf.vertices.items()
@@ -525,9 +526,10 @@ class ComputationGraph(KStepExecutorMixin):
         if key not in self._jit_output:
             @jax.jit
             def fwd(params, state, xs, rng, fmasks):
-                acts, _, _ = self._forward(params, state, xs,
-                                           training=training, rng=rng,
-                                           fmasks=fmasks)
+                with self._mesh_scope():
+                    acts, _, _ = self._forward(params, state, xs,
+                                               training=training,
+                                               rng=rng, fmasks=fmasks)
                 return tuple(acts[o] for o in self.conf.network_outputs)
             self._jit_output[key] = fwd
         rng = self._next_call_rng() if training else None

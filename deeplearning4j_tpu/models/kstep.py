@@ -1,8 +1,7 @@
 """k-step fused on-device training + AOT train-program warmup.
 
-BENCH_DETAIL's MFU analysis shows small models are dispatch-bound:
-LeNet spends ~1 ms/step in device compute but pays a full host
-round-trip per step, with ±20% jitter. The classic fix is the
+Small models are dispatch-bound: a LeNet step is little device
+compute and a full host round-trip. The classic fix is the
 in-graph training loop of the TensorFlow papers (arXiv:1605.08695
 §3.3, arXiv:1603.04467): keep the device busy across many steps per
 host interaction, and pre-compile the executables so the steady state
@@ -37,6 +36,7 @@ this module supplies the window plumbing):
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
 from typing import Any, Dict, Sequence, Tuple
@@ -263,6 +263,17 @@ class KStepExecutorMixin:
         self._jit_tbptt_step = None
         self._jit_kstep = {}
         self._aot = {}
+
+    def _mesh_scope(self):
+        """Trace-time announcement of the installed mesh
+        (``parallel/seq_context.gspmd_mesh``), entered by the traced
+        train and output bodies: what GSPMD cannot partition (the
+        Pallas attention kernels) wraps itself in a shard_map on it.
+        A null scope without a mesh."""
+        if self._mesh_ctx is None:
+            return contextlib.nullcontext()
+        from deeplearning4j_tpu.parallel.seq_context import gspmd_mesh
+        return gspmd_mesh(self._mesh_ctx.mesh)
 
     def _mesh_out_shardings(self):
         """Pinned ``out_shardings`` for the train programs under the

@@ -252,8 +252,9 @@ class MultiLayerNetwork(KStepExecutorMixin):
                                           training=True)
             return loss, new_states
 
-        (loss, new_states), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params)
+        with self._mesh_scope():
+            (loss, new_states), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params)
         grads = apply_gradient_normalization(self.layers, grads)
         updates, new_opt_state = optimizer.update(grads, opt_state,
                                                   params)
@@ -509,8 +510,10 @@ class MultiLayerNetwork(KStepExecutorMixin):
         if training not in self._jit_output:
             @jax.jit
             def fwd(params, state, x, rng):
-                y, _, _, _ = self._forward(params, state, x,
-                                           training=training, rng=rng)
+                with self._mesh_scope():
+                    y, _, _, _ = self._forward(params, state, x,
+                                               training=training,
+                                               rng=rng)
                 return y
             self._jit_output[training] = fwd
         rng = self._rng_key if training else None

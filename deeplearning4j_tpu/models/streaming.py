@@ -131,7 +131,7 @@ class _BoundedSession:
         program (lax.scan over the sampled tokens with the bounded
         caches as carries): a single device dispatch replaces
         n_tokens of them — the difference dominates when dispatch
-        latency is high (e.g. a tunnel'd chip). One compile per
+        latency is high. One compile per
         (n_tokens, greedy-vs-sampled) — the temperature itself is a
         traced operand, so per-request temperature jitter reuses one
         executable; identical ids to the unfused path

@@ -84,59 +84,34 @@ class SelfAttentionLayer(BaseLayer):
 
     def apply(self, params, state, x, *, training=False, rng=None,
               mask=None):
-        from deeplearning4j_tpu.ops.attention import flash_attention
+        from deeplearning4j_tpu.ops.attention import (flash_attention,
+                                                      mesh_island)
         x = self.apply_input_dropout(x, training=training, rng=rng)
         B, T, _ = x.shape
         q, k, v = self._project_qkv(params, x)
         from deeplearning4j_tpu.parallel.seq_context import (
-            current_seq_axis, current_seq_mesh)
+            current_mesh, current_seq_axis)
         seq_axis = current_seq_axis()
-        seq_mesh = current_seq_mesh()
-        if seq_axis is not None and seq_mesh is not None:
+        mesh = current_mesh()
+        if seq_axis is not None and mesh is not None:
             # GSPMD-mode sequence parallelism (seq composed with
             # dp/tp): the step is a plain jit, so the ring gets its
-            # own shard_map ISLAND over just the seq axis — other
-            # mesh axes (data, model) stay automatic, which is what
-            # lets Megatron head-sharded projections compose with the
-            # ring (seq_context.current_seq_mesh docstring).
-            from jax.sharding import PartitionSpec as _P
-
+            # own shard_map ISLAND — time over the seq axis, batch
+            # and heads over data/model — while everything outside
+            # it stays automatic, which is what lets Megatron
+            # head-sharded projections compose with the ring
+            # (seq_context.current_mesh docstring).
             from deeplearning4j_tpu.parallel.ring_attention import (
                 ring_self_attention)
-            try:
-                from jax import shard_map as _shard_map
-            except ImportError:
-                # the legacy jax.experimental.shard_map has no
-                # partial-manual (axis_names=) mode, so the island
-                # cannot be expressed there — no silent fallback
-                raise RuntimeError(
-                    "GSPMD-mode sequence parallelism (seq composed "
-                    "with dp/tp) needs jax.shard_map with axis_names "
-                    "support (jax >= 0.9); use a data x seq mesh on "
-                    "this jax version") from None
-            qs = _P(None, seq_axis)
             causal = self.causal
-            if mask is not None:
-                def island(qc, kc, vc, mc):
-                    o = ring_self_attention(qc, kc, vc,
-                                            axis_name=seq_axis,
-                                            causal=causal, kv_mask=mc)
-                    return o * mc[:, :, None, None]
 
-                out = _shard_map(
-                    island, mesh=seq_mesh,
-                    in_specs=(qs, qs, qs, qs), out_specs=qs,
-                    axis_names=frozenset({seq_axis}))(q, k, v, mask)
-            else:
-                def island(qc, kc, vc):
-                    return ring_self_attention(qc, kc, vc,
-                                               axis_name=seq_axis,
-                                               causal=causal)
+            def island(qc, kc, vc, mc=None):
+                o = ring_self_attention(qc, kc, vc, axis_name=seq_axis,
+                                        causal=causal, kv_mask=mc)
+                return o if mc is None else o * mc[:, :, None, None]
 
-                out = _shard_map(
-                    island, mesh=seq_mesh,
-                    in_specs=(qs, qs, qs), out_specs=qs,
-                    axis_names=frozenset({seq_axis}))(q, k, v)
+            out = mesh_island(island, mesh, q, k, v, mask,
+                              seq_axis=seq_axis)
         elif seq_axis is not None:
             # manual sequence-parallel step: x is the LOCAL (B, T/n, C)
             # chunk of a sequence sharded over `seq_axis`; attention

@@ -156,10 +156,7 @@ class GlobalPoolingLayer(Layer):
             # gathered result already carries the varying type
             return jnp.max(lax.all_gather(val, seq_ax), axis=0)
         # psum/pmean outputs are seq-INVARIANT: re-mark varying
-        # (identity on jax 0.4.x — no varying-axes types there; the
-        # wrapper's shard_map runs check_rep=False, parallel/compat.py)
-        from deeplearning4j_tpu.parallel.compat import pcast_varying
-        return pcast_varying(op(val, seq_ax), seq_ax)
+        return lax.pcast(op(val, seq_ax), seq_ax, to="varying")
 
     def apply_stream(self, params, cache, x):
         """Stateful streaming inference (the rnnTimeStep contract

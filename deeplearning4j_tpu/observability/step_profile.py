@@ -41,9 +41,9 @@ __all__ = ["PEAK_BF16_FLOPS", "peak_flops_for_kind",
            "TRAIN_FLOP_MULTIPLIER", "ProfilerListener"]
 
 
-# bf16 peak FLOP/s per chip by device kind (prefix match) — mirrors
-# bench.py's table, which stays import-free on purpose (the bench
-# orchestrator must not import the package before its watchdog arms).
+# bf16 peak FLOP/s per chip by device kind (prefix match) — the one
+# table; bench.py and chip_smoke.py read it through
+# peak_flops_for_kind and treat an unknown kind as an error.
 PEAK_BF16_FLOPS = {
     "TPU v5 lite": 197e12,    # v5e
     "TPU v5": 459e12,         # v5p

@@ -32,6 +32,7 @@ implementation elsewhere (same math, same results — checked by tests).
 from __future__ import annotations
 
 import functools
+import logging
 import math
 
 import jax
@@ -41,16 +42,18 @@ import numpy as np
 __all__ = ["flash_attention", "pallas_flash_attention",
            "pallas_flash_attention_bwd"]
 
+logger = logging.getLogger("deeplearning4j_tpu")
+
 _NEG_INF = -1e30
 
 
-def _sds(sh, dt, vma):
-    """ShapeDtypeStruct, declaring varying mesh axes when the kernel
-    runs inside a checked shard_map (ring attention passes the ring
-    axis)."""
-    if vma:
-        return jax.ShapeDtypeStruct(sh, dt, vma=frozenset(vma))
-    return jax.ShapeDtypeStruct(sh, dt)
+def _vma_of(*xs):
+    """Union of the operands' varying mesh axes: empty outside a
+    ``shard_map``, the manual axes the data is split over inside one.
+    A kernel's outputs vary over exactly what its inputs vary over,
+    and a checked ``shard_map`` refuses a ``pallas_call`` whose
+    ``out_shape`` does not say so."""
+    return frozenset().union(*(jax.typeof(x).vma for x in xs))
 
 
 def _prec(precision):
@@ -163,14 +166,13 @@ def _lanes8(x, B, T):
 @functools.partial(jax.jit,
                    static_argnames=("causal", "block_q", "block_k",
                                     "interpret", "precision",
-                                    "return_lse", "vma"))
+                                    "return_lse"))
 def pallas_flash_attention(q, k, v, kv_mask=None, *,
                            causal: bool = False,
                            block_q: int = 128, block_k: int = 128,
                            interpret: bool = False,
                            precision: str = "default",
-                           return_lse: bool = False,
-                           vma=None):
+                           return_lse: bool = False):
     """q,k,v: (B, T, H, D) → (B, T, H, D) [, lse (B, H, T)]. T must be
     divisible by the block sizes (the layer wrapper pads). precision:
     'default' = bf16 MXU passes (what XLA gives plain f32 einsum);
@@ -190,6 +192,7 @@ def pallas_flash_attention(q, k, v, kv_mask=None, *,
     nq = T // block_q
     nk = T // block_k
     masked = kv_mask is not None
+    vma = _vma_of(q, k, v)
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                masked=masked, block_q=block_q,
@@ -208,8 +211,8 @@ def pallas_flash_attention(q, k, v, kv_mask=None, *,
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[
-            _sds((B * H, T, D), q.dtype, vma),
-            _sds((B * H, T, 8), jnp.float32, vma),
+            jax.ShapeDtypeStruct((B * H, T, D), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B * H, T, 8), jnp.float32, vma=vma),
         ],
         grid=(B * H, nq, nk),
         in_specs=in_specs,
@@ -366,13 +369,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
 
 @functools.partial(jax.jit,
                    static_argnames=("causal", "block_q", "block_k",
-                                    "interpret", "precision", "vma"))
+                                    "interpret", "precision"))
 def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
                                causal: bool = False,
                                block_q: int = 128, block_k: int = 128,
                                interpret: bool = False,
-                               precision: str = "default",
-                               vma=None):
+                               precision: str = "default"):
     """Backward pass: (q,k,v,o,lse,do) → (dq, dk, dv), all (B,T,H,D)
     (lse: (B,H,T) from the forward). Standard flash backward:
     delta = rowsum(do·o), p recomputed per tile from the saved lse.
@@ -394,6 +396,7 @@ def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
     nq = T // block_q
     nk = T // block_k
     prec = _prec(precision)
+    vma = _vma_of(q, k, v, do)
     masked = kv_mask is not None
     maskb = (_lanes8(kv_mask.astype(jnp.float32), B, T)
              if masked else None)
@@ -413,7 +416,7 @@ def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           masked=masked, block_q=block_q,
                           block_k=block_k, nk=nk, precision=prec),
-        out_shape=_sds((B * H, T, D), q.dtype, vma),
+        out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype, vma=vma),
         grid=(B * H, nq, nk),
         in_specs=in_specs,
         out_specs=qspec,
@@ -439,8 +442,8 @@ def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           masked=masked, block_q=block_q,
                           block_k=block_k, nq=nq, precision=prec),
-        out_shape=[_sds((B * H, T, D), k.dtype, vma),
-                   _sds((B * H, T, D), v.dtype, vma)],
+        out_shape=[jax.ShapeDtypeStruct((B * H, T, D), k.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((B * H, T, D), v.dtype, vma=vma)],
         grid=(B * H, nk, nq),
         in_specs=in_specs2,
         out_specs=[kspec2, kspec2],
@@ -607,6 +610,30 @@ def float_kv_mask(kv_mask):
     return kv_mask
 
 
+def mesh_island(fn, mesh, q, k, v, kv_mask=None, *, seq_axis=None):
+    """``fn(q, k, v[, kv_mask])`` as a fully manual ``shard_map``
+    island on ``mesh`` inside a GSPMD-partitioned step: batch over
+    'data' and heads over 'model' where they divide (attention is
+    independent per example and per head, so those axes need no
+    collective), time over ``seq_axis`` when the caller rides the ring
+    over it. Every mesh axis is manual inside — a Mosaic kernel
+    lowers only there."""
+    from jax.sharding import PartitionSpec as P
+
+    def axis(name, n):
+        size = mesh.shape.get(name, 1)
+        return name if size > 1 and n % size == 0 else None
+
+    qspec = P(axis("data", q.shape[0]), seq_axis,
+              axis("model", q.shape[2]), None)
+    operands, in_specs = (q, k, v), (qspec,) * 3
+    if kv_mask is not None:
+        operands += (kv_mask,)
+        in_specs += (P(qspec[0], seq_axis),)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=qspec)(*operands)
+
+
 def flash_attention(q, k, v, *, causal: bool = False,
                     block_q: int = 0, block_k: int = 0,
                     precision: str = "default", kv_mask=None):
@@ -619,13 +646,44 @@ def flash_attention(q, k, v, *, causal: bool = False,
     key-padding mask — variable-length batches KEEP the kernel
     (round-3 verdict weak #7); masked keys leave the softmax, padded
     query rows are the caller's to zero (reference masking contract,
-    nn/api/Layer.java:317)."""
+    nn/api/Layer.java:317).
+
+    The choice is recorded, not silent: a ``flash_attention/<impl>``
+    named scope around the call and a debug log line at trace time.
+    In a GSPMD-partitioned step that announced its mesh
+    (``parallel/seq_context.current_mesh``) the call runs as a
+    :func:`mesh_island`; inside somebody's ``shard_map`` it runs on
+    the local block as it is."""
+    from deeplearning4j_tpu.parallel.seq_context import current_mesh
+    T = q.shape[1]
     if block_q <= 0:
-        block_q = _auto_block(q.shape[1], q.shape[3])
+        block_q = _auto_block(T, q.shape[3])
     if block_k <= 0:
-        block_k = _auto_block(q.shape[1], q.shape[3])
+        block_k = _auto_block(T, q.shape[3])
     if kv_mask is not None:
         kv_mask = float_kv_mask(kv_mask)
-        return _flash_masked(q, k, v, kv_mask, causal, block_q,
-                             block_k, precision)
-    return _flash(q, k, v, causal, block_q, block_k, precision)
+        impl = ("pallas" if _use_pallas_masked(T, block_q, block_k)
+                else "exact_masked")
+
+        def fn(q, k, v, kv_mask):
+            return _flash_masked(q, k, v, kv_mask, causal, block_q,
+                                 block_k, precision)
+    else:
+        impl = ("pallas" if _use_pallas(T, block_q, block_k)
+                else "blockwise")
+
+        def fn(q, k, v):
+            return _flash(q, k, v, causal, block_q, block_k, precision)
+
+    mesh = current_mesh()
+    island = (mesh is not None and mesh.size > 1
+              and not jax.sharding.get_abstract_mesh().manual_axes)
+    logger.debug("flash_attention: %s%s q=%s %s block=(%d, %d) "
+                 "causal=%s masked=%s", impl,
+                 " in a mesh island" if island else "", q.shape,
+                 q.dtype, block_q, block_k, causal, kv_mask is not None)
+    operands = (q, k, v) if kv_mask is None else (q, k, v, kv_mask)
+    with jax.named_scope(f"flash_attention/{impl}"):
+        if island:
+            return mesh_island(fn, mesh, *operands)
+        return fn(*operands)

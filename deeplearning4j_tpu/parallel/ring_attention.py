@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 import math
 from typing import Optional
 
@@ -43,6 +44,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["ring_attention", "blockwise_attention", "attention_reference",
            "make_ring_attention_fn", "ring_self_attention"]
+
+logger = logging.getLogger("deeplearning4j_tpu")
 
 
 def attention_reference(q, k, v, *, causal: bool = False, scale=None):
@@ -230,15 +233,6 @@ def _jnp_chunk_bwd(q, k, v, o, lse, do, causal, kmask=None):
     return dq, dk, dv
 
 
-def _vma_of(x):
-    """The tracer's varying mesh axes (empty outside checked
-    shard_map / on older jax)."""
-    try:
-        return tuple(sorted(jax.typeof(x).vma))
-    except Exception:
-        return ()
-
-
 def _varying_zero_bht(q, dtype=jnp.float32):
     """A (B, H, Tl) zero derived from q (+0·x), so it carries q's FULL
     varying-axes set — under a dp×sp mesh the batch varies over
@@ -247,15 +241,13 @@ def _varying_zero_bht(q, dtype=jnp.float32):
     return (0.0 * jnp.moveaxis(q[..., 0], 1, 2)).astype(dtype)
 
 
-def _chunk_branches(causal, impl, vma=None, masked=False):
+def _chunk_branches(causal, impl, masked=False):
     """(full, diagonal, skip) forward branches for one ring chunk.
     The kernel's causal flag is static, so the runtime three-way
     (src before / at / after my block) is a lax.switch over
     statically-compiled variants. impl: 'pallas' (TPU kernels) or
-    'jnp' (test double / CPU). ``vma``: varying mesh axes of the
-    operands, declared on the kernel outputs. ``masked``: branches
-    additionally take the (B, Tk) key-padding chunk that rotates with
-    its K/V block."""
+    'jnp' (test double / CPU). ``masked``: branches additionally take
+    the (B, Tk) key-padding chunk that rotates with its K/V block."""
     from deeplearning4j_tpu.ops.attention import pallas_flash_attention
 
     def _run(q, k, v, km, c):
@@ -263,7 +255,7 @@ def _chunk_branches(causal, impl, vma=None, masked=False):
             return _jnp_chunk(q, k, v, c, km)
         return pallas_flash_attention(q, k, v, km, causal=c,
                                       block_q=_blk(q), block_k=_blk(q),
-                                      return_lse=True, vma=vma)
+                                      return_lse=True)
 
     def skip(q, k, v, *_):        # one body serves both arities
         B, T, H, D = q.shape
@@ -301,9 +293,7 @@ def _ring_flash_sharded(q, k, v, kmask=None, *, axis_name: str,
     idx = lax.axis_index(axis_name)
     B, Tl, H, D = q.shape
     masked = kmask is not None
-    full, diag, skip = _chunk_branches(
-        causal, impl, _vma_of(q) if impl == "pallas" else None,
-        masked=masked)
+    full, diag, skip = _chunk_branches(causal, impl, masked=masked)
     perm = [(i, (i + 1) % n) for i in range(n)]
     o = jnp.zeros_like(q)            # zeros_like(q): already varying
     lse = (jnp.full((B, H, Tl), -jnp.inf, jnp.float32)
@@ -345,14 +335,12 @@ def _ring_flash_bwd_sharded(q, k, v, o, lse, do, kmask=None, *,
     blk = _blk(q)
     masked = kmask is not None
 
-    vma = _vma_of(q) if impl == "pallas" else None
-
     def _run_bwd(q, k, v, o, lse, do, km, c):
         if impl == "jnp":
             return _jnp_chunk_bwd(q, k, v, o, lse, do, c, km)
         return pallas_flash_attention_bwd(q, k, v, o, lse, do, km,
                                           causal=c, block_q=blk,
-                                          block_k=blk, vma=vma)
+                                          block_k=blk)
 
     def bwd_skip(q, k, v, *_):    # one body serves both arities
         return (jnp.zeros_like(q), jnp.zeros_like(k),
@@ -478,17 +466,21 @@ def ring_self_attention(q, k, v, *, axis_name: str,
     blk = _blk(q)
     impl = ("pallas" if jax.default_backend() == "tpu" and blk > 0
             else "jnp")
-    if kv_mask is not None:
-        from deeplearning4j_tpu.ops.attention import float_kv_mask
-        kv_mask = float_kv_mask(kv_mask)
-        # the mask kernel tile puts block_k on lanes: Mosaic needs it
-        # 128-divisible or equal to the (local) array dim
-        if impl == "pallas" and not (blk % 128 == 0
-                                     or blk == q.shape[1]):
-            impl = "jnp"
-        return _make_ring_flash_masked(axis_name, causal, impl)(
-            q, k, v, kv_mask)
-    return _make_ring_flash_inner(axis_name, causal, impl)(q, k, v)
+    # the mask kernel tile puts block_k on lanes: Mosaic needs it
+    # 128-divisible or equal to the (local) array dim
+    if (kv_mask is not None and impl == "pallas"
+            and not (blk % 128 == 0 or blk == q.shape[1])):
+        impl = "jnp"
+    # the choice is recorded, not silent (as ops.attention does)
+    logger.debug("ring_self_attention: %s q=%s %s axis=%s causal=%s "
+                 "masked=%s", impl, q.shape, q.dtype, axis_name, causal,
+                 kv_mask is not None)
+    with jax.named_scope(f"ring_self_attention/{impl}"):
+        if kv_mask is not None:
+            from deeplearning4j_tpu.ops.attention import float_kv_mask
+            return _make_ring_flash_masked(axis_name, causal, impl)(
+                q, k, v, float_kv_mask(kv_mask))
+        return _make_ring_flash_inner(axis_name, causal, impl)(q, k, v)
 
 
 def make_ring_attention_fn(mesh: Mesh, *, axis: str = "seq",
@@ -501,11 +493,6 @@ def make_ring_attention_fn(mesh: Mesh, *, axis: str = "seq",
     flash kernels (forward AND backward) when running on TPU with
     tile-divisible local lengths and the default 1/sqrt(D) scale;
     'never' keeps the pure-jnp blockwise accumulation (any backend)."""
-    try:
-        from jax import shard_map
-    except ImportError:                  # older jax
-        from jax.experimental.shard_map import shard_map
-
     spec = P(None, axis, None, None)
 
     def inner(q, k, v):
@@ -520,8 +507,8 @@ def make_ring_attention_fn(mesh: Mesh, *, axis: str = "seq",
         return _ring_attention_sharded(q, k, v, axis_name=axis,
                                        causal=causal, scale=s)
 
-    sharded = shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
-                        out_specs=spec)
+    sharded = jax.shard_map(inner, mesh=mesh,
+                            in_specs=(spec, spec, spec), out_specs=spec)
 
     @jax.jit
     def fn(q, k, v):

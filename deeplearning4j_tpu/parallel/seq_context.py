@@ -1,4 +1,5 @@
-"""Trace-time sequence-parallel context.
+"""Trace-time mesh context: which seq axis a step shards time over,
+and which mesh a GSPMD-partitioned step runs on.
 
 When ``ParallelWrapper`` trains over a mesh with a ``seq`` axis it
 shards the time dimension of every (B, T, ...) activation across
@@ -16,6 +17,13 @@ wrapper's mesh decides the execution strategy (reference bar: the
 wrapper runs any Model, deeplearning4j-scaleout-parallelwrapper/
 ParallelWrapper.java:58).
 
+The same seam carries the MESH of a plain-jit (GSPMD) step
+(``gspmd_mesh``; entered by both executors' traced bodies under
+``fit(mesh_spec=)``, by the wrapper around its plain data step and by
+the tp serving backend): the Pallas attention kernels cannot be
+partitioned automatically, so ``ops.attention.flash_attention`` wraps
+itself in a shard_map on the announced mesh.
+
 A thread-local suffices because the context only needs to be live
 while JAX traces the step (tracing is single-threaded per step build);
 the traced computation itself carries no Python state.
@@ -28,7 +36,7 @@ import threading
 from typing import Optional
 
 __all__ = ["sequence_parallel", "sequence_parallel_gspmd",
-           "current_seq_axis", "current_seq_mesh",
+           "gspmd_mesh", "current_seq_axis", "current_mesh",
            "current_loss_axes"]
 
 _tls = threading.local()
@@ -39,23 +47,24 @@ def current_seq_axis() -> Optional[str]:
     return getattr(_tls, "axis", None)
 
 
-def current_seq_mesh():
-    """The mesh of a GSPMD-mode sequence-parallel trace, or None.
+def current_mesh():
+    """The mesh of a GSPMD-partitioned trace, or None.
 
-    Two execution modes share the seq seam:
+    A step is traced in one of two modes:
 
     - **manual** (``sequence_parallel``): the WRAPPER traces the whole
       step inside one shard_map; layer code sees local chunks and the
       attention layer calls ``ring_self_attention`` directly (it is
-      already inside the manual region). ``current_seq_mesh()`` is
-      None.
-    - **GSPMD** (``sequence_parallel_gspmd``): the step is a plain jit
-      with GSPMD partitioning every axis (data/model/seq), and ONLY
-      the ring needs manual collectives — the attention layer opens
-      its own shard_map island over just the seq axis (jax
-      ``axis_names={seq}``; other axes stay automatic). This is what
-      makes seq COMPOSABLE with tensor parallelism: Megatron-sharded
-      projections stay GSPMD while the ring rides its island.
+      already inside the manual region). ``current_mesh()`` is None.
+    - **GSPMD** (``gspmd_mesh``, ``sequence_parallel_gspmd``): the
+      step is a plain jit over global logical arrays and GSPMD
+      partitions every axis. What GSPMD cannot partition opens its
+      own fully manual shard_map island on this mesh: the Pallas
+      kernels (``ops/attention.flash_attention`` — a Mosaic call has
+      no partitioning rule), and, when a seq axis is active, the
+      ring's collectives (``SelfAttentionLayer``). Everything outside
+      the islands stays automatic, which is what lets
+      Megatron-sharded projections compose with them.
     """
     return getattr(_tls, "mesh", None)
 
@@ -73,37 +82,33 @@ def current_loss_axes():
 
 
 @contextlib.contextmanager
+def _scope(axis, loss_axes, mesh):
+    prev = (getattr(_tls, "axis", None),
+            getattr(_tls, "loss_axes", None),
+            getattr(_tls, "mesh", None))
+    _tls.axis, _tls.loss_axes, _tls.mesh = axis, loss_axes, mesh
+    try:
+        yield
+    finally:
+        _tls.axis, _tls.loss_axes, _tls.mesh = prev
+
+
 def sequence_parallel(axis_name: str, loss_axes=None):
     """Activate MANUAL sequence-parallel routing while tracing a step
     (inside the wrapper's shard_map)."""
-    prev = getattr(_tls, "axis", None)
-    prev_axes = getattr(_tls, "loss_axes", None)
-    prev_mesh = getattr(_tls, "mesh", None)
-    _tls.axis = axis_name
-    _tls.loss_axes = loss_axes
-    _tls.mesh = None
-    try:
-        yield
-    finally:
-        _tls.axis = prev
-        _tls.loss_axes = prev_axes
-        _tls.mesh = prev_mesh
+    return _scope(axis_name, loss_axes, None)
 
 
-@contextlib.contextmanager
 def sequence_parallel_gspmd(mesh, axis_name: str = "seq"):
     """Activate GSPMD-mode sequence-parallel routing: the attention
-    layers open shard_map islands over ``axis_name`` on ``mesh``;
-    everything else partitions automatically (composes with dp/tp)."""
-    prev = getattr(_tls, "axis", None)
-    prev_axes = getattr(_tls, "loss_axes", None)
-    prev_mesh = getattr(_tls, "mesh", None)
-    _tls.axis = axis_name
-    _tls.loss_axes = None
-    _tls.mesh = mesh
-    try:
-        yield
-    finally:
-        _tls.axis = prev
-        _tls.loss_axes = prev_axes
-        _tls.mesh = prev_mesh
+    layers open shard_map islands on ``mesh`` that ride the ring over
+    ``axis_name``; everything else partitions automatically (composes
+    with dp/tp)."""
+    return _scope(axis_name, None, mesh)
+
+
+def gspmd_mesh(mesh):
+    """Announce the mesh a plain-jit (GSPMD) step is partitioned
+    over, with no seq axis active: single-device kernel calls wrap
+    themselves in a shard_map on it (see :func:`current_mesh`)."""
+    return _scope(None, None, mesh)

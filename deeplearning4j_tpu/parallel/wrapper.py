@@ -59,6 +59,7 @@ from deeplearning4j_tpu.data.iterators import (AsyncDataSetIterator,
 from deeplearning4j_tpu.parallel.mesh import (MeshSpec, build_mesh,
                                               largest_pow2,
                                               shrink_data_mesh)
+from deeplearning4j_tpu.parallel.seq_context import gspmd_mesh
 
 logger = logging.getLogger("deeplearning4j_tpu")
 
@@ -194,8 +195,6 @@ class ParallelWrapper:
         leading mesh axis."""
         from deeplearning4j_tpu.models.computation_graph import (
             ComputationGraph)
-        from deeplearning4j_tpu.parallel.compat import (pcast_varying,
-                                                        shard_map_compat)
         from deeplearning4j_tpu.parallel.compression import (
             make_compressed_psum_ef)
 
@@ -217,10 +216,10 @@ class ParallelWrapper:
             residual = jax.tree_util.tree_map(lambda r: r[0], residual)
             # mark params device-varying: otherwise jax's varying-axes
             # AD auto-psums the cotangent (full-precision!) before we
-            # get to intercept it with the compressed reduce (0.4.x:
-            # identity — check_rep=False already leaves the cotangent
-            # per-device, see parallel/compat.py)
-            params_v = pcast_varying(params, "data")
+            # get to intercept it with the compressed reduce
+            params_v = jax.tree_util.tree_map(
+                lambda p: jax.lax.pcast(p, "data", to="varying"),
+                params)
 
             def loss_fn(p):
                 return model._loss(p, state, batch, rng, training=True)
@@ -238,11 +237,14 @@ class ParallelWrapper:
                                                   new_residual)
             return new_params, new_state, new_opt, new_residual, loss
 
-        smapped = shard_map_compat(
+        # check_vma=True: the varying-axes types are what make AD
+        # psum replicated-param cotangents (and what _varying opts
+        # out of) — the step's math depends on them being tracked
+        smapped = jax.shard_map(
             per_device, mesh=mesh,
             in_specs=(P(), P(), P(), P("data"), P("data"), P(), P()),
             out_specs=(P(), P(), P(), P("data"), P()),
-            varying_params=True)
+            check_vma=True)
         return jax.jit(smapped, donate_argnums=(0, 1, 2, 3))
 
     # ---- sequence-parallel train step ----
@@ -383,9 +385,6 @@ class ParallelWrapper:
         compression exists for."""
         from deeplearning4j_tpu.models.computation_graph import (
             ComputationGraph)
-        from deeplearning4j_tpu.parallel.compat import (HAS_PCAST,
-                                                        pcast_varying,
-                                                        shard_map_compat)
         from deeplearning4j_tpu.parallel.seq_context import (
             sequence_parallel)
 
@@ -417,7 +416,9 @@ class ParallelWrapper:
                 # varying over 'data' only: the seq cotangent still
                 # auto-psums (full precision, ICI); the data-axis
                 # reduction is ours to compress
-                params_in = pcast_varying(params, "data")
+                params_in = jax.tree_util.tree_map(
+                    lambda p: jax.lax.pcast(p, "data", to="varying"),
+                    params)
             else:
                 params_in = params
             with sequence_parallel("seq", loss_axes=axes):
@@ -427,18 +428,6 @@ class ParallelWrapper:
 
                 (loss, new_state), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params_in)
-            if not HAS_PCAST:
-                # 0.4.x fallback (check_rep=False): NO cotangent
-                # auto-psum happened — reduce explicitly, in full
-                # precision, over exactly the axes new jax's AD
-                # covers (every axis uncompressed; 'seq' only when
-                # the data-axis reduction belongs to the compressed
-                # psum below)
-                red = (tuple(a for a in axes if a != "data")
-                       if compressed else axes)
-                if red:
-                    grads = jax.tree_util.tree_map(
-                        lambda g: jax.lax.psum(g, red), grads)
             # grads on each data shard: Σ over seq shards of ∂(local
             # mean loss); the global loss is the MEAN of the uniform
             # local means — normalize by the full shard count
@@ -461,11 +450,11 @@ class ParallelWrapper:
         bspec_l = P(daxis) if self._seq_collapses else bspec_t
         bspec = (bspec_t, bspec_l, bspec_t, bspec_l)
         if compressed:
-            smapped = shard_map_compat(
+            smapped = jax.shard_map(
                 per_device, mesh=mesh,
                 in_specs=(P(), P(), P(), P("data"), bspec, P(), P()),
                 out_specs=(P(), P(), P(), P("data"), P()),
-                varying_params=True)
+                check_vma=True)
             return jax.jit(smapped, donate_argnums=(0, 1, 2, 3))
 
         def no_residual(params, state, opt_state, batch, base_rng,
@@ -473,11 +462,13 @@ class ParallelWrapper:
             return per_device(params, state, opt_state, None, batch,
                               base_rng, step)
 
-        smapped = shard_map_compat(
+        # check_vma=True: AD's psum of the replicated-param
+        # cotangents over every mesh axis IS the gradient reduction
+        smapped = jax.shard_map(
             no_residual, mesh=mesh,
             in_specs=(P(), P(), P(), bspec, P(), P()),
             out_specs=(P(), P(), P(), P()),
-            varying_params=True)
+            check_vma=True)
         return jax.jit(smapped, donate_argnums=(0, 1, 2))
 
     def _make_seq_gspmd_step(self):
@@ -800,10 +791,15 @@ class ParallelWrapper:
                 self._residual, batch, model._rng_key,
                 np.int32(model.iteration_count))
         else:
-            model.params, model.state, model.opt_state, loss = \
-                step(model.params, model.state, model.opt_state,
-                     batch, model._rng_key,
-                     np.int32(model.iteration_count))
+            # the plain step is the model's own jit, partitioned by
+            # GSPMD over this mesh: announce it for whenever the call
+            # traces, so kernel calls wrap themselves for it (the
+            # manual seq step sets its own scope inside)
+            with gspmd_mesh(self.mesh):
+                model.params, model.state, model.opt_state, loss = \
+                    step(model.params, model.state, model.opt_state,
+                         batch, model._rng_key,
+                         np.int32(model.iteration_count))
         model.score_value = loss
         for lst in model.listeners:
             lst.iteration_done(model, model.iteration_count, loss, n)

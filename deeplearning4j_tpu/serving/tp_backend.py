@@ -45,6 +45,7 @@ class TensorParallelModel:
         from jax.sharding import NamedSharding, PartitionSpec as P
         from deeplearning4j_tpu.parallel.mesh_spec import (
             build_mesh_context)
+        from deeplearning4j_tpu.parallel.seq_context import gspmd_mesh
         from deeplearning4j_tpu.serving.errors import ServingError
 
         if not hasattr(model, "_forward"):
@@ -74,8 +75,9 @@ class TensorParallelModel:
         self._lock = threading.Lock()
 
         def fwd(params, state, x):
-            y, _, _, _ = model._forward(params, state, x,
-                                        training=False, rng=None)
+            with gspmd_mesh(self.ctx.mesh):
+                y, _, _, _ = model._forward(params, state, x,
+                                            training=False, rng=None)
             return y
 
         self._jit_fwd = jax.jit(fwd, out_shardings=self._repl)

@@ -1,0 +1,227 @@
+"""Compile-only checks against a DESCRIBED TPU v5e (no chip attached).
+
+The TPU compiler is installed in the CPU sandbox and compiles for a
+topology that is described, not attached — so what Mosaic or the SPMD
+partitioner would refuse on the chip is refused here, at no chip time
+(the on-chip-measurement guide, section 2, rehearsal 3). Nothing runs:
+these cases say nothing about results or times.
+
+Covered: the flash kernels of the main path at real widths, forward
+and backward, plain and key-masked; the same kernels under a
+four-device ``data`` mesh both ways a user reaches them —
+``fit(mesh_spec=)``'s GSPMD step (a Mosaic call has no partitioning
+rule: ``flash_attention`` must open its own shard_map island) and
+``ParallelWrapper``'s manual shard_map step (a checked shard_map
+needs the kernel outputs' varying axes declared) — and the ring island
+of a dp x tp x sp mesh. Dispatch asks ``jax.default_backend()``, which
+is 'cpu' here, so the cases that go through ``flash_attention`` steer
+it in the test.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import (Mesh, NamedSharding,  # noqa: E402
+                          PartitionSpec as P, SingleDeviceSharding)
+
+from deeplearning4j_tpu.ops import attention as A  # noqa: E402
+from deeplearning4j_tpu.parallel.mesh import AXES  # noqa: E402
+from deeplearning4j_tpu.parallel.seq_context import (  # noqa: E402
+    gspmd_mesh, sequence_parallel_gspmd)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:     # no libtpu / unknown topology here
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without the chip (the next one
+    warns and recompiles) — keep the cache out of these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Kernel dispatch decides on jax.default_backend(); the described
+    device does not change it, so the test does."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _mesh(topo, **sizes):
+    shape = tuple(sizes.get(a, 1) for a in AXES)
+    return Mesh(np.array(topo.devices).reshape(shape), AXES)
+
+
+def _kernels_in(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+# ---- single chip: the kernels themselves ---------------------------------
+
+@pytest.mark.parametrize("shape", [(8, 1024, 16, 64), (2, 1024, 8, 128)],
+                         ids=["lm_b8_h16_d64", "b2_h8_d128"])
+@pytest.mark.parametrize("masked", [False, True],
+                         ids=["plain", "kv_masked"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_kernel_compiles_for_v5e(topo, shape, masked, direction):
+    B, T, H, D = shape
+    one = SingleDeviceSharding(topo.devices[0])
+    blk = A._auto_block(T, D)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+    mask = (jax.ShapeDtypeStruct((B, T), jnp.float32, sharding=one)
+            if masked else None)
+    kw = dict(causal=True, block_q=blk, block_k=blk)
+    if direction == "fwd":
+        def fn(q, k, v, m):
+            return A.pallas_flash_attention(q, k, v, m,
+                                            return_lse=True, **kw)
+        args = (x, x, x, mask)
+        want = 1
+    else:
+        lse = jax.ShapeDtypeStruct((B, H, T), jnp.float32, sharding=one)
+
+        def fn(q, k, v, o, l, do, m):
+            return A.pallas_flash_attention_bwd(q, k, v, o, l, do, m,
+                                                **kw)
+        args = (x, x, x, x, lse, x, mask)
+        want = 2                  # dq, fused dk/dv
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert _kernels_in(compiled) == want
+
+
+def test_small_auto_block_compiles_for_v5e(topo):
+    """T = 40 takes the 8-wide auto tile: the smallest block the
+    dispatcher can pick must still satisfy Mosaic's tiling."""
+    one = SingleDeviceSharding(topo.devices[0])
+    blk = A._auto_block(40, 64)
+    assert blk == 8
+    x = jax.ShapeDtypeStruct((2, 40, 4, 64), jnp.float32, sharding=one)
+
+    def loss(q, k, v):
+        o, lse = A.pallas_flash_attention(q, k, v, causal=True,
+                                          block_q=blk, block_k=blk,
+                                          return_lse=True)
+        return A.pallas_flash_attention_bwd(q, k, v, o, lse, o,
+                                            causal=True, block_q=blk,
+                                            block_k=blk)
+    assert _kernels_in(jax.jit(loss).lower(x, x, x).compile()) == 3
+
+
+# ---- four chips: the kernels on a mesh -----------------------------------
+
+def _attention_loss(q, k, v, mask=None):
+    o = A.flash_attention(q, k, v, causal=True, kv_mask=mask)
+    return jnp.sum(o.astype(jnp.float32) ** 2)
+
+
+@pytest.mark.parametrize("masked", [False, True],
+                         ids=["plain", "kv_masked"])
+def test_flash_under_gspmd_data_mesh(topo, as_tpu, masked):
+    """The fit(mesh_spec="dp=4") path: a plain jit over batch-sharded
+    operands. Without the island the partitioner answers 'Mosaic
+    kernels cannot be automatically partitioned'."""
+    mesh = _mesh(topo, data=4)
+    x = jax.ShapeDtypeStruct((8, 1024, 16, 64), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("data")))
+    args = (x, x, x)
+    if masked:
+        args += (jax.ShapeDtypeStruct(
+            (8, 1024), jnp.float32,
+            sharding=NamedSharding(mesh, P("data"))),)
+
+    def step(*a):
+        with gspmd_mesh(mesh):
+            return jax.value_and_grad(_attention_loss,
+                                      argnums=(0, 1, 2))(*a)
+    compiled = jax.jit(step).lower(*args).compile()
+    assert _kernels_in(compiled) == 3          # fwd, dq, dk/dv
+    # the island really splits the batch: 8 examples -> 2 per device
+    assert "bf16[2,1024,16,64]" in compiled.as_text()
+
+
+def test_flash_under_gspmd_dp_tp_mesh(topo, as_tpu):
+    """dp=2 x tp=2 (serving/tp_backend's forward): batch over 'data',
+    heads over 'model'."""
+    mesh = _mesh(topo, data=2, model=2)
+    x = jax.ShapeDtypeStruct(
+        (8, 1024, 16, 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("data", None, "model", None)))
+
+    def fwd(q, k, v):
+        with gspmd_mesh(mesh):
+            return A.flash_attention(q, k, v, causal=True)
+    compiled = jax.jit(fwd).lower(x, x, x).compile()
+    assert _kernels_in(compiled) == 1
+    assert "bf16[4,1024,8,64]" in compiled.as_text()
+
+
+@pytest.mark.parametrize("masked", [False, True],
+                         ids=["plain", "kv_masked"])
+def test_flash_inside_manual_shard_map(topo, as_tpu, masked):
+    """The ParallelWrapper manual-step path: the whole loss traced
+    inside one checked shard_map over 'data'. Without vma on the
+    kernel outputs shard_map answers 'vma ... must not be None'."""
+    mesh = _mesh(topo, data=4)
+    x = jax.ShapeDtypeStruct((8, 1024, 16, 64), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("data")))
+    args, specs = (x, x, x), (P("data"),) * 3
+    if masked:
+        args += (jax.ShapeDtypeStruct(
+            (8, 1024), jnp.float32,
+            sharding=NamedSharding(mesh, P("data"))),)
+        specs += (P("data"),)
+
+    def per_device(*a):
+        loss, grads = jax.value_and_grad(_attention_loss,
+                                         argnums=(0, 1, 2))(*a)
+        return jax.lax.pmean(loss, "data"), grads
+    step = jax.shard_map(per_device, mesh=mesh, in_specs=specs,
+                         out_specs=(P(), (P("data"),) * 3),
+                         check_vma=True)
+    assert _kernels_in(jax.jit(step).lower(*args).compile()) == 3
+
+
+def test_ring_island_on_dp_tp_sp_mesh(topo, as_tpu):
+    """GSPMD-mode sequence parallelism: the attention layer's ring
+    island must be manual over EVERY mesh axis — a Mosaic call under
+    an axis left automatic is refused like the plain GSPMD case."""
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    mesh = _mesh(topo, data=2, seq=2)
+    layer = SelfAttentionLayer(n_in=256, n_out=256, n_heads=4,
+                               causal=True)
+    repl = NamedSharding(mesh, P())
+    params = {k: jax.ShapeDtypeStruct((256, 256), jnp.float32,
+                                      sharding=repl)
+              for k in ("Wq", "Wk", "Wv", "Wo")}
+    params["bo"] = jax.ShapeDtypeStruct((256,), jnp.float32,
+                                        sharding=repl)
+    x = jax.ShapeDtypeStruct(
+        (4, 2048, 256), jnp.float32,
+        sharding=NamedSharding(mesh, P("data", "seq")))
+
+    def loss(p, x):
+        with sequence_parallel_gspmd(mesh, "seq"):
+            y, _ = layer.apply(p, {}, x)
+        return jnp.sum(y ** 2)
+    compiled = jax.jit(jax.grad(loss)).lower(params, x).compile()
+    assert _kernels_in(compiled) >= 3
+    assert "collective-permute" in compiled.as_text()

@@ -313,6 +313,23 @@ class TestGL005LiteralDrift:
                                {"value": 100.0, "unit": "u"}]})
         assert run_lint(repo, paths=[], rules=["GL005"]).new == []
 
+    def test_missing_artifact_means_nothing_was_measured(self,
+                                                         tmp_path):
+        # no BENCH_DETAIL.json: every multiplier is a finding (both
+        # through the rule and through the legacy check()), targets
+        # stay exempt, and the other sub-checks still run
+        from tools.graftlint.rules import gl005_literal_drift as gl5
+        repo = self._fake_repo(
+            tmp_path,
+            "measured 1.3x vs baseline\n"
+            "derived 2.0x between configs\n"
+            "goal (target: 0.7x) is exempt\n"
+            "alert on `foo_requests_total`\n")
+        os.remove(os.path.join(repo, "BENCH_DETAIL.json"))
+        r = run_lint(repo, paths=[], rules=["GL005"])
+        assert sorted(f.line for f in r.new) == [1, 2]
+        assert len(gl5.check(repo)) == 2
+
     def test_suppressed_markdown_comment(self, tmp_path):
         repo = self._fake_repo(
             tmp_path,

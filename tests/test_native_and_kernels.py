@@ -541,8 +541,8 @@ class TestMaskedFlashKernels:
         """Same contract for ring_self_attention inside shard_map."""
         import jax
         import jax.numpy as jnp
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
         from deeplearning4j_tpu.parallel.ring_attention import (
             ring_self_attention)

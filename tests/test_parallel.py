@@ -361,7 +361,7 @@ class TestCompression:
         """int8_quantize_ef on one member must produce the same
         residual and total as int8_all_reduce_ef over a 1-wide axis:
         the PS push path and the DCN all-reduce share one quantizer."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from deeplearning4j_tpu.parallel.compression import (
@@ -606,7 +606,6 @@ class TestRingFlashAttention:
     def _run(self, causal):
         from jax.sharding import PartitionSpec as P
 
-        from deeplearning4j_tpu.parallel.compat import shard_map_compat
         from deeplearning4j_tpu.parallel.mesh import MeshSpec, build_mesh
         from deeplearning4j_tpu.parallel.ring_attention import (
             _make_ring_flash_inner, attention_reference)
@@ -614,10 +613,9 @@ class TestRingFlashAttention:
         q, k, v, do = self._mkqkv()
         spec = P(None, "seq", None, None)
         inner = _make_ring_flash_inner("seq", causal, impl="jnp")
-        fn = jax.jit(shard_map_compat(inner, mesh=mesh,
-                                      in_specs=(spec, spec, spec),
-                                      out_specs=spec,
-                                      varying_params=True))
+        fn = jax.jit(jax.shard_map(inner, mesh=mesh,
+                                   in_specs=(spec, spec, spec),
+                                   out_specs=spec))
         o = fn(q, k, v)
         ref = attention_reference(q, k, v, causal=causal)
         np.testing.assert_allclose(np.asarray(o), np.asarray(ref),
@@ -635,21 +633,7 @@ class TestRingFlashAttention:
                 np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5,
                 err_msg=f"{name} mismatch (causal={causal})")
 
-    @staticmethod
-    def _skip_unless_noncausal_ring_portable():
-        # the NON-causal ring lowers an axis_index into a PartitionId
-        # instruction jax 0.4.x's SPMD partitioner refuses
-        # ("PartitionId ... is ambiguous"); the causal ring (and the
-        # whole executor-integrated seq path) runs fine there via
-        # parallel/compat.py — this is the one ring regime that
-        # genuinely needs newer jax/XLA
-        from deeplearning4j_tpu.parallel.compat import HAS_PCAST
-        if not HAS_PCAST:
-            pytest.skip("non-causal ring flash needs newer jax/XLA "
-                        "(PartitionId unsupported under 0.4.x SPMD)")
-
     def test_ring_flash_matches_oracle(self):
-        self._skip_unless_noncausal_ring_portable()
         self._run(causal=False)
 
     def test_ring_flash_causal_matches_oracle(self):
@@ -671,10 +655,8 @@ class TestRingFlashAttention:
         """bf16 q/k/v through the ring (the mixed-precision activation
         dtype): carry dtypes must stay stable and the result must
         match the f32 oracle at bf16 tolerance."""
-        self._skip_unless_noncausal_ring_portable()
         from jax.sharding import PartitionSpec as P
 
-        from deeplearning4j_tpu.parallel.compat import shard_map_compat
         from deeplearning4j_tpu.parallel.mesh import MeshSpec, build_mesh
         from deeplearning4j_tpu.parallel.ring_attention import (
             _make_ring_flash_inner, attention_reference)
@@ -683,10 +665,9 @@ class TestRingFlashAttention:
         qh, kh, vh = (a.astype(jnp.bfloat16) for a in (q, k, v))
         spec = P(None, "seq", None, None)
         inner = _make_ring_flash_inner("seq", False, impl="jnp")
-        fn = jax.jit(shard_map_compat(inner, mesh=mesh,
-                                      in_specs=(spec, spec, spec),
-                                      out_specs=spec,
-                                      varying_params=True))
+        fn = jax.jit(jax.shard_map(inner, mesh=mesh,
+                                   in_specs=(spec, spec, spec),
+                                   out_specs=spec))
         o = fn(qh, kh, vh)
         assert o.dtype == jnp.bfloat16
         ref = attention_reference(q, k, v)
@@ -914,10 +895,8 @@ class TestNetworkSpmdPipeline:
         bridge.train_batch(x, y)
         bridge.train_batch(x, y)
         bridge.collect_params()
-        # jax-version-dependent parity envelope (see the constant's
-        # rationale in parallel/compat.py); pp4-vs-pp1 below stays
-        # exact on both jax lines
-        from deeplearning4j_tpu.parallel.compat import (
+        # the envelope the dryrun shares; pp4-vs-pp1 below is exact
+        from deeplearning4j_tpu.parallel.pipeline_spmd import (
             PP_SINGLE_DEVICE_TOL)
         rt, at = PP_SINGLE_DEVICE_TOL
         np.testing.assert_allclose(

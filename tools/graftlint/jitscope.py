@@ -27,7 +27,6 @@ JIT_WRAPPERS = {
 # canonical dotted names whose FIRST argument is a traced body
 BODY_TAKERS = {
     "jax.shard_map", "shard_map",
-    "jax.experimental.shard_map.shard_map",
     "jax.lax.scan", "lax.scan",
     "jax.lax.while_loop", "lax.while_loop",
     "jax.lax.fori_loop", "lax.fori_loop",
