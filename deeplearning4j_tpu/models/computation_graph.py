@@ -165,7 +165,11 @@ class ComputationGraph(KStepExecutorMixin):
                         if rng is not None else None)
                 from deeplearning4j_tpu.nn.errors import (
                     layer_error_context)
-                with layer_error_context(f"vertex '{name}'", obj, xs[0]):
+                # the vertex's name on its device ops (metadata
+                # only): a profiler trace then splits fusion time by
+                # layer
+                with layer_error_context(f"vertex '{name}'", obj,
+                                         xs[0]), jax.named_scope(name):
                     if carries is not None and \
                             isinstance(obj, BaseRecurrentLayer):
                         c0 = carries.get(name)
@@ -204,7 +208,8 @@ class ComputationGraph(KStepExecutorMixin):
                 else:
                     use_mask = combine_masks_or(in_masks)
                 with layer_error_context(f"vertex '{name}'", obj,
-                                         xs[0] if xs else None):
+                                         xs[0] if xs else None), \
+                        jax.named_scope(name):
                     acts[name] = obj.apply(xs, mask=use_mask)
                 masks[name] = obj.propagate_mask(in_masks, xs,
                                                  mask_env=masks)
@@ -226,9 +231,10 @@ class ComputationGraph(KStepExecutorMixin):
                 lrng = (jax.random.fold_in(rng, 1000 + topo.index(out_name))
                         if rng is not None else None)
                 lmask = lmasks[i] if lmasks is not None else None
-                total = total + obj.loss_from_input(
-                    params[out_name], acts[out_name], labels[i],
-                    training=training, rng=lrng, mask=lmask)
+                with jax.named_scope(out_name):
+                    total = total + obj.loss_from_input(
+                        params[out_name], acts[out_name], labels[i],
+                        training=training, rng=lrng, mask=lmask)
                 if isinstance(obj, CenterLossOutputLayer):
                     total = total + obj.lambda_ * obj.center_loss(
                         state[out_name], acts[out_name], labels[i])
@@ -260,13 +266,14 @@ class ComputationGraph(KStepExecutorMixin):
             apply_gradient_normalization)
         layer_cfgs = {n: v[0] for n, v in self.conf.vertices.items()
                       if n in params}
-        grads = apply_gradient_normalization(layer_cfgs, grads)
-        updates, new_opt = optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
-        constrained = {}
-        for name, p in new_params.items():
-            obj, _ = self.conf.vertices[name]
-            constrained[name] = apply_layer_constraints(obj, p)
+        with jax.named_scope("updater"):
+            grads = apply_gradient_normalization(layer_cfgs, grads)
+            updates, new_opt = optimizer.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
+            constrained = {}
+            for name, p in new_params.items():
+                obj, _ = self.conf.vertices[name]
+                constrained[name] = apply_layer_constraints(obj, p)
         if self._health_enabled:
             # fused finite check + global norms, computed inside
             # this same XLA program (observability/health.py)

@@ -95,6 +95,24 @@ def stack_batches(batch_tuples: Sequence):
         *batch_tuples)
 
 
+def _tree_nbytes(tree) -> int:
+    """Bytes of a batch's arrays as they cross to the device."""
+    import jax
+    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree))
+
+
+def _h2d_wait(trace, batch) -> None:
+    """While tracing, and with the step already enqueued: wait until
+    the batch's copy has landed, which is when the device can start
+    the step. The span's end names what the device waited for; it
+    does not delay the device, and the host would block in a
+    listener's score fetch next anyway. Off, nothing is called."""
+    if trace.enabled:
+        import jax
+        with trace.span("h2d_wait"):
+            jax.block_until_ready(batch)
+
+
 def make_kstep_fn(step_core, k: int, health_enabled: bool,
                   out_shardings=None):
     """Build the fused k-step train program.
@@ -300,29 +318,39 @@ class KStepExecutorMixin:
         from deeplearning4j_tpu.observability.tracing import trace
         pending = []          # k-step window under collection
         while True:
-            # data wait timed apart from the step so the profiler/
-            # tracer can tell an input-starved chip from a
-            # dispatch-bound host
-            t0 = time.perf_counter()
-            with trace.span("data_wait"):
-                ds = next(data_iter, None)
-            if ds is None:
-                break
-            wait = time.perf_counter() - t0
-            m = self._coerce_fit_batch(ds)
-            if self._batch_is_tbptt(m, tbptt):
-                # tBPTT chunks its own loop — flush the window first
-                # so step order is preserved
-                self._flush_window(pending, k)
-                with trace.span("train_step_tbptt"):
-                    self._run_tbptt(m, tbptt, data_wait_s=wait)
-                continue
-            if k == 1:
-                self._fit_one(m, wait)
-                continue
-            pending.append((m, wait))
-            if len(pending) == k:
-                self._flush_window(pending, k)
+            # one iteration's spans hang under ``step`` (a group, so
+            # not annotated into the profiler's trace); the tracer may
+            # be switched inside the iterator, so an iteration can
+            # arrive without one
+            with trace.span("step", annotate=False) as step:
+                # data wait timed apart from the step so the profiler/
+                # tracer can tell an input-starved chip from a
+                # dispatch-bound host
+                t0 = time.perf_counter()
+                with trace.span("data_wait"):
+                    ds = next(data_iter, None)
+                if ds is None:
+                    step.set("exhausted", True)
+                    break
+                wait = time.perf_counter() - t0
+                m = self._coerce_fit_batch(ds)
+                # the iteration this batch becomes (a window's batches
+                # wait in ``pending`` for its last)
+                step.set("iteration", self.iteration_count + len(pending))
+                step.set("samples", m.num_examples())
+                if self._batch_is_tbptt(m, tbptt):
+                    # tBPTT chunks its own loop — flush the window
+                    # first so step order is preserved
+                    self._flush_window(pending, k)
+                    with trace.span("train_step_tbptt"):
+                        self._run_tbptt(m, tbptt, data_wait_s=wait)
+                    continue
+                if k == 1:
+                    self._fit_one(m, wait)
+                    continue
+                pending.append((m, wait))
+                if len(pending) == k:
+                    self._flush_window(pending, k)
         self._flush_window(pending, k)
 
     def _fit_one(self, ds, data_wait_s: float = 0.0):
@@ -331,20 +359,24 @@ class KStepExecutorMixin:
         from deeplearning4j_tpu.observability.tracing import trace
         t1 = time.perf_counter()
         with trace.span("train_step"):
-            if self._mesh_ctx is not None:
-                # shard from HOST arrays: host→mesh device_put is a
-                # plain per-shard copy, while resharding an already-
-                # committed device array onto a multi-axis mesh
-                # compiles a _multi_slice program per shape — a stray
-                # compile the warmed zero-compile steady state must
-                # not pay
-                batch = self._mesh_ctx.shard_batch(
-                    self._batch_tuple_np(ds))
-            else:
-                batch = self._batch_tuple(ds)
-            out = self._step_fn_for(batch)(
-                self.params, self.state, self.opt_state, batch,
-                self._rng_key, np.int32(self.iteration_count))
+            with trace.span("batch_to_device") as sp:
+                if self._mesh_ctx is not None:
+                    # shard from HOST arrays: host→mesh device_put is
+                    # a plain per-shard copy, while resharding an
+                    # already-committed device array onto a multi-axis
+                    # mesh compiles a _multi_slice program per shape —
+                    # a stray compile the warmed zero-compile steady
+                    # state must not pay
+                    batch = self._mesh_ctx.shard_batch(
+                        self._batch_tuple_np(ds))
+                else:
+                    batch = self._batch_tuple(ds)
+                if trace.enabled:
+                    sp.set("bytes", _tree_nbytes(batch))
+            with trace.span("enqueue"):
+                out = self._step_fn_for(batch)(
+                    self.params, self.state, self.opt_state, batch,
+                    self._rng_key, np.int32(self.iteration_count))
         if self._health_enabled:
             (self.params, self.state, self.opt_state,
              loss, self._last_health) = out
@@ -354,6 +386,7 @@ class KStepExecutorMixin:
         self.score_value = loss
         # (data_wait_s, dispatch_s) — ProfilerListener
         self._step_timing = (data_wait_s, time.perf_counter() - t1)
+        _h2d_wait(trace, batch)
         with trace.span("listeners"):
             for lst in self.listeners:
                 lst.iteration_done(self, self.iteration_count, loss,
@@ -450,14 +483,23 @@ class KStepExecutorMixin:
         window — every step is still observed, detection lag is
         bounded by k."""
         from deeplearning4j_tpu.observability.tracing import trace
-        window = stack_batches(tups)
-        if self._mesh_ctx is not None:
-            window = self._mesh_ctx.shard_window(window)
-        fn = self._kstep_fn_for(window, k)
-        t1 = time.perf_counter()
-        with trace.span("train_step_fused"):
-            out = fn(self.params, self.state, self.opt_state, window,
-                     self._rng_key, np.int32(self.iteration_count))
+        with trace.span("train_step_fused") as fused:
+            with trace.span("batch_to_device") as sp:
+                window = stack_batches(tups)
+                if self._mesh_ctx is not None:
+                    window = self._mesh_ctx.shard_window(window)
+                if trace.enabled:
+                    sp.set("bytes", _tree_nbytes(window))
+            fn = self._kstep_fn_for(window, k)
+            t1 = time.perf_counter()
+            # without a mesh the window is still on the host here: its
+            # copy rides the call
+            with trace.span("enqueue"):
+                out = fn(self.params, self.state, self.opt_state,
+                         window, self._rng_key,
+                         np.int32(self.iteration_count))
+            fused.set("steps", k)
+        _h2d_wait(trace, window)
         health_host = None
         if self._health_enabled:
             (self.params, self.state, self.opt_state,

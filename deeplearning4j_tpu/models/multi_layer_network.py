@@ -183,7 +183,10 @@ class MultiLayerNetwork(KStepExecutorMixin):
             lrng = None
             if rng is not None:
                 lrng = jax.random.fold_in(rng, i)
-            with layer_error_context(f"layer {i}", layer, x):
+            # the layer's name on its device ops (metadata only): a
+            # profiler trace then splits fusion time by layer
+            with layer_error_context(f"layer {i}", layer, x), \
+                    jax.named_scope(f"{i}_{type(layer).__name__}"):
                 if carries is not None and isinstance(layer,
                                                      BaseRecurrentLayer):
                     c0 = carries[i]
@@ -219,9 +222,11 @@ class MultiLayerNetwork(KStepExecutorMixin):
         if out_idx in self.conf.preprocessors:
             h = self.conf.preprocessors[out_idx](h)
         orng = jax.random.fold_in(rng, out_idx) if rng is not None else None
-        loss = out_layer.loss_from_input(params[out_idx], h, labels,
-                                         training=training, rng=orng,
-                                         mask=lmask)
+        with jax.named_scope(
+                f"{out_idx}_{type(out_layer).__name__}"):
+            loss = out_layer.loss_from_input(
+                params[out_idx], h, labels, training=training,
+                rng=orng, mask=lmask)
         if isinstance(out_layer, CenterLossOutputLayer):
             loss = loss + out_layer.lambda_ * out_layer.center_loss(
                 state[out_idx], h, labels)
@@ -255,14 +260,15 @@ class MultiLayerNetwork(KStepExecutorMixin):
         with self._mesh_scope():
             (loss, new_states), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
-        grads = apply_gradient_normalization(self.layers, grads)
-        updates, new_opt_state = optimizer.update(grads, opt_state,
-                                                  params)
-        new_params = optax.apply_updates(params, updates)
-        new_params = [
-            apply_layer_constraints(l, p)
-            for l, p in zip(self.layers, new_params)
-        ]
+        with jax.named_scope("updater"):
+            grads = apply_gradient_normalization(self.layers, grads)
+            updates, new_opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+            new_params = optax.apply_updates(params, updates)
+            new_params = [
+                apply_layer_constraints(l, p)
+                for l, p in zip(self.layers, new_params)
+            ]
         if self._health_enabled:
             # fused finite check + global norms, computed inside
             # this same XLA program (observability/health.py)

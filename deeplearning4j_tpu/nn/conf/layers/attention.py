@@ -376,19 +376,26 @@ class TransformerEncoderLayer(BaseLayer):
     def apply(self, params, state, x, *, training=False, rng=None,
               mask=None):
         self._ensure_attn()
-        h = _layer_norm(x, params["ln1_g"], params["ln1_b"])
-        a, _ = self._attn.apply(params["attn"], {}, h,
-                                training=training, rng=rng, mask=mask)
+        # the halves' names on their device ops (metadata only): a
+        # profiler trace then splits the block's fusion time
+        with jax.named_scope("ln1"):
+            h = _layer_norm(x, params["ln1_g"], params["ln1_b"])
+        with jax.named_scope("attn"):
+            a, _ = self._attn.apply(params["attn"], {}, h,
+                                    training=training, rng=rng,
+                                    mask=mask)
         x = x + a
         return x + self._mlp_half(params, x), state
 
     def _mlp_half(self, params, x):
         """Pre-LN MLP residual branch — shared by apply and
         apply_stream (per-token, so streaming needs no carry)."""
-        h = _layer_norm(x, params["ln2_g"], params["ln2_b"])
+        with jax.named_scope("ln2"):
+            h = _layer_norm(x, params["ln2_g"], params["ln2_b"])
         act = self.activation_fn()
-        return act(h @ params["W1"] + params["b1"]) @ params["W2"] \
-            + params["b2"]
+        with jax.named_scope("mlp"):
+            return act(h @ params["W1"] + params["b1"]) \
+                @ params["W2"] + params["b2"]
 
     def apply_stream(self, params, cache, x):
         """Incremental decode through the full pre-LN block: the

@@ -24,6 +24,18 @@ Design constraints, in priority order:
    eviction) rather than growing without bound inside a
    long-running server, so an export holds the newest traces.
 
+One clock (ISSUE 24). Every recorded event carries ``t_ns``, its
+start in raw ``time.perf_counter_ns()``; a span opened inside another
+on the same thread records the parent's ``span_id`` as ``parent_id``
+(self time = duration minus the children's cover). While enabled, a
+``with`` span also enters a ``jax.profiler.TraceAnnotation`` named
+``dl4j/<name>``, and :meth:`Tracer.enable` writes one
+``dl4j/clock_anchor/<perf_counter_ns>`` annotation: a profiler capture
+whose host tracer is on then holds the program's spans on the device
+trace's clock, and ``anchor.start_ns - <perf_counter_ns>`` is the exact
+offset from ``t_ns`` to that clock. ``jax`` is imported inside
+``enable()`` only.
+
 Request-scoped tracing (the serving observability PR) adds
 :class:`RequestContext`: one trace id minted at HTTP admission (or
 adopted from a W3C ``traceparent`` header, so a router→replica hop
@@ -79,6 +91,9 @@ class _NoopSpan:
     def set(self, key, value):          # attr API parity with Span
         return self
 
+    def discard(self):
+        return self
+
 
 _NOOP_SPAN = _NoopSpan()
 
@@ -111,13 +126,17 @@ class Span:
 
     __slots__ = ("_tracer", "name", "attrs", "tid", "depth",
                  "t0_ns", "dur_ns", "trace_id", "span_id",
-                 "parent_id")
+                 "parent_id", "_annotate", "_ann", "_discarded")
 
     def __init__(self, tracer: "Tracer", name: str,
-                 attrs: Optional[Dict[str, Any]]):
+                 attrs: Optional[Dict[str, Any]],
+                 annotate: bool = True):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
+        self._annotate = annotate
+        self._ann = None
+        self._discarded = False
         self.tid = 0
         self.depth = 0
         self.t0_ns = 0
@@ -134,26 +153,45 @@ class Span:
         self.attrs[key] = value
         return self
 
+    def discard(self) -> "Span":
+        """Leave no event when this span closes (a loop pass that
+        turned out to hold no work). Its annotation in a profiler
+        capture stays."""
+        self._discarded = True
+        return self
+
     def __enter__(self) -> "Span":
+        tracer = self._tracer
         self.tid = threading.get_ident()
-        self.depth = self._tracer._push()
+        if self.span_id is None:
+            self.span_id = _new_span_id()
+        # the enclosing open span of this thread is the parent, unless
+        # the span already rides a request trace's parent
+        self.depth, parent = tracer._push(self.span_id)
+        if self.parent_id is None:
+            self.parent_id = parent
+        if self._annotate and tracer._annotation is not None:
+            # the same interval in the profiler's own trace, on the
+            # device trace's clock (a no-op outside a capture)
+            self._ann = tracer._annotation("dl4j/" + self.name)
+            self._ann.__enter__()
         self.t0_ns = time.perf_counter_ns()
         # sinks (the flight recorder) learn about the span at OPEN so
-        # a bundle dumped mid-span can list it as unclosed; span ids
-        # are minted only when someone is listening or the span rides
-        # a request trace — the no-sink hot path stays id-free
-        if self._tracer._sinks or self.trace_id is not None:
-            if self.span_id is None:
-                self.span_id = _new_span_id()
-            self._tracer._notify_open(self)
+        # a bundle dumped mid-span can list it as unclosed
+        if tracer._sinks or self.trace_id is not None:
+            tracer._notify_open(self)
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.dur_ns = time.perf_counter_ns() - self.t0_ns
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         if exc_type is not None:
             self.set("error", exc_type.__name__)
         self._tracer._pop()
-        self._tracer._record(self)
+        if not self._discarded:
+            self._tracer._record(self)
         return False
 
 
@@ -166,9 +204,15 @@ class Tracer:
 
     def __init__(self, enabled: bool = False,
                  buffer_limit: int = 200_000):
-        self._enabled = enabled
+        self._enabled = False
         self.buffer_limit = buffer_limit
         self._lock = threading.Lock()
+        # jax.profiler.TraceAnnotation while enabled, else None
+        self._annotation = None
+        # one reading of both host clocks at enable():
+        # (perf_counter_ns, time_ns) — what maps an event's ``t_ns``
+        # onto Unix time, the base of a profile's ``profile_start_time``
+        self.clock_anchor: Optional[tuple] = None
         # ring, not list: request spans are recorded even while the
         # tracer is disabled (sampling gates them, not ``--trace``),
         # so a long-running server must evict OLDEST once full — an
@@ -192,6 +236,8 @@ class Tracer:
         # drain (``export_since``) resumes from, immune to ring
         # eviction (unlike buffer indices)
         self._seq = 0
+        if enabled:
+            self.enable()
 
     # ---- recording state ----
     @property
@@ -202,14 +248,44 @@ class Tracer:
         """Start recording; with ``jsonl_path`` every completed span
         is also appended to that file as one JSON line (crash-safe
         streaming — the in-memory buffer is still kept for
-        ``export_chrome_trace``)."""
+        ``export_chrome_trace``). Reads both host clocks once
+        (``clock_anchor``) and writes the clock-anchor annotation
+        into a running profiler capture."""
+        if self._annotation is None:
+            try:
+                from jax.profiler import TraceAnnotation
+                self._annotation = TraceAnnotation
+            except ImportError:     # spans still record, unannotated
+                pass
         with self._lock:
             if jsonl_path is not None:
                 if self._jsonl is not None:
                     self._jsonl.close()
                 self._jsonl = open(jsonl_path, "a")
+            self.clock_anchor = (time.perf_counter_ns(), time.time_ns())
             self._enabled = True
+        self.emit_clock_anchor()
         return self
+
+    def emit_clock_anchor(self) -> None:
+        """One ``dl4j/clock_anchor/<perf_counter_ns>`` annotation whose
+        name is the host clock's reading at its own start: in a
+        profiler capture, ``start_ns`` of that event minus the number
+        in its name is the offset from every event's ``t_ns`` to the
+        trace's clock. ``enable()`` writes one; a capture started
+        later needs another."""
+        if self._annotation is None:
+            return
+        # the name has to exist before the annotation starts (a
+        # TraceAnnotation starts when it is built), so the reading is
+        # agreed first and the start held until the clock shows it
+        # (50 us, once per call)
+        at = time.perf_counter_ns() + 50_000
+        name = f"dl4j/clock_anchor/{at}"
+        while time.perf_counter_ns() < at:
+            pass
+        with self._annotation(name):
+            time.perf_counter_ns()
 
     def disable(self) -> None:
         with self._lock:
@@ -227,13 +303,16 @@ class Tracer:
             self._seq = 0
 
     # ---- span API ----
-    def span(self, name: str, attrs: Optional[Dict[str, Any]] = None):
+    def span(self, name: str, attrs: Optional[Dict[str, Any]] = None,
+             annotate: bool = True):
         """Context manager timing a nested interval. MUST stay
         allocation-free when disabled — the fit loops call this every
-        iteration unconditionally."""
+        iteration unconditionally. ``annotate=False`` keeps a span
+        that only groups its children out of the profiler's trace,
+        where the widest event would name every device gap."""
         if not self._enabled:
             return _NOOP_SPAN
-        return Span(self, name, attrs)
+        return Span(self, name, attrs, annotate)
 
     def instant(self, name: str,
                 attrs: Optional[Dict[str, Any]] = None) -> None:
@@ -243,7 +322,9 @@ class Tracer:
             return
         s = Span(self, name, attrs)
         s.tid = threading.get_ident()
-        s.depth = getattr(self._tls, "depth", 0)
+        stack = getattr(self._tls, "stack", None)
+        if stack:
+            s.depth, s.parent_id = len(stack), stack[-1]
         s.t0_ns = time.perf_counter_ns()
         s.dur_ns = 0
         self._record(s)
@@ -290,13 +371,19 @@ class Tracer:
         return self._origin_ns
 
     # ---- per-thread nesting ----
-    def _push(self) -> int:
-        d = getattr(self._tls, "depth", 0)
-        self._tls.depth = d + 1
-        return d
+    def _push(self, span_id: str):
+        """Open a span on this thread: (its depth, its parent's id)."""
+        stack = getattr(self._tls, "stack", None)
+        if stack is None:
+            stack = self._tls.stack = []
+        parent = stack[-1] if stack else None
+        stack.append(span_id)
+        return len(stack) - 1, parent
 
     def _pop(self) -> None:
-        self._tls.depth = max(0, getattr(self._tls, "depth", 1) - 1)
+        stack = getattr(self._tls, "stack", None)
+        if stack:
+            stack.pop()
 
     # ---- storage ----
     def _span_ids(self, span: Span, ev: dict) -> None:
@@ -331,6 +418,7 @@ class Tracer:
         ev = {"name": span.name,
               "ts_us": (span.t0_ns - self._origin_ns) / 1e3,
               "dur_us": span.dur_ns / 1e3,
+              "t_ns": span.t0_ns,
               "tid": span.tid,
               "depth": span.depth}
         self._span_ids(span, ev)

@@ -44,7 +44,8 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from deeplearning4j_tpu import chaos
-from deeplearning4j_tpu.observability.tracing import RequestContext
+from deeplearning4j_tpu.observability.tracing import (RequestContext,
+                                                      trace)
 from deeplearning4j_tpu.serving import tiers
 from deeplearning4j_tpu.serving.errors import (KVLeaseError,
                                                KVPagePoolExhaustedError,
@@ -238,6 +239,8 @@ class ContinuousBatcher(ServingBackend):
         # version — a whole-request histogram can't show a
         # first-token stall inside an otherwise-fast stream
         self._stream = self.metrics.streaming(name, version)
+        # the worker loop's own view of a step (parts, slot-steps)
+        self._steps = self.metrics.batcher_steps(name)
         self.version = version
         # registry identity (the MODEL name, not the backend name):
         # exported leases carry it so an importing replica can
@@ -925,126 +928,173 @@ class ContinuousBatcher(ServingBackend):
                     self.session.prefix_cache.fingerprints(limit)}
 
     def _loop(self) -> None:
+        """One pass per device step. Its three parts are timed on
+        every step into ``serving_step_seconds`` (admit / device /
+        sample) and, while the tracer is on, recorded as
+        ``serve_step/<part>`` spans under one ``serve_step``; a pass
+        that found no live slot leaves neither."""
         while not self._stop.is_set():
-            self._service_migration()
-            have_active = any(s is not None and not s.parked
-                              for s in self._slots)
-            self._pump(block=not have_active and not self._pending)
-            self._expire_pending()
-            self._admit()
-            active = np.asarray([s is not None and not s.parked
-                                 for s in self._slots])
-            if not active.any():
-                if (self._draining.is_set() and self._queue.empty()
-                        and not self._pending
-                        and not any(s is not None
-                                    for s in self._slots)):
-                    # parked slots count: a drain must not complete
-                    # while an un-acked offer still owns pages
-                    self._drained.set()
-                continue
-            x = np.zeros((self.slots, 1, 1), np.float32)
-            for i, s in enumerate(self._slots):
-                if s is not None:
-                    x[i, 0, 0] = s.feed
-            # chaos site: crash kills the worker (active streams fail
-            # with the crash error, the loop restarts), hang stalls a
-            # step, poison NaNs this step's logits (each active
-            # stream then fails per-slot, never the worker)
-            try:
-                fault = chaos.step_fault("serving.worker.step")
-            except BaseException as e:
-                for i, s in enumerate(self._slots):
-                    if s is not None:
-                        self._endpoint.count_error()
-                        s.req.error = e
-                        s.req.event.set()
-                        self._release_slot(i)
-                raise
-            try:
-                h = np.asarray(self.session.step_slots(x, active))
-            except BaseException as e:
-                # a failed device step poisons every active stream —
-                # deliver the error, recycle the slots, and REBUILD
-                # the session carries: the jitted step donates them,
-                # so after a mid-call failure the old buffers may
-                # already be deleted and every later step would die
-                # with them
-                for i, s in enumerate(self._slots):
-                    if s is not None:
-                        self._endpoint.count_error()
-                        s.req.error = e
-                        s.req.event.set()
-                        self._release_slot(i)
+            if not self._pending and not any(
+                    s is not None and not s.parked for s in self._slots):
+                # idle: wait for a request outside any step, so that
+                # ``admit`` times work and never the wait
+                self._pump(block=True)
+            with trace.span("serve_step", annotate=False) as step:
+                t0 = time.perf_counter()
+                with trace.span("serve_step/admit") as admit:
+                    fed = self._gather_step()
+                    if fed is None:
+                        admit.discard()
+                        step.discard()
+                        continue
+                x, active = fed
+                # chaos site: crash kills the worker (active streams
+                # fail with the crash error, the loop restarts), hang
+                # stalls a step, poison NaNs this step's logits (each
+                # active stream then fails per-slot, never the worker)
                 try:
-                    self.session.reinit_states()
-                except BaseException:
-                    pass      # next step surfaces any persistent fault
-                continue
-            if fault is not None and fault.kind == "poison":
-                h = np.full_like(h, np.nan)
-            self._occupancy.record(int(active.sum()))
-            for i, s in enumerate(self._slots):
-                if s is None:
-                    continue
-                if s.prompt_left:
-                    # still prefilling: teacher-force the next prompt
-                    # token; this step's output is discarded
-                    s.feed = s.prompt_left.pop(0)
-                    if not s.prompt_left and s.req.prefill_export:
-                        # the export point: every prompt position
-                        # except the last is in the KV cache — the
-                        # decode replica re-feeds the last token and
-                        # samples, bit-identical to staying here
-                        self._finish_prefill_export(i, s)
-                    continue
-                try:
-                    nxt = self._sample(h[i, 0], s)
+                    fault = chaos.step_fault("serving.worker.step")
                 except BaseException as e:
-                    # per-slot host-side failure (e.g. NaN output
-                    # probabilities under temperature sampling) fails
-                    # only this request — never the worker
-                    self._endpoint.count_error()
-                    s.req.error = e
-                    s.req.event.set()
-                    self._release_slot(i)
-                    continue
-                s.out.append(nxt)
-                now_t = time.monotonic()
-                ctx = s.req.ctx
-                tid = (ctx.trace_id
-                       if ctx is not None and ctx.sampled else None)
-                if len(s.out) == 1:
-                    # first emitted token: prefill ends, decode
-                    # begins; TTFT measured from admission (what the
-                    # caller actually waited for a first token).
-                    # Prefix-hit streams (cache hits AND imported
-                    # leases) land in their own TTFT population so
-                    # the hit-vs-cold split is scrapeable.
-                    if ctx is not None:
-                        ctx.phase_done("prefill", now_in="decode")
-                    self._stream.record_ttft(
-                        now_t - s.req.t_submit, trace_id=tid,
-                        prefix_hit=s.prefix_hit > 0)
-                elif s.t_last_token is not None:
-                    self._stream.record_itl(
-                        now_t - s.t_last_token, trace_id=tid)
-                s.t_last_token = now_t
-                if len(s.out) >= s.req.n_tokens:
-                    s.req.result = np.asarray(s.out, np.int64)
-                    if ctx is not None:
-                        # decode segment closes BEFORE the event: the
-                        # waiter's respond stamp must come after
-                        ctx.phase_done(
-                            "decode", now_in="respond",
-                            attrs={"tokens": len(s.out)})
-                    s.req.event.set()
-                    # slot recycled next admit; a cleanly-finished
-                    # stream donates its full-prompt pages to the
-                    # prefix cache
-                    self._release_slot(i, register=True)
-                else:
-                    s.feed = nxt
+                    self._fail_active(e)
+                    raise
+                t1 = time.perf_counter()
+                with trace.span("serve_step/device"):
+                    try:
+                        h = np.asarray(
+                            self.session.step_slots(x, active))
+                    except BaseException as e:
+                        # a failed device step poisons every active
+                        # stream — deliver the error, recycle the
+                        # slots, and REBUILD the session carries: the
+                        # jitted step donates them, so after a
+                        # mid-call failure the old buffers may already
+                        # be deleted and every later step would die
+                        # with them
+                        self._fail_active(e)
+                        try:
+                            self.session.reinit_states()
+                        except BaseException:
+                            pass  # next step surfaces a persistent fault
+                        continue
+                t2 = time.perf_counter()
+                if fault is not None and fault.kind == "poison":
+                    h = np.full_like(h, np.nan)
+                n_active = int(active.sum())
+                self._occupancy.record(n_active)
+                with trace.span("serve_step/sample"):
+                    n_prompt, n_decode = self._consume_step(h)
+                self._steps.record(t1 - t0, t2 - t1,
+                                   time.perf_counter() - t2,
+                                   n_prompt, n_decode)
+                step.set("active", n_active)
+                step.set("prompt_slots", n_prompt)
+                step.set("decode_slots", n_decode)
+
+    def _fail_active(self, e: BaseException) -> None:
+        """Deliver ``e`` to every slotted stream and recycle the
+        slots."""
+        for i, s in enumerate(self._slots):
+            if s is not None:
+                self._endpoint.count_error()
+                s.req.error = e
+                s.req.event.set()
+                self._release_slot(i)
+
+    def _gather_step(self):
+        """Everything between two device steps: migration service,
+        queue pump, deadline expiry, admission, and the tokens each
+        slot feeds. ``(x, active)``, or None when no slot is live."""
+        self._service_migration()
+        self._pump(block=False)
+        self._expire_pending()
+        self._admit()
+        active = np.asarray([s is not None and not s.parked
+                             for s in self._slots])
+        if not active.any():
+            if (self._draining.is_set() and self._queue.empty()
+                    and not self._pending
+                    and not any(s is not None for s in self._slots)):
+                # parked slots count: a drain must not complete
+                # while an un-acked offer still owns pages
+                self._drained.set()
+            return None
+        x = np.zeros((self.slots, 1, 1), np.float32)
+        for i, s in enumerate(self._slots):
+            if s is not None:
+                x[i, 0, 0] = s.feed
+        return x, active
+
+    def _consume_step(self, h: np.ndarray):
+        """The host's turn after a device step: each live slot either
+        consumes its next prompt token (the step's output discarded)
+        or samples and emits one. Returns how many did which."""
+        n_prompt = n_decode = 0
+        for i, s in enumerate(self._slots):
+            if s is None or s.parked:
+                # a parked slot was not stepped: its stream must
+                # resume exactly where it was offered
+                continue
+            if s.prompt_left:
+                # still prefilling: teacher-force the next prompt
+                # token; this step's output is discarded
+                s.feed = s.prompt_left.pop(0)
+                n_prompt += 1
+                if not s.prompt_left and s.req.prefill_export:
+                    # the export point: every prompt position
+                    # except the last is in the KV cache — the
+                    # decode replica re-feeds the last token and
+                    # samples, bit-identical to staying here
+                    self._finish_prefill_export(i, s)
+                continue
+            try:
+                nxt = self._sample(h[i, 0], s)
+            except BaseException as e:
+                # per-slot host-side failure (e.g. NaN output
+                # probabilities under temperature sampling) fails
+                # only this request — never the worker
+                self._endpoint.count_error()
+                s.req.error = e
+                s.req.event.set()
+                self._release_slot(i)
+                continue
+            s.out.append(nxt)
+            n_decode += 1
+            now_t = time.monotonic()
+            ctx = s.req.ctx
+            tid = (ctx.trace_id
+                   if ctx is not None and ctx.sampled else None)
+            if len(s.out) == 1:
+                # first emitted token: prefill ends, decode
+                # begins; TTFT measured from admission (what the
+                # caller actually waited for a first token).
+                # Prefix-hit streams (cache hits AND imported
+                # leases) land in their own TTFT population so
+                # the hit-vs-cold split is scrapeable.
+                if ctx is not None:
+                    ctx.phase_done("prefill", now_in="decode")
+                self._stream.record_ttft(
+                    now_t - s.req.t_submit, trace_id=tid,
+                    prefix_hit=s.prefix_hit > 0)
+            elif s.t_last_token is not None:
+                self._stream.record_itl(
+                    now_t - s.t_last_token, trace_id=tid)
+            s.t_last_token = now_t
+            if len(s.out) >= s.req.n_tokens:
+                s.req.result = np.asarray(s.out, np.int64)
+                if ctx is not None:
+                    # decode segment closes BEFORE the event: the
+                    # waiter's respond stamp must come after
+                    ctx.phase_done(
+                        "decode", now_in="respond",
+                        attrs={"tokens": len(s.out)})
+                s.req.event.set()
+                # slot recycled next admit; a cleanly-finished
+                # stream donates its full-prompt pages to the
+                # prefix cache
+                self._release_slot(i, register=True)
+            else:
+                s.feed = nxt
+        return n_prompt, n_decode
 
     def slots_debug(self) -> List[dict]:
         """Per-slot state for ``/debug/slots``: what each KV-cache

@@ -31,7 +31,7 @@ from deeplearning4j_tpu.observability.registry import (
 )
 
 __all__ = ["LatencyHistogram", "EndpointMetrics", "BatchOccupancy",
-           "ServingMetrics"]
+           "BatcherStepMetrics", "ServingMetrics"]
 
 
 _EDGES = default_latency_buckets()    # seconds; +1 overflow at the end
@@ -228,6 +228,46 @@ class BatchOccupancy:
                 "max_batch_size": self.max_batch_size}
 
 
+class BatcherStepMetrics:
+    """One continuous-batcher step, seen from inside its loop:
+    ``serving_step_seconds{part}`` splits the step's wall time into
+    ``admit`` (migration service, queue pump, expiry, admission,
+    building the fed tokens), ``device`` (``step_slots`` through the
+    logits' arrival on the host) and ``sample`` (the per-slot loop:
+    sampling, bookkeeping, waking waiters);
+    ``serving_slot_steps_total{kind}`` counts what each live slot did
+    with the step: ``prompt`` (consumed a prompt token, output
+    discarded) or ``decode`` (emitted a token). The request-phase
+    histograms time a request from outside the steps that serve it;
+    these say what a step costs and what it was spent on."""
+
+    def __init__(self, registry: Optional[MetricsRegistry] = None,
+                 name: str = "generate"):
+        reg = registry or MetricsRegistry()
+        self._parts = {
+            part: reg.histogram(
+                "serving_step_seconds",
+                help="continuous-batcher step wall time by part "
+                     "(seconds)",
+                labels={"endpoint": name, "part": part},
+                buckets=_EDGES)
+            for part in ("admit", "device", "sample")}
+        self._kinds = {
+            kind: reg.counter(
+                "serving_slot_steps_total",
+                help="slot-steps by what the slot did with the step",
+                labels={"endpoint": name, "kind": kind})
+            for kind in ("prompt", "decode")}
+
+    def record(self, admit_s: float, device_s: float, sample_s: float,
+               prompt_slots: int, decode_slots: int) -> None:
+        self._parts["admit"].record(admit_s)
+        self._parts["device"].record(device_s)
+        self._parts["sample"].record(sample_s)
+        self._kinds["prompt"].inc(prompt_slots)
+        self._kinds["decode"].inc(decode_slots)
+
+
 class StreamingMetrics:
     """Token-streaming latency for one generate backend:
     time-to-first-token and inter-token latency, labeled by model
@@ -288,6 +328,7 @@ class ServingMetrics:
         self._endpoints: Dict[str, EndpointMetrics] = {}
         self._occupancy: Dict[str, BatchOccupancy] = {}
         self._streaming: Dict[tuple, StreamingMetrics] = {}
+        self._steps: Dict[str, BatcherStepMetrics] = {}
         self._gauges: Dict[str, Callable[[], float]] = {}
         self._iteration = 0
 
@@ -300,6 +341,13 @@ class ServingMetrics:
                     registry=self.registry, name=name,
                     version=str(version))
             return self._streaming[key]
+
+    def batcher_steps(self, name: str) -> BatcherStepMetrics:
+        with self._lock:
+            if name not in self._steps:
+                self._steps[name] = BatcherStepMetrics(
+                    registry=self.registry, name=name)
+            return self._steps[name]
 
     def latency_attribution(self) -> dict:
         """Tail-latency attribution: per endpoint, the whole-request
@@ -394,6 +442,7 @@ class ServingMetrics:
         with self._lock:
             self._endpoints.pop(name, None)
             self._occupancy.pop(name, None)
+            self._steps.pop(name, None)
             for key in [k for k in self._streaming if k[0] == name]:
                 self._streaming.pop(key, None)
         dropped = 0

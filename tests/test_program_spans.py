@@ -1,0 +1,462 @@
+"""Program spans on one clock (ISSUE 24): the tracer's raw clock,
+parents and profiler annotations; the spans of one ``fit`` iteration on
+both the k=1 and the fused path; the batcher loop's per-step parts and
+slot-step counts; and the layer names on the train step's ops.
+"""
+
+import os
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TRACING = os.path.join(REPO, "deeplearning4j_tpu", "observability",
+                       "tracing.py")
+
+
+def _mlp():
+    from deeplearning4j_tpu import (MultiLayerNetwork,
+                                    NeuralNetConfiguration)
+    from deeplearning4j_tpu.nn.conf import updaters
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.conf.layers import (DenseLayer,
+                                                   OutputLayer)
+    conf = (NeuralNetConfiguration.builder().set_seed(0)
+            .updater(updaters.adam(1e-2)).list()
+            .layer(DenseLayer(n_out=16, activation="relu"))
+            .layer(OutputLayer(n_out=3, loss="mcxent"))
+            .set_input_type(InputType.feed_forward(4)).build())
+    return MultiLayerNetwork(conf).init()
+
+
+def _graph():
+    from deeplearning4j_tpu import (ComputationGraph,
+                                    NeuralNetConfiguration)
+    from deeplearning4j_tpu.nn.conf import updaters
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.conf.layers import (DenseLayer,
+                                                   OutputLayer)
+    conf = (NeuralNetConfiguration.builder().set_seed(0)
+            .updater(updaters.adam(1e-2)).graph_builder()
+            .add_inputs("in")
+            .add_layer("hidden", DenseLayer(n_out=8, activation="relu"),
+                       "in")
+            .add_layer("out", OutputLayer(n_out=3, loss="mcxent"),
+                       "hidden")
+            .set_outputs("out")
+            .set_input_types(InputType.feed_forward(4)).build())
+    return ComputationGraph(conf).init()
+
+
+def _data(n=32):
+    from deeplearning4j_tpu.data.dataset import DataSet
+    rng = np.random.default_rng(0)
+    x = rng.normal(0, 1, (n, 4)).astype("float32")
+    y = np.eye(3, dtype="float32")[rng.integers(0, 3, n)]
+    return DataSet(x, y)
+
+
+def _leaves(net):
+    import jax
+    return [np.asarray(l) for l in jax.tree_util.tree_leaves(net.params)]
+
+
+@pytest.fixture
+def traced():
+    """The process-wide tracer, on for one test and empty before and
+    after."""
+    from deeplearning4j_tpu.observability.tracing import trace
+    trace.clear()
+    trace.enable()
+    try:
+        yield trace
+    finally:
+        trace.disable()
+        trace.clear()
+
+
+def _inside(child, parent):
+    c0, p0 = child["t_ns"], parent["t_ns"]
+    return (p0 <= c0 and c0 + child["dur_us"] * 1e3
+            <= p0 + parent["dur_us"] * 1e3 + 1)
+
+
+# ---------------------------------------------------------------------------
+# the tracer
+# ---------------------------------------------------------------------------
+
+class TestOneClock:
+    def test_disabled_tracer_does_not_import_jax(self):
+        """The module alone, in a fresh interpreter: a disabled tracer
+        hands out the shared no-op and never touches jax; ``enable()``
+        is what imports it."""
+        code = (
+            "import importlib.util, sys\n"
+            f"spec = importlib.util.spec_from_file_location('t', "
+            f"{TRACING!r})\n"
+            "m = importlib.util.module_from_spec(spec)\n"
+            "sys.modules['t'] = m\n"
+            "spec.loader.exec_module(m)\n"
+            "t = m.Tracer()\n"
+            "a, b = t.span('x'), t.span('y', annotate=False)\n"
+            "assert a is b\n"
+            "with a as s:\n"
+            "    s.set('k', 1).discard()\n"
+            "t.instant('i')\n"
+            "assert t.events() == [] and t.clock_anchor is None\n"
+            "assert 'jax' not in sys.modules, 'disabled tracer "
+            "imported jax'\n"
+            "t.enable()\n"
+            "assert 'jax' in sys.modules\n"
+            "print('ok')\n")
+        out = subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            timeout=120, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.strip() == "ok"
+
+    def test_events_carry_the_raw_clock_and_their_parent(self):
+        from deeplearning4j_tpu.observability.tracing import Tracer
+        before = (time.perf_counter_ns(), time.time_ns())
+        t = Tracer().enable()
+        perf, wall = t.clock_anchor
+        assert before[0] <= perf <= time.perf_counter_ns()
+        assert before[1] <= wall <= time.time_ns()
+        with t.span("outer", annotate=False):
+            lo = time.perf_counter_ns()
+            with t.span("inner", {"k": 1}):
+                pass
+            t.instant("mark")
+            hi = time.perf_counter_ns()
+        with t.span("next"):
+            pass
+        ev = {e["name"]: e for e in t.events()}
+        assert lo <= ev["inner"]["t_ns"] <= hi
+        assert ev["inner"]["parent_id"] == ev["outer"]["span_id"]
+        assert ev["mark"]["parent_id"] == ev["outer"]["span_id"]
+        assert "parent_id" not in ev["outer"]
+        assert "parent_id" not in ev["next"]       # the stack unwound
+        assert _inside(ev["inner"], ev["outer"])
+        # ts_us is the same instant, relative to the tracer's origin
+        assert ev["inner"]["ts_us"] == pytest.approx(
+            (ev["inner"]["t_ns"] - t.origin_ns) / 1e3)
+
+    def test_discarded_span_leaves_no_event(self):
+        from deeplearning4j_tpu.observability.tracing import Tracer
+        t = Tracer(enabled=True)
+        with t.span("pass") as outer:
+            with t.span("work"):
+                pass
+            outer.discard()
+        with t.span("kept"):
+            pass
+        ev = {e["name"]: e for e in t.events()}
+        assert set(ev) == {"work", "kept"}
+        assert "parent_id" not in ev["kept"]
+
+    def test_switched_on_and_off_inside_a_span(self):
+        """The benchmark switches the tracer from inside the iterator:
+        spans opened before the switch are the no-op, those closed
+        after it still unwind."""
+        from deeplearning4j_tpu.observability.tracing import Tracer
+        t = Tracer()
+        with t.span("step"):                # no-op: opened while off
+            t.enable()
+            with t.span("train_step"):
+                pass
+        with t.span("step"):
+            with t.span("data_wait"):
+                t.disable()
+            with t.span("train_step"):      # no-op again
+                pass
+        names = [e["name"] for e in t.events()]
+        assert names == ["train_step", "data_wait", "step"]
+        assert "parent_id" not in t.events()[0]
+
+
+# ---------------------------------------------------------------------------
+# one fit iteration's spans
+# ---------------------------------------------------------------------------
+
+def _by_id(events):
+    """Instants (a compile watch's ``xla_compile`` marks, when an
+    earlier test installed one) carry no id."""
+    return {e["span_id"]: e for e in events if "span_id" in e}
+
+
+def _by_iteration(events):
+    """{iteration: (step event, [its descendants])} for whole
+    iterations."""
+    by_id = _by_id(events)
+    out = {}
+    for e in events:
+        if e["name"] == "step" and "iteration" in (e.get("args") or {}):
+            out[e["args"]["iteration"]] = (e, [])
+    for e in by_id.values():
+        up = by_id.get(e.get("parent_id"))
+        while up is not None and up["name"] != "step":
+            up = by_id.get(up.get("parent_id"))
+        if up is not None and "iteration" in (up.get("args") or {}):
+            out[up["args"]["iteration"]][1].append(e)
+    return out
+
+
+class TestFitSpans:
+    @pytest.mark.parametrize("make", [_mlp, _graph],
+                             ids=["multilayer", "graph"])
+    def test_single_step_path(self, traced, make):
+        net = make()
+        ds = _data(16)
+        net.fit(ds, batch_size=8) if make is _mlp else net.fit(
+            [_data(8), _data(8)])
+        events = traced.events()
+        by_id = _by_id(events)
+        its = _by_iteration(events)
+        assert sorted(its) == [0, 1]
+        batch_bytes = 8 * 4 * 4 + 8 * 3 * 4
+        for i, (step, kids) in its.items():
+            assert step["args"]["samples"] == 8
+            names = sorted(e["name"] for e in kids)
+            assert names == sorted(
+                ["data_wait", "train_step", "batch_to_device",
+                 "enqueue", "h2d_wait", "listeners"]), names
+            ev = {e["name"]: e for e in kids}
+            for name in ("data_wait", "train_step", "h2d_wait",
+                         "listeners"):
+                assert ev[name]["parent_id"] == step["span_id"]
+            for name in ("batch_to_device", "enqueue"):
+                assert (ev[name]["parent_id"]
+                        == ev["train_step"]["span_id"])
+            for e in kids:
+                assert _inside(e, by_id[e["parent_id"]]), e["name"]
+            assert ev["batch_to_device"]["args"]["bytes"] == batch_bytes
+            # in the order the work happens
+            order = [ev[n]["t_ns"] for n in (
+                "data_wait", "batch_to_device", "enqueue", "h2d_wait",
+                "listeners")]
+            assert order == sorted(order)
+        # the pass that found the iterator empty is a step of its own
+        tail = [e for e in events if e["name"] == "step"
+                and (e.get("args") or {}).get("exhausted")]
+        assert len(tail) == 1
+        assert len([e for e in events if e["name"] == "step"]) == 3
+
+    def test_fused_path(self, traced):
+        """k=2: every batch still gets its ``step`` and ``data_wait``;
+        the window's spans hang under the step of its last batch."""
+        net = _mlp()
+        net.fit(_data(32), batch_size=8, steps_per_device_call=2)
+        events = traced.events()
+        by_id = _by_id(events)
+        its = _by_iteration(events)
+        assert sorted(its) == [0, 1, 2, 3]
+        for i in (0, 2):
+            assert [e["name"] for e in its[i][1]] == ["data_wait"]
+        for i in (1, 3):
+            step, kids = its[i]
+            ev = {e["name"]: e for e in kids}
+            assert sorted(ev) == sorted(
+                ["data_wait", "train_step_fused", "batch_to_device",
+                 "enqueue", "h2d_wait", "listeners"])
+            assert ev["train_step_fused"]["args"]["steps"] == 2
+            for name in ("batch_to_device", "enqueue"):
+                assert (ev[name]["parent_id"]
+                        == ev["train_step_fused"]["span_id"])
+            for e in kids:
+                assert _inside(e, by_id[e["parent_id"]]), e["name"]
+            # the stacked window: two batches
+            assert ev["batch_to_device"]["args"]["bytes"] == 2 * (
+                8 * 4 * 4 + 8 * 3 * 4)
+
+    @pytest.mark.parametrize("k", [1, 2])
+    def test_params_identical_with_the_tracer_on_and_off(self, k):
+        from deeplearning4j_tpu.observability.tracing import trace
+        off = _mlp()
+        off.fit(_data(32), batch_size=8, steps_per_device_call=k)
+        on = _mlp()
+        trace.clear()
+        trace.enable()
+        try:
+            on.fit(_data(32), batch_size=8, steps_per_device_call=k)
+        finally:
+            trace.disable()
+            trace.clear()
+        assert on.iteration_count == off.iteration_count == 4
+        for a, b in zip(_leaves(on), _leaves(off)):
+            assert a.tobytes() == b.tobytes()
+
+    def test_tracer_switched_inside_the_iterator(self):
+        """As the benchmark does it: on inside the second ``next``, off
+        inside the fourth. The second iteration arrives without a
+        ``step``, the third is whole, the fourth is cut short, nothing
+        raises."""
+        from deeplearning4j_tpu.observability.tracing import trace
+        from deeplearning4j_tpu.data.iterators import DataSetIterator
+
+        class Feed(DataSetIterator):
+            def reset(self):
+                pass
+
+            def _iterate(self):
+                for i in range(5):
+                    if i == 1:
+                        trace.enable()
+                    if i == 3:
+                        trace.disable()
+                    yield _data(8)
+
+        net = _mlp()
+        trace.clear()
+        try:
+            net.fit(Feed())
+            events = trace.events()
+        finally:
+            trace.disable()
+            trace.clear()
+        assert net.iteration_count == 5
+        its = _by_iteration(events)
+        # the fourth is cut short: its step was open at the switch
+        assert sorted(its) == [2, 3]
+        assert len(its[2][1]) == 6
+        assert [e["name"] for e in its[3][1]] == ["data_wait"]
+        waits = [e for e in events if e["name"] == "h2d_wait"]
+        assert len(waits) == 2              # iterations 1 and 2
+        assert sum("parent_id" in e for e in waits) == 1
+
+
+# ---------------------------------------------------------------------------
+# layer names on the train step's ops
+# ---------------------------------------------------------------------------
+
+class TestLayerScopes:
+    def _lowered(self, net, batch):
+        import jax
+        if net._jit_train_step is None:
+            net._jit_train_step = net._make_train_step()
+        return net._jit_train_step.lower(
+            net.params, net.state, net.opt_state, batch, net._rng_key,
+            np.int32(0)).as_text(debug_info=True)
+
+    def test_multilayer_ops_carry_layer_and_updater_names(self):
+        net = _mlp()
+        text = self._lowered(net, net._batch_tuple(_data(8)))
+        for scope in ("0_DenseLayer", "1_OutputLayer", "updater"):
+            assert scope in text, scope
+        # the backward pass keeps the layer's name
+        assert "transpose(jvp(0_DenseLayer))" in text
+
+    def test_graph_ops_carry_vertex_names(self):
+        net = _graph()
+        text = self._lowered(
+            net, net._batch_tuple(net._as_multi(_data(8))))
+        for scope in ("hidden", "out", "updater"):
+            assert f"/{scope}/" in text or f"({scope})" in text, scope
+
+
+# ---------------------------------------------------------------------------
+# the batcher loop
+# ---------------------------------------------------------------------------
+
+LM_V, LM_CAP = 13, 32
+
+
+def _lm():
+    from deeplearning4j_tpu import (MultiLayerNetwork,
+                                    NeuralNetConfiguration)
+    from deeplearning4j_tpu.nn.conf import updaters
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.conf.layers import (
+        EmbeddingSequenceLayer, RnnOutputLayer, TransformerEncoderLayer)
+    conf = (NeuralNetConfiguration.builder().set_seed(0)
+            .updater(updaters.adam(1e-3)).list()
+            .layer(EmbeddingSequenceLayer(n_in=LM_V, n_out=16))
+            .layer(TransformerEncoderLayer(n_heads=2, causal=True))
+            .layer(RnnOutputLayer(n_out=LM_V, loss="mcxent"))
+            .set_input_type(InputType.recurrent(LM_V, LM_CAP)).build())
+    return MultiLayerNetwork(conf).init()
+
+
+PROMPTS = [np.array([1, 2, 3]), np.array([4, 5]), np.array([6]),
+           np.array([7, 8, 9, 10]), np.array([2, 9]), np.array([3])]
+N_TOKENS = 5
+
+
+def _metric(snap, name, **labels):
+    for key, v in snap.items():
+        if key.startswith(name + "{") and all(
+                f'{k}="{val}"' in key for k, val in labels.items()):
+            return v
+    raise KeyError((name, labels))
+
+
+class TestBatcherSteps:
+    def _run(self):
+        from deeplearning4j_tpu.serving.continuous import (
+            ContinuousBatcher)
+        cb = ContinuousBatcher(_lm(), slots=2, capacity=LM_CAP,
+                               queue_limit=16)
+        handles = [cb.submit(p, N_TOKENS) for p in PROMPTS]
+        got = [cb.wait(h) for h in handles]
+        assert cb.drain()
+        assert all(len(g) == N_TOKENS for g in got)
+        return cb.metrics.registry.snapshot()
+
+    def test_slot_steps_and_parts(self):
+        """The loop's own rule: a request of P prompt tokens and N
+        emitted tokens takes P - 1 prompt slot-steps (each feeds one
+        prompt token and discards the output; the last prompt token's
+        step samples) and N decode slot-steps. No prompt here shares a
+        whole page with another, so none is skipped by a prefix hit."""
+        snap = self._run()
+        prompt = _metric(snap, "serving_slot_steps_total", kind="prompt")
+        decode = _metric(snap, "serving_slot_steps_total", kind="decode")
+        assert prompt == sum(len(p) - 1 for p in PROMPTS)
+        assert decode == N_TOKENS * len(PROMPTS)
+        batches = _metric(snap, "serving_batches_total")
+        items = _metric(snap, "serving_batch_items_total")
+        assert prompt + decode == items     # every live slot did one
+        parts = {p: _metric(snap, "serving_step_seconds", part=p)
+                 for p in ("admit", "device", "sample")}
+        for p, h in parts.items():
+            assert h["count"] == batches, p
+            assert h["sum"] > 0
+        assert prompt + decode <= 2 * batches
+
+    def test_serve_step_spans_when_the_tracer_is_on(self, traced):
+        snap = self._run()
+        events = [e for e in traced.events()
+                  if e["name"].startswith("serve_step")]
+        steps = [e for e in events if e["name"] == "serve_step"]
+        assert len(steps) == _metric(snap, "serving_batches_total")
+        by_parent = {}
+        for e in events:
+            if e["name"] != "serve_step":
+                by_parent.setdefault(e["parent_id"], []).append(e)
+        total = {"prompt_slots": 0, "decode_slots": 0, "active": 0}
+        for s in steps:
+            kids = by_parent[s["span_id"]]
+            assert [k["name"] for k in kids] == [
+                "serve_step/admit", "serve_step/device",
+                "serve_step/sample"]
+            assert all(_inside(k, s) for k in kids)
+            for key in total:
+                total[key] += s["args"][key]
+        assert total["prompt_slots"] == sum(len(p) - 1 for p in PROMPTS)
+        assert total["decode_slots"] == N_TOKENS * len(PROMPTS)
+        assert total["active"] == _metric(
+            snap, "serving_batch_items_total")
+
+    def test_evicting_the_endpoint_drops_the_step_series(self):
+        from deeplearning4j_tpu.serving.metrics import ServingMetrics
+        m = ServingMetrics()
+        m.batcher_steps("generate/lm/v1").record(0.1, 0.2, 0.3, 1, 2)
+        assert any(k.startswith("serving_step_seconds")
+                   for k in m.registry.snapshot())
+        m.evict_endpoint("generate/lm/v1")
+        left = [k for k in m.registry.snapshot()
+                if k.startswith(("serving_step_seconds",
+                                 "serving_slot_steps_total"))]
+        assert left == []
