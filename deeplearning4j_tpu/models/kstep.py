@@ -101,12 +101,18 @@ def _tree_nbytes(tree) -> int:
     return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree))
 
 
+# the k=1 lookahead has pulled nothing (None is an exhausted iterator)
+_UNPULLED = object()
+
+
 def _h2d_wait(trace, batch) -> None:
     """While tracing, and with the step already enqueued: wait until
     the batch's copy has landed, which is when the device can start
     the step. The span's end names what the device waited for; it
     does not delay the device, and the host would block in a
-    listener's score fetch next anyway. Off, nothing is called."""
+    listener's score fetch next anyway. A batch the k=1 lookahead
+    placed a step early has landed already, and the span is empty.
+    Off, nothing is called."""
     if trace.enabled:
         import jax
         with trace.span("h2d_wait"):
@@ -314,26 +320,39 @@ class KStepExecutorMixin:
         """One epoch's batch loop (shared by both executors' ``fit``):
         time the data wait, collect k-batch windows (k > 1), flush on
         tBPTT entries so step order is preserved, and flush the tail
-        at exhaustion. Epoch hooks stay with the caller."""
+        at exhaustion. Epoch hooks stay with the caller.
+
+        At k=1 the loop looks exactly ONE batch ahead: once step n is
+        enqueued, and before its listeners run, batch n+1 is pulled
+        and its placement started, so its copy runs while the device
+        computes step n and while the host blocks in a listener's
+        score fetch; the next pass only enqueues. The lookahead's
+        ``data_wait`` and ``batch_to_device`` spans hang under step
+        n's ``step``. It never crosses the epoch's end (an exhausted
+        iterator is not pulled again), never places a tBPTT batch (the
+        next pass gets it unplaced) and does not exist at k > 1.
+
+        Contract: when a listener raises out of ``iteration_done`` at
+        iteration n, ``iteration_count`` is n and the parameters are
+        those after step n, as before, and the iterator has been
+        advanced one batch PAST the step that raised. When the
+        iterator or the placement of batch n+1 raises, step n's
+        listeners have run and counted it first."""
         from deeplearning4j_tpu.observability.tracing import trace
         pending = []          # k-step window under collection
+        nxt = _UNPULLED       # what the k=1 lookahead pulled
         while True:
             # one iteration's spans hang under ``step`` (a group, so
             # not annotated into the profiler's trace); the tracer may
             # be switched inside the iterator, so an iteration can
             # arrive without one
             with trace.span("step", annotate=False) as step:
-                # data wait timed apart from the step so the profiler/
-                # tracer can tell an input-starved chip from a
-                # dispatch-bound host
-                t0 = time.perf_counter()
-                with trace.span("data_wait"):
-                    ds = next(data_iter, None)
-                if ds is None:
+                if nxt is _UNPULLED:
+                    nxt = self._pull_batch(data_iter, tbptt, ahead=False)
+                if nxt is None:
                     step.set("exhausted", True)
                     break
-                wait = time.perf_counter() - t0
-                m = self._coerce_fit_batch(ds)
+                (m, wait, batch), nxt = nxt, _UNPULLED
                 # the iteration this batch becomes (a window's batches
                 # wait in ``pending`` for its last)
                 step.set("iteration", self.iteration_count + len(pending))
@@ -346,33 +365,75 @@ class KStepExecutorMixin:
                         self._run_tbptt(m, tbptt, data_wait_s=wait)
                     continue
                 if k == 1:
-                    self._fit_one(m, wait)
+                    step.set("prefetched", batch is not None)
+                    self._enqueue_step(m, wait, batch)
+                    try:
+                        nxt = self._pull_batch(data_iter, tbptt,
+                                               ahead=True)
+                    finally:
+                        self._finish_step(m)
                     continue
                 pending.append((m, wait))
                 if len(pending) == k:
                     self._flush_window(pending, k)
         self._flush_window(pending, k)
 
+    def _pull_batch(self, data_iter, tbptt, ahead: bool):
+        """The iterator's next batch as ``(batch object, data wait
+        seconds, placed tuple or None)``, or None at exhaustion.
+        ``ahead`` (the k=1 lookahead, called while the step just
+        enqueued runs) also starts the batch's placement, unless it is
+        a tBPTT batch."""
+        from deeplearning4j_tpu.observability.tracing import trace
+        # data wait timed apart from the step so the profiler/tracer
+        # can tell an input-starved chip from a dispatch-bound host
+        t0 = time.perf_counter()
+        with trace.span("data_wait"):
+            ds = next(data_iter, None)
+        if ds is None:
+            return None
+        wait = time.perf_counter() - t0
+        m = self._coerce_fit_batch(ds)
+        placed = None
+        if ahead and not self._batch_is_tbptt(m, tbptt):
+            placed = self._place_batch(m, ahead=True)
+        return m, wait, placed
+
+    def _place_batch(self, ds, ahead: bool):
+        """Start the batch's host→device copy (``device_put`` returns
+        before it lands). ``ahead``: started before the previous
+        step's listeners ran."""
+        from deeplearning4j_tpu.observability.tracing import trace
+        with trace.span("batch_to_device") as sp:
+            if self._mesh_ctx is not None:
+                # shard from HOST arrays: host→mesh device_put is
+                # a plain per-shard copy, while resharding an
+                # already-committed device array onto a multi-axis
+                # mesh compiles a _multi_slice program per shape —
+                # a stray compile the warmed zero-compile steady
+                # state must not pay
+                batch = self._mesh_ctx.shard_batch(
+                    self._batch_tuple_np(ds))
+            else:
+                batch = self._batch_tuple(ds)
+            if trace.enabled:
+                sp.set("bytes", _tree_nbytes(batch)).set("ahead", ahead)
+        return batch
+
     def _fit_one(self, ds, data_wait_s: float = 0.0):
-        """One single-step device call + listener pass (the k=1 path,
-        byte-for-byte the pre-k-step fit-loop body)."""
+        """One single-step device call + listener pass (the k=1 path
+        of ``fit_batches`` and of a window's tail: no lookahead)."""
+        self._enqueue_step(ds, data_wait_s)
+        self._finish_step(ds)
+
+    def _enqueue_step(self, ds, data_wait_s: float, batch=None):
+        """Enqueue the k=1 program on ``ds``; ``batch`` is its placed
+        tuple when the lookahead placed it, else it is placed here."""
         from deeplearning4j_tpu.observability.tracing import trace
         t1 = time.perf_counter()
         with trace.span("train_step"):
-            with trace.span("batch_to_device") as sp:
-                if self._mesh_ctx is not None:
-                    # shard from HOST arrays: host→mesh device_put is
-                    # a plain per-shard copy, while resharding an
-                    # already-committed device array onto a multi-axis
-                    # mesh compiles a _multi_slice program per shape —
-                    # a stray compile the warmed zero-compile steady
-                    # state must not pay
-                    batch = self._mesh_ctx.shard_batch(
-                        self._batch_tuple_np(ds))
-                else:
-                    batch = self._batch_tuple(ds)
-                if trace.enabled:
-                    sp.set("bytes", _tree_nbytes(batch))
+            if batch is None:
+                batch = self._place_batch(ds, ahead=False)
             with trace.span("enqueue"):
                 out = self._step_fn_for(batch)(
                     self.params, self.state, self.opt_state, batch,
@@ -387,10 +448,14 @@ class KStepExecutorMixin:
         # (data_wait_s, dispatch_s) — ProfilerListener
         self._step_timing = (data_wait_s, time.perf_counter() - t1)
         _h2d_wait(trace, batch)
+
+    def _finish_step(self, ds):
+        """The enqueued step's listener pass; counts the iteration."""
+        from deeplearning4j_tpu.observability.tracing import trace
         with trace.span("listeners"):
             for lst in self.listeners:
-                lst.iteration_done(self, self.iteration_count, loss,
-                                   ds.num_examples())
+                lst.iteration_done(self, self.iteration_count,
+                                   self.score_value, ds.num_examples())
         self.iteration_count += 1
 
     def fit_batches(self, batches, *, steps_per_device_call=1):

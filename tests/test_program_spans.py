@@ -64,6 +64,64 @@ def _leaves(net):
     return [np.asarray(l) for l in jax.tree_util.tree_leaves(net.params)]
 
 
+def _batches(n, rows=8):
+    """n distinct batches."""
+    from deeplearning4j_tpu.data.dataset import DataSet
+    rng = np.random.default_rng(1)
+    return [DataSet(rng.normal(0, 1, (rows, 4)).astype("float32"),
+                    np.eye(3, dtype="float32")[rng.integers(0, 3, rows)])
+            for _ in range(n)]
+
+
+def _features(m):
+    """The (first) features array of a DataSet or MultiDataSet."""
+    f = m.features
+    return f[0] if isinstance(f, (list, tuple)) else f
+
+
+def _logged_fit(net, batches, **fit_kwargs):
+    """``fit`` over an iterator, a listener and the executor's
+    placement (``_batch_tuple``) that write what they do, in order,
+    into one list: ("iter",), ("pull", i or None at exhaustion),
+    ("place", i), ("listener", iteration), ("epoch_start",),
+    ("epoch_end",)."""
+    from deeplearning4j_tpu.data.iterators import DataSetIterator
+    from deeplearning4j_tpu.train.listeners import TrainingListener
+    log = []
+    index = {id(b.features): i for i, b in enumerate(batches)}
+
+    class Feed(DataSetIterator):
+        def reset(self):
+            log.append(("iter",))
+
+        def _iterate(self):
+            for i, ds in enumerate(batches):
+                log.append(("pull", i))
+                yield ds
+            log.append(("pull", None))
+
+    class Listener(TrainingListener):
+        def on_epoch_start(self, model):
+            log.append(("epoch_start",))
+
+        def on_epoch_end(self, model):
+            log.append(("epoch_end",))
+
+        def iteration_done(self, model, iteration, score, batch_size):
+            log.append(("listener", iteration))
+
+    place = net._batch_tuple
+
+    def logged_place(ds):
+        log.append(("place", index[id(_features(ds))]))
+        return place(ds)
+
+    net._batch_tuple = logged_place
+    net.set_listeners(Listener())
+    net.fit(Feed(), **fit_kwargs)
+    return log
+
+
 @pytest.fixture
 def traced():
     """The process-wide tracer, on for one test and empty before and
@@ -204,45 +262,147 @@ def _by_iteration(events):
     return out
 
 
+EXECUTORS = pytest.mark.parametrize("make", [_mlp, _graph],
+                                   ids=["multilayer", "graph"])
+
+
 class TestFitSpans:
-    @pytest.mark.parametrize("make", [_mlp, _graph],
-                             ids=["multilayer", "graph"])
+    @EXECUTORS
     def test_single_step_path(self, traced, make):
+        """k=1 looks one batch ahead: the pull and the placement of
+        batch n+1 hang under iteration n's ``step``, after its enqueue
+        and ``h2d_wait`` and before its listeners."""
         net = make()
-        ds = _data(16)
-        net.fit(ds, batch_size=8) if make is _mlp else net.fit(
-            [_data(8), _data(8)])
+        net.fit(_data(24), batch_size=8) if make is _mlp else net.fit(
+            [_data(8), _data(8), _data(8)])
         events = traced.events()
         by_id = _by_id(events)
         its = _by_iteration(events)
-        assert sorted(its) == [0, 1]
+        assert sorted(its) == [0, 1, 2]
         batch_bytes = 8 * 4 * 4 + 8 * 3 * 4
+        # the first iteration pulls and places its own batch; the
+        # last one's lookahead finds the iterator empty
+        want = {0: ["data_wait", "train_step", "batch_to_device",
+                    "enqueue", "h2d_wait", "data_wait",
+                    "batch_to_device", "listeners"],
+                1: ["train_step", "enqueue", "h2d_wait", "data_wait",
+                    "batch_to_device", "listeners"],
+                2: ["train_step", "enqueue", "h2d_wait", "data_wait",
+                    "listeners"]}
+        ahead = {0: [False, True], 1: [True], 2: []}
         for i, (step, kids) in its.items():
             assert step["args"]["samples"] == 8
-            names = sorted(e["name"] for e in kids)
-            assert names == sorted(
-                ["data_wait", "train_step", "batch_to_device",
-                 "enqueue", "h2d_wait", "listeners"]), names
-            ev = {e["name"]: e for e in kids}
-            for name in ("data_wait", "train_step", "h2d_wait",
-                         "listeners"):
-                assert ev[name]["parent_id"] == step["span_id"]
-            for name in ("batch_to_device", "enqueue"):
-                assert (ev[name]["parent_id"]
-                        == ev["train_step"]["span_id"])
+            assert step["args"]["prefetched"] is (i > 0)
             for e in kids:
                 assert _inside(e, by_id[e["parent_id"]]), e["name"]
-            assert ev["batch_to_device"]["args"]["bytes"] == batch_bytes
-            # in the order the work happens
-            order = [ev[n]["t_ns"] for n in (
-                "data_wait", "batch_to_device", "enqueue", "h2d_wait",
-                "listeners")]
-            assert order == sorted(order)
+            kids = sorted(kids, key=lambda e: e["t_ns"])
+            assert [e["name"] for e in kids] == want[i]
+            train_step = next(e for e in kids if e["name"] == "train_step")
+            for e in kids:
+                under_train_step = e["name"] == "enqueue" or (
+                    e["name"] == "batch_to_device"
+                    and not e["args"]["ahead"])
+                assert e["parent_id"] == (
+                    train_step["span_id"] if under_train_step
+                    else step["span_id"]), e["name"]
+            assert [e["args"] for e in kids
+                    if e["name"] == "batch_to_device"] == [
+                {"bytes": batch_bytes, "ahead": a} for a in ahead[i]]
         # the pass that found the iterator empty is a step of its own
         tail = [e for e in events if e["name"] == "step"
                 and (e.get("args") or {}).get("exhausted")]
         assert len(tail) == 1
-        assert len([e for e in events if e["name"] == "step"]) == 3
+        assert len([e for e in events if e["name"] == "step"]) == 4
+        # every batch crossed once
+        assert len([e for e in events
+                    if e["name"] == "batch_to_device"]) == 3
+
+    @EXECUTORS
+    def test_next_batch_is_placed_before_the_listener_runs(self, make):
+        log = _logged_fit(make(), _batches(3))
+        assert log == [
+            ("epoch_start",), ("iter",),
+            ("pull", 0), ("place", 0),
+            ("pull", 1), ("place", 1), ("listener", 0),
+            ("pull", 2), ("place", 2), ("listener", 1),
+            ("pull", None), ("listener", 2),
+            ("epoch_end",)]
+
+    @EXECUTORS
+    def test_iterator_is_pulled_at_most_one_ahead(self, make):
+        log = _logged_fit(make(), _batches(6))
+        pulled = 0
+        for what, *arg in log:
+            if what == "pull" and arg[0] is not None:
+                pulled += 1
+            elif what == "listener":
+                # iteration n has batches 0..n behind it
+                assert pulled <= arg[0] + 2
+        assert pulled == 6
+
+    @EXECUTORS
+    def test_no_pull_across_an_epoch_end(self, make):
+        log = _logged_fit(make(), _batches(2), epochs=2)
+        assert log == [
+            ("epoch_start",), ("iter",),
+            ("pull", 0), ("place", 0),
+            ("pull", 1), ("place", 1), ("listener", 0),
+            ("pull", None), ("listener", 1),
+            ("epoch_end",), ("epoch_start",), ("iter",),
+            ("pull", 0), ("place", 0),
+            ("pull", 1), ("place", 1), ("listener", 2),
+            ("pull", None), ("listener", 3),
+            ("epoch_end",)]
+
+    @EXECUTORS
+    def test_tbptt_batch_is_never_placed_ahead(self, traced, make):
+        """Batch 1 is tBPTT (stubbed: the loop only asks the executor's
+        adapter): the lookahead of step 0 pulls it and hands it on
+        unplaced, and the batch after it is pulled by its own pass."""
+        net = make()
+        batches = _batches(4)
+        chunked = []
+        net._batch_is_tbptt = lambda m, tbptt: (
+            _features(m) is batches[1].features)
+
+        def run_tbptt(m, tbptt, data_wait_s=0.0):
+            chunked.append(_features(m))
+            net.iteration_count += 1
+
+        net._run_tbptt = run_tbptt
+        log = _logged_fit(net, batches)
+        assert log == [
+            ("epoch_start",), ("iter",),
+            ("pull", 0), ("place", 0), ("pull", 1), ("listener", 0),
+            ("pull", 2), ("place", 2),
+            ("pull", 3), ("place", 3), ("listener", 2),
+            ("pull", None), ("listener", 3),
+            ("epoch_end",)]
+        assert len(chunked) == 1 and chunked[0] is batches[1].features
+        steps = {e["args"]["iteration"]: e["args"]
+                 for e in traced.events() if e["name"] == "step"
+                 and "iteration" in (e.get("args") or {})}
+        assert {i: a.get("prefetched") for i, a in steps.items()} == {
+            0: False, 1: None, 2: False, 3: True}
+
+    @EXECUTORS
+    def test_fused_window_is_never_placed_ahead(self, traced, make):
+        """k=2 over five batches: two windows and a tail through the
+        k=1 program, none pulled before the listeners of the one
+        before it, no ``prefetched`` and no ``ahead``."""
+        log = _logged_fit(make(), _batches(5), steps_per_device_call=2)
+        assert log == [
+            ("epoch_start",), ("iter",),
+            ("pull", 0), ("pull", 1), ("listener", 0), ("listener", 1),
+            ("pull", 2), ("pull", 3), ("listener", 2), ("listener", 3),
+            ("pull", 4), ("pull", None), ("place", 4), ("listener", 4),
+            ("epoch_end",)]
+        events = traced.events()
+        assert not any("prefetched" in (e.get("args") or {})
+                       for e in events if e["name"] == "step")
+        assert [e["args"]["ahead"] for e in events
+                if e["name"] == "batch_to_device"
+                and "ahead" in e["args"]] == [False]
 
     def test_fused_path(self, traced):
         """k=2: every batch still gets its ``step`` and ``data_wait``;
@@ -290,9 +450,11 @@ class TestFitSpans:
 
     def test_tracer_switched_inside_the_iterator(self):
         """As the benchmark does it: on inside the second ``next``, off
-        inside the fourth. The second iteration arrives without a
-        ``step``, the third is whole, the fourth is cut short, nothing
-        raises."""
+        inside the fourth, both of which the lookahead calls from the
+        iteration before. The first iteration's ``step`` was opened
+        with the tracer off (what runs after the switch hangs under
+        nothing), the second is whole, the third is cut short after
+        its lookahead's pull, nothing raises."""
         from deeplearning4j_tpu.observability.tracing import trace
         from deeplearning4j_tpu.data.iterators import DataSetIterator
 
@@ -318,13 +480,18 @@ class TestFitSpans:
             trace.clear()
         assert net.iteration_count == 5
         its = _by_iteration(events)
-        # the fourth is cut short: its step was open at the switch
-        assert sorted(its) == [2, 3]
-        assert len(its[2][1]) == 6
-        assert [e["name"] for e in its[3][1]] == ["data_wait"]
+        assert sorted(its) == [1, 2]
+        assert len(its[1][1]) == 6
+        assert its[1][0]["args"]["prefetched"] is True
+        assert [e["name"] for e in its[2][1]] == [
+            "enqueue", "train_step", "h2d_wait", "data_wait"]
         waits = [e for e in events if e["name"] == "h2d_wait"]
         assert len(waits) == 2              # iterations 1 and 2
-        assert sum("parent_id" in e for e in waits) == 1
+        assert all("parent_id" in e for e in waits)
+        # the placement of the second batch, under no step
+        placed = [e for e in events if e["name"] == "batch_to_device"]
+        assert [("parent_id" in e, e["args"]["ahead"])
+                for e in placed] == [(False, True), (True, True)]
 
 
 # ---------------------------------------------------------------------------
