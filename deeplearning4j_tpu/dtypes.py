@@ -20,7 +20,7 @@ from contextlib import contextmanager
 import jax.numpy as jnp
 
 __all__ = ["Policy", "policy", "set_policy", "default_policy",
-           "highest_precision", "promote_half"]
+           "highest_precision", "promote_half", "einsum_f32"]
 
 
 def promote_half(x):
@@ -31,6 +31,15 @@ def promote_half(x):
     if x.dtype in (jnp.bfloat16, jnp.float16):
         return x.astype(jnp.float32)
     return x
+
+
+def einsum_f32(subscripts, a, b):
+    """``einsum`` of two operands of one dtype, accumulated AND
+    returned in float32: where a half-precision product feeds a
+    softmax, a router or a loss, it is not rounded to half on the
+    way."""
+    return jnp.einsum(subscripts, a, b.astype(a.dtype),
+                      preferred_element_type=jnp.float32)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -62,6 +62,20 @@ def _pages_for(tokens: int, page_size: int) -> int:
     return -(-int(tokens) // int(page_size))
 
 
+def _params_dtype(net):
+    import jax
+    import jax.numpy as jnp
+    kinds = {leaf.dtype for leaf in jax.tree_util.tree_leaves(net.params)}
+    return kinds.pop() if len(kinds) == 1 else jnp.float32
+
+
+def _np_dtype(name: str) -> np.dtype:
+    """A pool leaf's dtype from its name in a lease header; the half
+    types numpy lacks (bfloat16) are jax's."""
+    import jax.numpy as jnp
+    return np.dtype(getattr(jnp, name, None) or name)
+
+
 # ---------------------------------------------------------------------------
 # prefix fingerprints — the router-side half of KV-aware routing
 # ---------------------------------------------------------------------------
@@ -410,7 +424,10 @@ class PagedSlotSession:
             # memory parity with the dense session by default: the
             # win then comes from reserving per-request actual need
             n_pages = self.slots * self.pages_per_slot
-        self._dtype = dtype or jnp.float32
+        # the cache rides the dtype the network's parameters were
+        # built in (the dtype policy's): float32 unless they are all
+        # of one half-precision type
+        self._dtype = dtype or _params_dtype(net)
         self.allocator = PagedKVAllocator(n_pages, self.page_size)
         self.prefix_cache = PrefixCache(self.allocator)
         self.slot_pos = np.zeros((self.slots,), np.int32)
@@ -420,6 +437,14 @@ class PagedSlotSession:
         self._pools = self._fresh_pools()
         self._step = None
         self._copy_page = None
+        # layers whose decode step returns counts beside its output
+        # (an expert layer's tokens per expert); none: the step and
+        # its program are what they were without this
+        self._aux_layers = [i for i, layer in enumerate(net.layers)
+                            if getattr(layer, "stream_aux", False)]
+        # the latest step's counts, (len(_aux_layers), ...) on the
+        # device, unfetched; None for a network that has none
+        self.step_aux = None
 
     # ---- pools ----
     def _fresh_pools(self):
@@ -665,7 +690,7 @@ class PagedSlotSession:
             import jax.numpy as jnp
             n_leaf_rows = sum(len(s) for s in schema
                               if s is not None)
-            row_bytes = [np.dtype(d["dtype"]).itemsize
+            row_bytes = [_np_dtype(d["dtype"]).itemsize
                          * int(np.prod(d["shape"]))
                          for s in schema if s is not None
                          for d in s]
@@ -683,7 +708,7 @@ class PagedSlotSession:
                 new_leaves = []
                 for leaf, spec in zip(leaves, schema[i]):
                     shape = tuple(spec["shape"])
-                    dtype = np.dtype(spec["dtype"])
+                    dtype = _np_dtype(spec["dtype"])
                     nb = dtype.itemsize * int(np.prod(shape))
                     for k in range(pages_written):
                         page = np.frombuffer(
@@ -721,22 +746,38 @@ class PagedSlotSession:
 
     def _make_step(self):
         import jax
+        import jax.numpy as jnp
         net = self.net
         layers = list(net.layers)
         preprocessors = dict(net.conf.preprocessors)
 
-        def step(params, layer_states, pools, table, pos, x):
+        aux_layers = set(self._aux_layers)
+
+        def step(params, layer_states, pools, table, pos, x,
+                 active=None):
             h = x
             new_pools = list(pools)
+            aux = []
             for i, layer in enumerate(layers):
                 if i in preprocessors:
                     h = preprocessors[i](h)
-                if hasattr(layer, "apply_stream_paged"):
-                    h, new_pools[i] = layer.apply_stream_paged(
-                        params[i], pools[i], table, pos, h)
-                else:
-                    h, _ = layer.apply(params[i], layer_states[i], h,
-                                       training=False)
+                # the layer's name on its device ops, as the
+                # executors' forward has it (metadata only)
+                with jax.named_scope(f"{i}_{type(layer).__name__}"):
+                    if i in aux_layers:
+                        h, new_pools[i], counts = \
+                            layer.apply_stream_paged_aux(
+                                params[i], pools[i], table, pos, h,
+                                active)
+                        aux.append(counts)
+                    elif hasattr(layer, "apply_stream_paged"):
+                        h, new_pools[i] = layer.apply_stream_paged(
+                            params[i], pools[i], table, pos, h)
+                    else:
+                        h, _ = layer.apply(params[i], layer_states[i],
+                                           h, training=False)
+            if aux:
+                return h, new_pools, jnp.stack(aux)
             return h, new_pools
 
         return jax.jit(step, donate_argnums=(2,))
@@ -765,9 +806,13 @@ class PagedSlotSession:
         # inactive slots step with pos 0 over their all-zero table
         # row: the write targets scratch, never a live page
         pos = np.where(active, self.slot_pos, 0).astype(np.int32)
-        h, self._pools = self._step(
-            self.net.params, self.net.state, self._pools,
-            jnp.asarray(self._table), jnp.asarray(pos), x)
+        args = (self.net.params, self.net.state, self._pools,
+                jnp.asarray(self._table), jnp.asarray(pos), x)
+        if self._aux_layers:
+            h, self._pools, self.step_aux = self._step(
+                *args, jnp.asarray(active))
+        else:
+            h, self._pools = self._step(*args)
         self.slot_pos = self.slot_pos + active.astype(
             self.slot_pos.dtype)
         return h

@@ -30,7 +30,7 @@ from deeplearning4j_tpu.nn.conf.layers.pooling import (
     SubsamplingLayer, Subsampling1DLayer, GlobalPoolingLayer, PoolingType,
 )
 from deeplearning4j_tpu.nn.conf.layers.normalization import (
-    BatchNormalization, LayerNormalization,
+    BatchNormalization, LayerNormalization, RMSNormalization,
     LocalResponseNormalization,
 )
 from deeplearning4j_tpu.nn.conf.layers.recurrent import (
@@ -42,6 +42,12 @@ from deeplearning4j_tpu.nn.conf.layers.special import (
 )
 from deeplearning4j_tpu.nn.conf.layers.attention import (
     SelfAttentionLayer, TransformerEncoderLayer,
+)
+from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
+    LatentAttentionLayer,
+)
+from deeplearning4j_tpu.nn.conf.layers.moe import (
+    SparseExpertsLayer, LatentDecoderBlock,
 )
 
 __all__ = [
@@ -56,10 +62,11 @@ __all__ = [
     "CroppingLayer", "SpaceToDepthLayer", "SpaceToBatchLayer",
     "SubsamplingLayer", "Subsampling1DLayer", "GlobalPoolingLayer",
     "PoolingType",
-    "BatchNormalization", "LayerNormalization",
+    "BatchNormalization", "LayerNormalization", "RMSNormalization",
     "LocalResponseNormalization",
     "LSTM", "GravesLSTM", "GravesBidirectionalLSTM", "Bidirectional",
     "SimpleRnn", "LastTimeStep", "RnnLossLayer",
     "FrozenLayer", "VariationalAutoencoder", "Yolo2OutputLayer",
     "SelfAttentionLayer", "TransformerEncoderLayer",
+    "LatentAttentionLayer", "SparseExpertsLayer", "LatentDecoderBlock",
 ]

@@ -1,0 +1,284 @@
+"""Multi-head latent attention (DeepSeek-V2, arXiv 2405.04434, §2.1).
+
+Queries come through a low-rank bottleneck; keys and values are
+rebuilt from ONE shared latent ``c_kv`` a token (``kv_lora_rank``
+wide) plus one rotary key ``k_r`` shared by all heads. The cache holds
+those two and nothing per head: ``kv_lora_rank + qk_rope_head_dim``
+values a token a layer, where multi-head attention holds
+``2 * heads * head_dim``.
+
+Two forms of the same attention:
+
+* ``apply`` (full sequence) rebuilds per-head keys and values from
+  the latent and attends over them with exact attention (einsum +
+  float32 softmax). It does NOT use the Pallas flash kernel: that
+  kernel takes q, k and v of one head size, and here q·k is
+  ``qk_nope + qk_rope`` wide and v ``v_head_dim``.
+* ``apply_stream_paged`` (decode over the paged latent pool) is the
+  ABSORBED form: ``W_kvb``'s key half is folded into the query and its
+  value half is applied after the weighted sum, so attention runs
+  over the cached latent itself and no per-head key or value is ever
+  materialised.
+
+Parameters, activations and cache ride the dtype the parameters were
+built in; products accumulate in float32 (the MXU's own), and scores,
+softmax and norm statistics are float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deeplearning4j_tpu import dtypes
+from deeplearning4j_tpu.dtypes import einsum_f32
+from deeplearning4j_tpu.nn.conf.inputs import InputType
+from deeplearning4j_tpu.nn.conf.layers.base import (BaseLayer,
+                                                    register_layer)
+from deeplearning4j_tpu.nn.conf.layers.normalization import rms_norm
+
+__all__ = ["LatentAttentionLayer", "yarn_inv_freq", "yarn_mscale"]
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, scaling: Optional[dict]):
+    """Rotary inverse frequencies of ``dim // 2`` pairs. With a
+    ``yarn`` scaling: extrapolated (``theta**(-2i/dim)``) and
+    interpolated (the same over ``factor``) frequencies blended per
+    dimension by the linear ramp between the dimensions that make
+    ``beta_fast`` and ``beta_slow`` rotations over the original
+    context (Peng et al. 2023, as the DeepSeek-V2 reference code)."""
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if not scaling:
+        return extra.astype(np.float32)
+    if scaling.get("type", "yarn") != "yarn":
+        raise ValueError(f"rope_scaling type {scaling.get('type')!r}: "
+                         "only 'yarn' is implemented")
+    factor = float(scaling["factor"])
+    orig = scaling["original_max_position_embeddings"]
+
+    def correction_dim(rotations):
+        return (dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(correction_dim(scaling.get("beta_slow", 1))),
+               dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(
+        np.float32)
+
+
+def _rope(x, positions, inv_freq, scale):
+    """Rotate the interleaved pairs ``(x[2i], x[2i+1])`` of the last
+    axis by ``positions * inv_freq[i]``. ``positions`` broadcasts
+    against ``x``'s leading axes. The result is laid out
+    ``[evens, odds]``; queries and keys go through here alike, so
+    their products do not see the order."""
+    ang = positions[..., None].astype(jnp.float32) * inv_freq
+    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., 0::2], xf[..., 1::2]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _mm(a, b):
+    """a @ b in a's dtype (the MXU accumulates in float32)."""
+    return a @ b.astype(a.dtype)
+
+
+@register_layer
+@dataclasses.dataclass
+class LatentAttentionLayer(BaseLayer):
+    """Causal multi-head latent attention, (B,T,C) -> (B,T,C). No
+    bias anywhere."""
+
+    n_in: Optional[int] = None
+    n_heads: int = 4
+    q_lora_rank: int = 24
+    kv_lora_rank: int = 16
+    qk_nope_head_dim: int = 8
+    qk_rope_head_dim: int = 4
+    v_head_dim: int = 8
+    rope_theta: float = 10000.0
+    # {"type": "yarn", "factor", "original_max_position_embeddings",
+    #  "beta_fast", "beta_slow", "mscale", "mscale_all_dim"} or None
+    rope_scaling: Optional[dict] = None
+    eps: float = 1e-6
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if self.n_in is None:
+            self.n_in = input_type.size
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType.recurrent(self.n_in or input_type.size,
+                                   input_type.timesteps)
+
+    def initialize(self, key, input_type: InputType):
+        self.set_n_in(input_type)
+        d, H = self.n_in, self.n_heads
+        rq, rkv = self.q_lora_rank, self.kv_lora_rank
+        dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                      self.v_head_dim)
+        if dr % 2:
+            raise ValueError("qk_rope_head_dim must be even, got "
+                             f"{dr}")
+        ks = jax.random.split(key, 5)
+        ones = lambda n: jnp.ones((n,), dtypes.policy().param_dtype)
+        w = lambda k, a, b: self._sample_w(k, (a, b), a, b)
+        return {"Wqa": w(ks[0], d, rq), "q_gain": ones(rq),
+                "Wqb": w(ks[1], rq, H * (dn + dr)),
+                "Wkva": w(ks[2], d, rkv + dr), "kv_gain": ones(rkv),
+                "Wkvb": w(ks[3], rkv, H * (dn + dv)),
+                "Wo": w(ks[4], H * dv, d)}, {}
+
+    # ---- pieces shared by both forms ----
+    def _softmax_scale(self):
+        s = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        sc = self.rope_scaling
+        if sc and sc.get("mscale_all_dim"):
+            m = yarn_mscale(sc["factor"], sc["mscale_all_dim"])
+            s *= m * m
+        return s
+
+    def _rope_tables(self):
+        sc = self.rope_scaling
+        inv = yarn_inv_freq(self.qk_rope_head_dim, self.rope_theta, sc)
+        scale = 1.0
+        if sc:
+            scale = (yarn_mscale(sc["factor"], sc.get("mscale", 1))
+                     / yarn_mscale(sc["factor"],
+                                   sc.get("mscale_all_dim", 0) or 0))
+        return jnp.asarray(inv), scale
+
+    def _project(self, params, x, positions):
+        """x (B,t,C) at ``positions`` (B,t) -> q_nope (B,t,H,dn),
+        q_rope (B,t,H,dr) rotated, c_kv (B,t,rkv) normed, k_r
+        (B,t,dr) rotated: the last two are what the cache holds."""
+        B, t, _ = x.shape
+        H, dn, dr = (self.n_heads, self.qk_nope_head_dim,
+                     self.qk_rope_head_dim)
+        x = x.astype(params["Wqa"].dtype)
+        inv, scale = self._rope_tables()
+        cq = rms_norm(_mm(x, params["Wqa"]), params["q_gain"], self.eps)
+        q = _mm(cq, params["Wqb"]).reshape(B, t, H, dn + dr)
+        q_rope = _rope(q[..., dn:], positions[:, :, None], inv, scale)
+        kv = _mm(x, params["Wkva"])
+        ckv = rms_norm(kv[..., :self.kv_lora_rank], params["kv_gain"],
+                       self.eps)
+        kr = _rope(kv[..., self.kv_lora_rank:], positions, inv, scale)
+        return q[..., :dn], q_rope, ckv, kr
+
+    def _kvb(self, params):
+        """W_kvb as (rkv, H, dn) for keys and (rkv, H, dv) for
+        values."""
+        w = params["Wkvb"].reshape(self.kv_lora_rank, self.n_heads,
+                                   self.qk_nope_head_dim
+                                   + self.v_head_dim)
+        return w[..., :self.qk_nope_head_dim], \
+            w[..., self.qk_nope_head_dim:]
+
+    def _attend(self, params, q_lat, q_rope, ckv, kr, q_pos):
+        """The absorbed attention: ``q_lat`` (B,t,H,rkv) and
+        ``q_rope`` (B,t,H,dr) over a latent history ``ckv`` (B,K,rkv),
+        ``kr`` (B,K,dr); key j is visible to query i iff
+        ``j <= q_pos[b, i]``. Returns (B,t,C)."""
+        from deeplearning4j_tpu.ops.attention import _NEG_INF
+        B, t = q_lat.shape[:2]
+        ckv, kr = ckv.astype(q_lat.dtype), kr.astype(q_lat.dtype)
+        s = (einsum_f32("bthr,bkr->bhtk", q_lat, ckv)
+             + einsum_f32("bthd,bkd->bhtk", q_rope, kr))
+        s = s * self._softmax_scale()
+        k_pos = jnp.arange(ckv.shape[1])[None, None, :]
+        s = jnp.where((k_pos <= q_pos[:, :, None])[:, None], s,
+                      _NEG_INF)
+        p = jax.nn.softmax(s, axis=-1).astype(q_lat.dtype)
+        o_lat = jnp.einsum("bhtk,bkr->bthr", p, ckv)
+        _, wv = self._kvb(params)
+        o = jnp.einsum("bthr,rhd->bthd", o_lat, wv)
+        return _mm(o.reshape(B, t, -1), params["Wo"])
+
+    def _absorb(self, params, q_nope):
+        wk, _ = self._kvb(params)
+        return jnp.einsum("bthd,rhd->bthr", q_nope, wk)
+
+    # ---- full sequence ----
+    def apply(self, params, state, x, *, training=False, rng=None,
+              mask=None):
+        """Exact causal attention over per-head keys and values
+        rebuilt from the latent (the published, unabsorbed form)."""
+        if mask is not None:
+            raise NotImplementedError(
+                "LatentAttentionLayer has no key-padding mask: feed "
+                "sequences of one length")
+        from deeplearning4j_tpu.ops.attention import _NEG_INF
+        x = self.apply_input_dropout(x, training=training, rng=rng)
+        B, T, _ = x.shape
+        pos = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
+        q_nope, q_rope, ckv, kr = self._project(params, x, pos)
+        wk, wv = self._kvb(params)
+        k_nope = jnp.einsum("bkr,rhd->bkhd", ckv, wk)
+        v = jnp.einsum("bkr,rhd->bkhd", ckv, wv)
+        s = (einsum_f32("bthd,bkhd->bhtk", q_nope, k_nope)
+             + einsum_f32("bthd,bkd->bhtk", q_rope, kr))
+        s = s * self._softmax_scale()
+        causal = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
+        s = jnp.where(causal[None, None], s, _NEG_INF)
+        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        o = jnp.einsum("bhtk,bkhd->bthd", p, v)
+        return _mm(o.reshape(B, T, -1), params["Wo"]), state
+
+    def apply_absorbed(self, params, x):
+        """The full sequence through the ABSORBED form (what decode
+        computes, without a cache): equal to ``apply`` up to
+        rounding, which a test holds."""
+        B, T, _ = x.shape
+        pos = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
+        q_nope, q_rope, ckv, kr = self._project(params, x, pos)
+        return self._attend(params, self._absorb(params, q_nope),
+                            q_rope, ckv, kr, pos)
+
+    # ---- paged latent cache ----
+    def zero_page_pool(self, n_pages: int, page_size: int, dtype):
+        """The physical pool of this layer: the normed latent and the
+        rotated shared key of every cached token, by page."""
+        return {"ckv": jnp.zeros((n_pages, page_size,
+                                  self.kv_lora_rank), dtype),
+                "kr": jnp.zeros((n_pages, page_size,
+                                 self.qk_rope_head_dim), dtype)}
+
+    def apply_stream_paged(self, params, pool, table, pos, x):
+        """One decode step for all slots over the paged latent pool
+        (the ``SelfAttentionLayer.apply_stream_paged`` contract:
+        ``x`` (S,t,C), ``table`` (S,P), ``pos`` (S,)): write each
+        slot's new latent and rotary key at its (page, offset), gather
+        each slot's virtual cache and attend in the absorbed form.
+        Returns (out, pool)."""
+        S, t, _ = x.shape
+        ps = pool["ckv"].shape[1]
+        wpos = pos[:, None] + jnp.arange(t)[None, :]        # (S, t)
+        q_nope, q_rope, ckv, kr = self._project(params, x, wpos)
+        page_ids = jnp.take_along_axis(table, wpos // ps, axis=1)
+        offs = wpos % ps
+        ckv_pool = pool["ckv"].at[page_ids, offs].set(
+            ckv.astype(pool["ckv"].dtype))
+        kr_pool = pool["kr"].at[page_ids, offs].set(
+            kr.astype(pool["kr"].dtype))
+        K = table.shape[1] * ps
+        out = self._attend(
+            params, self._absorb(params, q_nope), q_rope,
+            ckv_pool[table].reshape(S, K, -1),
+            kr_pool[table].reshape(S, K, -1), wpos)
+        return out, {"ckv": ckv_pool, "kr": kr_pool}

@@ -1,0 +1,288 @@
+"""Sparse experts, and the decoder block that carries them.
+
+``SparseExpertsLayer`` is the routed feed-forward of the DeepSeek-V2 /
+V3 line (arXiv 2405.04434 §2.2, 2412.19437 §2.1.2): a router scores
+every token against ALL ``n_routed_experts`` experts, the ``top_k``
+best are selected, and the token's output is the weighted sum of the
+selected experts' SiLU-gated MLPs plus a shared expert that every
+token passes through.
+
+The layer is told which experts it HOLDS (``held = (first, count)``):
+one chip's share of an expert-parallel group. The router keeps its
+full width and the normaliser runs over all selected experts, held or
+not; the layer computes the part of the sum that its own experts
+give. On one chip it runs without its exchange: what absent experts
+would add is left out, and nothing stands in for them. Summed over
+the shares of a group, with the shared expert counted once, the parts
+give the whole layer (tests/test_latent_moe.py holds that).
+
+``LatentDecoderBlock`` is the pre-RMSNorm residual block
+``h = x + MLA(norm(x)); y = h + F(norm(h))`` with ``F`` either a
+dense SiLU-gated MLP or the expert layer.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu import dtypes
+from deeplearning4j_tpu.dtypes import einsum_f32
+from deeplearning4j_tpu.nn.conf.inputs import InputType
+from deeplearning4j_tpu.nn.conf.layers.base import (BaseLayer,
+                                                    register_layer)
+from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
+    LatentAttentionLayer, _mm)
+from deeplearning4j_tpu.nn.conf.layers.normalization import rms_norm
+
+__all__ = ["SparseExpertsLayer", "LatentDecoderBlock", "swiglu"]
+
+_F32 = jnp.float32
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """``(silu(x W_gate) * x W_up) W_down``; float32 accumulation and
+    activation, operands and result in ``x``'s dtype."""
+    g = einsum_f32("...d,dw->...w", x, w_gate)
+    u = einsum_f32("...d,dw->...w", x, w_up)
+    return _mm((jax.nn.silu(g) * u).astype(x.dtype), w_down)
+
+
+@register_layer
+@dataclasses.dataclass
+class SparseExpertsLayer(BaseLayer):
+    """Routed + shared experts, (B,T,C) -> (B,T,C)."""
+
+    n_in: Optional[int] = None
+    n_routed_experts: int = 16          # the router's width
+    held: Optional[Tuple[int, int]] = None   # (first, count); None: all
+    top_k: int = 4
+    expert_width: int = 32
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+
+    seq_parallelizable = True           # per token
+
+    def __post_init__(self):
+        if self.held is not None:
+            self.held = (int(self.held[0]), int(self.held[1]))
+        first, count = self.held_range()
+        if not (0 <= first and count >= 1
+                and first + count <= self.n_routed_experts):
+            raise ValueError(
+                f"held {self.held} lies outside the router's "
+                f"{self.n_routed_experts} experts")
+
+    def held_range(self):
+        return self.held or (0, self.n_routed_experts)
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if self.n_in is None:
+            self.n_in = input_type.size
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType.recurrent(self.n_in or input_type.size,
+                                   input_type.timesteps)
+
+    def initialize(self, key, input_type: InputType):
+        self.set_n_in(input_type)
+        d, w = self.n_in, self.expert_width
+        n = self.held_range()[1]
+        ks = jax.random.split(key, 7)
+        p = {"Wr": self._sample_w(ks[0], (d, self.n_routed_experts), d,
+                                  self.n_routed_experts),
+             "Wg": self._sample_w(ks[1], (n, d, w), d, w),
+             "Wu": self._sample_w(ks[2], (n, d, w), d, w),
+             "Wd": self._sample_w(ks[3], (n, w, d), w, d)}
+        if self.n_shared_experts:
+            ws = w * self.n_shared_experts
+            p.update(Wsg=self._sample_w(ks[4], (d, ws), d, ws),
+                     Wsu=self._sample_w(ks[5], (d, ws), d, ws),
+                     Wsd=self._sample_w(ks[6], (ws, d), ws, d))
+        return p, {}
+
+    # ---- the router ----
+    def route(self, params, x):
+        """``x`` (N,C) -> (ids (N,k) int32, weights (N,k) float32):
+        the selected experts of every token over the router's whole
+        width, and their combine weights."""
+        with jax.named_scope("moe/router"):
+            scores = jax.nn.sigmoid(
+                einsum_f32("nd,de->ne", x, params["Wr"]))
+            w, ids = jax.lax.top_k(scores, self.top_k)
+            if self.norm_topk_prob:
+                w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+            return ids.astype(jnp.int32), w * self.routed_scaling_factor
+
+    # ---- the held experts' part ----
+    def apply_counted(self, params, x, active=None):
+        """(out, counts): ``counts`` (held,) int32, how many tokens
+        each held expert served. ``active`` (B,) marks the rows that
+        carry a token; the others reach no routed expert and are not
+        counted (a free slot of a decode batch)."""
+        shape = x.shape
+        x = x.reshape(-1, shape[-1]).astype(params["Wr"].dtype)
+        ids, w = self.route(params, x)
+        first, count = self.held_range()
+        with jax.named_scope("moe/experts"):
+            # combine weight of every (token, held expert): 0 unless
+            # selected. Every token goes through every held expert
+            # and the weight picks: at decode widths the experts'
+            # weights, not the rows, bound the time (PERF.md)
+            hit = (ids - first)[:, :, None] == jnp.arange(count)
+            if active is not None:                       # (N,k,held)
+                rows = jnp.repeat(active, x.shape[0] // active.shape[0])
+                hit = hit & rows[:, None, None]
+            comb = jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)
+            counts = jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
+            g = einsum_f32("nd,edw->enw", x, params["Wg"])
+            u = einsum_f32("nd,edw->enw", x, params["Wu"])
+            y = einsum_f32("enw,ewd->end",
+                           (jax.nn.silu(g) * u).astype(x.dtype),
+                           params["Wd"])
+            out = jnp.einsum("end,ne->nd", y, comb)
+        if self.n_shared_experts:
+            with jax.named_scope("moe/shared"):
+                out = out + swiglu(x, params["Wsg"], params["Wsu"],
+                                   params["Wsd"]).astype(_F32)
+        return out.astype(x.dtype).reshape(shape), counts
+
+    def apply(self, params, state, x, *, training=False, rng=None,
+              mask=None):
+        x = self.apply_input_dropout(x, training=training, rng=rng)
+        return self.apply_counted(params, x)[0], state
+
+
+@register_layer
+@dataclasses.dataclass
+class LatentDecoderBlock(BaseLayer):
+    """Pre-RMSNorm decoder block: latent attention, then a dense
+    SiLU-gated MLP (``n_routed_experts == 0``) or the expert layer.
+    The fields are the two sub-layers' own, flat, so that the block
+    round-trips through JSON like every DSL layer."""
+
+    n_in: Optional[int] = None
+    eps: float = 1e-6
+    # latent attention (LatentAttentionLayer)
+    n_heads: int = 4
+    q_lora_rank: int = 24
+    kv_lora_rank: int = 16
+    qk_nope_head_dim: int = 8
+    qk_rope_head_dim: int = 4
+    v_head_dim: int = 8
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[dict] = None
+    # dense MLP width (used when n_routed_experts == 0)
+    intermediate_size: int = 128
+    # expert layer (SparseExpertsLayer)
+    n_routed_experts: int = 0
+    held: Optional[Tuple[int, int]] = None
+    top_k: int = 4
+    expert_width: int = 32
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if self.n_in is None:
+            self.n_in = input_type.size
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType.recurrent(self.n_in or input_type.size,
+                                   input_type.timesteps)
+
+    @property
+    def stream_aux(self) -> bool:
+        """Does a decode step of this block return counts beside its
+        output (``apply_stream_paged_aux``)? The paged session asks."""
+        return self.n_routed_experts > 0
+
+    def _ensure_parts(self):
+        if not hasattr(self, "_attn"):
+            common = dict(n_in=self.n_in, weight_init=self.weight_init,
+                          weight_distribution=self.weight_distribution)
+            self._attn = LatentAttentionLayer(
+                n_heads=self.n_heads, q_lora_rank=self.q_lora_rank,
+                kv_lora_rank=self.kv_lora_rank,
+                qk_nope_head_dim=self.qk_nope_head_dim,
+                qk_rope_head_dim=self.qk_rope_head_dim,
+                v_head_dim=self.v_head_dim, rope_theta=self.rope_theta,
+                rope_scaling=self.rope_scaling, eps=self.eps, **common)
+            self._moe = None
+            if self.n_routed_experts:
+                self._moe = SparseExpertsLayer(
+                    n_routed_experts=self.n_routed_experts,
+                    held=self.held, top_k=self.top_k,
+                    expert_width=self.expert_width,
+                    n_shared_experts=self.n_shared_experts,
+                    routed_scaling_factor=self.routed_scaling_factor,
+                    norm_topk_prob=self.norm_topk_prob, **common)
+        return self._attn, self._moe
+
+    def initialize(self, key, input_type: InputType):
+        self.set_n_in(input_type)
+        attn, moe = self._ensure_parts()
+        ka, km, k1, k2, k3 = jax.random.split(key, 5)
+        d, ff = self.n_in, self.intermediate_size
+        pd = dtypes.policy().param_dtype
+        t = InputType.recurrent(d)
+        p = {"norm1_gain": jnp.ones((d,), pd),
+             "norm2_gain": jnp.ones((d,), pd),
+             "attn": attn.initialize(ka, t)[0]}
+        if moe is not None:
+            p["moe"] = moe.initialize(km, t)[0]
+        else:
+            p.update(Wg=self._sample_w(k1, (d, ff), d, ff),
+                     Wu=self._sample_w(k2, (d, ff), d, ff),
+                     Wd=self._sample_w(k3, (ff, d), ff, d))
+        return p, {}
+
+    def _ffn_half(self, params, h, active=None):
+        """(h + F(norm(h)), counts or None)."""
+        _, moe = self._ensure_parts()
+        z = rms_norm(h, params["norm2_gain"], self.eps)
+        if moe is None:
+            with jax.named_scope("mlp"):
+                return h + swiglu(z, params["Wg"], params["Wu"],
+                                  params["Wd"]), None
+        f, counts = moe.apply_counted(params["moe"], z, active)
+        return h + f, counts
+
+    def apply(self, params, state, x, *, training=False, rng=None,
+              mask=None):
+        attn, _ = self._ensure_parts()
+        x = x.astype(params["norm1_gain"].dtype)
+        with jax.named_scope("mla"):
+            a, _ = attn.apply(
+                params["attn"], {},
+                rms_norm(x, params["norm1_gain"], self.eps),
+                training=training, rng=rng, mask=mask)
+        return self._ffn_half(params, x + a)[0], state
+
+    # ---- paged decode ----
+    def zero_page_pool(self, n_pages: int, page_size: int, dtype):
+        return self._ensure_parts()[0].zero_page_pool(
+            n_pages, page_size, dtype)
+
+    def apply_stream_paged_aux(self, params, pool, table, pos, x,
+                               active=None):
+        """(out, pool, counts): one decode step through the block;
+        ``counts`` is None for a dense block, else the (held,) tokens
+        each held expert served among the ``active`` slots."""
+        attn, _ = self._ensure_parts()
+        x = x.astype(params["norm1_gain"].dtype)
+        with jax.named_scope("mla"):
+            a, pool = attn.apply_stream_paged(
+                params["attn"], pool, table, pos,
+                rms_norm(x, params["norm1_gain"], self.eps))
+        h, counts = self._ffn_half(params, x + a, active)
+        return h, pool, counts
+
+    def apply_stream_paged(self, params, pool, table, pos, x):
+        h, pool, _ = self.apply_stream_paged_aux(params, pool, table,
+                                                 pos, x)
+        return h, pool

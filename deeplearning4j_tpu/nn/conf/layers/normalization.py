@@ -27,7 +27,7 @@ from deeplearning4j_tpu.nn.conf.layers.base import (
 )
 
 __all__ = ["BatchNormalization", "LayerNormalization",
-           "LocalResponseNormalization"]
+           "RMSNormalization", "LocalResponseNormalization"]
 
 
 @register_layer
@@ -151,3 +151,42 @@ class LayerNormalization(Layer):
               mask=None):
         return layer_norm(x, params["gamma"], params["beta"],
                           self.eps), state
+
+
+def rms_norm(x, gain, eps=1e-6):
+    """Last-axis root-mean-square norm (Zhang & Sennrich 2019): no
+    mean, no bias. The statistic is float32 whatever ``x`` is (a
+    bfloat16 mean of squares loses the small rows); the result comes
+    back in ``x``'s dtype. Shared by RMSNormalization and the decoder
+    block's inlined norms."""
+    xf = jnp.asarray(x, jnp.float32)
+    inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                    + eps)
+    return (xf * inv * jnp.asarray(gain, jnp.float32)).astype(x.dtype)
+
+
+@register_layer
+@dataclasses.dataclass
+class RMSNormalization(Layer):
+    """``x / rms(x) * gain`` over the LAST axis: the norm of the
+    DeepSeek / Llama line of decoders, and the final norm before
+    their output head. Stateless and per token, like
+    LayerNormalization."""
+
+    n_in: Optional[int] = None
+    eps: float = 1e-6
+
+    seq_parallelizable = True
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if self.n_in is None:
+            self.n_in = input_type.size
+
+    def initialize(self, key, input_type: InputType):
+        self.set_n_in(input_type)
+        return {"gain": jnp.ones((self.n_in,),
+                                 dtypes.policy().param_dtype)}, {}
+
+    def apply(self, params, state, x, *, training=False, rng=None,
+              mask=None):
+        return rms_norm(x, params["gain"], self.eps), state

@@ -68,7 +68,14 @@ class OutputLayer(FeedForwardLayer):
         x = self.apply_input_dropout(x, training=training, rng=rng)
         if x.ndim > 2 and not isinstance(self, RnnOutputLayer):
             x = x.reshape(x.shape[0], -1)
-        z = x @ params["W"]
+        if x.dtype == params["W"].dtype and x.dtype in (
+                jnp.bfloat16, jnp.float16):
+            # a half-precision head (parameters AND activations):
+            # the logits leave the product in float32, not rounded
+            # to half and promoted afterwards
+            z = dtypes.einsum_f32("...d,dv->...v", x, params["W"])
+        else:
+            z = x @ params["W"]
         if self.has_bias:
             z = z + params["b"]
         return z

@@ -933,6 +933,7 @@ class ContinuousBatcher(ServingBackend):
         sample) and, while the tracer is on, recorded as
         ``serve_step/<part>`` spans under one ``serve_step``; a pass
         that found no live slot leaves neither."""
+        import jax
         while not self._stop.is_set():
             if not self._pending and not any(
                     s is not None and not s.parked for s in self._slots):
@@ -960,8 +961,14 @@ class ContinuousBatcher(ServingBackend):
                 t1 = time.perf_counter()
                 with trace.span("serve_step/device"):
                     try:
-                        h = np.asarray(
-                            self.session.step_slots(x, active))
+                        h = self.session.step_slots(x, active)
+                        aux = getattr(self.session, "step_aux", None)
+                        if aux is None:
+                            h = np.asarray(h)
+                        else:
+                            # an expert layer's counts come back
+                            # with the logits, in one transfer
+                            h, aux = jax.device_get((h, aux))
                     except BaseException as e:
                         # a failed device step poisons every active
                         # stream — deliver the error, recycle the
@@ -986,6 +993,8 @@ class ContinuousBatcher(ServingBackend):
                 self._steps.record(t1 - t0, t2 - t1,
                                    time.perf_counter() - t2,
                                    n_prompt, n_decode)
+                if aux is not None:
+                    self._steps.record_experts(aux)
                 step.set("active", n_active)
                 step.set("prompt_slots", n_prompt)
                 step.set("decode_slots", n_decode)

@@ -244,6 +244,7 @@ class BatcherStepMetrics:
     def __init__(self, registry: Optional[MetricsRegistry] = None,
                  name: str = "generate"):
         reg = registry or MetricsRegistry()
+        self._reg, self._name, self._experts = reg, name, None
         self._parts = {
             part: reg.histogram(
                 "serving_step_seconds",
@@ -266,6 +267,33 @@ class BatcherStepMetrics:
         self._parts["sample"].record(sample_s)
         self._kinds["prompt"].inc(prompt_slots)
         self._kinds["decode"].inc(decode_slots)
+
+    def record_experts(self, counts) -> None:
+        """One step's auxiliary counts of a network with expert
+        layers: ``counts[l, e]`` tokens served by held expert ``e``
+        of expert layer ``l``. ``serving_moe_local_pairs_total``
+        adds the (token, held expert) pairs computed here,
+        ``serving_moe_expert_hits_total`` the (layer, held expert)
+        that served at least one token this step, and
+        ``serving_moe_expert_slots_total`` the (layer, held expert)
+        there were: hits over slots is the share of the held
+        experts' weights a step had to read. The series exist only
+        for a backend whose network returns such counts."""
+        if self._experts is None:
+            self._experts = {
+                what: self._reg.counter(
+                    f"serving_moe_{what}_total", help=text,
+                    labels={"endpoint": self._name})
+                for what, text in (
+                    ("local_pairs", "(token, held expert) pairs "
+                                    "computed by this replica"),
+                    ("expert_hits", "(layer, held expert) that "
+                                    "served a token in a step"),
+                    ("expert_slots", "(layer, held expert) per "
+                                     "step"))}
+        self._experts["local_pairs"].inc(int(counts.sum()))
+        self._experts["expert_hits"].inc(int((counts > 0).sum()))
+        self._experts["expert_slots"].inc(int(counts.size))
 
 
 class StreamingMetrics:
