@@ -26,16 +26,22 @@ vLLM-style paged memory model over the same layer math:
   copy-on-write. Cache entries are LRU-evicted when the allocator
   runs dry.
 - **PagedSlotSession** — the continuous-batching substrate over page
-  tables: one jitted (slots, 1) decode step; each attention layer
-  writes new k/v into the slot's current page and attends over the
-  slot's GATHERED virtual cache (``apply_stream_paged``). With
+  tables: one jitted step at two widths. ``step_slots`` is the
+  (slots, 1) decode step; ``step_chunk`` is (slots, t), chunked
+  prefill: a slot feeds its next ``n_valid`` tokens, up to t of its
+  prompt or the one it decodes, in one call. Each attention layer
+  writes new k/v into the slot's pages and attends over the slot's
+  GATHERED virtual cache (``apply_stream_paged``). With
   ``pages_per_slot * page_size`` equal to the dense capacity the
   math is position-for-position identical to the dense path —
-  greedy-token parity is tested.
+  greedy-token parity is tested, and so is the chunk step against
+  the same tokens fed one by one.
 
-Page id 0 is a reserved scratch page: inactive slots' page-table rows
-are all-zero, so their dummy writes land in scratch and can never
-corrupt a live page. The allocator hands out ids ``1..n_pages``.
+Page id 0 is a reserved scratch page: a slot that sits a step out is
+given an all-zero page-table row, and the chunk step sends every row
+past a slot's ``n_valid`` there, so dummy writes land in scratch and
+can never corrupt a live page. The allocator hands out ids
+``1..n_pages``.
 """
 
 from __future__ import annotations
@@ -435,7 +441,25 @@ class PagedSlotSession:
                                np.int32)
         self._leases: Dict[int, _Lease] = {}
         self._pools = self._fresh_pools()
+        # one jitted step: ``step_slots`` runs it at (slots, 1, C),
+        # ``step_chunk`` at (slots, t, C), each shape its own program
         self._step = None
+        paged = [i for i, layer in enumerate(net.layers)
+                 if hasattr(layer, "apply_stream_paged")]
+        self._last_paged = paged[-1] if paged else -1
+        # May ``step_chunk`` stand in for token-by-token steps? Only
+        # where every layer without a cache is pointwise in time
+        # (``seq_parallelizable``) and no preprocessor reshapes a
+        # chunk on its way to a cache: a layer below the last cache
+        # sees t rows of a slot where a single step shows it one, and
+        # a layer above it is given a slot's last valid row alone,
+        # which is the whole chunk's output at that row only if rows
+        # do not mix.
+        self.chunkable = bool(paged) and not any(
+            i <= self._last_paged for i in net.conf.preprocessors
+        ) and all(hasattr(layer, "apply_stream_paged")
+                  or getattr(layer, "seq_parallelizable", False)
+                  for layer in net.layers)
         self._copy_page = None
         # layers whose decode step returns counts beside its output
         # (an expert layer's tokens per expert); none: the step and
@@ -753,8 +777,21 @@ class PagedSlotSession:
 
         aux_layers = set(self._aux_layers)
 
+        last_paged = self._last_paged
+
         def step(params, layer_states, pools, table, pos, x,
-                 active=None):
+                 active=None, n_valid=None):
+            # ``n_valid`` makes this the chunk program: x is
+            # (slots, t, C), a layer with a cache sends the rows past
+            # a slot's n_valid to the scratch page and an expert
+            # layer counts the valid rows; without it the program is
+            # the (slots, 1, C) one, op for op what it was
+            kw = {}
+            chunk = n_valid is not None
+            if chunk:
+                kw["n_valid"] = n_valid
+                active = (jnp.arange(x.shape[1])[None, :]
+                          < n_valid[:, None])
             h = x
             new_pools = list(pools)
             aux = []
@@ -768,14 +805,23 @@ class PagedSlotSession:
                         h, new_pools[i], counts = \
                             layer.apply_stream_paged_aux(
                                 params[i], pools[i], table, pos, h,
-                                active)
+                                active, **kw)
                         aux.append(counts)
                     elif hasattr(layer, "apply_stream_paged"):
                         h, new_pools[i] = layer.apply_stream_paged(
-                            params[i], pools[i], table, pos, h)
+                            params[i], pools[i], table, pos, h, **kw)
                     else:
                         h, _ = layer.apply(params[i], layer_states[i],
                                            h, training=False)
+                if chunk and i == last_paged:
+                    # no later layer holds a cache and each is
+                    # pointwise in time (``chunkable``), so only a
+                    # slot's last valid row is ever looked at: the
+                    # head, its softmax and the copy back stay
+                    # (slots, 1, V)
+                    h = jnp.take_along_axis(
+                        h, jnp.maximum(n_valid - 1, 0)[:, None, None],
+                        axis=1)
             if aux:
                 return h, new_pools, jnp.stack(aux)
             return h, new_pools
@@ -803,11 +849,14 @@ class PagedSlotSession:
                 "the session with a larger capacity")
         if self._step is None:
             self._step = self._make_step()
-        # inactive slots step with pos 0 over their all-zero table
-        # row: the write targets scratch, never a live page
+        # inactive slots step with pos 0 over an all-zero table row:
+        # the write targets scratch, never a live page (a slot that
+        # is bound but sits a step out, as a parked one does, would
+        # otherwise have its position 0 overwritten)
         pos = np.where(active, self.slot_pos, 0).astype(np.int32)
+        table = np.where(active[:, None], self._table, 0)
         args = (self.net.params, self.net.state, self._pools,
-                jnp.asarray(self._table), jnp.asarray(pos), x)
+                jnp.asarray(table), jnp.asarray(pos), x)
         if self._aux_layers:
             h, self._pools, self.step_aux = self._step(
                 *args, jnp.asarray(active))
@@ -815,6 +864,53 @@ class PagedSlotSession:
             h, self._pools = self._step(*args)
         self.slot_pos = self.slot_pos + active.astype(
             self.slot_pos.dtype)
+        return h
+
+    def step_chunk(self, x, n_valid):
+        """One device step that feeds slot ``s`` its next
+        ``n_valid[s]`` tokens, the rows ``x[s, :n_valid[s]]`` of a
+        (slots, t, C) ``x``: up to t prompt tokens for a slot in
+        prefill, 1 for a slot in decode, 0 for a free or parked slot
+        (nothing of it is touched). The same positions of the same
+        pages hold afterwards what ``n_valid[s]`` calls of
+        :meth:`step_slots` would have left, the rows past ``n_valid``
+        having gone to the scratch page, and ``slot_pos`` has advanced
+        by ``n_valid``. Returns (slots, 1, V): the output at each
+        slot's LAST valid row (row 0 of a slot that had none). One
+        program per ``t``, compiled when first called at that width."""
+        import jax.numpy as jnp
+        x = jnp.asarray(x)
+        n_valid = np.asarray(n_valid, np.int32)
+        if x.ndim != 3 or x.shape[0] != self.slots \
+                or n_valid.shape != (self.slots,):
+            raise ValueError(
+                f"x {x.shape} / n_valid {n_valid.shape}: want "
+                f"({self.slots}, t, C) and ({self.slots},)")
+        t = int(x.shape[1])
+        if n_valid.min() < 0 or n_valid.max() > t:
+            raise ValueError(f"n_valid must lie in [0, {t}]")
+        if not self.chunkable:
+            raise ValueError(
+                "this network has a layer that is not pointwise in "
+                "time beside its caches; feed it through step_slots")
+        live = n_valid > 0
+        if live.any() and int((self.slot_pos + n_valid)[live].max()) \
+                > self.capacity:
+            raise ValueError(
+                f"slot overflow: a chunk ends at pos "
+                f"{int((self.slot_pos + n_valid)[live].max())} with "
+                f"capacity {self.capacity}")
+        if self._step is None:
+            self._step = self._make_step()
+        pos = np.where(live, self.slot_pos, 0).astype(np.int32)
+        out = self._step(self.net.params, self.net.state, self._pools,
+                         jnp.asarray(self._table), jnp.asarray(pos), x,
+                         None, jnp.asarray(n_valid))
+        if self._aux_layers:
+            h, self._pools, self.step_aux = out
+        else:
+            h, self._pools = out
+        self.slot_pos = self.slot_pos + n_valid
         return h
 
     def reinit_states(self) -> None:

@@ -29,6 +29,32 @@ from deeplearning4j_tpu.nn.conf.layers.normalization import (
     layer_norm as _layer_norm)
 
 
+def paged_write_targets(table, pos, t, page_size, n_valid=None):
+    """Where a paged step writes the ``t`` rows of every slot:
+    ``(wpos, page_ids, offs)``, each (S, t). Row ``j`` of slot ``s``
+    is position ``pos[s] + j``, in page ``table[s, wpos // page_size]``
+    at offset ``wpos % page_size``.
+
+    ``n_valid`` (S,) is given by the chunk program, whose rows at or
+    past a slot's ``n_valid`` carry no token (a prompt's ragged tail,
+    the t - 1 spare rows of a slot in decode, every row of a free
+    slot): they go to the scratch page 0, which no slot reads unmasked.
+    Their own table lookup is not trusted: near capacity
+    ``wpos // page_size`` runs past the table's width, where a clamped
+    lookup would land in the slot's last live page."""
+    wpos = pos[:, None] + jnp.arange(t)[None, :]
+    if n_valid is None:
+        page_ids = jnp.take_along_axis(table, wpos // page_size, axis=1)
+    else:
+        page_ids = jnp.where(
+            jnp.arange(t)[None, :] < n_valid[:, None],
+            jnp.take_along_axis(
+                table, jnp.minimum(wpos // page_size,
+                                   table.shape[1] - 1), axis=1),
+            0)
+    return wpos, page_ids, wpos % page_size
+
+
 @register_layer
 @dataclasses.dataclass
 class SelfAttentionLayer(BaseLayer):
@@ -262,12 +288,15 @@ class SelfAttentionLayer(BaseLayer):
         page_size-token cache row."""
         return self.zero_stream_cache(n_pages, page_size, dtype)
 
-    def apply_stream_paged(self, params, pool, table, pos, x):
+    def apply_stream_paged(self, params, pool, table, pos, x,
+                           n_valid=None):
         """One jittable decode step over paged caches for ALL slots at
         once. ``x`` is the new (S, t, C) chunk (one row per slot),
         ``pool`` the physical {'k','v'} pages of shape
         (n_pages, page_size, H, Dh), ``table`` the (S, P) per-slot
-        page table, ``pos`` the (S,) per-slot token positions. Writes
+        page table, ``pos`` the (S,) per-slot token positions,
+        ``n_valid`` (S,) how many of a slot's t rows carry a token
+        (None: all of them; see :func:`paged_write_targets`). Writes
         each slot's new k/v at its (page, offset) — scatter indices
         are unique because written pages are slot-exclusive (shared
         prefix pages are read-only; divergence is copy-on-write at
@@ -285,9 +314,8 @@ class SelfAttentionLayer(BaseLayer):
         ps = pool["k"].shape[1]
         q, k, v = self._project_qkv(params, x)
         # write positions for the t new tokens of every slot
-        wpos = pos[:, None] + jnp.arange(t)[None, :]        # (S, t)
-        page_ids = jnp.take_along_axis(table, wpos // ps, axis=1)
-        offs = wpos % ps
+        wpos, page_ids, offs = paged_write_targets(table, pos, t, ps,
+                                                   n_valid)
         k_pool = pool["k"].at[page_ids, offs].set(
             k.astype(pool["k"].dtype))
         v_pool = pool["v"].at[page_ids, offs].set(
@@ -425,13 +453,14 @@ class TransformerEncoderLayer(BaseLayer):
         return self._ensure_attn().zero_page_pool(n_pages, page_size,
                                                   dtype)
 
-    def apply_stream_paged(self, params, pool, table, pos, x):
+    def apply_stream_paged(self, params, pool, table, pos, x,
+                           n_valid=None):
         """Paged-cache decode step through the pre-LN block (see
         SelfAttentionLayer.apply_stream_paged)."""
         self._ensure_attn()
         h = _layer_norm(x, params["ln1_g"], params["ln1_b"])
         a, pool = self._attn.apply_stream_paged(params["attn"], pool,
-                                                table, pos, h)
+                                                table, pos, h, n_valid)
         x = x + a
         return x + self._mlp_half(params, x), pool
 

@@ -259,19 +259,21 @@ class LatentAttentionLayer(BaseLayer):
                 "kr": jnp.zeros((n_pages, page_size,
                                  self.qk_rope_head_dim), dtype)}
 
-    def apply_stream_paged(self, params, pool, table, pos, x):
+    def apply_stream_paged(self, params, pool, table, pos, x,
+                           n_valid=None):
         """One decode step for all slots over the paged latent pool
         (the ``SelfAttentionLayer.apply_stream_paged`` contract:
-        ``x`` (S,t,C), ``table`` (S,P), ``pos`` (S,)): write each
-        slot's new latent and rotary key at its (page, offset), gather
-        each slot's virtual cache and attend in the absorbed form.
-        Returns (out, pool)."""
+        ``x`` (S,t,C), ``table`` (S,P), ``pos`` (S,), ``n_valid``
+        (S,) or None): write each slot's new latent and rotary key at
+        its (page, offset), gather each slot's virtual cache and
+        attend in the absorbed form. Returns (out, pool)."""
+        from deeplearning4j_tpu.nn.conf.layers.attention import (
+            paged_write_targets)
         S, t, _ = x.shape
         ps = pool["ckv"].shape[1]
-        wpos = pos[:, None] + jnp.arange(t)[None, :]        # (S, t)
+        wpos, page_ids, offs = paged_write_targets(table, pos, t, ps,
+                                                   n_valid)
         q_nope, q_rope, ckv, kr = self._project(params, x, wpos)
-        page_ids = jnp.take_along_axis(table, wpos // ps, axis=1)
-        offs = wpos % ps
         ckv_pool = pool["ckv"].at[page_ids, offs].set(
             ckv.astype(pool["ckv"].dtype))
         kr_pool = pool["kr"].at[page_ids, offs].set(

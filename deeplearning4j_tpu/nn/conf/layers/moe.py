@@ -121,9 +121,10 @@ class SparseExpertsLayer(BaseLayer):
     # ---- the held experts' part ----
     def apply_counted(self, params, x, active=None):
         """(out, counts): ``counts`` (held,) int32, how many tokens
-        each held expert served. ``active`` (B,) marks the rows that
-        carry a token; the others reach no routed expert and are not
-        counted (a free slot of a decode batch)."""
+        each held expert served. ``active`` marks the rows that carry
+        a token, (B,) for whole sequences or (B,T) row by row (the
+        chunk program's ragged rows); the others reach no routed
+        expert and are not counted (a free slot of a decode batch)."""
         shape = x.shape
         x = x.reshape(-1, shape[-1]).astype(params["Wr"].dtype)
         ids, w = self.route(params, x)
@@ -135,7 +136,8 @@ class SparseExpertsLayer(BaseLayer):
             # weights, not the rows, bound the time (PERF.md)
             hit = (ids - first)[:, :, None] == jnp.arange(count)
             if active is not None:                       # (N,k,held)
-                rows = jnp.repeat(active, x.shape[0] // active.shape[0])
+                rows = jnp.repeat(active.reshape(-1),
+                                  x.shape[0] // active.size)
                 hit = hit & rows[:, None, None]
             comb = jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)
             counts = jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
@@ -269,20 +271,23 @@ class LatentDecoderBlock(BaseLayer):
             n_pages, page_size, dtype)
 
     def apply_stream_paged_aux(self, params, pool, table, pos, x,
-                               active=None):
+                               active=None, n_valid=None):
         """(out, pool, counts): one decode step through the block;
         ``counts`` is None for a dense block, else the (held,) tokens
-        each held expert served among the ``active`` slots."""
+        each held expert served among the ``active`` slots, or rows
+        where the chunk program gives a (slots, t) mask beside its
+        ``n_valid``."""
         attn, _ = self._ensure_parts()
         x = x.astype(params["norm1_gain"].dtype)
         with jax.named_scope("mla"):
             a, pool = attn.apply_stream_paged(
                 params["attn"], pool, table, pos,
-                rms_norm(x, params["norm1_gain"], self.eps))
+                rms_norm(x, params["norm1_gain"], self.eps), n_valid)
         h, counts = self._ffn_half(params, x + a, active)
         return h, pool, counts
 
-    def apply_stream_paged(self, params, pool, table, pos, x):
-        h, pool, _ = self.apply_stream_paged_aux(params, pool, table,
-                                                 pos, x)
+    def apply_stream_paged(self, params, pool, table, pos, x,
+                           n_valid=None):
+        h, pool, _ = self.apply_stream_paged_aux(
+            params, pool, table, pos, x, n_valid=n_valid)
         return h, pool

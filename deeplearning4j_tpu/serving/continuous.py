@@ -6,10 +6,17 @@ whole batch before admitting new work leaves device slots idle exactly
 when traffic is heaviest. This module does iteration-level scheduling
 (the Orca/vLLM idea, here over ``models/streaming.py``'s
 SlotStreamingSession): a fixed pool of KV-cache slots steps together
-— every step is the SAME (slots, 1, 1) compiled executable — and
-between steps finished slots are recycled to queued requests. Prompt
-prefill rides the decode steps token-by-token (teacher-forced), so
-admission never changes the compiled shape.
+and between steps finished slots are recycled to queued requests.
+Prompt prefill rides the same steps teacher-forced, so admission
+never compiles anything: a step is one of two programs, whoever is in
+the pool. Over a paged session a step that finds a slot with prompt
+tokens to spare is (slots, t, 1), CHUNKED prefill: that slot feeds
+its next ``min(t, tokens left)`` prompt tokens, a slot in decode its
+one token, and the chunk that carries a prompt's last token emits the
+request's first output token; a step whose slots all decode is
+(slots, 1, 1). ``t`` follows from the pool (``chunk_width``). Over the
+dense session every step is (slots, 1, 1) and a prompt takes a step a
+token.
 
 Admission control mirrors the scheduler (the shared
 ``serving/lifecycle.py`` plumbing): bounded queue with
@@ -56,6 +63,27 @@ from deeplearning4j_tpu.serving.lifecycle import (BaseRequest,
 from deeplearning4j_tpu.serving.metrics import ServingMetrics
 
 __all__ = ["ContinuousBatcher", "MigrationOffer"]
+
+# Rows of one chunked-prefill step, slots x t. The program's shape is
+# (slots, t) whoever is in prefill, so every such step pays for
+# slots * t rows through every matmul and every slot's attention: the
+# budget is in rows, and the pool's width sets t. Read on the chip at
+# 128 / 256 / 512 in both serving cells of the benchmark (PERF.md
+# section 6, PR 27): 8 slots serve the same at 128 and 256 and less at
+# 512; 64 slots, where nearly every step has some slot in prefill,
+# gain at 128 and lose at 256 and 512.
+CHUNK_ROWS = 128
+
+
+def chunk_width(slots: int, capacity: int) -> int:
+    """Prompt tokens a slot in prefill may feed a device step: the
+    largest power of two with ``slots * t <= CHUNK_ROWS`` (16 at 8
+    slots, 2 at 64), no wider than a slot itself. At 1 prefill is
+    token by token."""
+    t = 1
+    while slots * t * 2 <= CHUNK_ROWS and t * 2 <= capacity:
+        t *= 2
+    return t
 
 
 def _migrate_chaos(blob: bytes) -> bytes:
@@ -241,6 +269,13 @@ class ContinuousBatcher(ServingBackend):
         self._stream = self.metrics.streaming(name, version)
         # the worker loop's own view of a step (parts, slot-steps)
         self._steps = self.metrics.batcher_steps(name)
+        # chunked prefill: the width of the second step program, 1
+        # where the session has no chunk entry point (the dense one,
+        # a network whose layers mix rows)
+        self._chunk_t = (chunk_width(slots, capacity)
+                         if getattr(self.session, "chunkable", False)
+                         else 1)
+        self._warmed = False
         self.version = version
         # registry identity (the MODEL name, not the backend name):
         # exported leases carry it so an importing replica can
@@ -693,9 +728,10 @@ class ContinuousBatcher(ServingBackend):
                 self.session.reset_slot(free[0])
             if r.ctx is not None:
                 # slotted: queue_wait ends, prefill begins (prompt
-                # tokens ride the decode steps teacher-forced; a
-                # prefix-cache hit resumes AFTER the cached tokens —
-                # the ledger records how many were skipped)
+                # tokens ride the pool's steps teacher-forced, up to
+                # ``_chunk_t`` of them a step; a prefix-cache hit
+                # resumes AFTER the cached tokens — the ledger
+                # records how many were skipped)
                 attrs = {"slot": free[0]}
                 if resume:
                     attrs["prefix_hit_tokens"] = resume
@@ -948,7 +984,8 @@ class ContinuousBatcher(ServingBackend):
                         admit.discard()
                         step.discard()
                         continue
-                x, active = fed
+                x, n_valid = fed
+                chunk = x.shape[1] > 1
                 # chaos site: crash kills the worker (active streams
                 # fail with the crash error, the loop restarts), hang
                 # stalls a step, poison NaNs this step's logits (each
@@ -961,7 +998,10 @@ class ContinuousBatcher(ServingBackend):
                 t1 = time.perf_counter()
                 with trace.span("serve_step/device"):
                     try:
-                        h = self.session.step_slots(x, active)
+                        if chunk:
+                            h = self.session.step_chunk(x, n_valid)
+                        else:
+                            h = self.session.step_slots(x, n_valid > 0)
                         aux = getattr(self.session, "step_aux", None)
                         if aux is None:
                             h = np.asarray(h)
@@ -986,18 +1026,48 @@ class ContinuousBatcher(ServingBackend):
                 t2 = time.perf_counter()
                 if fault is not None and fault.kind == "poison":
                     h = np.full_like(h, np.nan)
-                n_active = int(active.sum())
+                n_active = int((n_valid > 0).sum())
                 self._occupancy.record(n_active)
                 with trace.span("serve_step/sample"):
-                    n_prompt, n_decode = self._consume_step(h)
+                    n_prompt, n_decode, prompt_tokens = \
+                        self._consume_step(h, n_valid)
                 self._steps.record(t1 - t0, t2 - t1,
                                    time.perf_counter() - t2,
-                                   n_prompt, n_decode)
+                                   n_prompt, n_decode,
+                                   "chunk" if chunk else "single",
+                                   prompt_tokens)
                 if aux is not None:
                     self._steps.record_experts(aux)
                 step.set("active", n_active)
                 step.set("prompt_slots", n_prompt)
                 step.set("decode_slots", n_decode)
+                step.set("rows", int(x.shape[1]))
+                step.set("prompt_tokens", prompt_tokens)
+
+    def _warm_programs(self) -> None:
+        """Compile (or load) both widths of the paged step ahead of
+        the first step that feeds a token, on a batch whose slots all
+        sit the step out (nothing but the scratch page is written):
+        which program a step runs depends on who is in the pool, and
+        neither may compile under live traffic. A batcher without the
+        chunk program compiles its one step at the first request, as
+        before."""
+        self._warmed = True
+        if self._chunk_t == 1:
+            return
+        x = np.zeros((self.slots, self._chunk_t, 1), np.float32)
+        idle = np.zeros((self.slots,), np.int32)
+        try:
+            self.session.step_chunk(x, idle)
+            self.session.step_slots(x[:, :1], idle > 0)
+        except BaseException:
+            # the step donates the pools: rebuild them, and let the
+            # first real step surface a persistent fault to its
+            # requests
+            try:
+                self.session.reinit_states()
+            except BaseException:
+                pass
 
     def _fail_active(self, e: BaseException) -> None:
         """Deliver ``e`` to every slotted stream and recycle the
@@ -1012,14 +1082,19 @@ class ContinuousBatcher(ServingBackend):
     def _gather_step(self):
         """Everything between two device steps: migration service,
         queue pump, deadline expiry, admission, and the tokens each
-        slot feeds. ``(x, active)``, or None when no slot is live."""
+        slot feeds. ``(x, n_valid)``, or None when no slot is live:
+        ``x`` is (slots, rows, 1) and slot ``i`` feeds its first
+        ``n_valid[i]`` rows (0: free or parked). ``rows`` is the chunk
+        width when some live slot has prompt tokens beyond its
+        ``feed``, else 1: a pool that only decodes runs the
+        single-token program."""
         self._service_migration()
         self._pump(block=False)
         self._expire_pending()
         self._admit()
-        active = np.asarray([s is not None and not s.parked
-                             for s in self._slots])
-        if not active.any():
+        live = [(i, s) for i, s in enumerate(self._slots)
+                if s is not None and not s.parked]
+        if not live:
             if (self._draining.is_set() and self._queue.empty()
                     and not self._pending
                     and not any(s is not None for s in self._slots)):
@@ -1027,26 +1102,43 @@ class ContinuousBatcher(ServingBackend):
                 # while an un-acked offer still owns pages
                 self._drained.set()
             return None
-        x = np.zeros((self.slots, 1, 1), np.float32)
-        for i, s in enumerate(self._slots):
-            if s is not None:
-                x[i, 0, 0] = s.feed
-        return x, active
+        if not self._warmed:
+            self._warm_programs()
+        rows = self._chunk_t if any(s.prompt_left
+                                    for _, s in live) else 1
+        x = np.zeros((self.slots, rows, 1), np.float32)
+        n_valid = np.zeros((self.slots,), np.int32)
+        for i, s in live:
+            # a prefill-only request stops one token short: its
+            # export point is every prompt position but the last
+            n = min(rows, 1 + len(s.prompt_left)
+                    - int(s.req.prefill_export))
+            x[i, 0, 0] = s.feed
+            x[i, 1:n, 0] = s.prompt_left[:n - 1]
+            n_valid[i] = n
+        return x, n_valid
 
-    def _consume_step(self, h: np.ndarray):
-        """The host's turn after a device step: each live slot either
-        consumes its next prompt token (the step's output discarded)
-        or samples and emits one. Returns how many did which."""
-        n_prompt = n_decode = 0
+    def _consume_step(self, h: np.ndarray, n_valid: np.ndarray):
+        """The host's turn after a device step: a live slot whose
+        fed rows ended inside its prompt drops them (the step's
+        output discarded); one whose rows carried the prompt's last
+        token, or its last sampled token, samples from its row of
+        ``h`` and emits. Returns how many slots did which, and how
+        many prompt tokens the step fed."""
+        n_prompt = n_decode = prompt_tokens = 0
         for i, s in enumerate(self._slots):
-            if s is None or s.parked:
+            n = int(n_valid[i])
+            if s is None or n == 0:
                 # a parked slot was not stepped: its stream must
                 # resume exactly where it was offered
                 continue
-            if s.prompt_left:
-                # still prefilling: teacher-force the next prompt
-                # token; this step's output is discarded
-                s.feed = s.prompt_left.pop(0)
+            if not s.out:
+                prompt_tokens += n
+            if n <= len(s.prompt_left):
+                # still prefilling: the next step starts at the
+                # first prompt token this one did not feed
+                s.feed = s.prompt_left[n - 1]
+                del s.prompt_left[:n]
                 n_prompt += 1
                 if not s.prompt_left and s.req.prefill_export:
                     # the export point: every prompt position
@@ -1055,6 +1147,7 @@ class ContinuousBatcher(ServingBackend):
                     # samples, bit-identical to staying here
                     self._finish_prefill_export(i, s)
                 continue
+            s.prompt_left = []
             try:
                 nxt = self._sample(h[i, 0], s)
             except BaseException as e:
@@ -1103,7 +1196,7 @@ class ContinuousBatcher(ServingBackend):
                 self._release_slot(i, register=True)
             else:
                 s.feed = nxt
-        return n_prompt, n_decode
+        return n_prompt, n_decode, prompt_tokens
 
     def slots_debug(self) -> List[dict]:
         """Per-slot state for ``/debug/slots``: what each KV-cache

@@ -236,10 +236,15 @@ class BatcherStepMetrics:
     logits' arrival on the host) and ``sample`` (the per-slot loop:
     sampling, bookkeeping, waking waiters);
     ``serving_slot_steps_total{kind}`` counts what each live slot did
-    with the step: ``prompt`` (consumed a prompt token, output
-    discarded) or ``decode`` (emitted a token). The request-phase
-    histograms time a request from outside the steps that serve it;
-    these say what a step costs and what it was spent on."""
+    with the step: ``prompt`` (fed prompt tokens only, output
+    discarded) or ``decode`` (emitted a token: a chunk that carried
+    its prompt's last token counts here);
+    ``serving_steps_total{program}`` counts the steps by the program
+    they ran, ``single`` (slots, 1) or ``chunk`` (slots, t), and
+    ``serving_prompt_tokens_total`` the prompt tokens they fed to the
+    device. The request-phase histograms time a request from outside
+    the steps that serve it; these say what a step costs and what it
+    was spent on."""
 
     def __init__(self, registry: Optional[MetricsRegistry] = None,
                  name: str = "generate"):
@@ -259,14 +264,27 @@ class BatcherStepMetrics:
                 help="slot-steps by what the slot did with the step",
                 labels={"endpoint": name, "kind": kind})
             for kind in ("prompt", "decode")}
+        self._programs = {
+            program: reg.counter(
+                "serving_steps_total",
+                help="device steps by the step program they ran",
+                labels={"endpoint": name, "program": program})
+            for program in ("single", "chunk")}
+        self._prompt_tokens = reg.counter(
+            "serving_prompt_tokens_total",
+            help="prompt tokens fed to the device",
+            labels={"endpoint": name})
 
     def record(self, admit_s: float, device_s: float, sample_s: float,
-               prompt_slots: int, decode_slots: int) -> None:
+               prompt_slots: int, decode_slots: int,
+               program: str = "single", prompt_tokens: int = 0) -> None:
         self._parts["admit"].record(admit_s)
         self._parts["device"].record(device_s)
         self._parts["sample"].record(sample_s)
         self._kinds["prompt"].inc(prompt_slots)
         self._kinds["decode"].inc(decode_slots)
+        self._programs[program].inc()
+        self._prompt_tokens.inc(prompt_tokens)
 
     def record_experts(self, counts) -> None:
         """One step's auxiliary counts of a network with expert

@@ -1,0 +1,153 @@
+"""The chunk step of a paged session against the same tokens fed one
+by one: what ``tests/test_decode_paged.py`` (key/value pool) and
+``tests/test_latent_moe.py`` (latent pool, expert counts) run as cases
+of one parametrised test.
+
+Two sessions over one network get the same leases; ``feed_both`` gives
+``chunked`` one ``step_chunk`` call and ``single`` the same tokens
+through ``step_slots``, one call a token, and holds the first to the
+second: the written positions and each slot's last valid row to float
+tolerance, every other position of every leased page bit for bit to
+what it held before the call (the rows past ``n_valid`` may alter
+nothing that lives), ``slot_pos``, and an expert layer's counts to the
+one-by-one counts summed."""
+
+import jax
+import numpy as np
+
+CASES = ("ragged", "prefix_resume", "near_capacity")
+
+SLOTS, CAPACITY, PAGE, T = 4, 32, 4, 8
+
+
+def sessions(net):
+    return tuple(net.paged_slot_streaming_session(
+        capacity=CAPACITY, slots=SLOTS, page_size=PAGE)
+        for _ in range(2))
+
+
+def pages_of(sess, pages):
+    """Per pool leaf, the contents of ``pages``: (len(pages), page
+    size, ...) arrays."""
+    return [np.asarray(leaf)[np.asarray(pages)]
+            for pool in sess._pools if pool is not None
+            for leaf in jax.tree_util.tree_leaves(pool)]
+
+
+def live_rows(sess, slot):
+    """The slot's leased pages as its virtual cache: per pool leaf a
+    (leased positions, ...) array, in table order."""
+    return [rows.reshape((-1,) + rows.shape[2:])
+            for rows in pages_of(sess, sess._leases[slot].pages)]
+
+
+def feed_single(sess, tokens):
+    """``tokens``: {slot: [ids]} through ``step_slots``, a call a
+    token. Returns ({slot: output at its last token}, counts summed
+    or None)."""
+    last, counts = {}, None
+    for j in range(max(len(v) for v in tokens.values())):
+        x = np.zeros((sess.slots, 1, 1), np.float32)
+        active = np.zeros((sess.slots,), bool)
+        for slot, ids in tokens.items():
+            if j < len(ids):
+                x[slot, 0, 0], active[slot] = ids[j], True
+        h = np.asarray(sess.step_slots(x, active))
+        for slot in np.flatnonzero(active):
+            last[int(slot)] = h[slot, 0]
+        if sess.step_aux is not None:
+            aux = np.asarray(sess.step_aux)
+            counts = aux if counts is None else counts + aux
+    return last, counts
+
+
+def feed_both(chunked, single, tokens, atol=1e-5):
+    """One ``step_chunk`` of width T on ``chunked``, the same tokens
+    one by one on ``single``, and every comparison the module's text
+    names. Returns the chunk's (slots, 1, V) output."""
+    x = np.zeros((SLOTS, T, 1), np.float32)
+    n_valid = np.zeros((SLOTS,), np.int32)
+    for slot, ids in tokens.items():
+        x[slot, :len(ids), 0], n_valid[slot] = ids, len(ids)
+    before = {slot: live_rows(chunked, slot)
+              for slot in chunked._leases}
+    pos0 = chunked.slot_pos.copy()
+    h = np.asarray(chunked.step_chunk(x, n_valid))
+    assert h.shape[:2] == (SLOTS, 1)
+    last, counts = feed_single(single, tokens)
+    np.testing.assert_array_equal(chunked.slot_pos, single.slot_pos)
+    np.testing.assert_array_equal(chunked.slot_pos, pos0 + n_valid)
+    for slot, ids in tokens.items():
+        np.testing.assert_allclose(h[slot, 0], last[slot], atol=atol)
+    for slot, was in before.items():
+        lo, hi = int(pos0[slot]), int(pos0[slot] + n_valid[slot])
+        now, want = live_rows(chunked, slot), live_rows(single, slot)
+        for a, b, w in zip(now, was, want):
+            written = np.zeros((a.shape[0],), bool)
+            written[lo:hi] = True
+            np.testing.assert_array_equal(a[~written], b[~written])
+            # every position a later step may read
+            np.testing.assert_allclose(a[:hi], w[:hi], atol=atol)
+    if counts is not None:
+        np.testing.assert_array_equal(np.asarray(chunked.step_aux),
+                                      counts)
+    return h
+
+
+def run_case(net, vocab, case):
+    rng = np.random.default_rng(sum(map(ord, case)))
+    ids = lambda n: [int(v) for v in rng.integers(1, vocab, n)]
+    chunked, single = sessions(net)
+
+    def bind(slot, prompt, n_tokens):
+        for s in (chunked, single):
+            s.bind(slot, s.reserve(prompt, n_tokens))
+
+    if case == "ragged":
+        # a slot with T rows, one with fewer, a decode slot with 1, a
+        # free slot with 0, in one call
+        long, short, old = ids(11), ids(3), ids(5)
+        bind(0, long, 4)
+        bind(1, short, 4)
+        bind(3, old, 4)
+        for s in (chunked, single):
+            feed_single(s, {3: old})
+        feed_both(chunked, single,
+                  {0: long[:T], 1: short, 3: ids(1)})
+        # and again: the long prompt's ragged tail beside two decodes
+        feed_both(chunked, single,
+                  {0: long[T:], 1: ids(1), 3: ids(1)})
+    elif case == "prefix_resume":
+        # a slot that starts at a prefix hit: its first two pages are
+        # the cache's too, and stay as they were
+        first = ids(11)
+        bind(0, first, 2)
+        for s in (chunked, single):
+            feed_single(s, {0: first})
+            s.release(0, register_prompt=first)
+        again = first[:2 * PAGE] + ids(5)
+        bind(2, again, 4)
+        lease = chunked._leases[2]
+        assert lease.resume_pos == 2 * PAGE
+        shared = lease.pages[:2]
+        assert all(chunked.allocator.refcount(p) > 1 for p in shared)
+        was = pages_of(chunked, shared)
+        feed_both(chunked, single, {2: again[2 * PAGE:]})
+        for a, b in zip(pages_of(chunked, shared), was):
+            np.testing.assert_array_equal(a, b)
+        assert all(chunked.allocator.refcount(p) > 1 for p in shared)
+    elif case == "near_capacity":
+        # a slot whose table is full to its width, within T tokens of
+        # capacity: the rows past n_valid would run off the table,
+        # where a clamped lookup lands in its last live page
+        full, other = ids(CAPACITY - 2), ids(6)
+        bind(0, full, 2)
+        bind(1, other, 4)
+        assert len(chunked._leases[0].pages) == chunked.pages_per_slot
+        for lo in range(0, 24, T):
+            feed_both(chunked, single, {0: full[lo:lo + T]})
+        feed_both(chunked, single, {0: full[24:29], 1: other})
+        feed_both(chunked, single, {0: full[29:], 1: ids(1)})
+        feed_both(chunked, single, {0: ids(1), 1: ids(1)})
+        feed_both(chunked, single, {0: ids(1)})
+        assert int(chunked.slot_pos[0]) == CAPACITY

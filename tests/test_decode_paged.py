@@ -26,6 +26,7 @@ import urllib.request
 import numpy as np
 import pytest
 
+import chunk_parity
 from deeplearning4j_tpu import (MultiLayerNetwork, chaos,
                                 NeuralNetConfiguration)
 from deeplearning4j_tpu.models.paged_kv import (PagedKVAllocator,
@@ -67,6 +68,47 @@ def _rnn_lm(seed=0):
             .layer(RnnOutputLayer(n_out=V, loss="mcxent"))
             .set_input_type(InputType.recurrent(V, CAP)).build())
     return MultiLayerNetwork(conf).init()
+
+
+# ---------------------------------------------------------------------------
+# the chunk step against token-by-token steps (tests/chunk_parity.py;
+# the latent pool's cases are in tests/test_latent_moe.py)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("case", chunk_parity.CASES)
+def test_chunk_step_matches_token_by_token(case):
+    chunk_parity.run_case(_lm(layers=2), V, case)
+
+
+def test_a_slot_that_sits_a_step_out_keeps_its_pages():
+    """A bound slot that is not active in a step (a parked one) has
+    nothing written: neither program may touch its position 0."""
+    net = _lm(layers=1)
+    sess = net.paged_slot_streaming_session(capacity=16, slots=2,
+                                            page_size=4)
+    sess.bind(0, sess.reserve([1, 2, 3, 4, 5], 2))
+    sess.bind(1, sess.reserve([5, 6, 7], 2))
+    chunk_parity.feed_single(sess, {0: [1, 2, 3], 1: [5, 6, 7]})
+    was = chunk_parity.live_rows(sess, 1)
+    sess.step_slots(np.full((2, 1, 1), 4, np.float32),
+                    np.array([True, False]))
+    x = np.full((2, 4, 1), 5, np.float32)
+    sess.step_chunk(x, np.array([1, 0], np.int32))
+    for a, b in zip(chunk_parity.live_rows(sess, 1), was):
+        np.testing.assert_array_equal(a, b)
+    assert sess.slot_pos.tolist() == [5, 3]
+
+
+def test_chunk_step_refuses_what_it_cannot_hold():
+    sess = _lm().paged_slot_streaming_session(capacity=8, slots=2,
+                                              page_size=4)
+    sess.bind(0, sess.reserve([1, 2, 3, 4, 5, 6], 2))
+    x = np.ones((2, 4, 1), np.float32)
+    with pytest.raises(ValueError, match="n_valid must lie"):
+        sess.step_chunk(x, np.array([5, 0]))
+    sess.step_chunk(x, np.array([4, 0]))
+    sess.step_chunk(x, np.array([4, 0]))
+    with pytest.raises(ValueError, match="slot overflow"):
+        sess.step_chunk(x, np.array([1, 0]))
 
 
 # ---------------------------------------------------------------------------

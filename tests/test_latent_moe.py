@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import chunk_parity
 from deeplearning4j_tpu import (MultiLayerNetwork, NeuralNetConfiguration,
                                 dtypes)
 from deeplearning4j_tpu.nn.conf.inputs import InputType
@@ -175,6 +176,14 @@ def test_paged_prefill_then_decode_matches_the_reference(tiny_net):
     assert aux.shape == (2, 16) and (aux.sum(axis=1) == 12).all()
 
 
+@pytest.mark.parametrize("case", chunk_parity.CASES)
+def test_chunk_step_matches_token_by_token(tiny_net, case):
+    """The latent pool's cases of tests/chunk_parity.py: the two
+    expert layers' counts of a chunk are the one-by-one counts
+    summed, so rows past ``n_valid`` reach no routed expert."""
+    chunk_parity.run_case(tiny_net, 96, case)
+
+
 def test_free_slots_reach_no_expert(tiny_net):
     sess = tiny_net.paged_slot_streaming_session(capacity=8, slots=4,
                                                  page_size=4)
@@ -240,11 +249,14 @@ def test_batcher_serves_what_the_session_decodes(tiny_net):
     steps = snap['serving_step_seconds{endpoint="axk",part="device"}'][
         "count"]
     assert slots == steps * 2 * 16 and 0 < hit <= min(pairs, slots)
-    # every live slot-step routed one token to top-4 of 16 held, in
-    # two expert layers
-    live = sum(v for k, v in snap.items()
-               if k.startswith("serving_slot_steps_total"))
-    assert pairs == live * 2 * 4
+    # every token fed, the prompts' (a chunk a prompt; the repeat
+    # found all but its last token cached) and each sampled token but
+    # a request's last, went to top-4 of 16 held in two expert layers
+    fed = snap['serving_prompt_tokens_total{endpoint="axk"}']
+    assert fed == 3 + 6 + 9 + (9 - 8)
+    assert snap['serving_steps_total{endpoint="axk",program="chunk"}'] \
+        == 3
+    assert pairs == (fed + 4 * (5 - 1)) * 2 * 4
 
 
 def test_lease_export_import_on_the_latent_pool(tiny_net):

@@ -559,12 +559,21 @@ def _metric(snap, name, **labels):
     raise KeyError((name, labels))
 
 
+CHUNK_T = 2
+
+
 class TestBatcherSteps:
+    @pytest.fixture(autouse=True)
+    def _two_tokens_a_chunk(self, monkeypatch):
+        from deeplearning4j_tpu.serving import continuous
+        monkeypatch.setattr(continuous, "CHUNK_ROWS", 2 * CHUNK_T)
+
     def _run(self):
         from deeplearning4j_tpu.serving.continuous import (
             ContinuousBatcher)
         cb = ContinuousBatcher(_lm(), slots=2, capacity=LM_CAP,
                                queue_limit=16)
+        assert cb._chunk_t == CHUNK_T
         handles = [cb.submit(p, N_TOKENS) for p in PROMPTS]
         got = [cb.wait(h) for h in handles]
         assert cb.drain()
@@ -573,16 +582,22 @@ class TestBatcherSteps:
 
     def test_slot_steps_and_parts(self):
         """The loop's own rule: a request of P prompt tokens and N
-        emitted tokens takes P - 1 prompt slot-steps (each feeds one
-        prompt token and discards the output; the last prompt token's
-        step samples) and N decode slot-steps. No prompt here shares a
-        whole page with another, so none is skipped by a prefix hit."""
+        emitted tokens takes ceil(P / t) - 1 prompt slot-steps (each
+        feeds t prompt tokens and discards the output; the step that
+        feeds the last prompt token samples) and N decode slot-steps.
+        No prompt here shares a whole page with another, so none is
+        skipped by a prefix hit."""
         snap = self._run()
         prompt = _metric(snap, "serving_slot_steps_total", kind="prompt")
         decode = _metric(snap, "serving_slot_steps_total", kind="decode")
-        assert prompt == sum(len(p) - 1 for p in PROMPTS)
+        assert prompt == sum(-(-len(p) // CHUNK_T) - 1 for p in PROMPTS)
         assert decode == N_TOKENS * len(PROMPTS)
+        assert _metric(snap, "serving_prompt_tokens_total") == sum(
+            len(p) for p in PROMPTS)
         batches = _metric(snap, "serving_batches_total")
+        assert batches == sum(
+            _metric(snap, "serving_steps_total", program=p)
+            for p in ("single", "chunk"))
         items = _metric(snap, "serving_batch_items_total")
         assert prompt + decode == items     # every live slot did one
         parts = {p: _metric(snap, "serving_step_seconds", part=p)
@@ -602,7 +617,11 @@ class TestBatcherSteps:
         for e in events:
             if e["name"] != "serve_step":
                 by_parent.setdefault(e["parent_id"], []).append(e)
-        total = {"prompt_slots": 0, "decode_slots": 0, "active": 0}
+        total = {"prompt_slots": 0, "decode_slots": 0, "active": 0,
+                 "prompt_tokens": 0}
+        assert {s["args"]["rows"] for s in steps} == {1, CHUNK_T}
+        assert sum(s["args"]["rows"] > 1 for s in steps) == _metric(
+            snap, "serving_steps_total", program="chunk")
         for s in steps:
             kids = by_parent[s["span_id"]]
             assert [k["name"] for k in kids] == [
@@ -611,7 +630,9 @@ class TestBatcherSteps:
             assert all(_inside(k, s) for k in kids)
             for key in total:
                 total[key] += s["args"][key]
-        assert total["prompt_slots"] == sum(len(p) - 1 for p in PROMPTS)
+        assert total["prompt_slots"] == sum(
+            -(-len(p) // CHUNK_T) - 1 for p in PROMPTS)
+        assert total["prompt_tokens"] == sum(len(p) for p in PROMPTS)
         assert total["decode_slots"] == N_TOKENS * len(PROMPTS)
         assert total["active"] == _metric(
             snap, "serving_batch_items_total")
@@ -625,5 +646,7 @@ class TestBatcherSteps:
         m.evict_endpoint("generate/lm/v1")
         left = [k for k in m.registry.snapshot()
                 if k.startswith(("serving_step_seconds",
-                                 "serving_slot_steps_total"))]
+                                 "serving_slot_steps_total",
+                                 "serving_steps_total",
+                                 "serving_prompt_tokens_total"))]
         assert left == []

@@ -1,0 +1,63 @@
+#!/usr/bin/env python3
+"""A serving cell read at several row budgets of the chunked-prefill
+step (``deeplearning4j_tpu.serving.continuous.CHUNK_ROWS``: a slot in
+prefill feeds up to t tokens a step, slots * t <= rows):
+
+    python3 tools/measure_chunk_rows.py <workload> \\
+        <seconds> <seed> <rows> [<rows> ...]
+
+For each budget one untraced window through the driver itself, in one
+process (one set-up of the chip, the weights made anew each time), and
+one JSON line: the budget, the width t it gives the cell's pool, the
+driver's result, and the batcher's own counters over the window (the
+readers of benchmark/layer_metrics that need no trace). The program
+has no option for the budget; this script sets the module's constant,
+which is how PERF.md's three readings were taken. A budget of 1 is
+token-by-token prefill.
+"""
+
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+COUNTERS = ("chunk_steps_pct.serve", "prompt_slot_steps_pct.serve",
+            "step_device_ms.serve", "step_host_ms.serve",
+            "prefill_ms.serve", "queue_wait_ms.serve",
+            "batch_occupancy_pct.serve",
+            "moe_local_pairs_per_step.serve")
+
+
+def main(workload, seconds, seed, budgets):
+    from benchmark.harness import session, spec
+    from deeplearning4j_tpu.serving import continuous
+    cell = spec.load(workload)
+    driver = spec.load_module("drivers", cell.traffic["driver"])
+    server = cell.traffic["server"]
+    for rows in budgets:
+        continuous.CHUNK_ROWS = rows
+        width = continuous.chunk_width(server["slots"],
+                                       server["capacity"])
+        s = session.Session(cell, seed, seconds, 0, time.perf_counter())
+        result = driver.run(s)
+        read = {}
+        for name in COUNTERS:
+            v = spec.load_module("layer_metrics", name).read(s.obs)
+            if v is not None:
+                read[name] = v
+        print(json.dumps({
+            "rows": rows, "t": width, "seed": seed,
+            "correct": result["correct"], "failed": result["failed"],
+            "checks": {c["name"]: c["value"] for c in s.checks},
+            "metrics": {k: v["value"]
+                        for k, v in result["metrics"].items()},
+            "counters": read,
+            "peak": result["device"]["memory_peak_bytes"]}), flush=True)
+
+
+if __name__ == "__main__":
+    main(sys.argv[1], float(sys.argv[2]), int(sys.argv[3]),
+         [int(a) for a in sys.argv[4:]])
