@@ -6,9 +6,9 @@ Drives the main path once — build from the config DSL, ``fit``, save,
 TPU chip, at the full width of the widest language model this repo has
 trained (``EmbeddingSequenceLayer(2048 -> 1024)`` + 8 causal
 ``TransformerEncoderLayer(n_heads=16)`` + ``RnnOutputLayer(2048)``,
-T = 1024, batch 8, built under ``dtypes.tpu_bf16()`` as bench.py
-builds it), plus three steps of zoo ResNet50 (batch 128, 224x224, f32)
-through the second executor. Weights and data come from ``--seed``;
+T = 1024, batch 8, built under ``dtypes.tpu_bf16()``), plus three
+steps of zoo ResNet50 (batch 128, 224x224, f32) through the second
+executor. Weights and data come from ``--seed``;
 nothing is read but the repo. (The transformer layers do not read the
 policy's compute dtype: this LM's tensors are float32 and its matmuls
 run at the backend's default precision — on a TPU, operands rounded to
@@ -157,9 +157,8 @@ def lm_conf(size, seed):
     from deeplearning4j_tpu.nn.conf.inputs import InputType
     from deeplearning4j_tpu.nn.conf.layers import (
         EmbeddingSequenceLayer, RnnOutputLayer, TransformerEncoderLayer)
-    # adam(1e-4), not bench.py's 1e-3: at this width 1e-3 diverges
-    # within three steps (on the CPU in f32 as on the chip) — the
-    # bench leg times steps and never looked at its loss
+    # adam(1e-4): at this width 1e-3 diverges within three steps (on
+    # the CPU in f32 as on the chip)
     b = (NeuralNetConfiguration.builder().set_seed(seed)
          .updater(updaters.adam(1e-4)).list()
          .layer(EmbeddingSequenceLayer(n_in=size["vocab"],

@@ -22,7 +22,7 @@ import sys
 
 def _cmd_train(args):
     if args.chaos:
-        # fault injection for the bench/soak path: the plan is JSON
+        # fault injection for the soak path: the plan is JSON
         # (inline or a file); the effective seed is printed so any
         # chaotic run can be replayed exactly
         from deeplearning4j_tpu import chaos
@@ -669,7 +669,6 @@ def _cmd_serve_fleet(args):
         probe_interval_s=args.probe_interval,
         hedge_after_s=None if args.hedge_after_ms <= 0
         else args.hedge_after_ms / 1e3,
-        kv_routing=not args.no_kv_routing,
         sample_rate=args.trace_sample).start()
     slos = None
     if args.slo:
@@ -1251,10 +1250,6 @@ def build_parser() -> argparse.ArgumentParser:
     f.add_argument("--kv-pages", type=int, default=None,
                    help="KV pool pages per replica (default: "
                         "memory parity with the dense session)")
-    f.add_argument("--no-kv-routing", action="store_true",
-                   help="disable prefix-aware generate routing "
-                        "(affinity + least-loaded only — the bench "
-                        "baseline)")
     f.add_argument("--probe-interval", type=float, default=1.0,
                    metavar="S",
                    help="active health-probe period (seconds)")

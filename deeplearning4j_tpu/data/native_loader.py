@@ -324,8 +324,7 @@ class NativeImageDataSetIterator(DataSetIterator):
                 # arrays are handed off as-is — the old reusable
                 # buffer forced a second 60MB Python-side .copy()
                 # per batch, which was the dominant EXPOSED cost
-                # under decode-ahead overlap (bench leg
-                # overlap_exposed)
+                # under decode-ahead overlap
                 feat = np.empty((self._bs, self.height, self.width,
                                  self.channels), np.float32)
                 lab = np.empty((self._bs, n_classes), np.float32)

@@ -69,8 +69,8 @@ def tpu_bf16() -> Policy:
     """bf16 compute AND bf16 hidden activations / f32 params — the
     MXU-native training policy. Keeping inter-layer activations in
     bfloat16 halves the HBM traffic of every elementwise/BN boundary
-    (measured +1.4% ResNet50 step throughput over bf16-compute with
-    f32 activations, tipping the bench past the flax-bf16 baseline);
+    (the policy the ``resnet50_train`` cell runs under: PERF.md
+    section 4);
     output layers promote logits to f32 before softmax/loss
     (output.py), and BN statistics accumulate in f32 regardless
     (normalization.py)."""

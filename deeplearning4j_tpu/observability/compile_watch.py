@@ -15,8 +15,9 @@ module makes them visible two ways:
 
 2. ``install_global_watch()`` hooks ``jax.monitoring`` so every
    backend compile in the process — watched or not — is counted, with
-   persistent-compilation-cache hits/misses split out. bench.py's leg
-   subprocesses read this to record ``compile_cache_hit`` per leg.
+   persistent-compilation-cache hits/misses split out. The
+   benchmark's ``setup_compile_s`` and ``chip_smoke.py``'s per-phase
+   ``cache_hit`` read it.
 
 Both report through the unified metrics registry and (optionally)
 drop ``xla_compile`` instants on the tracer so compiles show up in
@@ -242,8 +243,8 @@ class GlobalCompileStats:
       persistent compilation cache.
     - ``persistent_cache_hits``: requests served from it.
 
-    ``cache_hit`` is the per-leg question bench asks: did this
-    process reuse compiled artifacts instead of cold-compiling?
+    ``cache_hit`` answers: did this process reuse compiled
+    artifacts instead of cold-compiling?
     """
 
     def __init__(self, registry=None, tracer=None):
@@ -267,7 +268,7 @@ class GlobalCompileStats:
             help="compiles served from the persistent XLA cache")
 
     def mark(self) -> dict:
-        """Snapshot for delta accounting (per bench leg section)."""
+        """Snapshot for delta accounting (``summary(since=mark)``)."""
         with self._lock:
             return {"backend_compiles": self.backend_compiles,
                     "compile_secs": self.compile_secs,

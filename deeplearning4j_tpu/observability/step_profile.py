@@ -18,8 +18,9 @@ dispatch queue hid becomes a measured number:
 - ``device_fence_ms``  queued device work outstanding at the fence —
   >> 0 means the device, not the host, bounds throughput
 - ``steps_per_sec`` / ``samples_per_sec`` and (given
-  ``flops_per_sample``) **MFU** against the chip's bf16 peak — the
-  same model-FLOPs accounting bench.py's legs use.
+  ``flops_per_sample``) **MFU** against the chip's bf16 peak:
+  model FLOPs (forward x :data:`TRAIN_FLOP_MULTIPLIER`), not the
+  hardware's.
 
 Reports land in ``.reports``, the log, and (optionally) a
 ``ui/stats.py`` storage via the ``profile`` field of StatsReport, so
@@ -41,9 +42,11 @@ __all__ = ["PEAK_BF16_FLOPS", "peak_flops_for_kind",
            "TRAIN_FLOP_MULTIPLIER", "ProfilerListener"]
 
 
-# bf16 peak FLOP/s per chip by device kind (prefix match) — the one
-# table; bench.py and chip_smoke.py read it through
-# peak_flops_for_kind and treat an unknown kind as an error.
+# bf16 peak FLOP/s per chip by device kind (prefix match): the
+# package's table. ProfilerListener and chip_smoke.py read it through
+# peak_flops_for_kind (chip_smoke.py treats an unknown kind as an
+# error); the benchmark keeps its own in benchmark/harness/peaks.py,
+# because the package must not import benchmark/.
 PEAK_BF16_FLOPS = {
     "TPU v5 lite": 197e12,    # v5e
     "TPU v5": 459e12,         # v5p

@@ -52,8 +52,8 @@ Pieces:
   required), pushes, and on a staleness refusal folds the refused
   delta back into the residual (no signal lost) before re-pulling.
 - :func:`run_async_training` — in-process harness (server + N worker
-  threads) for tests and the ``ps_async_training`` bench leg;
-  ``cli.py train-ps`` runs the real multi-process topology.
+  threads) for tests; ``cli.py train-ps`` runs the real
+  multi-process topology.
 
 Chaos sites (deterministic drills, chaos/injector.py):
 ``ps.push.drop`` swallows a received push unacked (worker deadline →
@@ -868,7 +868,7 @@ class ParameterServer:
             return sorted(self._workers)
 
     def wait_version(self, version: int, timeout: float = 10.0) -> bool:
-        """Test/bench helper: block (bounded) until the server has
+        """Test helper: block (bounded) until the server has
         applied at least ``version`` pushes."""
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
@@ -1198,7 +1198,7 @@ class PSWorker:
 
 
 # ---------------------------------------------------------------------------
-# in-process harness (tests + the ps_async_training bench leg)
+# in-process harness (tests)
 # ---------------------------------------------------------------------------
 
 def run_async_training(model_factory: Callable[[int], object],

@@ -12,7 +12,7 @@ Two replica flavours:
 - :class:`InProcessReplica` — a ``ModelServer`` in this process on a
   loopback port. Cheap to boot, fully introspectable (the chaos
   ``hang`` kind reaches straight into ``server.chaos_delay_s``), the
-  test/bench workhorse.
+  test workhorse.
 - :class:`SubprocessReplica` — ``python -m deeplearning4j_tpu serve``
   in a child process. ``kill()`` is a REAL ``SIGKILL``; drain rides
   SIGINT (the CLI's ctrl-c drain path).
@@ -237,12 +237,10 @@ class SubprocessReplica(_BaseReplica):
     """``python -m deeplearning4j_tpu serve`` in a child process —
     the replica the SIGKILL drill means literally."""
 
-    def __init__(self, rid: int, model_specs: List[str], port: int,
-                 extra_args: Optional[List[str]] = None):
+    def __init__(self, rid: int, model_specs: List[str], port: int):
         super().__init__(rid)
         self.port = port
         self._model_specs = list(model_specs)
-        self._extra_args = list(extra_args or [])
         self.proc: Optional[subprocess.Popen] = None
 
     def start(self) -> "SubprocessReplica":
@@ -250,7 +248,6 @@ class SubprocessReplica(_BaseReplica):
                "--host", self.host, "--port", str(self.port)]
         for spec in self._model_specs:
             cmd += ["--model", spec]
-        cmd += self._extra_args
         self.proc = subprocess.Popen(cmd,
                                      stdout=subprocess.DEVNULL,
                                      stderr=subprocess.DEVNULL)
@@ -329,15 +326,12 @@ class ReplicaFleet:
                  n: int = 2, server_kwargs: Optional[dict] = None,
                  model_specs: Optional[List[str]] = None,
                  base_port: int = 0, roles=None,
-                 extra_args: Optional[List[str]] = None,
                  net_chaos=None,
                  net_chaos_seed: Optional[int] = None,
                  model_version: int = 1):
-        if model_factory is None and not model_specs \
-                and not extra_args:
+        if model_factory is None and not model_specs:
             raise ValueError("fleet needs a model_factory (in-process"
-                             " replicas) or model_specs / extra_args "
-                             "such as --index (subprocess)")
+                             " replicas) or model_specs (subprocess)")
         if model_factory is None and base_port <= 0:
             # subprocess replicas advertise base_port + rid to the
             # router; 0 would mean "probe http://127.0.0.1:0 forever"
@@ -348,9 +342,6 @@ class ReplicaFleet:
         self._model_factory = model_factory
         self._server_kwargs = dict(server_kwargs or {})
         self._model_specs = list(model_specs or [])
-        # extra CLI flags each subprocess replica boots with (e.g.
-        # ``--index`` so every replica hosts its own index copy)
-        self._extra_args = list(extra_args or [])
         self._base_port = base_port
         self.n = n
         # disaggregation roles, boot order ("prefill=1,decode=3" /
@@ -509,8 +500,7 @@ class ReplicaFleet:
                                  model_version=boot_version)
         else:
             r = SubprocessReplica(rid, self._model_specs,
-                                  self._base_port + rid,
-                                  extra_args=self._extra_args)
+                                  self._base_port + rid)
         if role is not None:
             r.role = role
         elif rid < len(self._roles):

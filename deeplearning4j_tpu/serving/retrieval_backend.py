@@ -331,7 +331,7 @@ class RetrievalService:
                batch_sizes=(1,)) -> List[str]:
         """Pre-build the named search buckets and drive one query
         through each device path, so steady-state traffic compiles
-        zero times (asserted by the bench leg)."""
+        zero times."""
         warmed = []
         dim = self.index.dim
         for k in ks:

@@ -110,7 +110,7 @@ class RolloutController:
     comparative SLO gate, rolling back automatically on any failure.
 
     ``run()`` is synchronous and deterministic (what the soak tests
-    and the bench drive); ``start()`` wraps it in a daemon thread
+    drive); ``start()`` wraps it in a daemon thread
     for the CLI's operator verbs (``fleet-rollout start|status|
     abort`` over the router's ``/v1/rollout/*``)."""
 

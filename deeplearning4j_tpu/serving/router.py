@@ -184,8 +184,7 @@ class Router:
                  hedge_after_s: Optional[float] = 0.75,
                  hedge_min_budget_s: float = 1.0,
                  affinity_max: int = 4096,
-                 sample_rate: float = 0.01, tracer=None,
-                 kv_routing: bool = True):
+                 sample_rate: float = 0.01, tracer=None):
         self.fleet = fleet
         self.host = host
         self.port = port
@@ -201,9 +200,6 @@ class Router:
         self.hedge_after_s = hedge_after_s
         self.hedge_min_budget_s = hedge_min_budget_s
         self.affinity_max = affinity_max
-        # kv_routing=False disables the prefix-aware generate pick
-        # (affinity + least-loaded only) — the bench baseline knob
-        self.kv_routing = bool(kv_routing)
         self.sampler = Sampler(rate=sample_rate)
         self.tracer = tracer if tracer is not None else get_tracer()
         # optional fleet-health callable (a FleetCollector's
@@ -404,7 +400,7 @@ class Router:
         # those, they are NOT unregistered when the version leaves
         # the pool: version cardinality is bounded by deployments
         # (rare, operator-driven — not per-replica churn), and the
-        # rollout bench / loadgen read the retired incumbent's
+        # loadgen's per-version report reads the retired incumbent's
         # series AFTER promotion — dropping them would erase the
         # baseline half of every per-version report
         for vstr in sorted({str(getattr(r, "model_version", 1) or 1)
@@ -526,7 +522,7 @@ class Router:
                     for r in self.fleet.snapshot()):
                 self._note_failure(view)
         prefixes = None
-        if (ok or health) and self.kv_routing and (
+        if (ok or health) and (
                 load is None or load["kv_pages_total"] > 0):
             # only paged replicas can advertise prefixes; skip the
             # extra call when the metrics snapshot proves there is
@@ -775,7 +771,7 @@ class Router:
                 retry_after_s=self._soonest_retry_s())
         candidates = self._weighted_subset(candidates, trace_id)
         hit_tokens = 0
-        if prompt is not None and self.kv_routing:
+        if prompt is not None:
             fp_cache: Dict[int, list] = {}
             hits = {v.rid: self._prompt_hit_tokens(v, prompt,
                                                    fp_cache)

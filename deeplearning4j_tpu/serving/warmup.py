@@ -17,8 +17,8 @@ representative zero inputs through the REAL serving entry points —
   ``n_tokens``), for models that support streaming.
 
 After warmup a steady-state request burst compiles ZERO times —
-``observability.compile_watch.zero_compile_scope`` proves it, and the
-``aot_warmup`` bench leg records first-request latency warm vs cold.
+``observability.compile_watch.zero_compile_scope`` proves it
+(``tests/test_kstep.py``).
 
 Predict warmup drives ``model.output`` directly (the scheduler's own
 device call, bypassing its queue), so it leaves NO trace in serving

@@ -14,10 +14,10 @@ nowhere else:
   checkout, ``<repo>/.jax_cache`` — never a temporary name, a pid or
   a time.
 
-``chip_smoke.py`` and every ``bench.py`` leg call
-:func:`setup_compile_cache` before they touch a device; the CLI calls
-it only when ``--xla-cache`` is given (no persistent cache unless
-asked).
+``chip_smoke.py`` and the benchmark's session
+(``benchmark/harness/session.py``) call :func:`setup_compile_cache`
+before they touch a device; the CLI calls it only when
+``--xla-cache`` is given (no persistent cache unless asked).
 """
 
 from __future__ import annotations

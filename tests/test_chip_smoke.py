@@ -1,5 +1,5 @@
 """``chip_smoke.py`` away from the chip, and the compile-cache helper
-it shares with ``bench.py`` and the CLI.
+it shares with the benchmark's session and the CLI.
 
 The smoke script proves the main path on a TPU; here on the CPU it has
 to FAIL — quickly, with no result line — and the one helper that

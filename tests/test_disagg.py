@@ -361,7 +361,7 @@ class TestBatcherHandoff:
 def stack():
     built = []
 
-    def build(n=2, roles=None, delay=0.0, **router_kw):
+    def build(n=2, roles=None, delay=0.0):
         def factory():
             return {"lm": SlowLM(delay=delay)}
 
@@ -369,11 +369,10 @@ def stack():
             factory, n=n, roles=roles,
             server_kwargs=dict(slots=2, capacity=CAP,
                                page_size=PS)).start()
-        kw = dict(probe_interval_s=0.1, probe_timeout_s=1.0,
-                  hedge_after_s=None, request_timeout_s=60.0,
-                  sample_rate=1.0)
-        kw.update(router_kw)
-        router = Router(fleet, **kw).start()
+        router = Router(fleet, probe_interval_s=0.1,
+                        probe_timeout_s=1.0, hedge_after_s=None,
+                        request_timeout_s=60.0,
+                        sample_rate=1.0).start()
         built.append((fleet, router))
         return fleet, router
 
@@ -440,18 +439,6 @@ class TestDisaggE2E:
         sig = router.load_signals()
         assert all("prefix_cache_hits_total" in s for s in sig)
         assert all("role" in s for s in sig)
-
-    def test_kv_routing_off_keeps_counters_zero(self, stack,
-                                                reference_ids):
-        fleet, router = stack(n=2, kv_routing=False)
-        base = f"http://127.0.0.1:{router.port}"
-        for _ in range(2):
-            st, out, _ = _post(base, "/v1/generate",
-                               {"model": "lm", "prompt": PROMPT,
-                                "n_tokens": 12})
-            assert st == 200 and out["ids"] == reference_ids[12]
-        time.sleep(0.3)
-        assert router._kv_routed.value == 0
 
 
 class TestDrainMigration:

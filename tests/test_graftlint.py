@@ -817,8 +817,8 @@ class TestCLI:
         p = run_cli()
         assert p.returncode == 0, p.stdout + p.stderr
 
-    def test_examples_bench_tests_clean_too(self):
-        p = run_cli("examples", "bench.py", "--no-baseline")
+    def test_examples_and_chip_smoke_clean_too(self):
+        p = run_cli("examples", "chip_smoke.py", "--no-baseline")
         assert p.returncode == 0, p.stdout
 
     def test_stats_report(self):

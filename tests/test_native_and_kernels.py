@@ -162,50 +162,6 @@ class TestNativeLoader:
         assert np.isfinite(ds.features).all()
         assert ds.features.max() > 1.0      # 0-255 range, not empty
 
-    def test_native_image_decode_throughput(self, tmp_path):
-        """The point of the native path: the measured decode rate must
-        beat single-threaded PIL (GIL-free worker pool)."""
-        import time
-
-        from PIL import Image
-
-        from deeplearning4j_tpu.data.native_loader import (
-            NativeImageDataSetIterator, native_image_available)
-        if not native_image_available():
-            pytest.skip("no native toolchain / libpng")
-        # the justification config: 224x224, one ResNet50 batch
-        root = str(tmp_path / "imgs")
-        self._write_png_tree(root, n_per=128, hw=224, classes=("a",))
-        t0 = time.perf_counter()
-        it = NativeImageDataSetIterator(root, batch_size=128,
-                                        height=224, width=224,
-                                        n_threads=4)
-        n_native = sum(ds.num_examples() for ds in it)
-        dt_native = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        n_pil = 0
-        for f in sorted(os.listdir(os.path.join(root, "a"))):
-            img = Image.open(os.path.join(root, "a", f)).convert("RGB")
-            np.asarray(img, dtype=np.float32)
-            n_pil += 1
-        dt_pil = time.perf_counter() - t0
-        assert n_native == n_pil == 128
-        print(f"native {n_native / dt_native:.0f} img/s vs PIL "
-              f"{n_pil / dt_pil:.0f} img/s "
-              f"(batch-128 ETL: native {dt_native * 1e3:.0f} ms vs "
-              f"PIL {dt_pil * 1e3:.0f} ms vs ~88 ms TPU step)")
-        cores = os.cpu_count() or 1
-        if cores >= 4:
-            # GIL-free decode team vs 1 Python thread: the native
-            # path must win where parallelism exists (TPU-VM hosts
-            # have dozens of cores)
-            assert dt_native < dt_pil
-        else:
-            # this box cannot demonstrate parallel decode (e.g. the
-            # 1-core CI container); correctness checked above, and
-            # single-core native must at least be same order as PIL
-            assert dt_native < dt_pil * 3
-
     def test_word_count(self, tmp_path):
         from deeplearning4j_tpu.data.native_loader import (
             native_available, native_count_words)
@@ -230,8 +186,9 @@ class TestNativeLoader:
 
 
 class TestFlashAttention:
-    """Pallas kernel in interpret mode on CPU (real-TPU run covered by
-    bench/driver); dispatcher falls back to blockwise off-TPU."""
+    """Pallas kernel in interpret mode on CPU (the real-TPU run is the
+    benchmark's train cells); dispatcher falls back to blockwise
+    off-TPU."""
 
     def test_interpret_matches_reference(self, rng):
         from deeplearning4j_tpu.ops.attention import (
@@ -430,8 +387,7 @@ class TestMaskedFlashKernels:
     """kv_mask-aware Pallas kernels (round-3 verdict weak #7):
     variable-length batches keep the kernel instead of falling back to
     exact O(T^2) attention — validated against the exact masked
-    oracle in both directions (interpret mode; real-TPU covered by
-    the driver bench)."""
+    oracle in both directions (interpret mode)."""
 
     def _mk(self, rng, B=2, T=16, H=2, D=8):
         q, k, v = (rng.normal(0, 1, (B, T, H, D)).astype(np.float32)

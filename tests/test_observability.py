@@ -620,99 +620,3 @@ class TestPerfClaimsLint:
             "derived 2.0x between configs\n"
             "goal (target: 0.7x) is exempt\n")
         assert mod.check(str(tmp_path)) == []
-
-
-# ---------------------------------------------------------------------------
-# bench runner (structural; the legs themselves are called in-process
-# on the CPU by the tests of what they measure)
-# ---------------------------------------------------------------------------
-
-class TestBenchRunner:
-    def _bench(self):
-        sys.path.insert(0, REPO)
-        try:
-            import bench
-        finally:
-            sys.path.pop(0)
-        return bench
-
-    def test_legs_registered(self):
-        bench = self._bench()
-        names = [n for n, _, _ in bench._LEGS]
-        assert names[0] == "resnet_f32"          # the headline leads
-        assert list(bench._LEG_FNS) == names
-        assert len(set(names)) == len(names) == 24
-
-    def test_one_peak_table(self):
-        # bench reads the package's table; an accelerator it does not
-        # know is an error, not an omitted MFU
-        bench = self._bench()
-        from deeplearning4j_tpu.observability.step_profile import (
-            TRAIN_FLOP_MULTIPLIER)
-        assert not hasattr(bench, "_PEAK_BF16")
-        assert bench.TRAIN_MULT == TRAIN_FLOP_MULTIPLIER
-        platform, kind, peak = bench._device()
-        assert (platform, peak) == ("cpu", None) and kind
-
-    def test_unknown_accelerator_kind_is_an_error(self, monkeypatch):
-        bench = self._bench()
-        import jax
-
-        class Dev:
-            platform, device_kind = "tpu", "TPU v99"
-        monkeypatch.setattr(jax, "devices", lambda *a: [Dev()])
-        with pytest.raises(RuntimeError, match="TPU v99"):
-            bench._device()
-
-    def test_leg_result_names_its_platform(self, monkeypatch):
-        # a cheap leg, in process, on the CPU: the line it would print
-        # says so — a CPU number can never pass for a device number
-        bench = self._bench()
-        monkeypatch.setattr(bench, "CKPT_HIDDEN", 64)
-        monkeypatch.setattr(bench, "CKPT_LAYERS", 2)
-        monkeypatch.setattr(bench, "CKPT_SAVES", 2)
-        out = bench._run_leg("checkpoint_async")
-        assert out["platform"] == "cpu"
-        assert out["device_kind"] == "cpu"
-        assert out["device_count"] == 8
-        assert out["unit"] == "ms/save"
-
-    def test_runner_refuses_a_platform_that_is_not_tpu(self, monkeypatch,
-                                                       capsys):
-        bench = self._bench()
-        monkeypatch.setattr(
-            bench, "_probe_device",
-            lambda: {"platform": "cpu", "kind": "cpu", "count": 1})
-
-        def no_leg(*a, **k):
-            raise AssertionError("a leg ran without a TPU")
-        monkeypatch.setattr(bench, "_run_leg_child", no_leg)
-        assert bench.main([]) == 1
-        assert capsys.readouterr().out == ""     # nothing measured
-
-    @pytest.mark.parametrize("outcomes,rc", [
-        (["ok", "ok"], 0), (["ok", "skip"], 0), (["fail", "ok"], 1),
-        (["ok", "fail"], 1)])
-    def test_runner_exit_code_follows_the_legs(self, monkeypatch,
-                                               outcomes, rc):
-        bench = self._bench()
-        monkeypatch.setattr(
-            bench, "_probe_device",
-            lambda: {"platform": "tpu", "kind": "TPU v5 lite",
-                     "count": 1})
-        monkeypatch.setattr(bench, "_LEGS",
-                            [(f"leg{i}", None, 30)
-                             for i in range(len(outcomes))])
-        ran = []
-
-        def child(name, timeout):
-            ran.append(name)
-            return outcomes[len(ran) - 1]
-        monkeypatch.setattr(bench, "_run_leg_child", child)
-        assert bench.main([]) == rc
-        assert ran == ["leg0", "leg1"]        # a failure stops nothing
-
-    def test_failing_leg_child_is_a_failure(self):
-        # the real child: an unknown leg exits non-zero before jax
-        bench = self._bench()
-        assert bench._run_leg_child("no_such_leg", timeout=60) == "fail"

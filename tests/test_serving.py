@@ -77,6 +77,19 @@ def _mlp(seed=0):
     return MultiLayerNetwork(conf).init()
 
 
+def _assert_each_caller_got_its_own_row(results, direct):
+    """The scheduler's contract is "each caller gets its own row", not
+    bit identity across batch shapes: a row served from a padded
+    power-of-two batch and the same row at batch 1 differ by an ulp
+    in the CPU's matmul (and on the TPU, PERF.md section 6, PR 21)."""
+    for i, got in enumerate(results):
+        np.testing.assert_allclose(got, direct[i], rtol=1e-6, atol=0)
+        gaps = [float(np.abs(got - d).max()) for d in direct]
+        # nearer its own direct output than any other caller's, by
+        # orders of magnitude: the inputs are distinct random rows
+        assert min(gaps[:i] + gaps[i + 1:]) > 1e3 * gaps[i], (i, gaps)
+
+
 LM_V, LM_CAP = 13, 32
 
 
@@ -310,8 +323,8 @@ class TestBatchScheduler:
         for t in threads:
             t.join()
         s.shutdown()
-        for i in range(10):
-            np.testing.assert_array_equal(results[i], direct[i])
+        _assert_each_caller_got_its_own_row(
+            [results[i] for i in range(10)], direct)
 
 
 # ---------------------------------------------------------------------------
@@ -716,9 +729,9 @@ class TestServingEndToEnd:
         assert not errors, errors
         # zero lost or duplicated responses
         assert len(results) == n_predict + n_generate
-        # outputs equal direct single-request model calls
-        for i in range(n_predict):
-            np.testing.assert_array_equal(results[("p", i)], direct[i])
+        # every caller got its own row of the direct model's outputs
+        _assert_each_caller_got_its_own_row(
+            [results[("p", i)] for i in range(n_predict)], direct)
         for i in range(n_generate):
             np.testing.assert_array_equal(results[("g", i)],
                                           gen_ref[i])

@@ -80,8 +80,8 @@ from typing import Callable, Dict, Optional
 from deeplearning4j_tpu.serving.tiers import TIERS as _TIERS
 
 __all__ = ["LoadGen", "SearchWorkload", "generate_body_fn",
-           "scrape_streaming_latency", "scrape_ttft_populations",
-           "parse_profile", "parse_tier_mix", "tiered_body_fn"]
+           "scrape_streaming_latency", "parse_profile",
+           "parse_tier_mix", "tiered_body_fn"]
 
 
 def _default_body(i: int) -> dict:
@@ -337,10 +337,8 @@ def _accumulate_histogram(text: str, metric: str,
                           pop_counts: Dict[str, float]) -> None:
     """Fold one Prometheus exposition's ``metric`` histogram lines
     into running bucket/count accumulators (overall + split by the
-    ``population`` label) — the ONE parser behind both the per-
-    server scrape below and bench.py's fleet-merged TTFT read
-    (summing buckets before quantiles; merging per-server quantiles
-    would be statistically wrong)."""
+    ``population`` label): the parser behind the per-server scrape
+    below."""
     for line in text.splitlines():
         if not line.startswith(metric):
             continue
@@ -460,24 +458,6 @@ def scrape_version_breakdown(url: str,
             buckets.get(ver, {}), n)["p99"] if n else 0.0
         out[ver] = entry
     return out
-
-
-def scrape_ttft_populations(urls, timeout_s: float = 5.0) -> dict:
-    """Fleet-merged TTFT split: sum every server's
-    ``serving_ttft_seconds`` buckets per ``population`` label, then
-    take quantiles — ``{"cold": {count, p50, p95, p99},
-    "prefix_hit": {...}}`` in milliseconds."""
-    buckets: Dict[float, float] = {}
-    counts: Dict[str, float] = {}
-    pop_buckets: Dict[str, Dict[float, float]] = {
-        "cold": {}, "prefix_hit": {}}
-    pop_counts: Dict[str, float] = {"cold": 0.0, "prefix_hit": 0.0}
-    for url in urls:
-        _accumulate_histogram(_fetch_exposition(url, timeout_s),
-                              "serving_ttft_seconds", buckets,
-                              counts, pop_buckets, pop_counts)
-    return {pop: _quantile_entry(pop_buckets[pop], pop_counts[pop])
-            for pop in ("cold", "prefix_hit")}
 
 
 class LoadGen:
@@ -939,9 +919,9 @@ def main(argv=None):
     p.add_argument("--retries", type=int, default=2)
     p.add_argument("--out", default=None, metavar="PATH",
                    help="also write the full report as JSON to PATH "
-                        "(machine-readable: bench legs and the fleet "
-                        "collector tests read this instead of "
-                        "parsing stdout)")
+                        "(machine-readable: the fleet collector "
+                        "tests read this instead of parsing "
+                        "stdout)")
     args = p.parse_args(argv)
     if args.duration is None and args.total is None:
         args.duration = 10.0
