@@ -7,7 +7,7 @@ ceiling the ROADMAP "decode fast path" item names. This module is the
 vLLM-style paged memory model over the same layer math:
 
 - **PagedKVAllocator** — one physical pool of fixed-size pages per
-  model (per attention layer: a ``(n_pages, page_size, H, Dh)``
+  model (per attention layer: a ``(n_pages, page_size, H * Dh)``
   buffer, allocated once). Pages are refcounted; a request reserves
   only the pages its ``prompt + n_tokens`` worst case needs, so
   concurrent slot count is bounded by TOTAL KV memory, not by
@@ -30,8 +30,11 @@ vLLM-style paged memory model over the same layer math:
   (slots, 1) decode step; ``step_chunk`` is (slots, t), chunked
   prefill: a slot feeds its next ``n_valid`` tokens, up to t of its
   prompt or the one it decodes, in one call. Each attention layer
-  writes new k/v into the slot's pages and attends over the slot's
-  GATHERED virtual cache (``apply_stream_paged``). With
+  writes new k/v into the slot's pages and attends over the
+  positions the slot holds (``apply_stream_paged``): on a TPU page by
+  page through the slot's table and no further than its length
+  (``ops/paged_attention.py``), elsewhere over the slot's GATHERED
+  virtual cache. With
   ``pages_per_slot * page_size`` equal to the dense capacity the
   math is position-for-position identical to the dense path —
   greedy-token parity is tested, and so is the chunk step against
@@ -469,6 +472,12 @@ class PagedSlotSession:
         # the latest step's counts, (len(_aux_layers), ...) on the
         # device, unfetched; None for a network that has none
         self.step_aux = None
+        # the latest step's (read, spanned) KV positions: what its
+        # attention layers read of each slot's cache, and the slots x
+        # capacity the page tables span (host arithmetic, see
+        # ``_note_kv_read``)
+        self.step_kv_positions = (0, 0)
+        self._by_table: Dict[int, bool] = {}
 
     # ---- pools ----
     def _fresh_pools(self):
@@ -828,6 +837,33 @@ class PagedSlotSession:
 
         return jax.jit(step, donate_argnums=(2,))
 
+    def _note_kv_read(self, t: int, lengths) -> None:
+        """``step_kv_positions`` of a step at ``t`` rows a slot, from
+        the ``lengths`` its attention is given (``pos`` plus the rows
+        a slot feeds). This is the ACCOUNTING the layers' predicate
+        implies, host arithmetic and no measurement: layers that read
+        by table (``paged_reads_by_table``: all of them must, a layer
+        that does not say is taken to gather) fetch of each slot the
+        pages up to the one its length ends in
+        (``ops.paged_attention.pages_read``, the kernel's own rule);
+        layers that gather read every slot's whole table. What the
+        device moved is in its trace."""
+        spanned = self.slots * self.pages_per_slot * self.page_size
+        if t not in self._by_table:
+            paged = [layer for layer in self.net.layers
+                     if hasattr(layer, "apply_stream_paged")]
+            self._by_table[t] = all(
+                hasattr(layer, "paged_reads_by_table")
+                and layer.paged_reads_by_table(self.page_size, t,
+                                               self._dtype)
+                for layer in paged)
+        read = spanned
+        if self._by_table[t]:
+            from deeplearning4j_tpu.ops.paged_attention import pages_read
+            read = int(pages_read(lengths, self.page_size).sum()) \
+                * self.page_size
+        self.step_kv_positions = (read, spanned)
+
     def step_slots(self, x, active):
         """One decode step for every slot at once — the
         ``SlotStreamingSession.step_slots`` contract: ``x`` is
@@ -864,6 +900,9 @@ class PagedSlotSession:
             h, self._pools = self._step(*args)
         self.slot_pos = self.slot_pos + active.astype(
             self.slot_pos.dtype)
+        # a slot that sits the step out has length 1: its dummy row,
+        # in the scratch page
+        self._note_kv_read(1, pos + 1)
         return h
 
     def step_chunk(self, x, n_valid):
@@ -911,6 +950,7 @@ class PagedSlotSession:
         else:
             h, self._pools = out
         self.slot_pos = self.slot_pos + n_valid
+        self._note_kv_read(t, pos + n_valid)
         return h
 
     def reinit_states(self) -> None:

@@ -170,13 +170,11 @@ class SelfAttentionLayer(BaseLayer):
             proj = proj + params["bo"]
         return proj, state
 
-    def _project_qkv(self, params, x):
-        """The shared q/k/v projection (+optional biases) and head
-        split — ONE implementation for apply and apply_stream, so
-        full-sequence and streaming outputs cannot drift."""
-        B, T, _ = x.shape
-        H = self.n_heads
-        Dh = self.n_out // H
+    def _project_flat(self, params, x):
+        """The shared q/k/v projection (+optional biases), heads side
+        by side in the last axis — ONE implementation for apply and
+        the streaming variants, so full-sequence and streaming
+        outputs cannot drift."""
         q = x @ params["Wq"]
         k = x @ params["Wk"]
         v = x @ params["Wv"]
@@ -184,8 +182,16 @@ class SelfAttentionLayer(BaseLayer):
             q = q + params["bq"]
             k = k + params["bk"]
             v = v + params["bv"]
-        split = lambda y: y.reshape(B, T, H, Dh)
-        return split(q), split(k), split(v)
+        return q, k, v
+
+    def _project_qkv(self, params, x):
+        """:meth:`_project_flat` with the head split: three
+        (B, T, H, Dh)."""
+        B, T, _ = x.shape
+        H = self.n_heads
+        Dh = self.n_out // H
+        return tuple(y.reshape(B, T, H, Dh)
+                     for y in self._project_flat(params, x))
 
     # ---- stateful streaming inference (rnnTimeStep contract,
     #      MultiLayerNetwork.java:2656): the attention analog of a
@@ -278,22 +284,34 @@ class SelfAttentionLayer(BaseLayer):
 
     # ---- paged (block) KV cache: the vLLM memory model over the
     #      same math as apply_stream_bounded. The session owns ONE
-    #      physical pool of fixed-size pages per layer; each slot sees
-    #      a VIRTUAL contiguous cache assembled by gathering its page
-    #      table — so KV memory is bounded by the pool, not by
-    #      slots x max-capacity (models/paged_kv.py) ----
+    #      physical pool of fixed-size pages per layer; each slot's
+    #      cache is the pages its table names, read in place — so KV
+    #      memory is bounded by the pool, not by slots x max-capacity
+    #      (models/paged_kv.py), and a step's KV traffic by the
+    #      tokens the slots hold (ops/paged_attention.py) ----
     def zero_page_pool(self, n_pages: int, page_size: int, dtype):
-        """Physical page pool for this layer: ``zero_stream_cache``
-        with (batch, capacity) = (n_pages, page_size) — a page IS a
-        page_size-token cache row."""
-        return self.zero_stream_cache(n_pages, page_size, dtype)
+        """Physical page pool for this layer: {'k','v'} of
+        (n_pages, page_size, H * Dh). A page is ``page_size`` rows of
+        all heads side by side, as the projections leave them: one
+        contiguous, lane-dense block for the by-table kernel to
+        fetch. Two DISTINCT buffers (see ``zero_stream_cache``)."""
+        shape = (n_pages, page_size, self.n_out)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    def paged_reads_by_table(self, page_size: int, t: int, dtype) -> bool:
+        """Will ``apply_stream_paged`` at ``t`` rows a slot read each
+        slot's live pages by table (True) or gather every slot's whole
+        capacity (False)? The session's accounting asks."""
+        from deeplearning4j_tpu.ops.paged_attention import reads_by_table
+        return reads_by_table(self.n_heads, self.n_out // self.n_heads,
+                              page_size, t, dtype)
 
     def apply_stream_paged(self, params, pool, table, pos, x,
                            n_valid=None):
         """One jittable decode step over paged caches for ALL slots at
         once. ``x`` is the new (S, t, C) chunk (one row per slot),
         ``pool`` the physical {'k','v'} pages of shape
-        (n_pages, page_size, H, Dh), ``table`` the (S, P) per-slot
+        (n_pages, page_size, H * Dh), ``table`` the (S, P) per-slot
         page table, ``pos`` the (S,) per-slot token positions,
         ``n_valid`` (S,) how many of a slot's t rows carry a token
         (None: all of them; see :func:`paged_write_targets`). Writes
@@ -301,46 +319,31 @@ class SelfAttentionLayer(BaseLayer):
         are unique because written pages are slot-exclusive (shared
         prefix pages are read-only; divergence is copy-on-write at
         admission, host-side) — then attends each slot's queries over
-        its GATHERED virtual cache of P*page_size positions with the
-        same k_pos <= q_pos mask as the dense step. With
-        P*page_size == dense capacity the math is position-for-
-        position identical to apply_stream_bounded (greedy-token
-        parity is tested). Returns (out, pool)."""
+        the positions the slot holds, with the same k_pos <= q_pos
+        mask as the dense step: on a TPU page by page through the
+        slot's table and no further than its length, elsewhere over
+        the slot's GATHERED virtual cache of P*page_size positions
+        (``ops/paged_attention.py``; the gather is the tests' oracle
+        for the kernel). With P*page_size == dense capacity the math
+        is position-for-position identical to apply_stream_bounded
+        (greedy-token parity is tested). Returns (out, pool)."""
         if not self.causal:
             raise ValueError(
                 "apply_stream_paged requires causal=True: streaming "
                 "non-causal attention would need future timesteps")
-        S, t, _ = x.shape
+        from deeplearning4j_tpu.ops.paged_attention import paged_attention
+        t = x.shape[1]
         ps = pool["k"].shape[1]
-        q, k, v = self._project_qkv(params, x)
+        q, k, v = self._project_flat(params, x)
         # write positions for the t new tokens of every slot
-        wpos, page_ids, offs = paged_write_targets(table, pos, t, ps,
-                                                   n_valid)
+        _, page_ids, offs = paged_write_targets(table, pos, t, ps,
+                                                n_valid)
         k_pool = pool["k"].at[page_ids, offs].set(
             k.astype(pool["k"].dtype))
         v_pool = pool["v"].at[page_ids, offs].set(
             v.astype(pool["v"].dtype))
-        # gather each slot's virtual cache: (S, P, ps, H, Dh) ->
-        # (S, P*ps, H, Dh). Stale/unassigned table entries gather
-        # garbage pages, but their virtual positions exceed pos and
-        # the mask zeroes them exactly (exp(_NEG_INF - max) == 0.0)
-        P = table.shape[1]
-        H = self.n_heads
-        Dh = self.n_out // H
-        k_cache = k_pool[table].reshape(S, P * ps, H, Dh)
-        v_cache = v_pool[table].reshape(S, P * ps, H, Dh)
-        scale = q.shape[-1] ** -0.5
-        from deeplearning4j_tpu.ops.attention import _NEG_INF
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q,
-                            k_cache.astype(q.dtype)) * scale
-        k_pos = jnp.arange(P * ps)[None, None, :]           # (1,1,K)
-        q_pos = wpos[:, :, None]                            # (S,t,1)
-        logits = jnp.where((k_pos <= q_pos)[:, None], logits,
-                           _NEG_INF)
-        probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs,
-                         v_cache.astype(q.dtype))
-        out = out.reshape(S, t, self.n_out)
+        out = paged_attention(q, k_pool, v_pool, table, pos, n_valid,
+                              n_heads=self.n_heads)
         proj = out @ params["Wo"]
         if self.out_bias:
             proj = proj + params["bo"]
@@ -452,6 +455,10 @@ class TransformerEncoderLayer(BaseLayer):
     def zero_page_pool(self, n_pages: int, page_size: int, dtype):
         return self._ensure_attn().zero_page_pool(n_pages, page_size,
                                                   dtype)
+
+    def paged_reads_by_table(self, page_size: int, t: int, dtype) -> bool:
+        return self._ensure_attn().paged_reads_by_table(page_size, t,
+                                                        dtype)
 
     def apply_stream_paged(self, params, pool, table, pos, x,
                            n_valid=None):

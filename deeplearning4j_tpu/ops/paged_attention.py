@@ -1,0 +1,333 @@
+"""Attention over a paged KV pool, read in place by page table.
+
+The paged decode step (``SelfAttentionLayer.apply_stream_paged``,
+models/paged_kv.py) used to build every slot's virtual cache with
+``k_pool[table]``: a copy of ``slots x capacity`` positions a layer a
+step whatever the slots hold, then read again by two einsums under a
+mask. This kernel walks a slot's pages BY TABLE and stops at the
+slot's last live page, so traffic follows the tokens held and not
+the capacity:
+
+- ``table`` and the per-slot ``lengths`` / ``pos`` are scalar
+  prefetch; the pool leaves stay in HBM (``pl.ANY``) and whole pages
+  are fetched by hand, ``pages_per_block`` of them a block, double
+  buffered: while block i is computed block i + 1 is in flight, and a
+  slot's last block prefetches the next slot's first;
+- a pool leaf is ``(n_pages, page_size, H * Dh)``: a page is
+  ``page_size`` lane-dense rows of all heads, one contiguous DMA;
+- all heads of a slot go through the MXU at once. Query row ``j`` of
+  head ``h`` is the flat ``(H * Dh,)`` query row ``j`` with every
+  other head's columns zeroed (a block-diagonal ``(t * H, H * Dh)``
+  operand), so ``scores = Qx @ K_block^T`` is ``(t * H, block)`` with
+  keys on lanes, and ``Qx``'s zeros do the head split the gather path
+  did with a reshape. The value product is ``(t * H, H * Dh)``, of
+  which row ``(j, h)`` keeps head ``h``'s columns. The MXU is idle in
+  a decode step, so the H-fold surplus of multiplies is free next to
+  per-head matmuls that would each wait out the unit's latency;
+- running maximum and sum in float32 (online softmax); the two dots
+  with float32 accumulation at the precision the gather's einsums
+  come to on the chip (``_dot_precision``).
+
+Query row ``j`` of slot ``s`` sits at ``pos[s] + j`` and sees keys at
+positions ``<= pos[s] + j`` and ``< lengths[s]``: rows of one chunk are
+causal among themselves, and nothing past a slot's length reaches any
+row, whatever the table's stale entries point at. A slot of length 0
+fetches nothing and yields zero rows.
+
+``paged_attention`` dispatches: this kernel on a TPU for the shapes it
+tiles, the gather elsewhere (the CPU path and the tests' oracle).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu.ops.attention import _NEG_INF
+
+__all__ = ["paged_attention", "paged_attention_gather",
+           "pallas_paged_attention", "pages_read", "reads_by_table"]
+
+# keys a block: one lane tile of scores. A live slot of the serving
+# cells holds about a hundred tokens, so most slots are one block
+_BLOCK_KEYS = 128
+# bytes of fast memory the kernel may ask for (``_vmem_bytes``): three
+# quarters of the 16 MiB Mosaic gives a kernel on a v5e unasked, the
+# rest left to the temporaries Mosaic makes of its own
+_VMEM_BUDGET = 12 << 20
+
+
+def pages_read(lengths, page_size: int):
+    """Pages the kernel fetches of a slot that holds ``lengths``
+    positions: up to the one its last position is in, none at length
+    0. The kernel's ``live_pages``; the session's accounting of KV
+    positions read uses it on host arrays."""
+    return (lengths + page_size - 1) // page_size
+
+
+def paged_attention_gather(q, k_pool, v_pool, table, pos, n_heads):
+    """The gather path: each slot's virtual cache of ``P * page_size``
+    positions assembled from its page table, dense attention under the
+    ``k_pos <= q_pos`` mask. Stale or unassigned table entries gather
+    garbage pages, but their positions exceed every query's and the
+    mask zeroes them exactly (``exp(_NEG_INF - max) == 0.0``).
+    ``q`` (S, t, H * Dh), pool leaves (n_pages, page_size, H * Dh),
+    ``table`` (S, P), ``pos`` (S,) → (S, t, H * Dh)."""
+    S, t, HD = q.shape
+    P = table.shape[1]
+    ps = k_pool.shape[1]
+    H = n_heads
+    Dh = HD // H
+    qh = q.reshape(S, t, H, Dh)
+    k_cache = k_pool[table].reshape(S, P * ps, H, Dh)
+    v_cache = v_pool[table].reshape(S, P * ps, H, Dh)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", qh,
+                        k_cache.astype(q.dtype)) * (Dh ** -0.5)
+    k_pos = jnp.arange(P * ps)[None, None, :]                  # (1,1,K)
+    q_pos = (pos[:, None] + jnp.arange(t)[None, :])[:, :, None]
+    logits = jnp.where((k_pos <= q_pos)[:, None], logits, _NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v_cache.astype(q.dtype))
+    return out.reshape(S, t, HD)
+
+
+def _kernel(lengths_ref, pos_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
+            qx_scr, qlim_scr, m_scr, l_scr, acc_scr, k_buf, v_buf, sems,
+            state, *, n_heads, head_dim, page_size, pages_per_block,
+            pages_per_slot, precision):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    H, Dh, ps, ppb, P = (n_heads, head_dim, page_size, pages_per_block,
+                         pages_per_slot)
+    t = q_ref.shape[1]
+    HD = H * Dh
+    bk = ppb * ps
+    s = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    length = lengths_ref[s]
+    pos = pos_ref[s]
+
+    def live_pages(slot):
+        return pages_read(lengths_ref[slot], ps)
+
+    n_blocks = (live_pages(s) + ppb - 1) // ppb
+
+    def each_live_page(slot, blk, buf, fn):
+        """``fn`` on the K and the V copy of every live page of block
+        ``blk`` of ``slot`` into buffer ``buf``: the same descriptors
+        start a block and wait for it."""
+        n_live = live_pages(slot)
+        for p in range(ppb):
+            idx = blk * ppb + p
+
+            @pl.when(idx < n_live)
+            def _():
+                page = table_ref[slot * P + jnp.minimum(idx, P - 1)]
+                rows = pl.ds(p * ps, ps)
+                fn(pltpu.make_async_copy(
+                    k_hbm.at[page], k_buf.at[buf, rows], sems.at[0, buf]))
+                fn(pltpu.make_async_copy(
+                    v_hbm.at[page], v_buf.at[buf, rows], sems.at[1, buf]))
+
+    # state[0]: the buffer the next block to compute lands in;
+    # state[1]: the slot whose first block is already in flight
+    @pl.when(s == 0)
+    def _():
+        state[0] = 0
+        state[1] = -1
+        # a block's tail past the slot's live pages is never fetched:
+        # what the buffer holds there must be finite, since the value
+        # product multiplies it by an exact zero
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    @pl.when(state[1] != s)
+    def _():
+        each_live_page(s, 0, state[0], lambda c: c.start())
+
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, HD), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (H, HD), 0)
+    own = (col >= head * Dh) & (col < (head + 1) * Dh)
+    for j in range(t):
+        rows = slice(j * H, (j + 1) * H)
+        # (selected in float32: Mosaic has no relayout of the 32-bit
+        # mask onto a 16-bit operand's tiling)
+        qx_scr[rows, :] = jnp.where(
+            own, jnp.broadcast_to(
+                q_ref[0, j:j + 1, :].astype(jnp.float32), (H, HD)),
+            0.0).astype(qx_scr.dtype)
+        qlim_scr[rows, :] = jnp.full(
+            (H, qlim_scr.shape[1]), jnp.minimum(pos + j, length - 1),
+            jnp.int32)
+
+    def block(i, carry):
+        buf = state[0]
+        last = i + 1 >= n_blocks
+
+        @pl.when(jnp.logical_not(last))
+        def _():
+            each_live_page(s, i + 1, 1 - buf, lambda c: c.start())
+
+        @pl.when(last & (s + 1 < n_slots))
+        def _():
+            each_live_page(s + 1, 0, 1 - buf, lambda c: c.start())
+            state[1] = s + 1
+
+        each_live_page(s, i, buf, lambda c: c.wait())
+        qx = qx_scr[...]
+        k = k_buf[buf].astype(qx.dtype)
+        v = v_buf[buf].astype(qx.dtype)
+        sc = jax.lax.dot_general(
+            qx, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=precision) * (Dh ** -0.5)
+        k_pos = i * bk + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        sc = jnp.where(k_pos <= qlim_scr[:, 0:1], sc, _NEG_INF)
+        m_prev = m_scr[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        # block 0 holds key 0, which every row of a slot with a token
+        # sees: m_new is finite from there on, and a masked score's
+        # exp(_NEG_INF - m_new) is an exact zero
+        p = jnp.exp(sc - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_scr[:, 0:1] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        state[0] = 1 - buf
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+
+    # a slot of length 0 ran no block: acc and l are 0, its rows come
+    # out 0 and not NaN
+    inv = 1.0 / jnp.maximum(l_scr[:, 0:1], 1e-30)
+    for j in range(t):
+        rows = slice(j * H, (j + 1) * H)
+        kept = jnp.where(own, acc_scr[rows, :] * inv[rows], 0.0)
+        o_ref[0, j:j + 1, :] = jnp.sum(
+            kept, axis=0, keepdims=True).astype(o_ref.dtype)
+
+
+def _dot_precision(t: int, dtype):
+    """The precision of the kernel's two dots: what the gather's
+    einsums come to on the chip. One query row a slot is a
+    matrix-vector product, which XLA keeps off the MXU and in float32
+    (multiply-reduce fusions): float32 passes here, over an MXU that a
+    decode step leaves idle. A chunk of rows is a matmul at the
+    default precision, operands rounded to bfloat16, there as here."""
+    if t == 1 and jnp.dtype(dtype) == jnp.float32:
+        return jax.lax.Precision.HIGHEST
+    return jax.lax.Precision.DEFAULT
+
+
+def _vmem_bytes(n_heads: int, head_dim: int, page_size: int, t: int,
+                dtype) -> int:
+    """The fast memory :func:`pallas_paged_attention` asks for: its
+    scratch as declared there, the double-buffered query and output
+    blocks, the float32 value product beside the accumulator and,
+    where the dots run float32 passes, the pieces Mosaic splits a
+    block of K or V into for them."""
+    HD, R = n_heads * head_dim, t * n_heads
+    item = jnp.dtype(dtype).itemsize
+    block = max(page_size, _BLOCK_KEYS // page_size * page_size)
+    passes = _dot_precision(t, dtype) == jax.lax.Precision.HIGHEST
+    return (2 * 2 * block * HD * item        # K and V, double buffered
+            + passes * block * HD * 4        # an operand's pieces
+            + R * HD * (item + 4 + 4)        # block-diag q, acc, product
+            + 3 * R * 128 * 4                # row limits, max, sum
+            + 2 * 2 * t * HD * item)         # q and o blocks
+
+
+@functools.partial(jax.jit, static_argnames=("n_heads", "interpret"))
+def pallas_paged_attention(q, k_pool, v_pool, table, lengths, pos, *,
+                           n_heads: int, interpret: bool = False):
+    """``q`` (S, t, H * Dh); pool leaves (n_pages, page_size, H * Dh);
+    ``table`` (S, P) int32; ``lengths`` (S,) the positions a slot holds
+    once the step's rows are written; ``pos`` (S,) the position of a
+    slot's query row 0 → (S, t, H * Dh) in ``q``'s dtype."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, t, HD = q.shape
+    P = table.shape[1]
+    ps = k_pool.shape[1]
+    ppb = max(1, min(P, _BLOCK_KEYS // ps))
+    R = t * n_heads
+    kernel = functools.partial(
+        _kernel, n_heads=n_heads, head_dim=HD // n_heads, page_size=ps,
+        pages_per_block=ppb, pages_per_slot=P,
+        precision=_dot_precision(t, q.dtype))
+    row = pl.BlockSpec((1, t, HD), lambda s, *_: (s, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S,),
+            in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=row,
+            scratch_shapes=[
+                pltpu.VMEM((R, HD), q.dtype),           # block-diag q
+                pltpu.VMEM((R, 128), jnp.int32),        # last key a row sees
+                pltpu.VMEM((R, 128), jnp.float32),      # running max
+                pltpu.VMEM((R, 128), jnp.float32),      # running sum
+                pltpu.VMEM((R, HD), jnp.float32),       # accumulator
+                pltpu.VMEM((2, ppb * ps, HD), k_pool.dtype),
+                pltpu.VMEM((2, ppb * ps, HD), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((S, t, HD), q.dtype),
+        # slots in turn on one core: the buffers, their semaphores and
+        # the prefetch of the next slot's first block are carried over
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="pallas_paged_attention",
+    )(lengths.astype(jnp.int32), pos.astype(jnp.int32),
+      table.reshape(-1).astype(jnp.int32), q, k_pool, v_pool)
+
+
+def reads_by_table(n_heads: int, head_dim: int, page_size: int, t: int,
+                   dtype) -> bool:
+    """Does :func:`paged_attention` run the kernel for these shapes?
+    Only on a TPU, and only where Mosaic tiles them: a page is whole
+    sublane tiles of its dtype, a row whole lane tiles, a slot's heads
+    whole sublane tiles of the float32 accumulator, and everything
+    the kernel holds in fast memory (``_vmem_bytes``: the K and V
+    buffers grow with the row, the accumulator and its likes with
+    ``t * H`` rows of it) fits the budget."""
+    sublanes = 32 // jnp.dtype(dtype).itemsize
+    return (jax.default_backend() == "tpu"
+            and page_size % sublanes == 0
+            and (n_heads * head_dim) % 128 == 0
+            and n_heads % 8 == 0
+            and _vmem_bytes(n_heads, head_dim, page_size, t, dtype)
+            <= _VMEM_BUDGET)
+
+
+def paged_attention(q, k_pool, v_pool, table, pos, n_valid=None, *,
+                    n_heads: int):
+    """Each slot's ``t`` query rows over the positions it holds in the
+    paged pool (the step's own rows already written there): by table
+    where :func:`reads_by_table` says so, by the gather elsewhere.
+    ``n_valid`` (S,): rows at or past it carry no token; their output
+    is finite and means nothing."""
+    t, HD = q.shape[1:]
+    if reads_by_table(n_heads, HD // n_heads, k_pool.shape[1], t,
+                      k_pool.dtype):
+        lengths = pos + (t if n_valid is None else n_valid)
+        with jax.named_scope("paged_attention/pallas"):
+            return pallas_paged_attention(
+                q, k_pool, v_pool, table, lengths, pos, n_heads=n_heads)
+    with jax.named_scope("paged_attention/gather"):
+        return paged_attention_gather(q, k_pool, v_pool, table, pos,
+                                      n_heads)

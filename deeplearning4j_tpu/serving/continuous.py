@@ -1038,6 +1038,9 @@ class ContinuousBatcher(ServingBackend):
                                    prompt_tokens)
                 if aux is not None:
                     self._steps.record_experts(aux)
+                if self._paged:
+                    self._steps.record_kv_positions(
+                        *self.session.step_kv_positions)
                 step.set("active", n_active)
                 step.set("prompt_slots", n_prompt)
                 step.set("decode_slots", n_decode)

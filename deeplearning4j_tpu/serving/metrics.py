@@ -242,14 +242,22 @@ class BatcherStepMetrics:
     ``serving_steps_total{program}`` counts the steps by the program
     they ran, ``single`` (slots, 1) or ``chunk`` (slots, t), and
     ``serving_prompt_tokens_total`` the prompt tokens they fed to the
-    device. The request-phase histograms time a request from outside
-    the steps that serve it; these say what a step costs and what it
-    was spent on."""
+    device. ``serving_kv_positions_read_total`` adds the KV positions
+    a step's attention layers read (by table: each slot's pages up to
+    its length; by gather: every slot's whole capacity) as the
+    session ACCOUNTS them from the lengths it feeds and its layers'
+    dispatch, not as the device measured them, and
+    ``serving_kv_positions_spanned_total`` the slots x capacity the
+    page tables span; both exist only over a paged pool. The
+    request-phase histograms time a request from outside the steps
+    that serve it; these say what a step costs and what it was spent
+    on."""
 
     def __init__(self, registry: Optional[MetricsRegistry] = None,
                  name: str = "generate"):
         reg = registry or MetricsRegistry()
         self._reg, self._name, self._experts = reg, name, None
+        self._kv = None
         self._parts = {
             part: reg.histogram(
                 "serving_step_seconds",
@@ -285,6 +293,21 @@ class BatcherStepMetrics:
         self._kinds["decode"].inc(decode_slots)
         self._programs[program].inc()
         self._prompt_tokens.inc(prompt_tokens)
+
+    def record_kv_positions(self, read: int, spanned: int) -> None:
+        """One step's KV positions over a paged pool
+        (``PagedSlotSession.step_kv_positions``): read over spanned
+        is the share of the pool's span a step's attention touches."""
+        if self._kv is None:
+            self._kv = tuple(
+                self._reg.counter(
+                    f"serving_kv_positions_{what}_total", help=text,
+                    labels={"endpoint": self._name})
+                for what, text in (
+                    ("read", "KV positions the steps' attention read"),
+                    ("spanned", "slots x capacity per step")))
+        self._kv[0].inc(read)
+        self._kv[1].inc(spanned)
 
     def record_experts(self, counts) -> None:
         """One step's auxiliary counts of a network with expert

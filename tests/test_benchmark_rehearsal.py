@@ -158,3 +158,5 @@ def test_program_fed_layer_metrics_come_back(cell, tiny, capsys):
            for name in want}
     missing = sorted(n for n, v in got.items() if v is None)
     assert not missing, (missing, capsys.readouterr().out[-4000:])
+    # the CPU's paged step gathers: it reads what its tables span
+    assert got.get("kv_read_pct.serve", 100.0) == 100.0

@@ -126,6 +126,45 @@ def test_small_auto_block_compiles_for_v5e(topo):
     assert _kernels_in(jax.jit(loss).lower(x, x, x).compile()) == 3
 
 
+@pytest.mark.parametrize("heads, head_dim, slots, t, dtype, by_table", [
+    (16, 64, 8, 1, jnp.float32, True), (16, 64, 8, 16, jnp.float32, True),
+    (16, 64, 64, 2, jnp.float32, True), (16, 64, 8, 16, jnp.bfloat16, True),
+    (32, 128, 8, 1, jnp.float32, True), (32, 128, 8, 4, jnp.float32, False),
+    (64, 128, 8, 1, jnp.float32, False), (64, 128, 8, 1, jnp.bfloat16, False)],
+    ids=["decode_8x1", "chunk_8x16", "chunk_64x2", "chunk_8x16_bf16",
+         "wide_32x128_decode", "wide_32x128_chunk_gathers",
+         "wide_64x128_gathers", "wide_64x128_bf16_gathers"])
+def test_paged_attention_step_compiles_for_v5e(topo, as_tpu, heads,
+                                               head_dim, slots, t, dtype,
+                                               by_table):
+    """``SelfAttentionLayer.apply_stream_paged`` over the serving
+    cell's pool (64 pages of 16 a slot). At gpt2-medium's widths, both
+    step programs: the dispatch takes the by-table kernel, and Mosaic
+    takes its page DMAs, its block-diagonal operand, its tiling and
+    the float32 passes of its single-row dots. At wider rows the
+    kernel's buffers outgrow the fast memory Mosaic gives it (64 x 128
+    in float32 is refused at compile time): whatever the predicate
+    admits compiles, and what it does not keeps the gather."""
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    width = heads * head_dim
+    layer = SelfAttentionLayer(n_in=width, n_out=width, n_heads=heads,
+                               causal=True)
+    assert layer.paged_reads_by_table(16, t, dtype) == by_table
+    assert not layer.paged_reads_by_table(4, t, dtype)   # no whole tile
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    w = sds((width, width), dtype)
+    params = {"Wq": w, "Wk": w, "Wv": w, "Wo": w,
+              "bo": sds((width,), dtype)}
+    pool = {name: sds((slots * 64 + 1, 16, width), dtype)
+            for name in ("k", "v")}
+    ints = lambda *shape: sds(shape, jnp.int32)
+    compiled = jax.jit(layer.apply_stream_paged, donate_argnums=(1,)).lower(
+        params, pool, ints(slots, 64), ints(slots),
+        sds((slots, t, width), dtype), ints(slots)).compile()
+    assert _kernels_in(compiled) == int(by_table)
+
+
 # ---- four chips: the kernels on a mesh -----------------------------------
 
 def _attention_loss(q, k, v, mask=None):

@@ -9,6 +9,8 @@ stops one token short and imports to the same ids; a migration offered
 mid-prefill that resumes, on a survivor or on the incumbent, to the
 same ids. The session's own parity is in tests/chunk_parity.py."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -156,6 +158,50 @@ def test_a_prompt_of_n_takes_ceil_n_over_t_steps(net, four_rows, n):
     assert c == {"chunk": steps - tail_of_one,
                  "single": 2 + tail_of_one, "prompt": steps - 1,
                  "decode": 3, "prompt_tokens": n}
+
+
+@pytest.mark.parametrize("path", ["gather", "by_table"])
+def test_kv_positions_read_follow_the_path(net, four_rows, monkeypatch,
+                                           path):
+    """``serving_kv_positions_{read,spanned}_total`` over a scripted
+    schedule. The gather reads what the tables span. By table (the
+    layers' own predicate forced, and the kernel in interpret mode
+    for the CPU) a step reads its live slots' pages up to their
+    lengths, and in the single program one page of each slot that sits
+    the step out: its dummy row's, which the kernel fetches."""
+    from deeplearning4j_tpu.ops import paged_attention as PA
+    if path == "by_table":
+        monkeypatch.setattr(PA, "reads_by_table", lambda *a: True)
+        monkeypatch.setattr(
+            PA, "pallas_paged_attention",
+            functools.partial(PA.pallas_paged_attention, interpret=True))
+    sizes = [(19, 3), (9, 4), (2, 4)]
+    cb, metrics = _batcher(net, "kv")
+    try:
+        # one at a time: each is alone in the pool, so its steps end
+        # at t, 2t, ..., n, then n + 1, ... (its last token is never
+        # fed)
+        got = [[int(t) for t in cb.generate(_prompt(n, n), n_tokens)]
+               for n, n_tokens in sizes]
+        by_table = cb.session._by_table
+    finally:
+        cb.shutdown(drain=True)
+    for (n, n_tokens), g in zip(sizes, got):
+        _same_ids(g, *_token_by_token(net, _prompt(n, n), n_tokens))
+    snap = metrics.registry.snapshot()
+    read = snap['serving_kv_positions_read_total{endpoint="kv"}']
+    spanned = snap['serving_kv_positions_spanned_total{endpoint="kv"}']
+    c = _counts(metrics, "kv")
+    assert spanned == (c["chunk"] + c["single"]) * SLOTS * CAP
+    assert by_table == dict.fromkeys(by_table, path == "by_table")
+    if path == "gather":
+        assert read == spanned
+        return
+    ends = [e for n, n_tokens in sizes
+            for e in list(range(T, n, T)) + list(range(n, n + n_tokens))]
+    assert len(ends) == c["chunk"] + c["single"]
+    assert read == (sum(-(-e // PS) * PS for e in ends)
+                    + c["single"] * (SLOTS - 1) * PS)
 
 
 def test_a_pool_that_only_decodes_never_runs_the_chunk_program(net):

@@ -71,6 +71,73 @@ def _rnn_lm(seed=0):
 
 
 # ---------------------------------------------------------------------------
+# the by-table kernel (ops/paged_attention.py) in Pallas' interpret
+# mode against the gather path, which stays the CPU path; the Mosaic
+# kernel itself: tests/test_chip_compile.py compiles it,
+# benchmark/tests/measure_paged_attention.py runs it on the chip
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("t", [1, 2, 16])
+def test_by_table_kernel_matches_the_gather(t, dtype):
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.ops import paged_attention as PA
+    H, Dh, ps, P, S = 2, 64, 16, 10, 8       # two blocks of 8 pages
+    cap, HD = P * ps, H * Dh
+    rng = np.random.default_rng([t, len(dtype)])
+    n_live = S * P
+    k_pool = rng.normal(size=(n_live + 3, ps, HD))
+    v_pool = rng.normal(size=(n_live + 3, ps, HD))
+    # pages no slot holds: large finite garbage, which stale table
+    # entries past a slot's length point at
+    garbage = [n_live + 1, n_live + 2]
+    k_pool[garbage] = v_pool[garbage] = 1e30
+    table = rng.permutation(np.arange(1, n_live + 1)).reshape(S, P)
+    #        free  one  ends on a page  mid-page  full     shares 3's
+    pos = [0,      0,   2 * ps - t,     37,       cap - t, 2 * ps + 3,
+           0,      ps + 5]             # parked; decodes in a chunk step
+    n_valid = [0,  1,   t,              t,        t,       max(t - 1, 1),
+               0,  1]
+    pos, n_valid = np.array(pos, np.int32), np.array(n_valid, np.int32)
+    table[0] = 0
+    table[5, :2] = table[3, :2]               # a shared prompt prefix
+    for s in range(S):
+        held = -(-(pos[s] + n_valid[s]) // ps)
+        if s != 6:                            # 6 keeps a whole table
+            table[s, held:] = garbage[s % 2]
+    q = rng.normal(size=(S, t, HD))
+    q, k_pool, v_pool = (jnp.asarray(a, dtype) for a in (q, k_pool, v_pool))
+    table = jnp.asarray(table, jnp.int32)
+    got = np.asarray(PA.pallas_paged_attention(
+        q, k_pool, v_pool, table, jnp.asarray(pos + n_valid),
+        jnp.asarray(pos), n_heads=H, interpret=True), np.float32)
+    want = np.asarray(PA.paged_attention_gather(
+        q, k_pool, v_pool, table, jnp.asarray(pos), H), np.float32)
+    assert np.isfinite(got).all()
+    assert not got[[0, 6]].any()              # length 0: zeros, not NaN
+    rows = np.arange(t)[None, :] < n_valid[:, None]
+    assert rows.sum() >= 6
+    # float32 to rounding; in bfloat16 the gather rounds its softmax to
+    # bfloat16 where the kernel keeps float32
+    np.testing.assert_allclose(got[rows], want[rows], rtol=0,
+                               atol=2e-6 if dtype == "float32" else 4e-2)
+
+
+@pytest.mark.parametrize("t, dtype, want", [
+    (1, "float32", "HIGHEST"), (2, "float32", "DEFAULT"),
+    (16, "float32", "DEFAULT"), (1, "bfloat16", "DEFAULT")])
+def test_by_table_kernel_keeps_the_gathers_precision(t, dtype, want):
+    """On the chip the gather's single-row einsums are float32
+    multiply-reduce fusions and its chunk einsums matmuls at the
+    default precision: the kernel's dots follow (the CPU computes
+    both alike, so only the choice can be held here;
+    benchmark/tests/measure_paged_attention.py reads the gap to
+    float64 on the chip)."""
+    import jax
+    from deeplearning4j_tpu.ops import paged_attention as PA
+    assert PA._dot_precision(t, dtype) == getattr(jax.lax.Precision, want)
+
+
+# ---------------------------------------------------------------------------
 # the chunk step against token-by-token steps (tests/chunk_parity.py;
 # the latent pool's cases are in tests/test_latent_moe.py)
 # ---------------------------------------------------------------------------
