@@ -337,16 +337,19 @@ class PrefixCache:
         return []
 
     def evict(self, n_pages_needed: int) -> None:
-        """Drop LRU entries until ~``n_pages_needed`` page references
-        were released (or the cache is empty). Called by the
-        allocator mid-``alloc``; pages shared with live slots lose
-        the cache's reference but stay resident."""
-        released = 0
+        """Drop LRU entries until ``n_pages_needed`` PAGES came free
+        (or the cache is empty). Called by the allocator
+        mid-``alloc``; pages shared with live slots lose the cache's
+        reference but stay resident. A prompt of m pages holds m
+        entries over the same pages and a page frees only at its last
+        reference, so the count is of pages freed, not of references
+        dropped: an admission that is refused here is tried again a
+        device step later."""
         with self._lock:
-            while self._entries and released < n_pages_needed:
+            want = self._alloc.free_count() + n_pages_needed
+            while self._entries and self._alloc.free_count() < want:
                 _, chain = self._entries.popitem(last=False)
                 self._alloc.decref(chain)
-                released += len(chain)
                 self.evictions_total += 1
 
     def clear(self) -> None:
@@ -470,7 +473,8 @@ class PagedSlotSession:
         self._aux_layers = [i for i, layer in enumerate(net.layers)
                             if getattr(layer, "stream_aux", False)]
         # the latest step's counts, (len(_aux_layers), ...) on the
-        # device, unfetched; None for a network that has none
+        # device, unfetched (a dict of such arrays where the layers
+        # return a dict of counts); None for a network that has none
         self.step_aux = None
         # the latest step's (read, spanned) KV positions: what its
         # attention layers read of each slot's cache, and the slots x
@@ -832,7 +836,10 @@ class PagedSlotSession:
                         h, jnp.maximum(n_valid - 1, 0)[:, None, None],
                         axis=1)
             if aux:
-                return h, new_pools, jnp.stack(aux)
+                # per layer a (held,) row, or a dict of counts with
+                # that row among them: stacked leaf by leaf
+                return h, new_pools, jax.tree_util.tree_map(
+                    lambda *rows: jnp.stack(rows), *aux)
             return h, new_pools
 
         return jax.jit(step, donate_argnums=(2,))

@@ -47,7 +47,7 @@ from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
     LatentAttentionLayer,
 )
 from deeplearning4j_tpu.nn.conf.layers.moe import (
-    SparseExpertsLayer, LatentDecoderBlock,
+    SparseExpertsLayer, LatentDecoderBlock, ShortcutExpertBlock,
 )
 
 __all__ = [
@@ -69,4 +69,5 @@ __all__ = [
     "FrozenLayer", "VariationalAutoencoder", "Yolo2OutputLayer",
     "SelfAttentionLayer", "TransformerEncoderLayer",
     "LatentAttentionLayer", "SparseExpertsLayer", "LatentDecoderBlock",
+    "ShortcutExpertBlock",
 ]

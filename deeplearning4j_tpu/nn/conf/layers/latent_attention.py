@@ -117,6 +117,12 @@ class LatentAttentionLayer(BaseLayer):
     #  "beta_fast", "beta_slow", "mscale", "mscale_all_dim"} or None
     rope_scaling: Optional[dict] = None
     eps: float = 1e-6
+    # LongCat-Flash's ``mla_scale_q_lora`` / ``mla_scale_kv_lora``:
+    # the query times sqrt(n_in / q_lora_rank) and the normed
+    # key-value latent times sqrt(n_in / kv_lora_rank), constants
+    # that undo what the two bottlenecks take off the variance
+    scale_q_lora: bool = False
+    scale_kv_lora: bool = False
 
     def set_n_in(self, input_type: InputType) -> None:
         if self.n_in is None:
@@ -166,18 +172,35 @@ class LatentAttentionLayer(BaseLayer):
     def _project(self, params, x, positions):
         """x (B,t,C) at ``positions`` (B,t) -> q_nope (B,t,H,dn),
         q_rope (B,t,H,dr) rotated, c_kv (B,t,rkv) normed, k_r
-        (B,t,dr) rotated: the last two are what the cache holds."""
+        (B,t,dr) rotated: the last two are what the cache holds.
+        ``scale_q_lora`` scales both halves of the query,
+        ``scale_kv_lora`` the latent and not the rotary key."""
         B, t, _ = x.shape
         H, dn, dr = (self.n_heads, self.qk_nope_head_dim,
                      self.qk_rope_head_dim)
         x = x.astype(params["Wqa"].dtype)
         inv, scale = self._rope_tables()
         cq = rms_norm(_mm(x, params["Wqa"]), params["q_gain"], self.eps)
-        q = _mm(cq, params["Wqb"]).reshape(B, t, H, dn + dr)
+        if self.scale_q_lora:
+            # scaled in float32, rounded once
+            q = (einsum_f32("btr,rn->btn", cq, params["Wqb"])
+                 * math.sqrt(self.n_in / self.q_lora_rank)).astype(
+                     cq.dtype)
+        else:
+            q = _mm(cq, params["Wqb"])
+        q = q.reshape(B, t, H, dn + dr)
         q_rope = _rope(q[..., dn:], positions[:, :, None], inv, scale)
         kv = _mm(x, params["Wkva"])
-        ckv = rms_norm(kv[..., :self.kv_lora_rank], params["kv_gain"],
-                       self.eps)
+        ckv = kv[..., :self.kv_lora_rank]
+        if self.scale_kv_lora:
+            # the cache holds the SCALED latent, so the absorbed
+            # decode reads it as it reads an unscaled one
+            ckv = (rms_norm(ckv.astype(jnp.float32), params["kv_gain"],
+                            self.eps)
+                   * math.sqrt(self.n_in / self.kv_lora_rank)).astype(
+                       kv.dtype)
+        else:
+            ckv = rms_norm(ckv, params["kv_gain"], self.eps)
         kr = _rope(kv[..., self.kv_lora_rank:], positions, inv, scale)
         return q[..., :dn], q_rope, ckv, kr
 

@@ -16,9 +16,21 @@ would add is left out, and nothing stands in for them. Summed over
 the shares of a group, with the shared expert counted once, the parts
 give the whole layer (tests/test_latent_moe.py holds that).
 
+With ``scoring_func="softmax"``, ``router_bias`` and
+``n_zero_experts`` it is LongCat-Flash's router (arXiv 2509.01322
+§2.1): a softmax over the routed AND the zero-compute experts, a
+correction bias that enters the selection only, and identity experts
+whose pick returns the token itself times its weight. The zero
+experts' part needs no exchange, so every share computes it.
+
 ``LatentDecoderBlock`` is the pre-RMSNorm residual block
 ``h = x + MLA(norm(x)); y = h + F(norm(h))`` with ``F`` either a
 dense SiLU-gated MLP or the expert layer.
+
+``ShortcutExpertBlock`` is LongCat-Flash's shortcut-connected layer
+(§2.2): two latent attentions and two dense MLPs in sequence, and one
+expert layer that reads the first sub-layer's normed hidden state and
+joins the residual stream at the end of the second.
 """
 
 from __future__ import annotations
@@ -38,7 +50,8 @@ from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
     LatentAttentionLayer, _mm)
 from deeplearning4j_tpu.nn.conf.layers.normalization import rms_norm
 
-__all__ = ["SparseExpertsLayer", "LatentDecoderBlock", "swiglu"]
+__all__ = ["SparseExpertsLayer", "LatentDecoderBlock",
+           "ShortcutExpertBlock", "swiglu"]
 
 _F32 = jnp.float32
 
@@ -64,10 +77,22 @@ class SparseExpertsLayer(BaseLayer):
     n_shared_experts: int = 1
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    scoring_func: str = "sigmoid"       # or "softmax", over the width
+    # identity experts behind the routed ones in the router's width
+    # (LongCat-Flash's zero-compute experts): a pick of one returns
+    # the token itself times its weight
+    n_zero_experts: int = 0
+    # a correction bias ``br`` over the router's width that enters
+    # the SELECTION and not the weights
+    router_bias: bool = False
 
     seq_parallelizable = True           # per token
 
     def __post_init__(self):
+        if self.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError(
+                f"scoring_func {self.scoring_func!r}: 'sigmoid' or "
+                "'softmax'")
         if self.held is not None:
             self.held = (int(self.held[0]), int(self.held[1]))
         first, count = self.held_range()
@@ -79,6 +104,10 @@ class SparseExpertsLayer(BaseLayer):
 
     def held_range(self):
         return self.held or (0, self.n_routed_experts)
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed_experts + self.n_zero_experts
 
     def set_n_in(self, input_type: InputType) -> None:
         if self.n_in is None:
@@ -93,8 +122,8 @@ class SparseExpertsLayer(BaseLayer):
         d, w = self.n_in, self.expert_width
         n = self.held_range()[1]
         ks = jax.random.split(key, 7)
-        p = {"Wr": self._sample_w(ks[0], (d, self.n_routed_experts), d,
-                                  self.n_routed_experts),
+        p = {"Wr": self._sample_w(ks[0], (d, self.router_width), d,
+                                  self.router_width),
              "Wg": self._sample_w(ks[1], (n, d, w), d, w),
              "Wu": self._sample_w(ks[2], (n, d, w), d, w),
              "Wd": self._sample_w(ks[3], (n, w, d), w, d)}
@@ -103,6 +132,9 @@ class SparseExpertsLayer(BaseLayer):
             p.update(Wsg=self._sample_w(ks[4], (d, ws), d, ws),
                      Wsu=self._sample_w(ks[5], (d, ws), d, ws),
                      Wsd=self._sample_w(ks[6], (ws, d), ws, d))
+        if self.router_bias:
+            p["br"] = jnp.zeros((self.router_width,),
+                                dtypes.policy().param_dtype)
         return p, {}
 
     # ---- the router ----
@@ -111,9 +143,16 @@ class SparseExpertsLayer(BaseLayer):
         the selected experts of every token over the router's whole
         width, and their combine weights."""
         with jax.named_scope("moe/router"):
-            scores = jax.nn.sigmoid(
-                einsum_f32("nd,de->ne", x, params["Wr"]))
-            w, ids = jax.lax.top_k(scores, self.top_k)
+            scores = einsum_f32("nd,de->ne", x, params["Wr"])
+            scores = (jax.nn.sigmoid(scores)
+                      if self.scoring_func == "sigmoid"
+                      else jax.nn.softmax(scores, axis=-1))
+            if self.router_bias:
+                _, ids = jax.lax.top_k(
+                    scores + params["br"].astype(_F32), self.top_k)
+                w = jnp.take_along_axis(scores, ids, axis=-1)
+            else:
+                w, ids = jax.lax.top_k(scores, self.top_k)
             if self.norm_topk_prob:
                 w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
             return ids.astype(jnp.int32), w * self.routed_scaling_factor
@@ -123,12 +162,21 @@ class SparseExpertsLayer(BaseLayer):
         """(out, counts): ``counts`` (held,) int32, how many tokens
         each held expert served. ``active`` marks the rows that carry
         a token, (B,) for whole sequences or (B,T) row by row (the
-        chunk program's ragged rows); the others reach no routed
-        expert and are not counted (a free slot of a decode batch)."""
+        chunk program's ragged rows); the others reach no expert and
+        are not counted (a free slot of a decode batch)."""
+        out, tally = self.apply_tallied(params, x, active)
+        return out, tally["held"]
+
+    def apply_tallied(self, params, x, active=None):
+        """(out, tally): ``apply_counted`` with all three counts of
+        the ``active`` rows, ``{"held": (held,) tokens a held expert,
+        "zero": () (row, zero expert) pairs, "selected": () (row,
+        selected expert) pairs}``, int32."""
         shape = x.shape
         x = x.reshape(-1, shape[-1]).astype(params["Wr"].dtype)
         ids, w = self.route(params, x)
         first, count = self.held_range()
+        rows = None
         with jax.named_scope("moe/experts"):
             # combine weight of every (token, held expert): 0 unless
             # selected. Every token goes through every held expert
@@ -151,7 +199,23 @@ class SparseExpertsLayer(BaseLayer):
             with jax.named_scope("moe/shared"):
                 out = out + swiglu(x, params["Wsg"], params["Wsu"],
                                    params["Wsd"]).astype(_F32)
-        return out.astype(x.dtype).reshape(shape), counts
+        n_zero = jnp.zeros((), jnp.int32)
+        if self.n_zero_experts:
+            # on the token's own chip in a deployment: no exchange,
+            # so every share computes it for every row it has
+            with jax.named_scope("moe/zero"):
+                zero = ids >= self.n_routed_experts      # (N,k)
+                if rows is not None:
+                    zero = zero & rows[:, None]
+                out = out + (jnp.sum(jnp.where(zero, w, 0.0), axis=1)
+                             [:, None] * x.astype(_F32))
+                n_zero = jnp.sum(zero, dtype=jnp.int32)
+        n_rows = x.shape[0] if rows is None else jnp.sum(
+            rows, dtype=jnp.int32)
+        tally = {"held": counts, "zero": n_zero,
+                 "selected": jnp.asarray(n_rows * self.top_k,
+                                         jnp.int32)}
+        return out.astype(x.dtype).reshape(shape), tally
 
     def apply(self, params, state, x, *, training=False, rng=None,
               mask=None):
@@ -285,6 +349,157 @@ class LatentDecoderBlock(BaseLayer):
                 rms_norm(x, params["norm1_gain"], self.eps), n_valid)
         h, counts = self._ffn_half(params, x + a, active)
         return h, pool, counts
+
+    def apply_stream_paged(self, params, pool, table, pos, x,
+                           n_valid=None):
+        h, pool, _ = self.apply_stream_paged_aux(
+            params, pool, table, pos, x, n_valid=n_valid)
+        return h, pool
+
+
+@register_layer
+@dataclasses.dataclass
+class ShortcutExpertBlock(BaseLayer):
+    """LongCat-Flash's shortcut-connected expert layer::
+
+        h0 = x  + MLA_0(norm(x));   z0 = norm(h0)
+        m  = MoE(z0)                    # the shortcut: read here ...
+        h1 = h0 + MLP_0(z0)
+        h2 = h1 + MLA_1(norm(h1))
+        y  = h2 + MLP_1(norm(h2)) + m   # ... joined here
+
+    Every norm has its own gain; the two attentions have their own
+    weights and their own caches (``zero_page_pool`` gives
+    ``{"a0", "a1"}`` over one page table). The fields are the parts'
+    own, flat, as ``LatentDecoderBlock`` has them."""
+
+    n_in: Optional[int] = None
+    eps: float = 1e-5
+    # the two latent attentions (LatentAttentionLayer)
+    n_heads: int = 4
+    q_lora_rank: int = 24
+    kv_lora_rank: int = 16
+    qk_nope_head_dim: int = 8
+    qk_rope_head_dim: int = 4
+    v_head_dim: int = 8
+    rope_theta: float = 10000.0
+    scale_q_lora: bool = True
+    scale_kv_lora: bool = True
+    # the two dense MLPs
+    intermediate_size: int = 128
+    # the expert layer (SparseExpertsLayer): a softmax router with
+    # its correction bias, no normaliser over the selected, no
+    # shared expert
+    n_routed_experts: int = 16
+    n_zero_experts: int = 8
+    held: Optional[Tuple[int, int]] = None
+    top_k: int = 4
+    expert_width: int = 32
+    routed_scaling_factor: float = 1.0
+
+    stream_aux = True       # a decode step returns the expert tally
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if self.n_in is None:
+            self.n_in = input_type.size
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType.recurrent(self.n_in or input_type.size,
+                                   input_type.timesteps)
+
+    def _ensure_parts(self):
+        if not hasattr(self, "_attn"):
+            common = dict(n_in=self.n_in, weight_init=self.weight_init,
+                          weight_distribution=self.weight_distribution)
+            self._attn = LatentAttentionLayer(
+                n_heads=self.n_heads, q_lora_rank=self.q_lora_rank,
+                kv_lora_rank=self.kv_lora_rank,
+                qk_nope_head_dim=self.qk_nope_head_dim,
+                qk_rope_head_dim=self.qk_rope_head_dim,
+                v_head_dim=self.v_head_dim, rope_theta=self.rope_theta,
+                eps=self.eps, scale_q_lora=self.scale_q_lora,
+                scale_kv_lora=self.scale_kv_lora, **common)
+            self._moe = SparseExpertsLayer(
+                n_routed_experts=self.n_routed_experts,
+                n_zero_experts=self.n_zero_experts, held=self.held,
+                top_k=self.top_k, expert_width=self.expert_width,
+                n_shared_experts=0,
+                routed_scaling_factor=self.routed_scaling_factor,
+                norm_topk_prob=False, scoring_func="softmax",
+                router_bias=True, **common)
+        return self._attn, self._moe
+
+    def initialize(self, key, input_type: InputType):
+        self.set_n_in(input_type)
+        attn, moe = self._ensure_parts()
+        ks = jax.random.split(key, 9)
+        d, ff = self.n_in, self.intermediate_size
+        t = InputType.recurrent(d)
+        ones = lambda: jnp.ones((d,), dtypes.policy().param_dtype)
+        mlp = lambda k: {"Wg": self._sample_w(k[0], (d, ff), d, ff),
+                         "Wu": self._sample_w(k[1], (d, ff), d, ff),
+                         "Wd": self._sample_w(k[2], (ff, d), ff, d)}
+        p = {"moe": moe.initialize(ks[8], t)[0]}
+        for i in (0, 1):
+            p.update({f"norm_a{i}_gain": ones(),
+                      f"norm_f{i}_gain": ones(),
+                      f"attn{i}": attn.initialize(ks[i], t)[0],
+                      f"mlp{i}": mlp(ks[2 + 3 * i:5 + 3 * i])})
+        return p, {}
+
+    def _forward(self, params, x, attend, active=None):
+        """The layer's equations; ``attend(i, z)`` is sub-layer
+        ``i``'s attention over the normed ``z``."""
+        _, moe = self._ensure_parts()
+        norm = lambda h, name: rms_norm(h, params[name], self.eps)
+        mlp = lambda i, z: swiglu(z, params[f"mlp{i}"]["Wg"],
+                                  params[f"mlp{i}"]["Wu"],
+                                  params[f"mlp{i}"]["Wd"])
+        x = x.astype(params["norm_a0_gain"].dtype)
+        with jax.named_scope("mla0"):
+            h = x + attend(0, norm(x, "norm_a0_gain"))
+        z = norm(h, "norm_f0_gain")
+        m, tally = moe.apply_tallied(params["moe"], z, active)
+        with jax.named_scope("mlp0"):
+            h = h + mlp(0, z)
+        with jax.named_scope("mla1"):
+            h = h + attend(1, norm(h, "norm_a1_gain"))
+        with jax.named_scope("mlp1"):
+            h = h + mlp(1, norm(h, "norm_f1_gain")) + m
+        return h, tally
+
+    def apply(self, params, state, x, *, training=False, rng=None,
+              mask=None):
+        attn, _ = self._ensure_parts()
+        attend = lambda i, z: attn.apply(
+            params[f"attn{i}"], {}, z, training=training, rng=rng,
+            mask=mask)[0]
+        return self._forward(params, x, attend)[0], state
+
+    # ---- paged decode ----
+    def zero_page_pool(self, n_pages: int, page_size: int, dtype):
+        attn, _ = self._ensure_parts()
+        return {f"a{i}": attn.zero_page_pool(n_pages, page_size, dtype)
+                for i in (0, 1)}
+
+    def apply_stream_paged_aux(self, params, pool, table, pos, x,
+                               active=None, n_valid=None):
+        """(out, pool, tally): one decode step through both
+        sub-layers; ``tally`` is the expert layer's
+        (``SparseExpertsLayer.apply_tallied``) over the ``active``
+        slots, or rows where the chunk program gives a (slots, t)
+        mask beside its ``n_valid``."""
+        attn, _ = self._ensure_parts()
+        new_pool = {}
+
+        def attend(i, z):
+            a, new_pool[f"a{i}"] = attn.apply_stream_paged(
+                params[f"attn{i}"], pool[f"a{i}"], table, pos, z,
+                n_valid)
+            return a
+
+        h, tally = self._forward(params, x, attend, active)
+        return h, new_pool, tally
 
     def apply_stream_paged(self, params, pool, table, pos, x,
                            n_valid=None):

@@ -257,7 +257,7 @@ class BatcherStepMetrics:
                  name: str = "generate"):
         reg = registry or MetricsRegistry()
         self._reg, self._name, self._experts = reg, name, None
-        self._kv = None
+        self._kv = self._pairs = None
         self._parts = {
             part: reg.histogram(
                 "serving_step_seconds",
@@ -319,7 +319,29 @@ class BatcherStepMetrics:
         ``serving_moe_expert_slots_total`` the (layer, held expert)
         there were: hits over slots is the share of the held
         experts' weights a step had to read. The series exist only
-        for a backend whose network returns such counts."""
+        for a backend whose network returns such counts.
+
+        A network with zero-compute experts gives a dict:
+        ``counts["held"]`` as above, ``counts["zero"][l]`` the
+        (token, zero expert) pairs of layer ``l`` and
+        ``counts["selected"][l]`` all its (token, selected expert)
+        pairs, which feed ``serving_moe_zero_pairs_total`` and
+        ``serving_moe_selected_pairs_total``; the three series above
+        keep their meaning (held routed experts only)."""
+        if isinstance(counts, dict):
+            if self._pairs is None:
+                self._pairs = {
+                    what: self._reg.counter(
+                        f"serving_moe_{what}_pairs_total", help=text,
+                        labels={"endpoint": self._name})
+                    for what, text in (
+                        ("zero", "(token, zero-compute expert) pairs "
+                                 "the router selected"),
+                        ("selected", "(token, selected expert) pairs "
+                                     "over the router's whole width"))}
+            for what, counter in self._pairs.items():
+                counter.inc(int(counts[what].sum()))
+            counts = counts["held"]
         if self._experts is None:
             self._experts = {
                 what: self._reg.counter(

@@ -56,8 +56,11 @@ def feed_single(sess, tokens):
         for slot in np.flatnonzero(active):
             last[int(slot)] = h[slot, 0]
         if sess.step_aux is not None:
-            aux = np.asarray(sess.step_aux)
-            counts = aux if counts is None else counts + aux
+            # an array, or a dict of arrays (a layer with zero
+            # experts): summed leaf by leaf
+            aux = jax.device_get(sess.step_aux)
+            counts = aux if counts is None else jax.tree_util.tree_map(
+                np.add, counts, aux)
     return last, counts
 
 
@@ -89,8 +92,8 @@ def feed_both(chunked, single, tokens, atol=1e-5):
             # every position a later step may read
             np.testing.assert_allclose(a[:hi], w[:hi], atol=atol)
     if counts is not None:
-        np.testing.assert_array_equal(np.asarray(chunked.step_aux),
-                                      counts)
+        jax.tree_util.tree_map(np.testing.assert_array_equal,
+                               jax.device_get(chunked.step_aux), counts)
     return h
 
 

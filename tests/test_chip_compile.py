@@ -165,6 +165,54 @@ def test_paged_attention_step_compiles_for_v5e(topo, as_tpu, heads,
     assert _kernels_in(compiled) == int(by_table)
 
 
+@pytest.mark.parametrize("t", [4, 1], ids=["chunk_32x4", "decode_32x1"])
+def test_shortcut_expert_block_step_compiles_for_v5e(topo, t):
+    """``ShortcutExpertBlock.apply_stream_paged_aux`` at the widths
+    and the pool of the benchmark's ``longcat_serve_tooluse`` cell
+    (hidden 6144, 64 heads, 16 held of 512 + 256 experts, top-12; 32
+    slots of 64 pages of 16) in bfloat16, both step programs: the
+    768-wide top-k, two latent pools written and gathered, and the
+    tally beside the output, in a chip's memory."""
+    from deeplearning4j_tpu import dtypes
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.conf.layers import ShortcutExpertBlock
+    bf16, slots = jnp.bfloat16, 32
+    layer = ShortcutExpertBlock(
+        n_in=6144, eps=1e-5, n_heads=64, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, rope_theta=1e7, intermediate_size=12288,
+        n_routed_experts=512, n_zero_experts=256, held=(0, 16),
+        top_k=12, expert_width=2048, routed_scaling_factor=6.0)
+    one = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        tree)
+    with dtypes.policy_scope(dtypes.Policy(
+            param_dtype=bf16, compute_dtype=bf16, output_dtype=bf16)):
+        params = place(jax.eval_shape(lambda: layer.initialize(
+            jax.random.PRNGKey(0), InputType.recurrent(6144))[0]))
+    assert {a.dtype for a in jax.tree_util.tree_leaves(params)} == {
+        jnp.dtype(bf16)}
+    pool = place(jax.eval_shape(
+        lambda: layer.zero_page_pool(slots * 64 + 1, 16, bf16)))
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    compiled = jax.jit(layer.apply_stream_paged_aux,
+                       donate_argnums=(1,)).lower(
+        params, pool, sds((slots, 64), jnp.int32),
+        sds((slots,), jnp.int32), sds((slots, t, 6144), bf16),
+        sds((slots, t), bool) if t > 1 else sds((slots,), bool),
+        sds((slots,), jnp.int32) if t > 1 else None).compile()
+    out, new_pool, tally = compiled.output_shardings
+    assert set(new_pool) == {"a0", "a1"}
+    assert set(tally) == {"held", "zero", "selected"}
+    mem = compiled.memory_analysis()
+    # a layer's weights are 2.49 GB; the step's temporaries beside
+    # them (gathers of 32 x 1,024 cached rows, scores, the experts'
+    # dense pass) stay under 1 GB
+    assert 2.4e9 < mem.argument_size_in_bytes < 2.7e9
+    assert mem.temp_size_in_bytes < 1e9
+
+
 # ---- four chips: the kernels on a mesh -----------------------------------
 
 def _attention_loss(q, k, v, mask=None):

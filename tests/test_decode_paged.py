@@ -241,6 +241,31 @@ class TestPagedAllocator:
         assert a.in_use() == 6 and len(pc) == 1
         assert a.refcount(pages[0]) == 1
 
+    def test_eviction_frees_the_pages_an_allocation_needs(self):
+        """A prompt of m pages holds m entries over the same pages:
+        one ``alloc`` under pressure drops as many as it takes for
+        the pages to come free (counting dropped references would
+        free nothing and leave the request to be retried a device
+        step later, several times over)."""
+        a = PagedKVAllocator(n_pages=24, page_size=4)
+        pc = PrefixCache(a)
+        for k in range(2):                # two finished 12-page prompts
+            pages = a.alloc(12)
+            assert pc.register(np.arange(48) + 100 * k, pages) == 12
+            a.decref(pages)
+        assert a.free_count() == 0 and len(pc) == 24
+        got = a.alloc(10, evictor=pc)     # one call, no retry
+        assert len(got) == 10
+        # the older prompt went whole, the newer one stays whole
+        assert pc.evictions_total == 12 and len(pc) == 12
+        assert pc.lookup(np.arange(48)) == []
+        assert len(pc.lookup(np.arange(48) + 100)) == 12
+        # what live leases hold cannot be freed: the cache empties
+        # and the allocation still fails typed
+        with pytest.raises(KVPagePoolExhaustedError):
+            a.alloc(15, evictor=pc)
+        assert len(pc) == 0 and a.free_count() == 2
+
     def test_session_reserve_cow_on_full_prompt_hit(self):
         net = _lm()
         sess = net.paged_slot_streaming_session(capacity=CAP,
