@@ -38,7 +38,12 @@ vLLM-style paged memory model over the same layer math:
   ``pages_per_slot * page_size`` equal to the dense capacity the
   math is position-for-position identical to the dense path —
   greedy-token parity is tested, and so is the chunk step against
-  the same tokens fed one by one.
+  the same tokens fed one by one. ``step_ids`` runs the same step at
+  either width and returns, in place of the probability rows, each
+  slot's greedy id and a flag that its row was finite, both left on
+  the device; a slot may take the id the previous such step picked
+  for it as its first row, so a decode loop need not wait for one
+  step's ids before it enqueues the next.
 
 Page id 0 is a reserved scratch page: a slot that sits a step out is
 given an all-zero page-table row, and the chunk step sends every row
@@ -65,6 +70,10 @@ from deeplearning4j_tpu.serving.errors import (KVLeaseCorruptError,
 __all__ = ["PagedKVAllocator", "PrefixCache", "PagedSlotSession",
            "prefix_fingerprint", "prefix_fingerprints", "parse_lease",
            "LEASE_WIRE_VERSION"]
+
+
+_NOT_CHUNKABLE = ("this network has a layer that is not pointwise in "
+                  "time beside its caches; feed it through ")
 
 
 def _pages_for(tokens: int, page_size: int) -> int:
@@ -450,6 +459,11 @@ class PagedSlotSession:
         # one jitted step: ``step_slots`` runs it at (slots, 1, C),
         # ``step_chunk`` at (slots, t, C), each shape its own program
         self._step = None
+        # the same step with the greedy pick on the device
+        # (``step_ids``), and the ids its latest call picked: unfetched,
+        # the next call's ``prev_ids``
+        self._step_ids = None
+        self._prev_ids = jnp.zeros((self.slots,), jnp.int32)
         paged = [i for i, layer in enumerate(net.layers)
                  if hasattr(layer, "apply_stream_paged")]
         self._last_paged = paged[-1] if paged else -1
@@ -842,7 +856,28 @@ class PagedSlotSession:
                     lambda *rows: jnp.stack(rows), *aux)
             return h, new_pools
 
-        return jax.jit(step, donate_argnums=(2,))
+        def step_ids(params, layer_states, pools, table, pos, x,
+                     n_valid, prev_ids, use_prev):
+            # row 0 of a slot in ``use_prev`` is the id the previous
+            # call picked for it, which never left the device
+            x = x.at[:, 0, 0].set(jnp.where(
+                use_prev, prev_ids.astype(x.dtype), x[:, 0, 0]))
+            if x.shape[1] == 1:
+                out = step(params, layer_states, pools, table, pos, x,
+                           n_valid > 0)
+            else:
+                out = step(params, layer_states, pools, table, pos, x,
+                           None, n_valid)
+            # (slots, V): the rows ``step_slots`` / ``step_chunk``
+            # return. argmax takes the first index on ties, as
+            # ``np.argmax`` over the fetched row does
+            row = out[0][:, 0]
+            ids = jnp.argmax(row, axis=-1).astype(jnp.int32)
+            finite = jnp.all(jnp.isfinite(row), axis=-1)
+            return (ids, finite) + tuple(out[1:])
+
+        self._step = jax.jit(step, donate_argnums=(2,))
+        self._step_ids = jax.jit(step_ids, donate_argnums=(2,))
 
     def _note_kv_read(self, t: int, lengths) -> None:
         """``step_kv_positions`` of a step at ``t`` rows a slot, from
@@ -891,7 +926,7 @@ class PagedSlotSession:
                 f"{self.capacity} — admit shorter requests or build "
                 "the session with a larger capacity")
         if self._step is None:
-            self._step = self._make_step()
+            self._make_step()
         # inactive slots step with pos 0 over an all-zero table row:
         # the write targets scratch, never a live page (a slot that
         # is bound but sits a step out, as a parked one does, would
@@ -912,6 +947,26 @@ class PagedSlotSession:
         self._note_kv_read(1, pos + 1)
         return h
 
+    def _check_rows(self, x, n_valid):
+        """Shapes and bounds of a step that feeds slot ``s`` the rows
+        ``x[s, :n_valid[s]]``: ``(t, live)``, or the ValueError."""
+        if x.ndim != 3 or x.shape[0] != self.slots \
+                or n_valid.shape != (self.slots,):
+            raise ValueError(
+                f"x {x.shape} / n_valid {n_valid.shape}: want "
+                f"({self.slots}, t, C) and ({self.slots},)")
+        t = int(x.shape[1])
+        if n_valid.min() < 0 or n_valid.max() > t:
+            raise ValueError(f"n_valid must lie in [0, {t}]")
+        live = n_valid > 0
+        if live.any() and int((self.slot_pos + n_valid)[live].max()) \
+                > self.capacity:
+            raise ValueError(
+                f"slot overflow: a step ends at pos "
+                f"{int((self.slot_pos + n_valid)[live].max())} with "
+                f"capacity {self.capacity}")
+        return t, live
+
     def step_chunk(self, x, n_valid):
         """One device step that feeds slot ``s`` its next
         ``n_valid[s]`` tokens, the rows ``x[s, :n_valid[s]]`` of a
@@ -927,27 +982,11 @@ class PagedSlotSession:
         import jax.numpy as jnp
         x = jnp.asarray(x)
         n_valid = np.asarray(n_valid, np.int32)
-        if x.ndim != 3 or x.shape[0] != self.slots \
-                or n_valid.shape != (self.slots,):
-            raise ValueError(
-                f"x {x.shape} / n_valid {n_valid.shape}: want "
-                f"({self.slots}, t, C) and ({self.slots},)")
-        t = int(x.shape[1])
-        if n_valid.min() < 0 or n_valid.max() > t:
-            raise ValueError(f"n_valid must lie in [0, {t}]")
+        t, live = self._check_rows(x, n_valid)
         if not self.chunkable:
-            raise ValueError(
-                "this network has a layer that is not pointwise in "
-                "time beside its caches; feed it through step_slots")
-        live = n_valid > 0
-        if live.any() and int((self.slot_pos + n_valid)[live].max()) \
-                > self.capacity:
-            raise ValueError(
-                f"slot overflow: a chunk ends at pos "
-                f"{int((self.slot_pos + n_valid)[live].max())} with "
-                f"capacity {self.capacity}")
+            raise ValueError(_NOT_CHUNKABLE + "step_slots")
         if self._step is None:
-            self._step = self._make_step()
+            self._make_step()
         pos = np.where(live, self.slot_pos, 0).astype(np.int32)
         out = self._step(self.net.params, self.net.state, self._pools,
                          jnp.asarray(self._table), jnp.asarray(pos), x,
@@ -959,6 +998,52 @@ class PagedSlotSession:
         self.slot_pos = self.slot_pos + n_valid
         self._note_kv_read(t, pos + n_valid)
         return h
+
+    def step_ids(self, x, n_valid, use_prev):
+        """The step of :meth:`step_slots` (``x`` (slots, 1, 1)) or
+        :meth:`step_chunk` (``x`` (slots, t, 1)) with the greedy pick
+        made on the device: returns ``(ids, finite)``, both (slots,)
+        and both still on the device, in place of the (slots, 1, V)
+        rows. ``ids[s]`` is the argmax of slot ``s``'s last valid row
+        (the first index on ties) and ``finite[s]`` says that every
+        value of that row is finite: an id whose flag is false is
+        worth nothing. A slot in ``use_prev`` feeds, as row 0, the id
+        the PREVIOUS call of this method picked for it, whether or
+        not anyone fetched it; every other row is ``x``'s. ``n_valid``
+        is :meth:`step_chunk`'s at either width (1 or 0 a slot at
+        t = 1). Pools, ``slot_pos`` and ``step_aux`` move as under the
+        row-returning calls."""
+        x = np.asarray(x, np.float32)
+        n_valid = np.asarray(n_valid, np.int32)
+        use_prev = np.asarray(use_prev, bool)
+        t, live = self._check_rows(x, n_valid)
+        if x.shape[2] != 1 or use_prev.shape != (self.slots,):
+            raise ValueError(
+                f"x {x.shape} / use_prev {use_prev.shape}: want "
+                f"({self.slots}, t, 1) token ids and ({self.slots},)")
+        if t > 1 and not self.chunkable:
+            raise ValueError(_NOT_CHUNKABLE + "one row a step")
+        if self._step_ids is None:
+            self._make_step()
+        pos = np.where(live, self.slot_pos, 0).astype(np.int32)
+        # the single-token program trusts the table (``step_slots``):
+        # a slot that sits the step out gets the all-zero row. A COPY
+        # either way: the call returns before the device has read its
+        # operands, and ``release`` / ``bind`` write ``_table`` in
+        # place while the step is still in flight
+        table = self._table.copy() if t > 1 else np.where(
+            live[:, None], self._table, 0)
+        out = self._step_ids(self.net.params, self.net.state,
+                             self._pools, table, pos, x, n_valid,
+                             self._prev_ids, use_prev)
+        if self._aux_layers:
+            ids, finite, self._pools, self.step_aux = out
+        else:
+            ids, finite, self._pools = out
+        self._prev_ids = ids
+        self.slot_pos = self.slot_pos + n_valid
+        self._note_kv_read(t, pos + (n_valid if t > 1 else 1))
+        return ids, finite
 
     def reinit_states(self) -> None:
         """Post-crash recovery: the jitted step donates the pools, so

@@ -21,11 +21,24 @@ token.
 Admission control mirrors the scheduler (the shared
 ``serving/lifecycle.py`` plumbing): bounded queue with
 ``QueueFullError`` shed, per-request deadline checked while queued,
-graceful drain. Sampling happens host-side per step (greedy or
-temperature with a per-request seeded RNG), which keeps per-request
-sampling parameters out of the compiled program; each slot's logits
-are bitwise independent of its neighbours (vmapped B=1 math —
-slot-reuse parity against a sequential decode is tested).
+graceful drain. Each slot's logits are bitwise independent of its
+neighbours (vmapped B=1 math — slot-reuse parity against a sequential
+decode is tested).
+
+Over a paged session the loop runs ONE STEP AHEAD of the device.
+Nothing it schedules depends on a token's value (a request ends
+after ``n_tokens`` tokens, prompts are known, pages are reserved at
+admission), so the step picks each slot's greedy id on the device
+(``PagedSlotSession.step_ids``), step n+1 is enqueued feeding those
+ids where they lie, and the host fetches step n's ids and their
+finite flags, a few hundred bytes, while step n+1 runs: it schedules
+ahead from counts and delivers tokens one pass behind. Temperature
+sampling stays host-side with a per-request seeded RNG over the full
+probability rows (``step_slots`` / ``step_chunk``), which keeps
+per-request sampling parameters out of the compiled program: a step
+in which such a request emits is taken synchronously, as is every
+step under a drain, an armed migration or a prefill export reaching
+its export point, and every step of the dense session.
 
 Since the decode-fast-path PR the KV state behind the slots is PAGED
 by default (``kv_mode="auto"``): transformer-style models get a
@@ -73,6 +86,9 @@ __all__ = ["ContinuousBatcher", "MigrationOffer"]
 # 512; 64 slots, where nearly every step has some slot in prefill,
 # gain at 128 and lose at 256 and 512.
 CHUNK_ROWS = 128
+
+_NON_FINITE = ("non-finite probabilities in decode step (device fault "
+               "or poisoned model output)")
 
 
 def chunk_width(slots: int, capacity: int) -> int:
@@ -154,20 +170,28 @@ class _GenRequest(BaseRequest):
 
 
 class _Slot:
-    __slots__ = ("req", "feed", "prompt_left", "out", "rng",
-                 "t_slotted", "t_last_token", "prefix_hit", "parked",
-                 "no_migrate")
+    __slots__ = ("req", "feed", "prompt_left", "out", "emitted",
+                 "rng", "t_slotted", "t_last_token", "prefix_hit",
+                 "parked", "no_migrate")
 
     def __init__(self, req: _GenRequest, resume: int = 0):
         # ``resume``: prompt positions [0, resume) are already in the
         # KV cache (a prefix-cache hit) — prefill starts at the
         # resume token instead of token 0
         self.req = req
-        self.feed = int(req.prompt[resume])
+        # the token the next step's row 0 feeds; None once the stream
+        # decodes: its last emitted token, ``out[-1]`` when that step
+        # was delivered, else the id the step in flight left on the
+        # device
+        self.feed: Optional[int] = int(req.prompt[resume])
         self.prompt_left = list(int(t)
                                 for t in req.prompt[resume + 1:])
         self.prefix_hit = int(resume)
+        # ``emitted`` counts the tokens SCHEDULED (a step that emits
+        # one was enqueued), ``out`` holds those delivered: the
+        # scheduler runs on the count, one step ahead of the values
         self.out: List[int] = []
+        self.emitted = 0
         self.rng = (np.random.default_rng(req.seed)
                     if req.temperature > 0 else None)
         self.t_slotted = time.monotonic()
@@ -194,14 +218,43 @@ class _Slot:
         if out:
             s = cls(req, resume=len(req.prompt) - 1)
             s.prompt_left = []
-            s.feed = out[-1]
+            s.feed = None
             s.out = out
+            s.emitted = len(out)
         else:
             s = cls(req, resume=pos)
         s.prefix_hit = int(pos)
         if rng_state is not None and s.rng is not None:
             s.rng.bit_generator.state = rng_state
         return s
+
+
+class _Step:
+    """One device step from its planning to its delivery. ``live``
+    and ``emitters`` hold (row, ``_Slot``) and not slot indices: by
+    the time an enqueued step's ids reach the host, a slot whose
+    stream it finished may already belong to the next request."""
+
+    __slots__ = ("x", "n_valid", "use_prev", "live", "emitters",
+                 "sync", "rows", "poison", "result", "n_prompt",
+                 "prompt_tokens")
+
+    def __init__(self, x, n_valid, use_prev, live):
+        self.x, self.n_valid, self.use_prev = x, n_valid, use_prev
+        self.live = live
+        # the live slots whose fed rows end with their prompt's last
+        # token or the token they decoded last: each emits one token
+        self.emitters: list = []
+        # collect the step in flight first, and deliver this one in
+        # the pass that enqueues it
+        self.sync = False
+        # some emitter samples from its whole probability row
+        self.rows = False
+        self.poison = False
+        # what the enqueue returned, unfetched: (ids, finite, aux), or
+        # (rows, aux) through the row-returning entry points
+        self.result = None
+        self.n_prompt = self.prompt_tokens = 0
 
 
 class ContinuousBatcher(ServingBackend):
@@ -276,6 +329,10 @@ class ContinuousBatcher(ServingBackend):
                          if getattr(self.session, "chunkable", False)
                          else 1)
         self._warmed = False
+        # the step whose ids are still on the device: enqueued and
+        # scheduled past, not yet delivered (``_loop``). At most one.
+        self._inflight: Optional[_Step] = None
+        self._collected = [0.0, 0.0]
         self.version = version
         # registry identity (the MODEL name, not the backend name):
         # exported leases carry it so an importing replica can
@@ -755,9 +812,7 @@ class ContinuousBatcher(ServingBackend):
             # np.argmax over an all-NaN row silently returns 0 — a
             # poisoned/diverged decode step must fail THIS request
             # loudly, not stream token 0 with a 200
-            raise ValueError(
-                "non-finite probabilities in decode step (device "
-                "fault or poisoned model output)")
+            raise ValueError(_NON_FINITE)
         if slot.req.temperature <= 0:
             return int(np.argmax(probs))
         logits = np.log(probs + 1e-9) / slot.req.temperature
@@ -887,7 +942,10 @@ class ContinuousBatcher(ServingBackend):
                 s.no_migrate = True
                 with self._migrate_lock:
                     self._parked.pop(handle, None)
-        if self._migrate.is_set():
+        # an offer exports ``out``: none is made while a step's ids are
+        # still on the device (a migration armed after ``_gather_step``
+        # looked makes the next step synchronous and is offered then)
+        if self._migrate.is_set() and self._inflight is None:
             for i, s in enumerate(self._slots):
                 if s is not None and not s.parked \
                         and not s.no_migrate \
@@ -964,28 +1022,36 @@ class ContinuousBatcher(ServingBackend):
                     self.session.prefix_cache.fingerprints(limit)}
 
     def _loop(self) -> None:
-        """One pass per device step. Its three parts are timed on
-        every step into ``serving_step_seconds`` (admit / device /
-        sample) and, while the tracer is on, recorded as
-        ``serve_step/<part>`` spans under one ``serve_step``; a pass
-        that found no live slot leaves neither."""
-        import jax
+        """One pass per device step, and over a paged session one
+        step AHEAD of the device: a pass plans step n+1 from counts
+        (``_gather_step``), enqueues it, moves the slots on
+        (``_advance``), and only then fetches and delivers step n
+        (``_fetch``, ``_deliver``), whose ids step n+1 was fed on the
+        device. A step that must be synchronous (``_Step.sync``) has
+        the step in flight collected first and is delivered in its own
+        pass. The parts are timed on every step into
+        ``serving_step_seconds``: ``admit`` is the scheduling (both
+        halves, before and after the enqueue), ``device`` the enqueue
+        and the wait for the ids that are due, ``sample`` their
+        delivery; while the tracer is on they are ``serve_step/<part>``
+        spans under one ``serve_step`` (the few lines of ``_advance``
+        run inside ``serve_step/device``). A pass that found no live
+        slot leaves neither."""
         while not self._stop.is_set():
-            if not self._pending and not any(
+            if self._inflight is None and not self._pending and not any(
                     s is not None and not s.parked for s in self._slots):
                 # idle: wait for a request outside any step, so that
                 # ``admit`` times work and never the wait
                 self._pump(block=True)
             with trace.span("serve_step", annotate=False) as step:
                 t0 = time.perf_counter()
+                self._collected = [0.0, 0.0]
                 with trace.span("serve_step/admit") as admit:
-                    fed = self._gather_step()
-                    if fed is None:
+                    st = self._gather_step()
+                    if st is None:
                         admit.discard()
                         step.discard()
                         continue
-                x, n_valid = fed
-                chunk = x.shape[1] > 1
                 # chaos site: crash kills the worker (active streams
                 # fail with the crash error, the loop restarts), hang
                 # stalls a step, poison NaNs this step's logits (each
@@ -995,74 +1061,63 @@ class ContinuousBatcher(ServingBackend):
                 except BaseException as e:
                     self._fail_active(e)
                     raise
+                st.poison = fault is not None and fault.kind == "poison"
+                prev = self._inflight
                 t1 = time.perf_counter()
                 with trace.span("serve_step/device"):
                     try:
-                        if chunk:
-                            h = self.session.step_chunk(x, n_valid)
-                        else:
-                            h = self.session.step_slots(x, n_valid > 0)
-                        aux = getattr(self.session, "step_aux", None)
-                        if aux is None:
-                            h = np.asarray(h)
-                        else:
-                            # an expert layer's counts come back
-                            # with the logits, in one transfer
-                            h, aux = jax.device_get((h, aux))
+                        self._enqueue_step(st)
+                        t_adv = time.perf_counter()
+                        self._advance(st)
+                        t_adv = time.perf_counter() - t_adv
+                        self._inflight = None if st.sync else st
+                        due = st if st.sync else prev
+                        got = None if due is None else self._fetch(due)
                     except BaseException as e:
-                        # a failed device step poisons every active
-                        # stream — deliver the error, recycle the
-                        # slots, and REBUILD the session carries: the
-                        # jitted step donates them, so after a
-                        # mid-call failure the old buffers may already
-                        # be deleted and every later step would die
-                        # with them
-                        self._fail_active(e)
-                        try:
-                            self.session.reinit_states()
-                        except BaseException:
-                            pass  # next step surfaces a persistent fault
+                        self._device_failed(e, st, prev)
                         continue
                 t2 = time.perf_counter()
-                if fault is not None and fault.kind == "poison":
-                    h = np.full_like(h, np.nan)
-                n_active = int((n_valid > 0).sum())
-                self._occupancy.record(n_active)
                 with trace.span("serve_step/sample"):
-                    n_prompt, n_decode, prompt_tokens = \
-                        self._consume_step(h, n_valid)
-                self._steps.record(t1 - t0, t2 - t1,
-                                   time.perf_counter() - t2,
-                                   n_prompt, n_decode,
-                                   "chunk" if chunk else "single",
-                                   prompt_tokens)
-                if aux is not None:
-                    self._steps.record_experts(aux)
+                    if due is not None:
+                        self._deliver(due, got)
+                t3 = time.perf_counter()
+                chunk = st.x.shape[1] > 1
+                fetched, delivered = self._collected
+                self._occupancy.record(len(st.live))
+                self._steps.record(
+                    t1 - t0 - fetched - delivered + t_adv,
+                    t2 - t1 - t_adv + fetched, t3 - t2 + delivered,
+                    st.n_prompt, len(st.emitters),
+                    "chunk" if chunk else "single", st.prompt_tokens,
+                    ahead=prev is not None)
                 if self._paged:
                     self._steps.record_kv_positions(
                         *self.session.step_kv_positions)
-                step.set("active", n_active)
-                step.set("prompt_slots", n_prompt)
-                step.set("decode_slots", n_decode)
-                step.set("rows", int(x.shape[1]))
-                step.set("prompt_tokens", prompt_tokens)
+                step.set("active", len(st.live))
+                step.set("prompt_slots", st.n_prompt)
+                step.set("decode_slots", len(st.emitters))
+                step.set("rows", int(st.x.shape[1]))
+                step.set("prompt_tokens", st.prompt_tokens)
+                step.set("ahead", prev is not None)
 
     def _warm_programs(self) -> None:
         """Compile (or load) both widths of the paged step ahead of
         the first step that feeds a token, on a batch whose slots all
         sit the step out (nothing but the scratch page is written):
         which program a step runs depends on who is in the pool, and
-        neither may compile under live traffic. A batcher without the
-        chunk program compiles its one step at the first request, as
-        before."""
+        neither may compile under live traffic. These are the
+        id-returning programs every greedy step runs; the
+        row-returning pair compiles when first asked for (a request
+        with a temperature). A batcher without the chunk program
+        compiles its one step at the first request, as before."""
         self._warmed = True
         if self._chunk_t == 1:
             return
         x = np.zeros((self.slots, self._chunk_t, 1), np.float32)
         idle = np.zeros((self.slots,), np.int32)
         try:
-            self.session.step_chunk(x, idle)
-            self.session.step_slots(x[:, :1], idle > 0)
+            self.session.step_ids(x, idle, idle > 0)
+            self.session.step_ids(x[:, :1], idle, idle > 0)
         except BaseException:
             # the step donates the pools: rebuild them, and let the
             # first real step surface a persistent fault to its
@@ -1072,9 +1127,18 @@ class ContinuousBatcher(ServingBackend):
             except BaseException:
                 pass
 
-    def _fail_active(self, e: BaseException) -> None:
-        """Deliver ``e`` to every slotted stream and recycle the
-        slots."""
+    def _fail_active(self, e: BaseException, *steps) -> None:
+        """Deliver ``e`` to every slotted stream, and to every stream
+        that left its slot with a token still owed it by a step not
+        yet delivered (the one in flight, and ``steps``), and recycle
+        the slots."""
+        for st in steps + (self._inflight,):
+            for i, s in (st.emitters if st is not None else ()):
+                if self._slots[i] is not s and not s.req.event.is_set():
+                    self._endpoint.count_error()
+                    s.req.error = e
+                    s.req.event.set()
+        self._inflight = None
         for i, s in enumerate(self._slots):
             if s is not None:
                 self._endpoint.count_error()
@@ -1082,22 +1146,40 @@ class ContinuousBatcher(ServingBackend):
                 s.req.event.set()
                 self._release_slot(i)
 
-    def _gather_step(self):
+    def _device_failed(self, e: BaseException, *steps) -> None:
+        """A failed device step poisons every active stream and both
+        steps that may be in flight: deliver the error, recycle the
+        slots, and REBUILD the session carries: the jitted step
+        donates them, so after a mid-call failure the old buffers may
+        already be deleted and every later step would die with
+        them."""
+        self._fail_active(e, *steps)
+        try:
+            self.session.reinit_states()
+        except BaseException:
+            pass  # next step surfaces a persistent fault
+
+    def _gather_step(self) -> Optional[_Step]:
         """Everything between two device steps: migration service,
-        queue pump, deadline expiry, admission, and the tokens each
-        slot feeds. ``(x, n_valid)``, or None when no slot is live:
-        ``x`` is (slots, rows, 1) and slot ``i`` feeds its first
-        ``n_valid[i]`` rows (0: free or parked). ``rows`` is the chunk
-        width when some live slot has prompt tokens beyond its
-        ``feed``, else 1: a pool that only decodes runs the
-        single-token program."""
+        queue pump, deadline expiry, admission, and the plan of the
+        next step (``_plan_step``), or None when no slot is live. The
+        step in flight is collected first where what follows needs
+        its tokens on the host: before a migration is serviced or a
+        drain goes on (the offers export ``out``), before a
+        synchronous step, and when the pool ran empty."""
+        if self._inflight is not None and (
+                self._migrate.is_set() or self._parked
+                or self._draining.is_set()):
+            self._collect()
         self._service_migration()
         self._pump(block=False)
         self._expire_pending()
         self._admit()
-        live = [(i, s) for i, s in enumerate(self._slots)
-                if s is not None and not s.parked]
-        if not live:
+        st = self._plan_step()
+        if self._inflight is not None and (st is None or st.sync):
+            self._collect()
+            st = self._plan_step()
+        if st is None:
             if (self._draining.is_set() and self._queue.empty()
                     and not self._pending
                     and not any(s is not None for s in self._slots)):
@@ -1107,42 +1189,88 @@ class ContinuousBatcher(ServingBackend):
             return None
         if not self._warmed:
             self._warm_programs()
+        return st
+
+    def _plan_step(self) -> Optional[_Step]:
+        """The next device step, from the slots as they stand and
+        without touching them: ``x`` is (slots, rows, 1) and slot ``i``
+        feeds its first ``n_valid[i]`` rows (0: free or parked).
+        ``rows`` is the chunk width when some live slot has prompt
+        tokens beyond its ``feed``, else 1: a pool that only decodes
+        runs the single-token program. A decoding slot feeds its last
+        token: from ``out`` where it was delivered, else by
+        ``use_prev`` from the ids the step in flight leaves on the
+        device."""
+        live = [(i, s) for i, s in enumerate(self._slots)
+                if s is not None and not s.parked]
+        if not live:
+            return None
         rows = self._chunk_t if any(s.prompt_left
                                     for _, s in live) else 1
-        x = np.zeros((self.slots, rows, 1), np.float32)
-        n_valid = np.zeros((self.slots,), np.int32)
+        st = _Step(np.zeros((self.slots, rows, 1), np.float32),
+                   np.zeros((self.slots,), np.int32),
+                   np.zeros((self.slots,), bool), live)
+        st.rows = not self._paged
+        st.sync = (not self._paged or self._draining.is_set()
+                   or self._migrate.is_set() or bool(self._parked))
         for i, s in live:
             # a prefill-only request stops one token short: its
             # export point is every prompt position but the last
             n = min(rows, 1 + len(s.prompt_left)
                     - int(s.req.prefill_export))
-            x[i, 0, 0] = s.feed
-            x[i, 1:n, 0] = s.prompt_left[:n - 1]
-            n_valid[i] = n
-        return x, n_valid
+            if s.feed is not None:
+                st.x[i, 0, 0] = s.feed
+            elif len(s.out) == s.emitted:
+                st.x[i, 0, 0] = s.out[-1]
+            else:
+                st.use_prev[i] = True
+            st.x[i, 1:n, 0] = s.prompt_left[:n - 1]
+            st.n_valid[i] = n
+            if n > len(s.prompt_left):
+                st.emitters.append((i, s))
+                if s.req.temperature > 0:
+                    # its seeded NumPy stream samples from the whole
+                    # row, on the host and in this pass
+                    st.rows = st.sync = True
+            elif n == len(s.prompt_left) and s.req.prefill_export:
+                st.sync = True      # reaches its export point
+        return st
 
-    def _consume_step(self, h: np.ndarray, n_valid: np.ndarray):
-        """The host's turn after a device step: a live slot whose
-        fed rows ended inside its prompt drops them (the step's
-        output discarded); one whose rows carried the prompt's last
-        token, or its last sampled token, samples from its row of
-        ``h`` and emits. Returns how many slots did which, and how
-        many prompt tokens the step fed."""
-        n_prompt = n_decode = prompt_tokens = 0
-        for i, s in enumerate(self._slots):
-            n = int(n_valid[i])
-            if s is None or n == 0:
-                # a parked slot was not stepped: its stream must
-                # resume exactly where it was offered
-                continue
-            if not s.out:
-                prompt_tokens += n
+    def _enqueue_step(self, st: _Step) -> None:
+        """Hand the planned step to the device; nothing is waited
+        for. Greedy steps of a paged session return ids, any other
+        the probability rows."""
+        sess = self.session
+        if st.rows:
+            if st.x.shape[1] > 1:
+                h = sess.step_chunk(st.x, st.n_valid)
+            else:
+                h = sess.step_slots(st.x, st.n_valid > 0)
+            # an expert layer's counts come back with the rows, in
+            # one transfer
+            st.result = (h, getattr(sess, "step_aux", None))
+        else:
+            st.result = sess.step_ids(st.x, st.n_valid, st.use_prev) \
+                + (sess.step_aux,)
+
+    def _advance(self, st: _Step) -> None:
+        """Move the slots past an enqueued step, from counts alone:
+        a slot whose fed rows ended inside its prompt drops them; one
+        whose rows carried its prompt's last token, or its last
+        emitted token, has one more token scheduled, and at
+        ``n_tokens`` of them its stream is over: the slot is
+        recycled now, for the next admission, and the token itself
+        arrives with ``_deliver``."""
+        for i, s in st.live:
+            n = int(st.n_valid[i])
+            if not s.emitted:
+                st.prompt_tokens += n
             if n <= len(s.prompt_left):
                 # still prefilling: the next step starts at the
                 # first prompt token this one did not feed
                 s.feed = s.prompt_left[n - 1]
                 del s.prompt_left[:n]
-                n_prompt += 1
+                st.n_prompt += 1
                 if not s.prompt_left and s.req.prefill_export:
                     # the export point: every prompt position
                     # except the last is in the KV cache — the
@@ -1151,21 +1279,82 @@ class ContinuousBatcher(ServingBackend):
                     self._finish_prefill_export(i, s)
                 continue
             s.prompt_left = []
+            s.feed = None
+            s.emitted += 1
+            if s.emitted >= s.req.n_tokens:
+                # a stream that ran to its end donates its
+                # full-prompt pages to the prefix cache
+                self._release_slot(i, register=True)
+
+    def _fetch(self, st: _Step):
+        """Wait for an enqueued step and bring back what the host
+        reads of it: ids and finite flags, or the probability rows,
+        and an expert layer's counts, in one transfer."""
+        import jax
+        got = jax.device_get(st.result)
+        st.result = None
+        return got
+
+    def _collect(self) -> None:
+        """Fetch and deliver the step in flight outside the pass that
+        would have, timed into that pass's ``device`` and ``sample``
+        parts."""
+        st, self._inflight = self._inflight, None
+        t0 = time.perf_counter()
+        with trace.span("serve_step/device"):
             try:
-                nxt = self._sample(h[i, 0], s)
+                got = self._fetch(st)
             except BaseException as e:
-                # per-slot host-side failure (e.g. NaN output
-                # probabilities under temperature sampling) fails
+                self._device_failed(e, st)
+                return
+        t1 = time.perf_counter()
+        with trace.span("serve_step/sample"):
+            self._deliver(st, got)
+        self._collected[0] += t1 - t0
+        self._collected[1] += time.perf_counter() - t1
+
+    def _deliver(self, st: _Step, got) -> None:
+        """A step's tokens, on the host at last: each emitter's id is
+        appended to its stream with the TTFT / ITL stamps and phase
+        marks, and a stream that has its ``n_tokens`` completes. An
+        emitter whose row was not finite fails alone; one that an
+        earlier delivery already failed is passed over."""
+        if st.rows:
+            (h, aux), ids, finite = got, None, None
+            if st.poison:
+                h = np.full_like(h, np.nan)
+        else:
+            ids, finite, aux = got
+            if st.poison:
+                finite = np.zeros_like(finite)
+        for i, s in st.emitters:
+            req = s.req
+            if req.event.is_set():
+                continue
+            try:
+                if st.rows:
+                    nxt = self._sample(h[i, 0], s)
+                elif finite[i]:
+                    nxt = int(ids[i])
+                else:
+                    raise ValueError(_NON_FINITE)
+            except BaseException as e:
+                # per-slot failure (NaN output probabilities) fails
                 # only this request — never the worker
                 self._endpoint.count_error()
-                s.req.error = e
-                s.req.event.set()
-                self._release_slot(i)
+                req.error = e
+                req.event.set()
+                if self._slots[i] is s:
+                    self._release_slot(i)
+                elif self._paged:
+                    # ``_advance`` took the stream for finished and
+                    # donated its prompt's pages: nothing a faulted
+                    # stream wrote may be served to a later prompt
+                    self.session.prefix_cache.clear()
                 continue
             s.out.append(nxt)
-            n_decode += 1
             now_t = time.monotonic()
-            ctx = s.req.ctx
+            ctx = req.ctx
             tid = (ctx.trace_id
                    if ctx is not None and ctx.sampled else None)
             if len(s.out) == 1:
@@ -1178,28 +1367,23 @@ class ContinuousBatcher(ServingBackend):
                 if ctx is not None:
                     ctx.phase_done("prefill", now_in="decode")
                 self._stream.record_ttft(
-                    now_t - s.req.t_submit, trace_id=tid,
+                    now_t - req.t_submit, trace_id=tid,
                     prefix_hit=s.prefix_hit > 0)
             elif s.t_last_token is not None:
                 self._stream.record_itl(
                     now_t - s.t_last_token, trace_id=tid)
             s.t_last_token = now_t
-            if len(s.out) >= s.req.n_tokens:
-                s.req.result = np.asarray(s.out, np.int64)
+            if len(s.out) >= req.n_tokens:
+                req.result = np.asarray(s.out, np.int64)
                 if ctx is not None:
                     # decode segment closes BEFORE the event: the
                     # waiter's respond stamp must come after
                     ctx.phase_done(
                         "decode", now_in="respond",
                         attrs={"tokens": len(s.out)})
-                s.req.event.set()
-                # slot recycled next admit; a cleanly-finished
-                # stream donates its full-prompt pages to the
-                # prefix cache
-                self._release_slot(i, register=True)
-            else:
-                s.feed = nxt
-        return n_prompt, n_decode, prompt_tokens
+                req.event.set()
+        if aux is not None:
+            self._steps.record_experts(aux)
 
     def slots_debug(self) -> List[dict]:
         """Per-slot state for ``/debug/slots``: what each KV-cache
@@ -1251,6 +1435,12 @@ class ContinuousBatcher(ServingBackend):
         # released HERE (host-side bookkeeping, safe in the crash
         # handler) so refcounts cannot leak across a worker restart
         casualties = []
+        st, self._inflight = self._inflight, None
+        if st is not None:
+            # streams that left their slot with the step in flight
+            # still owing them their last token
+            casualties.extend(s.req for i, s in st.emitters
+                              if self._slots[i] is not s)
         for i, s in enumerate(self._slots):
             if s is not None:
                 casualties.append(s.req)

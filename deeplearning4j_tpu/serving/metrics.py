@@ -230,11 +230,16 @@ class BatchOccupancy:
 
 class BatcherStepMetrics:
     """One continuous-batcher step, seen from inside its loop:
-    ``serving_step_seconds{part}`` splits the step's wall time into
-    ``admit`` (migration service, queue pump, expiry, admission,
-    building the fed tokens), ``device`` (``step_slots`` through the
-    logits' arrival on the host) and ``sample`` (the per-slot loop:
-    sampling, bookkeeping, waking waiters);
+    ``serving_step_seconds{part}`` splits a pass's wall time into
+    ``admit`` (the scheduling: migration service, queue pump, expiry,
+    admission, planning the step and moving the slots past it),
+    ``device`` (the step's enqueue and the wait for the ids that are
+    due: the previous step's where the loop runs one step ahead, the
+    step's own in a synchronous pass) and ``sample`` (their
+    delivery: host sampling where a request has a temperature,
+    bookkeeping, waking waiters);
+    ``serving_lookahead_steps_total`` counts the steps enqueued while
+    the previous step's ids were not yet on the host;
     ``serving_slot_steps_total{kind}`` counts what each live slot did
     with the step: ``prompt`` (fed prompt tokens only, output
     discarded) or ``decode`` (emitted a token: a chunk that carried
@@ -282,10 +287,16 @@ class BatcherStepMetrics:
             "serving_prompt_tokens_total",
             help="prompt tokens fed to the device",
             labels={"endpoint": name})
+        self._ahead = reg.counter(
+            "serving_lookahead_steps_total",
+            help="device steps enqueued while the previous step's "
+                 "ids were not yet on the host",
+            labels={"endpoint": name})
 
     def record(self, admit_s: float, device_s: float, sample_s: float,
                prompt_slots: int, decode_slots: int,
-               program: str = "single", prompt_tokens: int = 0) -> None:
+               program: str = "single", prompt_tokens: int = 0,
+               ahead: bool = False) -> None:
         self._parts["admit"].record(admit_s)
         self._parts["device"].record(device_s)
         self._parts["sample"].record(sample_s)
@@ -293,6 +304,8 @@ class BatcherStepMetrics:
         self._kinds["decode"].inc(decode_slots)
         self._programs[program].inc()
         self._prompt_tokens.inc(prompt_tokens)
+        if ahead:
+            self._ahead.inc()
 
     def record_kv_positions(self, read: int, spanned: int) -> None:
         """One step's KV positions over a paged pool

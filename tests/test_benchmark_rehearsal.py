@@ -14,6 +14,13 @@ Three contracts a cell:
   ``program_counter``). The ``device_trace`` readers need the TPU's
   "XLA Ops" plane and are the chip's to show.
 
+A fourth, for the serving cells: a served token altered where it is
+produced comes out not ``correct``. Since PR 32 a greedy token is
+produced on the device and reaches the host in ``_fetch``;
+``benchmark/tests/test_cells_cpu.py`` still alters ``_sample``, which
+only a request with a temperature passes now (a ``benchmark`` issue's
+to move: PERF.md section 7), so the contract is held here.
+
 ``python -m pytest benchmark/tests -q`` remains the fuller hand run
 (controls, broken steps, the recorded trace). Nothing of it is
 imported: its conftest sets the environment of its own process.
@@ -160,3 +167,31 @@ def test_program_fed_layer_metrics_come_back(cell, tiny, capsys):
     assert not missing, (missing, capsys.readouterr().out[-4000:])
     # the CPU's paged step gathers: it reads what its tables span
     assert got.get("kv_read_pct.serve", 100.0) == 100.0
+
+
+SERVE = [w["name"] for w in BENCH["workloads"]
+         if "serve_tokens_per_s" in {
+             m["name"] for m in BENCH["end_to_end"]
+             if w["name"] in m.get("workloads", [w["name"]])}]
+
+
+@pytest.mark.parametrize("cell", SERVE)
+def test_a_token_altered_where_it_is_produced_is_not_correct(
+        cell, tiny, capsys, monkeypatch):
+    def break_token(server):
+        from deeplearning4j_tpu.serving.continuous import (
+            ContinuousBatcher)
+        vocab = spec.load(cell).config["vocab_size"]
+        real = ContinuousBatcher._fetch
+
+        def off_by_one(self, st):
+            got = real(self, st)
+            if st.rows:
+                return got
+            return ((got[0] + 1) % vocab,) + tuple(got[1:])
+        monkeypatch.setattr(ContinuousBatcher, "_fetch", off_by_one)
+
+    r = bench_run.main(_args(cell), find_devices=_cpu_devices,
+                       break_token=break_token)
+    assert r["attempted"] > 0
+    assert r["correct"] is False, capsys.readouterr().out

@@ -272,17 +272,17 @@ def test_a_migration_offered_mid_prefill_resumes(net, four_rows,
     try:
         # arm the drain from inside the worker, after the prompt's
         # third chunk: the offer is then cut at position 12 of 30
-        step_chunk, calls = a.session.step_chunk, []
+        step_ids, calls = a.session.step_ids, []
 
-        def counted(x, n_valid):
-            h = step_chunk(x, n_valid)
-            if int(np.sum(n_valid)):
+        def counted(x, n_valid, use_prev):
+            out = step_ids(x, n_valid, use_prev)
+            if x.shape[1] > 1 and int(np.sum(n_valid)):
                 calls.append(int(np.sum(n_valid)))
                 if len(calls) == 3:
                     a.request_migration()
-            return h
+            return out
 
-        a.session.step_chunk = counted
+        a.session.step_ids = counted
         offer = a.wait(a.submit(prompt, 5))
         assert isinstance(offer, MigrationOffer)
         assert (offer.pos, offer.tokens_out) == (3 * T, 0)

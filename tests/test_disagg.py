@@ -81,13 +81,17 @@ class SlowLM:
     def paged_slot_streaming_session(self, **kw):
         s = self.net.paged_slot_streaming_session(**kw)
         if self.delay:
-            orig, d = s.step_slots, self.delay
+            d = self.delay
 
-            def slow(x, active):
-                time.sleep(d)
-                return orig(x, active)
+            def slowed(orig):
+                def slow(*a):
+                    time.sleep(d)
+                    return orig(*a)
+                return slow
 
-            s.step_slots = slow
+            # greedy steps take ``step_ids``, sampled ones the rows
+            s.step_slots = slowed(s.step_slots)
+            s.step_ids = slowed(s.step_ids)
         return s
 
     def slot_streaming_session(self, **kw):

@@ -636,6 +636,52 @@ class TestBatcherSteps:
         assert total["decode_slots"] == N_TOKENS * len(PROMPTS)
         assert total["active"] == _metric(
             snap, "serving_batch_items_total")
+        # ``ahead``: the step was enqueued while the one before it was
+        # still owed its ids; the counter counts the same steps. Six
+        # greedy requests through two slots keep the pool live, so
+        # only the first step (and one after the pool ran empty, had
+        # it) finds nothing in flight
+        ahead = [bool(s["args"]["ahead"]) for s in steps]
+        assert sum(ahead) == _metric(
+            snap, "serving_lookahead_steps_total")
+        assert not ahead[0] and sum(ahead) >= len(steps) - 2
+
+    def test_the_parts_say_schedule_wait_and_deliver(self):
+        """``admit`` is the scheduling on both sides of the enqueue
+        (the plan before it, the count-only advance after it),
+        ``device`` the enqueue and the wait for the ids that are due,
+        ``sample`` their delivery: a sleep planted in each lands in
+        its own part and in no other."""
+        from deeplearning4j_tpu.serving.continuous import (
+            ContinuousBatcher)
+        cb = ContinuousBatcher(_lm(), slots=2, capacity=LM_CAP,
+                               queue_limit=16)
+        advance, deliver = cb._advance, cb._deliver
+        step_ids = cb.session.step_ids
+
+        def slept(fn, seconds):
+            def slow(*a):
+                time.sleep(seconds)
+                return fn(*a)
+            return slow
+
+        cb._advance = slept(advance, 0.05)
+        cb._deliver = slept(deliver, 0.02)
+        cb.session.step_ids = slept(step_ids, 0.01)
+        assert len(cb.generate(PROMPTS[0], N_TOKENS)) == N_TOKENS
+        assert cb.drain()
+        snap = cb.metrics.registry.snapshot()
+        n = _metric(snap, "serving_batches_total")
+        assert n == -(-len(PROMPTS[0]) // CHUNK_T) + N_TOKENS - 1
+        parts = {p: _metric(snap, "serving_step_seconds", part=p)["sum"]
+                 for p in ("admit", "device", "sample")}
+        assert parts["admit"] >= 0.05 * n
+        # every step but the warm-up's pair went through the slowed
+        # entry point; the advance's sleep is not in this part
+        assert 0.01 * n <= parts["device"] < 0.05 * n
+        # the last step's ids are collected after the pool ran empty,
+        # in a pass that enqueues nothing and records no parts
+        assert 0.02 * (n - 1) <= parts["sample"] < 0.05 * n
 
     def test_evicting_the_endpoint_drops_the_step_series(self):
         from deeplearning4j_tpu.serving.metrics import ServingMetrics
