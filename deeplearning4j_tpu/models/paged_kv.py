@@ -45,6 +45,17 @@ vLLM-style paged memory model over the same layer math:
   for it as its first row, so a decode loop need not wait for one
   step's ids before it enqueues the next.
 
+Two kinds of cache live in one session. A layer whose attention sees
+every earlier position keeps it in the allocator's pages, named by
+the slot's table, as above. A layer with a sliding window
+(``ring_pages(page_size) > 0``) keeps a RING of that many pages a
+slot in a pool of its own: slot ``s`` owns pages ``1 + s * R ..
+(s + 1) * R`` for as long as it exists, position ``p`` lives at ring
+row ``p mod (R * page_size)``, and the layer tells by position which
+rows a query may see, so nothing is allocated, exhausted, shared or
+zeroed for this kind. A ring cannot be shared, so a network that has
+one takes no prefix hit and registers no prefix.
+
 Page id 0 is a reserved scratch page: a slot that sits a step out is
 given an all-zero page-table row, and the chunk step sends every row
 past a slot's ``n_valid`` there, so dummy writes land in scratch and
@@ -78,6 +89,13 @@ _NOT_CHUNKABLE = ("this network has a layer that is not pointwise in "
 
 def _pages_for(tokens: int, page_size: int) -> int:
     return -(-int(tokens) // int(page_size))
+
+
+def _no_paged_analog(layer) -> bool:
+    """Does ``layer`` carry state that no page holds (a recurrent
+    carry or a running statistic)?"""
+    return not hasattr(layer, "apply_stream_paged") and (
+        hasattr(layer, "zero_state") or hasattr(layer, "apply_stream"))
 
 
 def _params_dtype(net):
@@ -384,14 +402,18 @@ class _Lease:
     """One admitted stream's page reservation."""
 
     __slots__ = ("pages", "resume_pos", "prefix_hit_tokens",
-                 "prompt_len")
+                 "prompt_len", "ring_rows")
 
     def __init__(self, pages, resume_pos, prefix_hit_tokens,
-                 prompt_len):
+                 prompt_len, ring_rows=None):
         self.pages = pages                    # table order
         self.resume_pos = resume_pos          # first position to feed
         self.prefix_hit_tokens = prefix_hit_tokens
         self.prompt_len = prompt_len
+        # an imported lease's ring rows, {layer: [leaf rows]} in
+        # position order up to ``resume_pos``: a ring belongs to a
+        # slot, so they reach the device at ``bind``
+        self.ring_rows = ring_rows
 
 
 class PagedSlotSession:
@@ -403,9 +425,12 @@ class PagedSlotSession:
     ``capacity`` still bounds ONE request's prompt+generation length
     (it is the page-table width in tokens); memory is bounded by
     ``n_pages * page_size`` total. Supported layers: paged attention
-    (``apply_stream_paged``) and stateless layers — recurrent
-    carries (``zero_state``) and running statistics have no paged
-    analog; build the dense session for those models.
+    (``apply_stream_paged``) of either kind, in the allocator's pages
+    or, where the layer gives ``ring_pages(page_size) > 0``, in a
+    ring of that many pages a slot outside the allocator (module
+    docstring), and stateless layers — recurrent carries
+    (``zero_state``) and running statistics have no paged analog;
+    build the dense session for those models.
     """
 
     @staticmethod
@@ -416,21 +441,14 @@ class PagedSlotSession:
         ``kv_mode="auto"`` fallback keys on, so that REAL
         construction errors (bad page_size/n_pages) are never
         mistaken for an unsupported model."""
-        return not any(
-            not hasattr(layer, "apply_stream_paged")
-            and (hasattr(layer, "zero_state")
-                 or hasattr(layer, "apply_stream"))
-            for layer in net.layers)
+        return not any(_no_paged_analog(layer) for layer in net.layers)
 
     def __init__(self, net, slots: int, capacity: int,
                  page_size: int = 16, n_pages: Optional[int] = None,
                  dtype=None):
         import jax.numpy as jnp
         for i, layer in enumerate(net.layers):
-            if hasattr(layer, "apply_stream_paged"):
-                continue
-            if hasattr(layer, "zero_state") or hasattr(
-                    layer, "apply_stream"):
+            if _no_paged_analog(layer):
                 raise ValueError(
                     f"layer {i} ({type(layer).__name__}) carries "
                     "state with no paged analog (recurrent carry or "
@@ -455,6 +473,19 @@ class PagedSlotSession:
         self._table = np.zeros((self.slots, self.pages_per_slot),
                                np.int32)
         self._leases: Dict[int, _Lease] = {}
+        # pages of the ring a slot owns in each layer's pool; 0 for a
+        # layer in the allocator's pages and for one without a cache
+        self._ring = [
+            int(layer.ring_pages(self.page_size))
+            if hasattr(layer, "apply_stream_paged")
+            and hasattr(layer, "ring_pages") else 0
+            for layer in net.layers]
+        self._ring_sizes = sorted({r for r in self._ring if r})
+        # the widest step a slot may be fed: a ring has a page beyond
+        # its window, so up to ``page_size`` rows overwrite nothing a
+        # row of the same step reads
+        self.chunk_rows_max = (self.page_size if self._ring_sizes
+                               else self.capacity)
         self._pools = self._fresh_pools()
         # one jitted step: ``step_slots`` runs it at (slots, 1, C),
         # ``step_chunk`` at (slots, t, C), each shape its own program
@@ -496,16 +527,20 @@ class PagedSlotSession:
         # ``_note_kv_read``)
         self.step_kv_positions = (0, 0)
         self._by_table: Dict[int, bool] = {}
+        # the latest step's ring pages (held, full, overwritten), see
+        # ``_note_ring``; stays zero without a ring layer
+        self.step_ring_pages = (0, 0, 0)
 
     # ---- pools ----
     def _fresh_pools(self):
         pools = []
-        for layer in self.net.layers:
+        for layer, ring in zip(self.net.layers, self._ring):
             if hasattr(layer, "apply_stream_paged"):
                 # +1 physical row: page id 0 is the scratch page
                 pools.append(layer.zero_page_pool(
-                    self.allocator.n_pages + 1, self.page_size,
-                    self._dtype))
+                    (self.slots * ring if ring
+                     else self.allocator.n_pages) + 1,
+                    self.page_size, self._dtype))
             else:
                 pools.append(None)
         return pools
@@ -549,7 +584,10 @@ class PagedSlotSession:
                 f"prompt ({T0}) + n_tokens ({n_tokens}) exceeds the "
                 f"page-table width (capacity {self.capacity})")
         total_pages = _pages_for(T0 + int(n_tokens), self.page_size)
-        shared = self.prefix_cache.lookup(prompt)
+        # a hit would resume behind an empty window: wrong logits,
+        # not slow ones
+        shared = ([] if self._ring_sizes
+                  else self.prefix_cache.lookup(prompt))
         # the LAST prompt token must be re-fed to produce the first
         # output logits, so a hit can cover at most T0 - 1 positions
         resume = min(len(shared) * self.page_size, T0 - 1)
@@ -581,6 +619,10 @@ class PagedSlotSession:
         self._table[slot, :len(lease.pages)] = lease.pages
         self.slot_pos[slot] = lease.resume_pos
         self._leases[slot] = lease
+        if lease.ring_rows:
+            self._write_ring_rows(slot, lease.resume_pos,
+                                  lease.ring_rows)
+            lease.ring_rows = None
 
     def release(self, slot: int, register_prompt=None) -> None:
         """Recycle a slot: drop its page references; when the stream
@@ -591,7 +633,7 @@ class PagedSlotSession:
         self.slot_pos[slot] = 0
         if lease is None:
             return
-        if register_prompt is not None:
+        if register_prompt is not None and not self._ring_sizes:
             prompt = np.asarray(register_prompt).reshape(-1)
             n_full = prompt.size // self.page_size
             if n_full > 0:
@@ -611,7 +653,7 @@ class PagedSlotSession:
         and the boundary page may be half-written. Returns how many
         pages were registered."""
         lease = self._leases.get(slot)
-        if lease is None:
+        if lease is None or self._ring_sizes:
             return 0
         pos = int(self.slot_pos[slot])
         prompt = np.asarray(prompt).reshape(-1)
@@ -626,27 +668,55 @@ class PagedSlotSession:
     #      the stream (prompt, sampled tokens, rng) is the CALLER's
     #      ``extra`` dict, carried opaquely in the header ----
     def _pool_schema(self) -> List[Optional[List[dict]]]:
-        """Per-layer leaf schema (page-row shape + dtype) — what two
-        replicas must agree on for a lease to be portable. None for
-        stateless layers."""
+        """Per-layer leaf schema (page-row shape + dtype, and the
+        ring's pages for a layer that keeps one) — what two replicas
+        must agree on for a lease to be portable. None for stateless
+        layers."""
         import jax
         schema: List[Optional[List[dict]]] = []
-        for pool in self._pools:
+        for pool, ring in zip(self._pools, self._ring):
             if pool is None:
                 schema.append(None)
                 continue
             leaves = jax.tree_util.tree_leaves(pool)
-            schema.append([{"shape": list(leaf.shape[1:]),
-                            "dtype": str(leaf.dtype)}
+            kind = {"ring": ring} if ring else {}
+            schema.append([dict(shape=list(leaf.shape[1:]),
+                                dtype=str(leaf.dtype), **kind)
                            for leaf in leaves])
         return schema
+
+    def _ring_span(self, ring: int, pos: int) -> np.ndarray:
+        """The positions a ring of ``ring`` pages still holds of a
+        stream at ``pos``, oldest first."""
+        return np.arange(pos - min(pos, ring * self.page_size), pos)
+
+    def _ring_place(self, slot: int, ring: int, positions):
+        """(page ids, offsets) of ``positions`` in ``slot``'s ring."""
+        row = positions % (ring * self.page_size)
+        return (1 + slot * ring + row // self.page_size,
+                row % self.page_size)
+
+    def _write_ring_rows(self, slot: int, pos: int, ring_rows) -> None:
+        """Put an imported lease's ring rows (``{layer: [leaf rows in
+        position order up to pos]}``) into ``slot``'s rings."""
+        import jax
+        import jax.numpy as jnp
+        for i, rows in ring_rows.items():
+            pages, offs = self._ring_place(
+                slot, self._ring[i], self._ring_span(self._ring[i], pos))
+            leaves, treedef = jax.tree_util.tree_flatten(self._pools[i])
+            self._pools[i] = jax.tree_util.tree_unflatten(
+                treedef, [leaf.at[pages, offs].set(jnp.asarray(r))
+                          for leaf, r in zip(leaves, rows)])
 
     def export_lease(self, slot: int,
                      extra: Optional[dict] = None) -> bytes:
         """Serialize slot ``slot``'s attention state: a versioned
         header (wire version, page size, position, per-layer pool
         schema, the caller's ``extra``) followed by the raw contents
-        of every page the stream has written, CRC-tagged. The slot
+        of every page the stream has written and, of a layer that
+        keeps a ring, of the rows the slot's ring still holds, oldest
+        position first, CRC-tagged. The slot
         and its lease are left untouched — the caller decides
         whether the incumbent keeps decoding (failed handoff) or
         releases (acked migration). Device→host gather happens here,
@@ -660,10 +730,16 @@ class PagedSlotSession:
         pages_written = _pages_for(pos, self.page_size) if pos else 0
         page_ids = lease.pages[:pages_written]
         chunks: List[bytes] = []
-        for pool in self._pools:
+        for pool, ring in zip(self._pools, self._ring):
             if pool is None:
                 continue
             for leaf in jax.tree_util.tree_leaves(pool):
+                if ring:
+                    pages, offs = self._ring_place(
+                        slot, ring, self._ring_span(ring, pos))
+                    chunks.append(np.ascontiguousarray(
+                        np.asarray(leaf[pages, offs])).tobytes())
+                    continue
                 for pid in page_ids:
                     chunks.append(np.ascontiguousarray(
                         np.asarray(leaf[pid])).tobytes())
@@ -741,19 +817,35 @@ class PagedSlotSession:
             import jax.numpy as jnp
             n_leaf_rows = sum(len(s) for s in schema
                               if s is not None)
-            row_bytes = [_np_dtype(d["dtype"]).itemsize
-                         * int(np.prod(d["shape"]))
-                         for s in schema if s is not None
-                         for d in s]
-            expect = sum(b * pages_written for b in row_bytes)
+            # a page of a layer in the allocator's pages, a position
+            # of a layer that keeps a ring
+            expect = sum(
+                _np_dtype(d["dtype"]).itemsize
+                * (int(np.prod(d["shape"][1:]))
+                   * self._ring_span(d["ring"], pos).size
+                   if "ring" in d
+                   else int(np.prod(d["shape"])) * pages_written)
+                for s in schema if s is not None for d in s)
             if len(payload) != expect:
                 raise KVLeaseCorruptError(
                     f"lease payload is {len(payload)} bytes; schema "
                     f"demands {expect} ({n_leaf_rows} pool leaves x "
                     f"{pages_written} pages)")
             off = 0
+            ring_rows: Dict[int, list] = {}
             for i, pool in enumerate(self._pools):
                 if pool is None:
+                    continue
+                if self._ring[i]:
+                    n = self._ring_span(self._ring[i], pos).size
+                    for spec in schema[i]:
+                        dtype = _np_dtype(spec["dtype"])
+                        width = int(np.prod(spec["shape"][1:]))
+                        ring_rows.setdefault(i, []).append(
+                            np.frombuffer(payload, dtype=dtype,
+                                          count=n * width, offset=off
+                                          ).reshape(n, width))
+                        off += n * width * dtype.itemsize
                     continue
                 leaves, treedef = jax.tree_util.tree_flatten(pool)
                 new_leaves = []
@@ -776,7 +868,7 @@ class PagedSlotSession:
             self.allocator.decref(fresh)
             raise
         lease = _Lease(fresh, pos, prefix_hit_tokens=0,
-                       prompt_len=pos)
+                       prompt_len=pos, ring_rows=ring_rows)
         return lease, dict(header.get("extra") or {})
 
     # ---- device step ----
@@ -792,7 +884,8 @@ class PagedSlotSession:
         import jax.numpy as jnp
         d, s = jnp.int32(dst), jnp.int32(src)
         for i, pool in enumerate(self._pools):
-            if pool is not None:
+            # page ids are the allocator's: a ring has none of them
+            if pool is not None and not self._ring[i]:
                 self._pools[i] = self._copy_page(pool, d, s)
 
     def _make_step(self):
@@ -906,6 +999,30 @@ class PagedSlotSession:
                 * self.page_size
         self.step_kv_positions = (read, spanned)
 
+    def _note_ring(self, pos, n_valid) -> None:
+        """``step_ring_pages`` of a step that fed slot ``s`` the
+        positions ``pos[s] .. pos[s] + n_valid[s] - 1``, over the
+        slots it fed and once for each size of ring the network has
+        (its layers of one window are one kind): ``held``, the pages
+        of their rings that hold a position the ring still keeps;
+        ``full``, the pages the same slots would hold had that kind
+        kept every position; ``overwritten``, the ring pages a write
+        of this step began to reuse. Host arithmetic from the
+        positions, as ``_note_kv_read`` is."""
+        if not self._ring_sizes:
+            return
+        fed = n_valid > 0
+        start, end = pos[fed], (pos + n_valid)[fed]
+        ps = self.page_size
+        pages = lambda n: -(-n // ps)
+        held = full = over = 0
+        for ring in self._ring_sizes:
+            full += int(pages(end).sum())
+            held += int(np.minimum(pages(end), ring).sum())
+            over += int((pages(end) - pages(np.maximum(
+                start, ring * ps))).clip(min=0).sum())
+        self.step_ring_pages = (held, full, over)
+
     def step_slots(self, x, active):
         """One decode step for every slot at once — the
         ``SlotStreamingSession.step_slots`` contract: ``x`` is
@@ -945,6 +1062,7 @@ class PagedSlotSession:
         # a slot that sits the step out has length 1: its dummy row,
         # in the scratch page
         self._note_kv_read(1, pos + 1)
+        self._note_ring(pos, active.astype(np.int32))
         return h
 
     def _check_rows(self, x, n_valid):
@@ -997,6 +1115,7 @@ class PagedSlotSession:
             h, self._pools = out
         self.slot_pos = self.slot_pos + n_valid
         self._note_kv_read(t, pos + n_valid)
+        self._note_ring(pos, n_valid)
         return h
 
     def step_ids(self, x, n_valid, use_prev):
@@ -1043,6 +1162,7 @@ class PagedSlotSession:
         self._prev_ids = ids
         self.slot_pos = self.slot_pos + n_valid
         self._note_kv_read(t, pos + (n_valid if t > 1 else 1))
+        self._note_ring(pos, n_valid)
         return ids, finite
 
     def reinit_states(self) -> None:

@@ -42,12 +42,14 @@ from deeplearning4j_tpu.nn.conf.layers.special import (
 )
 from deeplearning4j_tpu.nn.conf.layers.attention import (
     SelfAttentionLayer, TransformerEncoderLayer,
+    GroupedQueryAttentionLayer,
 )
 from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
     LatentAttentionLayer,
 )
 from deeplearning4j_tpu.nn.conf.layers.moe import (
     SparseExpertsLayer, LatentDecoderBlock, ShortcutExpertBlock,
+    GroupedQueryDecoderBlock,
 )
 
 __all__ = [
@@ -68,6 +70,7 @@ __all__ = [
     "SimpleRnn", "LastTimeStep", "RnnLossLayer",
     "FrozenLayer", "VariationalAutoencoder", "Yolo2OutputLayer",
     "SelfAttentionLayer", "TransformerEncoderLayer",
+    "GroupedQueryAttentionLayer",
     "LatentAttentionLayer", "SparseExpertsLayer", "LatentDecoderBlock",
-    "ShortcutExpertBlock",
+    "ShortcutExpertBlock", "GroupedQueryDecoderBlock",
 ]

@@ -7,6 +7,11 @@ self-attention over (B,T,C) inputs backed by the Pallas flash kernel on
 TPU (ops/attention.py — the framework's hand-written-kernel seam);
 ``TransformerEncoderLayer`` is the full pre-LN block (MHA + MLP with
 residuals) so the config DSL can express transformer stacks.
+``GroupedQueryAttentionLayer`` is causal attention whose query heads
+share fewer key/value heads, of a key width and a value width of their
+own, with rotary positions, an optional sliding window and an optional
+learned sink; a window layer's paged cache is a ring of pages a slot
+owns, not pages of the allocator.
 """
 
 from __future__ import annotations
@@ -18,15 +23,18 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu import dtypes
+from deeplearning4j_tpu.dtypes import einsum_f32
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers.base import (BaseLayer,
                                                     register_layer)
 
-__all__ = ["SelfAttentionLayer", "TransformerEncoderLayer"]
+__all__ = ["SelfAttentionLayer", "TransformerEncoderLayer",
+           "GroupedQueryAttentionLayer"]
 
 
 from deeplearning4j_tpu.nn.conf.layers.normalization import (
     layer_norm as _layer_norm)
+from deeplearning4j_tpu.nn.conf.layers.rotary import rope, yarn_inv_freq
 
 
 def paged_write_targets(table, pos, t, page_size, n_valid=None):
@@ -348,6 +356,255 @@ class SelfAttentionLayer(BaseLayer):
         if self.out_bias:
             proj = proj + params["bo"]
         return proj, {"k": k_pool, "v": v_pool}
+
+
+def ring_write_targets(table, pos, t, page_size, ring_pages,
+                       n_valid=None):
+    """:func:`paged_write_targets` for a layer whose cache is a RING
+    of ``ring_pages`` pages a slot: ``(wpos, page_ids, offs, last)``.
+    Slot ``s`` owns the physical pages ``1 + s * ring_pages ..
+    (s + 1) * ring_pages`` whatever its table says, and position ``p``
+    lives at ring row ``p mod (ring_pages * page_size)`` of them.
+    ``last`` (S,) is the last position the step writes for a slot
+    (``pos - 1`` where it writes none).
+
+    Rows that carry no token go to the scratch page 0: those at or
+    past ``n_valid`` in the chunk program; in the single-row program,
+    which has no ``n_valid``, the row of a slot that sits the step
+    out, which the session marks by an all-zero table row (the
+    allocator never hands out page 0)."""
+    n_slots = table.shape[0]
+    span = ring_pages * page_size
+    wpos = pos[:, None] + jnp.arange(t)[None, :]
+    if n_valid is None:
+        live = jnp.broadcast_to(table[:, :1] > 0, wpos.shape)
+        last = pos + (t - 1)
+    else:
+        live = jnp.arange(t)[None, :] < n_valid[:, None]
+        last = pos + n_valid - 1
+    first = 1 + jnp.arange(n_slots)[:, None] * ring_pages
+    row = wpos % span
+    page_ids = jnp.where(live, first + row // page_size, 0)
+    return wpos, page_ids, row % page_size, last
+
+
+@register_layer
+@dataclasses.dataclass
+class GroupedQueryAttentionLayer(BaseLayer):
+    """Causal grouped-query attention, (B,T,C) -> (B,T,C): ``n_heads``
+    query heads of ``qk_head_dim`` over ``n_kv_heads`` key heads of
+    ``qk_head_dim`` and value heads of ``v_head_dim``; query head
+    ``i`` reads key/value head ``i // (n_heads / n_kv_heads)``. No
+    bias anywhere.
+
+    ``rotary_dim``: the first so many values of every query and key
+    head are rotated by position (half-split pairs, ``rope_theta``);
+    0 gives no positions. ``value_scale`` multiplies the values
+    before they are cached. ``window``: query ``i`` sees keys ``j``
+    with ``i - window < j <= i``; None sees every ``j <= i``.
+    ``sink``: a learned logit a query head (parameter ``sink``) that
+    joins the softmax's denominator and adds no value.
+
+    Two forms of one mathematics: ``apply`` attends over the whole
+    sequence, ``apply_stream_paged`` over a paged cache that holds
+    rotated keys and scaled values. Both go through ``_project`` and
+    ``_attend`` (exact einsum, float32 scores and softmax: the flash
+    and by-table kernels take one head size and equal head counts).
+
+    A layer with a ``window`` keeps its cache in a RING: the session
+    gives it ``slots * ring_pages + 1`` pages and slot ``s`` owns
+    pages ``1 + s * ring_pages ..``, position ``p`` at ring row
+    ``p mod (ring_pages * page_size)``. Visibility is decided by the
+    POSITION a ring row holds, worked out from ``pos``, so a row left
+    by an earlier tenant or by a position since overwritten is never
+    seen and nothing is ever zeroed."""
+
+    n_in: Optional[int] = None
+    n_heads: int = 4
+    n_kv_heads: int = 2
+    qk_head_dim: int = 8
+    v_head_dim: int = 8
+    rotary_dim: int = 0
+    rope_theta: float = 10000.0
+    window: Optional[int] = None
+    sink: bool = False
+    value_scale: float = 1.0
+
+    def __post_init__(self):
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(f"n_heads {self.n_heads} not divisible by "
+                             f"n_kv_heads {self.n_kv_heads}")
+        if self.rotary_dim % 2 or self.rotary_dim > self.qk_head_dim:
+            raise ValueError(
+                f"rotary_dim {self.rotary_dim}: even and at most "
+                f"qk_head_dim {self.qk_head_dim}")
+        if self.window is not None and self.window < 1:
+            raise ValueError(f"window must be >= 1, got {self.window}")
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if self.n_in is None:
+            self.n_in = input_type.size
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType.recurrent(self.n_in or input_type.size,
+                                   input_type.timesteps)
+
+    def initialize(self, key, input_type: InputType):
+        self.set_n_in(input_type)
+        d, H, K = self.n_in, self.n_heads, self.n_kv_heads
+        dq, dv = self.qk_head_dim, self.v_head_dim
+        ks = jax.random.split(key, 4)
+        w = lambda k, a, b: self._sample_w(k, (a, b), a, b)
+        p = {"Wq": w(ks[0], d, H * dq), "Wk": w(ks[1], d, K * dq),
+             "Wv": w(ks[2], d, K * dv), "Wo": w(ks[3], H * dv, d)}
+        if self.sink:
+            p["sink"] = jnp.zeros((H,), dtypes.policy().param_dtype)
+        return p, {}
+
+    # ---- pieces shared by both forms ----
+    def _rotate(self, y, positions):
+        """y (B,t,N,dq) at ``positions`` (B,t)."""
+        r = self.rotary_dim
+        if not r:
+            return y
+        inv = jnp.asarray(yarn_inv_freq(r, self.rope_theta, None))
+        return jnp.concatenate(
+            [rope(y[..., :r], positions[:, :, None], inv, halves=True),
+             y[..., r:]], axis=-1)
+
+    def _project(self, params, x, positions):
+        """x (B,t,C) at ``positions`` (B,t) -> q (B,t,H,dq) rotated,
+        k (B,t,K,dq) rotated, v (B,t,K,dv) scaled: k and v are what
+        the cache holds."""
+        B, t, _ = x.shape
+        H, K = self.n_heads, self.n_kv_heads
+        x = x.astype(params["Wq"].dtype)
+        q = (x @ params["Wq"]).reshape(B, t, H, self.qk_head_dim)
+        k = (x @ params["Wk"]).reshape(B, t, K, self.qk_head_dim)
+        if self.value_scale == 1.0:
+            v = x @ params["Wv"]
+        else:
+            # scaled in float32, rounded once
+            v = (einsum_f32("btc,cn->btn", x, params["Wv"])
+                 * self.value_scale).astype(x.dtype)
+        return (self._rotate(q, positions), self._rotate(k, positions),
+                v.reshape(B, t, K, self.v_head_dim))
+
+    def _attend(self, params, q, k, v, q_pos, k_pos):
+        """q (B,t,H,dq) at ``q_pos`` (B,t) over keys k (B,N,K,dq) and
+        values v (B,N,K,dv) that hold the positions ``k_pos`` (B,N):
+        key ``j`` is visible to query ``i`` iff ``0 <= k_pos[j] <=
+        q_pos[i]`` and, with a window, ``k_pos[j] > q_pos[i] -
+        window``. Returns (B,t,C)."""
+        from deeplearning4j_tpu.ops.attention import _NEG_INF
+        B, t, H, dq = q.shape
+        K = self.n_kv_heads
+        k, v = k.astype(q.dtype), v.astype(q.dtype)
+        s = einsum_f32("btkgd,bnkd->bkgtn",
+                       q.reshape(B, t, K, H // K, dq), k) * dq ** -0.5
+        qp, kp = q_pos[:, :, None], k_pos[:, None, :]
+        seen = (kp >= 0) & (kp <= qp)
+        if self.window is not None:
+            seen = seen & (kp > qp - self.window)
+        s = jnp.where(seen[:, None, None], s, _NEG_INF)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        if self.sink:
+            b = params["sink"].astype(jnp.float32).reshape(
+                1, K, H // K, 1, 1)
+            m = jnp.maximum(m, b)
+        e = jnp.exp(s - m)
+        z = jnp.sum(e, axis=-1, keepdims=True)
+        if self.sink:
+            z = z + jnp.exp(b - m)
+        o = jnp.einsum("bkgtn,bnkd->btkgd", (e / z).astype(v.dtype), v)
+        return o.reshape(B, t, -1) @ params["Wo"]
+
+    # ---- full sequence ----
+    def apply(self, params, state, x, *, training=False, rng=None,
+              mask=None):
+        if mask is not None:
+            raise NotImplementedError(
+                "GroupedQueryAttentionLayer has no key-padding mask: "
+                "feed sequences of one length")
+        x = self.apply_input_dropout(x, training=training, rng=rng)
+        B, T, _ = x.shape
+        pos = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
+        q, k, v = self._project(params, x, pos)
+        return self._attend(params, q, k, v, pos, pos), state
+
+    # ---- paged cache ----
+    def ring_pages(self, page_size: int) -> int:
+        """Pages of the ring a slot owns in this layer's pool: the
+        window's and one more, so that a step of up to
+        ``ring_pages * page_size - window + 1`` rows a slot overwrites
+        no position one of its own rows still reads. 0 without a
+        window: the cache lives in the allocator's pages."""
+        if self.window is None:
+            return 0
+        return -(-self.window // page_size) + 1
+
+    def zero_page_pool(self, n_pages: int, page_size: int, dtype):
+        """{'k': (n_pages, page_size, K * dq), 'v': (.., K * dv)}:
+        rotated keys and scaled values, heads side by side."""
+        K = self.n_kv_heads
+        return {"k": jnp.zeros((n_pages, page_size,
+                                K * self.qk_head_dim), dtype),
+                "v": jnp.zeros((n_pages, page_size,
+                                K * self.v_head_dim), dtype)}
+
+    def paged_reads_by_table(self, page_size: int, t: int, dtype) -> bool:
+        """False: ``apply_stream_paged`` gathers (the by-table kernel
+        takes equal head counts and one head size)."""
+        return False
+
+    def apply_stream_paged(self, params, pool, table, pos, x,
+                           n_valid=None):
+        """One step for all slots over the paged cache (the
+        ``SelfAttentionLayer.apply_stream_paged`` contract). Without a
+        window the slot's keys are the pages its table names, gathered
+        whole; with one they are the slot's own ring
+        (:func:`ring_write_targets`), whose pool the session sized by
+        ``ring_pages``: a chunk wider than the ring has room for
+        raises here, at trace time. Returns (out, pool)."""
+        S, t, _ = x.shape
+        ps = pool["k"].shape[1]
+        K = self.n_kv_heads
+        if self.window is None:
+            wpos, page_ids, offs = paged_write_targets(table, pos, t, ps,
+                                                       n_valid)
+            rows = lambda leaf: leaf[table]
+            k_pos = jnp.broadcast_to(
+                jnp.arange(table.shape[1] * ps)[None],
+                (S, table.shape[1] * ps))
+        else:
+            R = (pool["k"].shape[0] - 1) // S
+            span = R * ps
+            if span < self.window + t - 1:
+                raise ValueError(
+                    f"a step of {t} rows a slot over a window of "
+                    f"{self.window} needs a ring of "
+                    f"{self.window + t - 1} positions; this pool gives "
+                    f"a slot {R} pages of {ps}")
+            wpos, page_ids, offs, last = ring_write_targets(
+                table, pos, t, ps, R, n_valid)
+            # slot s owns pages 1 + s R ..: the rings lie side by
+            # side behind the scratch page, so no gather
+            rows = lambda leaf: leaf[1:]
+            # the position ring row r holds once the step has
+            # written: the latest p <= last with p = r (mod span);
+            # negative where the slot has not come that far
+            k_pos = last[:, None] - (last[:, None]
+                                     - jnp.arange(span)[None]) % span
+        q, k, v = self._project(params, x, wpos)
+        k_pool = pool["k"].at[page_ids, offs].set(
+            k.reshape(S, t, -1).astype(pool["k"].dtype))
+        v_pool = pool["v"].at[page_ids, offs].set(
+            v.reshape(S, t, -1).astype(pool["v"].dtype))
+        n = k_pos.shape[1]
+        out = self._attend(
+            params, q, rows(k_pool).reshape(S, n, K, self.qk_head_dim),
+            rows(v_pool).reshape(S, n, K, self.v_head_dim), wpos, k_pos)
+        return out, {"k": k_pool, "v": v_pool}
 
 
 @register_layer

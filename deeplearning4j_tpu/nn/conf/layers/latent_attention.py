@@ -33,7 +33,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from deeplearning4j_tpu import dtypes
 from deeplearning4j_tpu.dtypes import einsum_f32
@@ -41,57 +40,10 @@ from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers.base import (BaseLayer,
                                                     register_layer)
 from deeplearning4j_tpu.nn.conf.layers.normalization import rms_norm
+from deeplearning4j_tpu.nn.conf.layers.rotary import (rope, yarn_inv_freq,
+                                                      yarn_mscale)
 
 __all__ = ["LatentAttentionLayer", "yarn_inv_freq", "yarn_mscale"]
-
-
-def yarn_mscale(factor: float, mscale: float) -> float:
-    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
-
-
-def yarn_inv_freq(dim: int, theta: float, scaling: Optional[dict]):
-    """Rotary inverse frequencies of ``dim // 2`` pairs. With a
-    ``yarn`` scaling: extrapolated (``theta**(-2i/dim)``) and
-    interpolated (the same over ``factor``) frequencies blended per
-    dimension by the linear ramp between the dimensions that make
-    ``beta_fast`` and ``beta_slow`` rotations over the original
-    context (Peng et al. 2023, as the DeepSeek-V2 reference code)."""
-    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
-    if not scaling:
-        return extra.astype(np.float32)
-    if scaling.get("type", "yarn") != "yarn":
-        raise ValueError(f"rope_scaling type {scaling.get('type')!r}: "
-                         "only 'yarn' is implemented")
-    factor = float(scaling["factor"])
-    orig = scaling["original_max_position_embeddings"]
-
-    def correction_dim(rotations):
-        return (dim * math.log(orig / (rotations * 2 * math.pi))
-                / (2 * math.log(theta)))
-
-    low = max(math.floor(correction_dim(scaling.get("beta_fast", 32))), 0)
-    high = min(math.ceil(correction_dim(scaling.get("beta_slow", 1))),
-               dim - 1)
-    if low == high:
-        high += 0.001
-    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
-                   / (high - low), 0.0, 1.0)
-    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(
-        np.float32)
-
-
-def _rope(x, positions, inv_freq, scale):
-    """Rotate the interleaved pairs ``(x[2i], x[2i+1])`` of the last
-    axis by ``positions * inv_freq[i]``. ``positions`` broadcasts
-    against ``x``'s leading axes. The result is laid out
-    ``[evens, odds]``; queries and keys go through here alike, so
-    their products do not see the order."""
-    ang = positions[..., None].astype(jnp.float32) * inv_freq
-    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
-    xf = x.astype(jnp.float32)
-    a, b = xf[..., 0::2], xf[..., 1::2]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
 
 
 def _mm(a, b):
@@ -189,7 +141,7 @@ class LatentAttentionLayer(BaseLayer):
         else:
             q = _mm(cq, params["Wqb"])
         q = q.reshape(B, t, H, dn + dr)
-        q_rope = _rope(q[..., dn:], positions[:, :, None], inv, scale)
+        q_rope = rope(q[..., dn:], positions[:, :, None], inv, scale)
         kv = _mm(x, params["Wkva"])
         ckv = kv[..., :self.kv_lora_rank]
         if self.scale_kv_lora:
@@ -201,7 +153,7 @@ class LatentAttentionLayer(BaseLayer):
                        kv.dtype)
         else:
             ckv = rms_norm(ckv, params["kv_gain"], self.eps)
-        kr = _rope(kv[..., self.kv_lora_rank:], positions, inv, scale)
+        kr = rope(kv[..., self.kv_lora_rank:], positions, inv, scale)
         return q[..., :dn], q_rope, ckv, kr
 
     def _kvb(self, params):

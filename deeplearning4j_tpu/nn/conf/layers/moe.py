@@ -31,6 +31,11 @@ dense SiLU-gated MLP or the expert layer.
 (§2.2): two latent attentions and two dense MLPs in sequence, and one
 expert layer that reads the first sub-layer's normed hidden state and
 joins the residual stream at the end of the second.
+
+``GroupedQueryDecoderBlock`` is ``LatentDecoderBlock``'s residual
+block over grouped-query attention (global, or a sliding window with
+a learned sink) and a sigmoid router with a correction bias and no
+shared expert: MiMo-V2's layer.
 """
 
 from __future__ import annotations
@@ -44,6 +49,8 @@ import jax.numpy as jnp
 from deeplearning4j_tpu import dtypes
 from deeplearning4j_tpu.dtypes import einsum_f32
 from deeplearning4j_tpu.nn.conf.inputs import InputType
+from deeplearning4j_tpu.nn.conf.layers.attention import (
+    GroupedQueryAttentionLayer)
 from deeplearning4j_tpu.nn.conf.layers.base import (BaseLayer,
                                                     register_layer)
 from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
@@ -51,7 +58,7 @@ from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
 from deeplearning4j_tpu.nn.conf.layers.normalization import rms_norm
 
 __all__ = ["SparseExpertsLayer", "LatentDecoderBlock",
-           "ShortcutExpertBlock", "swiglu"]
+           "ShortcutExpertBlock", "GroupedQueryDecoderBlock", "swiglu"]
 
 _F32 = jnp.float32
 
@@ -223,6 +230,39 @@ class SparseExpertsLayer(BaseLayer):
         return self.apply_counted(params, x)[0], state
 
 
+def _ffn_half(params, h, moe, eps, active=None):
+    """The second half of a pre-RMSNorm decoder block,
+    ``(h + F(norm(h)), counts or None)``: ``F`` is the expert layer
+    ``moe`` (parameters ``params["moe"]``) or, where that is None,
+    the dense SiLU-gated MLP ``Wg, Wu, Wd``."""
+    z = rms_norm(h, params["norm2_gain"], eps)
+    if moe is None:
+        with jax.named_scope("mlp"):
+            return h + swiglu(z, params["Wg"], params["Wu"],
+                              params["Wd"]), None
+    f, counts = moe.apply_counted(params["moe"], z, active)
+    return h + f, counts
+
+
+def _init_decoder_block(block, key, attn, moe):
+    """Parameters of a pre-RMSNorm decoder block over ``attn`` and
+    ``moe`` (None: the dense MLP of ``block.intermediate_size``)."""
+    ka, km, k1, k2, k3 = jax.random.split(key, 5)
+    d, ff = block.n_in, block.intermediate_size
+    pd = dtypes.policy().param_dtype
+    t = InputType.recurrent(d)
+    p = {"norm1_gain": jnp.ones((d,), pd),
+         "norm2_gain": jnp.ones((d,), pd),
+         "attn": attn.initialize(ka, t)[0]}
+    if moe is not None:
+        p["moe"] = moe.initialize(km, t)[0]
+    else:
+        p.update(Wg=block._sample_w(k1, (d, ff), d, ff),
+                 Wu=block._sample_w(k2, (d, ff), d, ff),
+                 Wd=block._sample_w(k3, (ff, d), ff, d))
+    return p, {}
+
+
 @register_layer
 @dataclasses.dataclass
 class LatentDecoderBlock(BaseLayer):
@@ -291,32 +331,11 @@ class LatentDecoderBlock(BaseLayer):
 
     def initialize(self, key, input_type: InputType):
         self.set_n_in(input_type)
-        attn, moe = self._ensure_parts()
-        ka, km, k1, k2, k3 = jax.random.split(key, 5)
-        d, ff = self.n_in, self.intermediate_size
-        pd = dtypes.policy().param_dtype
-        t = InputType.recurrent(d)
-        p = {"norm1_gain": jnp.ones((d,), pd),
-             "norm2_gain": jnp.ones((d,), pd),
-             "attn": attn.initialize(ka, t)[0]}
-        if moe is not None:
-            p["moe"] = moe.initialize(km, t)[0]
-        else:
-            p.update(Wg=self._sample_w(k1, (d, ff), d, ff),
-                     Wu=self._sample_w(k2, (d, ff), d, ff),
-                     Wd=self._sample_w(k3, (ff, d), ff, d))
-        return p, {}
+        return _init_decoder_block(self, key, *self._ensure_parts())
 
     def _ffn_half(self, params, h, active=None):
-        """(h + F(norm(h)), counts or None)."""
-        _, moe = self._ensure_parts()
-        z = rms_norm(h, params["norm2_gain"], self.eps)
-        if moe is None:
-            with jax.named_scope("mlp"):
-                return h + swiglu(z, params["Wg"], params["Wu"],
-                                  params["Wd"]), None
-        f, counts = moe.apply_counted(params["moe"], z, active)
-        return h + f, counts
+        return _ffn_half(params, h, self._ensure_parts()[1], self.eps,
+                         active)
 
     def apply(self, params, state, x, *, training=False, rng=None,
               mask=None):
@@ -500,6 +519,130 @@ class ShortcutExpertBlock(BaseLayer):
 
         h, tally = self._forward(params, x, attend, active)
         return h, new_pool, tally
+
+    def apply_stream_paged(self, params, pool, table, pos, x,
+                           n_valid=None):
+        h, pool, _ = self.apply_stream_paged_aux(
+            params, pool, table, pos, x, n_valid=n_valid)
+        return h, pool
+
+
+@register_layer
+@dataclasses.dataclass
+class GroupedQueryDecoderBlock(BaseLayer):
+    """Pre-RMSNorm decoder block ``h = x + GQA(norm(x)); y = h +
+    F(norm(h))``: grouped-query attention
+    (``GroupedQueryAttentionLayer``: global, or with ``window`` a
+    sliding window whose paged cache is a slot-owned ring), then a
+    dense SiLU-gated MLP (``n_routed_experts == 0``) or the expert
+    layer with a sigmoid router, its selection-only correction bias,
+    the selected weights normalised and no shared expert. The fields
+    are the two sub-layers' own, flat, as ``LatentDecoderBlock`` has
+    them."""
+
+    n_in: Optional[int] = None
+    eps: float = 1e-5
+    # grouped-query attention (GroupedQueryAttentionLayer)
+    n_heads: int = 4
+    n_kv_heads: int = 2
+    qk_head_dim: int = 8
+    v_head_dim: int = 8
+    rotary_dim: int = 0
+    rope_theta: float = 10000.0
+    window: Optional[int] = None
+    sink: bool = False
+    value_scale: float = 1.0
+    # dense MLP width (used when n_routed_experts == 0)
+    intermediate_size: int = 128
+    # expert layer (SparseExpertsLayer)
+    n_routed_experts: int = 0
+    held: Optional[Tuple[int, int]] = None
+    top_k: int = 4
+    expert_width: int = 32
+    routed_scaling_factor: float = 1.0
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if self.n_in is None:
+            self.n_in = input_type.size
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType.recurrent(self.n_in or input_type.size,
+                                   input_type.timesteps)
+
+    @property
+    def stream_aux(self) -> bool:
+        return self.n_routed_experts > 0
+
+    def _ensure_parts(self):
+        if not hasattr(self, "_attn"):
+            common = dict(n_in=self.n_in, weight_init=self.weight_init,
+                          weight_distribution=self.weight_distribution)
+            self._attn = GroupedQueryAttentionLayer(
+                n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
+                qk_head_dim=self.qk_head_dim,
+                v_head_dim=self.v_head_dim, rotary_dim=self.rotary_dim,
+                rope_theta=self.rope_theta, window=self.window,
+                sink=self.sink, value_scale=self.value_scale, **common)
+            self._moe = None
+            if self.n_routed_experts:
+                self._moe = SparseExpertsLayer(
+                    n_routed_experts=self.n_routed_experts,
+                    held=self.held, top_k=self.top_k,
+                    expert_width=self.expert_width, n_shared_experts=0,
+                    routed_scaling_factor=self.routed_scaling_factor,
+                    norm_topk_prob=True, scoring_func="sigmoid",
+                    router_bias=True, **common)
+        return self._attn, self._moe
+
+    def initialize(self, key, input_type: InputType):
+        self.set_n_in(input_type)
+        return _init_decoder_block(self, key, *self._ensure_parts())
+
+    def _block(self, params, x, attend, active=None):
+        """The block's equations; ``attend(z)`` is the attention over
+        the normed ``z``."""
+        x = x.astype(params["norm1_gain"].dtype)
+        with jax.named_scope("attn/global" if self.window is None
+                             else "attn/window"):
+            a = attend(rms_norm(x, params["norm1_gain"], self.eps))
+        return _ffn_half(params, x + a, self._ensure_parts()[1],
+                         self.eps, active)
+
+    def apply(self, params, state, x, *, training=False, rng=None,
+              mask=None):
+        attn, _ = self._ensure_parts()
+        attend = lambda z: attn.apply(params["attn"], {}, z,
+                                      training=training, rng=rng,
+                                      mask=mask)[0]
+        return self._block(params, x, attend)[0], state
+
+    # ---- paged decode ----
+    def ring_pages(self, page_size: int) -> int:
+        return self._ensure_parts()[0].ring_pages(page_size)
+
+    def zero_page_pool(self, n_pages: int, page_size: int, dtype):
+        return self._ensure_parts()[0].zero_page_pool(
+            n_pages, page_size, dtype)
+
+    def paged_reads_by_table(self, page_size: int, t: int, dtype) -> bool:
+        return self._ensure_parts()[0].paged_reads_by_table(
+            page_size, t, dtype)
+
+    def apply_stream_paged_aux(self, params, pool, table, pos, x,
+                               active=None, n_valid=None):
+        """(out, pool, counts), as
+        ``LatentDecoderBlock.apply_stream_paged_aux``."""
+        attn, _ = self._ensure_parts()
+        new_pool = []
+
+        def attend(z):
+            a, p = attn.apply_stream_paged(params["attn"], pool, table,
+                                           pos, z, n_valid)
+            new_pool.append(p)
+            return a
+
+        h, counts = self._block(params, x, attend, active)
+        return h, new_pool[0], counts
 
     def apply_stream_paged(self, params, pool, table, pos, x,
                            n_valid=None):

@@ -325,7 +325,8 @@ class ContinuousBatcher(ServingBackend):
         # chunked prefill: the width of the second step program, 1
         # where the session has no chunk entry point (the dense one,
         # a network whose layers mix rows)
-        self._chunk_t = (chunk_width(slots, capacity)
+        self._chunk_t = (chunk_width(slots, min(
+                             capacity, self.session.chunk_rows_max))
                          if getattr(self.session, "chunkable", False)
                          else 1)
         self._warmed = False
@@ -1093,6 +1094,9 @@ class ContinuousBatcher(ServingBackend):
                 if self._paged:
                     self._steps.record_kv_positions(
                         *self.session.step_kv_positions)
+                    if any(self.session.step_ring_pages):
+                        self._steps.record_kv_ring(
+                            *self.session.step_ring_pages)
                 step.set("active", len(st.live))
                 step.set("prompt_slots", st.n_prompt)
                 step.set("decode_slots", len(st.emitters))

@@ -253,7 +253,15 @@ class BatcherStepMetrics:
     session ACCOUNTS them from the lengths it feeds and its layers'
     dispatch, not as the device measured them, and
     ``serving_kv_positions_spanned_total`` the slots x capacity the
-    page tables span; both exist only over a paged pool. The
+    page tables span; both exist only over a paged pool, and count
+    the layers whose cache lives in the allocator's pages. Over a
+    network with a layer that keeps a ring of pages a slot (a sliding
+    window), ``serving_kv_ring_pages_held_total`` adds, a step, the
+    ring pages of the slots it fed that hold a position the ring
+    still keeps, ``serving_kv_ring_pages_full_total`` the pages the
+    same slots would hold had that kind kept every position, and
+    ``serving_kv_ring_wraps_total`` the ring pages a write of the
+    step began to reuse (0: the traffic never outgrew a ring). The
     request-phase histograms time a request from outside the steps
     that serve it; these say what a step costs and what it was spent
     on."""
@@ -262,7 +270,7 @@ class BatcherStepMetrics:
                  name: str = "generate"):
         reg = registry or MetricsRegistry()
         self._reg, self._name, self._experts = reg, name, None
-        self._kv = self._pairs = None
+        self._kv = self._pairs = self._ring = None
         self._parts = {
             part: reg.histogram(
                 "serving_step_seconds",
@@ -321,6 +329,23 @@ class BatcherStepMetrics:
                     ("spanned", "slots x capacity per step")))
         self._kv[0].inc(read)
         self._kv[1].inc(spanned)
+
+    def record_kv_ring(self, held: int, full: int, wraps: int) -> None:
+        """One step's ring pages of a network with a ring layer
+        (``PagedSlotSession.step_ring_pages``)."""
+        if self._ring is None:
+            self._ring = tuple(
+                self._reg.counter(
+                    f"serving_kv_ring_{what}_total", help=text,
+                    labels={"endpoint": self._name})
+                for what, text in (
+                    ("pages_held", "ring pages of the fed slots that "
+                                   "hold a position the ring keeps"),
+                    ("pages_full", "pages the same slots would hold "
+                                   "had the rings kept every position"),
+                    ("wraps", "ring pages a step began to reuse")))
+        for counter, n in zip(self._ring, (held, full, wraps)):
+            counter.inc(n)
 
     def record_experts(self, counts) -> None:
         """One step's auxiliary counts of a network with expert

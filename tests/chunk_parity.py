@@ -10,7 +10,14 @@ second: the written positions and each slot's last valid row to float
 tolerance, every other position of every leased page bit for bit to
 what it held before the call (the rows past ``n_valid`` may alter
 nothing that lives), ``slot_pos``, and an expert layer's counts to the
-one-by-one counts summed."""
+one-by-one counts summed.
+
+A layer that keeps a RING of pages a slot (a sliding window: the
+session's ``_ring``) is read where its rows live: the slot's own ring
+pages, position ``p`` at ring row ``p mod span``. Such a network's
+cases run at a page of 8 (``run_case(..., page=8)``), where a ring has
+room for the T rows of a chunk, and without ``prefix_resume``: a ring
+is not shared."""
 
 import jax
 import numpy as np
@@ -20,25 +27,43 @@ CASES = ("ragged", "prefix_resume", "near_capacity")
 SLOTS, CAPACITY, PAGE, T = 4, 32, 4, 8
 
 
-def sessions(net):
+def sessions(net, page=PAGE):
     return tuple(net.paged_slot_streaming_session(
-        capacity=CAPACITY, slots=SLOTS, page_size=PAGE)
+        capacity=CAPACITY, slots=SLOTS, page_size=page)
         for _ in range(2))
 
 
 def pages_of(sess, pages):
-    """Per pool leaf, the contents of ``pages``: (len(pages), page
-    size, ...) arrays."""
+    """Per leaf of the pools in the allocator's pages, the contents
+    of ``pages``: (len(pages), page size, ...) arrays."""
     return [np.asarray(leaf)[np.asarray(pages)]
-            for pool in sess._pools if pool is not None
+            for pool, ring in zip(sess._pools, sess._ring)
+            if pool is not None and not ring
             for leaf in jax.tree_util.tree_leaves(pool)]
 
 
+def leaf_rings(sess):
+    """Per pool leaf, in ``live_rows``' order, the pages of the ring
+    a slot owns there (0: the leaf lives in the allocator's pages)."""
+    return [ring for pool, ring in zip(sess._pools, sess._ring)
+            if pool is not None
+            for _ in jax.tree_util.tree_leaves(pool)]
+
+
 def live_rows(sess, slot):
-    """The slot's leased pages as its virtual cache: per pool leaf a
-    (leased positions, ...) array, in table order."""
-    return [rows.reshape((-1,) + rows.shape[2:])
-            for rows in pages_of(sess, sess._leases[slot].pages)]
+    """The slot's cache rows: per pool leaf a (positions held, ...)
+    array: its leased pages in table order or, for a layer that keeps
+    a ring, the slot's ring, position p at row p mod its length."""
+    out = []
+    for pool, ring in zip(sess._pools, sess._ring):
+        if pool is None:
+            continue
+        pages = (1 + slot * ring + np.arange(ring) if ring
+                 else np.asarray(sess._leases[slot].pages))
+        for leaf in jax.tree_util.tree_leaves(pool):
+            rows = np.asarray(leaf)[pages]
+            out.append(rows.reshape((-1,) + rows.shape[2:]))
+    return out
 
 
 def feed_single(sess, tokens):
@@ -85,22 +110,25 @@ def feed_both(chunked, single, tokens, atol=1e-5):
     for slot, was in before.items():
         lo, hi = int(pos0[slot]), int(pos0[slot] + n_valid[slot])
         now, want = live_rows(chunked, slot), live_rows(single, slot)
-        for a, b, w in zip(now, was, want):
-            written = np.zeros((a.shape[0],), bool)
-            written[lo:hi] = True
+        for a, b, w, ring in zip(now, was, want, leaf_rings(chunked)):
+            span = a.shape[0]
+            written = np.zeros((span,), bool)
+            written[np.arange(lo, hi) % span if ring
+                    else slice(lo, hi)] = True
             np.testing.assert_array_equal(a[~written], b[~written])
             # every position a later step may read
-            np.testing.assert_allclose(a[:hi], w[:hi], atol=atol)
+            read = np.arange(max(0, hi - span) if ring else 0, hi) % span
+            np.testing.assert_allclose(a[read], w[read], atol=atol)
     if counts is not None:
         jax.tree_util.tree_map(np.testing.assert_array_equal,
                                jax.device_get(chunked.step_aux), counts)
     return h
 
 
-def run_case(net, vocab, case):
+def run_case(net, vocab, case, page=PAGE):
     rng = np.random.default_rng(sum(map(ord, case)))
     ids = lambda n: [int(v) for v in rng.integers(1, vocab, n)]
-    chunked, single = sessions(net)
+    chunked, single = sessions(net, page)
 
     def bind(slot, prompt, n_tokens):
         for s in (chunked, single):
@@ -128,14 +156,14 @@ def run_case(net, vocab, case):
         for s in (chunked, single):
             feed_single(s, {0: first})
             s.release(0, register_prompt=first)
-        again = first[:2 * PAGE] + ids(5)
+        again = first[:2 * page] + ids(5)
         bind(2, again, 4)
         lease = chunked._leases[2]
-        assert lease.resume_pos == 2 * PAGE
+        assert lease.resume_pos == 2 * page
         shared = lease.pages[:2]
         assert all(chunked.allocator.refcount(p) > 1 for p in shared)
         was = pages_of(chunked, shared)
-        feed_both(chunked, single, {2: again[2 * PAGE:]})
+        feed_both(chunked, single, {2: again[2 * page:]})
         for a, b in zip(pages_of(chunked, shared), was):
             np.testing.assert_array_equal(a, b)
         assert all(chunked.allocator.refcount(p) > 1 for p in shared)

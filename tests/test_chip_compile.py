@@ -213,6 +213,48 @@ def test_shortcut_expert_block_step_compiles_for_v5e(topo, t):
     assert mem.temp_size_in_bytes < 1e9
 
 
+@pytest.mark.parametrize("t", [2, 1], ids=["chunk_64x2", "decode_64x1"])
+def test_grouped_query_window_cell_step_fits_v5e(topo, t):
+    """The WHOLE id-returning step of the benchmark's
+    ``mimo_serve_mixedlen`` cell (``PagedSlotSession._step_ids`` over
+    the configuration's own network: 7 layers at the published widths
+    in bfloat16, 64 slots of capacity 2,048, page 16), both step
+    programs: global layers over the allocator's 8,193 pages, window
+    layers over 64 rings of 9 pages, in a chip's 16 GB."""
+    from benchmark.harness import spec
+    from deeplearning4j_tpu.models.paged_kv import PagedSlotSession
+    cell = spec.load("mimo_serve_mixedlen")
+    config, sv = cell.config, cell.traffic["server"]
+    builder = spec.load_module("builders", config["builder"])
+    with builder.policy(config):
+        net = builder.build(config).init()       # parameters as shapes
+    sess = PagedSlotSession(net, sv["slots"], sv["capacity"],
+                            sv["page_size"])
+    assert sess._ring == [0, 0, 9, 9, 9, 9, 0, 9, 0, 0]
+    assert sess.chunk_rows_max == 16 >= t
+    sess._make_step()
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), tree)
+    slots = sv["slots"]
+    compiled = sess._step_ids.lower(
+        place(net.params), net.state, place(sess._pools),
+        sds((slots, sess.pages_per_slot), jnp.int32),
+        sds((slots,), jnp.int32), sds((slots, t, 1), jnp.float32),
+        sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots,), bool)).compile()
+    mem = compiled.memory_analysis()
+    # 6.86 GB of weights, 0.67 GB of global pages, 0.24 GB of rings;
+    # the pools are donated; the gathers of two global layers' whole
+    # tables and the experts' dense pass stay under 1 GB
+    assert 7.7e9 < mem.argument_size_in_bytes < 7.9e9
+    assert mem.alias_size_in_bytes > 0.9e9
+    assert mem.temp_size_in_bytes < 1e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 12e9
+
+
 # ---- four chips: the kernels on a mesh -----------------------------------
 
 def _attention_loss(q, k, v, mask=None):

@@ -178,6 +178,39 @@ def test_chunk_step_refuses_what_it_cannot_hold():
         sess.step_chunk(x, np.array([1, 0]))
 
 
+@pytest.mark.parametrize("n_valid", [None, [3, 0, 1]],
+                         ids=["single_row_program", "chunk_program"])
+def test_ring_write_targets(n_valid):
+    """Where a layer that keeps a ring of 3 pages of 4 a slot writes
+    a step's rows: slot s in pages 1 + 3 s .., position p at ring row
+    p mod 12 whatever the table says; rows that carry no token in the
+    scratch page (past ``n_valid``, or in the single-row program the
+    slot whose table row is zeroed)."""
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nn.conf.layers.attention import (
+        ring_write_targets)
+    table = jnp.asarray([[7, 9, 0], [0, 0, 0], [5, 0, 0]], jnp.int32)
+    pos = jnp.asarray([10, 0, 25], jnp.int32)
+    t = 1 if n_valid is None else 3
+    wpos, pages, offs, last = ring_write_targets(
+        table, pos, t, 4, 3,
+        None if n_valid is None else jnp.asarray(n_valid, jnp.int32))
+    assert wpos.tolist() == [[10 + j for j in range(t)],
+                             list(range(t)),
+                             [25 + j for j in range(t)]]
+    if n_valid is None:
+        # 10 -> page 2 of slot 0's ring; 25 = 2 x 12 + 1 -> page 0 of
+        # slot 2's; slot 1 sits the step out
+        assert pages.tolist() == [[1 + 2], [0], [7 + 0]]
+        assert offs.tolist() == [[2], [0], [1]]
+        assert last.tolist() == [10, 0, 25]
+    else:
+        # 10, 11 in page 2 and 12 -> ring row 0 in page 0 of slot 0
+        assert pages.tolist() == [[3, 3, 1], [0, 0, 0], [7, 0, 0]]
+        assert offs.tolist() == [[2, 3, 0], [0, 1, 2], [1, 2, 3]]
+        assert last.tolist() == [12, -1, 25]
+
+
 # ---------------------------------------------------------------------------
 # allocator + prefix-cache unit tests
 # ---------------------------------------------------------------------------
