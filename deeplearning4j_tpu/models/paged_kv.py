@@ -976,17 +976,20 @@ class PagedSlotSession:
         """``step_kv_positions`` of a step at ``t`` rows a slot, from
         the ``lengths`` its attention is given (``pos`` plus the rows
         a slot feeds). This is the ACCOUNTING the layers' predicate
-        implies, host arithmetic and no measurement: layers that read
-        by table (``paged_reads_by_table``: all of them must, a layer
-        that does not say is taken to gather) fetch of each slot the
-        pages up to the one its length ends in
-        (``ops.paged_attention.pages_read``, the kernel's own rule);
-        layers that gather read every slot's whole table. What the
-        device moved is in its trace."""
+        implies, host arithmetic and no measurement, of the layers
+        whose pages the allocator hands out (a ring layer reads its
+        own ring whatever the table spans: ``_note_ring`` counts
+        those): layers that read by table (``paged_reads_by_table``:
+        all of them must, a layer that does not say is taken to
+        gather) fetch of each slot the pages up to the one its length
+        ends in (``ops.paged_attention.pages_read``, the kernel's own
+        rule); layers that gather read every slot's whole table. What
+        the device moved is in its trace."""
         spanned = self.slots * self.pages_per_slot * self.page_size
         if t not in self._by_table:
-            paged = [layer for layer in self.net.layers
-                     if hasattr(layer, "apply_stream_paged")]
+            paged = [layer for layer, ring in zip(self.net.layers,
+                                                  self._ring)
+                     if hasattr(layer, "apply_stream_paged") and not ring]
             self._by_table[t] = all(
                 hasattr(layer, "paged_reads_by_table")
                 and layer.paged_reads_by_table(self.page_size, t,

@@ -409,7 +409,10 @@ class GroupedQueryAttentionLayer(BaseLayer):
     sequence, ``apply_stream_paged`` over a paged cache that holds
     rotated keys and scaled values. Both go through ``_project`` and
     ``_attend`` (exact einsum, float32 scores and softmax: the flash
-    and by-table kernels take one head size and equal head counts).
+    kernels take one head size and equal head counts); on a TPU a
+    layer without ``window`` and ``sink`` reads its paged cache
+    through the grouped by-table kernel of ``ops/paged_attention.py``,
+    the same mathematics page by page.
 
     A layer with a ``window`` keeps its cache in a RING: the session
     gives it ``slots * ring_pages + 1`` pages and slot ``s`` owns
@@ -553,19 +556,31 @@ class GroupedQueryAttentionLayer(BaseLayer):
                                 K * self.v_head_dim), dtype)}
 
     def paged_reads_by_table(self, page_size: int, t: int, dtype) -> bool:
-        """False: ``apply_stream_paged`` gathers (the by-table kernel
-        takes equal head counts and one head size)."""
-        return False
+        """Will ``apply_stream_paged`` at ``t`` rows a slot read each
+        slot's live pages by table (True), or its whole table or ring
+        (False)? A predicate of the shapes
+        (``ops.paged_attention.grouped_reads_by_table``); a layer with
+        a ``window`` or a ``sink`` keeps ``_attend``: the kernel has
+        neither a first position nor a logit in its denominator."""
+        from deeplearning4j_tpu.ops.paged_attention import \
+            grouped_reads_by_table
+        return (self.window is None and not self.sink
+                and grouped_reads_by_table(
+                    self.n_heads, self.n_kv_heads, self.qk_head_dim,
+                    self.v_head_dim, page_size, t, dtype))
 
     def apply_stream_paged(self, params, pool, table, pos, x,
                            n_valid=None):
         """One step for all slots over the paged cache (the
         ``SelfAttentionLayer.apply_stream_paged`` contract). Without a
-        window the slot's keys are the pages its table names, gathered
-        whole; with one they are the slot's own ring
-        (:func:`ring_write_targets`), whose pool the session sized by
-        ``ring_pages``: a chunk wider than the ring has room for
-        raises here, at trace time. Returns (out, pool)."""
+        window the slot's keys are the pages its table names: read
+        page by page and no further than its length where
+        ``paged_reads_by_table`` holds (``ops/paged_attention.py``'s
+        grouped kernel: the same mathematics as ``_attend``, which is
+        its oracle), gathered whole elsewhere; with one they are the
+        slot's own ring (:func:`ring_write_targets`), whose pool the
+        session sized by ``ring_pages``: a chunk wider than the ring
+        has room for raises here, at trace time. Returns (out, pool)."""
         S, t, _ = x.shape
         ps = pool["k"].shape[1]
         K = self.n_kv_heads
@@ -600,6 +615,15 @@ class GroupedQueryAttentionLayer(BaseLayer):
             k.reshape(S, t, -1).astype(pool["k"].dtype))
         v_pool = pool["v"].at[page_ids, offs].set(
             v.reshape(S, t, -1).astype(pool["v"].dtype))
+        if self.paged_reads_by_table(ps, t, k_pool.dtype):
+            from deeplearning4j_tpu.ops.paged_attention import \
+                pallas_paged_attention_grouped
+            lengths = pos + (t if n_valid is None else n_valid)
+            with jax.named_scope("paged_attention/pallas"):
+                o = pallas_paged_attention_grouped(
+                    q, k_pool, v_pool, table, lengths, pos,
+                    n_heads=self.n_heads, n_kv_heads=K)
+            return o @ params["Wo"], {"k": k_pool, "v": v_pool}
         n = k_pos.shape[1]
         out = self._attend(
             params, q, rows(k_pool).reshape(S, n, K, self.qk_head_dim),

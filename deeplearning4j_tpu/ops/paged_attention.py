@@ -36,6 +36,14 @@ fetches nothing and yields zero rows.
 
 ``paged_attention`` dispatches: this kernel on a TPU for the shapes it
 tiles, the gather elsewhere (the CPU path and the tests' oracle).
+
+``pallas_paged_attention_grouped`` is the same walk for grouped-query
+heads of two widths (``GroupedQueryAttentionLayer``: ``H`` query heads
+of ``dq`` over ``K`` key heads of ``dq`` and value heads of ``dv``): a
+second kernel body that shares the page walk (``_page_walk``) and the
+online-softmax block and none of the head algebra. The layer
+dispatches on ``grouped_reads_by_table``; its ``_attend`` over the
+gathered table is the other path and the oracle.
 """
 
 from __future__ import annotations
@@ -47,8 +55,10 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.ops.attention import _NEG_INF
 
-__all__ = ["paged_attention", "paged_attention_gather",
-           "pallas_paged_attention", "pages_read", "reads_by_table"]
+__all__ = ["grouped_reads_by_table", "paged_attention",
+           "paged_attention_gather", "pallas_paged_attention",
+           "pallas_paged_attention_grouped", "pages_read",
+           "reads_by_table"]
 
 # keys a block: one lane tile of scores. A live slot of the serving
 # cells holds about a hundred tokens, so most slots are one block
@@ -93,27 +103,26 @@ def paged_attention_gather(q, k_pool, v_pool, table, pos, n_heads):
     return out.reshape(S, t, HD)
 
 
-def _kernel(lengths_ref, pos_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
-            qx_scr, qlim_scr, m_scr, l_scr, acc_scr, k_buf, v_buf, sems,
-            state, *, n_heads, head_dim, page_size, pages_per_block,
-            pages_per_slot, precision):
+def _page_walk(lengths_ref, table_ref, k_hbm, v_hbm, k_buf, v_buf, sems,
+               state, *, page_size, pages_per_block, pages_per_slot):
+    """The page walk both kernels share, for the slot ``s`` of this
+    grid step: its live pages fetched by table into the double buffer,
+    ``pages_per_block`` a block. Returns ``(s, start, each_block)``:
+    ``start()`` sets the first block going unless the slot before
+    already did (operands are built behind it), and
+    ``each_block(compute)`` calls ``compute(i, buf)`` once block ``i``
+    has landed in ``k_buf[buf]`` / ``v_buf[buf]``. While a block is
+    computed the next is in flight, and a slot's last block prefetches
+    the next slot's first."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    H, Dh, ps, ppb, P = (n_heads, head_dim, page_size, pages_per_block,
-                         pages_per_slot)
-    t = q_ref.shape[1]
-    HD = H * Dh
-    bk = ppb * ps
+    ps, ppb, P = page_size, pages_per_block, pages_per_slot
     s = pl.program_id(0)
     n_slots = pl.num_programs(0)
-    length = lengths_ref[s]
-    pos = pos_ref[s]
 
     def live_pages(slot):
         return pages_read(lengths_ref[slot], ps)
-
-    n_blocks = (live_pages(s) + ppb - 1) // ppb
 
     def each_live_page(slot, blk, buf, fn):
         """``fn`` on the K and the V copy of every live page of block
@@ -132,20 +141,89 @@ def _kernel(lengths_ref, pos_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
                 fn(pltpu.make_async_copy(
                     v_hbm.at[page], v_buf.at[buf, rows], sems.at[1, buf]))
 
-    # state[0]: the buffer the next block to compute lands in;
-    # state[1]: the slot whose first block is already in flight
-    @pl.when(s == 0)
-    def _():
-        state[0] = 0
-        state[1] = -1
-        # a block's tail past the slot's live pages is never fetched:
-        # what the buffer holds there must be finite, since the value
-        # product multiplies it by an exact zero
-        v_buf[...] = jnp.zeros_like(v_buf)
+    def start():
+        nonlocal n_blocks
+        n_blocks = (live_pages(s) + ppb - 1) // ppb
 
-    @pl.when(state[1] != s)
-    def _():
-        each_live_page(s, 0, state[0], lambda c: c.start())
+        # state[0]: the buffer the next block to compute lands in;
+        # state[1]: the slot whose first block is already in flight
+        @pl.when(s == 0)
+        def _():
+            state[0] = 0
+            state[1] = -1
+            # a block's tail past the slot's live pages is never
+            # fetched: what the buffer holds there must be finite,
+            # since the value product multiplies it by an exact zero
+            v_buf[...] = jnp.zeros_like(v_buf)
+
+        @pl.when(state[1] != s)
+        def _():
+            each_live_page(s, 0, state[0], lambda c: c.start())
+
+    def each_block(compute):
+        def block(i, carry):
+            buf = state[0]
+            last = i + 1 >= n_blocks
+
+            @pl.when(jnp.logical_not(last))
+            def _():
+                each_live_page(s, i + 1, 1 - buf, lambda c: c.start())
+
+            @pl.when(last & (s + 1 < n_slots))
+            def _():
+                each_live_page(s + 1, 0, 1 - buf, lambda c: c.start())
+                state[1] = s + 1
+
+            each_live_page(s, i, buf, lambda c: c.wait())
+            compute(i, buf)
+            state[0] = 1 - buf
+            return carry
+
+        jax.lax.fori_loop(0, n_blocks, block, 0)
+
+    n_blocks = None     # the slot's blocks, counted by ``start``
+    return s, start, each_block
+
+
+def _softmax_block(sc, v, i, qlim_scr, m_scr, l_scr, acc_scr, precision):
+    """One block of the online softmax: the scores ``sc`` (rows, keys)
+    of block ``i`` masked to the last key each row sees, the running
+    maximum and sum in float32, the probabilities rounded to the
+    values' dtype and multiplied into the accumulator."""
+    k_pos = i * sc.shape[1] + jax.lax.broadcasted_iota(
+        jnp.int32, sc.shape, 1)
+    sc = jnp.where(k_pos <= qlim_scr[:, 0:1], sc, _NEG_INF)
+    m_prev = m_scr[:, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+    # block 0 holds key 0, which every row of a slot with a token
+    # sees: m_new is finite from there on, and a masked score's
+    # exp(_NEG_INF - m_new) is an exact zero
+    p = jnp.exp(sc - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_scr[:, 0:1] * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision)
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+
+def _kernel(lengths_ref, pos_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
+            qx_scr, qlim_scr, m_scr, l_scr, acc_scr, k_buf, v_buf, sems,
+            state, *, n_heads, head_dim, page_size, pages_per_block,
+            pages_per_slot, precision):
+    from jax.experimental import pallas as pl
+
+    H, Dh = n_heads, head_dim
+    t = q_ref.shape[1]
+    HD = H * Dh
+    s, start, each_block = _page_walk(
+        lengths_ref, table_ref, k_hbm, v_hbm, k_buf, v_buf, sems, state,
+        page_size=page_size, pages_per_block=pages_per_block,
+        pages_per_slot=pages_per_slot)
+    length = lengths_ref[s]
+    pos = pos_ref[s]
+    start()
 
     m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
@@ -165,20 +243,7 @@ def _kernel(lengths_ref, pos_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
             (H, qlim_scr.shape[1]), jnp.minimum(pos + j, length - 1),
             jnp.int32)
 
-    def block(i, carry):
-        buf = state[0]
-        last = i + 1 >= n_blocks
-
-        @pl.when(jnp.logical_not(last))
-        def _():
-            each_live_page(s, i + 1, 1 - buf, lambda c: c.start())
-
-        @pl.when(last & (s + 1 < n_slots))
-        def _():
-            each_live_page(s + 1, 0, 1 - buf, lambda c: c.start())
-            state[1] = s + 1
-
-        each_live_page(s, i, buf, lambda c: c.wait())
+    def block(i, buf):
         qx = qx_scr[...]
         k = k_buf[buf].astype(qx.dtype)
         v = v_buf[buf].astype(qx.dtype)
@@ -186,25 +251,10 @@ def _kernel(lengths_ref, pos_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
             qx, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=precision) * (Dh ** -0.5)
-        k_pos = i * bk + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        sc = jnp.where(k_pos <= qlim_scr[:, 0:1], sc, _NEG_INF)
-        m_prev = m_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        # block 0 holds key 0, which every row of a slot with a token
-        # sees: m_new is finite from there on, and a masked score's
-        # exp(_NEG_INF - m_new) is an exact zero
-        p = jnp.exp(sc - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_scr[:, 0:1] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-        state[0] = 1 - buf
-        return carry
+        _softmax_block(sc, v, i, qlim_scr, m_scr, l_scr, acc_scr,
+                       precision)
 
-    jax.lax.fori_loop(0, n_blocks, block, 0)
+    each_block(block)
 
     # a slot of length 0 ran no block: acc and l are 0, its rows come
     # out 0 and not NaN
@@ -214,6 +264,70 @@ def _kernel(lengths_ref, pos_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
         kept = jnp.where(own, acc_scr[rows, :] * inv[rows], 0.0)
         o_ref[0, j:j + 1, :] = jnp.sum(
             kept, axis=0, keepdims=True).astype(o_ref.dtype)
+
+
+def _grouped_kernel(lengths_ref, pos_ref, table_ref, q_ref, k_hbm, v_hbm,
+                    o_ref, qx_scr, qlim_scr, m_scr, l_scr, acc_scr, k_buf,
+                    v_buf, sems, state, *, n_heads, n_kv_heads, page_size,
+                    pages_per_block, pages_per_slot, precision):
+    """``_kernel`` for grouped-query heads of two widths: ``n_heads``
+    query heads of ``dq`` over ``n_kv_heads`` key heads of ``dq`` and
+    value heads of ``dv``. ``q_ref`` (1, t * H, dq) holds row ``(j,
+    h)`` at ``j * H + h``; the block-diagonal operand puts it at the
+    columns of key head ``h // (H / K)`` of a flat ``(K * dq,)`` key
+    row, so ``scores = Qx @ K_block^T`` is ``(t * H, block)`` in one
+    pass over the block. The value product is ``(t * H, K * dv)``, of
+    which row ``(j, h)`` keeps its own key head's ``dv`` columns:
+    ``o_ref`` (1, t * H, dv)."""
+    from jax.experimental import pallas as pl
+
+    H, K = n_heads, n_kv_heads
+    R, dq = q_ref.shape[1:]
+    dv = o_ref.shape[2]
+    s, start, each_block = _page_walk(
+        lengths_ref, table_ref, k_hbm, v_hbm, k_buf, v_buf, sems, state,
+        page_size=page_size, pages_per_block=pages_per_block,
+        pages_per_slot=pages_per_slot)
+    start()
+
+    def key_head(width):
+        """(R, width): the key head that row ``j * H + h`` reads."""
+        row = jax.lax.broadcasted_iota(jnp.int32, (R, width), 0)
+        return jax.lax.div(jax.lax.rem(row, H), H // K)
+
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    # (selected in float32, as in ``_kernel``)
+    q = q_ref[0].astype(jnp.float32)
+    head = key_head(dq)
+    for k in range(K):
+        qx_scr[:, k * dq:(k + 1) * dq] = jnp.where(
+            head == k, q, 0.0).astype(qx_scr.dtype)
+    row = jax.lax.broadcasted_iota(jnp.int32, qlim_scr.shape, 0)
+    qlim_scr[...] = jnp.minimum(pos_ref[s] + jax.lax.div(row, H),
+                                lengths_ref[s] - 1)
+
+    def block(i, buf):
+        qx = qx_scr[...]
+        k = k_buf[buf].astype(qx.dtype)
+        v = v_buf[buf].astype(qx.dtype)
+        sc = jax.lax.dot_general(
+            qx, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=precision) * (dq ** -0.5)
+        _softmax_block(sc, v, i, qlim_scr, m_scr, l_scr, acc_scr,
+                       precision)
+
+    each_block(block)
+
+    # (a slot of length 0: zeros, as in ``_kernel``)
+    head = key_head(dv)
+    out = acc_scr[:, 0:dv]
+    for k in range(1, K):
+        out = jnp.where(head == k, acc_scr[:, k * dv:(k + 1) * dv], out)
+    inv = 1.0 / jnp.maximum(l_scr[:, 0:1], 1e-30)
+    o_ref[0] = (out * inv).astype(o_ref.dtype)
 
 
 def _dot_precision(t: int, dtype):
@@ -228,22 +342,24 @@ def _dot_precision(t: int, dtype):
     return jax.lax.Precision.DEFAULT
 
 
-def _vmem_bytes(n_heads: int, head_dim: int, page_size: int, t: int,
-                dtype) -> int:
-    """The fast memory :func:`pallas_paged_attention` asks for: its
+def _vmem_bytes(rows: int, key_row: int, value_row: int, q_and_o: int,
+                page_size: int, t: int, dtype) -> int:
+    """The fast memory either kernel asks for, over ``rows`` = ``t * H``
+    query rows, pool rows ``key_row`` and ``value_row`` wide and
+    ``q_and_o`` elements in a slot's query and output blocks: the
     scratch as declared there, the double-buffered query and output
     blocks, the float32 value product beside the accumulator and,
     where the dots run float32 passes, the pieces Mosaic splits a
     block of K or V into for them."""
-    HD, R = n_heads * head_dim, t * n_heads
     item = jnp.dtype(dtype).itemsize
     block = max(page_size, _BLOCK_KEYS // page_size * page_size)
     passes = _dot_precision(t, dtype) == jax.lax.Precision.HIGHEST
-    return (2 * 2 * block * HD * item        # K and V, double buffered
-            + passes * block * HD * 4        # an operand's pieces
-            + R * HD * (item + 4 + 4)        # block-diag q, acc, product
-            + 3 * R * 128 * 4                # row limits, max, sum
-            + 2 * 2 * t * HD * item)         # q and o blocks
+    return (2 * block * (key_row + value_row) * item  # K, V double buffered
+            + passes * block * max(key_row, value_row) * 4  # operand pieces
+            + rows * (key_row * item          # block-diag q
+                      + value_row * (4 + 4))  # acc, product
+            + 3 * rows * 128 * 4              # row limits, max, sum
+            + 2 * q_and_o * item)             # q and o blocks
 
 
 @functools.partial(jax.jit, static_argnames=("n_heads", "interpret"))
@@ -310,8 +426,9 @@ def reads_by_table(n_heads: int, head_dim: int, page_size: int, t: int,
             and page_size % sublanes == 0
             and (n_heads * head_dim) % 128 == 0
             and n_heads % 8 == 0
-            and _vmem_bytes(n_heads, head_dim, page_size, t, dtype)
-            <= _VMEM_BUDGET)
+            and _vmem_bytes(t * n_heads, n_heads * head_dim,
+                            n_heads * head_dim, 2 * t * n_heads * head_dim,
+                            page_size, t, dtype) <= _VMEM_BUDGET)
 
 
 def paged_attention(q, k_pool, v_pool, table, pos, n_valid=None, *,
@@ -331,3 +448,79 @@ def paged_attention(q, k_pool, v_pool, table, pos, n_valid=None, *,
     with jax.named_scope("paged_attention/gather"):
         return paged_attention_gather(q, k_pool, v_pool, table, pos,
                                       n_heads)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("n_heads", "n_kv_heads", "interpret"))
+def pallas_paged_attention_grouped(q, k_pool, v_pool, table, lengths, pos,
+                                   *, n_heads: int, n_kv_heads: int,
+                                   interpret: bool = False):
+    """``q`` (S, t, H, dq); ``k_pool`` (n_pages, page_size, K * dq) and
+    ``v_pool`` (n_pages, page_size, K * dv); ``table``, ``lengths``,
+    ``pos`` as in :func:`pallas_paged_attention` → (S, t, H * dv) in
+    ``q``'s dtype. Query head ``h`` reads key/value head
+    ``h // (H / K)``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, t, H, dq = q.shape
+    P = table.shape[1]
+    ps, krow = k_pool.shape[1:]
+    vrow = v_pool.shape[2]
+    dv = vrow // n_kv_heads
+    ppb = max(1, min(P, _BLOCK_KEYS // ps))
+    R = t * H
+    kernel = functools.partial(
+        _grouped_kernel, n_heads=H, n_kv_heads=n_kv_heads, page_size=ps,
+        pages_per_block=ppb, pages_per_slot=P,
+        precision=_dot_precision(t, q.dtype))
+    rows = lambda width: pl.BlockSpec((1, R, width), lambda s, *_: (s, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S,),
+            in_specs=[rows(dq), pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=rows(dv),
+            scratch_shapes=[
+                pltpu.VMEM((R, krow), q.dtype),         # block-diag q
+                pltpu.VMEM((R, 128), jnp.int32),        # last key a row sees
+                pltpu.VMEM((R, 128), jnp.float32),      # running max
+                pltpu.VMEM((R, 128), jnp.float32),      # running sum
+                pltpu.VMEM((R, vrow), jnp.float32),     # accumulator
+                pltpu.VMEM((2, ppb * ps, krow), k_pool.dtype),
+                pltpu.VMEM((2, ppb * ps, vrow), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((S, R, dv), q.dtype),
+        # (slots in turn on one core, as in ``pallas_paged_attention``)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="pallas_paged_attention_grouped",
+    )(lengths.astype(jnp.int32), pos.astype(jnp.int32),
+      table.reshape(-1).astype(jnp.int32), q.reshape(S, R, dq), k_pool,
+      v_pool)
+    return out.reshape(S, t, H * dv)
+
+
+def grouped_reads_by_table(n_heads: int, n_kv_heads: int, qk_head_dim: int,
+                           v_head_dim: int, page_size: int, t: int,
+                           dtype) -> bool:
+    """:func:`reads_by_table` for :func:`pallas_paged_attention_grouped`:
+    on a TPU, a page whole sublane tiles of its dtype, a key row and a
+    value head whole lane tiles (a row of the output is one value
+    head), a slot's ``t * H`` rows whole sublane tiles, and the fast
+    memory within the budget."""
+    sublanes = 32 // jnp.dtype(dtype).itemsize
+    return (jax.default_backend() == "tpu"
+            and page_size % sublanes == 0
+            and (n_kv_heads * qk_head_dim) % 128 == 0
+            and v_head_dim % 128 == 0
+            and (t * n_heads) % sublanes == 0
+            and _vmem_bytes(t * n_heads, n_kv_heads * qk_head_dim,
+                            n_kv_heads * v_head_dim,
+                            t * n_heads * (qk_head_dim + v_head_dim),
+                            page_size, t, dtype) <= _VMEM_BUDGET)

@@ -165,6 +165,52 @@ def test_paged_attention_step_compiles_for_v5e(topo, as_tpu, heads,
     assert _kernels_in(compiled) == int(by_table)
 
 
+@pytest.mark.parametrize("kind, t, kernels", [
+    ("global", 2, 1), ("global", 1, 1), ("window", 2, 0), ("window", 1, 0)],
+    ids=["global_chunk_64x2", "global_decode_64x1", "window_chunk_64x2",
+         "window_decode_64x1"])
+def test_grouped_query_paged_step_compiles_for_v5e(topo, as_tpu, kind, t,
+                                                   kernels):
+    """``GroupedQueryAttentionLayer.apply_stream_paged`` at the shapes
+    of the benchmark's ``mimo_serve_mixedlen`` cell in bfloat16, both
+    step programs. A global layer (64 heads of 192 over 4, values of
+    128; 64 slots x 128 pages of 16): the grouped by-table kernel is
+    in the compiled step, so Mosaic takes a key row of 6 lane tiles
+    beside a value row of 4, the block-diagonal operand's columns at
+    multiples of 192, the 32 KB table in scalar memory and the
+    (slots, t * 64, 128) output. A window layer (8 key heads, window
+    128, a sink; 64 rings of 9 pages): no kernel, the ring slice and
+    ``_attend`` as before."""
+    from deeplearning4j_tpu.nn.conf.layers import GroupedQueryAttentionLayer
+    bf16, slots, d = jnp.bfloat16, 64, 4096
+    layer = GroupedQueryAttentionLayer(
+        n_in=d, n_heads=64, qk_head_dim=192, v_head_dim=128, rotary_dim=64,
+        value_scale=0.707, **(
+            dict(n_kv_heads=4, rope_theta=1e7) if kind == "global"
+            else dict(n_kv_heads=8, window=128, sink=True)))
+    assert layer.paged_reads_by_table(16, t, bf16) == bool(kernels)
+    assert not layer.paged_reads_by_table(8, t, bf16)    # no whole tile
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    K = layer.n_kv_heads
+    params = {"Wq": sds((d, 64 * 192), bf16), "Wk": sds((d, K * 192), bf16),
+              "Wv": sds((d, K * 128), bf16), "Wo": sds((64 * 128, d), bf16)}
+    if layer.sink:
+        params["sink"] = sds((64,), bf16)
+    n_pages = slots * (layer.ring_pages(16) or 128) + 1
+    pool = {"k": sds((n_pages, 16, K * 192), bf16),
+            "v": sds((n_pages, 16, K * 128), bf16)}
+    ints = lambda *shape: sds(shape, jnp.int32)
+    compiled = jax.jit(layer.apply_stream_paged, donate_argnums=(1,)).lower(
+        params, pool, ints(slots, 128), ints(slots),
+        sds((slots, t, d), bf16), ints(slots)).compile()
+    assert _kernels_in(compiled) == kernels
+    # the gathers of a global layer's whole table were 2 x 335 MB (what
+    # is left at t = 1 is XLA's transposed copy of Wq, 101 MB)
+    if kernels:
+        assert compiled.memory_analysis().temp_size_in_bytes < 150e6
+
+
 @pytest.mark.parametrize("t", [4, 1], ids=["chunk_32x4", "decode_32x1"])
 def test_shortcut_expert_block_step_compiles_for_v5e(topo, t):
     """``ShortcutExpertBlock.apply_stream_paged_aux`` at the widths

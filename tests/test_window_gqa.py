@@ -473,15 +473,21 @@ def test_ring_pages_accounting():
     assert sess.step_ring_pages == (3, 10, 1)
 
 
-def test_batcher_serves_what_the_reference_decodes(tiny_net):
+@pytest.mark.parametrize("path", ["gather", "by_table"])
+def test_batcher_serves_what_the_reference_decodes(tiny_net, monkeypatch,
+                                                   path):
     """Through ``ContinuousBatcher`` (chunked prefill at the ring's
     width, ids picked on the device, one step ahead): the greedy ids
     of requests that outgrow their rings are the reference's at every
     position where its best leads by a margin, the expert counters
     fill as for every expert network, and the three ring counters
-    exist and move."""
+    exist and move. With the global layers' pages read by table (the
+    predicate forced, the kernel in interpret mode) the same ids, and
+    the KV positions read fall under the span."""
     from deeplearning4j_tpu.serving.continuous import ContinuousBatcher
     from deeplearning4j_tpu.serving.metrics import ServingMetrics
+    if path == "by_table":
+        _by_table(monkeypatch)
     metrics = ServingMetrics()
     cb = ContinuousBatcher(tiny_net, slots=2, capacity=128,
                            page_size=PAGE, kv_mode="paged",
@@ -512,6 +518,139 @@ def test_batcher_serves_what_the_reference_decodes(tiny_net):
     assert 0 < held < full
     assert read("serving_kv_ring_wraps_total") > 0
     assert read("serving_steps_total") > 0
+    spanned = read("serving_kv_positions_spanned_total")
+    assert spanned == read("serving_steps_total") * 2 * 128
+    if path == "gather":
+        assert read("serving_kv_positions_read_total") == spanned
+    else:
+        assert 0 < read("serving_kv_positions_read_total") < 0.7 * spanned
+
+# ---- the global layer's pages read by table ------------------------
+# (ops/paged_attention.py's grouped kernel in Pallas' interpret mode
+# against ``_attend`` over the gathered table, which stays the CPU
+# path; Mosaic's verdict on the kernel: tests/test_chip_compile.py)
+
+def _by_table(monkeypatch, holds=True):
+    """The layers' shape predicate forced, and the kernel in interpret
+    mode for the CPU."""
+    import functools
+    from deeplearning4j_tpu.ops import paged_attention as PA
+    monkeypatch.setattr(PA, "grouped_reads_by_table", lambda *a: holds)
+    monkeypatch.setattr(
+        PA, "pallas_paged_attention_grouped",
+        functools.partial(PA.pallas_paged_attention_grouped,
+                          interpret=True))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("t", [1, 2, 16])
+@pytest.mark.parametrize("heads", [(16, 2, 192, 128), (8, 4, 128, 128)],
+                         ids=["16_over_2_qk192_v128",
+                              "8_over_4_qk128_v128"])
+def test_grouped_by_table_kernel_matches_attend(monkeypatch, heads, t,
+                                                dtype):
+    """``apply_stream_paged`` of a global layer, the kernel's path
+    against the gather's, on one pool, table and chunk: a free slot, a
+    slot with one token, slots that end on a page's last row and
+    mid-page, one past the first block of 128 keys, a full one, a
+    shared prefix, stale table tails that point at garbage, and
+    ``n_valid`` short of ``t``."""
+    H, K, dq, dv = heads
+    ps, P, S, C = 16, 10, 8, 64               # two blocks of 8 pages
+    cap = P * ps
+    layer = GroupedQueryAttentionLayer(
+        n_in=C, n_heads=H, n_kv_heads=K, qk_head_dim=dq, v_head_dim=dv,
+        rotary_dim=64, value_scale=0.707)
+    assert not layer.paged_reads_by_table(ps, t, dtype)      # the CPU
+    params = jax.tree_util.tree_map(
+        lambda w: w.astype(dtype),
+        _seeded(layer.initialize(jax.random.PRNGKey(0),
+                                 InputType.recurrent(C))[0], seed=5))
+    rng = np.random.default_rng([t, len(dtype), dq])
+    n_live = S * P
+    pool = {"k": rng.normal(size=(n_live + 3, ps, K * dq)),
+            "v": rng.normal(size=(n_live + 3, ps, K * dv))}
+    # pages no slot holds: large finite garbage, which stale table
+    # entries past a slot's length point at
+    garbage = [n_live + 1, n_live + 2]
+    for leaf in pool.values():
+        leaf[garbage] = 1e30
+    table = rng.permutation(np.arange(1, n_live + 1)).reshape(S, P)
+    #        free  one  ends on a page  mid-page  full     shares 3's
+    pos = [0,      0,   2 * ps - t,     37,       cap - t, 2 * ps + 3,
+           0,      8 * ps + 5]         # parked; in the second block
+    n_valid = [0,  1,   t,              t,        t,       max(t - 1, 1),
+               0,  1]
+    pos, n_valid = np.array(pos, np.int32), np.array(n_valid, np.int32)
+    table[0] = 0
+    table[5, :2] = table[3, :2]               # a shared prompt prefix
+    for s in range(S):
+        held = -(-(pos[s] + n_valid[s]) // ps)
+        if s != 6:                            # 6 keeps a whole table
+            table[s, held:] = garbage[s % 2]
+    pool = {name: jnp.asarray(leaf, dtype) for name, leaf in pool.items()}
+    x = jnp.asarray(rng.normal(size=(S, t, C)), dtype)
+    args = (params, pool, jnp.asarray(table, jnp.int32), jnp.asarray(pos),
+            x, jnp.asarray(n_valid))
+    want, want_pool = layer.apply_stream_paged(*args)
+    _by_table(monkeypatch)
+    assert layer.paged_reads_by_table(ps, t, dtype)
+    got, got_pool = layer.apply_stream_paged(*args)
+    for name in pool:
+        np.testing.assert_array_equal(
+            np.asarray(got_pool[name], np.float32),
+            np.asarray(want_pool[name], np.float32))
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    assert not got[[0, 6]].any()              # length 0: zeros, not NaN
+    rows = np.arange(t)[None, :] < n_valid[:, None]
+    assert rows.sum() >= 6
+    assert np.abs(want[rows]).max() > 0.5
+    # float32 to rounding; in bfloat16 one rounding of the output
+    # (``_attend`` rounds the normalised probabilities where the
+    # kernel normalises in float32 after the value product)
+    np.testing.assert_allclose(got[rows], want[rows], rtol=0,
+                               atol=5e-6 if dtype == "float32" else 4e-2)
+
+
+@pytest.mark.parametrize("changed, page, backend, want", [
+    ({}, 16, "tpu", True), ({}, 16, "cpu", False), ({}, 4, "tpu", False),
+    ({"window": 128}, 16, "tpu", False), ({"sink": True}, 16, "tpu", False),
+    ({"v_head_dim": 96}, 16, "tpu", False),
+    ({"n_heads": 4}, 16, "tpu", False)],
+    ids=["mimo_global", "off_a_tpu", "page_no_whole_tile", "window",
+         "sink", "value_head_no_lane_tile", "rows_no_sublane_tile"])
+def test_the_predicate_is_of_the_shapes_and_the_window(monkeypatch, changed,
+                                                       page, backend,
+                                                       want):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    layer = GroupedQueryAttentionLayer(**dict(
+        dict(n_heads=64, n_kv_heads=4, qk_head_dim=192, v_head_dim=128),
+        **changed))
+    for t in (1, 2):
+        assert layer.paged_reads_by_table(page, t, jnp.bfloat16) == want
+
+
+@pytest.mark.parametrize("by_table", [False, True],
+                         ids=["gather", "by_table"])
+def test_kv_positions_count_the_allocators_layers(tiny_net, monkeypatch,
+                                                  by_table):
+    """``step_kv_positions`` asks the layers in the allocator's pages
+    alone: ring layers never read by table and do not make the step a
+    gather. By table a step reads each slot's pages up to its length;
+    with the predicate False the tables' whole span, as before."""
+    _by_table(monkeypatch, by_table)
+    sess = _session(tiny_net, slots=4)
+    answers = [layer.paged_reads_by_table(PAGE, 2, jnp.float32)
+               for layer in tiny_net.layers
+               if hasattr(layer, "apply_stream_paged")]
+    assert answers == [by_table if w is None else False
+                       for w in (None, WINDOW, WINDOW, WINDOW, WINDOW,
+                                 None, WINDOW)]
+    sess._note_kv_read(2, np.array([0, 1, 40, 256], np.int32))
+    spanned = 4 * 256
+    assert sess.step_kv_positions == (
+        (0 + 16 + 48 + 256) if by_table else spanned, spanned)
 
 
 def test_the_defaults_are_the_layers_they_were():
