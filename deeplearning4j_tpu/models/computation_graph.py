@@ -52,6 +52,9 @@ class ComputationGraph(KStepExecutorMixin):
         self._optimizer = None
         self._jit_train_step = None
         self._jit_tbptt_step = None
+        # train programs told to observability.programs, by name
+        # (kstep._register_program)
+        self._registered: Dict[str, Any] = {}
         # k-step fused programs (models/kstep.py): dict k -> jitted
         # scan program, plus AOT-compiled executables keyed by batch
         # signature (warmup() fills; the fit loop dispatches them
@@ -282,20 +285,6 @@ class ComputationGraph(KStepExecutorMixin):
             health = fused_health(loss, grads, updates, constrained)
             return constrained, new_state, new_opt, loss, health
         return constrained, new_state, new_opt, loss
-
-    def _make_train_step(self):
-        core = self._train_core
-
-        # under a mesh context the program's output layout is pinned
-        # to the placed model's (kstep._train_jit_kwargs) — GSPMD
-        # must not drift a carry sharding and recompile every step
-        @functools.partial(jax.jit, donate_argnums=(0, 1, 2),
-                           **self._train_jit_kwargs())
-        def train_step(params, state, opt_state, batch, base_rng, step):
-            rng = jax.random.fold_in(base_rng, step)
-            return core(params, state, opt_state, batch, rng)
-
-        return train_step
 
     def _sync_health_mode(self) -> None:
         """Compile the fused health check into the train step iff a

@@ -37,7 +37,7 @@ this module supplies the window plumbing):
 from __future__ import annotations
 
 import contextlib
-import functools
+import copy
 import time
 from typing import Any, Dict, Sequence, Tuple
 
@@ -144,18 +144,28 @@ def make_kstep_fn(step_core, k: int, health_enabled: bool,
     NEXT window's changed input shardings silently recompile every
     call.
     """
+    import jax
+    return jax.jit(kstep_fn(step_core, k, health_enabled),
+                   **_carry_jit_kwargs(out_shardings))
+
+
+def _carry_jit_kwargs(out_shardings=None) -> dict:
+    """``jax.jit`` options of a train program: the ``(params, state,
+    opt_state)`` carry donated, the outputs pinned under a mesh."""
+    kw = {"donate_argnums": (0, 1, 2)}
+    if out_shardings is not None:
+        kw["out_shardings"] = out_shardings
+    return kw
+
+
+def kstep_fn(step_core, k: int, health_enabled: bool):
+    """The Python function :func:`make_kstep_fn` jits."""
     if k < 2:
         raise ValueError("k-step fusion needs k >= 2; the k=1 path "
                          "is the executor's single-step program")
     import jax
     import jax.numpy as jnp
 
-    jit_kwargs = {}
-    if out_shardings is not None:
-        jit_kwargs["out_shardings"] = out_shardings
-
-    @functools.partial(jax.jit, donate_argnums=(0, 1, 2),
-                       **jit_kwargs)
     def kstep_train(params, state, opt_state, window, base_rng, step0):
         def body(carry, xs):
             p, s, o = carry
@@ -309,12 +319,57 @@ class KStepExecutorMixin:
         n_out = 2 if self._health_enabled else 1
         return self._mesh_ctx.step_out_shardings(self, n_out)
 
-    def _train_jit_kwargs(self) -> dict:
-        """Extra ``jax.jit`` kwargs for the executor's k=1 train
-        step: pinned ``out_shardings`` under a mesh context (see
-        module docstring), nothing otherwise."""
-        sh = self._mesh_out_shardings()
-        return {} if sh is None else {"out_shardings": sh}
+    def _train_step_fn(self):
+        """The Python function of the k=1 train program."""
+        import jax
+        core = self._train_core
+
+        def train_step(params, state, opt_state, batch, base_rng, step):
+            # step arrives as a traced scalar; folding inside the jit
+            # avoids a host-side dispatch per iteration
+            rng = jax.random.fold_in(base_rng, step)
+            return core(params, state, opt_state, batch, rng)
+
+        return train_step
+
+    def _make_train_step(self):
+        # under a mesh context the program's output layout is pinned
+        # to the placed model's: GSPMD must not drift a carry
+        # sharding and recompile every step
+        import jax
+        return jax.jit(self._train_step_fn(), **_carry_jit_kwargs(
+            self._mesh_out_shardings()))
+
+    def _without_arrays(self):
+        """A shallow copy of this executor that can trace its
+        programs and keeps nothing else alive: every attribute that
+        holds a ``jax.Array`` (parameters, optimizer state, the rng
+        key, the latest batch and score), every compiled program and
+        the listeners are dropped. What the traced math reads (the
+        configuration, the layers, the optimizer's rule, the mesh
+        context) is shared with the original."""
+        import jax
+        shell = copy.copy(self)
+        for key, value in vars(self).items():
+            if key.startswith("_jit") or key in ("_aot", "_registered"):
+                setattr(shell, key, {} if isinstance(value, dict) else None)
+            elif key == "listeners" or any(
+                    isinstance(leaf, jax.Array)
+                    for leaf in jax.tree_util.tree_leaves(value)):
+                setattr(shell, key, None)
+        return shell
+
+    def _register_program(self, name: str, jitted, fn_of, args) -> None:
+        """Tell ``observability.programs`` of the train program
+        ``jitted`` (built by this executor) about to run on ``args``
+        for the first time. ``fn_of(executor)`` makes its Python
+        function: it is called on :meth:`_without_arrays`, so what the
+        registry keeps holds no parameter."""
+        from deeplearning4j_tpu.observability import programs
+        self._registered[name] = jitted
+        programs.register(
+            name, fn_of(self._without_arrays()),
+            _carry_jit_kwargs(self._mesh_out_shardings()), args)
 
     def _fit_epoch(self, data_iter, k: int, tbptt) -> None:
         """One epoch's batch loop (shared by both executors' ``fit``):
@@ -434,10 +489,15 @@ class KStepExecutorMixin:
         with trace.span("train_step"):
             if batch is None:
                 batch = self._place_batch(ds, ahead=False)
-            with trace.span("enqueue"):
-                out = self._step_fn_for(batch)(
-                    self.params, self.state, self.opt_state, batch,
+            args = (self.params, self.state, self.opt_state, batch,
                     self._rng_key, np.int32(self.iteration_count))
+            if self._registered.get("train_step") \
+                    is not self._jit_train_step:
+                self._register_program(
+                    "train_step", self._jit_train_step,
+                    KStepExecutorMixin._train_step_fn, args)
+            with trace.span("enqueue"):
+                out = self._step_fn_for(batch)(*args)
         if self._health_enabled:
             (self.params, self.state, self.opt_state,
              loss, self._last_health) = out
@@ -556,13 +616,20 @@ class KStepExecutorMixin:
                 if trace.enabled:
                     sp.set("bytes", _tree_nbytes(window))
             fn = self._kstep_fn_for(window, k)
+            args = (self.params, self.state, self.opt_state, window,
+                    self._rng_key, np.int32(self.iteration_count))
+            name = f"train_step_fused/k={k}"
+            if self._registered.get(name) is not self._jit_kstep.get(k):
+                health = self._health_enabled
+                self._register_program(
+                    name, self._jit_kstep[k],
+                    lambda net: kstep_fn(net._train_core, k, health),
+                    args)
             t1 = time.perf_counter()
             # without a mesh the window is still on the host here: its
             # copy rides the call
             with trace.span("enqueue"):
-                out = fn(self.params, self.state, self.opt_state,
-                         window, self._rng_key,
-                         np.int32(self.iteration_count))
+                out = fn(*args)
             fused.set("steps", k)
         _h2d_wait(trace, window)
         health_host = None

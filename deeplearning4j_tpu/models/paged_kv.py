@@ -83,6 +83,9 @@ __all__ = ["PagedKVAllocator", "PrefixCache", "PagedSlotSession",
            "LEASE_WIRE_VERSION"]
 
 
+# the step consumes the pools it is given and returns their successors
+_DONATE_POOLS = {"donate_argnums": (2,)}
+
 _NOT_CHUNKABLE = ("this network has a layer that is not pointwise in "
                   "time beside its caches; feed it through ")
 
@@ -494,6 +497,9 @@ class PagedSlotSession:
         # (``step_ids``), and the ids its latest call picked: unfetched,
         # the next call's ``prev_ids``
         self._step_ids = None
+        # (kind, t) of the step programs that have run
+        # (``_register_program``)
+        self._registered = set()
         self._prev_ids = jnp.zeros((self.slots,), jnp.int32)
         paged = [i for i, layer in enumerate(net.layers)
                  if hasattr(layer, "apply_stream_paged")]
@@ -969,8 +975,19 @@ class PagedSlotSession:
             finite = jnp.all(jnp.isfinite(row), axis=-1)
             return (ids, finite) + tuple(out[1:])
 
-        self._step = jax.jit(step, donate_argnums=(2,))
-        self._step_ids = jax.jit(step_ids, donate_argnums=(2,))
+        self._step = jax.jit(step, **_DONATE_POOLS)
+        self._step_ids = jax.jit(step_ids, **_DONATE_POOLS)
+
+    def _register_program(self, kind: str, t: int, jitted, args) -> None:
+        """Tell ``observability.programs`` of a step program about to
+        run on ``args`` for the first time, as ``<kind>/t=<rows a
+        slot>``. The closures of
+        ``_make_step`` hold the layers and nothing of ``net.params``,
+        so the registry keeps no array alive through them."""
+        from deeplearning4j_tpu.observability import programs
+        self._registered.add((kind, t))
+        programs.register(f"{kind}/t={t}", jitted.__wrapped__,
+                          _DONATE_POOLS, args)
 
     def _note_kv_read(self, t: int, lengths) -> None:
         """``step_kv_positions`` of a step at ``t`` rows a slot, from
@@ -1056,8 +1073,11 @@ class PagedSlotSession:
         args = (self.net.params, self.net.state, self._pools,
                 jnp.asarray(table), jnp.asarray(pos), x)
         if self._aux_layers:
-            h, self._pools, self.step_aux = self._step(
-                *args, jnp.asarray(active))
+            args += (jnp.asarray(active),)
+        if ("paged_step", 1) not in self._registered:
+            self._register_program("paged_step", 1, self._step, args)
+        if self._aux_layers:
+            h, self._pools, self.step_aux = self._step(*args)
         else:
             h, self._pools = self._step(*args)
         self.slot_pos = self.slot_pos + active.astype(
@@ -1109,9 +1129,13 @@ class PagedSlotSession:
         if self._step is None:
             self._make_step()
         pos = np.where(live, self.slot_pos, 0).astype(np.int32)
-        out = self._step(self.net.params, self.net.state, self._pools,
-                         jnp.asarray(self._table), jnp.asarray(pos), x,
-                         None, jnp.asarray(n_valid))
+        args = (self.net.params, self.net.state, self._pools,
+                jnp.asarray(self._table), jnp.asarray(pos), x, None,
+                jnp.asarray(n_valid))
+        if ("paged_step_chunk", t) not in self._registered:
+            self._register_program("paged_step_chunk", t, self._step,
+                                   args)
+        out = self._step(*args)
         if self._aux_layers:
             h, self._pools, self.step_aux = out
         else:
@@ -1155,9 +1179,12 @@ class PagedSlotSession:
         # place while the step is still in flight
         table = self._table.copy() if t > 1 else np.where(
             live[:, None], self._table, 0)
-        out = self._step_ids(self.net.params, self.net.state,
-                             self._pools, table, pos, x, n_valid,
-                             self._prev_ids, use_prev)
+        args = (self.net.params, self.net.state, self._pools, table,
+                pos, x, n_valid, self._prev_ids, use_prev)
+        if ("paged_step_ids", t) not in self._registered:
+            self._register_program("paged_step_ids", t, self._step_ids,
+                                   args)
+        out = self._step_ids(*args)
         if self._aux_layers:
             ids, finite, self._pools, self.step_aux = out
         else:

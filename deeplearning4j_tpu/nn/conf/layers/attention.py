@@ -746,9 +746,12 @@ class TransformerEncoderLayer(BaseLayer):
         """Paged-cache decode step through the pre-LN block (see
         SelfAttentionLayer.apply_stream_paged)."""
         self._ensure_attn()
-        h = _layer_norm(x, params["ln1_g"], params["ln1_b"])
-        a, pool = self._attn.apply_stream_paged(params["attn"], pool,
-                                                table, pos, h, n_valid)
+        # the halves' names as ``apply`` gives them (metadata only)
+        with jax.named_scope("ln1"):
+            h = _layer_norm(x, params["ln1_g"], params["ln1_b"])
+        with jax.named_scope("attn"):
+            a, pool = self._attn.apply_stream_paged(
+                params["attn"], pool, table, pos, h, n_valid)
         x = x + a
         return x + self._mlp_half(params, x), pool
 

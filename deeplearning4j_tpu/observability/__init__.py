@@ -1,5 +1,5 @@
 """Observability subsystem: tracing, recompile watchdog, unified
-metrics registry, training-step profiler.
+metrics registry, training-step profiler, step-program registry.
 
 The measurement substrate under every perf claim in this repo (the
 reference's PerformanceListener/StatsStorage pipeline, grown into the
@@ -13,6 +13,9 @@ tracing + compile/runtime-attribution subsystem TensorFlow
                      Prometheus text exposition
 - ``step_profile``   data-wait / dispatch / device decomposition +
                      MFU, riding the standard listener chain
+- ``programs``       the step programs this process built, and for
+                     each the table from a device op to the
+                     ``jax.named_scope`` it was traced under
 
 and (ISSUE 3) the layer that WATCHES the measurements and acts:
 

@@ -1033,11 +1033,13 @@ class ContinuousBatcher(ServingBackend):
         pass. The parts are timed on every step into
         ``serving_step_seconds``: ``admit`` is the scheduling (both
         halves, before and after the enqueue), ``device`` the enqueue
-        and the wait for the ids that are due, ``sample`` their
-        delivery; while the tracer is on they are ``serve_step/<part>``
-        spans under one ``serve_step`` (the few lines of ``_advance``
-        run inside ``serve_step/device``). A pass that found no live
-        slot leaves neither."""
+        (alone in ``serving_step_enqueue_seconds``) and the wait for
+        the ids that are due, ``sample`` their delivery; while the
+        tracer is on they are ``serve_step/<part>`` spans under one
+        ``serve_step``, with ``serve_step/enqueue`` and
+        ``serve_step/fetch`` under ``serve_step/device`` (the few
+        lines of ``_advance`` run between the two). A pass that found
+        no live slot leaves neither."""
         while not self._stop.is_set():
             if self._inflight is None and not self._pending and not any(
                     s is not None and not s.parked for s in self._slots):
@@ -1067,13 +1069,16 @@ class ContinuousBatcher(ServingBackend):
                 t1 = time.perf_counter()
                 with trace.span("serve_step/device"):
                     try:
-                        self._enqueue_step(st)
-                        t_adv = time.perf_counter()
+                        with trace.span("serve_step/enqueue"):
+                            self._enqueue_step(st)
+                        t_enq = time.perf_counter()
                         self._advance(st)
-                        t_adv = time.perf_counter() - t_adv
+                        t_adv = time.perf_counter() - t_enq
                         self._inflight = None if st.sync else st
                         due = st if st.sync else prev
-                        got = None if due is None else self._fetch(due)
+                        with trace.span("serve_step/fetch"):
+                            got = (None if due is None
+                                   else self._fetch(due))
                     except BaseException as e:
                         self._device_failed(e, st, prev)
                         continue
@@ -1090,7 +1095,7 @@ class ContinuousBatcher(ServingBackend):
                     t2 - t1 - t_adv + fetched, t3 - t2 + delivered,
                     st.n_prompt, len(st.emitters),
                     "chunk" if chunk else "single", st.prompt_tokens,
-                    ahead=prev is not None)
+                    ahead=prev is not None, enqueue_s=t_enq - t1)
                 if self._paged:
                     self._steps.record_kv_positions(
                         *self.session.step_kv_positions)

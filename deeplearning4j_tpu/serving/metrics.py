@@ -235,7 +235,9 @@ class BatcherStepMetrics:
     admission, planning the step and moving the slots past it),
     ``device`` (the step's enqueue and the wait for the ids that are
     due: the previous step's where the loop runs one step ahead, the
-    step's own in a synchronous pass) and ``sample`` (their
+    step's own in a synchronous pass;
+    ``serving_step_enqueue_seconds`` is the enqueue alone, the host's
+    dispatch of the step) and ``sample`` (their
     delivery: host sampling where a request has a temperature,
     bookkeeping, waking waiters);
     ``serving_lookahead_steps_total`` counts the steps enqueued while
@@ -279,6 +281,11 @@ class BatcherStepMetrics:
                 labels={"endpoint": name, "part": part},
                 buckets=_EDGES)
             for part in ("admit", "device", "sample")}
+        self._enqueue = reg.histogram(
+            "serving_step_enqueue_seconds",
+            help="the host's dispatch of a step, of its device part "
+                 "(seconds)",
+            labels={"endpoint": name}, buckets=_EDGES)
         self._kinds = {
             kind: reg.counter(
                 "serving_slot_steps_total",
@@ -304,10 +311,11 @@ class BatcherStepMetrics:
     def record(self, admit_s: float, device_s: float, sample_s: float,
                prompt_slots: int, decode_slots: int,
                program: str = "single", prompt_tokens: int = 0,
-               ahead: bool = False) -> None:
+               ahead: bool = False, enqueue_s: float = 0.0) -> None:
         self._parts["admit"].record(admit_s)
         self._parts["device"].record(device_s)
         self._parts["sample"].record(sample_s)
+        self._enqueue.record(enqueue_s)
         self._kinds["prompt"].inc(prompt_slots)
         self._kinds["decode"].inc(decode_slots)
         self._programs[program].inc()
