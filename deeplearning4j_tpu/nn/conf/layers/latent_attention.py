@@ -171,7 +171,6 @@ class LatentAttentionLayer(BaseLayer):
         ``kr`` (B,K,dr); key j is visible to query i iff
         ``j <= q_pos[b, i]``. Returns (B,t,C)."""
         from deeplearning4j_tpu.ops.attention import _NEG_INF
-        B, t = q_lat.shape[:2]
         ckv, kr = ckv.astype(q_lat.dtype), kr.astype(q_lat.dtype)
         s = (einsum_f32("bthr,bkr->bhtk", q_lat, ckv)
              + einsum_f32("bthd,bkd->bhtk", q_rope, kr))
@@ -180,7 +179,13 @@ class LatentAttentionLayer(BaseLayer):
         s = jnp.where((k_pos <= q_pos[:, :, None])[:, None], s,
                       _NEG_INF)
         p = jax.nn.softmax(s, axis=-1).astype(q_lat.dtype)
-        o_lat = jnp.einsum("bhtk,bkr->bthr", p, ckv)
+        return self._from_latent(
+            params, jnp.einsum("bhtk,bkr->bthr", p, ckv))
+
+    def _from_latent(self, params, o_lat):
+        """Each head's weighted sum of the latent, (B,t,H,rkv),
+        through ``W_kvb``'s value half and ``Wo``: (B,t,C)."""
+        B, t = o_lat.shape[:2]
         _, wv = self._kvb(params)
         o = jnp.einsum("bthr,rhd->bthd", o_lat, wv)
         return _mm(o.reshape(B, t, -1), params["Wo"])
@@ -228,11 +233,27 @@ class LatentAttentionLayer(BaseLayer):
     # ---- paged latent cache ----
     def zero_page_pool(self, n_pages: int, page_size: int, dtype):
         """The physical pool of this layer: the normed latent and the
-        rotated shared key of every cached token, by page."""
+        rotated shared key of every cached token, by page. The key's
+        row is whole lane tiles, zeros past ``qk_rope_head_dim``: a
+        page of narrower rows is one Mosaic will not copy
+        (``ops/paged_attention.py``'s latent kernel)."""
+        from deeplearning4j_tpu.ops.paged_attention import lane_tiled
         return {"ckv": jnp.zeros((n_pages, page_size,
                                   self.kv_lora_rank), dtype),
                 "kr": jnp.zeros((n_pages, page_size,
-                                 self.qk_rope_head_dim), dtype)}
+                                 lane_tiled(self.qk_rope_head_dim)),
+                                dtype)}
+
+    def paged_reads_by_table(self, page_size: int, t: int, dtype) -> bool:
+        """Will ``apply_stream_paged`` at ``t`` rows a slot read each
+        slot's live pages by table (True), or its whole table
+        (False)? A predicate of the shapes
+        (``ops.paged_attention.latent_reads_by_table``)."""
+        from deeplearning4j_tpu.ops.paged_attention import \
+            latent_reads_by_table
+        return latent_reads_by_table(
+            self.n_heads, self.kv_lora_rank, self.qk_rope_head_dim,
+            page_size, t, dtype)
 
     def apply_stream_paged(self, params, pool, table, pos, x,
                            n_valid=None):
@@ -240,22 +261,39 @@ class LatentAttentionLayer(BaseLayer):
         (the ``SelfAttentionLayer.apply_stream_paged`` contract:
         ``x`` (S,t,C), ``table`` (S,P), ``pos`` (S,), ``n_valid``
         (S,) or None): write each slot's new latent and rotary key at
-        its (page, offset), gather each slot's virtual cache and
-        attend in the absorbed form. Returns (out, pool)."""
+        its (page, offset) and attend in the absorbed form, over the
+        pages a slot holds, read by table and no further than its
+        length, where ``paged_reads_by_table`` holds
+        (``ops/paged_attention.py``'s latent kernel: the same
+        mathematics as ``_attend``, which is its oracle), over each
+        slot's virtual cache gathered whole elsewhere. Returns
+        (out, pool)."""
         from deeplearning4j_tpu.nn.conf.layers.attention import (
             paged_write_targets)
         S, t, _ = x.shape
         ps = pool["ckv"].shape[1]
+        dr = self.qk_rope_head_dim
         wpos, page_ids, offs = paged_write_targets(table, pos, t, ps,
                                                    n_valid)
         q_nope, q_rope, ckv, kr = self._project(params, x, wpos)
         ckv_pool = pool["ckv"].at[page_ids, offs].set(
             ckv.astype(pool["ckv"].dtype))
-        kr_pool = pool["kr"].at[page_ids, offs].set(
-            kr.astype(pool["kr"].dtype))
+        kr_pool = pool["kr"].at[page_ids, offs].set(jnp.pad(
+            kr.astype(pool["kr"].dtype),
+            ((0, 0), (0, 0), (0, pool["kr"].shape[2] - dr))))
+        new_pool = {"ckv": ckv_pool, "kr": kr_pool}
+        q_lat = self._absorb(params, q_nope)
+        if self.paged_reads_by_table(ps, t, ckv_pool.dtype):
+            from deeplearning4j_tpu.ops.paged_attention import \
+                pallas_paged_attention_latent
+            lengths = pos + (t if n_valid is None else n_valid)
+            with jax.named_scope("paged_attention/pallas"):
+                o_lat = pallas_paged_attention_latent(
+                    q_lat, q_rope, ckv_pool, kr_pool, table, lengths, pos,
+                    scale=self._softmax_scale())
+            return self._from_latent(params, o_lat), new_pool
         K = table.shape[1] * ps
         out = self._attend(
-            params, self._absorb(params, q_nope), q_rope,
-            ckv_pool[table].reshape(S, K, -1),
-            kr_pool[table].reshape(S, K, -1), wpos)
-        return out, {"ckv": ckv_pool, "kr": kr_pool}
+            params, q_lat, q_rope, ckv_pool[table].reshape(S, K, -1),
+            kr_pool[table].reshape(S, K, -1)[..., :dr], wpos)
+        return out, new_pool

@@ -353,6 +353,10 @@ class LatentDecoderBlock(BaseLayer):
         return self._ensure_parts()[0].zero_page_pool(
             n_pages, page_size, dtype)
 
+    def paged_reads_by_table(self, page_size: int, t: int, dtype) -> bool:
+        return self._ensure_parts()[0].paged_reads_by_table(
+            page_size, t, dtype)
+
     def apply_stream_paged_aux(self, params, pool, table, pos, x,
                                active=None, n_valid=None):
         """(out, pool, counts): one decode step through the block;
@@ -500,6 +504,12 @@ class ShortcutExpertBlock(BaseLayer):
         attn, _ = self._ensure_parts()
         return {f"a{i}": attn.zero_page_pool(n_pages, page_size, dtype)
                 for i in (0, 1)}
+
+    def paged_reads_by_table(self, page_size: int, t: int, dtype) -> bool:
+        """Both attentions are one layer object over two pools of one
+        shape: its answer is the answer of both."""
+        return self._ensure_parts()[0].paged_reads_by_table(
+            page_size, t, dtype)
 
     def apply_stream_paged_aux(self, params, pool, table, pos, x,
                                active=None, n_valid=None):

@@ -37,13 +37,13 @@ fetches nothing and yields zero rows.
 ``paged_attention`` dispatches: this kernel on a TPU for the shapes it
 tiles, the gather elsewhere (the CPU path and the tests' oracle).
 
-``pallas_paged_attention_grouped`` is the same walk for grouped-query
-heads of two widths (``GroupedQueryAttentionLayer``: ``H`` query heads
-of ``dq`` over ``K`` key heads of ``dq`` and value heads of ``dv``): a
-second kernel body that shares the page walk (``_page_walk``) and the
-online-softmax block and none of the head algebra. The layer
-dispatches on ``grouped_reads_by_table``; its ``_attend`` over the
-gathered table is the other path and the oracle.
+``pallas_paged_attention_grouped`` (grouped-query heads of two widths,
+``GroupedQueryAttentionLayer``) and ``pallas_paged_attention_latent``
+(the absorbed latent attention, ``LatentAttentionLayer``: one shared
+key head, whose values are the keys' own latent part) are further
+bodies that share the page walk (``_page_walk``) and the online-softmax
+block and none of the head algebra. Each layer dispatches on its own
+predicate; its ``_attend`` over the gathered table is the oracle.
 """
 
 from __future__ import annotations
@@ -55,10 +55,10 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.ops.attention import _NEG_INF
 
-__all__ = ["grouped_reads_by_table", "paged_attention",
-           "paged_attention_gather", "pallas_paged_attention",
-           "pallas_paged_attention_grouped", "pages_read",
-           "reads_by_table"]
+__all__ = ["grouped_reads_by_table", "lane_tiled", "latent_reads_by_table",
+           "paged_attention", "paged_attention_gather",
+           "pallas_paged_attention", "pallas_paged_attention_grouped",
+           "pallas_paged_attention_latent", "pages_read", "reads_by_table"]
 
 # keys a block: one lane tile of scores. A live slot of the serving
 # cells holds about a hundred tokens, so most slots are one block
@@ -105,7 +105,7 @@ def paged_attention_gather(q, k_pool, v_pool, table, pos, n_heads):
 
 def _page_walk(lengths_ref, table_ref, k_hbm, v_hbm, k_buf, v_buf, sems,
                state, *, page_size, pages_per_block, pages_per_slot):
-    """The page walk both kernels share, for the slot ``s`` of this
+    """The page walk the kernels share, for the slot ``s`` of this
     grid step: its live pages fetched by table into the double buffer,
     ``pages_per_block`` a block. Returns ``(s, start, each_block)``:
     ``start()`` sets the first block going unless the slot before
@@ -344,7 +344,7 @@ def _dot_precision(t: int, dtype):
 
 def _vmem_bytes(rows: int, key_row: int, value_row: int, q_and_o: int,
                 page_size: int, t: int, dtype) -> int:
-    """The fast memory either kernel asks for, over ``rows`` = ``t * H``
+    """The fast memory a kernel asks for, over ``rows`` = ``t * H``
     query rows, pool rows ``key_row`` and ``value_row`` wide and
     ``q_and_o`` elements in a slot's query and output blocks: the
     scratch as declared there, the double-buffered query and output
@@ -523,4 +523,132 @@ def grouped_reads_by_table(n_heads: int, n_kv_heads: int, qk_head_dim: int,
             and _vmem_bytes(t * n_heads, n_kv_heads * qk_head_dim,
                             n_kv_heads * v_head_dim,
                             t * n_heads * (qk_head_dim + v_head_dim),
+                            page_size, t, dtype) <= _VMEM_BUDGET)
+
+
+def _latent_kernel(lengths_ref, pos_ref, table_ref, ql_ref, qr_ref, kr_hbm,
+                   ckv_hbm, o_ref, qlim_scr, m_scr, l_scr, acc_scr, kr_buf,
+                   ckv_buf, sems, state, *, n_heads, scale, page_size,
+                   pages_per_block, pages_per_slot, precision):
+    """``_kernel`` for the absorbed latent attention: true multi-query
+    attention, every row of ``ql_ref`` (1, t * H, rkv) and ``qr_ref``
+    (1, t * H, dr) (row ``(j, h)`` at ``j * H + h``) over the ONE key
+    head a token has, its latent beside its rotary key. The walk's two
+    leaves are the rotary key (its "key") and the latent (its
+    "value"): ``scores = (q_lat @ ckv^T + q_rope @ kr^T) * scale``,
+    and the values are the latent block itself, so no operand is
+    block-diagonal and the accumulator ``o_ref`` (1, t * H, rkv) is
+    what ``W_kvb``'s value half is applied to outside."""
+    H = n_heads
+    s, start, each_block = _page_walk(
+        lengths_ref, table_ref, kr_hbm, ckv_hbm, kr_buf, ckv_buf, sems,
+        state, page_size=page_size, pages_per_block=pages_per_block,
+        pages_per_slot=pages_per_slot)
+    start()
+
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    row = jax.lax.broadcasted_iota(jnp.int32, qlim_scr.shape, 0)
+    qlim_scr[...] = jnp.minimum(pos_ref[s] + jax.lax.div(row, H),
+                                lengths_ref[s] - 1)
+
+    def block(i, buf):
+        ql, qr = ql_ref[0], qr_ref[0]
+        ckv = ckv_buf[buf].astype(ql.dtype)
+        kr = kr_buf[buf].astype(qr.dtype)
+        dot = lambda q, k: jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+        sc = (dot(ql, ckv) + dot(qr, kr)) * scale
+        _softmax_block(sc, ckv, i, qlim_scr, m_scr, l_scr, acc_scr,
+                       precision)
+
+    each_block(block)
+
+    # (a slot of length 0: zeros, as in ``_kernel``)
+    inv = 1.0 / jnp.maximum(l_scr[:, 0:1], 1e-30)
+    o_ref[0] = (acc_scr[...] * inv).astype(o_ref.dtype)
+
+
+def lane_tiled(width: int) -> int:
+    """``width`` up to whole lane tiles: the row the latent pool keeps
+    a rotary key in (Mosaic will not copy a page of narrower rows)."""
+    return -(-width // 128) * 128
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def pallas_paged_attention_latent(q_lat, q_rope, ckv_pool, kr_pool, table,
+                                  lengths, pos, *, scale: float,
+                                  interpret: bool = False):
+    """``q_lat`` (S, t, H, rkv) and ``q_rope`` (S, t, H, dr), the
+    absorbed query's two halves; ``ckv_pool`` (n_pages, page_size,
+    rkv) and ``kr_pool`` (n_pages, page_size, >= dr: a rotary key and
+    zeros past it, ``LatentAttentionLayer.zero_page_pool``, which the
+    query's zeros there meet); ``scale`` the softmax scale (the
+    layer's, which carries YaRN's); ``table``, ``lengths``, ``pos`` as
+    in :func:`pallas_paged_attention` → (S, t, H, rkv) in ``q_lat``'s
+    dtype: each head's weighted sum of the cached latent."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, t, H, rkv = q_lat.shape
+    dr = kr_pool.shape[2]
+    q_rope = jnp.pad(q_rope, ((0, 0),) * 3 + ((0, dr - q_rope.shape[3]),))
+    P = table.shape[1]
+    ps = ckv_pool.shape[1]
+    ppb = max(1, min(P, _BLOCK_KEYS // ps))
+    R = t * H
+    kernel = functools.partial(
+        _latent_kernel, n_heads=H, scale=scale, page_size=ps,
+        pages_per_block=ppb, pages_per_slot=P,
+        precision=_dot_precision(t, q_lat.dtype))
+    rows = lambda width: pl.BlockSpec((1, R, width), lambda s, *_: (s, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S,),
+            in_specs=[rows(rkv), rows(dr),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=rows(rkv),
+            scratch_shapes=[
+                pltpu.VMEM((R, 128), jnp.int32),        # last key a row sees
+                pltpu.VMEM((R, 128), jnp.float32),      # running max
+                pltpu.VMEM((R, 128), jnp.float32),      # running sum
+                pltpu.VMEM((R, rkv), jnp.float32),      # accumulator
+                pltpu.VMEM((2, ppb * ps, dr), kr_pool.dtype),
+                pltpu.VMEM((2, ppb * ps, rkv), ckv_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((S, R, rkv), q_lat.dtype),
+        # (slots in turn on one core, as in ``pallas_paged_attention``)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="pallas_paged_attention_latent",
+    )(lengths.astype(jnp.int32), pos.astype(jnp.int32),
+      table.reshape(-1).astype(jnp.int32), q_lat.reshape(S, R, rkv),
+      q_rope.reshape(S, R, dr), kr_pool, ckv_pool)
+    return out.reshape(S, t, H, rkv)
+
+
+def latent_reads_by_table(n_heads: int, kv_lora_rank: int,
+                          qk_rope_head_dim: int, page_size: int, t: int,
+                          dtype) -> bool:
+    """:func:`reads_by_table` for :func:`pallas_paged_attention_latent`:
+    on a TPU, a page whole sublane tiles of its dtype, the latent row
+    whole lane tiles (the rotary key's row is widened to them in the
+    pool), a slot's ``t * H`` rows whole sublane tiles, and the fast
+    memory within the budget."""
+    sublanes = 32 // jnp.dtype(dtype).itemsize
+    kr_row = lane_tiled(qk_rope_head_dim)
+    return (jax.default_backend() == "tpu"
+            and page_size % sublanes == 0
+            and kv_lora_rank % 128 == 0
+            and (t * n_heads) % sublanes == 0
+            and _vmem_bytes(t * n_heads, kr_row, kv_lora_rank,
+                            t * n_heads * (2 * kv_lora_rank + kr_row),
                             page_size, t, dtype) <= _VMEM_BUDGET)

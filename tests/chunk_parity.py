@@ -17,7 +17,15 @@ session's ``_ring``) is read where its rows live: the slot's own ring
 pages, position ``p`` at ring row ``p mod span``. Such a network's
 cases run at a page of 8 (``run_case(..., page=8)``), where a ring has
 room for the T rows of a chunk, and without ``prefix_resume``: a ring
-is not shared."""
+is not shared.
+
+``latent_by_table`` steers a latent layer's step onto the by-table
+kernel, as the chip takes it: the shapes' predicate forced and the
+kernel in Pallas' interpret mode, for the same cases again; and
+``kv_positions_follow_the_dispatch`` is the one test both files make
+of the session's accounting under either answer."""
+
+import functools
 
 import jax
 import numpy as np
@@ -25,6 +33,57 @@ import numpy as np
 CASES = ("ragged", "prefix_resume", "near_capacity")
 
 SLOTS, CAPACITY, PAGE, T = 4, 32, 4, 8
+
+
+def latent_by_table(monkeypatch, holds=True):
+    """``LatentAttentionLayer``'s shape predicate forced, and the
+    latent kernel in interpret mode for the CPU."""
+    from deeplearning4j_tpu.ops import paged_attention as PA
+    monkeypatch.setattr(PA, "latent_reads_by_table", lambda *a: holds)
+    monkeypatch.setattr(
+        PA, "pallas_paged_attention_latent",
+        functools.partial(PA.pallas_paged_attention_latent,
+                          interpret=True))
+
+
+def kv_positions_follow_the_dispatch(net, monkeypatch, by_table):
+    """The session's accounting asks the blocks of ``net``, which ask
+    their latent attention: by table a step reads each slot's pages up
+    to its length, by the gather its table's whole span; and the
+    batcher's counters carry what the session says."""
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.serving.metrics import (BatcherStepMetrics,
+                                                    ServingMetrics)
+    latent_by_table(monkeypatch, by_table)
+    blocks = [layer for layer in net.layers
+              if hasattr(layer, "apply_stream_paged")]
+    assert len(blocks) >= 2
+    assert all(b.paged_reads_by_table(PAGE, 2, jnp.float32) == by_table
+               for b in blocks)
+    sess = net.paged_slot_streaming_session(capacity=CAPACITY, slots=SLOTS,
+                                            page_size=PAGE)
+    prompt = [int(v) for v in np.random.default_rng(6).integers(1, 96, 9)]
+    sess.bind(1, sess.reserve(prompt, 4))
+    x = np.zeros((SLOTS, T, 1), np.float32)
+    x[1, :, 0] = prompt[:T]
+    sess.step_chunk(x, np.array([0, T, 0, 0], np.int32))
+    spanned = SLOTS * CAPACITY
+    # slot 1 holds two pages, the free slots none
+    assert sess.step_kv_positions == (8 if by_table else spanned, spanned)
+    x = np.zeros((SLOTS, 1, 1), np.float32)
+    x[1, 0, 0] = prompt[T]
+    sess.step_slots(x, np.array([False, True, False, False]))
+    # three pages; the free slots' dummy row fetches the scratch page
+    # their tables name
+    read = (12 + 3 * PAGE) if by_table else spanned
+    assert sess.step_kv_positions == (read, spanned)
+    metrics = ServingMetrics()
+    BatcherStepMetrics(metrics.registry, "e").record_kv_positions(
+        *sess.step_kv_positions)
+    snap = metrics.registry.snapshot()
+    assert snap['serving_kv_positions_read_total{endpoint="e"}'] == read
+    assert snap['serving_kv_positions_spanned_total{endpoint="e"}'] == \
+        spanned
 
 
 def sessions(net, page=PAGE):

@@ -211,24 +211,13 @@ def test_grouped_query_paged_step_compiles_for_v5e(topo, as_tpu, kind, t,
         assert compiled.memory_analysis().temp_size_in_bytes < 150e6
 
 
-@pytest.mark.parametrize("t", [4, 1], ids=["chunk_32x4", "decode_32x1"])
-def test_shortcut_expert_block_step_compiles_for_v5e(topo, t):
-    """``ShortcutExpertBlock.apply_stream_paged_aux`` at the widths
-    and the pool of the benchmark's ``longcat_serve_tooluse`` cell
-    (hidden 6144, 64 heads, 16 held of 512 + 256 experts, top-12; 32
-    slots of 64 pages of 16) in bfloat16, both step programs: the
-    768-wide top-k, two latent pools written and gathered, and the
-    tally beside the output, in a chip's memory."""
+def _block_step(topo, layer, hidden, slots, t):
+    """``layer.apply_stream_paged_aux`` compiled for one described
+    chip in bfloat16 over ``slots`` x 64 pages of 16, as the paged
+    step calls it at ``t`` rows a slot."""
     from deeplearning4j_tpu import dtypes
     from deeplearning4j_tpu.nn.conf.inputs import InputType
-    from deeplearning4j_tpu.nn.conf.layers import ShortcutExpertBlock
-    bf16, slots = jnp.bfloat16, 32
-    layer = ShortcutExpertBlock(
-        n_in=6144, eps=1e-5, n_heads=64, q_lora_rank=1536,
-        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
-        v_head_dim=128, rope_theta=1e7, intermediate_size=12288,
-        n_routed_experts=512, n_zero_experts=256, held=(0, 16),
-        top_k=12, expert_width=2048, routed_scaling_factor=6.0)
+    bf16 = jnp.bfloat16
     one = SingleDeviceSharding(topo.devices[0])
     place = lambda tree: jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
@@ -236,27 +225,107 @@ def test_shortcut_expert_block_step_compiles_for_v5e(topo, t):
     with dtypes.policy_scope(dtypes.Policy(
             param_dtype=bf16, compute_dtype=bf16, output_dtype=bf16)):
         params = place(jax.eval_shape(lambda: layer.initialize(
-            jax.random.PRNGKey(0), InputType.recurrent(6144))[0]))
+            jax.random.PRNGKey(0), InputType.recurrent(hidden))[0]))
     assert {a.dtype for a in jax.tree_util.tree_leaves(params)} == {
         jnp.dtype(bf16)}
     pool = place(jax.eval_shape(
         lambda: layer.zero_page_pool(slots * 64 + 1, 16, bf16)))
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
-    compiled = jax.jit(layer.apply_stream_paged_aux,
-                       donate_argnums=(1,)).lower(
+    return jax.jit(layer.apply_stream_paged_aux,
+                   donate_argnums=(1,)).lower(
         params, pool, sds((slots, 64), jnp.int32),
-        sds((slots,), jnp.int32), sds((slots, t, 6144), bf16),
+        sds((slots,), jnp.int32), sds((slots, t, hidden), bf16),
         sds((slots, t), bool) if t > 1 else sds((slots,), bool),
         sds((slots,), jnp.int32) if t > 1 else None).compile()
+
+
+@pytest.mark.parametrize("t", [2, 1], ids=["chunk_64x2", "decode_64x1"])
+def test_latent_decoder_block_step_compiles_for_v5e(topo, as_tpu, t):
+    """``LatentDecoderBlock.apply_stream_paged_aux`` at the widths and
+    the pool of the benchmark's ``axk1_serve_decode`` cell (hidden
+    7168, 64 heads over a latent of 512 + 64, YaRN, 12 held of 192
+    experts, top-8; 64 slots of 64 pages of 16) in bfloat16, both step
+    programs: the latent by-table kernel is in the compiled step, so
+    Mosaic takes a page of 512-wide latent rows beside one of rotary
+    keys widened to a lane tile, ``t * 64`` rows of one shared key
+    head and the (slots, t * 64, 512) output; and the gathers' copies
+    and float32 scores are gone from the step's temporaries."""
+    from deeplearning4j_tpu.nn.conf.layers import LatentDecoderBlock
+    layer = LatentDecoderBlock(
+        n_in=7168, n_heads=64, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_scaling={"type": "yarn", "factor": 32, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": 4096},
+        n_routed_experts=192, held=(0, 12), top_k=8, expert_width=2048,
+        n_shared_experts=1, routed_scaling_factor=2.5)
+    assert layer.paged_reads_by_table(16, t, jnp.bfloat16)
+    assert not layer.paged_reads_by_table(8, t, jnp.bfloat16)   # no tile
+    compiled = _block_step(topo, layer, 7168, 64, t)
+    assert _kernels_in(compiled) == 1
+    mem = compiled.memory_analysis()
+    # a layer's weights are 1.35 GB and its pool 84 MB; the step's
+    # temporaries are 37 / 41 MB where the gathered copies of 64 x
+    # 1,024 cached rows and the float32 scores made them 152 / 124 MB
+    assert 1.3e9 < mem.argument_size_in_bytes < 1.6e9
+    assert mem.temp_size_in_bytes < 60e6
+
+
+@pytest.mark.parametrize("heads, t, dtype, admitted", [
+    (64, 16, jnp.bfloat16, True), (64, 8, jnp.float32, True),
+    (128, 1, jnp.float32, True), (64, 32, jnp.bfloat16, False)],
+    ids=["chunk_8x16_bf16", "chunk_8x8_f32", "decode_128_heads_f32_passes",
+         "chunk_8x32_refused"])
+def test_latent_kernel_compiles_where_the_predicate_admits(
+        topo, as_tpu, heads, t, dtype, admitted):
+    """The latent kernel alone at the widest row counts
+    ``latent_reads_by_table`` admits (1,024 rows a slot in bfloat16,
+    512 in float32, the float32 passes of a single-row step): Mosaic
+    takes them; at 2,048 rows, which it would refuse for fast memory,
+    the predicate says no and the layer keeps the gather."""
+    from deeplearning4j_tpu.ops import paged_attention as PA
+    assert PA.latent_reads_by_table(heads, 512, 64, 16, t, dtype) == admitted
+    if not admitted:
+        return
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    ints = lambda *shape: sds(shape, jnp.int32)
+    fn = lambda *a: PA.pallas_paged_attention_latent(*a, scale=0.07)
+    compiled = jax.jit(fn).lower(
+        sds((8, t, heads, 512), dtype), sds((8, t, heads, 64), dtype),
+        sds((513, 16, 512), dtype), sds((513, 16, 128), dtype),
+        ints(8, 64), ints(8), ints(8)).compile()
+    assert _kernels_in(compiled) == 1
+
+
+@pytest.mark.parametrize("t", [4, 1], ids=["chunk_32x4", "decode_32x1"])
+def test_shortcut_expert_block_step_compiles_for_v5e(topo, as_tpu, t):
+    """``ShortcutExpertBlock.apply_stream_paged_aux`` at the widths
+    and the pool of the benchmark's ``longcat_serve_tooluse`` cell
+    (hidden 6144, 64 heads, 16 held of 512 + 256 experts, top-12; 32
+    slots of 64 pages of 16) in bfloat16, both step programs: the
+    768-wide top-k, two latent pools written and read by table (one
+    latent kernel an attention, 256 rows a slot in the chunk
+    program), and the tally beside the output, in a chip's memory."""
+    from deeplearning4j_tpu.nn.conf.layers import ShortcutExpertBlock
+    layer = ShortcutExpertBlock(
+        n_in=6144, eps=1e-5, n_heads=64, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, rope_theta=1e7, intermediate_size=12288,
+        n_routed_experts=512, n_zero_experts=256, held=(0, 16),
+        top_k=12, expert_width=2048, routed_scaling_factor=6.0)
+    assert layer.paged_reads_by_table(16, t, jnp.bfloat16)
+    compiled = _block_step(topo, layer, 6144, 32, t)
+    assert _kernels_in(compiled) == 2
     out, new_pool, tally = compiled.output_shardings
     assert set(new_pool) == {"a0", "a1"}
     assert set(tally) == {"held", "zero", "selected"}
     mem = compiled.memory_analysis()
     # a layer's weights are 2.49 GB; the step's temporaries beside
-    # them (gathers of 32 x 1,024 cached rows, scores, the experts'
-    # dense pass) stay under 1 GB
+    # them are 29 / 8 MB where the two gathers of 32 x 1,024 cached
+    # rows and their float32 scores made them 118 / 74 MB
     assert 2.4e9 < mem.argument_size_in_bytes < 2.7e9
-    assert mem.temp_size_in_bytes < 1e9
+    assert mem.temp_size_in_bytes < 50e6
 
 
 @pytest.mark.parametrize("t", [2, 1], ids=["chunk_64x2", "decode_64x1"])

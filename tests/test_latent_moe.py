@@ -159,7 +159,8 @@ def test_paged_prefill_then_decode_matches_the_reference(tiny_net):
     pool = sess._pools[1]
     assert set(pool) == {"ckv", "kr"}
     assert pool["ckv"].shape == (13, 4, 16)      # 12 pages + scratch
-    assert pool["kr"].shape == (13, 4, 4)
+    # the rotary key of 4 in a row of one lane tile, zeros past it
+    assert pool["kr"].shape == (13, 4, 128)
     for i in range(3):
         sess.bind(i, sess.reserve(ids[i, :1], T - 1))
     got = []
@@ -176,11 +177,17 @@ def test_paged_prefill_then_decode_matches_the_reference(tiny_net):
     assert aux.shape == (2, 16) and (aux.sum(axis=1) == 12).all()
 
 
+@pytest.mark.parametrize("path", ["gather", "by_table"])
 @pytest.mark.parametrize("case", chunk_parity.CASES)
-def test_chunk_step_matches_token_by_token(tiny_net, case):
+def test_chunk_step_matches_token_by_token(tiny_net, monkeypatch, case,
+                                           path):
     """The latent pool's cases of tests/chunk_parity.py: the two
     expert layers' counts of a chunk are the one-by-one counts
-    summed, so rows past ``n_valid`` reach no routed expert."""
+    summed, so rows past ``n_valid`` reach no routed expert. Once by
+    the gather (the CPU's path) and once with the pages read by table
+    (the chip's: the predicate forced, the kernel interpreted)."""
+    if path == "by_table":
+        chunk_parity.latent_by_table(monkeypatch)
     chunk_parity.run_case(tiny_net, 96, case)
 
 
@@ -209,9 +216,18 @@ def test_a_network_without_experts_keeps_its_step():
     assert sess._pools[1]["k"].dtype == jnp.float32
 
 
-def test_batcher_serves_what_the_session_decodes(tiny_net):
+@pytest.mark.parametrize("path", ["gather", "by_table"])
+def test_batcher_serves_what_the_session_decodes(tiny_net, monkeypatch,
+                                                 path):
+    """Through ``ContinuousBatcher`` (chunked prefill, ids picked on
+    the device, one step ahead) the ids of a session fed token by
+    token, the expert counters, a prefix hit; with the latent pool
+    read by table (the predicate forced, the kernel interpreted) the
+    same, and the KV positions read fall under the span."""
     from deeplearning4j_tpu.serving.continuous import ContinuousBatcher
     from deeplearning4j_tpu.serving.metrics import ServingMetrics
+    if path == "by_table":
+        chunk_parity.latent_by_table(monkeypatch)
     net = tiny_net
     prompts = [list(map(int, _ids(1, n, seed=n)[0])) for n in (3, 6, 9)]
     want = []
@@ -257,6 +273,10 @@ def test_batcher_serves_what_the_session_decodes(tiny_net):
     assert snap['serving_steps_total{endpoint="axk",program="chunk"}'] \
         == 3
     assert pairs == (fed + 4 * (5 - 1)) * 2 * 4
+    read = snap['serving_kv_positions_read_total{endpoint="axk"}']
+    spanned = snap['serving_kv_positions_spanned_total{endpoint="axk"}']
+    assert (read == spanned) if path == "gather" else (
+        0 < read < 0.5 * spanned)
 
 
 def test_lease_export_import_on_the_latent_pool(tiny_net):
@@ -398,3 +418,121 @@ def test_fit_runs_through_the_block(tiny_net):
     y = np.eye(96, dtype=np.float32)[np.roll(ids, -1, axis=1)]
     net.fit(x, y, epochs=1)
     assert np.isfinite(net.score_value)
+
+
+# ---- the latent pool read by table ---------------------------------
+# (ops/paged_attention.py's latent kernel in Pallas' interpret mode
+# against ``_attend`` over the gathered table, which stays the CPU
+# path; Mosaic's verdict on the kernel: tests/test_chip_compile.py)
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("t", [1, 2, 4])
+@pytest.mark.parametrize("fields", [
+    dict(rope_scaling=YARN), dict(scale_q_lora=True, scale_kv_lora=True)],
+    ids=["yarn_softmax_scale", "scaled_loras"])
+def test_latent_by_table_kernel_matches_attend(monkeypatch, fields, t,
+                                               dtype):
+    """``apply_stream_paged``, the kernel's path against the gather's,
+    on one pool, table and chunk: a free slot, a slot with one token,
+    slots that end on a page's last row, on the last key of the first
+    block of 128 and at capacity (two blocks and a half), one
+    mid-page over a shared prefix with ``n_valid`` short of ``t``, one
+    in the second block, and stale table tails that point at pages of
+    large finite values. YaRN gives the softmax a scale that is not
+    ``(dn + dr) ** -0.5``; the scaled layer caches a scaled latent."""
+    ps, P, S, C = 16, 20, 8, 64
+    cap = P * ps
+    layer = LatentAttentionLayer(n_in=C, n_heads=8, kv_lora_rank=32,
+                                 **fields)
+    if "rope_scaling" in fields:
+        assert layer._softmax_scale() != pytest.approx(12 ** -0.5)
+    assert not layer.paged_reads_by_table(ps, t, dtype)      # the CPU
+    rng = np.random.default_rng([t, len(dtype), len(fields)])
+    params = jax.tree_util.tree_map(
+        lambda w: jnp.asarray(
+            (w.ndim == 1) + rng.normal(0.0, 0.25, w.shape), dtype),
+        layer.initialize(jax.random.PRNGKey(0),
+                         InputType.recurrent(C))[0])
+    n_live = S * P
+    pool = {name: np.zeros((n_live + 3,) + leaf.shape[1:])
+            for name, leaf in layer.zero_page_pool(1, ps, dtype).items()}
+    pool["ckv"][:] = rng.normal(size=pool["ckv"].shape)
+    pool["kr"][..., :4] = rng.normal(size=pool["kr"][..., :4].shape)
+    # pages no slot holds: large finite garbage, which stale table
+    # entries past a slot's length point at
+    garbage = [n_live + 1, n_live + 2]
+    for leaf in pool.values():
+        leaf[garbage] = 1e30
+    table = rng.permutation(np.arange(1, n_live + 1)).reshape(S, P)
+    #        free  one  a page  a block   full     shares 3's
+    pos = [0,      0,   ps - t, 128 - t,  cap - t, 2 * ps + 3,
+           0,      8 * ps + 5]         # parked; in the second block
+    n_valid = [0,  1,   t,      t,        t,       max(t - 1, 1),
+               0,  1]
+    pos, n_valid = np.array(pos, np.int32), np.array(n_valid, np.int32)
+    table[0] = 0
+    table[5, :2] = table[3, :2]               # a shared prompt prefix
+    for s in range(S):
+        held = -(-(pos[s] + n_valid[s]) // ps)
+        if s != 6:                            # 6 keeps a whole table
+            table[s, held:] = garbage[s % 2]
+    pool = {name: jnp.asarray(leaf, dtype) for name, leaf in pool.items()}
+    x = jnp.asarray(rng.normal(size=(S, t, C)), dtype)
+    args = (params, pool, jnp.asarray(table, jnp.int32), jnp.asarray(pos),
+            x, jnp.asarray(n_valid))
+    want, want_pool = layer.apply_stream_paged(*args)
+    chunk_parity.latent_by_table(monkeypatch)
+    assert layer.paged_reads_by_table(ps, t, dtype)
+    got, got_pool = layer.apply_stream_paged(*args)
+    for name in pool:
+        np.testing.assert_array_equal(
+            np.asarray(got_pool[name], np.float32),
+            np.asarray(want_pool[name], np.float32))
+    # a written row is the rotary key and zeros past it
+    assert not np.asarray(got_pool["kr"], np.float32)[
+        table[3, 128 // ps - 1], :, 4:].any()
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    assert not got[[0, 6]].any()              # length 0: zeros, not NaN
+    rows = np.arange(t)[None, :] < n_valid[:, None]
+    assert rows.sum() >= 6
+    assert np.abs(want[rows]).max() > 0.5
+    # float32 to rounding; in bfloat16 one rounding of the output
+    # (``_attend`` rounds the normalised probabilities where the
+    # kernel normalises in float32 after the value product)
+    np.testing.assert_allclose(got[rows], want[rows], rtol=0,
+                               atol=2e-5 if dtype == "float32" else 4e-2)
+
+
+@pytest.mark.parametrize("changed, page, backend, want", [
+    ({}, 16, "tpu", (True, True, True)),
+    ({}, 16, "cpu", (False, False, False)),
+    ({}, 8, "tpu", (False, False, False)),
+    ({"kv_lora_rank": 192}, 16, "tpu", (False, False, False)),
+    ({"n_heads": 4}, 16, "tpu", (False, False, True)),
+    ({"qk_rope_head_dim": 32}, 16, "tpu", (True, True, True)),
+    ({"n_heads": 512}, 16, "tpu", (True, True, False))],
+    ids=["axk1", "off_a_tpu", "page_no_whole_tile",
+         "latent_no_lane_tile", "rows_no_sublane_tile_under_t4",
+         "rotary_key_of_any_width", "rows_past_the_fast_memory_at_t4"])
+def test_the_predicate_is_of_the_shapes(monkeypatch, changed, page,
+                                        backend, want):
+    """``paged_reads_by_table`` at t = 1, 2, 4 of the layer and of
+    the two blocks that carry it (``ShortcutExpertBlock``: one answer
+    for both its attentions, which are one layer over two pools)."""
+    from deeplearning4j_tpu.nn.conf.layers import ShortcutExpertBlock
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    shape = dict(dict(n_in=256, n_heads=64, kv_lora_rank=512,
+                      qk_rope_head_dim=64), **changed)
+    for layer in (LatentAttentionLayer(**shape),
+                  LatentDecoderBlock(**shape),
+                  ShortcutExpertBlock(**shape)):
+        assert tuple(layer.paged_reads_by_table(page, t, jnp.bfloat16)
+                     for t in (1, 2, 4)) == want
+
+
+@pytest.mark.parametrize("by_table", [False, True],
+                         ids=["gather", "by_table"])
+def test_kv_positions_follow_the_dispatch(tiny_net, monkeypatch, by_table):
+    chunk_parity.kv_positions_follow_the_dispatch(tiny_net, monkeypatch,
+                                                  by_table)

@@ -185,7 +185,7 @@ def test_paged_prefill_then_decode_matches_the_reference(tiny_net):
     assert set(pool) == {"a0", "a1"}
     for half in pool.values():
         assert half["ckv"].shape == (13, 4, 16)  # 12 pages + scratch
-        assert half["kr"].shape == (13, 4, 4)
+        assert half["kr"].shape == (13, 4, 128)     # one lane tile
     for i in range(3):
         sess.bind(i, sess.reserve(ids[i, :1], T - 1))
     got = []
@@ -209,13 +209,26 @@ def test_paged_prefill_then_decode_matches_the_reference(tiny_net):
     assert (aux["held"].sum(axis=1) + aux["zero"] == 12).all()
 
 
+@pytest.mark.parametrize("path", ["gather", "by_table"])
 @pytest.mark.parametrize("case", chunk_parity.CASES)
-def test_chunk_step_matches_token_by_token(tiny_net, case):
+def test_chunk_step_matches_token_by_token(tiny_net, monkeypatch, case,
+                                           path):
     """The two-pool layer's cases of tests/chunk_parity.py (ragged
     ``n_valid`` among them): every leaf of both pools, and the three
     counts of a chunk are the one-by-one counts summed, so rows past
-    ``n_valid`` reach no expert, routed or zero."""
+    ``n_valid`` reach no expert, routed or zero. Once by the gather
+    (the CPU's path) and once with both pools read by table (the
+    chip's: the predicate forced, the kernel interpreted)."""
+    if path == "by_table":
+        chunk_parity.latent_by_table(monkeypatch)
     chunk_parity.run_case(tiny_net, 96, case)
+
+
+@pytest.mark.parametrize("by_table", [False, True],
+                         ids=["gather", "by_table"])
+def test_kv_positions_follow_the_dispatch(tiny_net, monkeypatch, by_table):
+    chunk_parity.kv_positions_follow_the_dispatch(tiny_net, monkeypatch,
+                                                  by_table)
 
 
 def test_chunk_step_at_the_cells_width_matches_token_by_token(tiny_net):
