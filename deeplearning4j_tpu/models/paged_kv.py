@@ -45,7 +45,7 @@ vLLM-style paged memory model over the same layer math:
   for it as its first row, so a decode loop need not wait for one
   step's ids before it enqueues the next.
 
-Two kinds of cache live in one session. A layer whose attention sees
+Three kinds of cache live in one session. A layer whose attention sees
 every earlier position keeps it in the allocator's pages, named by
 the slot's table, as above. A layer with a sliding window
 (``ring_pages(page_size) > 0``) keeps a RING of that many pages a
@@ -53,8 +53,13 @@ slot in a pool of its own: slot ``s`` owns pages ``1 + s * R ..
 (s + 1) * R`` for as long as it exists, position ``p`` lives at ring
 row ``p mod (R * page_size)``, and the layer tells by position which
 rows a query may see, so nothing is allocated, exhausted, shared or
-zeroed for this kind. A ring cannot be shared, so a network that has
-one takes no prefix hit and registers no prefix.
+zeroed for this kind. A layer whose state has a FIXED size and no
+positions (a state-space recurrence: the layer has
+``zero_state_pool``) keeps one row a slot in a pool of ``slots`` rows,
+no pages at all: a slot that feeds position 0 starts from zeros
+whatever its row holds, so this kind is not zeroed either. Neither a
+ring nor a state row can be shared, so a network that has one takes
+no prefix hit and registers no prefix.
 
 Page id 0 is a reserved scratch page: a slot that sits a step out is
 given an all-zero page-table row, and the chunk step sends every row
@@ -95,8 +100,8 @@ def _pages_for(tokens: int, page_size: int) -> int:
 
 
 def _no_paged_analog(layer) -> bool:
-    """Does ``layer`` carry state that no page holds (a recurrent
-    carry or a running statistic)?"""
+    """Does ``layer`` carry state that this session has no pool for
+    (an LSTM-style carry or a running statistic)?"""
     return not hasattr(layer, "apply_stream_paged") and (
         hasattr(layer, "zero_state") or hasattr(layer, "apply_stream"))
 
@@ -405,10 +410,10 @@ class _Lease:
     """One admitted stream's page reservation."""
 
     __slots__ = ("pages", "resume_pos", "prefix_hit_tokens",
-                 "prompt_len", "ring_rows")
+                 "prompt_len", "ring_rows", "state_rows")
 
     def __init__(self, pages, resume_pos, prefix_hit_tokens,
-                 prompt_len, ring_rows=None):
+                 prompt_len, ring_rows=None, state_rows=None):
         self.pages = pages                    # table order
         self.resume_pos = resume_pos          # first position to feed
         self.prefix_hit_tokens = prefix_hit_tokens
@@ -417,6 +422,9 @@ class _Lease:
         # position order up to ``resume_pos``: a ring belongs to a
         # slot, so they reach the device at ``bind``
         self.ring_rows = ring_rows
+        # an imported lease's state rows, {layer: [leaf row]}: they
+        # too belong to a slot
+        self.state_rows = state_rows
 
 
 class PagedSlotSession:
@@ -430,8 +438,9 @@ class PagedSlotSession:
     ``n_pages * page_size`` total. Supported layers: paged attention
     (``apply_stream_paged``) of either kind, in the allocator's pages
     or, where the layer gives ``ring_pages(page_size) > 0``, in a
-    ring of that many pages a slot outside the allocator (module
-    docstring), and stateless layers — recurrent carries
+    ring of that many pages a slot outside the allocator, layers
+    with a fixed-size state in a row a slot (``zero_state_pool``;
+    module docstring), and stateless layers — LSTM-style carries
     (``zero_state``) and running statistics have no paged analog;
     build the dense session for those models.
     """
@@ -449,6 +458,7 @@ class PagedSlotSession:
     def __init__(self, net, slots: int, capacity: int,
                  page_size: int = 16, n_pages: Optional[int] = None,
                  dtype=None):
+        import jax
         import jax.numpy as jnp
         for i, layer in enumerate(net.layers):
             if _no_paged_analog(layer):
@@ -484,6 +494,16 @@ class PagedSlotSession:
             and hasattr(layer, "ring_pages") else 0
             for layer in net.layers]
         self._ring_sizes = sorted({r for r in self._ring if r})
+        # layers that keep a fixed-size state in a row a slot
+        self._state = [hasattr(layer, "apply_stream_paged")
+                       and hasattr(layer, "zero_state_pool")
+                       for layer in net.layers]
+        # a ring or a state row belongs to its slot: no prefix is
+        # shared over a network that has either
+        self._slot_owned = bool(self._ring_sizes) or any(self._state)
+        # slots whose state rows a request has written since the
+        # pools were made (``_note_state``)
+        self._state_used = np.zeros((self.slots,), bool)
         # the widest step a slot may be fed: a ring has a page beyond
         # its window, so up to ``page_size`` rows overwrite nothing a
         # row of the same step reads
@@ -536,12 +556,23 @@ class PagedSlotSession:
         # the latest step's ring pages (held, full, overwritten), see
         # ``_note_ring``; stays zero without a ring layer
         self.step_ring_pages = (0, 0, 0)
+        # bytes of the state pools, and the slots of the latest step
+        # that began a request on a row an earlier one had written
+        # (``_note_state``); both stay zero without a state layer
+        self.state_pool_bytes = sum(
+            leaf.nbytes for pool, kept in zip(self._pools, self._state)
+            if kept for leaf in jax.tree_util.tree_leaves(pool))
+        self.step_state_restarts = 0
 
     # ---- pools ----
     def _fresh_pools(self):
         pools = []
-        for layer, ring in zip(self.net.layers, self._ring):
-            if hasattr(layer, "apply_stream_paged"):
+        for layer, ring, kept in zip(self.net.layers, self._ring,
+                                     self._state):
+            if kept:
+                pools.append(layer.zero_state_pool(self.slots,
+                                                   self._dtype))
+            elif hasattr(layer, "apply_stream_paged"):
                 # +1 physical row: page id 0 is the scratch page
                 pools.append(layer.zero_page_pool(
                     (self.slots * ring if ring
@@ -592,7 +623,7 @@ class PagedSlotSession:
         total_pages = _pages_for(T0 + int(n_tokens), self.page_size)
         # a hit would resume behind an empty window: wrong logits,
         # not slow ones
-        shared = ([] if self._ring_sizes
+        shared = ([] if self._slot_owned
                   else self.prefix_cache.lookup(prompt))
         # the LAST prompt token must be re-fed to produce the first
         # output logits, so a hit can cover at most T0 - 1 positions
@@ -629,6 +660,9 @@ class PagedSlotSession:
             self._write_ring_rows(slot, lease.resume_pos,
                                   lease.ring_rows)
             lease.ring_rows = None
+        if lease.state_rows:
+            self._write_state_rows(slot, lease.state_rows)
+            lease.state_rows = None
 
     def release(self, slot: int, register_prompt=None) -> None:
         """Recycle a slot: drop its page references; when the stream
@@ -639,7 +673,7 @@ class PagedSlotSession:
         self.slot_pos[slot] = 0
         if lease is None:
             return
-        if register_prompt is not None and not self._ring_sizes:
+        if register_prompt is not None and not self._slot_owned:
             prompt = np.asarray(register_prompt).reshape(-1)
             n_full = prompt.size // self.page_size
             if n_full > 0:
@@ -659,7 +693,7 @@ class PagedSlotSession:
         and the boundary page may be half-written. Returns how many
         pages were registered."""
         lease = self._leases.get(slot)
-        if lease is None or self._ring_sizes:
+        if lease is None or self._slot_owned:
             return 0
         pos = int(self.slot_pos[slot])
         prompt = np.asarray(prompt).reshape(-1)
@@ -674,18 +708,20 @@ class PagedSlotSession:
     #      the stream (prompt, sampled tokens, rng) is the CALLER's
     #      ``extra`` dict, carried opaquely in the header ----
     def _pool_schema(self) -> List[Optional[List[dict]]]:
-        """Per-layer leaf schema (page-row shape + dtype, and the
-        ring's pages for a layer that keeps one) — what two replicas
-        must agree on for a lease to be portable. None for stateless
-        layers."""
+        """Per-layer leaf schema (page-row shape + dtype, the ring's
+        pages for a layer that keeps one, ``state`` for a layer whose
+        row is a slot's whole state) — what two replicas must agree
+        on for a lease to be portable. None for stateless layers."""
         import jax
         schema: List[Optional[List[dict]]] = []
-        for pool, ring in zip(self._pools, self._ring):
+        for pool, ring, kept in zip(self._pools, self._ring,
+                                    self._state):
             if pool is None:
                 schema.append(None)
                 continue
             leaves = jax.tree_util.tree_leaves(pool)
-            kind = {"ring": ring} if ring else {}
+            kind = ({"ring": ring} if ring
+                    else {"state": True} if kept else {})
             schema.append([dict(shape=list(leaf.shape[1:]),
                                 dtype=str(leaf.dtype), **kind)
                            for leaf in leaves])
@@ -715,14 +751,27 @@ class PagedSlotSession:
                 treedef, [leaf.at[pages, offs].set(jnp.asarray(r))
                           for leaf, r in zip(leaves, rows)])
 
+    def _write_state_rows(self, slot: int, state_rows) -> None:
+        """Put an imported lease's state rows (``{layer: [leaf
+        row]}``) into ``slot``'s row of each state pool."""
+        import jax
+        import jax.numpy as jnp
+        for i, rows in state_rows.items():
+            leaves, treedef = jax.tree_util.tree_flatten(self._pools[i])
+            self._pools[i] = jax.tree_util.tree_unflatten(
+                treedef, [leaf.at[slot].set(jnp.asarray(r))
+                          for leaf, r in zip(leaves, rows)])
+        self._state_used[slot] = True
+
     def export_lease(self, slot: int,
                      extra: Optional[dict] = None) -> bytes:
         """Serialize slot ``slot``'s attention state: a versioned
         header (wire version, page size, position, per-layer pool
         schema, the caller's ``extra``) followed by the raw contents
-        of every page the stream has written and, of a layer that
-        keeps a ring, of the rows the slot's ring still holds, oldest
-        position first, CRC-tagged. The slot
+        of every page the stream has written, of a layer that keeps a
+        ring the rows the slot's ring still holds, oldest position
+        first, and of a layer that keeps a state the slot's row,
+        CRC-tagged. The slot
         and its lease are left untouched — the caller decides
         whether the incumbent keeps decoding (failed handoff) or
         releases (acked migration). Device→host gather happens here,
@@ -736,10 +785,15 @@ class PagedSlotSession:
         pages_written = _pages_for(pos, self.page_size) if pos else 0
         page_ids = lease.pages[:pages_written]
         chunks: List[bytes] = []
-        for pool, ring in zip(self._pools, self._ring):
+        for pool, ring, kept in zip(self._pools, self._ring,
+                                    self._state):
             if pool is None:
                 continue
             for leaf in jax.tree_util.tree_leaves(pool):
+                if kept:
+                    chunks.append(np.ascontiguousarray(
+                        np.asarray(leaf[slot])).tobytes())
+                    continue
                 if ring:
                     pages, offs = self._ring_place(
                         slot, ring, self._ring_span(ring, pos))
@@ -824,13 +878,15 @@ class PagedSlotSession:
             n_leaf_rows = sum(len(s) for s in schema
                               if s is not None)
             # a page of a layer in the allocator's pages, a position
-            # of a layer that keeps a ring
+            # of a layer that keeps a ring, the row of one that keeps
+            # a state
             expect = sum(
                 _np_dtype(d["dtype"]).itemsize
                 * (int(np.prod(d["shape"][1:]))
                    * self._ring_span(d["ring"], pos).size
                    if "ring" in d
-                   else int(np.prod(d["shape"])) * pages_written)
+                   else int(np.prod(d["shape"]))
+                   * (1 if "state" in d else pages_written))
                 for s in schema if s is not None for d in s)
             if len(payload) != expect:
                 raise KVLeaseCorruptError(
@@ -839,19 +895,27 @@ class PagedSlotSession:
                     f"{pages_written} pages)")
             off = 0
             ring_rows: Dict[int, list] = {}
+            state_rows: Dict[int, list] = {}
             for i, pool in enumerate(self._pools):
                 if pool is None:
                     continue
-                if self._ring[i]:
-                    n = self._ring_span(self._ring[i], pos).size
+                if self._state[i] or self._ring[i]:
+                    # a slot's own rows: they wait in the lease for
+                    # ``bind``. A state leaf is one row of the
+                    # schema's shape, a ring leaf the positions its
+                    # ring still holds
+                    rows = state_rows if self._state[i] else ring_rows
+                    held = self._ring_span(self._ring[i], pos).size
                     for spec in schema[i]:
                         dtype = _np_dtype(spec["dtype"])
-                        width = int(np.prod(spec["shape"][1:]))
-                        ring_rows.setdefault(i, []).append(
-                            np.frombuffer(payload, dtype=dtype,
-                                          count=n * width, offset=off
-                                          ).reshape(n, width))
-                        off += n * width * dtype.itemsize
+                        shape = (tuple(spec["shape"]) if self._state[i]
+                                 else (held,
+                                       int(np.prod(spec["shape"][1:]))))
+                        count = int(np.prod(shape))
+                        rows.setdefault(i, []).append(np.frombuffer(
+                            payload, dtype=dtype, count=count,
+                            offset=off).reshape(shape))
+                        off += count * dtype.itemsize
                     continue
                 leaves, treedef = jax.tree_util.tree_flatten(pool)
                 new_leaves = []
@@ -874,7 +938,8 @@ class PagedSlotSession:
             self.allocator.decref(fresh)
             raise
         lease = _Lease(fresh, pos, prefix_hit_tokens=0,
-                       prompt_len=pos, ring_rows=ring_rows)
+                       prompt_len=pos, ring_rows=ring_rows,
+                       state_rows=state_rows)
         return lease, dict(header.get("extra") or {})
 
     # ---- device step ----
@@ -890,8 +955,10 @@ class PagedSlotSession:
         import jax.numpy as jnp
         d, s = jnp.int32(dst), jnp.int32(src)
         for i, pool in enumerate(self._pools):
-            # page ids are the allocator's: a ring has none of them
-            if pool is not None and not self._ring[i]:
+            # page ids are the allocator's: a ring and a state pool
+            # have none of them
+            if pool is not None and not self._ring[i] \
+                    and not self._state[i]:
                 self._pools[i] = self._copy_page(pool, d, s)
 
     def _make_step(self):
@@ -996,7 +1063,7 @@ class PagedSlotSession:
         implies, host arithmetic and no measurement, of the layers
         whose pages the allocator hands out (a ring layer reads its
         own ring whatever the table spans: ``_note_ring`` counts
-        those): layers that read by table (``paged_reads_by_table``:
+        those; a state layer reads no position at all): layers that read by table (``paged_reads_by_table``:
         all of them must, a layer that does not say is taken to
         gather) fetch of each slot the pages up to the one its length
         ends in (``ops.paged_attention.pages_read``, the kernel's own
@@ -1004,9 +1071,10 @@ class PagedSlotSession:
         the device moved is in its trace."""
         spanned = self.slots * self.pages_per_slot * self.page_size
         if t not in self._by_table:
-            paged = [layer for layer, ring in zip(self.net.layers,
-                                                  self._ring)
-                     if hasattr(layer, "apply_stream_paged") and not ring]
+            paged = [layer for layer, ring, kept in zip(
+                         self.net.layers, self._ring, self._state)
+                     if hasattr(layer, "apply_stream_paged")
+                     and not ring and not kept]
             self._by_table[t] = all(
                 hasattr(layer, "paged_reads_by_table")
                 and layer.paged_reads_by_table(self.page_size, t,
@@ -1042,6 +1110,19 @@ class PagedSlotSession:
             over += int((pages(end) - pages(np.maximum(
                 start, ring * ps))).clip(min=0).sum())
         self.step_ring_pages = (held, full, over)
+
+    def _note_state(self, n_valid) -> None:
+        """``step_state_restarts`` of a step about to feed slot ``s``
+        ``n_valid[s]`` rows from ``slot_pos[s]``: the slots that begin
+        a request (position 0) on a state row an earlier request has
+        written. Such a row is neither zeroed nor read: the layer
+        starts from zeros by position. Host arithmetic."""
+        if not self.state_pool_bytes:
+            return
+        fed = n_valid > 0
+        self.step_state_restarts = int(
+            (fed & (self.slot_pos == 0) & self._state_used).sum())
+        self._state_used |= fed
 
     def step_slots(self, x, active):
         """One decode step for every slot at once — the
@@ -1080,6 +1161,7 @@ class PagedSlotSession:
             h, self._pools, self.step_aux = self._step(*args)
         else:
             h, self._pools = self._step(*args)
+        self._note_state(active)
         self.slot_pos = self.slot_pos + active.astype(
             self.slot_pos.dtype)
         # a slot that sits the step out has length 1: its dummy row,
@@ -1140,6 +1222,7 @@ class PagedSlotSession:
             h, self._pools, self.step_aux = out
         else:
             h, self._pools = out
+        self._note_state(n_valid)
         self.slot_pos = self.slot_pos + n_valid
         self._note_kv_read(t, pos + n_valid)
         self._note_ring(pos, n_valid)
@@ -1190,6 +1273,7 @@ class PagedSlotSession:
         else:
             ids, finite, self._pools = out
         self._prev_ids = ids
+        self._note_state(n_valid)
         self.slot_pos = self.slot_pos + n_valid
         self._note_kv_read(t, pos + (n_valid if t > 1 else 1))
         self._note_ring(pos, n_valid)
@@ -1208,3 +1292,4 @@ class PagedSlotSession:
         self._table = np.zeros((self.slots, self.pages_per_slot),
                                np.int32)
         self._pools = self._fresh_pools()
+        self._state_used[:] = False

@@ -47,9 +47,12 @@ from deeplearning4j_tpu.nn.conf.layers.attention import (
 from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
     LatentAttentionLayer,
 )
+from deeplearning4j_tpu.nn.conf.layers.state_space import (
+    Mamba2MixerLayer,
+)
 from deeplearning4j_tpu.nn.conf.layers.moe import (
     SparseExpertsLayer, LatentDecoderBlock, ShortcutExpertBlock,
-    GroupedQueryDecoderBlock,
+    GroupedQueryDecoderBlock, StateSpaceDecoderBlock,
 )
 
 __all__ = [
@@ -73,4 +76,5 @@ __all__ = [
     "GroupedQueryAttentionLayer",
     "LatentAttentionLayer", "SparseExpertsLayer", "LatentDecoderBlock",
     "ShortcutExpertBlock", "GroupedQueryDecoderBlock",
+    "Mamba2MixerLayer", "StateSpaceDecoderBlock",
 ]

@@ -404,6 +404,8 @@ class GroupedQueryAttentionLayer(BaseLayer):
     with ``i - window < j <= i``; None sees every ``j <= i``.
     ``sink``: a learned logit a query head (parameter ``sink``) that
     joins the softmax's denominator and adds no value.
+    ``softmax_scale`` multiplies the scores; None is
+    ``qk_head_dim ** -0.5``.
 
     Two forms of one mathematics: ``apply`` attends over the whole
     sequence, ``apply_stream_paged`` over a paged cache that holds
@@ -432,6 +434,7 @@ class GroupedQueryAttentionLayer(BaseLayer):
     window: Optional[int] = None
     sink: bool = False
     value_scale: float = 1.0
+    softmax_scale: Optional[float] = None
 
     def __post_init__(self):
         if self.n_heads % self.n_kv_heads:
@@ -504,7 +507,9 @@ class GroupedQueryAttentionLayer(BaseLayer):
         K = self.n_kv_heads
         k, v = k.astype(q.dtype), v.astype(q.dtype)
         s = einsum_f32("btkgd,bnkd->bkgtn",
-                       q.reshape(B, t, K, H // K, dq), k) * dq ** -0.5
+                       q.reshape(B, t, K, H // K, dq), k) * (
+            dq ** -0.5 if self.softmax_scale is None
+            else self.softmax_scale)
         qp, kp = q_pos[:, :, None], k_pos[:, None, :]
         seen = (kp >= 0) & (kp <= qp)
         if self.window is not None:
@@ -546,14 +551,38 @@ class GroupedQueryAttentionLayer(BaseLayer):
             return 0
         return -(-self.window // page_size) + 1
 
+    def _value_lanes(self, page_size: int, dtype) -> int:
+        """The width a value head takes in the paged pool:
+        ``v_head_dim``, or that rounded up to whole lane tiles (zeros
+        behind the values) where that alone lets the layer read its
+        pages by table: a row of the kernel's output is one value
+        head."""
+        dv = self.v_head_dim
+        tiled = -(-dv // 128) * 128
+        if tiled != dv and self._by_table(tiled, page_size, 1, dtype):
+            return tiled
+        return dv
+
+    def _by_table(self, lanes: int, page_size: int, t: int, dtype) -> bool:
+        """``paged_reads_by_table`` over a pool whose value heads are
+        ``lanes`` wide."""
+        from deeplearning4j_tpu.ops.paged_attention import \
+            grouped_reads_by_table
+        return (self.window is None and not self.sink
+                and grouped_reads_by_table(
+                    self.n_heads, self.n_kv_heads, self.qk_head_dim,
+                    lanes, page_size, t, dtype))
+
     def zero_page_pool(self, n_pages: int, page_size: int, dtype):
         """{'k': (n_pages, page_size, K * dq), 'v': (.., K * dv)}:
-        rotated keys and scaled values, heads side by side."""
+        rotated keys and scaled values, heads side by side (a value
+        head as wide as ``_value_lanes`` says)."""
         K = self.n_kv_heads
         return {"k": jnp.zeros((n_pages, page_size,
                                 K * self.qk_head_dim), dtype),
                 "v": jnp.zeros((n_pages, page_size,
-                                K * self.v_head_dim), dtype)}
+                                K * self._value_lanes(page_size, dtype)),
+                               dtype)}
 
     def paged_reads_by_table(self, page_size: int, t: int, dtype) -> bool:
         """Will ``apply_stream_paged`` at ``t`` rows a slot read each
@@ -562,12 +591,8 @@ class GroupedQueryAttentionLayer(BaseLayer):
         (``ops.paged_attention.grouped_reads_by_table``); a layer with
         a ``window`` or a ``sink`` keeps ``_attend``: the kernel has
         neither a first position nor a logit in its denominator."""
-        from deeplearning4j_tpu.ops.paged_attention import \
-            grouped_reads_by_table
-        return (self.window is None and not self.sink
-                and grouped_reads_by_table(
-                    self.n_heads, self.n_kv_heads, self.qk_head_dim,
-                    self.v_head_dim, page_size, t, dtype))
+        return self._by_table(self._value_lanes(page_size, dtype),
+                              page_size, t, dtype)
 
     def apply_stream_paged(self, params, pool, table, pos, x,
                            n_valid=None):
@@ -611,23 +636,36 @@ class GroupedQueryAttentionLayer(BaseLayer):
             k_pos = last[:, None] - (last[:, None]
                                      - jnp.arange(span)[None]) % span
         q, k, v = self._project(params, x, wpos)
+        # a value head narrower than the pool keeps it (zeros behind)
+        dv, lanes = self.v_head_dim, pool["v"].shape[-1] // K
+        if lanes != dv:
+            v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, lanes - dv)))
         k_pool = pool["k"].at[page_ids, offs].set(
             k.reshape(S, t, -1).astype(pool["k"].dtype))
         v_pool = pool["v"].at[page_ids, offs].set(
             v.reshape(S, t, -1).astype(pool["v"].dtype))
-        if self.paged_reads_by_table(ps, t, k_pool.dtype):
+        if self._by_table(lanes, ps, t, k_pool.dtype):
             from deeplearning4j_tpu.ops.paged_attention import \
                 pallas_paged_attention_grouped
             lengths = pos + (t if n_valid is None else n_valid)
+            if self.softmax_scale is not None:
+                # the kernel scales by dq ** -0.5: the rest goes into
+                # q (exact where the ratio is a power of two)
+                q = q * (self.softmax_scale * self.qk_head_dim ** 0.5)
             with jax.named_scope("paged_attention/pallas"):
                 o = pallas_paged_attention_grouped(
                     q, k_pool, v_pool, table, lengths, pos,
                     n_heads=self.n_heads, n_kv_heads=K)
+            if lanes != dv:
+                o = o.reshape(S, t, self.n_heads, lanes)[..., :dv] \
+                    .reshape(S, t, -1)
             return o @ params["Wo"], {"k": k_pool, "v": v_pool}
         n = k_pos.shape[1]
-        out = self._attend(
-            params, q, rows(k_pool).reshape(S, n, K, self.qk_head_dim),
-            rows(v_pool).reshape(S, n, K, self.v_head_dim), wpos, k_pos)
+        keys = rows(k_pool).reshape(S, n, K, self.qk_head_dim)
+        values = rows(v_pool).reshape(S, n, K, lanes)
+        if lanes != dv:
+            values = values[..., :dv]
+        out = self._attend(params, q, keys, values, wpos, k_pos)
         return out, {"k": k_pool, "v": v_pool}
 
 

@@ -122,7 +122,11 @@ class EmbeddingLayer(FeedForwardLayer):
 @dataclasses.dataclass
 class EmbeddingSequenceLayer(FeedForwardLayer):
     """Sequence of ids (B,T) → (B,T,n_out) (reference added this in
-    later versions; capability parity with Keras Embedding import)."""
+    later versions; capability parity with Keras Embedding import).
+    ``multiplier`` scales the rows that come out (in float32, rounded
+    once)."""
+
+    multiplier: float = 1.0
 
     seq_parallelizable = True          # per-token gather
 
@@ -136,7 +140,10 @@ class EmbeddingSequenceLayer(FeedForwardLayer):
         idx = x.astype(jnp.int32)
         if idx.ndim == 3 and idx.shape[-1] == 1:
             idx = idx[..., 0]
-        return jnp.take(params["W"], idx, axis=0), state
+        y = jnp.take(params["W"], idx, axis=0)
+        if self.multiplier != 1.0:
+            y = (y.astype(jnp.float32) * self.multiplier).astype(y.dtype)
+        return y, state
 
     def output_type(self, input_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out, input_type.timesteps)

@@ -36,6 +36,11 @@ joins the residual stream at the end of the second.
 block over grouped-query attention (global, or a sliding window with
 a learned sink) and a sigmoid router with a correction bias and no
 shared expert: MiMo-V2's layer.
+
+``StateSpaceDecoderBlock`` is the same residual block over a Mamba-2
+mixer (``state_space.py``) and the dense MLP. It and
+``GroupedQueryDecoderBlock`` take a ``residual_multiplier`` on both
+branches: together they are Granite-4.0-H's two kinds of layer.
 """
 
 from __future__ import annotations
@@ -56,9 +61,11 @@ from deeplearning4j_tpu.nn.conf.layers.base import (BaseLayer,
 from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
     LatentAttentionLayer, _mm)
 from deeplearning4j_tpu.nn.conf.layers.normalization import rms_norm
+from deeplearning4j_tpu.nn.conf.layers.state_space import Mamba2MixerLayer
 
 __all__ = ["SparseExpertsLayer", "LatentDecoderBlock",
-           "ShortcutExpertBlock", "GroupedQueryDecoderBlock", "swiglu"]
+           "ShortcutExpertBlock", "GroupedQueryDecoderBlock",
+           "StateSpaceDecoderBlock", "swiglu"]
 
 _F32 = jnp.float32
 
@@ -230,22 +237,31 @@ class SparseExpertsLayer(BaseLayer):
         return self.apply_counted(params, x)[0], state
 
 
-def _ffn_half(params, h, moe, eps, active=None):
+def _residual(h, f, multiplier=1.0):
+    """``h + multiplier * f`` in ``h``'s dtype; a multiplier that is
+    not 1 scales in float32, so the sum is rounded once."""
+    if multiplier == 1.0:
+        return h + f
+    return (h.astype(_F32) + multiplier * f.astype(_F32)).astype(h.dtype)
+
+
+def _ffn_half(params, h, moe, eps, active=None, multiplier=1.0):
     """The second half of a pre-RMSNorm decoder block,
-    ``(h + F(norm(h)), counts or None)``: ``F`` is the expert layer
-    ``moe`` (parameters ``params["moe"]``) or, where that is None,
-    the dense SiLU-gated MLP ``Wg, Wu, Wd``."""
+    ``(h + multiplier * F(norm(h)), counts or None)``: ``F`` is the
+    expert layer ``moe`` (parameters ``params["moe"]``) or, where that
+    is None, the dense SiLU-gated MLP ``Wg, Wu, Wd``."""
     z = rms_norm(h, params["norm2_gain"], eps)
     if moe is None:
         with jax.named_scope("mlp"):
-            return h + swiglu(z, params["Wg"], params["Wu"],
-                              params["Wd"]), None
+            return _residual(h, swiglu(z, params["Wg"], params["Wu"],
+                                       params["Wd"]), multiplier), None
     f, counts = moe.apply_counted(params["moe"], z, active)
-    return h + f, counts
+    return _residual(h, f, multiplier), counts
 
 
-def _init_decoder_block(block, key, attn, moe):
-    """Parameters of a pre-RMSNorm decoder block over ``attn`` and
+def _init_decoder_block(block, key, attn, moe, mixer="attn"):
+    """Parameters of a pre-RMSNorm decoder block over ``attn`` (the
+    sequence mixer, whose parameters go under the key ``mixer``) and
     ``moe`` (None: the dense MLP of ``block.intermediate_size``)."""
     ka, km, k1, k2, k3 = jax.random.split(key, 5)
     d, ff = block.n_in, block.intermediate_size
@@ -253,7 +269,7 @@ def _init_decoder_block(block, key, attn, moe):
     t = InputType.recurrent(d)
     p = {"norm1_gain": jnp.ones((d,), pd),
          "norm2_gain": jnp.ones((d,), pd),
-         "attn": attn.initialize(ka, t)[0]}
+         mixer: attn.initialize(ka, t)[0]}
     if moe is not None:
         p["moe"] = moe.initialize(km, t)[0]
     else:
@@ -570,6 +586,10 @@ class GroupedQueryDecoderBlock(BaseLayer):
     top_k: int = 4
     expert_width: int = 32
     routed_scaling_factor: float = 1.0
+    # the attention's score scale (None: qk_head_dim ** -0.5) and what
+    # multiplies both branches before they join the residual stream
+    softmax_scale: Optional[float] = None
+    residual_multiplier: float = 1.0
 
     def set_n_in(self, input_type: InputType) -> None:
         if self.n_in is None:
@@ -592,7 +612,8 @@ class GroupedQueryDecoderBlock(BaseLayer):
                 qk_head_dim=self.qk_head_dim,
                 v_head_dim=self.v_head_dim, rotary_dim=self.rotary_dim,
                 rope_theta=self.rope_theta, window=self.window,
-                sink=self.sink, value_scale=self.value_scale, **common)
+                sink=self.sink, value_scale=self.value_scale,
+                softmax_scale=self.softmax_scale, **common)
             self._moe = None
             if self.n_routed_experts:
                 self._moe = SparseExpertsLayer(
@@ -615,8 +636,10 @@ class GroupedQueryDecoderBlock(BaseLayer):
         with jax.named_scope("attn/global" if self.window is None
                              else "attn/window"):
             a = attend(rms_norm(x, params["norm1_gain"], self.eps))
-        return _ffn_half(params, x + a, self._ensure_parts()[1],
-                         self.eps, active)
+        return _ffn_half(
+            params, _residual(x, a, self.residual_multiplier),
+            self._ensure_parts()[1], self.eps, active,
+            self.residual_multiplier)
 
     def apply(self, params, state, x, *, training=False, rng=None,
               mask=None):
@@ -659,3 +682,82 @@ class GroupedQueryDecoderBlock(BaseLayer):
         h, pool, _ = self.apply_stream_paged_aux(
             params, pool, table, pos, x, n_valid=n_valid)
         return h, pool
+
+
+@register_layer
+@dataclasses.dataclass
+class StateSpaceDecoderBlock(BaseLayer):
+    """Pre-RMSNorm decoder block ``h = x + m SSM(norm(x)); y = h + m
+    F(norm(h))``: a Mamba-2 mixer (``Mamba2MixerLayer``, whose fields
+    these are, flat) and the dense SiLU-gated MLP, ``m`` the
+    ``residual_multiplier``. What the mixer carries from token to
+    token has a fixed size, so its paged cache is a row a SLOT
+    (``zero_state_pool``), not pages."""
+
+    n_in: Optional[int] = None
+    eps: float = 1e-5
+    # state-space mixer (Mamba2MixerLayer)
+    n_heads: int = 4
+    head_dim: int = 8
+    state_size: int = 16
+    n_groups: int = 1
+    conv_width: int = 4
+    # dense MLP width
+    intermediate_size: int = 128
+    residual_multiplier: float = 1.0
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if self.n_in is None:
+            self.n_in = input_type.size
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType.recurrent(self.n_in or input_type.size,
+                                   input_type.timesteps)
+
+    def _mixer(self):
+        if not hasattr(self, "_ssm"):
+            self._ssm = Mamba2MixerLayer(
+                n_in=self.n_in, n_heads=self.n_heads,
+                head_dim=self.head_dim, state_size=self.state_size,
+                n_groups=self.n_groups, conv_width=self.conv_width,
+                eps=self.eps, weight_init=self.weight_init,
+                weight_distribution=self.weight_distribution)
+        return self._ssm
+
+    def initialize(self, key, input_type: InputType):
+        self.set_n_in(input_type)
+        return _init_decoder_block(self, key, self._mixer(), None,
+                                   mixer="ssm")
+
+    def _block(self, params, x, mix):
+        """The block's equations; ``mix(z)`` is the mixer over the
+        normed ``z``."""
+        x = x.astype(params["norm1_gain"].dtype)
+        with jax.named_scope("ssm"):
+            a = mix(rms_norm(x, params["norm1_gain"], self.eps))
+        m = self.residual_multiplier
+        return _ffn_half(params, _residual(x, a, m), None, self.eps,
+                         multiplier=m)[0]
+
+    def apply(self, params, state, x, *, training=False, rng=None,
+              mask=None):
+        mix = lambda z: self._mixer().apply(
+            params["ssm"], {}, z, training=training, rng=rng,
+            mask=mask)[0]
+        return self._block(params, x, mix), state
+
+    # ---- paged decode ----
+    def zero_state_pool(self, slots: int, dtype):
+        return self._mixer().zero_state_pool(slots, dtype)
+
+    def apply_stream_paged(self, params, pool, table, pos, x,
+                           n_valid=None):
+        new_pool = []
+
+        def mix(z):
+            a, p = self._mixer().apply_stream_paged(
+                params["ssm"], pool, table, pos, z, n_valid)
+            new_pool.append(p)
+            return a
+
+        return self._block(params, x, mix), new_pool[0]

@@ -116,7 +116,10 @@ class RnnOutputLayer(OutputLayer):
     """Time-distributed output layer (nn/conf/layers/RnnOutputLayer.java).
     Input (B,T,F) → (B,T,n_out); loss masked per timestep. DL4J reshapes
     to 2-d ((B*T),F) internally (FeedForwardToRnnPreProcessor) — here the
-    matmul is applied directly on the 3-d array."""
+    matmul is applied directly on the 3-d array. ``logits_divisor``
+    divides the pre-activations (the loss sees them divided too)."""
+
+    logits_divisor: float = 1.0
 
     # per-timestep logits; local-chunk mean loss pmeans to the global
     # mean under uniform shards (the wrapper enforces divisibility)
@@ -124,6 +127,10 @@ class RnnOutputLayer(OutputLayer):
 
     def output_type(self, input_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out, input_type.timesteps)
+
+    def _pre_output(self, params, x, *, training, rng):
+        z = super()._pre_output(params, x, training=training, rng=rng)
+        return z if self.logits_divisor == 1.0 else z / self.logits_divisor
 
     def loss_from_input(self, params, x, labels, *, training, rng, mask=None):
         z = self._pre_output(params, x, training=training, rng=rng)

@@ -48,9 +48,12 @@ ALLOCATOR (pages for this request's ``prompt + n_tokens`` worst
 case) instead of a per-slot capacity bucket, and slot count is
 bounded by total KV memory. Repeated prompts hit the prefix cache
 and skip the cached part of prefill entirely (the phase ledger
-records ``prefix_hit_tokens``). Models with recurrent carries fall
-back to the dense session (``kv_mode="dense"`` forces it; greedy
-tokens are bit-identical either way — tested).
+records ``prefix_hit_tokens``). A layer whose state has a fixed size
+(a state-space recurrence) keeps it in that session too, a row a
+slot, and a network with one shares no prefix. Models with LSTM-style
+carries or running statistics fall back to the dense session
+(``kv_mode="dense"`` forces it; greedy tokens are bit-identical
+either way — tested).
 """
 
 from __future__ import annotations
@@ -1102,6 +1105,10 @@ class ContinuousBatcher(ServingBackend):
                     if any(self.session.step_ring_pages):
                         self._steps.record_kv_ring(
                             *self.session.step_ring_pages)
+                    if self.session.state_pool_bytes:
+                        self._steps.record_state_rows(
+                            self.session.step_state_restarts,
+                            self.session.state_pool_bytes)
                 step.set("active", len(st.live))
                 step.set("prompt_slots", st.n_prompt)
                 step.set("decode_slots", len(st.emitters))

@@ -263,7 +263,13 @@ class BatcherStepMetrics:
     still keeps, ``serving_kv_ring_pages_full_total`` the pages the
     same slots would hold had that kind kept every position, and
     ``serving_kv_ring_wraps_total`` the ring pages a write of the
-    step began to reuse (0: the traffic never outgrew a ring). The
+    step began to reuse (0: the traffic never outgrew a ring). Over
+    a network with a layer that keeps a fixed-size state in a row a
+    slot, ``serving_state_rows_restarted_total`` adds, a step, the
+    slots that began a request on a row an earlier request had
+    written (the layer starts such a slot from zeros by its position;
+    nothing is zeroed), and the gauge ``serving_state_pool_bytes``
+    holds the size of those pools. The
     request-phase histograms time a request from outside the steps
     that serve it; these say what a step costs and what it was spent
     on."""
@@ -272,7 +278,7 @@ class BatcherStepMetrics:
                  name: str = "generate"):
         reg = registry or MetricsRegistry()
         self._reg, self._name, self._experts = reg, name, None
-        self._kv = self._pairs = self._ring = None
+        self._kv = self._pairs = self._ring = self._state = None
         self._parts = {
             part: reg.histogram(
                 "serving_step_seconds",
@@ -354,6 +360,22 @@ class BatcherStepMetrics:
                     ("wraps", "ring pages a step began to reuse")))
         for counter, n in zip(self._ring, (held, full, wraps)):
             counter.inc(n)
+
+    def record_state_rows(self, restarted: int, pool_bytes: int) -> None:
+        """One step of a network with a layer that keeps a fixed-size
+        state in a row a slot (``PagedSlotSession
+        .step_state_restarts`` / ``.state_pool_bytes``)."""
+        if self._state is None:
+            labels = {"endpoint": self._name}
+            self._state = self._reg.counter(
+                "serving_state_rows_restarted_total",
+                help="slots a step that began a request on a state row "
+                     "an earlier request had written", labels=labels)
+            self._reg.gauge(
+                "serving_state_pool_bytes",
+                help="bytes of the slot-owned state pools",
+                labels=labels).set(pool_bytes)
+        self._state.inc(restarted)
 
     def record_experts(self, counts) -> None:
         """One step's auxiliary counts of a network with expert

@@ -19,6 +19,7 @@ it in the test.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -211,6 +212,38 @@ def test_grouped_query_paged_step_compiles_for_v5e(topo, as_tpu, kind, t,
         assert compiled.memory_analysis().temp_size_in_bytes < 150e6
 
 
+@pytest.mark.parametrize("t", [2, 1], ids=["chunk_64x2", "decode_64x1"])
+def test_narrow_head_paged_step_compiles_for_v5e(topo, as_tpu, t):
+    """``GroupedQueryAttentionLayer.apply_stream_paged`` at the shapes
+    of the benchmark's ``granite_serve_chat`` cell (32 query heads over
+    8 key/value heads of 64, no rotary, scores times 1/64; 64 slots x
+    64 pages of 16, bfloat16): a value head of 64 is no whole lane
+    tile, so the pool keeps it 128 wide and the grouped by-table
+    kernel is in the compiled step, with a key row of 4 lane tiles
+    beside a value row of 8."""
+    from deeplearning4j_tpu.nn.conf.layers import GroupedQueryAttentionLayer
+    bf16, slots, d = jnp.bfloat16, 64, 2048
+    layer = GroupedQueryAttentionLayer(
+        n_in=d, n_heads=32, n_kv_heads=8, qk_head_dim=64, v_head_dim=64,
+        softmax_scale=0.015625)
+    assert layer.paged_reads_by_table(16, t, bf16)
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), tree)
+    params = {"Wq": sds((d, d), bf16), "Wk": sds((d, 512), bf16),
+              "Wv": sds((d, 512), bf16), "Wo": sds((d, d), bf16)}
+    pool = place(jax.eval_shape(
+        lambda: layer.zero_page_pool(slots * 64 + 1, 16, bf16)))
+    assert pool["k"].shape[-1] == 8 * 64 and pool["v"].shape[-1] == 8 * 128
+    ints = lambda *shape: sds(shape, jnp.int32)
+    compiled = jax.jit(layer.apply_stream_paged, donate_argnums=(1,)).lower(
+        params, pool, ints(slots, 64), ints(slots),
+        sds((slots, t, d), bf16), ints(slots)).compile()
+    assert _kernels_in(compiled) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 50e6
+
+
 def _block_step(topo, layer, hidden, slots, t):
     """``layer.apply_stream_paged_aux`` compiled for one described
     chip in bfloat16 over ``slots`` x 64 pages of 16, as the paged
@@ -368,6 +401,52 @@ def test_grouped_query_window_cell_step_fits_v5e(topo, t):
     assert mem.temp_size_in_bytes < 1e9
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 12e9
+
+
+@pytest.mark.parametrize("t", [2, 1], ids=["chunk_64x2", "decode_64x1"])
+def test_state_space_block_step_compiles_for_v5e(topo, t):
+    """``StateSpaceDecoderBlock.apply_stream_paged`` at the widths of
+    the benchmark's ``granite_serve_chat`` cell (hidden 2048, 64 heads
+    of 64 over a state of 128, convolution of 4, MLP of 8192) in
+    bfloat16 over 64 slots, both step programs: the (64, 64, 64, 128)
+    float32 state pool is donated and updated in place, and ONE
+    instruction of the compiled step reads it (the row reductions and
+    the update in one pass over the pool): a masked or rescaled copy
+    for a second reader would be another 134 MB of temporaries."""
+    from deeplearning4j_tpu import dtypes
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.conf.layers import StateSpaceDecoderBlock
+    bf16, slots, d = jnp.bfloat16, 64, 2048
+    layer = StateSpaceDecoderBlock(
+        n_in=d, n_heads=64, head_dim=64, state_size=128, n_groups=1,
+        conv_width=4, intermediate_size=8192, residual_multiplier=0.22)
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), tree)
+    with dtypes.policy_scope(dtypes.Policy(
+            param_dtype=bf16, compute_dtype=bf16, output_dtype=bf16)):
+        params = place(jax.eval_shape(lambda: layer.initialize(
+            jax.random.PRNGKey(0), InputType.recurrent(d))[0]))
+    pool = place(jax.eval_shape(
+        lambda: layer.zero_state_pool(slots, bf16)))
+    assert pool["ssm"].shape == (slots, 64, 64, 128)
+    assert pool["ssm"].dtype == jnp.float32
+    assert pool["conv"].shape == (slots, 3, 4352)
+    compiled = jax.jit(layer.apply_stream_paged, donate_argnums=(1,)).lower(
+        params, pool, sds((slots, 64), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots, t, d), bf16),
+        sds((slots,), jnp.int32) if t > 1 else None).compile()
+    mem = compiled.memory_analysis()
+    state = slots * 64 * 64 * 128 * 4
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state // 2
+    entry = compiled.as_text().split("ENTRY", 1)[1]
+    name = next(m for m in re.findall(r"%([\w.]+) = f32\[64,64,64,128\]"
+                                      r"\S* parameter", entry))
+    readers = [line for line in entry.splitlines()
+               if f"%{name}" in line and " parameter(" not in line]
+    assert len(readers) == 1, readers
 
 
 # ---- four chips: the kernels on a mesh -----------------------------------

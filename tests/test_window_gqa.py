@@ -616,10 +616,11 @@ def test_grouped_by_table_kernel_matches_attend(monkeypatch, heads, t,
 @pytest.mark.parametrize("changed, page, backend, want", [
     ({}, 16, "tpu", True), ({}, 16, "cpu", False), ({}, 4, "tpu", False),
     ({"window": 128}, 16, "tpu", False), ({"sink": True}, 16, "tpu", False),
-    ({"v_head_dim": 96}, 16, "tpu", False),
+    ({"v_head_dim": 96}, 16, "tpu", True),
     ({"n_heads": 4}, 16, "tpu", False)],
     ids=["mimo_global", "off_a_tpu", "page_no_whole_tile", "window",
-         "sink", "value_head_no_lane_tile", "rows_no_sublane_tile"])
+         "sink", "value_head_padded_to_a_lane_tile",
+         "rows_no_sublane_tile"])
 def test_the_predicate_is_of_the_shapes_and_the_window(monkeypatch, changed,
                                                        page, backend,
                                                        want):
@@ -629,6 +630,12 @@ def test_the_predicate_is_of_the_shapes_and_the_window(monkeypatch, changed,
         **changed))
     for t in (1, 2):
         assert layer.paged_reads_by_table(page, t, jnp.bfloat16) == want
+    # since PR 39 a value head narrower than a lane tile takes one in
+    # the pool where that alone admits the kernel
+    pool = jax.eval_shape(lambda: layer.zero_page_pool(3, page,
+                                                       jnp.bfloat16))
+    wide = 128 if want else layer.v_head_dim
+    assert pool["v"].shape == (3, page, layer.n_kv_heads * wide)
 
 
 @pytest.mark.parametrize("by_table", [False, True],
