@@ -43,7 +43,6 @@ from deeplearning4j_tpu.nn.conf.layers.base import (BaseLayer,
 __all__ = ["Mamba2MixerLayer"]
 
 _F32 = jnp.float32
-_EXACT = lax.Precision.HIGHEST
 
 
 @register_layer
@@ -136,7 +135,7 @@ class Mamba2MixerLayer(BaseLayer):
     def _conv(self, params, window, u):
         """The causal convolution of ``u`` (B,t,conv_dim) behind the
         K - 1 inputs before it, ``window`` (B,K-1,conv_dim): ``(x
-        (B,t,H,P), B and C (B,t,H,N) a head, the inputs with their
+        (B,t,H,P), B and C (B,t,G,N) a group, the inputs with their
         window in front (B,K-1+t,conv_dim))``, float32 but the last."""
         t, K = u.shape[1], self.conv_width
         H, G, N = self.n_heads, self.n_groups, self.state_size
@@ -147,11 +146,9 @@ class Mamba2MixerLayer(BaseLayer):
             acc = acc + w[k, 0] * xs[:, k:k + t].astype(_F32)
         a = jax.nn.silu(acc)
         lead, di = a.shape[:2], self.d_inner
-        heads = lambda m: jnp.repeat(m.reshape(*lead, G, N), H // G,
-                                     axis=2)
         return (a[..., :di].reshape(*lead, H, self.head_dim),
-                heads(a[..., di:di + G * N]), heads(a[..., di + G * N:]),
-                xs)
+                a[..., di:di + G * N].reshape(*lead, G, N),
+                a[..., di + G * N:].reshape(*lead, G, N), xs)
 
     def _dt(self, params, dt_raw):
         return jax.nn.softplus(dt_raw
@@ -177,9 +174,11 @@ class Mamba2MixerLayer(BaseLayer):
         x = self.apply_input_dropout(x, training=training, rng=rng)
         B = x.shape[0]
         z, u, dt_raw = self._in_proj(params, x)
-        xh, Bh, Ch, _ = self._conv(
+        xh, Bg, Cg, _ = self._conv(
             params, jnp.zeros((B, self.conv_width - 1, self.conv_dim),
                               u.dtype), u)
+        Bh, Ch = (jnp.repeat(m, self.n_heads // self.n_groups, axis=2)
+                  for m in (Bg, Cg))
         dt = self._dt(params, dt_raw)
         A = -jnp.exp(params["A_log"].astype(_F32))
 
@@ -227,58 +226,76 @@ class Mamba2MixerLayer(BaseLayer):
         exp(c_j - c_i) dt_i (C_j . B_i) x_i + D x_j`` and ``S_t =
         exp(c_t) S_0 + sum_i exp(c_t - c_i) dt_i x_i (outer) B_i``; a
         row past ``n_valid`` has ``dt = 0`` and changes nothing. Every
-        product with the state is elementwise in float32."""
+        product is elementwise in float32."""
         S, t, _ = x.shape
-        K = self.conv_width
+        K, G = self.conv_width, self.n_groups
         if n_valid is None:
             n_valid = jnp.where(table[:, 0] > 0, t, 0)
         fed, fresh = n_valid > 0, pos == 0
+        # the heads of a group side by side: they share the group's
+        # ``B`` and ``C`` rows, which are broadcast where they are
+        # used and never repeated a head
+        heads = lambda m: m.reshape(S, G, -1, *m.shape[2:])
+        state = heads(pool["ssm"])                      # (S,G,H/G,P,N)
         # a fresh slot's row is masked where it is USED (a select, so
         # that whatever the row holds, even a non-finite value, is
         # dropped): a masked copy of the whole state would be written
         # out for its two readers
-        state, restart = pool["ssm"], fresh[:, None, None, None]
+        restart = fresh[:, None, None, None]
         window = jnp.where(fresh[:, None, None], 0, pool["conv"])
         z, u, dt_raw = self._in_proj(params, x)
         with jax.named_scope("state"):
-            xh, Bh, Ch, xs = self._conv(params, window, u)
+            xh, Bg, Cg, xs = self._conv(params, window, u)
             valid = jnp.arange(t)[None, :] < n_valid[:, None]
             dt = jnp.where(valid[..., None], self._dt(params, dt_raw),
                            0.0)
             A = -jnp.exp(params["A_log"].astype(_F32))
-            c = jnp.cumsum(dt * A, axis=1)                  # (S,t,H)
+            D = params["D"].astype(_F32)
+            # The rows' small numbers are sums and products of whole
+            # arrays, a row at a time, which fuse into the programs
+            # that use them: a cumsum, a contraction at the highest
+            # precision or a gather by row is each a few programs of
+            # its own on the device, a layer
+            # (``tests/test_chip_compile.py`` holds that none is left)
+            c = [dt[:, 0] * A]                              # t of (S,H)
+            for i in range(1, t):
+                c.append(c[-1] + dt[:, i] * A)
+            since = lambda j: jnp.exp(
+                c[j][:, None] - jnp.stack(c[:j + 1], axis=1)
+            ) * dt[:, :j + 1]               # exp(c_j - c_i) dt_i, i <= j
             # one reduction a row, each over the state's own shape,
             # so that XLA may fuse them with the state's update below
             # into one pass over the pool
-            y = jnp.where(restart, 0.0, jnp.exp(c)[..., None] * jnp.stack(
-                [jnp.sum(state * Ch[:, j, :, None, :], axis=-1)
-                 for j in range(t)], axis=1))
-            # row i's part of row j's output, j >= i (c falls, so
-            # the exponent of a pair with j < i is positive: masked
-            # before the exp, not after)
-            causal = jnp.tril(jnp.ones((t, t), bool))[None, :, :, None]
-            seg = jnp.where(causal, c[:, :, None] - c[:, None, :],
-                            -jnp.inf)                       # (S,j,i,H)
-            w = jnp.exp(seg) * dt[:, None] * jnp.einsum(
-                "sjhn,sihn->sjih", Ch, Bh, precision=_EXACT)
-            y = y + jnp.einsum("sjih,sihp->sjhp", w, xh,
-                               precision=_EXACT)
-            y = y + params["D"].astype(_F32)[:, None] * xh
+            ys = []
+            for j in range(t):
+                y = heads(jnp.exp(c[j]))[..., None] * jnp.where(
+                    restart, 0.0,
+                    jnp.sum(state * Cg[:, j, :, None, None, :], axis=-1))
+                # the rows up to j: their part of row j's output
+                w = since(j).reshape(S, j + 1, G, -1) * jnp.sum(
+                    Cg[:, j, None] * Bg[:, :j + 1], axis=-1, keepdims=True)
+                ys.append(y.reshape(xh[:, j].shape) + D[:, None] * xh[:, j]
+                          + jnp.sum(w.reshape(S, j + 1, -1, 1)
+                                    * xh[:, :j + 1], axis=1))
             # what row i leaves in the state the step ends with
-            left = jnp.exp(c[:, -1:] - c) * dt              # (S,t,H)
-            new = jnp.where(restart, 0.0,
-                            jnp.exp(c[:, -1])[..., None, None] * state)
+            left = since(t - 1)                             # (S,t,H)
+            new = jnp.where(restart[..., None], 0.0,
+                            heads(jnp.exp(c[-1]))[..., None, None] * state)
             for i in range(t):
-                new = new + (left[:, i, :, None] * xh[:, i])[..., None] \
-                    * Bh[:, i, :, None, :]
+                new = new + heads(left[:, i, :, None] * xh[:, i])[
+                    ..., None] * Bg[:, i, :, None, None, :]
             # the window the next step finds: the K - 1 inputs before
-            # row n_valid
-            rows = n_valid[:, None] + jnp.arange(K - 1)[None, :]
-            tail = jnp.take_along_axis(xs, rows[:, :, None], axis=1)
-            pool = {
-                "ssm": jnp.where(fed[:, None, None, None], new, state),
-                "conv": jnp.where(fed[:, None, None],
-                                  tail.astype(pool["conv"].dtype),
-                                  pool["conv"])}
-            v = self._gate_norm(params, y, z)
+            # row n_valid, its own where the slot fed nothing (one
+            # select a count of rows: a gather by row is a handful of
+            # small programs on the device)
+            tail = pool["conv"]
+            for n in range(1, t + 1):
+                tail = jnp.where((n_valid == n)[:, None, None],
+                                 xs[:, n:n + K - 1].astype(tail.dtype),
+                                 tail)
+            pool = {"ssm": jnp.where(fed[:, None, None, None],
+                                     new.reshape(pool["ssm"].shape),
+                                     pool["ssm"]),
+                    "conv": tail}
+            v = self._gate_norm(params, jnp.stack(ys, axis=1), z)
         return v @ params["W_out"], pool

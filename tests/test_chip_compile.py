@@ -447,6 +447,12 @@ def test_state_space_block_step_compiles_for_v5e(topo, t):
     readers = [line for line in entry.splitlines()
                if f"%{name}" in line and " parameter(" not in line]
     assert len(readers) == 1, readers
+    # and the small numbers of a chunk's rows beside it are whole-array
+    # sums and products that fuse: no contraction, windowed reduction
+    # (a cumsum) or gather, each a few programs of its own a layer
+    small = re.findall(r" (dot|convolution|reduce-window|gather)\(.*"
+                       r'op_name="[^"]*/state/', compiled.as_text())
+    assert not small, small
 
 
 # ---- four chips: the kernels on a mesh -----------------------------------

@@ -152,17 +152,22 @@ def _mixer(seed=0):
     return layer, _perturbed(params, seed)
 
 
-def _mixer_by_position(p, x):
+def _mixer_by_position(p, x, dims=(H, P, N, G), carried=None):
     """The module docstring's equations for one sequence x (T, D),
-    float64, one position at a time."""
+    float64, one position at a time, from zeros; or, given
+    ``carried`` (a state (H, P, N) and the K - 1 inputs before x),
+    from there, and then ``(out, state)``."""
+    H, P, N, G = dims
     p = {k: np.asarray(v, np.float64) for k, v in p.items()}
     x = np.asarray(x, np.float64)
     di, cd = H * P, H * P + 2 * G * N
     silu = lambda a: a / (1 + np.exp(-a))
     proj = x @ p["W_in"]
     z, u, dt_raw = proj[:, :di], proj[:, di:di + cd], proj[:, di + cd:]
-    padded = np.concatenate([np.zeros((K - 1, cd)), u])
-    S, out = np.zeros((H, P, N)), []
+    S, window = (np.zeros((H, P, N)), np.zeros((K - 1, cd))) \
+        if carried is None else (np.array(carried[0], np.float64),
+                                 np.asarray(carried[1], np.float64))
+    padded, out = np.concatenate([window, u]), []
     for t in range(x.shape[0]):
         c = silu(sum(p["conv_w"][k, 0] * padded[t + k] for k in range(K))
                  + p["conv_b"])
@@ -179,7 +184,7 @@ def _mixer_by_position(p, x):
         v = (y.reshape(-1) * silu(z[t])).reshape(G, -1)
         v = v / np.sqrt((v * v).mean(-1, keepdims=True) + 1e-5)
         out.append((v.reshape(-1) * p["g"]) @ p["W_out"])
-    return np.stack(out)
+    return np.stack(out) if carried is None else (np.stack(out), S)
 
 
 def test_mixer_matches_the_recurrence_position_by_position():
@@ -230,6 +235,70 @@ def test_mixer_stream_matches_apply(t):
         pos += nv
     np.testing.assert_allclose(
         np.stack([np.concatenate(g) for g in got]), want, atol=ATOL)
+
+
+# what slots 0 and 1 do in the one step; slots 2 and 3 feed all their
+# rows in mid-stream
+ONE_STEP = {
+    "all_rows_valid": lambda t: dict(n_valid=(t, t), pos=(7, 3)),
+    "fewer_rows_than_t": lambda t: dict(n_valid=(max(t - 1, 1), 1),
+                                        pos=(7, 3)),
+    "a_slot_feeds_nothing": lambda t: dict(n_valid=(0, t), pos=(0, 3)),
+    "a_fresh_slot_over_nan": lambda t: dict(n_valid=(t, t), pos=(0, 3),
+                                            nan=0),
+}
+
+
+@pytest.mark.parametrize("case", list(ONE_STEP))
+@pytest.mark.parametrize("t", [1, 2, 4])
+def test_one_step_at_the_published_head_shape(t, case):
+    """Heads of 64 over a state of 128 as published (8 of them, one
+    group): ONE step of ``apply_stream_paged`` over a pool that an
+    earlier tenant left non-zero, junk in the rows past ``n_valid``,
+    against the recurrence in float64 from the same rows. A slot at
+    position 0 starts from zeros whatever its row holds (NaN too); a
+    slot that feeds nothing keeps its row bit for bit. Outputs of
+    size 3 agree to 2e-6 and states of size 9 to 8e-7."""
+    dims, slots, d = (8, 64, 128, 1), 4, 32
+    layer = Mamba2MixerLayer(n_in=d, n_heads=8, head_dim=64,
+                             state_size=128, n_groups=1, conv_width=K,
+                             weight_init="normal")
+    params = _perturbed(layer.initialize(
+        jax.random.PRNGKey(3), InputType.recurrent(d))[0], 3)
+    what = ONE_STEP[case](t)
+    rng = np.random.default_rng(t)
+    pool = {"ssm": rng.normal(0, 2, (slots, 8, 64, 128)).astype(
+                np.float32),
+            "conv": rng.normal(0, 1, (slots, K - 1, layer.conv_dim)
+                               ).astype(np.float32)}
+    if "nan" in what:
+        pool["ssm"][what["nan"], ::3, ::5] = np.nan
+    n_valid = np.array(what["n_valid"] + (t, t), np.int32)
+    pos = np.array(what["pos"] + (11, 40), np.int32)
+    x = rng.normal(0, 1, (slots, t, d)).astype(np.float32)
+    for s in range(slots):
+        x[s, n_valid[s]:] = 99.0
+    # the single-row program has no ``n_valid``: the all-zero table
+    # row marks the slot that sits the step out
+    table = np.where(n_valid[:, None] > 0, 1, 0).astype(np.int32)
+    args = (params, jax.tree_util.tree_map(jnp.asarray, pool),
+            jnp.asarray(table), jnp.asarray(pos), jnp.asarray(x))
+    got, got_pool = jax.jit(layer.apply_stream_paged)(
+        *args, *((jnp.asarray(n_valid),) if t > 1 else ()))
+    got, got_pool = np.asarray(got), jax.tree_util.tree_map(
+        np.asarray, got_pool)
+    for s in range(slots):
+        n = n_valid[s]
+        if n == 0:
+            for leaf in ("ssm", "conv"):
+                np.testing.assert_array_equal(got_pool[leaf][s],
+                                              pool[leaf][s])
+            continue
+        carried = (pool["ssm"][s], pool["conv"][s]) if pos[s] else \
+            (np.zeros((8, 64, 128)), np.zeros((K - 1, layer.conv_dim)))
+        want, state = _mixer_by_position(params, x[s, :n], dims, carried)
+        np.testing.assert_allclose(got[s, :n], want, atol=ATOL)
+        np.testing.assert_allclose(got_pool["ssm"][s], state, atol=ATOL)
 
 
 # ---- the network through the paged session ---------------------------
