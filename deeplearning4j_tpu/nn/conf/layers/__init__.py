@@ -50,9 +50,13 @@ from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
 from deeplearning4j_tpu.nn.conf.layers.state_space import (
     Mamba2MixerLayer,
 )
+from deeplearning4j_tpu.nn.conf.layers.short_conv import (
+    ShortConvMixerLayer,
+)
 from deeplearning4j_tpu.nn.conf.layers.moe import (
     SparseExpertsLayer, LatentDecoderBlock, ShortcutExpertBlock,
     GroupedQueryDecoderBlock, StateSpaceDecoderBlock,
+    ShortConvDecoderBlock,
 )
 
 __all__ = [
@@ -77,4 +81,5 @@ __all__ = [
     "LatentAttentionLayer", "SparseExpertsLayer", "LatentDecoderBlock",
     "ShortcutExpertBlock", "GroupedQueryDecoderBlock",
     "Mamba2MixerLayer", "StateSpaceDecoderBlock",
+    "ShortConvMixerLayer", "ShortConvDecoderBlock",
 ]

@@ -33,7 +33,7 @@ __all__ = ["SelfAttentionLayer", "TransformerEncoderLayer",
 
 
 from deeplearning4j_tpu.nn.conf.layers.normalization import (
-    layer_norm as _layer_norm)
+    layer_norm as _layer_norm, rms_norm)
 from deeplearning4j_tpu.nn.conf.layers.rotary import rope, yarn_inv_freq
 
 
@@ -405,7 +405,11 @@ class GroupedQueryAttentionLayer(BaseLayer):
     ``sink``: a learned logit a query head (parameter ``sink``) that
     joins the softmax's denominator and adds no value.
     ``softmax_scale`` multiplies the scores; None is
-    ``qk_head_dim ** -0.5``.
+    ``qk_head_dim ** -0.5``. ``qk_norm``: every query head and every
+    key head is RMS-normed over its ``qk_head_dim`` values before the
+    rotation (one gain for all query heads, ``q_norm_gain``, and one
+    for all key heads, ``k_norm_gain``; ``qk_norm_eps``), so the cache
+    holds normed keys.
 
     Two forms of one mathematics: ``apply`` attends over the whole
     sequence, ``apply_stream_paged`` over a paged cache that holds
@@ -435,6 +439,8 @@ class GroupedQueryAttentionLayer(BaseLayer):
     sink: bool = False
     value_scale: float = 1.0
     softmax_scale: Optional[float] = None
+    qk_norm: bool = False
+    qk_norm_eps: float = 1e-6
 
     def __post_init__(self):
         if self.n_heads % self.n_kv_heads:
@@ -465,6 +471,9 @@ class GroupedQueryAttentionLayer(BaseLayer):
              "Wv": w(ks[2], d, K * dv), "Wo": w(ks[3], H * dv, d)}
         if self.sink:
             p["sink"] = jnp.zeros((H,), dtypes.policy().param_dtype)
+        if self.qk_norm:
+            for name in ("q_norm_gain", "k_norm_gain"):
+                p[name] = jnp.ones((dq,), dtypes.policy().param_dtype)
         return p, {}
 
     # ---- pieces shared by both forms ----
@@ -479,14 +488,17 @@ class GroupedQueryAttentionLayer(BaseLayer):
              y[..., r:]], axis=-1)
 
     def _project(self, params, x, positions):
-        """x (B,t,C) at ``positions`` (B,t) -> q (B,t,H,dq) rotated,
-        k (B,t,K,dq) rotated, v (B,t,K,dv) scaled: k and v are what
-        the cache holds."""
+        """x (B,t,C) at ``positions`` (B,t) -> q (B,t,H,dq) and k
+        (B,t,K,dq), normed a head where ``qk_norm`` and rotated, v
+        (B,t,K,dv) scaled: k and v are what the cache holds."""
         B, t, _ = x.shape
         H, K = self.n_heads, self.n_kv_heads
         x = x.astype(params["Wq"].dtype)
         q = (x @ params["Wq"]).reshape(B, t, H, self.qk_head_dim)
         k = (x @ params["Wk"]).reshape(B, t, K, self.qk_head_dim)
+        if self.qk_norm:
+            q = rms_norm(q, params["q_norm_gain"], self.qk_norm_eps)
+            k = rms_norm(k, params["k_norm_gain"], self.qk_norm_eps)
         if self.value_scale == 1.0:
             v = x @ params["Wv"]
         else:

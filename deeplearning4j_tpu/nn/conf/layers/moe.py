@@ -41,6 +41,12 @@ shared expert: MiMo-V2's layer.
 mixer (``state_space.py``) and the dense MLP. It and
 ``GroupedQueryDecoderBlock`` take a ``residual_multiplier`` on both
 branches: together they are Granite-4.0-H's two kinds of layer.
+
+``ShortConvDecoderBlock`` is that block over a gated short
+convolution (``short_conv.py``) and the dense MLP or
+``GroupedQueryDecoderBlock``'s expert layer: with that block and its
+``qk_norm`` it is LFM2's two kinds of layer. Both blocks over a mixer
+whose cache is a row a slot are one ``_SlotStateBlock``.
 """
 
 from __future__ import annotations
@@ -61,11 +67,13 @@ from deeplearning4j_tpu.nn.conf.layers.base import (BaseLayer,
 from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
     LatentAttentionLayer, _mm)
 from deeplearning4j_tpu.nn.conf.layers.normalization import rms_norm
+from deeplearning4j_tpu.nn.conf.layers.short_conv import (
+    ShortConvMixerLayer)
 from deeplearning4j_tpu.nn.conf.layers.state_space import Mamba2MixerLayer
 
 __all__ = ["SparseExpertsLayer", "LatentDecoderBlock",
            "ShortcutExpertBlock", "GroupedQueryDecoderBlock",
-           "StateSpaceDecoderBlock", "swiglu"]
+           "StateSpaceDecoderBlock", "ShortConvDecoderBlock", "swiglu"]
 
 _F32 = jnp.float32
 
@@ -277,6 +285,22 @@ def _init_decoder_block(block, key, attn, moe, mixer="attn"):
                  Wu=block._sample_w(k2, (d, ff), d, ff),
                  Wd=block._sample_w(k3, (ff, d), ff, d))
     return p, {}
+
+
+def _biased_sigmoid_experts(block, held, common):
+    """The expert layer of ``block``'s flat fields over the ``held``
+    share, or None where it has no routed experts: a sigmoid router
+    with its selection-only correction bias, the selected weights
+    normalised, no shared expert (MiMo-V2's and LFM2's)."""
+    if not block.n_routed_experts:
+        return None
+    return SparseExpertsLayer(
+        n_routed_experts=block.n_routed_experts, held=held,
+        top_k=block.top_k, expert_width=block.expert_width,
+        n_shared_experts=0,
+        routed_scaling_factor=block.routed_scaling_factor,
+        norm_topk_prob=True, scoring_func="sigmoid", router_bias=True,
+        **common)
 
 
 @register_layer
@@ -590,6 +614,8 @@ class GroupedQueryDecoderBlock(BaseLayer):
     # multiplies both branches before they join the residual stream
     softmax_scale: Optional[float] = None
     residual_multiplier: float = 1.0
+    # an RMS norm over each query and key head, at the block's ``eps``
+    qk_norm: bool = False
 
     def set_n_in(self, input_type: InputType) -> None:
         if self.n_in is None:
@@ -613,16 +639,9 @@ class GroupedQueryDecoderBlock(BaseLayer):
                 v_head_dim=self.v_head_dim, rotary_dim=self.rotary_dim,
                 rope_theta=self.rope_theta, window=self.window,
                 sink=self.sink, value_scale=self.value_scale,
-                softmax_scale=self.softmax_scale, **common)
-            self._moe = None
-            if self.n_routed_experts:
-                self._moe = SparseExpertsLayer(
-                    n_routed_experts=self.n_routed_experts,
-                    held=self.held, top_k=self.top_k,
-                    expert_width=self.expert_width, n_shared_experts=0,
-                    routed_scaling_factor=self.routed_scaling_factor,
-                    norm_topk_prob=True, scoring_func="sigmoid",
-                    router_bias=True, **common)
+                softmax_scale=self.softmax_scale,
+                qk_norm=self.qk_norm, qk_norm_eps=self.eps, **common)
+            self._moe = _biased_sigmoid_experts(self, self.held, common)
         return self._attn, self._moe
 
     def initialize(self, key, input_type: InputType):
@@ -684,15 +703,87 @@ class GroupedQueryDecoderBlock(BaseLayer):
         return h, pool
 
 
+class _SlotStateBlock(BaseLayer):
+    """Pre-RMSNorm decoder block ``h = x + m Mixer(norm(x)); y = h + m
+    F(norm(h))`` over a sequence mixer whose paged cache is a row a
+    SLOT (``zero_state_pool``), not pages: what it carries from token
+    to token has a fixed size. ``F`` is the dense SiLU-gated MLP or,
+    where the subclass has routed experts, their layer, whose counts
+    then come out of the paged step (``stream_aux``). A subclass
+    gives the fields, ``mixer`` (the mixer's scope, and its key in
+    the parameters) and ``_ensure_parts() -> (mixer, experts or
+    None)``."""
+
+    # fields where a subclass has them
+    residual_multiplier = 1.0
+    n_routed_experts = 0
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if self.n_in is None:
+            self.n_in = input_type.size
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType.recurrent(self.n_in or input_type.size,
+                                   input_type.timesteps)
+
+    @property
+    def stream_aux(self) -> bool:
+        return self.n_routed_experts > 0
+
+    def initialize(self, key, input_type: InputType):
+        self.set_n_in(input_type)
+        return _init_decoder_block(self, key, *self._ensure_parts(),
+                                   mixer=self.mixer)
+
+    def _block(self, params, x, mix, active=None):
+        """The block's equations; ``mix(z)`` is the mixer over the
+        normed ``z``."""
+        x = x.astype(params["norm1_gain"].dtype)
+        with jax.named_scope(self.mixer):
+            a = mix(rms_norm(x, params["norm1_gain"], self.eps))
+        m = self.residual_multiplier
+        return _ffn_half(params, _residual(x, a, m),
+                         self._ensure_parts()[1], self.eps, active, m)
+
+    def apply(self, params, state, x, *, training=False, rng=None,
+              mask=None):
+        mix = lambda z: self._ensure_parts()[0].apply(
+            params[self.mixer], {}, z, training=training, rng=rng,
+            mask=mask)[0]
+        return self._block(params, x, mix)[0], state
+
+    # ---- paged decode ----
+    def zero_state_pool(self, slots: int, dtype):
+        return self._ensure_parts()[0].zero_state_pool(slots, dtype)
+
+    def apply_stream_paged_aux(self, params, pool, table, pos, x,
+                               active=None, n_valid=None):
+        """(out, pool, counts), as
+        ``LatentDecoderBlock.apply_stream_paged_aux``."""
+        new_pool = []
+
+        def mix(z):
+            a, p = self._ensure_parts()[0].apply_stream_paged(
+                params[self.mixer], pool, table, pos, z, n_valid)
+            new_pool.append(p)
+            return a
+
+        h, counts = self._block(params, x, mix, active)
+        return h, new_pool[0], counts
+
+    def apply_stream_paged(self, params, pool, table, pos, x,
+                           n_valid=None):
+        h, pool, _ = self.apply_stream_paged_aux(
+            params, pool, table, pos, x, n_valid=n_valid)
+        return h, pool
+
+
 @register_layer
 @dataclasses.dataclass
-class StateSpaceDecoderBlock(BaseLayer):
-    """Pre-RMSNorm decoder block ``h = x + m SSM(norm(x)); y = h + m
-    F(norm(h))``: a Mamba-2 mixer (``Mamba2MixerLayer``, whose fields
-    these are, flat) and the dense SiLU-gated MLP, ``m`` the
-    ``residual_multiplier``. What the mixer carries from token to
-    token has a fixed size, so its paged cache is a row a SLOT
-    (``zero_state_pool``), not pages."""
+class StateSpaceDecoderBlock(_SlotStateBlock):
+    """``_SlotStateBlock`` over a Mamba-2 mixer (``Mamba2MixerLayer``,
+    whose fields these are, flat) and the dense SiLU-gated MLP, both
+    branches times ``residual_multiplier``."""
 
     n_in: Optional[int] = None
     eps: float = 1e-5
@@ -706,15 +797,9 @@ class StateSpaceDecoderBlock(BaseLayer):
     intermediate_size: int = 128
     residual_multiplier: float = 1.0
 
-    def set_n_in(self, input_type: InputType) -> None:
-        if self.n_in is None:
-            self.n_in = input_type.size
+    mixer = "ssm"
 
-    def output_type(self, input_type: InputType) -> InputType:
-        return InputType.recurrent(self.n_in or input_type.size,
-                                   input_type.timesteps)
-
-    def _mixer(self):
+    def _ensure_parts(self):
         if not hasattr(self, "_ssm"):
             self._ssm = Mamba2MixerLayer(
                 n_in=self.n_in, n_heads=self.n_heads,
@@ -722,42 +807,36 @@ class StateSpaceDecoderBlock(BaseLayer):
                 n_groups=self.n_groups, conv_width=self.conv_width,
                 eps=self.eps, weight_init=self.weight_init,
                 weight_distribution=self.weight_distribution)
-        return self._ssm
+        return self._ssm, None
 
-    def initialize(self, key, input_type: InputType):
-        self.set_n_in(input_type)
-        return _init_decoder_block(self, key, self._mixer(), None,
-                                   mixer="ssm")
 
-    def _block(self, params, x, mix):
-        """The block's equations; ``mix(z)`` is the mixer over the
-        normed ``z``."""
-        x = x.astype(params["norm1_gain"].dtype)
-        with jax.named_scope("ssm"):
-            a = mix(rms_norm(x, params["norm1_gain"], self.eps))
-        m = self.residual_multiplier
-        return _ffn_half(params, _residual(x, a, m), None, self.eps,
-                         multiplier=m)[0]
+@register_layer
+@dataclasses.dataclass
+class ShortConvDecoderBlock(_SlotStateBlock):
+    """``_SlotStateBlock`` over a gated short convolution
+    (``ShortConvMixerLayer``), then the dense SiLU-gated MLP
+    (``n_routed_experts == 0``) or ``GroupedQueryDecoderBlock``'s
+    expert layer: LFM2's ``conv`` layer."""
 
-    def apply(self, params, state, x, *, training=False, rng=None,
-              mask=None):
-        mix = lambda z: self._mixer().apply(
-            params["ssm"], {}, z, training=training, rng=rng,
-            mask=mask)[0]
-        return self._block(params, x, mix), state
+    n_in: Optional[int] = None
+    eps: float = 1e-5
+    # gated short convolution (ShortConvMixerLayer)
+    conv_width: int = 3
+    # dense MLP width (used when n_routed_experts == 0)
+    intermediate_size: int = 128
+    # expert layer (SparseExpertsLayer), every expert held
+    n_routed_experts: int = 0
+    top_k: int = 4
+    expert_width: int = 32
+    routed_scaling_factor: float = 1.0
 
-    # ---- paged decode ----
-    def zero_state_pool(self, slots: int, dtype):
-        return self._mixer().zero_state_pool(slots, dtype)
+    mixer = "conv"
 
-    def apply_stream_paged(self, params, pool, table, pos, x,
-                           n_valid=None):
-        new_pool = []
-
-        def mix(z):
-            a, p = self._mixer().apply_stream_paged(
-                params["ssm"], pool, table, pos, z, n_valid)
-            new_pool.append(p)
-            return a
-
-        return self._block(params, x, mix), new_pool[0]
+    def _ensure_parts(self):
+        if not hasattr(self, "_conv"):
+            common = dict(n_in=self.n_in, weight_init=self.weight_init,
+                          weight_distribution=self.weight_distribution)
+            self._conv = ShortConvMixerLayer(
+                conv_width=self.conv_width, **common)
+            self._moe = _biased_sigmoid_experts(self, None, common)
+        return self._conv, self._moe

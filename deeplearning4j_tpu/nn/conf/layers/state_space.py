@@ -40,9 +40,23 @@ from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers.base import (BaseLayer,
                                                     register_layer)
 
-__all__ = ["Mamba2MixerLayer"]
+__all__ = ["Mamba2MixerLayer", "carried_window"]
 
 _F32 = jnp.float32
+
+
+def carried_window(kept, xs, n_valid):
+    """The window a slot-owned short convolution finds at its next
+    step: of ``xs`` (S, K - 1 + t, C), a step's t inputs behind the
+    K - 1 before them, the K - 1 before row ``n_valid[s]``; ``kept``
+    (S, K - 1, C), the pool's own, where the slot fed nothing. One
+    select a count of rows: a gather by row is a handful of small
+    programs on the device."""
+    width = kept.shape[1]
+    for n in range(1, xs.shape[1] - width + 1):
+        kept = jnp.where((n_valid == n)[:, None, None],
+                         xs[:, n:n + width].astype(kept.dtype), kept)
+    return kept
 
 
 @register_layer
@@ -228,7 +242,7 @@ class Mamba2MixerLayer(BaseLayer):
         row past ``n_valid`` has ``dt = 0`` and changes nothing. Every
         product is elementwise in float32."""
         S, t, _ = x.shape
-        K, G = self.conv_width, self.n_groups
+        G = self.n_groups
         if n_valid is None:
             n_valid = jnp.where(table[:, 0] > 0, t, 0)
         fed, fresh = n_valid > 0, pos == 0
@@ -285,14 +299,8 @@ class Mamba2MixerLayer(BaseLayer):
                 new = new + heads(left[:, i, :, None] * xh[:, i])[
                     ..., None] * Bg[:, i, :, None, None, :]
             # the window the next step finds: the K - 1 inputs before
-            # row n_valid, its own where the slot fed nothing (one
-            # select a count of rows: a gather by row is a handful of
-            # small programs on the device)
-            tail = pool["conv"]
-            for n in range(1, t + 1):
-                tail = jnp.where((n_valid == n)[:, None, None],
-                                 xs[:, n:n + K - 1].astype(tail.dtype),
-                                 tail)
+            # row n_valid, its own where the slot fed nothing
+            tail = carried_window(pool["conv"], xs, n_valid)
             pool = {"ssm": jnp.where(fed[:, None, None, None],
                                      new.reshape(pool["ssm"].shape),
                                      pool["ssm"]),

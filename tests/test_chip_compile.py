@@ -455,6 +455,110 @@ def test_state_space_block_step_compiles_for_v5e(topo, t):
     assert not small, small
 
 
+@pytest.mark.parametrize("t", [2, 1], ids=["chunk_64x2", "decode_64x1"])
+def test_short_conv_block_step_compiles_for_v5e(topo, t):
+    """``ShortConvDecoderBlock.apply_stream_paged`` at the widths of
+    the benchmark's ``lfm2_serve_agent`` cell (hidden 2048, a window
+    of 2 rows, the dense MLP of 11776) in bfloat16 over 64 slots, both
+    step programs: the (64, 2, 2048) window pool is donated and
+    updated in place, and what lies between the two projections is
+    whole-array products, sums and selects that fuse: no contraction,
+    windowed reduction (a cumsum) or gather by row under
+    ``conv/window``, and no program of its own for each tap (four
+    executed instructions at most: the gate's product, the taps'
+    slices, the select's predicates and the select; a window of 5
+    taps has as many)."""
+    from deeplearning4j_tpu import dtypes
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.conf.layers import ShortConvDecoderBlock
+    bf16, slots, d = jnp.bfloat16, 64, 2048
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), tree)
+
+    def between_the_projections(width):
+        """The executed instructions of the compiled step under
+        ``conv/window``."""
+        layer = ShortConvDecoderBlock(n_in=d, conv_width=width,
+                                      intermediate_size=11776)
+        with dtypes.policy_scope(dtypes.Policy(
+                param_dtype=bf16, compute_dtype=bf16, output_dtype=bf16)):
+            params = place(jax.eval_shape(lambda: layer.initialize(
+                jax.random.PRNGKey(0), InputType.recurrent(d))[0]))
+        assert params["conv"]["conv_w"].shape == (width, d)
+        pool = place(jax.eval_shape(
+            lambda: layer.zero_state_pool(slots, bf16)))
+        assert pool["conv"].shape == (slots, width - 1, d)
+        compiled = jax.jit(layer.apply_stream_paged,
+                           donate_argnums=(1,)).lower(
+            params, pool, sds((slots, 64), jnp.int32),
+            sds((slots,), jnp.int32), sds((slots, t, d), bf16),
+            sds((slots,), jnp.int32) if t > 1 else None).compile()
+        assert compiled.memory_analysis().alias_size_in_bytes >= \
+            slots * (width - 1) * d * 2
+        text = compiled.as_text()
+        small = re.findall(r" (dot|convolution|reduce-window|gather)\(.*"
+                           r'op_name="[^"]*/window/', text)
+        assert not small, small
+        return [line for line in text.split("ENTRY", 1)[1].splitlines()
+                if "/window/" in line and re.search(
+                    r" (fusion|custom-call|copy|select|multiply|add)\(",
+                    line)]
+
+    run = between_the_projections(3)
+    assert len(run) <= 4, run
+    assert len(between_the_projections(5)) == len(run)
+
+
+@pytest.mark.parametrize("t", [2, 1], ids=["chunk_64x2", "decode_64x1"])
+def test_short_conv_expert_cell_step_fits_v5e(topo, as_tpu, t):
+    """The WHOLE id-returning step of the benchmark's
+    ``lfm2_serve_agent`` cell (``PagedSlotSession._step_ids`` over the
+    configuration's own network: 9 layers at the published widths in
+    bfloat16 with ALL 64 experts of 8 layers, 64 slots of capacity
+    2,048, page 16), both step programs: 7 window pools of one leaf
+    beside 2 attention layers in the allocator's 8,193 pages (a value
+    head of 64 in a lane tile of 128, both read by the grouped
+    kernel), state layers that are ``aux`` layers too, in a chip's
+    16 GB."""
+    from benchmark.harness import spec
+    from deeplearning4j_tpu.models.paged_kv import PagedSlotSession
+    cell = spec.load("lfm2_serve_agent")
+    config, sv = cell.config, cell.traffic["server"]
+    builder = spec.load_module("builders", config["builder"])
+    with builder.policy(config):
+        net = builder.build(config).init()       # parameters as shapes
+    sess = PagedSlotSession(net, sv["slots"], sv["capacity"],
+                            sv["page_size"])
+    assert sess._state == [False, True, False, True, True, True, False,
+                           True, True, True, False, False]
+    assert sess._aux_layers == list(range(2, 10))
+    assert sess.state_pool_bytes == 7 * 64 * 2 * 2048 * 2
+    assert sess._pools[2]["v"].shape == (8193, 16, 8 * 128)
+    sess._make_step()
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), tree)
+    slots = sv["slots"]
+    compiled = sess._step_ids.lower(
+        place(net.params), net.state, place(sess._pools),
+        sds((slots, sess.pages_per_slot), jnp.int32),
+        sds((slots,), jnp.int32), sds((slots, t, 1), jnp.float32),
+        sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots,), bool)).compile()
+    assert _kernels_in(compiled) == 2
+    mem = compiled.memory_analysis()
+    # 10.62 GB of weights and 0.81 GB of pages; the pools are donated;
+    # the dense pass of 128 rows through 64 experts stays under 1 GB
+    assert 11.3e9 < mem.argument_size_in_bytes < 11.6e9
+    assert mem.alias_size_in_bytes > 0.8e9
+    assert mem.temp_size_in_bytes < 1e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 13e9
+
+
 # ---- four chips: the kernels on a mesh -----------------------------------
 
 def _attention_loss(q, k, v, mask=None):

@@ -318,32 +318,54 @@ def test_lease_export_import_on_the_latent_pool(tiny_net):
                                atol=1e-6)
 
 
-def test_shares_of_an_expert_group_add_up_to_the_whole_layer():
-    """4 shares of 4 of 16 experts: the parts that the shares give,
-    the shared expert counted once, are the uncut reference's layer
-    output."""
-    kw = dict(n_in=64, n_routed_experts=16, top_k=4, expert_width=32,
-              routed_scaling_factor=2.5)
+def _axk1_layer(p, x):
+    return REF._experts(p, x, dict(TINY, held_first_expert=0))[0]
+
+
+def _lfm2_layer(p, x):
+    return _load("reference", "lfm2_moe").experts(
+        p, x, {"num_experts_per_tok": 4, "routed_scaling_factor": 1})
+
+
+@pytest.mark.parametrize("kw, shares, reference", [
+    (dict(n_routed_experts=16, routed_scaling_factor=2.5), 4,
+     _axk1_layer),
+    # every expert on one chip (``held=None``): a sigmoid router with
+    # a selection-only bias, no shared expert
+    (dict(n_routed_experts=64, n_shared_experts=0, router_bias=True), 8,
+     _lfm2_layer),
+], ids=["axk1_4_shares_of_4", "lfm2_8_shares_of_8"])
+def test_shares_of_an_expert_group_add_up_to_the_whole_layer(
+        kw, shares, reference):
+    """The parts that the shares of an expert-parallel group give,
+    the shared expert (where there is one) counted once, are the
+    uncut reference's layer output, and so is what the layer that
+    holds every expert (``held=None``) gives."""
+    kw = dict(kw, n_in=64, top_k=4, expert_width=32)
     whole = SparseExpertsLayer(**kw)
+    assert whole.held is None
     p, _ = whole.initialize(jax.random.PRNGKey(0),
                             InputType.recurrent(64))
     p = jax.tree_util.tree_map(lambda w: w * 4.0, p)
+    if "br" in p:       # zeros at first: a bias that moves the picks
+        p["br"] = 0.2 * jax.random.normal(jax.random.PRNGKey(2),
+                                          p["br"].shape)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 9, 64))
     from deeplearning4j_tpu.nn.conf.layers.moe import swiglu
-    shared = swiglu(x, p["Wsg"], p["Wsu"], p["Wsd"])
+    shared = swiglu(x, p["Wsg"], p["Wsu"], p["Wsd"]) if "Wsg" in p \
+        else jnp.zeros_like(x)
     total, counted = shared, 0
-    for first in (0, 4, 8, 12):
-        part = SparseExpertsLayer(held=(first, 4), **kw)
-        pp = dict(p, **{k: p[k][first:first + 4]
+    each = kw["n_routed_experts"] // shares
+    for first in range(0, kw["n_routed_experts"], each):
+        part = SparseExpertsLayer(held=(first, each), **kw)
+        pp = dict(p, **{k: p[k][first:first + each]
                         for k in ("Wg", "Wu", "Wd")})
         out, counts = part.apply_counted(pp, x)
         total = total + (out - shared)
         counted += int(counts.sum())
     assert counted == 2 * 9 * 4          # every pair served once
-    config = dict(TINY, held_first_expert=0)
-    want = np.stack([np.asarray(REF._experts(
-        jax.tree_util.tree_map(lambda w: w.astype(jnp.float32), p),
-        x[b], config)[0]) for b in range(2)])
+    p32 = jax.tree_util.tree_map(lambda w: w.astype(jnp.float32), p)
+    want = np.stack([np.asarray(reference(p32, x[b])) for b in range(2)])
     assert float(np.abs(want).max()) > 0.1
     np.testing.assert_allclose(total, want, rtol=1e-5, atol=5e-5)
     np.testing.assert_allclose(whole.apply_counted(p, x)[0], want,
