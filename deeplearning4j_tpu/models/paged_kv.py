@@ -509,6 +509,12 @@ class PagedSlotSession:
         # row of the same step reads
         self.chunk_rows_max = (self.page_size if self._ring_sizes
                                else self.capacity)
+        # some layer's step is unrolled over a chunk's rows
+        # (``chunk_rows_unrolled``): every width of such a step is a
+        # long compile, so the batcher holds one chunk program
+        self.unrolls_chunk_rows = any(
+            getattr(layer, "chunk_rows_unrolled", False)
+            for layer in net.layers)
         self._pools = self._fresh_pools()
         # one jitted step: ``step_slots`` runs it at (slots, 1, C),
         # ``step_chunk`` at (slots, t, C), each shape its own program

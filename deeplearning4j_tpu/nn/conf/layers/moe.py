@@ -756,6 +756,11 @@ class _SlotStateBlock(BaseLayer):
     def zero_state_pool(self, slots: int, dtype):
         return self._ensure_parts()[0].zero_state_pool(slots, dtype)
 
+    @property
+    def chunk_rows_unrolled(self) -> bool:
+        return getattr(self._ensure_parts()[0], "chunk_rows_unrolled",
+                       False)
+
     def apply_stream_paged_aux(self, params, pool, table, pos, x,
                                active=None, n_valid=None):
         """(out, pool, counts), as

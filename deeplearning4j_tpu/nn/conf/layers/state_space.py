@@ -84,6 +84,12 @@ class Mamba2MixerLayer(BaseLayer):
     conv_width: int = 4
     eps: float = 1e-5
 
+    # the paged step sums a chunk's t rows in closed form, unrolled
+    # and quadratic in t (``apply_stream_paged``): each width of it is
+    # seconds of compile a layer, so the batcher keeps such a network
+    # to one chunk width (``PagedSlotSession.unrolls_chunk_rows``)
+    chunk_rows_unrolled = True
+
     def __post_init__(self):
         if self.n_heads % self.n_groups:
             raise ValueError(f"n_heads {self.n_heads} not divisible by "

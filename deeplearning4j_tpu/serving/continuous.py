@@ -8,15 +8,18 @@ when traffic is heaviest. This module does iteration-level scheduling
 SlotStreamingSession): a fixed pool of KV-cache slots steps together
 and between steps finished slots are recycled to queued requests.
 Prompt prefill rides the same steps teacher-forced, so admission
-never compiles anything: a step is one of two programs, whoever is in
-the pool. Over a paged session a step that finds a slot with prompt
-tokens to spare is (slots, t, 1), CHUNKED prefill: that slot feeds
-its next ``min(t, tokens left)`` prompt tokens, a slot in decode its
-one token, and the chunk that carries a prompt's last token emits the
-request's first output token; a step whose slots all decode is
-(slots, 1, 1). ``t`` follows from the pool (``chunk_width``). Over the
-dense session every step is (slots, 1, 1) and a prompt takes a step a
-token.
+never compiles anything: a step is one of two or three programs,
+whoever is in the pool. Over a paged session a step that finds a slot
+with prompt tokens to spare is (slots, t, 1), CHUNKED prefill: that
+slot feeds its next ``min(t, tokens left)`` prompt tokens, a slot in
+decode its one token, and the chunk that carries a prompt's last token
+emits the request's first output token; a step whose slots all decode
+is (slots, 1, 1). ``t`` follows from the pool (``chunk_width``), and a
+pool of many slots holds a second chunk program twice as wide
+(``wide_chunk_width``), which a step runs only when the prompt rows
+its slots have on offer would not fit the narrow one (``_plan_step``).
+Over the dense session every step is (slots, 1, 1) and a prompt takes
+a step a token.
 
 Admission control mirrors the scheduler (the shared
 ``serving/lifecycle.py`` plumbing): bounded queue with
@@ -87,22 +90,52 @@ __all__ = ["ContinuousBatcher", "MigrationOffer"]
 # 128 / 256 / 512 in both serving cells of the benchmark (PERF.md
 # section 6, PR 27): 8 slots serve the same at 128 and 256 and less at
 # 512; 64 slots, where nearly every step has some slot in prefill,
-# gain at 128 and lose at 256 and 512.
+# gain at 128 and lose at 256 and 512 where most slots decode
+# (``axk1_serve_decode``, read again at PR 42 with every attention kind
+# reading by table: 3,130 / 2,745 / 1,615 tokens/s). This is the width
+# of the chunk program every chunkable pool holds.
 CHUNK_ROWS = 128
+
+# Rows of the second, wider chunk program (``wide_chunk_width``): where
+# a bfloat16 matmul that streams its weights turns from memory-bound to
+# compute-bound on a v5e (197 TFLOP/s over 819 GB/s is 240 FLOP a byte,
+# and a row costs one FLOP a weight byte), so up to here rows ride on
+# weights the step reads anyway. A step at this width costs more than a
+# narrow one (an expert layer's dense pass puts every row through every
+# held expert), so it runs only when the rows on offer fill it
+# (``_plan_step``). Read on the chip at 128 / 256 / 512 rows in every
+# chunk step of four serving cells (PERF.md section 6, PR 42): where
+# most slot-steps feed prompts 256 gives 1.28 / 1.11 / 1.05 times the
+# tokens/s of 128 for a step 1.21-1.30 times as long, and 512 less
+# than 128 in all four (a step 2.1-2.3 times as long).
+WIDE_CHUNK_ROWS = 256
 
 _NON_FINITE = ("non-finite probabilities in decode step (device fault "
                "or poisoned model output)")
 
 
-def chunk_width(slots: int, capacity: int) -> int:
+def chunk_width(slots: int, capacity: int,
+                rows: Optional[int] = None) -> int:
     """Prompt tokens a slot in prefill may feed a device step: the
-    largest power of two with ``slots * t <= CHUNK_ROWS`` (16 at 8
-    slots, 2 at 64), no wider than a slot itself. At 1 prefill is
-    token by token."""
+    largest power of two with ``slots * t <= rows`` (``CHUNK_ROWS``
+    unless given: 16 at 8 slots, 2 at 64), no wider than a slot
+    itself. At 1 prefill is token by token."""
+    rows = CHUNK_ROWS if rows is None else rows
     t = 1
-    while slots * t * 2 <= CHUNK_ROWS and t * 2 <= capacity:
+    while slots * t * 2 <= rows and t * 2 <= capacity:
         t *= 2
     return t
+
+
+def wide_chunk_width(slots: int, capacity: int, page_size: int) -> int:
+    """The width of a pool's second chunk program, or 0 where it holds
+    none: ``chunk_width`` under ``WIDE_CHUNK_ROWS``, where that is
+    wider than the narrow program and the narrow one feeds a slot less
+    than a page a step (at 8 slots t is 16 already, and a prompt is a
+    small part of a request's steps)."""
+    t_lo = chunk_width(slots, capacity)
+    t_hi = chunk_width(slots, capacity, WIDE_CHUNK_ROWS)
+    return t_hi if t_lo < min(t_hi, page_size) else 0
 
 
 def _migrate_chaos(blob: bytes) -> bytes:
@@ -325,13 +358,22 @@ class ContinuousBatcher(ServingBackend):
         self._stream = self.metrics.streaming(name, version)
         # the worker loop's own view of a step (parts, slot-steps)
         self._steps = self.metrics.batcher_steps(name)
-        # chunked prefill: the width of the second step program, 1
-        # where the session has no chunk entry point (the dense one,
-        # a network whose layers mix rows)
-        self._chunk_t = (chunk_width(slots, min(
-                             capacity, self.session.chunk_rows_max))
-                         if getattr(self.session, "chunkable", False)
-                         else 1)
+        # chunked prefill: the width of the chunk program, 1 where the
+        # session has no chunk entry point (the dense one, a network
+        # whose layers mix rows), and of the wide one, 0 where the
+        # pool holds none
+        self._chunk_t, self._wide_t = 1, 0
+        if getattr(self.session, "chunkable", False):
+            widest = min(capacity, self.session.chunk_rows_max)
+            self._chunk_t = chunk_width(slots, widest)
+            # the second, wider chunk program (``_plan_step`` says
+            # when it runs); a layer whose step unrolls over the
+            # chunk's rows keeps the one width
+            if not self.session.unrolls_chunk_rows:
+                self._wide_t = wide_chunk_width(
+                    slots, widest, self.session.page_size)
+        if self._wide_t:
+            self._steps.holds_wide_program()
         self._warmed = False
         # the step whose ids are still on the device: enqueued and
         # scheduled past, not yet delivered (``_loop``). At most one.
@@ -1098,7 +1140,8 @@ class ContinuousBatcher(ServingBackend):
                     t2 - t1 - t_adv + fetched, t3 - t2 + delivered,
                     st.n_prompt, len(st.emitters),
                     "chunk" if chunk else "single", st.prompt_tokens,
-                    ahead=prev is not None, enqueue_s=t_enq - t1)
+                    ahead=prev is not None, enqueue_s=t_enq - t1,
+                    wide=st.x.shape[1] == self._wide_t)
                 if self._paged:
                     self._steps.record_kv_positions(
                         *self.session.step_kv_positions)
@@ -1117,23 +1160,27 @@ class ContinuousBatcher(ServingBackend):
                 step.set("ahead", prev is not None)
 
     def _warm_programs(self) -> None:
-        """Compile (or load) both widths of the paged step ahead of
+        """Compile (or load) every width of the paged step ahead of
         the first step that feeds a token, on a batch whose slots all
         sit the step out (nothing but the scratch page is written):
         which program a step runs depends on who is in the pool, and
-        neither may compile under live traffic. These are the
-        id-returning programs every greedy step runs; the
-        row-returning pair compiles when first asked for (a request
-        with a temperature). A batcher without the chunk program
-        compiles its one step at the first request, as before."""
+        none may compile under live traffic. These are the
+        id-returning programs every greedy step runs, the wide one
+        last; their row-returning siblings compile when first asked
+        for (a request with a temperature). A batcher without a chunk
+        program compiles its one step at the first request, as
+        before."""
         self._warmed = True
-        if self._chunk_t == 1:
+        widths = dict.fromkeys(
+            t for t in (self._chunk_t, 1, self._wide_t) if t)
+        if len(widths) == 1:
             return
-        x = np.zeros((self.slots, self._chunk_t, 1), np.float32)
         idle = np.zeros((self.slots,), np.int32)
         try:
-            self.session.step_ids(x, idle, idle > 0)
-            self.session.step_ids(x[:, :1], idle, idle > 0)
+            for t in widths:
+                self.session.step_ids(
+                    np.zeros((self.slots, t, 1), np.float32), idle,
+                    idle > 0)
         except BaseException:
             # the step donates the pools: rebuild them, and let the
             # first real step surface a persistent fault to its
@@ -1211,9 +1258,14 @@ class ContinuousBatcher(ServingBackend):
         """The next device step, from the slots as they stand and
         without touching them: ``x`` is (slots, rows, 1) and slot ``i``
         feeds its first ``n_valid[i]`` rows (0: free or parked).
-        ``rows`` is the chunk width when some live slot has prompt
-        tokens beyond its ``feed``, else 1: a pool that only decodes
-        runs the single-token program. A decoding slot feeds its last
+        ``rows`` is 1 when no live slot has prompt tokens beyond its
+        ``feed``: a pool that only decodes runs the single-token
+        program. Else it is the chunk width, and the wide one where
+        the pool holds a wide program and what that step would feed
+        (a slot in decode 1 row, one in prefill up to the wide width
+        of the rows it has left) would not fit in the narrow step's
+        ``slots * t`` rows: at twice the width at least half of the
+        wide step's rows then do work. A decoding slot feeds its last
         token: from ``out`` where it was delivered, else by
         ``use_prev`` from the ids the step in flight leaves on the
         device."""
@@ -1221,8 +1273,18 @@ class ContinuousBatcher(ServingBackend):
                 if s is not None and not s.parked]
         if not live:
             return None
-        rows = self._chunk_t if any(s.prompt_left
-                                    for _, s in live) else 1
+        # the rows a slot can feed; a prefill-only request stops one
+        # token short: its export point is every prompt position but
+        # the last
+        need = lambda s: (1 + len(s.prompt_left)
+                          - int(s.req.prefill_export))
+        rows = 1
+        if any(s.prompt_left for _, s in live):
+            rows = self._chunk_t
+            if self._wide_t and sum(
+                    min(self._wide_t, need(s)) for _, s in live) \
+                    >= self.slots * self._chunk_t:
+                rows = self._wide_t
         st = _Step(np.zeros((self.slots, rows, 1), np.float32),
                    np.zeros((self.slots,), np.int32),
                    np.zeros((self.slots,), bool), live)
@@ -1230,10 +1292,7 @@ class ContinuousBatcher(ServingBackend):
         st.sync = (not self._paged or self._draining.is_set()
                    or self._migrate.is_set() or bool(self._parked))
         for i, s in live:
-            # a prefill-only request stops one token short: its
-            # export point is every prompt position but the last
-            n = min(rows, 1 + len(s.prompt_left)
-                    - int(s.req.prefill_export))
+            n = min(rows, need(s))
             if s.feed is not None:
                 st.x[i, 0, 0] = s.feed
             elif len(s.out) == s.emitted:

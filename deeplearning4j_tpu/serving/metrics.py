@@ -249,8 +249,12 @@ class BatcherStepMetrics:
     ``serving_steps_total{program}`` counts the steps by the program
     they ran, ``single`` (slots, 1) or ``chunk`` (slots, t), and
     ``serving_prompt_tokens_total`` the prompt tokens they fed to the
-    device. ``serving_kv_positions_read_total`` adds the KV positions
-    a step's attention layers read (by table: each slot's pages up to
+    device. A pool that holds a second, wider chunk program counts its
+    steps under ``chunk`` too, and again in
+    ``serving_wide_steps_total``, a series only such a pool has
+    (``holds_wide_program``).
+    ``serving_kv_positions_read_total`` adds the KV positions a
+    step's attention layers read (by table: each slot's pages up to
     its length; by gather: every slot's whole capacity) as the
     session ACCOUNTS them from the lengths it feeds and its layers'
     dispatch, not as the device measured them, and
@@ -279,6 +283,7 @@ class BatcherStepMetrics:
         reg = registry or MetricsRegistry()
         self._reg, self._name, self._experts = reg, name, None
         self._kv = self._pairs = self._ring = self._state = None
+        self._wide = None       # ``holds_wide_program``
         self._parts = {
             part: reg.histogram(
                 "serving_step_seconds",
@@ -317,7 +322,8 @@ class BatcherStepMetrics:
     def record(self, admit_s: float, device_s: float, sample_s: float,
                prompt_slots: int, decode_slots: int,
                program: str = "single", prompt_tokens: int = 0,
-               ahead: bool = False, enqueue_s: float = 0.0) -> None:
+               ahead: bool = False, enqueue_s: float = 0.0,
+               wide: bool = False) -> None:
         self._parts["admit"].record(admit_s)
         self._parts["device"].record(device_s)
         self._parts["sample"].record(sample_s)
@@ -328,6 +334,16 @@ class BatcherStepMetrics:
         self._prompt_tokens.inc(prompt_tokens)
         if ahead:
             self._ahead.inc()
+        if wide:
+            self._wide.inc()
+
+    def holds_wide_program(self) -> None:
+        """The batcher's pool holds a second, wider chunk program:
+        ``serving_wide_steps_total`` counts the steps that ran it."""
+        self._wide = self._reg.counter(
+            "serving_wide_steps_total",
+            help="device steps that ran the wide chunk program",
+            labels={"endpoint": self._name})
 
     def record_kv_positions(self, read: int, spanned: int) -> None:
         """One step's KV positions over a paged pool

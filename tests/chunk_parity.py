@@ -17,7 +17,12 @@ session's ``_ring``) is read where its rows live: the slot's own ring
 pages, position ``p`` at ring row ``p mod span``. Such a network's
 cases run at a page of 8 (``run_case(..., page=8)``), where a ring has
 room for the T rows of a chunk, and without ``prefix_resume``: a ring
-is not shared.
+is not shared. A layer that keeps a fixed-size STATE in a row a slot
+(the session's ``_state``: a short convolution's window) has no
+positions: its row after the chunk is the row the one-by-one steps
+leave, and the row of a slot the call did not feed is bit for bit what
+it was. ``run_case(..., t=4)`` runs the same cases at a chunk of 4 rows
+(the batcher's wide program of a pool of 64 slots).
 
 ``latent_by_table`` steers a latent layer's step onto the by-table
 kernel, as the chip takes it: the shapes' predicate forced and the
@@ -104,9 +109,17 @@ def pages_of(sess, pages):
 def leaf_rings(sess):
     """Per pool leaf, in ``live_rows``' order, the pages of the ring
     a slot owns there (0: the leaf lives in the allocator's pages)."""
-    return [ring for pool, ring in zip(sess._pools, sess._ring)
-            if pool is not None
+    return [ring for pool, ring, state in zip(sess._pools, sess._ring,
+                                              sess._state)
+            if pool is not None and not state
             for _ in jax.tree_util.tree_leaves(pool)]
+
+
+def state_rows(sess, slot):
+    """The slot's row of every leaf of the slot-owned state pools."""
+    return [np.asarray(leaf)[slot]
+            for pool, state in zip(sess._pools, sess._state) if state
+            for leaf in jax.tree_util.tree_leaves(pool)]
 
 
 def live_rows(sess, slot):
@@ -114,8 +127,8 @@ def live_rows(sess, slot):
     array: its leased pages in table order or, for a layer that keeps
     a ring, the slot's ring, position p at row p mod its length."""
     out = []
-    for pool, ring in zip(sess._pools, sess._ring):
-        if pool is None:
+    for pool, ring, state in zip(sess._pools, sess._ring, sess._state):
+        if pool is None or state:
             continue
         pages = (1 + slot * ring + np.arange(ring) if ring
                  else np.asarray(sess._leases[slot].pages))
@@ -148,16 +161,18 @@ def feed_single(sess, tokens):
     return last, counts
 
 
-def feed_both(chunked, single, tokens, atol=1e-5):
-    """One ``step_chunk`` of width T on ``chunked``, the same tokens
-    one by one on ``single``, and every comparison the module's text
-    names. Returns the chunk's (slots, 1, V) output."""
-    x = np.zeros((SLOTS, T, 1), np.float32)
+def feed_both(chunked, single, tokens, atol=1e-5, t=T):
+    """One ``step_chunk`` of width ``t`` on ``chunked``, the same
+    tokens one by one on ``single``, and every comparison the module's
+    text names. Returns the chunk's (slots, 1, V) output."""
+    x = np.zeros((SLOTS, t, 1), np.float32)
     n_valid = np.zeros((SLOTS,), np.int32)
     for slot, ids in tokens.items():
         x[slot, :len(ids), 0], n_valid[slot] = ids, len(ids)
     before = {slot: live_rows(chunked, slot)
               for slot in chunked._leases}
+    rows_before = {slot: state_rows(chunked, slot)
+                   for slot in chunked._leases}
     pos0 = chunked.slot_pos.copy()
     h = np.asarray(chunked.step_chunk(x, n_valid))
     assert h.shape[:2] == (SLOTS, 1)
@@ -178,35 +193,41 @@ def feed_both(chunked, single, tokens, atol=1e-5):
             # every position a later step may read
             read = np.arange(max(0, hi - span) if ring else 0, hi) % span
             np.testing.assert_allclose(a[read], w[read], atol=atol)
+    for slot, was in rows_before.items():
+        for a, b, w in zip(state_rows(chunked, slot), was,
+                           state_rows(single, slot)):
+            if n_valid[slot]:
+                np.testing.assert_allclose(a, w, atol=atol)
+            else:
+                np.testing.assert_array_equal(a, b)
     if counts is not None:
         jax.tree_util.tree_map(np.testing.assert_array_equal,
                                jax.device_get(chunked.step_aux), counts)
     return h
 
 
-def run_case(net, vocab, case, page=PAGE):
+def run_case(net, vocab, case, page=PAGE, t=T):
     rng = np.random.default_rng(sum(map(ord, case)))
     ids = lambda n: [int(v) for v in rng.integers(1, vocab, n)]
     chunked, single = sessions(net, page)
+    both = functools.partial(feed_both, chunked, single, t=t)
 
     def bind(slot, prompt, n_tokens):
         for s in (chunked, single):
             s.bind(slot, s.reserve(prompt, n_tokens))
 
     if case == "ragged":
-        # a slot with T rows, one with fewer, a decode slot with 1, a
+        # a slot with t rows, one with fewer, a decode slot with 1, a
         # free slot with 0, in one call
-        long, short, old = ids(11), ids(3), ids(5)
+        long, short, old = ids(t + 3), ids(3), ids(5)
         bind(0, long, 4)
         bind(1, short, 4)
         bind(3, old, 4)
         for s in (chunked, single):
             feed_single(s, {3: old})
-        feed_both(chunked, single,
-                  {0: long[:T], 1: short, 3: ids(1)})
+        both({0: long[:t], 1: short, 3: ids(1)})
         # and again: the long prompt's ragged tail beside two decodes
-        feed_both(chunked, single,
-                  {0: long[T:], 1: ids(1), 3: ids(1)})
+        both({0: long[t:], 1: ids(1), 3: ids(1)})
     elif case == "prefix_resume":
         # a slot that starts at a prefix hit: its first two pages are
         # the cache's too, and stay as they were
@@ -215,29 +236,30 @@ def run_case(net, vocab, case, page=PAGE):
         for s in (chunked, single):
             feed_single(s, {0: first})
             s.release(0, register_prompt=first)
-        again = first[:2 * page] + ids(5)
+        again = first[:2 * page] + ids(min(5, t))
         bind(2, again, 4)
         lease = chunked._leases[2]
         assert lease.resume_pos == 2 * page
         shared = lease.pages[:2]
         assert all(chunked.allocator.refcount(p) > 1 for p in shared)
         was = pages_of(chunked, shared)
-        feed_both(chunked, single, {2: again[2 * page:]})
+        both({2: again[2 * page:]})
         for a, b in zip(pages_of(chunked, shared), was):
             np.testing.assert_array_equal(a, b)
         assert all(chunked.allocator.refcount(p) > 1 for p in shared)
     elif case == "near_capacity":
-        # a slot whose table is full to its width, within T tokens of
+        # a slot whose table is full to its width, within t tokens of
         # capacity: the rows past n_valid would run off the table,
         # where a clamped lookup lands in its last live page
         full, other = ids(CAPACITY - 2), ids(6)
         bind(0, full, 2)
         bind(1, other, 4)
         assert len(chunked._leases[0].pages) == chunked.pages_per_slot
-        for lo in range(0, 24, T):
-            feed_both(chunked, single, {0: full[lo:lo + T]})
-        feed_both(chunked, single, {0: full[24:29], 1: other})
-        feed_both(chunked, single, {0: full[29:], 1: ids(1)})
-        feed_both(chunked, single, {0: ids(1), 1: ids(1)})
-        feed_both(chunked, single, {0: ids(1)})
+        for lo in range(0, 24, t):
+            both({0: full[lo:lo + t]})
+        k = min(5, t)
+        both({0: full[24:24 + k], 1: other[:t]})
+        both({0: full[24 + k:], 1: other[t:] or ids(1)})
+        both({0: ids(1), 1: ids(1)})
+        both({0: ids(1)})
         assert int(chunked.slot_pos[0]) == CAPACITY

@@ -163,6 +163,9 @@ def test_program_fed_layer_metrics_come_back(cell, tiny, capsys):
         driver.run(s)
     got = {name: spec.load_module("layer_metrics", name).read(s.obs)
            for name in want}
+    # the tiny pools are 4 slots of a page of 4: none holds a wide
+    # chunk program, and where there is none its share has no reading
+    assert got.pop("wide_steps_pct.serve", None) is None
     missing = sorted(n for n, v in got.items() if v is None)
     assert not missing, (missing, capsys.readouterr().out[-4000:])
     # the CPU's paged step gathers: it reads what its tables span

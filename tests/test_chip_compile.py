@@ -272,7 +272,8 @@ def _block_step(topo, layer, hidden, slots, t):
         sds((slots,), jnp.int32) if t > 1 else None).compile()
 
 
-@pytest.mark.parametrize("t", [2, 1], ids=["chunk_64x2", "decode_64x1"])
+@pytest.mark.parametrize("t", [4, 2, 1],
+                         ids=["wide_64x4", "chunk_64x2", "decode_64x1"])
 def test_latent_decoder_block_step_compiles_for_v5e(topo, as_tpu, t):
     """``LatentDecoderBlock.apply_stream_paged_aux`` at the widths and
     the pool of the benchmark's ``axk1_serve_decode`` cell (hidden
@@ -331,7 +332,8 @@ def test_latent_kernel_compiles_where_the_predicate_admits(
     assert _kernels_in(compiled) == 1
 
 
-@pytest.mark.parametrize("t", [4, 1], ids=["chunk_32x4", "decode_32x1"])
+@pytest.mark.parametrize("t", [8, 4, 1],
+                         ids=["wide_32x8", "chunk_32x4", "decode_32x1"])
 def test_shortcut_expert_block_step_compiles_for_v5e(topo, as_tpu, t):
     """``ShortcutExpertBlock.apply_stream_paged_aux`` at the widths
     and the pool of the benchmark's ``longcat_serve_tooluse`` cell
@@ -358,10 +360,12 @@ def test_shortcut_expert_block_step_compiles_for_v5e(topo, as_tpu, t):
     # them are 29 / 8 MB where the two gathers of 32 x 1,024 cached
     # rows and their float32 scores made them 118 / 74 MB
     assert 2.4e9 < mem.argument_size_in_bytes < 2.7e9
-    assert mem.temp_size_in_bytes < 50e6
+    # (55 MB at the wide width's 256 rows)
+    assert mem.temp_size_in_bytes < (60e6 if t == 8 else 50e6)
 
 
-@pytest.mark.parametrize("t", [2, 1], ids=["chunk_64x2", "decode_64x1"])
+@pytest.mark.parametrize("t", [4, 2, 1],
+                         ids=["wide_64x4", "chunk_64x2", "decode_64x1"])
 def test_grouped_query_window_cell_step_fits_v5e(topo, t):
     """The WHOLE id-returning step of the benchmark's
     ``mimo_serve_mixedlen`` cell (``PagedSlotSession._step_ids`` over
@@ -511,7 +515,8 @@ def test_short_conv_block_step_compiles_for_v5e(topo, t):
     assert len(between_the_projections(5)) == len(run)
 
 
-@pytest.mark.parametrize("t", [2, 1], ids=["chunk_64x2", "decode_64x1"])
+@pytest.mark.parametrize("t", [4, 2, 1],
+                         ids=["wide_64x4", "chunk_64x2", "decode_64x1"])
 def test_short_conv_expert_cell_step_fits_v5e(topo, as_tpu, t):
     """The WHOLE id-returning step of the benchmark's
     ``lfm2_serve_agent`` cell (``PagedSlotSession._step_ids`` over the

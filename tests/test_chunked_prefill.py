@@ -7,23 +7,34 @@ mixed traffic; ``ceil(n / t)`` steps from a prompt of n to its first
 token; the step counters against the schedule; a prefill export that
 stops one token short and imports to the same ids; a migration offered
 mid-prefill that resumes, on a survivor or on the incumbent, to the
-same ids. The session's own parity is in tests/chunk_parity.py."""
+same ids. A pool of many slots holds a second chunk program twice as
+wide: which pools do, the rule that picks a step's width on hand-made
+pools either side of its turning point, an export at either width, and
+the three programs warm before the first token (the ids where widths
+alternate are held in tests/test_serve_lookahead.py). The session's
+own parity is in tests/chunk_parity.py."""
 
 import functools
+import json
+import os
 
 import numpy as np
 import pytest
+
+from test_serve_lookahead import Batcher
 
 from deeplearning4j_tpu import MultiLayerNetwork, NeuralNetConfiguration
 from deeplearning4j_tpu.models.paged_kv import parse_lease
 from deeplearning4j_tpu.nn.conf import updaters
 from deeplearning4j_tpu.nn.conf.inputs import InputType
-from deeplearning4j_tpu.nn.conf.layers import (EmbeddingSequenceLayer,
-                                               LSTM, RnnOutputLayer,
-                                               TransformerEncoderLayer)
+from deeplearning4j_tpu.nn.conf.layers import (
+    EmbeddingSequenceLayer, GroupedQueryDecoderBlock, LSTM,
+    RnnOutputLayer, ShortConvDecoderBlock, StateSpaceDecoderBlock,
+    TransformerEncoderLayer)
 from deeplearning4j_tpu.serving import ContinuousBatcher, continuous
 from deeplearning4j_tpu.serving.continuous import (MigrationOffer,
-                                                   chunk_width)
+                                                   chunk_width,
+                                                   wide_chunk_width)
 from deeplearning4j_tpu.serving.metrics import ServingMetrics
 
 pytestmark = pytest.mark.decode
@@ -45,8 +56,10 @@ def net():
 
 @pytest.fixture
 def four_rows(monkeypatch):
-    """A row budget that gives 4 slots a chunk of 4 tokens."""
+    """A row budget that gives 4 slots a chunk of 4 tokens, and no
+    second width."""
     monkeypatch.setattr(continuous, "CHUNK_ROWS", SLOTS * T)
+    monkeypatch.setattr(continuous, "WIDE_CHUNK_ROWS", SLOTS * T)
 
 
 def _prompt(n, seed):
@@ -110,6 +123,7 @@ def _counts(metrics, name):
 
 def test_the_width_follows_the_pool():
     assert continuous.CHUNK_ROWS == 128
+    assert continuous.WIDE_CHUNK_ROWS == 256
     assert chunk_width(8, 1024) == 16 and chunk_width(64, 1024) == 2
     assert chunk_width(4, 1024) == 32 and chunk_width(128, 1024) == 1
     assert chunk_width(1, 1024) == 128 and chunk_width(2, 16) == 16
@@ -296,3 +310,285 @@ def test_a_migration_offered_mid_prefill_resumes(net, four_rows,
         a.shutdown(drain=True)
         b.shutdown(drain=True)
     _same_ids(got, want, gaps)
+
+
+# ---------------------------------------------------------------------------
+# the second, wider chunk program
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("slots, capacity, page, want", [
+    (64, 2048, 16, (2, 4)),     # lfm2_serve_agent
+    (64, 1024, 16, (2, 4)),     # axk1_serve_decode
+    (32, 1024, 16, (4, 8)),     # longcat_serve_tooluse
+    (64, 16, 16, (2, 4)),       # mimo_serve_mixedlen: a ring caps a
+                                # slot's rows at a page
+    (8, 1024, 16, (16, 0)),     # gpt2m_serve_closed: a page a step
+    (16, 1024, 16, (8, 16)),
+    (16, 1024, 8, (8, 0)),      # the narrow width is a page already
+    (128, 1024, 16, (1, 2)),
+    (256, 1024, 16, (1, 0)),
+    (4, 4, 4, (4, 0)),          # the benchmark's tiny presets
+    (64, 2, 16, (2, 0)),        # no wider than a slot
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_the_widths_follow_the_pool(slots, capacity, page, want):
+    """(t_lo, t_hi) at the budgets of 128 and 256 rows; 0: the pool
+    holds no wide program."""
+    assert (chunk_width(slots, capacity),
+            wide_chunk_width(slots, capacity, page)) == want
+    # the wide width is the same function under the wide budget
+    assert want[1] in (0, chunk_width(slots, capacity,
+                                      continuous.WIDE_CHUNK_ROWS))
+
+
+def _block_net(block):
+    conf = (NeuralNetConfiguration.builder().set_seed(0)
+            .updater(updaters.sgd(0.0)).list()
+            .layer(EmbeddingSequenceLayer(n_in=V, n_out=16))
+            .layer(block)
+            .layer(RnnOutputLayer(n_out=V, loss="mcxent"))
+            .set_input_type(InputType.recurrent(V, 256)).build())
+    return MultiLayerNetwork(conf).init()
+
+
+@pytest.mark.parametrize("kind, page, want", [
+    ("ring", 16, (2, 4)), ("ring", 2, (2, 0)),
+    ("state_space", 16, (2, 0)), ("short_conv", 16, (2, 4))])
+def test_the_session_says_what_caps_the_widths(kind, page, want):
+    """A ring caps a slot's rows at a page (``chunk_rows_max``), and
+    a layer whose step unrolls over the chunk's rows keeps the pool to
+    one chunk width (``unrolls_chunk_rows``: the Mamba-2 mixer says so
+    of itself, the short convolution does not)."""
+    block = {"ring": GroupedQueryDecoderBlock(window=8),
+             "state_space": StateSpaceDecoderBlock(),
+             "short_conv": ShortConvDecoderBlock()}[kind]
+    cb = ContinuousBatcher(_block_net(block), slots=64, capacity=256,
+                           page_size=page, kv_mode="paged",
+                           metrics=ServingMetrics(), name=kind)
+    try:
+        sess = cb.session
+        assert sess.chunk_rows_max == (page if kind == "ring" else 256)
+        assert sess.unrolls_chunk_rows == (kind == "state_space")
+        assert (cb._chunk_t, cb._wide_t) == want
+        snap = cb.metrics.registry.snapshot()
+        # the counter exists where the program does, at 0
+        assert (f'serving_wide_steps_total{{endpoint="{kind}"}}'
+                in snap) == bool(want[1])
+    finally:
+        cb.shutdown(drain=True)
+
+
+WIDE_SLOTS, T_LO, T_HI = 16, 2, 4
+
+
+@pytest.fixture
+def two_widths(monkeypatch):
+    """Row budgets that give 16 slots chunks of 2 and of 4 tokens."""
+    monkeypatch.setattr(continuous, "CHUNK_ROWS", WIDE_SLOTS * T_LO)
+    monkeypatch.setattr(continuous, "WIDE_CHUNK_ROWS",
+                        WIDE_SLOTS * T_HI)
+
+
+def _gated(net, name):
+    """A 16-slot batcher that holds both chunk programs, its worker
+    held before its first pass until ``go()``: what was submitted
+    before is admitted at once (tests/test_serve_lookahead.py's)."""
+    b = Batcher(net, name, slots=WIDE_SLOTS, queue_limit=256)
+    assert (b.cb._chunk_t, b.cb._wide_t) == (T_LO, T_HI)
+    return b
+
+
+def _hand_made(left=0, export=False, decoding=False):
+    """A slot with ``left`` prompt tokens beyond its ``feed``, or one
+    in decode."""
+    req = continuous._GenRequest(np.arange(1, left + 2), 4, 0.0, 0, None)
+    req.prefill_export = export
+    s = continuous._Slot(req)
+    if decoding:
+        s.prompt_left, s.feed, s.out, s.emitted = [], None, [3], 1
+    return s
+
+
+_LONG, _DECODE = dict(left=9), dict(decoding=True)
+
+# (slots by hand, the step's rows): 16 slots, t 2 and 4, so the wide
+# step runs from 32 rows on offer
+POOLS = {
+    "all_decode": ([_DECODE] * 16, 1),
+    "one_token_prompts": ([dict(left=0)] * 3 + [_DECODE] * 5, 1),
+    "5_of_16_in_prefill": ([_LONG] * 5 + [_DECODE] * 11, T_LO),   # 31
+    "5_and_a_tail_of_2": ([_LONG] * 5 + [dict(left=1)]
+                          + [_DECODE] * 10, T_HI),                # 32
+    "6_of_16_in_prefill": ([_LONG] * 6 + [_DECODE] * 10, T_HI),   # 34
+    "7_alone": ([_LONG] * 7, T_LO),                               # 28
+    "7_and_a_tail_of_3": ([_LONG] * 7 + [dict(left=2)], T_LO),    # 31
+    "8_alone": ([_LONG] * 8, T_HI),                               # 32
+    "short_tails": ([dict(left=1)] * 15 + [_DECODE], T_LO),       # 31
+    "exports_of_4": ([dict(left=4, export=True)] * 8, T_HI),      # 32
+    "exports_of_3": ([dict(left=3, export=True)] * 10, T_LO),     # 30
+    "exports_of_3_fill_it": ([dict(left=3, export=True)] * 10
+                             + [_DECODE] * 2, T_HI),              # 32
+}
+
+
+@pytest.mark.parametrize("pool", list(POOLS))
+def test_the_plan_goes_wide_when_the_rows_on_offer_fill_it(
+        net, two_widths, pool):
+    made, want_rows = POOLS[pool]
+    b = _gated(net, "plan")
+    cb = b.cb
+    try:
+        cb._slots = [_hand_made(**kw) for kw in made] + [None] * (
+            WIDE_SLOTS - len(made))
+        st = cb._plan_step()
+        need = [1 if kw.get("decoding") else
+                1 + kw["left"] - int(kw.get("export", False))
+                for kw in made]
+        # the rule, written out
+        offered = sum(min(T_HI, n) for n in need)
+        assert want_rows == (
+            1 if not any(kw.get("left") for kw in made) else
+            T_HI if offered >= WIDE_SLOTS * T_LO else T_LO)
+        assert st.x.shape == (WIDE_SLOTS, want_rows, 1)
+        # each slot feeds what it has, up to the step's width: an
+        # export stops one token short at either width
+        assert st.n_valid.tolist() == [
+            min(want_rows, n) for n in need] + [0] * (
+                WIDE_SLOTS - len(made))
+        assert [i for i, _ in st.emitters] == [
+            i for i, (kw, n) in enumerate(zip(made, need))
+            if not kw.get("export") and n <= want_rows]
+    finally:
+        cb._slots = [None] * WIDE_SLOTS
+        assert b.close()
+
+
+@pytest.mark.parametrize("n_requests, wide", [(3, False), (10, True)])
+def test_prefill_exports_stop_one_token_short_at_either_width(
+        net, two_widths, n_requests, wide):
+    prompts = [_prompt(19, 40 + k) for k in range(n_requests)]
+    a, b = _gated(net, "prefill"), _gated(net, "decode")
+    b.go()
+    try:
+        reqs = [a.cb.submit(p, 5, prefill_export=True) for p in prompts]
+        a.go()
+        blobs = [a.cb.wait(r) for r in reqs]
+        for blob in blobs:
+            # every prompt position but the last is in the cache
+            assert parse_lease(blob)[0]["pos"] == 18
+        got = [int(t) for t in
+               b.cb.wait(b.cb.import_stream(blobs[-1]))]
+        # 18 tokens a prompt: chunks of 4, 4, 4, 4 and 2 (the tails of
+        # 2 do not fill a wide step), or nine of 2
+        assert a.count("serving_wide_steps_total") == (4 if wide else 0)
+        assert _counts(a.metrics, "prefill") == {
+            "chunk": 5 if wide else 9, "single": 0,
+            "prompt": n_requests * (5 if wide else 9), "decode": 0,
+            "prompt_tokens": 18 * n_requests}
+    finally:
+        assert a.close() and b.close()
+    _same_ids(got, *_token_by_token(net, prompts[-1], 5))
+
+
+def test_three_programs_are_warm_before_the_first_token(net, two_widths):
+    """The first step that feeds a token finds the single, the narrow
+    and the wide id-returning program compiled, and traffic that runs
+    all three compiles nothing."""
+    from deeplearning4j_tpu.observability.compile_watch import (
+        install_global_watch)
+    b = _gated(net, "warm")
+    cb = b.cb
+    try:
+        b.go()
+        cb.generate([5], 1)
+        assert cb.session._registered == {
+            ("paged_step_ids", t) for t in (1, T_LO, T_HI)}
+        sizes = [(30, 3)] * 9 + [(1, 6), (3, 2), (2, 9)] + [(25, 4)] * 3
+        with install_global_watch().zero_compile_scope(
+                "steps of three widths"):
+            reqs = [cb.submit(_prompt(n, 60 + k), n_tokens)
+                    for k, (n, n_tokens) in enumerate(sizes)]
+            for r in reqs:
+                cb.wait(r)
+        c = _counts(b.metrics, "warm")
+        assert 0 < b.count("serving_wide_steps_total") < c["chunk"] and c["single"] > 1
+        assert cb.session._registered == {
+            ("paged_step_ids", t) for t in (1, T_LO, T_HI)}
+    finally:
+        assert b.close()
+
+
+def test_the_wide_steps_reader_over_two_snapshots():
+    """benchmark/layer_metrics/wide_steps_pct.serve.py: the wide steps'
+    share of the window's steps where the batcher has the counter, and
+    nothing where it has not (a pool without a wide program, the
+    parent of PR 42) or the window held no step; the key a real
+    ``BatcherStepMetrics`` writes is the key the reader matches."""
+    from benchmark.harness import spec
+    reader = spec.load_module("layer_metrics", "wide_steps_pct.serve")
+    read = lambda before, after: reader.read(
+        {"counters": {"before": before, "after": after}})
+    ep = 'endpoint="generate/lm/v1"'
+    wide = "serving_wide_steps_total{%s}" % ep
+    steps = 'serving_steps_total{%s,program="%%s"}' % ep
+    before = {wide: 10.0, steps % "chunk": 60.0, steps % "single": 40.0}
+    after = {wide: 100.0, steps % "chunk": 180.0, steps % "single": 120.0}
+    assert read(before, after) == pytest.approx(45.0)
+    held_not_run = dict(after, **{wide: 10.0})
+    assert read(before, held_not_run) == 0.0
+    del before[wide], after[wide]
+    assert read(before, after) is None
+    assert read(held_not_run, held_not_run) is None
+    m = ServingMetrics()
+    recorded = m.batcher_steps("generate/lm/v1")
+    recorded.holds_wide_program()
+    first = m.registry.snapshot()
+    recorded.record(0.001, 0.002, 0.001, 2, 0, "chunk", wide=True)
+    recorded.record(0.001, 0.002, 0.001, 1, 1, "chunk")
+    recorded.record(0.001, 0.002, 0.001, 0, 2, "single")
+    recorded.record(0.001, 0.002, 0.001, 2, 0, "chunk", wide=True)
+    assert read(first, m.registry.snapshot()) == pytest.approx(50.0)
+    with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert bench["per_layer"][-1] == {
+        "name": "wide_steps_pct.serve", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "Serving",
+        "moves": "serve_tokens_per_s",
+        "workloads": ["axk1_serve_decode", "longcat_serve_tooluse",
+                      "mimo_serve_mixedlen", "lfm2_serve_agent"]}
+
+
+@pytest.mark.parametrize("cell, reading", [
+    ("mimo_serve_mixedlen", 2), ("lfm2_serve_agent", 2),
+    ("axk1_serve_decode", 8), ("longcat_serve_tooluse", 4)])
+def test_the_paged_kernels_admit_the_wide_width(monkeypatch, cell,
+                                                reading):
+    """At the wide width every attention layer of the cell's published
+    shapes that reads its pages by table at the narrow one still does
+    (the predicates of ``ops.paged_attention``: the tile conditions
+    and ``_vmem_bytes`` within ``_VMEM_BUDGET``; the backend asked for
+    a TPU here): a silent fall back to the gather would cost the wide
+    steps what the by-table kernels won. ``reading``: the layers that
+    read by table (MiMo's window layers keep their rings)."""
+    import jax
+    import jax.numpy as jnp
+    from benchmark.harness import spec
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c = spec.load(cell)
+    config, sv = c.config, c.traffic["server"]
+    builder = spec.load_module("builders", config["builder"])
+    with builder.policy(config):
+        net = builder.build(config).init()       # parameters as shapes
+    cap = min([sv["capacity"]] + [
+        sv["page_size"] for layer in net.layers
+        if getattr(layer, "ring_pages", lambda p: 0)(sv["page_size"])])
+    t_lo = chunk_width(sv["slots"], cap)
+    t_hi = wide_chunk_width(sv["slots"], cap, sv["page_size"])
+    assert t_hi == 2 * t_lo
+    by_table = [layer for layer in net.layers
+                if hasattr(layer, "paged_reads_by_table")
+                and layer.paged_reads_by_table(sv["page_size"], t_lo,
+                                               jnp.bfloat16)]
+    assert len(by_table) == reading
+    for layer in by_table:
+        assert layer.paged_reads_by_table(sv["page_size"], t_hi,
+                                          jnp.bfloat16)

@@ -177,18 +177,20 @@ def test_paged_prefill_then_decode_matches_the_reference(tiny_net):
     assert aux.shape == (2, 16) and (aux.sum(axis=1) == 12).all()
 
 
+@pytest.mark.parametrize("t", [8, 4])
 @pytest.mark.parametrize("path", ["gather", "by_table"])
 @pytest.mark.parametrize("case", chunk_parity.CASES)
 def test_chunk_step_matches_token_by_token(tiny_net, monkeypatch, case,
-                                           path):
+                                           path, t):
     """The latent pool's cases of tests/chunk_parity.py: the two
     expert layers' counts of a chunk are the one-by-one counts
     summed, so rows past ``n_valid`` reach no routed expert. Once by
     the gather (the CPU's path) and once with the pages read by table
-    (the chip's: the predicate forced, the kernel interpreted)."""
+    (the chip's: the predicate forced, the kernel interpreted), at
+    chunks of 8 rows and of 4 (a 64-slot pool's wide program)."""
     if path == "by_table":
         chunk_parity.latent_by_table(monkeypatch)
-    chunk_parity.run_case(tiny_net, 96, case)
+    chunk_parity.run_case(tiny_net, 96, case, t=t)
 
 
 def test_free_slots_reach_no_expert(tiny_net):

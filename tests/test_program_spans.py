@@ -567,6 +567,7 @@ class TestBatcherSteps:
     def _two_tokens_a_chunk(self, monkeypatch):
         from deeplearning4j_tpu.serving import continuous
         monkeypatch.setattr(continuous, "CHUNK_ROWS", 2 * CHUNK_T)
+        monkeypatch.setattr(continuous, "WIDE_CHUNK_ROWS", 2 * CHUNK_T)
 
     def _run(self):
         from deeplearning4j_tpu.serving.continuous import (

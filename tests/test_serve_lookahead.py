@@ -17,7 +17,11 @@ one runs. What is held here:
 - a poisoned step and a raised one, at the enqueue and at the fetch;
 - prefill export, a migration offered mid-decode and a drain, each
   with a step in flight;
-- ``serving_lookahead_steps_total`` against a scripted schedule.
+- ``serving_lookahead_steps_total`` against a scripted schedule;
+- a pool that holds a second, wider chunk program: single, narrow and
+  wide steps in one run, a decoding slot's id fed on the device across
+  a change of width, and the counters against the schedule written
+  out from counts.
 """
 
 import threading
@@ -55,6 +59,7 @@ def net():
 @pytest.fixture(autouse=True)
 def four_tokens_a_chunk(monkeypatch):
     monkeypatch.setattr(continuous, "CHUNK_ROWS", SLOTS * T)
+    monkeypatch.setattr(continuous, "WIDE_CHUNK_ROWS", SLOTS * T)
 
 
 @pytest.fixture(autouse=True)
@@ -573,3 +578,101 @@ def test_the_dense_session_never_runs_ahead(net):
         assert b.close()
     for (p, k), g in zip(((_prompt(5, 1), 4), (_prompt(2, 2), 6)), got):
         _same_ids(g, *_token_by_token(net, p, k))
+
+
+# ---------------------------------------------------------------------------
+# (f) a pool with a second, wider chunk program
+# ---------------------------------------------------------------------------
+
+WIDE_SLOTS, T_LO, T_HI = 16, 2, 4
+
+# (prompt tokens, output tokens): bursts of long prompts fill the wide
+# step, their tails and the short ones run the narrow one, and in
+# the end the pool only decodes
+ALTERNATING = ([(30, 3)] * 7 + [(1, 12), (2, 9), (5, 14), (3, 20)]
+               + [(9, 2), (14, 5)] * 3 + [(27, 4)] * 8 + [(1, 3), (6, 6)]
+               + [(22, 1)] * 6 + [(4, 18), (11, 13)])
+
+
+def _schedule(sizes, slots, t_lo, t_hi):
+    """The batcher's counters from counts alone: requests admitted in
+    order into the lowest free slots, one step a pass; a slot holds
+    [prompt tokens not yet fed, tokens still to emit]."""
+    pending, pool = [list(s) for s in sizes], [None] * slots
+    c = dict(single=0, chunk=0, wide=0, prompt=0, decode=0,
+             prompt_tokens=0)
+    while pending or any(pool):
+        for i in range(slots):
+            if pool[i] is None and pending:
+                pool[i] = pending.pop(0)
+        live = [s for s in pool if s]
+        need = [max(1, s[0]) for s in live]
+        rows = 1
+        if any(s[0] > 1 for s in live):
+            offered = sum(min(t_hi, n) for n in need)
+            rows = t_hi if offered >= slots * t_lo else t_lo
+        c["single" if rows == 1 else "chunk"] += 1
+        c["wide"] += rows == t_hi
+        for i, s in enumerate(pool):
+            if not s:
+                continue
+            n = min(rows, max(1, s[0]))
+            c["prompt_tokens"] += n if s[0] else 0
+            if s[0] > n:
+                s[0] -= n
+                c["prompt"] += 1
+                continue
+            s[0] = 0
+            s[1] -= 1
+            c["decode"] += 1
+            if not s[1]:
+                pool[i] = None
+    return c
+
+
+def test_the_schedule_written_out_is_the_narrow_batchers():
+    """``_schedule`` against counts derived by hand: alone, a prompt of
+    9 at t 4 takes chunks of 4 and 4 and a single step, then 4 more
+    steps (``test_lookahead_steps_follow_the_schedule``'s request)."""
+    assert _schedule([(9, 5)], 4, 4, 8) == dict(
+        single=5, chunk=2, wide=0, prompt=2, decode=5, prompt_tokens=9)
+    # eight of sixteen slots in prefill fill the wide step
+    c = _schedule([(9, 1)] * 8, 16, 2, 4)
+    assert (c["chunk"], c["wide"], c["single"]) == (2, 2, 1)
+
+
+def test_widths_alternate_and_the_ids_are_the_token_by_token_ids(
+        net, monkeypatch):
+    monkeypatch.setattr(continuous, "CHUNK_ROWS", WIDE_SLOTS * T_LO)
+    monkeypatch.setattr(continuous, "WIDE_CHUNK_ROWS", WIDE_SLOTS * T_HI)
+    reqs = [(_prompt(n, 100 + k), n_tokens, {})
+            for k, (n, n_tokens) in enumerate(ALTERNATING)]
+    runs = {}
+    for mode in ("ahead", "sync"):
+        b = Batcher(net, mode, synchronous=mode == "sync",
+                    slots=WIDE_SLOTS, queue_limit=256)
+        try:
+            assert (b.cb._chunk_t, b.cb._wide_t) == (T_LO, T_HI)
+            got = b.run(reqs)
+            assert b.cb._prefix_hits.value == 0
+        finally:
+            assert b.close()
+        runs[mode] = (got, dict(
+            single=b.count("serving_steps_total", program="single"),
+            chunk=b.count("serving_steps_total", program="chunk"),
+            wide=b.count("serving_wide_steps_total"),
+            prompt=b.count("serving_slot_steps_total", kind="prompt"),
+            decode=b.count("serving_slot_steps_total", kind="decode"),
+            prompt_tokens=b.count("serving_prompt_tokens_total")),
+            b.ahead(), b.steps())
+    got, counts, ahead, steps = runs["ahead"]
+    want = _schedule(ALTERNATING, WIDE_SLOTS, T_LO, T_HI)
+    # all three programs ran, a wide step counts as a chunk step, and
+    # the counters are the schedule's
+    assert 0 < want["wide"] < want["chunk"] and want["single"] > 0
+    assert counts == want and runs["sync"][:2] == (got, counts)
+    # the pool never ran empty: every step but the first was enqueued
+    # behind one still on the device, whatever the two steps' widths
+    assert ahead == steps - 1 and runs["sync"][2] == 0
+    for (p, n, _), g in zip(reqs, got):
+        _same_ids(g, *_token_by_token(net, p, n))

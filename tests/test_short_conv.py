@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import chunk_parity
 from deeplearning4j_tpu import dtypes
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import (
@@ -476,6 +477,17 @@ def test_chunked_prefill_then_decode_matches_the_reference(tiny_net, t):
     assert len(got) >= 26
     for pos, row in got.items():
         np.testing.assert_allclose(row, want[pos], atol=ATOL)
+
+
+@pytest.mark.parametrize("t", [4, 8])
+@pytest.mark.parametrize("case", ["ragged", "near_capacity"])
+def test_chunk_step_matches_token_by_token(tiny_net, case, t):
+    """tests/chunk_parity.py's cases over the two-row window and the
+    attention layer's pages under one table, at chunks of 4 rows (a
+    64-slot pool's wide program) and of 8: a fed slot's window is what
+    the one-by-one steps leave, an unfed slot's is untouched, and the
+    experts' counts of a chunk are the one-by-one counts summed."""
+    chunk_parity.run_case(tiny_net, VOCAB, case, page=4, t=t)
 
 
 def test_step_ids_picks_the_reference_best(tiny_net):

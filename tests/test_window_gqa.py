@@ -279,13 +279,18 @@ def test_a_chunk_wider_than_the_ring_raises(tiny_net):
     sess.step_chunk(x[:, :17], np.array([17, 0, 0], np.int32))
 
 
+@pytest.mark.parametrize("page, t", [(8, 8), (4, 4)],
+                         ids=["ring5_t8", "ring9_t4"])
 @pytest.mark.parametrize("case", ["ragged", "near_capacity"])
-def test_chunk_step_matches_token_by_token(tiny_net, case):
+def test_chunk_step_matches_token_by_token(tiny_net, case, page, t):
     """tests/chunk_parity.py's cases over both kinds of cache (a page
-    of 8: a ring of 5 pages has room for its 8-row chunks): the rows
-    past ``n_valid`` alter no ring row either, and an expert layer's
-    counts of a chunk are the one-by-one counts summed."""
-    chunk_parity.run_case(tiny_net, 96, case, page=8)
+    of 8: a ring of 5 pages has room for its 8-row chunks; a page of
+    4: a ring of 9 pages, the published count, under chunks of 4, a
+    64-slot pool's wide program): the rows past ``n_valid`` alter no
+    ring row either, and an expert layer's counts of a chunk are the
+    one-by-one counts summed."""
+    assert tiny_net.layers[2].ring_pages(page) == {8: 5, 4: 9}[page]
+    chunk_parity.run_case(tiny_net, 96, case, page=page, t=t)
 
 
 def test_a_slot_that_sits_a_step_out_keeps_its_ring(tiny_net):

@@ -1,19 +1,25 @@
 #!/usr/bin/env python3
 """A serving cell read at several row budgets of the chunked-prefill
-step (``deeplearning4j_tpu.serving.continuous.CHUNK_ROWS``: a slot in
-prefill feeds up to t tokens a step, slots * t <= rows):
+step (``deeplearning4j_tpu.serving.continuous.CHUNK_ROWS`` and
+``WIDE_CHUNK_ROWS``: a slot in prefill feeds up to t tokens a step,
+slots * t <= rows, and a pool may hold a second program at the wide
+budget, run only in the steps whose prompt rows fill it):
 
     python3 tools/measure_chunk_rows.py <workload> \\
-        <seconds> <seed> <rows> [<rows> ...]
+        <seconds> <seed> <rows>[:<wide rows>] [...]
 
-For each budget one untraced window through the driver itself, in one
-process (one set-up of the chip, the weights made anew each time), and
-one JSON line: the budget, the width t it gives the cell's pool, the
-driver's result, and the batcher's own counters over the window (the
-readers of benchmark/layer_metrics that need no trace). The program
-has no option for the budget; this script sets the module's constant,
-which is how PERF.md's three readings were taken. A budget of 1 is
-token-by-token prefill.
+``<rows>`` alone sets both budgets to it (one chunk program at that
+width in every chunk step, as before PR 42), ``128:256`` is the
+program's own pair. For each one untraced window through the driver
+itself, in one process (one set-up of the chip, the weights made anew
+each time), and one JSON line: the budgets, the widths ``t_lo`` and
+``t_hi`` the batcher gave the cell's pool (``t_hi`` 0: no wide
+program), the driver's result, and the batcher's own counters over
+the window (the readers of benchmark/layer_metrics that need no
+trace; ``wide_steps_pct.serve`` is the wide steps' share). The program
+has no option for the budgets; this script sets the module's
+constants, which is how PERF.md's readings were taken. A budget of 1
+is token-by-token prefill.
 """
 
 import json
@@ -24,7 +30,8 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-COUNTERS = ("chunk_steps_pct.serve", "prompt_slot_steps_pct.serve",
+COUNTERS = ("chunk_steps_pct.serve", "wide_steps_pct.serve",
+            "prompt_slot_steps_pct.serve",
             "step_device_ms.serve", "step_host_ms.serve",
             "prefill_ms.serve", "queue_wait_ms.serve",
             "batch_occupancy_pct.serve",
@@ -36,11 +43,16 @@ def main(workload, seconds, seed, budgets):
     from deeplearning4j_tpu.serving import continuous
     cell = spec.load(workload)
     driver = spec.load_module("drivers", cell.traffic["driver"])
-    server = cell.traffic["server"]
-    for rows in budgets:
-        continuous.CHUNK_ROWS = rows
-        width = continuous.chunk_width(server["slots"],
-                                       server["capacity"])
+    # the widths are the batcher's own: it knows its session's caps
+    widths, warm = [], continuous.ContinuousBatcher._warm_programs
+
+    def noting(cb):
+        widths.append((cb._chunk_t, cb._wide_t))
+        return warm(cb)
+
+    continuous.ContinuousBatcher._warm_programs = noting
+    for rows, wide in budgets:
+        continuous.CHUNK_ROWS, continuous.WIDE_CHUNK_ROWS = rows, wide
         s = session.Session(cell, seed, seconds, 0, time.perf_counter())
         result = driver.run(s)
         read = {}
@@ -49,7 +61,8 @@ def main(workload, seconds, seed, budgets):
             if v is not None:
                 read[name] = v
         print(json.dumps({
-            "rows": rows, "t": width, "seed": seed,
+            "rows": rows, "wide_rows": wide, "t_lo": widths[-1][0],
+            "t_hi": widths[-1][1], "seed": seed,
             "correct": result["correct"], "failed": result["failed"],
             "checks": {c["name"]: c["value"] for c in s.checks},
             "metrics": {k: v["value"]
@@ -60,4 +73,5 @@ def main(workload, seconds, seed, budgets):
 
 if __name__ == "__main__":
     main(sys.argv[1], float(sys.argv[2]), int(sys.argv[3]),
-         [int(a) for a in sys.argv[4:]])
+         [tuple(int(r) for r in (a.split(":") * 2)[:2])
+          for a in sys.argv[4:]])
