@@ -1051,6 +1051,17 @@ class PagedSlotSession:
         self._step = jax.jit(step, **_DONATE_POOLS)
         self._step_ids = jax.jit(step_ids, **_DONATE_POOLS)
 
+    def runs_grouped_experts(self, t: int) -> bool:
+        """Does the step program at ``t`` rows a slot run some expert
+        layer's held experts as the grouped pass over the selected
+        pairs (the block's ``experts_grouped`` of the ``slots * t``
+        rows such a step carries)? The batcher asks, for
+        ``serving_moe_grouped_steps_total``."""
+        return any(
+            hasattr(layer, "experts_grouped")
+            and layer.experts_grouped(self.slots * t, self._dtype)
+            for layer in self.net.layers)
+
     def _register_program(self, kind: str, t: int, jitted, args) -> None:
         """Tell ``observability.programs`` of a step program about to
         run on ``args`` for the first time, as ``<kind>/t=<rows a

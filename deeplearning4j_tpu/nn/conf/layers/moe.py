@@ -70,6 +70,7 @@ from deeplearning4j_tpu.nn.conf.layers.normalization import rms_norm
 from deeplearning4j_tpu.nn.conf.layers.short_conv import (
     ShortConvMixerLayer)
 from deeplearning4j_tpu.nn.conf.layers.state_space import Mamba2MixerLayer
+from deeplearning4j_tpu.ops import grouped_experts
 
 __all__ = ["SparseExpertsLayer", "LatentDecoderBlock",
            "ShortcutExpertBlock", "GroupedQueryDecoderBlock",
@@ -180,20 +181,34 @@ class SparseExpertsLayer(BaseLayer):
             return ids.astype(jnp.int32), w * self.routed_scaling_factor
 
     # ---- the held experts' part ----
-    def apply_counted(self, params, x, active=None):
+    def takes_grouped_pass(self, rows: int, dtype) -> bool:
+        """Does a serving step of ``rows`` rows run the grouped pass
+        (``ops.grouped_experts.grouped_pass`` of this layer's
+        shapes)?"""
+        return grouped_experts.grouped_pass(
+            rows, self.top_k, self.router_width, self.n_in,
+            self.expert_width, dtype)
+
+    def apply_counted(self, params, x, active=None, stream=False):
         """(out, counts): ``counts`` (held,) int32, how many tokens
         each held expert served. ``active`` marks the rows that carry
         a token, (B,) for whole sequences or (B,T) row by row (the
         chunk program's ragged rows); the others reach no expert and
-        are not counted (a free slot of a decode batch)."""
-        out, tally = self.apply_tallied(params, x, active)
+        are not counted (a free slot of a decode batch). ``stream``:
+        the call is a serving step's, see ``apply_tallied``."""
+        out, tally = self.apply_tallied(params, x, active, stream)
         return out, tally["held"]
 
-    def apply_tallied(self, params, x, active=None):
+    def apply_tallied(self, params, x, active=None, stream=False):
         """(out, tally): ``apply_counted`` with all three counts of
         the ``active`` rows, ``{"held": (held,) tokens a held expert,
         "zero": () (row, zero expert) pairs, "selected": () (row,
-        selected expert) pairs}``, int32."""
+        selected expert) pairs}``, int32. The held experts' part has
+        two forms that differ by the order of a float32 sum: the
+        DENSE pass, every row through every held expert, and, in a
+        serving step (``stream``: nothing differentiates it) whose
+        shapes ``takes_grouped_pass`` admits, the GROUPED pass over
+        the selected pairs alone (``ops.grouped_experts``)."""
         shape = x.shape
         x = x.reshape(-1, shape[-1]).astype(params["Wr"].dtype)
         ids, w = self.route(params, x)
@@ -201,9 +216,7 @@ class SparseExpertsLayer(BaseLayer):
         rows = None
         with jax.named_scope("moe/experts"):
             # combine weight of every (token, held expert): 0 unless
-            # selected. Every token goes through every held expert
-            # and the weight picks: at decode widths the experts'
-            # weights, not the rows, bound the time (PERF.md)
+            # selected
             hit = (ids - first)[:, :, None] == jnp.arange(count)
             if active is not None:                       # (N,k,held)
                 rows = jnp.repeat(active.reshape(-1),
@@ -211,12 +224,21 @@ class SparseExpertsLayer(BaseLayer):
                 hit = hit & rows[:, None, None]
             comb = jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)
             counts = jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
-            g = einsum_f32("nd,edw->enw", x, params["Wg"])
-            u = einsum_f32("nd,edw->enw", x, params["Wu"])
-            y = einsum_f32("enw,ewd->end",
-                           (jax.nn.silu(g) * u).astype(x.dtype),
-                           params["Wd"])
-            out = jnp.einsum("end,ne->nd", y, comb)
+            if stream and self.takes_grouped_pass(x.shape[0], x.dtype):
+                out = grouped_experts.pallas_grouped_experts(
+                    x, jnp.any(hit, axis=1), comb, params["Wg"],
+                    params["Wu"], params["Wd"])
+            else:
+                # every token goes through every held expert and the
+                # weight picks: up to an MXU tile of rows the
+                # experts' weights, not the rows, bound the time
+                # (PERF.md)
+                g = einsum_f32("nd,edw->enw", x, params["Wg"])
+                u = einsum_f32("nd,edw->enw", x, params["Wu"])
+                y = einsum_f32("enw,ewd->end",
+                               (jax.nn.silu(g) * u).astype(x.dtype),
+                               params["Wd"])
+                out = jnp.einsum("end,ne->nd", y, comb)
         if self.n_shared_experts:
             with jax.named_scope("moe/shared"):
                 out = out + swiglu(x, params["Wsg"], params["Wsu"],
@@ -253,18 +275,32 @@ def _residual(h, f, multiplier=1.0):
     return (h.astype(_F32) + multiplier * f.astype(_F32)).astype(h.dtype)
 
 
-def _ffn_half(params, h, moe, eps, active=None, multiplier=1.0):
+def _ffn_half(params, h, moe, eps, active=None, multiplier=1.0,
+              stream=False):
     """The second half of a pre-RMSNorm decoder block,
     ``(h + multiplier * F(norm(h)), counts or None)``: ``F`` is the
     expert layer ``moe`` (parameters ``params["moe"]``) or, where that
-    is None, the dense SiLU-gated MLP ``Wg, Wu, Wd``."""
+    is None, the dense SiLU-gated MLP ``Wg, Wu, Wd``. ``stream``: a
+    serving step's call (``SparseExpertsLayer.apply_tallied``)."""
     z = rms_norm(h, params["norm2_gain"], eps)
     if moe is None:
         with jax.named_scope("mlp"):
             return _residual(h, swiglu(z, params["Wg"], params["Wu"],
                                        params["Wd"]), multiplier), None
-    f, counts = moe.apply_counted(params["moe"], z, active)
+    f, counts = moe.apply_counted(params["moe"], z, active, stream)
     return _residual(h, f, multiplier), counts
+
+
+class _ExpertsPart:
+    """What a block whose ``_ensure_parts()[1]`` is the expert layer,
+    or None where it carries the dense MLP, says of that layer."""
+
+    def experts_grouped(self, rows: int, dtype) -> bool:
+        """Does a serving step of ``rows`` rows run this block's
+        experts as the grouped pass? The paged session asks, for
+        ``serving_moe_grouped_steps_total``."""
+        moe = self._ensure_parts()[1]
+        return moe is not None and moe.takes_grouped_pass(rows, dtype)
 
 
 def _init_decoder_block(block, key, attn, moe, mixer="attn"):
@@ -305,7 +341,7 @@ def _biased_sigmoid_experts(block, held, common):
 
 @register_layer
 @dataclasses.dataclass
-class LatentDecoderBlock(BaseLayer):
+class LatentDecoderBlock(_ExpertsPart, BaseLayer):
     """Pre-RMSNorm decoder block: latent attention, then a dense
     SiLU-gated MLP (``n_routed_experts == 0``) or the expert layer.
     The fields are the two sub-layers' own, flat, so that the block
@@ -373,9 +409,9 @@ class LatentDecoderBlock(BaseLayer):
         self.set_n_in(input_type)
         return _init_decoder_block(self, key, *self._ensure_parts())
 
-    def _ffn_half(self, params, h, active=None):
+    def _ffn_half(self, params, h, active=None, stream=False):
         return _ffn_half(params, h, self._ensure_parts()[1], self.eps,
-                         active)
+                         active, stream=stream)
 
     def apply(self, params, state, x, *, training=False, rng=None,
               mask=None):
@@ -410,7 +446,7 @@ class LatentDecoderBlock(BaseLayer):
             a, pool = attn.apply_stream_paged(
                 params["attn"], pool, table, pos,
                 rms_norm(x, params["norm1_gain"], self.eps), n_valid)
-        h, counts = self._ffn_half(params, x + a, active)
+        h, counts = self._ffn_half(params, x + a, active, stream=True)
         return h, pool, counts
 
     def apply_stream_paged(self, params, pool, table, pos, x,
@@ -422,7 +458,7 @@ class LatentDecoderBlock(BaseLayer):
 
 @register_layer
 @dataclasses.dataclass
-class ShortcutExpertBlock(BaseLayer):
+class ShortcutExpertBlock(_ExpertsPart, BaseLayer):
     """LongCat-Flash's shortcut-connected expert layer::
 
         h0 = x  + MLA_0(norm(x));   z0 = norm(h0)
@@ -510,9 +546,10 @@ class ShortcutExpertBlock(BaseLayer):
                       f"mlp{i}": mlp(ks[2 + 3 * i:5 + 3 * i])})
         return p, {}
 
-    def _forward(self, params, x, attend, active=None):
+    def _forward(self, params, x, attend, active=None, stream=False):
         """The layer's equations; ``attend(i, z)`` is sub-layer
-        ``i``'s attention over the normed ``z``."""
+        ``i``'s attention over the normed ``z``; ``stream``: a serving
+        step's call."""
         _, moe = self._ensure_parts()
         norm = lambda h, name: rms_norm(h, params[name], self.eps)
         mlp = lambda i, z: swiglu(z, params[f"mlp{i}"]["Wg"],
@@ -522,7 +559,7 @@ class ShortcutExpertBlock(BaseLayer):
         with jax.named_scope("mla0"):
             h = x + attend(0, norm(x, "norm_a0_gain"))
         z = norm(h, "norm_f0_gain")
-        m, tally = moe.apply_tallied(params["moe"], z, active)
+        m, tally = moe.apply_tallied(params["moe"], z, active, stream)
         with jax.named_scope("mlp0"):
             h = h + mlp(0, z)
         with jax.named_scope("mla1"):
@@ -567,7 +604,7 @@ class ShortcutExpertBlock(BaseLayer):
                 n_valid)
             return a
 
-        h, tally = self._forward(params, x, attend, active)
+        h, tally = self._forward(params, x, attend, active, stream=True)
         return h, new_pool, tally
 
     def apply_stream_paged(self, params, pool, table, pos, x,
@@ -579,7 +616,7 @@ class ShortcutExpertBlock(BaseLayer):
 
 @register_layer
 @dataclasses.dataclass
-class GroupedQueryDecoderBlock(BaseLayer):
+class GroupedQueryDecoderBlock(_ExpertsPart, BaseLayer):
     """Pre-RMSNorm decoder block ``h = x + GQA(norm(x)); y = h +
     F(norm(h))``: grouped-query attention
     (``GroupedQueryAttentionLayer``: global, or with ``window`` a
@@ -648,9 +685,9 @@ class GroupedQueryDecoderBlock(BaseLayer):
         self.set_n_in(input_type)
         return _init_decoder_block(self, key, *self._ensure_parts())
 
-    def _block(self, params, x, attend, active=None):
+    def _block(self, params, x, attend, active=None, stream=False):
         """The block's equations; ``attend(z)`` is the attention over
-        the normed ``z``."""
+        the normed ``z``; ``stream``: a serving step's call."""
         x = x.astype(params["norm1_gain"].dtype)
         with jax.named_scope("attn/global" if self.window is None
                              else "attn/window"):
@@ -658,7 +695,7 @@ class GroupedQueryDecoderBlock(BaseLayer):
         return _ffn_half(
             params, _residual(x, a, self.residual_multiplier),
             self._ensure_parts()[1], self.eps, active,
-            self.residual_multiplier)
+            self.residual_multiplier, stream)
 
     def apply(self, params, state, x, *, training=False, rng=None,
               mask=None):
@@ -693,7 +730,7 @@ class GroupedQueryDecoderBlock(BaseLayer):
             new_pool.append(p)
             return a
 
-        h, counts = self._block(params, x, attend, active)
+        h, counts = self._block(params, x, attend, active, stream=True)
         return h, new_pool[0], counts
 
     def apply_stream_paged(self, params, pool, table, pos, x,
@@ -703,7 +740,7 @@ class GroupedQueryDecoderBlock(BaseLayer):
         return h, pool
 
 
-class _SlotStateBlock(BaseLayer):
+class _SlotStateBlock(_ExpertsPart, BaseLayer):
     """Pre-RMSNorm decoder block ``h = x + m Mixer(norm(x)); y = h + m
     F(norm(h))`` over a sequence mixer whose paged cache is a row a
     SLOT (``zero_state_pool``), not pages: what it carries from token
@@ -735,15 +772,16 @@ class _SlotStateBlock(BaseLayer):
         return _init_decoder_block(self, key, *self._ensure_parts(),
                                    mixer=self.mixer)
 
-    def _block(self, params, x, mix, active=None):
+    def _block(self, params, x, mix, active=None, stream=False):
         """The block's equations; ``mix(z)`` is the mixer over the
-        normed ``z``."""
+        normed ``z``; ``stream``: a serving step's call."""
         x = x.astype(params["norm1_gain"].dtype)
         with jax.named_scope(self.mixer):
             a = mix(rms_norm(x, params["norm1_gain"], self.eps))
         m = self.residual_multiplier
         return _ffn_half(params, _residual(x, a, m),
-                         self._ensure_parts()[1], self.eps, active, m)
+                         self._ensure_parts()[1], self.eps, active, m,
+                         stream)
 
     def apply(self, params, state, x, *, training=False, rng=None,
               mask=None):
@@ -773,7 +811,7 @@ class _SlotStateBlock(BaseLayer):
             new_pool.append(p)
             return a
 
-        h, counts = self._block(params, x, mix, active)
+        h, counts = self._block(params, x, mix, active, stream=True)
         return h, new_pool[0], counts
 
     def apply_stream_paged(self, params, pool, table, pos, x,

@@ -374,6 +374,14 @@ class ContinuousBatcher(ServingBackend):
                     slots, widest, self.session.page_size)
         if self._wide_t:
             self._steps.holds_wide_program()
+        # the widths whose program runs its expert layers as the
+        # grouped pass: the session says, of the shapes each has
+        grouped = getattr(self.session, "runs_grouped_experts", None)
+        self._grouped_t = {
+            t for t in (1, self._chunk_t, self._wide_t)
+            if t and grouped is not None and grouped(t)}
+        if self._grouped_t:
+            self._steps.holds_grouped_program()
         self._warmed = False
         # the step whose ids are still on the device: enqueued and
         # scheduled past, not yet delivered (``_loop``). At most one.
@@ -1141,7 +1149,8 @@ class ContinuousBatcher(ServingBackend):
                     st.n_prompt, len(st.emitters),
                     "chunk" if chunk else "single", st.prompt_tokens,
                     ahead=prev is not None, enqueue_s=t_enq - t1,
-                    wide=st.x.shape[1] == self._wide_t)
+                    wide=st.x.shape[1] == self._wide_t,
+                    grouped=st.x.shape[1] in self._grouped_t)
                 if self._paged:
                     self._steps.record_kv_positions(
                         *self.session.step_kv_positions)

@@ -252,7 +252,11 @@ class BatcherStepMetrics:
     device. A pool that holds a second, wider chunk program counts its
     steps under ``chunk`` too, and again in
     ``serving_wide_steps_total``, a series only such a pool has
-    (``holds_wide_program``).
+    (``holds_wide_program``). ``serving_moe_grouped_steps_total``
+    counts the steps whose program runs its expert layers' held
+    experts as the grouped pass over the selected pairs, a series
+    only a session that holds such a program has
+    (``holds_grouped_program``).
     ``serving_kv_positions_read_total`` adds the KV positions a
     step's attention layers read (by table: each slot's pages up to
     its length; by gather: every slot's whole capacity) as the
@@ -284,6 +288,7 @@ class BatcherStepMetrics:
         self._reg, self._name, self._experts = reg, name, None
         self._kv = self._pairs = self._ring = self._state = None
         self._wide = None       # ``holds_wide_program``
+        self._grouped = None    # ``holds_grouped_program``
         self._parts = {
             part: reg.histogram(
                 "serving_step_seconds",
@@ -323,7 +328,7 @@ class BatcherStepMetrics:
                prompt_slots: int, decode_slots: int,
                program: str = "single", prompt_tokens: int = 0,
                ahead: bool = False, enqueue_s: float = 0.0,
-               wide: bool = False) -> None:
+               wide: bool = False, grouped: bool = False) -> None:
         self._parts["admit"].record(admit_s)
         self._parts["device"].record(device_s)
         self._parts["sample"].record(sample_s)
@@ -336,6 +341,8 @@ class BatcherStepMetrics:
             self._ahead.inc()
         if wide:
             self._wide.inc()
+        if grouped:
+            self._grouped.inc()
 
     def holds_wide_program(self) -> None:
         """The batcher's pool holds a second, wider chunk program:
@@ -343,6 +350,18 @@ class BatcherStepMetrics:
         self._wide = self._reg.counter(
             "serving_wide_steps_total",
             help="device steps that ran the wide chunk program",
+            labels={"endpoint": self._name})
+
+    def holds_grouped_program(self) -> None:
+        """Some step program of the batcher's session runs its expert
+        layers as the grouped pass
+        (``PagedSlotSession.runs_grouped_experts``):
+        ``serving_moe_grouped_steps_total`` counts the steps that ran
+        such a program."""
+        self._grouped = self._reg.counter(
+            "serving_moe_grouped_steps_total",
+            help="device steps whose program runs the held experts "
+                 "over the selected pairs alone",
             labels={"endpoint": self._name})
 
     def record_kv_positions(self, read: int, spanned: int) -> None:

@@ -166,10 +166,45 @@ def test_program_fed_layer_metrics_come_back(cell, tiny, capsys):
     # the tiny pools are 4 slots of a page of 4: none holds a wide
     # chunk program, and where there is none its share has no reading
     assert got.pop("wide_steps_pct.serve", None) is None
+    # off a TPU the experts' dense pass runs at every width
+    assert got.pop("moe_grouped_steps_pct.serve", None) is None
     missing = sorted(n for n, v in got.items() if v is None)
     assert not missing, (missing, capsys.readouterr().out[-4000:])
     # the CPU's paged step gathers: it reads what its tables span
     assert got.get("kv_read_pct.serve", 100.0) == 100.0
+
+
+GROUPED = next(m for m in BENCH["per_layer"]
+               if m["name"] == "moe_grouped_steps_pct.serve")
+
+
+@pytest.mark.parametrize("cell", GROUPED["workloads"])
+def test_grouped_steps_are_read_where_a_program_groups(
+        cell, tiny, capsys, monkeypatch):
+    """The four expert cells with the layer's predicate moved down to
+    the tiny pools (the test's steering: every chunk step groups, the
+    single-row program keeps the dense pass) and the kernel
+    interpreted: the batcher's counter reaches the reader through the
+    driver, and the share it reads is the chunk steps'."""
+    import functools
+
+    from deeplearning4j_tpu.ops import grouped_experts
+    c = spec.load(cell)
+    slots = c.traffic["server"]["slots"]
+    monkeypatch.setattr(grouped_experts, "grouped_pass",
+                        lambda n, *_: n > slots)
+    monkeypatch.setattr(
+        grouped_experts, "pallas_grouped_experts", functools.partial(
+            grouped_experts.pallas_grouped_experts, interpret=True))
+    s = session.Session(c, SEED, 2.0, 1, time.perf_counter(),
+                        find=_cpu_devices)
+    driver = spec.load_module("drivers", c.traffic["driver"])
+    with pytest.raises(RuntimeError, match="holds no device operation"):
+        driver.run(s)
+    read = lambda name: spec.load_module("layer_metrics", name).read(s.obs)
+    got = read("moe_grouped_steps_pct.serve")
+    assert got is not None and got > 0, capsys.readouterr().out[-4000:]
+    assert got == pytest.approx(read("chunk_steps_pct.serve"))
 
 
 SERVE = [w["name"] for w in BENCH["workloads"]

@@ -296,7 +296,9 @@ def test_latent_decoder_block_step_compiles_for_v5e(topo, as_tpu, t):
     assert layer.paged_reads_by_table(16, t, jnp.bfloat16)
     assert not layer.paged_reads_by_table(8, t, jnp.bfloat16)   # no tile
     compiled = _block_step(topo, layer, 7168, 64, t)
-    assert _kernels_in(compiled) == 1
+    # at the wide width's 256 rows the experts run grouped
+    assert layer.experts_grouped(64 * t, jnp.bfloat16) == (t == 4)
+    assert _kernels_in(compiled) == 1 + (t == 4)
     mem = compiled.memory_analysis()
     # a layer's weights are 1.35 GB and its pool 84 MB; the step's
     # temporaries are 37 / 41 MB where the gathered copies of 64 x
@@ -351,7 +353,11 @@ def test_shortcut_expert_block_step_compiles_for_v5e(topo, as_tpu, t):
         top_k=12, expert_width=2048, routed_scaling_factor=6.0)
     assert layer.paged_reads_by_table(16, t, jnp.bfloat16)
     compiled = _block_step(topo, layer, 6144, 32, t)
-    assert _kernels_in(compiled) == 2
+    # the experts run grouped at every width: past an MXU tile of rows
+    # at the wide one, and under it because 12 picks over a router of
+    # 768 leave a tenth and more of the held experts unpicked
+    assert layer.experts_grouped(32 * t, jnp.bfloat16)
+    assert _kernels_in(compiled) == 3
     out, new_pool, tally = compiled.output_shardings
     assert set(new_pool) == {"a0", "a1"}
     assert set(tally) == {"held", "zero", "selected"}
@@ -553,7 +559,10 @@ def test_short_conv_expert_cell_step_fits_v5e(topo, as_tpu, t):
         sds((slots,), jnp.int32), sds((slots, t, 1), jnp.float32),
         sds((slots,), jnp.int32), sds((slots,), jnp.int32),
         sds((slots,), bool)).compile()
-    assert _kernels_in(compiled) == 2
+    # two attention kernels, and at the wide width's 256 rows the
+    # grouped kernel of each of the eight expert layers
+    assert sess.runs_grouped_experts(t) == (t == 4)
+    assert _kernels_in(compiled) == 2 + 8 * (t == 4)
     mem = compiled.memory_analysis()
     # 10.62 GB of weights and 0.81 GB of pages; the pools are donated;
     # the dense pass of 128 rows through 64 experts stays under 1 GB
@@ -562,6 +571,48 @@ def test_short_conv_expert_cell_step_fits_v5e(topo, as_tpu, t):
     assert mem.temp_size_in_bytes < 1e9
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 13e9
+
+
+@pytest.mark.parametrize("d, w, routed, held, top_k", [
+    (2048, 1536, 64, None, 4), (4096, 2048, 256, (0, 16), 8)],
+    ids=["lfm2_24b_a2b", "mimo_v25_ep16"])
+def test_grouped_expert_pass_compiles_for_v5e(topo, as_tpu, d, w, routed,
+                                              held, top_k):
+    """The expert layer of the wide step (64 slots x 4 = 256 rows) at
+    ``lfm2_24b_a2b``'s and ``mimo_v25_ep16``'s own widths in bfloat16,
+    as a serving step calls it: the predicate admits it, the grouped
+    kernel is in the compiled text under its own name, and nothing
+    under ``moe/experts`` gathers, scatters or sorts rows (the
+    ordering is whole-array arithmetic and one 0/1 matmul). At the
+    narrow step's 128 rows the same call compiles to the dense pass."""
+    from deeplearning4j_tpu import dtypes
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.conf.layers import SparseExpertsLayer
+    bf16 = jnp.bfloat16
+    layer = SparseExpertsLayer(
+        n_in=d, n_routed_experts=routed, held=held, top_k=top_k,
+        expert_width=w, n_shared_experts=0, router_bias=True)
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    with dtypes.policy_scope(dtypes.Policy(
+            param_dtype=bf16, compute_dtype=bf16, output_dtype=bf16)):
+        params = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype),
+            jax.eval_shape(lambda: layer.initialize(
+                jax.random.PRNGKey(0), InputType.recurrent(d))[0]))
+    step = jax.jit(lambda p, x, a: layer.apply_tallied(p, x, a, True))
+    texts = {}
+    for t in (4, 2):
+        assert layer.takes_grouped_pass(64 * t, bf16) == (t == 4)
+        texts[t] = step.lower(params, sds((64, t, d), bf16),
+                              sds((64, t), bool)).compile().as_text()
+    assert texts[4].count("tpu_custom_call") == 1
+    assert "pallas_grouped_experts" in texts[4]
+    assert "tpu_custom_call" not in texts[2]
+    moved = re.findall(r" (gather|scatter|sort|dynamic-slice|"
+                       r'dynamic-update-slice)\(.*op_name="[^"]*'
+                       r'moe/experts', texts[4])
+    assert not moved, moved
 
 
 # ---- four chips: the kernels on a mesh -----------------------------------

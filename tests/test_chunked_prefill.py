@@ -549,10 +549,106 @@ def test_the_wide_steps_reader_over_two_snapshots():
     assert read(first, m.registry.snapshot()) == pytest.approx(50.0)
     with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["per_layer"][-1] == {
+    assert next(m for m in bench["per_layer"]
+                if m["name"] == "wide_steps_pct.serve") == {
         "name": "wide_steps_pct.serve", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "Serving",
         "moves": "serve_tokens_per_s",
+        "workloads": ["axk1_serve_decode", "longcat_serve_tooluse",
+                      "mimo_serve_mixedlen", "lfm2_serve_agent"]}
+
+
+@pytest.fixture
+def grouped_when_wide(monkeypatch):
+    """The expert layers take the grouped pass, interpreted, in the
+    steps that carry more than the narrow program's rows: the
+    predicate's turn, moved down to the tests' 16 slots."""
+    from deeplearning4j_tpu.ops import grouped_experts
+    monkeypatch.setattr(grouped_experts, "grouped_pass",
+                        lambda n, *_: n > WIDE_SLOTS * T_LO)
+    monkeypatch.setattr(
+        grouped_experts, "pallas_grouped_experts", functools.partial(
+            grouped_experts.pallas_grouped_experts, interpret=True))
+
+
+def _expert_net():
+    return _block_net(ShortConvDecoderBlock(
+        n_routed_experts=8, top_k=2, expert_width=32))
+
+
+def test_grouped_steps_are_counted_where_a_program_runs_them(
+        two_widths, grouped_when_wide):
+    """``serving_moe_grouped_steps_total`` exists where some program
+    of the session runs its expert layers grouped (here the wide one
+    alone) and counts the steps that ran it; what is served is what
+    the dense pass serves, token by token."""
+    net = _expert_net()
+    prompts = [_prompt(19, 70 + k) for k in range(10)]
+    b = _gated(net, "experts")
+    try:
+        sess = b.cb.session
+        assert [sess.runs_grouped_experts(t) for t in (1, T_LO, T_HI)] \
+            == [False, False, True]
+        assert b.cb._grouped_t == {T_HI}
+        got = b.run([(p, 4, {}) for p in prompts])
+        wide = b.count("serving_wide_steps_total")
+        assert 0 < wide < b.steps()
+        assert b.count("serving_moe_grouped_steps_total") == wide
+    finally:
+        assert b.close()
+    for p, ids in zip(prompts[:3], got):
+        _same_ids(ids, *_token_by_token(net, p, 4))
+
+
+@pytest.mark.parametrize("net_of", ["lm", "experts"])
+def test_no_grouped_counter_where_no_program_is_grouped(net, two_widths,
+                                                        net_of):
+    """Off a TPU the predicate is False at every width, and a network
+    without expert layers has nothing to group: no series."""
+    b = _gated(net if net_of == "lm" else _expert_net(), net_of)
+    try:
+        assert b.cb._grouped_t == set()
+        assert not any("serving_moe_grouped_steps_total" in k
+                       for k in b.metrics.registry.snapshot())
+    finally:
+        assert b.close()
+
+
+def test_the_grouped_steps_reader_over_two_snapshots():
+    """benchmark/layer_metrics/moe_grouped_steps_pct.serve.py: the
+    grouped steps' share of the window's steps where the batcher has
+    the counter (0 where such a program is held and never runs), and
+    nothing where it has not (the dense pass at every width, the
+    parent of PR 43) or the window held no step; the key a real
+    ``BatcherStepMetrics`` writes is the key the reader matches."""
+    from benchmark.harness import spec
+    reader = spec.load_module("layer_metrics",
+                              "moe_grouped_steps_pct.serve")
+    read = lambda before, after: reader.read(
+        {"counters": {"before": before, "after": after}})
+    m = ServingMetrics()
+    recorded = m.batcher_steps("generate/lm/v1")
+    bare = m.registry.snapshot()
+    recorded.record(0.001, 0.002, 0.001, 2, 0, "chunk")
+    assert read(bare, m.registry.snapshot()) is None
+    recorded.holds_grouped_program()
+    first = m.registry.snapshot()
+    assert read(first, first) is None
+    recorded.record(0.001, 0.002, 0.001, 1, 1, "chunk")
+    held_not_run = m.registry.snapshot()
+    assert read(first, held_not_run) == 0.0
+    recorded.record(0.001, 0.002, 0.001, 2, 0, "chunk", wide=False,
+                    grouped=True)
+    recorded.record(0.001, 0.002, 0.001, 0, 2, "single")
+    recorded.record(0.001, 0.002, 0.001, 2, 0, "chunk", grouped=True)
+    assert read(held_not_run, m.registry.snapshot()) == pytest.approx(
+        200.0 / 3)
+    with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert bench["per_layer"][-1] == {
+        "name": "moe_grouped_steps_pct.serve", "unit": "%",
+        "better": "higher", "source": "program_counter",
+        "layer": "Layers", "moves": "serve_tokens_per_s",
         "workloads": ["axk1_serve_decode", "longcat_serve_tooluse",
                       "mimo_serve_mixedlen", "lfm2_serve_agent"]}
 

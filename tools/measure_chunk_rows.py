@@ -35,7 +35,8 @@ COUNTERS = ("chunk_steps_pct.serve", "wide_steps_pct.serve",
             "step_device_ms.serve", "step_host_ms.serve",
             "prefill_ms.serve", "queue_wait_ms.serve",
             "batch_occupancy_pct.serve",
-            "moe_local_pairs_per_step.serve")
+            "moe_local_pairs_per_step.serve",
+            "moe_grouped_steps_pct.serve")
 
 
 def main(workload, seconds, seed, budgets):
