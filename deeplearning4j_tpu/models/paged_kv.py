@@ -45,21 +45,11 @@ vLLM-style paged memory model over the same layer math:
   for it as its first row, so a decode loop need not wait for one
   step's ids before it enqueues the next.
 
-Three kinds of cache live in one session. A layer whose attention sees
-every earlier position keeps it in the allocator's pages, named by
-the slot's table, as above. A layer with a sliding window
-(``ring_pages(page_size) > 0``) keeps a RING of that many pages a
-slot in a pool of its own: slot ``s`` owns pages ``1 + s * R ..
-(s + 1) * R`` for as long as it exists, position ``p`` lives at ring
-row ``p mod (R * page_size)``, and the layer tells by position which
-rows a query may see, so nothing is allocated, exhausted, shared or
-zeroed for this kind. A layer whose state has a FIXED size and no
-positions (a state-space recurrence: the layer has
-``zero_state_pool``) keeps one row a slot in a pool of ``slots`` rows,
-no pages at all: a slot that feeds position 0 starts from zeros
-whatever its row holds, so this kind is not zeroed either. Neither a
-ring nor a state row can be shared, so a network that has one takes
-no prefix hit and registers no prefix.
+Three kinds of cache live in one session: the allocator's pages, as
+above, a ring of pages a slot owns, and one fixed-size row a slot.
+Each layer DECLARES its kind (``nn.conf.layers.paged.PagedLayer``,
+which says what a layer gives to be served and what each kind means);
+the session reads the declaration once, when it is built.
 
 Page id 0 is a reserved scratch page: a slot that sits a step out is
 given an all-zero page-table row, and the chunk step sends every row
@@ -79,6 +69,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from deeplearning4j_tpu.nn.conf.layers.paged import (PAGES, RING, STATE,
+                                                     PagedLayer)
 from deeplearning4j_tpu.serving.errors import (KVLeaseCorruptError,
                                                KVLeaseVersionError,
                                                KVPagePoolExhaustedError)
@@ -435,14 +427,11 @@ class PagedSlotSession:
 
     ``capacity`` still bounds ONE request's prompt+generation length
     (it is the page-table width in tokens); memory is bounded by
-    ``n_pages * page_size`` total. Supported layers: paged attention
-    (``apply_stream_paged``) of either kind, in the allocator's pages
-    or, where the layer gives ``ring_pages(page_size) > 0``, in a
-    ring of that many pages a slot outside the allocator, layers
-    with a fixed-size state in a row a slot (``zero_state_pool``;
-    module docstring), and stateless layers — LSTM-style carries
-    (``zero_state``) and running statistics have no paged analog;
-    build the dense session for those models.
+    ``n_pages * page_size`` total. Supported layers: those that
+    declare a cache (``PagedLayer``; module docstring) and stateless
+    layers — LSTM-style carries (``zero_state``) and running
+    statistics have no paged analog; build the dense session for
+    those models.
     """
 
     @staticmethod
@@ -486,18 +475,20 @@ class PagedSlotSession:
         self._table = np.zeros((self.slots, self.pages_per_slot),
                                np.int32)
         self._leases: Dict[int, _Lease] = {}
+        # what each layer declares that it keeps between tokens; None
+        # for a layer without a paged step. Everything below branches
+        # on this reading
+        self._caches = caches = [
+            layer.paged_cache(self.page_size)
+            if isinstance(layer, PagedLayer) else None
+            for layer in net.layers]
         # pages of the ring a slot owns in each layer's pool; 0 for a
         # layer in the allocator's pages and for one without a cache
-        self._ring = [
-            int(layer.ring_pages(self.page_size))
-            if hasattr(layer, "apply_stream_paged")
-            and hasattr(layer, "ring_pages") else 0
-            for layer in net.layers]
+        self._ring = [c.ring_pages if c and c.kind == RING else 0
+                      for c in caches]
         self._ring_sizes = sorted({r for r in self._ring if r})
         # layers that keep a fixed-size state in a row a slot
-        self._state = [hasattr(layer, "apply_stream_paged")
-                       and hasattr(layer, "zero_state_pool")
-                       for layer in net.layers]
+        self._state = [c is not None and c.kind == STATE for c in caches]
         # a ring or a state row belongs to its slot: no prefix is
         # shared over a network that has either
         self._slot_owned = bool(self._ring_sizes) or any(self._state)
@@ -509,12 +500,11 @@ class PagedSlotSession:
         # row of the same step reads
         self.chunk_rows_max = (self.page_size if self._ring_sizes
                                else self.capacity)
-        # some layer's step is unrolled over a chunk's rows
-        # (``chunk_rows_unrolled``): every width of such a step is a
-        # long compile, so the batcher holds one chunk program
+        # some layer's step is unrolled over a chunk's rows: every
+        # width of such a step is a long compile, so the batcher holds
+        # one chunk program
         self.unrolls_chunk_rows = any(
-            getattr(layer, "chunk_rows_unrolled", False)
-            for layer in net.layers)
+            c.unrolls_chunk_rows for c in caches if c)
         self._pools = self._fresh_pools()
         # one jitted step: ``step_slots`` runs it at (slots, 1, C),
         # ``step_chunk`` at (slots, t, C), each shape its own program
@@ -527,9 +517,8 @@ class PagedSlotSession:
         # (``_register_program``)
         self._registered = set()
         self._prev_ids = jnp.zeros((self.slots,), jnp.int32)
-        paged = [i for i, layer in enumerate(net.layers)
-                 if hasattr(layer, "apply_stream_paged")]
-        self._last_paged = paged[-1] if paged else -1
+        cached = [i for i, c in enumerate(caches) if c]
+        self._last_paged = cached[-1] if cached else -1
         # May ``step_chunk`` stand in for token-by-token steps? Only
         # where every layer without a cache is pointwise in time
         # (``seq_parallelizable``) and no preprocessor reshapes a
@@ -538,17 +527,16 @@ class PagedSlotSession:
         # a layer above it is given a slot's last valid row alone,
         # which is the whole chunk's output at that row only if rows
         # do not mix.
-        self.chunkable = bool(paged) and not any(
+        self.chunkable = bool(cached) and not any(
             i <= self._last_paged for i in net.conf.preprocessors
-        ) and all(hasattr(layer, "apply_stream_paged")
-                  or getattr(layer, "seq_parallelizable", False)
-                  for layer in net.layers)
+        ) and all(c or getattr(layer, "seq_parallelizable", False)
+                  for layer, c in zip(net.layers, caches))
         self._copy_page = None
         # layers whose decode step returns counts beside its output
         # (an expert layer's tokens per expert); none: the step and
         # its program are what they were without this
-        self._aux_layers = [i for i, layer in enumerate(net.layers)
-                            if getattr(layer, "stream_aux", False)]
+        self._aux_layers = [i for i in cached
+                            if net.layers[i].stream_aux]
         # the latest step's counts, (len(_aux_layers), ...) on the
         # device, unfetched (a dict of such arrays where the layers
         # return a dict of counts); None for a network that has none
@@ -572,21 +560,16 @@ class PagedSlotSession:
 
     # ---- pools ----
     def _fresh_pools(self):
-        pools = []
-        for layer, ring, kept in zip(self.net.layers, self._ring,
-                                     self._state):
-            if kept:
-                pools.append(layer.zero_state_pool(self.slots,
-                                                   self._dtype))
-            elif hasattr(layer, "apply_stream_paged"):
-                # +1 physical row: page id 0 is the scratch page
-                pools.append(layer.zero_page_pool(
-                    (self.slots * ring if ring
-                     else self.allocator.n_pages) + 1,
-                    self.page_size, self._dtype))
-            else:
-                pools.append(None)
-        return pools
+        def rows(c):
+            if c.kind == STATE:
+                return self.slots
+            # pages and one more: page id 0 is the scratch page
+            return (self.slots * c.ring_pages if c.kind == RING
+                    else self.allocator.n_pages) + 1
+
+        return [None if c is None else layer.zero_pool(
+                    rows(c), self.page_size, self._dtype)
+                for layer, c in zip(self.net.layers, self._caches)]
 
     def pages_total(self) -> int:
         return self.allocator.n_pages
@@ -960,12 +943,11 @@ class PagedSlotSession:
             self._copy_page = jax.jit(copy, donate_argnums=(0,))
         import jax.numpy as jnp
         d, s = jnp.int32(dst), jnp.int32(src)
-        for i, pool in enumerate(self._pools):
+        for i, c in enumerate(self._caches):
             # page ids are the allocator's: a ring and a state pool
             # have none of them
-            if pool is not None and not self._ring[i] \
-                    and not self._state[i]:
-                self._pools[i] = self._copy_page(pool, d, s)
+            if c is not None and c.kind == PAGES:
+                self._pools[i] = self._copy_page(self._pools[i], d, s)
 
     def _make_step(self):
         import jax
@@ -975,7 +957,7 @@ class PagedSlotSession:
         preprocessors = dict(net.conf.preprocessors)
 
         aux_layers = set(self._aux_layers)
-
+        cached = [c is not None for c in self._caches]
         last_paged = self._last_paged
 
         def step(params, layer_states, pools, table, pos, x,
@@ -1006,7 +988,7 @@ class PagedSlotSession:
                                 params[i], pools[i], table, pos, h,
                                 active, **kw)
                         aux.append(counts)
-                    elif hasattr(layer, "apply_stream_paged"):
+                    elif cached[i]:
                         h, new_pools[i] = layer.apply_stream_paged(
                             params[i], pools[i], table, pos, h, **kw)
                     else:
@@ -1058,9 +1040,9 @@ class PagedSlotSession:
         rows such a step carries)? The batcher asks, for
         ``serving_moe_grouped_steps_total``."""
         return any(
-            hasattr(layer, "experts_grouped")
-            and layer.experts_grouped(self.slots * t, self._dtype)
-            for layer in self.net.layers)
+            self.net.layers[i].experts_grouped(self.slots * t,
+                                               self._dtype)
+            for i in self._aux_layers)
 
     def _register_program(self, kind: str, t: int, jitted, args) -> None:
         """Tell ``observability.programs`` of a step program about to
@@ -1080,23 +1062,19 @@ class PagedSlotSession:
         implies, host arithmetic and no measurement, of the layers
         whose pages the allocator hands out (a ring layer reads its
         own ring whatever the table spans: ``_note_ring`` counts
-        those; a state layer reads no position at all): layers that read by table (``paged_reads_by_table``:
-        all of them must, a layer that does not say is taken to
-        gather) fetch of each slot the pages up to the one its length
-        ends in (``ops.paged_attention.pages_read``, the kernel's own
-        rule); layers that gather read every slot's whole table. What
-        the device moved is in its trace."""
+        those; a state layer reads no position at all): layers that
+        read by table (``paged_reads_by_table``: all of them must)
+        fetch of each slot the pages up to the one its length ends in
+        (``ops.paged_attention.pages_read``, the kernel's own rule);
+        layers that gather read every slot's whole table. What the
+        device moved is in its trace."""
         spanned = self.slots * self.pages_per_slot * self.page_size
         if t not in self._by_table:
-            paged = [layer for layer, ring, kept in zip(
-                         self.net.layers, self._ring, self._state)
-                     if hasattr(layer, "apply_stream_paged")
-                     and not ring and not kept]
             self._by_table[t] = all(
-                hasattr(layer, "paged_reads_by_table")
-                and layer.paged_reads_by_table(self.page_size, t,
-                                               self._dtype)
-                for layer in paged)
+                layer.paged_reads_by_table(self.page_size, t,
+                                           self._dtype)
+                for layer, c in zip(self.net.layers, self._caches)
+                if c is not None and c.kind == PAGES)
         read = spanned
         if self._by_table[t]:
             from deeplearning4j_tpu.ops.paged_attention import pages_read

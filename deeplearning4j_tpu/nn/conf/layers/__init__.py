@@ -53,10 +53,10 @@ from deeplearning4j_tpu.nn.conf.layers.state_space import (
 from deeplearning4j_tpu.nn.conf.layers.short_conv import (
     ShortConvMixerLayer,
 )
-from deeplearning4j_tpu.nn.conf.layers.moe import (
-    SparseExpertsLayer, LatentDecoderBlock, ShortcutExpertBlock,
-    GroupedQueryDecoderBlock, StateSpaceDecoderBlock,
-    ShortConvDecoderBlock,
+from deeplearning4j_tpu.nn.conf.layers.moe import SparseExpertsLayer
+from deeplearning4j_tpu.nn.conf.layers.decoder_blocks import (
+    LatentDecoderBlock, ShortcutExpertBlock, GroupedQueryDecoderBlock,
+    StateSpaceDecoderBlock, ShortConvDecoderBlock,
 )
 
 __all__ = [

@@ -27,6 +27,9 @@ from deeplearning4j_tpu.dtypes import einsum_f32
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers.base import (BaseLayer,
                                                     register_layer)
+from deeplearning4j_tpu.nn.conf.layers.paged import (PAGES, RING,
+                                                     MixerCacheLayer,
+                                                     PagedCache, PagedLayer)
 
 __all__ = ["SelfAttentionLayer", "TransformerEncoderLayer",
            "GroupedQueryAttentionLayer"]
@@ -65,7 +68,7 @@ def paged_write_targets(table, pos, t, page_size, n_valid=None):
 
 @register_layer
 @dataclasses.dataclass
-class SelfAttentionLayer(BaseLayer):
+class SelfAttentionLayer(PagedLayer, BaseLayer):
     """Multi-head self-attention, (B,T,C) → (B,T,n_out)."""
 
     n_in: Optional[int] = None
@@ -297,7 +300,10 @@ class SelfAttentionLayer(BaseLayer):
     #      memory is bounded by the pool, not by slots x max-capacity
     #      (models/paged_kv.py), and a step's KV traffic by the
     #      tokens the slots hold (ops/paged_attention.py) ----
-    def zero_page_pool(self, n_pages: int, page_size: int, dtype):
+    def paged_cache(self, page_size: int) -> PagedCache:
+        return PagedCache(PAGES)
+
+    def zero_pool(self, n_pages: int, page_size: int, dtype):
         """Physical page pool for this layer: {'k','v'} of
         (n_pages, page_size, H * Dh). A page is ``page_size`` rows of
         all heads side by side, as the projections leave them: one
@@ -390,7 +396,7 @@ def ring_write_targets(table, pos, t, page_size, ring_pages,
 
 @register_layer
 @dataclasses.dataclass
-class GroupedQueryAttentionLayer(BaseLayer):
+class GroupedQueryAttentionLayer(PagedLayer, BaseLayer):
     """Causal grouped-query attention, (B,T,C) -> (B,T,C): ``n_heads``
     query heads of ``qk_head_dim`` over ``n_kv_heads`` key heads of
     ``qk_head_dim`` and value heads of ``v_head_dim``; query head
@@ -553,15 +559,14 @@ class GroupedQueryAttentionLayer(BaseLayer):
         return self._attend(params, q, k, v, pos, pos), state
 
     # ---- paged cache ----
-    def ring_pages(self, page_size: int) -> int:
-        """Pages of the ring a slot owns in this layer's pool: the
-        window's and one more, so that a step of up to
+    def paged_cache(self, page_size: int) -> PagedCache:
+        """Without a window the allocator's pages; with one a ring of
+        the window's pages and one more, so that a step of up to
         ``ring_pages * page_size - window + 1`` rows a slot overwrites
-        no position one of its own rows still reads. 0 without a
-        window: the cache lives in the allocator's pages."""
+        no position one of its own rows still reads."""
         if self.window is None:
-            return 0
-        return -(-self.window // page_size) + 1
+            return PagedCache(PAGES)
+        return PagedCache(RING, -(-self.window // page_size) + 1)
 
     def _value_lanes(self, page_size: int, dtype) -> int:
         """The width a value head takes in the paged pool:
@@ -585,7 +590,7 @@ class GroupedQueryAttentionLayer(BaseLayer):
                     self.n_heads, self.n_kv_heads, self.qk_head_dim,
                     lanes, page_size, t, dtype))
 
-    def zero_page_pool(self, n_pages: int, page_size: int, dtype):
+    def zero_pool(self, n_pages: int, page_size: int, dtype):
         """{'k': (n_pages, page_size, K * dq), 'v': (.., K * dv)}:
         rotated keys and scaled values, heads side by side (a value
         head as wide as ``_value_lanes`` says)."""
@@ -616,7 +621,7 @@ class GroupedQueryAttentionLayer(BaseLayer):
         grouped kernel: the same mathematics as ``_attend``, which is
         its oracle), gathered whole elsewhere; with one they are the
         slot's own ring (:func:`ring_write_targets`), whose pool the
-        session sized by ``ring_pages``: a chunk wider than the ring
+        session sized by ``paged_cache``: a chunk wider than the ring
         has room for raises here, at trace time. Returns (out, pool)."""
         S, t, _ = x.shape
         ps = pool["k"].shape[1]
@@ -683,7 +688,7 @@ class GroupedQueryAttentionLayer(BaseLayer):
 
 @register_layer
 @dataclasses.dataclass
-class TransformerEncoderLayer(BaseLayer):
+class TransformerEncoderLayer(MixerCacheLayer, BaseLayer):
     """Pre-LN transformer block: x + MHA(LN(x)); x + MLP(LN(x))."""
 
     n_in: Optional[int] = None
@@ -735,6 +740,8 @@ class TransformerEncoderLayer(BaseLayer):
                 causal=self.causal, weight_init=self.weight_init)
         return self._attn
 
+    _mixer = _ensure_attn
+
     def apply(self, params, state, x, *, training=False, rng=None,
               mask=None):
         self._ensure_attn()
@@ -782,14 +789,6 @@ class TransformerEncoderLayer(BaseLayer):
                                                    cache, h, pos)
         x = x + a
         return x + self._mlp_half(params, x), cache
-
-    def zero_page_pool(self, n_pages: int, page_size: int, dtype):
-        return self._ensure_attn().zero_page_pool(n_pages, page_size,
-                                                  dtype)
-
-    def paged_reads_by_table(self, page_size: int, t: int, dtype) -> bool:
-        return self._ensure_attn().paged_reads_by_table(page_size, t,
-                                                        dtype)
 
     def apply_stream_paged(self, params, pool, table, pos, x,
                            n_valid=None):

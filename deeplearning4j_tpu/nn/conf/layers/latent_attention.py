@@ -40,6 +40,8 @@ from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers.base import (BaseLayer,
                                                     register_layer)
 from deeplearning4j_tpu.nn.conf.layers.normalization import rms_norm
+from deeplearning4j_tpu.nn.conf.layers.paged import (PAGES, PagedCache,
+                                                     PagedLayer)
 from deeplearning4j_tpu.nn.conf.layers.rotary import (rope, yarn_inv_freq,
                                                       yarn_mscale)
 
@@ -53,7 +55,7 @@ def _mm(a, b):
 
 @register_layer
 @dataclasses.dataclass
-class LatentAttentionLayer(BaseLayer):
+class LatentAttentionLayer(PagedLayer, BaseLayer):
     """Causal multi-head latent attention, (B,T,C) -> (B,T,C). No
     bias anywhere."""
 
@@ -231,7 +233,10 @@ class LatentAttentionLayer(BaseLayer):
                             q_rope, ckv, kr, pos)
 
     # ---- paged latent cache ----
-    def zero_page_pool(self, n_pages: int, page_size: int, dtype):
+    def paged_cache(self, page_size: int) -> PagedCache:
+        return PagedCache(PAGES)
+
+    def zero_pool(self, n_pages: int, page_size: int, dtype):
         """The physical pool of this layer: the normed latent and the
         rotated shared key of every cached token, by page. The key's
         row is whole lane tiles, zeros past ``qk_rope_head_dim``: a

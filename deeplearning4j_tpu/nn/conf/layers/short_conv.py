@@ -32,6 +32,8 @@ from deeplearning4j_tpu.dtypes import einsum_f32
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers.base import (BaseLayer,
                                                     register_layer)
+from deeplearning4j_tpu.nn.conf.layers.paged import (STATE, PagedCache,
+                                                     PagedLayer)
 from deeplearning4j_tpu.nn.conf.layers.state_space import carried_window
 
 __all__ = ["ShortConvMixerLayer"]
@@ -41,7 +43,7 @@ _F32 = jnp.float32
 
 @register_layer
 @dataclasses.dataclass
-class ShortConvMixerLayer(BaseLayer):
+class ShortConvMixerLayer(PagedLayer, BaseLayer):
     """Gated short convolution, (B,T,C) -> (B,T,C); ``conv_width`` is
     the source's ``conv_L_cache``. No bias.
 
@@ -111,9 +113,12 @@ class ShortConvMixerLayer(BaseLayer):
         return self._mix(params, window, x)[0], state
 
     # ---- the serving step: a pool with one row a slot ----
-    def zero_state_pool(self, slots: int, dtype):
+    def paged_cache(self, page_size: int) -> PagedCache:
+        return PagedCache(STATE)
+
+    def zero_pool(self, slots: int, page_size: int, dtype):
         """{'conv': (slots, K - 1, D) ``dtype``}: row ``s`` belongs to
-        slot ``s`` (``Mamba2MixerLayer.zero_state_pool``)."""
+        slot ``s`` (``Mamba2MixerLayer.zero_pool``)."""
         return {"conv": jnp.zeros((slots, self.conv_width - 1,
                                    self.n_in), dtype)}
 

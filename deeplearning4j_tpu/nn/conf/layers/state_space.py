@@ -39,6 +39,8 @@ from deeplearning4j_tpu.dtypes import einsum_f32
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers.base import (BaseLayer,
                                                     register_layer)
+from deeplearning4j_tpu.nn.conf.layers.paged import (STATE, PagedCache,
+                                                     PagedLayer)
 
 __all__ = ["Mamba2MixerLayer", "carried_window"]
 
@@ -61,7 +63,7 @@ def carried_window(kept, xs, n_valid):
 
 @register_layer
 @dataclasses.dataclass
-class Mamba2MixerLayer(BaseLayer):
+class Mamba2MixerLayer(PagedLayer, BaseLayer):
     """Mamba-2 mixer, (B,T,C) -> (B,T,C). The fields carry the
     source's meanings: ``n_heads`` H (``mamba_n_heads``), ``head_dim``
     P (``mamba_d_head``), ``state_size`` N (``mamba_d_state``),
@@ -83,12 +85,6 @@ class Mamba2MixerLayer(BaseLayer):
     n_groups: int = 1
     conv_width: int = 4
     eps: float = 1e-5
-
-    # the paged step sums a chunk's t rows in closed form, unrolled
-    # and quadratic in t (``apply_stream_paged``): each width of it is
-    # seconds of compile a layer, so the batcher keeps such a network
-    # to one chunk width (``PagedSlotSession.unrolls_chunk_rows``)
-    chunk_rows_unrolled = True
 
     def __post_init__(self):
         if self.n_heads % self.n_groups:
@@ -217,12 +213,18 @@ class Mamba2MixerLayer(BaseLayer):
         return self._gate_norm(params, y, z) @ params["W_out"], state
 
     # ---- the serving step: a pool with one row a slot ----
-    def zero_state_pool(self, slots: int, dtype):
+    def paged_cache(self, page_size: int) -> PagedCache:
+        """A row a slot. The paged step sums a chunk's t rows in
+        closed form, unrolled and quadratic in t
+        (``apply_stream_paged``): each width of it is seconds of
+        compile a layer, so the batcher keeps such a network to one
+        chunk width (``PagedSlotSession.unrolls_chunk_rows``)."""
+        return PagedCache(STATE, unrolls_chunk_rows=True)
+
+    def zero_pool(self, slots: int, page_size: int, dtype):
         """{'ssm': (slots, H, P, N) float32, 'conv': (slots, K - 1,
         conv_dim) ``dtype``}: row ``s`` belongs to slot ``s`` for as
-        long as the session exists. That a layer has this method is
-        how the paged session knows that its cache is of this kind:
-        no pages, no positions."""
+        long as the session exists."""
         return {"ssm": jnp.zeros((slots, self.n_heads, self.head_dim,
                                   self.state_size), _F32),
                 "conv": jnp.zeros((slots, self.conv_width - 1,

@@ -584,7 +584,7 @@ def pallas_paged_attention_latent(q_lat, q_rope, ckv_pool, kr_pool, table,
     """``q_lat`` (S, t, H, rkv) and ``q_rope`` (S, t, H, dr), the
     absorbed query's two halves; ``ckv_pool`` (n_pages, page_size,
     rkv) and ``kr_pool`` (n_pages, page_size, >= dr: a rotary key and
-    zeros past it, ``LatentAttentionLayer.zero_page_pool``, which the
+    zeros past it, ``LatentAttentionLayer.zero_pool``, which the
     query's zeros there meet); ``scale`` the softmax scale (the
     layer's, which carries YaRN's); ``table``, ``lengths``, ``pos`` as
     in :func:`pallas_paged_attention` → (S, t, H, rkv) in ``q_lat``'s

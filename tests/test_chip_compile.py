@@ -198,7 +198,7 @@ def test_grouped_query_paged_step_compiles_for_v5e(topo, as_tpu, kind, t,
               "Wv": sds((d, K * 128), bf16), "Wo": sds((64 * 128, d), bf16)}
     if layer.sink:
         params["sink"] = sds((64,), bf16)
-    n_pages = slots * (layer.ring_pages(16) or 128) + 1
+    n_pages = slots * (layer.paged_cache(16).ring_pages or 128) + 1
     pool = {"k": sds((n_pages, 16, K * 192), bf16),
             "v": sds((n_pages, 16, K * 128), bf16)}
     ints = lambda *shape: sds(shape, jnp.int32)
@@ -234,7 +234,7 @@ def test_narrow_head_paged_step_compiles_for_v5e(topo, as_tpu, t):
     params = {"Wq": sds((d, d), bf16), "Wk": sds((d, 512), bf16),
               "Wv": sds((d, 512), bf16), "Wo": sds((d, d), bf16)}
     pool = place(jax.eval_shape(
-        lambda: layer.zero_page_pool(slots * 64 + 1, 16, bf16)))
+        lambda: layer.zero_pool(slots * 64 + 1, 16, bf16)))
     assert pool["k"].shape[-1] == 8 * 64 and pool["v"].shape[-1] == 8 * 128
     ints = lambda *shape: sds(shape, jnp.int32)
     compiled = jax.jit(layer.apply_stream_paged, donate_argnums=(1,)).lower(
@@ -262,7 +262,7 @@ def _block_step(topo, layer, hidden, slots, t):
     assert {a.dtype for a in jax.tree_util.tree_leaves(params)} == {
         jnp.dtype(bf16)}
     pool = place(jax.eval_shape(
-        lambda: layer.zero_page_pool(slots * 64 + 1, 16, bf16)))
+        lambda: layer.zero_pool(slots * 64 + 1, 16, bf16)))
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
     return jax.jit(layer.apply_stream_paged_aux,
                    donate_argnums=(1,)).lower(
@@ -439,7 +439,7 @@ def test_state_space_block_step_compiles_for_v5e(topo, t):
         params = place(jax.eval_shape(lambda: layer.initialize(
             jax.random.PRNGKey(0), InputType.recurrent(d))[0]))
     pool = place(jax.eval_shape(
-        lambda: layer.zero_state_pool(slots, bf16)))
+        lambda: layer.zero_pool(slots, 16, bf16)))
     assert pool["ssm"].shape == (slots, 64, 64, 128)
     assert pool["ssm"].dtype == jnp.float32
     assert pool["conv"].shape == (slots, 3, 4352)
@@ -498,7 +498,7 @@ def test_short_conv_block_step_compiles_for_v5e(topo, t):
                 jax.random.PRNGKey(0), InputType.recurrent(d))[0]))
         assert params["conv"]["conv_w"].shape == (width, d)
         pool = place(jax.eval_shape(
-            lambda: layer.zero_state_pool(slots, bf16)))
+            lambda: layer.zero_pool(slots, 16, bf16)))
         assert pool["conv"].shape == (slots, width - 1, d)
         compiled = jax.jit(layer.apply_stream_paged,
                            donate_argnums=(1,)).lower(

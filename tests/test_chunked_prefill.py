@@ -668,6 +668,7 @@ def test_the_paged_kernels_admit_the_wide_width(monkeypatch, cell,
     import jax
     import jax.numpy as jnp
     from benchmark.harness import spec
+    from deeplearning4j_tpu.nn.conf.layers.paged import PagedLayer
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     c = spec.load(cell)
     config, sv = c.config, c.traffic["server"]
@@ -676,7 +677,8 @@ def test_the_paged_kernels_admit_the_wide_width(monkeypatch, cell,
         net = builder.build(config).init()       # parameters as shapes
     cap = min([sv["capacity"]] + [
         sv["page_size"] for layer in net.layers
-        if getattr(layer, "ring_pages", lambda p: 0)(sv["page_size"])])
+        if isinstance(layer, PagedLayer)
+        and layer.paged_cache(sv["page_size"]).ring_pages])
     t_lo = chunk_width(sv["slots"], cap)
     t_hi = wide_chunk_width(sv["slots"], cap, sv["page_size"])
     assert t_hi == 2 * t_lo

@@ -479,7 +479,7 @@ def test_latent_by_table_kernel_matches_attend(monkeypatch, fields, t,
                          InputType.recurrent(C))[0])
     n_live = S * P
     pool = {name: np.zeros((n_live + 3,) + leaf.shape[1:])
-            for name, leaf in layer.zero_page_pool(1, ps, dtype).items()}
+            for name, leaf in layer.zero_pool(1, ps, dtype).items()}
     pool["ckv"][:] = rng.normal(size=pool["ckv"].shape)
     pool["kr"][..., :4] = rng.normal(size=pool["kr"][..., :4].shape)
     # pages no slot holds: large finite garbage, which stale table

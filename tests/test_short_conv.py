@@ -218,7 +218,7 @@ def test_mixer_stream_matches_apply(t):
     x = np.asarray(jax.random.normal(jax.random.PRNGKey(2), (slots, T, D)))
     want = np.asarray(layer.apply(params, {}, jnp.asarray(x))[0])
     pool = jax.tree_util.tree_map(lambda a: a + 7.0,
-                                  layer.zero_state_pool(slots, jnp.float32))
+                                  layer.zero_pool(slots, 4, jnp.float32))
     assert list(pool) == ["conv"] and pool["conv"].shape == (slots, K - 1,
                                                              D)
     step = jax.jit(layer.apply_stream_paged)
@@ -383,7 +383,7 @@ def test_qk_norm_through_the_by_table_kernel(monkeypatch, t):
     x = jax.random.normal(jax.random.PRNGKey(2), (slots, 40 + t, 64), bf16)
 
     def run():
-        pool = layer.zero_page_pool(slots * 4 + 1, page, bf16)
+        pool = layer.zero_pool(slots * 4 + 1, page, bf16)
         for p in range(40):
             _, pool = layer.apply_stream_paged(
                 params, pool, table, jnp.full((slots,), p, jnp.int32),
@@ -777,5 +777,5 @@ def test_both_slot_state_blocks_are_one_block():
     assert keys(moe) == ["conv", "moe", "norm1_gain", "norm2_gain"]
     assert sorted(moe.initialize(jax.random.PRNGKey(0), t)[0]["moe"]) \
         == ["Wd", "Wg", "Wr", "Wu", "br"]
-    assert list(conv.zero_state_pool(3, jnp.bfloat16)) == ["conv"]
-    assert sorted(ssm.zero_state_pool(3, jnp.bfloat16)) == ["conv", "ssm"]
+    assert list(conv.zero_pool(3, 4, jnp.bfloat16)) == ["conv"]
+    assert sorted(ssm.zero_pool(3, 4, jnp.bfloat16)) == ["conv", "ssm"]

@@ -212,7 +212,7 @@ def test_mixer_stream_matches_apply(t):
     x = np.asarray(jax.random.normal(jax.random.PRNGKey(2), (slots, T, D)))
     want = np.asarray(layer.apply(params, {}, jnp.asarray(x))[0])
     pool = jax.tree_util.tree_map(lambda a: a + 7.0,
-                                  layer.zero_state_pool(slots, jnp.float32))
+                                  layer.zero_pool(slots, 4, jnp.float32))
     step = jax.jit(layer.apply_stream_paged)
     rng = np.random.default_rng(t)
     pos, got = np.zeros(slots, np.int32), [[] for _ in range(slots)]
@@ -637,7 +637,7 @@ def test_narrow_heads_read_by_table_with_their_own_scale(monkeypatch):
 
     def run(layer):
         """40 positions token by token, then a chunk of two."""
-        pool = layer.zero_page_pool(slots * 4 + 1, page, bf16)
+        pool = layer.zero_pool(slots * 4 + 1, page, bf16)
         for p in range(40):
             _, pool = layer.apply_stream_paged(
                 params, pool, table, jnp.full((slots,), p, jnp.int32),

@@ -289,7 +289,7 @@ def test_chunk_step_matches_token_by_token(tiny_net, case, page, t):
     64-slot pool's wide program): the rows past ``n_valid`` alter no
     ring row either, and an expert layer's counts of a chunk are the
     one-by-one counts summed."""
-    assert tiny_net.layers[2].ring_pages(page) == {8: 5, 4: 9}[page]
+    assert tiny_net.layers[2].paged_cache(page).ring_pages == {8: 5, 4: 9}[page]
     chunk_parity.run_case(tiny_net, 96, case, page=page, t=t)
 
 
@@ -381,7 +381,8 @@ def test_two_kinds_of_pool_in_one_session(tiny_net):
     assert np.isfinite(np.asarray(out, np.float32)[0]).all()
 
 
-def _plain_lm():
+@pytest.fixture(scope="module")
+def plain_lm():
     """A small causal transformer LM, no window anywhere."""
     from deeplearning4j_tpu import (MultiLayerNetwork,
                                     NeuralNetConfiguration)
@@ -395,7 +396,7 @@ def _plain_lm():
     return MultiLayerNetwork(conf).init()
 
 
-def test_no_prefix_is_taken_or_registered_over_a_ring(tiny_net):
+def test_no_prefix_is_taken_or_registered_over_a_ring(tiny_net, plain_lm):
     """A ring cannot be shared and a hit would resume behind an empty
     window: a repeated prompt is served cold, to the reference's
     logits, and neither ``release`` nor ``register_written_prefix``
@@ -416,7 +417,7 @@ def test_no_prefix_is_taken_or_registered_over_a_ring(tiny_net):
     for pos, row in got.items():
         np.testing.assert_allclose(row, want[pos], atol=ATOL)
 
-    plain = _plain_lm().paged_slot_streaming_session(
+    plain = plain_lm.paged_slot_streaming_session(
         capacity=64, slots=2, page_size=PAGE)
     assert plain.chunk_rows_max == 64 and not any(plain._ring)
     plain.bind(0, plain.reserve(prompt, 2))
@@ -461,11 +462,10 @@ def test_lease_export_import_gives_the_same_next_logits(tiny_net, pos):
         _session(other, slots=2).import_lease(blob, pos + 8)
 
 
-def test_ring_pages_accounting():
+def test_ring_pages_accounting(tiny_net):
     """``step_ring_pages`` is host arithmetic from the positions a
     step feeds: (held, full, overwritten) over the fed slots."""
-    net = _net(TINY)
-    sess = _session(net, slots=4)
+    sess = _session(tiny_net, slots=4)
     span = RING * PAGE                       # 48 positions
     pos = np.array([0, 40, 47, 100], np.int32)
     sess._note_ring(pos, np.array([16, 2, 2, 0], np.int32))
@@ -637,7 +637,7 @@ def test_the_predicate_is_of_the_shapes_and_the_window(monkeypatch, changed,
         assert layer.paged_reads_by_table(page, t, jnp.bfloat16) == want
     # since PR 39 a value head narrower than a lane tile takes one in
     # the pool where that alone admits the kernel
-    pool = jax.eval_shape(lambda: layer.zero_page_pool(3, page,
+    pool = jax.eval_shape(lambda: layer.zero_pool(3, page,
                                                        jnp.bfloat16))
     wide = 128 if want else layer.v_head_dim
     assert pool["v"].shape == (3, page, layer.n_kv_heads * wide)
@@ -665,7 +665,7 @@ def test_kv_positions_count_the_allocators_layers(tiny_net, monkeypatch,
         (0 + 16 + 48 + 256) if by_table else spanned, spanned)
 
 
-def test_the_defaults_are_the_layers_they_were():
+def test_the_defaults_are_the_layers_they_were(plain_lm):
     """No field of the new layers reaches the old ones: a network
     without a window has no ring, no ring counter and the chunk width
     it had."""
@@ -673,7 +673,7 @@ def test_the_defaults_are_the_layers_they_were():
                                                        chunk_width)
     from deeplearning4j_tpu.serving.metrics import ServingMetrics
     metrics = ServingMetrics()
-    cb = ContinuousBatcher(_plain_lm(), slots=2, capacity=64,
+    cb = ContinuousBatcher(plain_lm, slots=2, capacity=64,
                            page_size=8, kv_mode="paged", metrics=metrics)
     try:
         assert cb._chunk_t == chunk_width(2, 64) == 64
@@ -682,6 +682,6 @@ def test_the_defaults_are_the_layers_they_were():
         cb.shutdown(drain=True)
     assert not [k for k in metrics.registry.snapshot()
                 if "kv_ring" in k]
-    assert GroupedQueryAttentionLayer().ring_pages(16) == 0
-    assert GroupedQueryAttentionLayer(window=128).ring_pages(16) == 9
-    assert GroupedQueryAttentionLayer(window=100).ring_pages(16) == 8
+    ring = lambda **kw: GroupedQueryAttentionLayer(**kw).paged_cache(
+        16).ring_pages
+    assert (ring(), ring(window=128), ring(window=100)) == (0, 9, 8)

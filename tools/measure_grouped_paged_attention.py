@@ -186,7 +186,7 @@ def setup(seed, layer, d, slots, pages, fill):
                          InputType.recurrent(d))[0])
     n_pages = slots * pages + 1
     pool = {}
-    for name, leaf in layer.zero_page_pool(n_pages, PAGE, bf16).items():
+    for name, leaf in layer.zero_pool(n_pages, PAGE, bf16).items():
         rows = np.zeros(leaf.shape, np.float32)
         fill(name, rows, rng)
         pool[name] = jnp.asarray(rows, bf16)
