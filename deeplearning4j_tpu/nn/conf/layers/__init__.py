@@ -53,10 +53,13 @@ from deeplearning4j_tpu.nn.conf.layers.state_space import (
 from deeplearning4j_tpu.nn.conf.layers.short_conv import (
     ShortConvMixerLayer,
 )
+from deeplearning4j_tpu.nn.conf.layers.delta_rule import (
+    GatedDeltaMixerLayer,
+)
 from deeplearning4j_tpu.nn.conf.layers.moe import SparseExpertsLayer
 from deeplearning4j_tpu.nn.conf.layers.decoder_blocks import (
     LatentDecoderBlock, ShortcutExpertBlock, GroupedQueryDecoderBlock,
-    StateSpaceDecoderBlock, ShortConvDecoderBlock,
+    StateSpaceDecoderBlock, ShortConvDecoderBlock, DeltaRuleDecoderBlock,
 )
 
 __all__ = [
@@ -82,4 +85,5 @@ __all__ = [
     "ShortcutExpertBlock", "GroupedQueryDecoderBlock",
     "Mamba2MixerLayer", "StateSpaceDecoderBlock",
     "ShortConvMixerLayer", "ShortConvDecoderBlock",
+    "GatedDeltaMixerLayer", "DeltaRuleDecoderBlock",
 ]

@@ -17,7 +17,7 @@ owns, not pages of the allocator.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -415,7 +415,9 @@ class GroupedQueryAttentionLayer(PagedLayer, BaseLayer):
     key head is RMS-normed over its ``qk_head_dim`` values before the
     rotation (one gain for all query heads, ``q_norm_gain``, and one
     for all key heads, ``k_norm_gain``; ``qk_norm_eps``), so the cache
-    holds normed keys.
+    holds normed keys; ``"width"`` norms the whole projected width
+    instead (all heads' values as one vector, gains of ``H dq`` and
+    ``K dq``: the Olmo family's).
 
     Two forms of one mathematics: ``apply`` attends over the whole
     sequence, ``apply_stream_paged`` over a paged cache that holds
@@ -445,10 +447,13 @@ class GroupedQueryAttentionLayer(PagedLayer, BaseLayer):
     sink: bool = False
     value_scale: float = 1.0
     softmax_scale: Optional[float] = None
-    qk_norm: bool = False
+    qk_norm: Union[bool, str] = False
     qk_norm_eps: float = 1e-6
 
     def __post_init__(self):
+        if self.qk_norm not in (False, True, "width"):
+            raise ValueError(f"qk_norm {self.qk_norm!r}: False, True "
+                             "(a head) or 'width'")
         if self.n_heads % self.n_kv_heads:
             raise ValueError(f"n_heads {self.n_heads} not divisible by "
                              f"n_kv_heads {self.n_kv_heads}")
@@ -478,8 +483,10 @@ class GroupedQueryAttentionLayer(PagedLayer, BaseLayer):
         if self.sink:
             p["sink"] = jnp.zeros((H,), dtypes.policy().param_dtype)
         if self.qk_norm:
-            for name in ("q_norm_gain", "k_norm_gain"):
-                p[name] = jnp.ones((dq,), dtypes.policy().param_dtype)
+            wide = self.qk_norm == "width"
+            for name, n in (("q_norm_gain", H), ("k_norm_gain", K)):
+                p[name] = jnp.ones((n * dq if wide else dq,),
+                                   dtypes.policy().param_dtype)
         return p, {}
 
     # ---- pieces shared by both forms ----
@@ -495,16 +502,24 @@ class GroupedQueryAttentionLayer(PagedLayer, BaseLayer):
 
     def _project(self, params, x, positions):
         """x (B,t,C) at ``positions`` (B,t) -> q (B,t,H,dq) and k
-        (B,t,K,dq), normed a head where ``qk_norm`` and rotated, v
-        (B,t,K,dv) scaled: k and v are what the cache holds."""
+        (B,t,K,dq), normed (a head, or the whole width) where
+        ``qk_norm`` and rotated, v (B,t,K,dv) scaled: k and v are what
+        the cache holds."""
         B, t, _ = x.shape
         H, K = self.n_heads, self.n_kv_heads
         x = x.astype(params["Wq"].dtype)
-        q = (x @ params["Wq"]).reshape(B, t, H, self.qk_head_dim)
-        k = (x @ params["Wk"]).reshape(B, t, K, self.qk_head_dim)
-        if self.qk_norm:
-            q = rms_norm(q, params["q_norm_gain"], self.qk_norm_eps)
-            k = rms_norm(k, params["k_norm_gain"], self.qk_norm_eps)
+        norm = lambda y, gain: rms_norm(y, params[gain], self.qk_norm_eps)
+
+        def project(name, gain, n):
+            y = x @ params[name]
+            if self.qk_norm == "width":
+                y = norm(y, gain)
+            return y.reshape(B, t, n, self.qk_head_dim)
+
+        q = project("Wq", "q_norm_gain", H)
+        k = project("Wk", "k_norm_gain", K)
+        if self.qk_norm is True:
+            q, k = norm(q, "q_norm_gain"), norm(k, "k_norm_gain")
         if self.value_scale == 1.0:
             v = x @ params["Wv"]
         else:

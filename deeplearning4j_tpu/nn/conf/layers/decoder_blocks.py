@@ -1,13 +1,15 @@
-"""Decoder blocks: a sequence mixer, then a feed-forward, each behind
+"""Decoder blocks: a sequence mixer, then a feed-forward, each with
 an RMS norm and joined to the residual stream.
 
-Four of the five are ONE block, ``_PreNormBlock``: ``h = x + m
+Five of the six are ONE block, ``_NormedBlock``: ``h = x + m
 Mixer(norm(x)); y = h + m F(norm(h))`` with ``F`` the dense SiLU-gated
-MLP or the expert layer (``moe.SparseExpertsLayer``). A class gives
-what a configuration adds: the two sub-layers' own fields, flat, so
-that the block round-trips through JSON like every DSL layer; the
-mixer's key in the parameters and its ``named_scope``; and
-``_ensure_parts() -> (mixer, experts or None)``.
+MLP or the expert layer (``moe.SparseExpertsLayer``), or, where the
+block's ``norm_placement`` is ``"post"``, each branch's OUTPUT normed
+(``h = x + m norm(Mixer(x)); y = h + m norm(F(h))``: the Olmo
+family's). A class gives what a configuration adds: the two
+sub-layers' own fields, flat, so that the block round-trips through
+JSON like every DSL layer; the mixer's key in the parameters and its
+``named_scope``; and ``_ensure_parts() -> (mixer, experts or None)``.
 
 - ``LatentDecoderBlock``: latent attention (DeepSeek-V2 / V3).
 - ``GroupedQueryDecoderBlock``: grouped-query attention, global or a
@@ -21,6 +23,10 @@ mixer's key in the parameters and its ``named_scope``; and
 - ``ShortConvDecoderBlock``: a gated short convolution
   (``short_conv.py``) and the dense MLP or the expert layer: LFM2's
   ``conv`` layer.
+- ``DeltaRuleDecoderBlock``: a gated delta-rule mixer
+  (``delta_rule.py``) and the dense MLP; with
+  ``GroupedQueryDecoderBlock``, ``norm_placement`` ``"post"`` and
+  ``qk_norm`` ``"width"``, Olmo-Hybrid's two kinds of layer.
 
 ``ShortcutExpertBlock`` is LongCat-Flash's shortcut-connected layer
 (arXiv 2509.01322 §2.2): two latent attentions and two dense MLPs in
@@ -35,7 +41,7 @@ mixer keeps (``paged.MixerCacheLayer``).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +52,8 @@ from deeplearning4j_tpu.nn.conf.layers.attention import (
     GroupedQueryAttentionLayer)
 from deeplearning4j_tpu.nn.conf.layers.base import (BaseLayer,
                                                     register_layer)
+from deeplearning4j_tpu.nn.conf.layers.delta_rule import (
+    GatedDeltaMixerLayer)
 from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
     LatentAttentionLayer)
 from deeplearning4j_tpu.nn.conf.layers.moe import (SparseExpertsLayer,
@@ -58,7 +66,7 @@ from deeplearning4j_tpu.nn.conf.layers.state_space import Mamba2MixerLayer
 
 __all__ = ["LatentDecoderBlock", "ShortcutExpertBlock",
            "GroupedQueryDecoderBlock", "StateSpaceDecoderBlock",
-           "ShortConvDecoderBlock"]
+           "ShortConvDecoderBlock", "DeltaRuleDecoderBlock"]
 
 _F32 = jnp.float32
 
@@ -72,19 +80,21 @@ def _residual(h, f, multiplier=1.0):
 
 
 def _ffn_half(params, h, moe, eps, active=None, multiplier=1.0,
-              stream=False):
-    """The second half of a pre-RMSNorm decoder block,
-    ``(h + multiplier * F(norm(h)), counts or None)``: ``F`` is the
-    expert layer ``moe`` (parameters ``params["moe"]``) or, where that
-    is None, the dense SiLU-gated MLP ``Wg, Wu, Wd``. ``stream``: a
+              stream=False, post=False):
+    """The second half of an RMS-normed decoder block,
+    ``(h + multiplier * F(norm(h)), counts or None)``, or with
+    ``post`` ``h + multiplier * norm(F(h))``: ``F`` is the expert
+    layer ``moe`` (parameters ``params["moe"]``) or, where that is
+    None, the dense SiLU-gated MLP ``Wg, Wu, Wd``. ``stream``: a
     serving step's call (``SparseExpertsLayer.apply_tallied``)."""
-    z = rms_norm(h, params["norm2_gain"], eps)
+    norm = lambda v: rms_norm(v, params["norm2_gain"], eps)
+    z = h if post else norm(h)
     if moe is None:
         with jax.named_scope("mlp"):
-            return _residual(h, swiglu(z, params["Wg"], params["Wu"],
-                                       params["Wd"]), multiplier), None
+            f = swiglu(z, params["Wg"], params["Wu"], params["Wd"])
+            return _residual(h, norm(f) if post else f, multiplier), None
     f, counts = moe.apply_counted(params["moe"], z, active, stream)
-    return _residual(h, f, multiplier), counts
+    return _residual(h, norm(f) if post else f, multiplier), counts
 
 
 def _biased_sigmoid_experts(block, held, common):
@@ -147,15 +157,28 @@ class _DecoderBlock(MixerCacheLayer, BaseLayer):
         return h, pool
 
 
-class _PreNormBlock(_DecoderBlock):
-    """``h = x + m Mixer(norm(x)); y = h + m F(norm(h))``, ``m`` the
-    ``residual_multiplier``: the equations, the parameters and both
-    forms (whole sequence, paged step), once. A subclass gives the
-    fields, ``mixer`` (the mixer's key in the parameters), ``scope``
-    (its ``named_scope``; ``mixer`` unless it says otherwise) and
-    ``_ensure_parts``."""
+NORM_PLACEMENTS = ("pre", "post")
 
-    residual_multiplier = 1.0   # a field where a subclass has one
+
+class _NormedBlock(_DecoderBlock):
+    """``h = x + m Mixer(norm(x)); y = h + m F(norm(h))``, ``m`` the
+    ``residual_multiplier``, or with ``norm_placement`` ``"post"``
+    ``h = x + m norm(Mixer(x)); y = h + m norm(F(h))`` (the mixer and
+    the feed-forward read the residual stream as it is, each branch's
+    output is normed before it joins): the equations, the parameters
+    and both forms (whole sequence, paged step), once. A subclass
+    gives the fields, ``mixer`` (the mixer's key in the parameters),
+    ``scope`` (its ``named_scope``; ``mixer`` unless it says
+    otherwise) and ``_ensure_parts``."""
+
+    residual_multiplier = 1.0   # fields where a subclass has them
+    norm_placement = "pre"
+
+    def __post_init__(self):
+        if self.norm_placement not in NORM_PLACEMENTS:
+            raise ValueError(
+                f"norm_placement {self.norm_placement!r}: one of "
+                f"{NORM_PLACEMENTS}")
 
     @property
     def scope(self) -> str:
@@ -180,15 +203,18 @@ class _PreNormBlock(_DecoderBlock):
         return p, {}
 
     def _block(self, params, x, mix, active=None, stream=False):
-        """The block's equations; ``mix(z)`` is the mixer over the
-        normed ``z``; ``stream``: a serving step's call."""
+        """The block's equations; ``mix(z)`` is the mixer over ``z``
+        (the normed input, or the input itself where the norm sits
+        behind the mixer); ``stream``: a serving step's call."""
         x = x.astype(params["norm1_gain"].dtype)
+        post = self.norm_placement == "post"
+        norm = lambda v: rms_norm(v, params["norm1_gain"], self.eps)
         with jax.named_scope(self.scope):
-            a = mix(rms_norm(x, params["norm1_gain"], self.eps))
+            a = norm(mix(x)) if post else mix(norm(x))
         m = self.residual_multiplier
         return _ffn_half(params, _residual(x, a, m),
                          self._ensure_parts()[1], self.eps, active, m,
-                         stream)
+                         stream, post)
 
     def apply(self, params, state, x, *, training=False, rng=None,
               mask=None):
@@ -218,8 +244,8 @@ class _PreNormBlock(_DecoderBlock):
 
 @register_layer
 @dataclasses.dataclass
-class LatentDecoderBlock(_PreNormBlock):
-    """``_PreNormBlock`` over latent attention
+class LatentDecoderBlock(_NormedBlock):
+    """``_NormedBlock`` over latent attention
     (``LatentAttentionLayer``), then a dense SiLU-gated MLP
     (``n_routed_experts == 0``) or the expert layer."""
 
@@ -410,8 +436,8 @@ class ShortcutExpertBlock(_DecoderBlock):
 
 @register_layer
 @dataclasses.dataclass
-class GroupedQueryDecoderBlock(_PreNormBlock):
-    """``_PreNormBlock`` over grouped-query attention
+class GroupedQueryDecoderBlock(_NormedBlock):
+    """``_NormedBlock`` over grouped-query attention
     (``GroupedQueryAttentionLayer``: global, or with ``window`` a
     sliding window whose paged cache is a slot-owned ring), then a
     dense SiLU-gated MLP (``n_routed_experts == 0``) or the expert
@@ -442,8 +468,10 @@ class GroupedQueryDecoderBlock(_PreNormBlock):
     # multiplies both branches before they join the residual stream
     softmax_scale: Optional[float] = None
     residual_multiplier: float = 1.0
-    # an RMS norm over each query and key head, at the block's ``eps``
-    qk_norm: bool = False
+    # an RMS norm at the block's ``eps`` over each query and key head
+    # (True) or over the whole projected width ("width")
+    qk_norm: Union[bool, str] = False
+    norm_placement: str = "pre"
 
     mixer = "attn"
 
@@ -468,8 +496,8 @@ class GroupedQueryDecoderBlock(_PreNormBlock):
 
 @register_layer
 @dataclasses.dataclass
-class StateSpaceDecoderBlock(_PreNormBlock):
-    """``_PreNormBlock`` over a Mamba-2 mixer (``Mamba2MixerLayer``,
+class StateSpaceDecoderBlock(_NormedBlock):
+    """``_NormedBlock`` over a Mamba-2 mixer (``Mamba2MixerLayer``,
     whose fields these are, flat) and the dense SiLU-gated MLP."""
 
     n_in: Optional[int] = None
@@ -498,8 +526,8 @@ class StateSpaceDecoderBlock(_PreNormBlock):
 
 @register_layer
 @dataclasses.dataclass
-class ShortConvDecoderBlock(_PreNormBlock):
-    """``_PreNormBlock`` over a gated short convolution
+class ShortConvDecoderBlock(_NormedBlock):
+    """``_NormedBlock`` over a gated short convolution
     (``ShortConvMixerLayer``), then the dense SiLU-gated MLP
     (``n_routed_experts == 0``) or ``GroupedQueryDecoderBlock``'s
     expert layer, every expert held: LFM2's ``conv`` layer."""
@@ -525,3 +553,36 @@ class ShortConvDecoderBlock(_PreNormBlock):
                 conv_width=self.conv_width, **common)
             self._moe = _biased_sigmoid_experts(self, None, common)
         return self._conv, self._moe
+
+
+@register_layer
+@dataclasses.dataclass
+class DeltaRuleDecoderBlock(_NormedBlock):
+    """``_NormedBlock`` over a gated delta-rule mixer
+    (``GatedDeltaMixerLayer``, whose fields these are, flat) and the
+    dense SiLU-gated MLP: Olmo-Hybrid's ``linear_attention`` layer
+    with ``norm_placement`` ``"post"``."""
+
+    n_in: Optional[int] = None
+    eps: float = 1e-6
+    # gated delta-rule mixer (GatedDeltaMixerLayer)
+    n_heads: int = 4
+    key_head_dim: int = 8
+    value_head_dim: int = 16
+    conv_width: int = 4
+    allow_neg_eigval: bool = False
+    # dense MLP width
+    intermediate_size: int = 128
+    norm_placement: str = "pre"
+
+    mixer = "delta"
+
+    def _ensure_parts(self):
+        if not hasattr(self, "_delta"):
+            self._delta = GatedDeltaMixerLayer(
+                n_heads=self.n_heads, key_head_dim=self.key_head_dim,
+                value_head_dim=self.value_head_dim,
+                conv_width=self.conv_width,
+                allow_neg_eigval=self.allow_neg_eigval, eps=self.eps,
+                **self._common())
+        return self._delta, None

@@ -64,7 +64,7 @@ class PagedLayer:
       layer also says ``experts_grouped(rows, dtype)``.
 
     A block around a mixer is a ``MixerCacheLayer``; a decoder block
-    is ``decoder_blocks._PreNormBlock``'s fields, key, scope and
+    is ``decoder_blocks._NormedBlock``'s fields, key, scope and
     parts. A new configuration touches its mixer's module, that one
     and a builder; ``models/paged_kv.py`` and ``serving/`` only for a
     new KIND of cache."""

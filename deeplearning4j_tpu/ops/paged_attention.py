@@ -342,6 +342,17 @@ def _dot_precision(t: int, dtype):
     return jax.lax.Precision.DEFAULT
 
 
+def _sublanes(dtype) -> int:
+    """Rows of ``dtype`` in one sublane tile."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _whole_tiles(rows: int, dtype) -> int:
+    """``rows`` rounded up to whole sublane tiles of ``dtype``."""
+    n = _sublanes(dtype)
+    return -(-rows // n) * n
+
+
 def _vmem_bytes(rows: int, key_row: int, value_row: int, q_and_o: int,
                 page_size: int, t: int, dtype) -> int:
     """The fast memory a kernel asks for, over ``rows`` = ``t * H``
@@ -421,7 +432,7 @@ def reads_by_table(n_heads: int, head_dim: int, page_size: int, t: int,
     the kernel holds in fast memory (``_vmem_bytes``: the K and V
     buffers grow with the row, the accumulator and its likes with
     ``t * H`` rows of it) fits the budget."""
-    sublanes = 32 // jnp.dtype(dtype).itemsize
+    sublanes = _sublanes(dtype)
     return (jax.default_backend() == "tpu"
             and page_size % sublanes == 0
             and (n_heads * head_dim) % 128 == 0
@@ -459,7 +470,9 @@ def pallas_paged_attention_grouped(q, k_pool, v_pool, table, lengths, pos,
     ``v_pool`` (n_pages, page_size, K * dv); ``table``, ``lengths``,
     ``pos`` as in :func:`pallas_paged_attention` → (S, t, H * dv) in
     ``q``'s dtype. Query head ``h`` reads key/value head
-    ``h // (H / K)``."""
+    ``h // (H / K)``. A slot's ``t * H`` rows that are no whole
+    sublane tiles (30 heads) are rounded up to them here: zero query
+    rows behind the slot's own, whose output is dropped."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -469,7 +482,10 @@ def pallas_paged_attention_grouped(q, k_pool, v_pool, table, lengths, pos,
     vrow = v_pool.shape[2]
     dv = vrow // n_kv_heads
     ppb = max(1, min(P, _BLOCK_KEYS // ps))
-    R = t * H
+    R = _whole_tiles(t * H, q.dtype)
+    q = q.reshape(S, t * H, dq)
+    if R != t * H:
+        q = jnp.pad(q, ((0, 0), (0, R - t * H), (0, 0)))
     kernel = functools.partial(
         _grouped_kernel, n_heads=H, n_kv_heads=n_kv_heads, page_size=ps,
         pages_per_block=ppb, pages_per_slot=P,
@@ -501,8 +517,9 @@ def pallas_paged_attention_grouped(q, k_pool, v_pool, table, lengths, pos,
         interpret=interpret,
         name="pallas_paged_attention_grouped",
     )(lengths.astype(jnp.int32), pos.astype(jnp.int32),
-      table.reshape(-1).astype(jnp.int32), q.reshape(S, R, dq), k_pool,
-      v_pool)
+      table.reshape(-1).astype(jnp.int32), q, k_pool, v_pool)
+    if R != t * H:
+        out = out[:, :t * H]
     return out.reshape(S, t, H * dv)
 
 
@@ -512,17 +529,17 @@ def grouped_reads_by_table(n_heads: int, n_kv_heads: int, qk_head_dim: int,
     """:func:`reads_by_table` for :func:`pallas_paged_attention_grouped`:
     on a TPU, a page whole sublane tiles of its dtype, a key row and a
     value head whole lane tiles (a row of the output is one value
-    head), a slot's ``t * H`` rows whole sublane tiles, and the fast
-    memory within the budget."""
-    sublanes = 32 // jnp.dtype(dtype).itemsize
+    head), and the fast memory within the budget, reckoned on a
+    slot's ``t * H`` rows rounded up to whole sublane tiles as the
+    wrapper rounds them."""
+    rows = _whole_tiles(t * n_heads, dtype)
     return (jax.default_backend() == "tpu"
-            and page_size % sublanes == 0
+            and page_size % _sublanes(dtype) == 0
             and (n_kv_heads * qk_head_dim) % 128 == 0
             and v_head_dim % 128 == 0
-            and (t * n_heads) % sublanes == 0
-            and _vmem_bytes(t * n_heads, n_kv_heads * qk_head_dim,
+            and _vmem_bytes(rows, n_kv_heads * qk_head_dim,
                             n_kv_heads * v_head_dim,
-                            t * n_heads * (qk_head_dim + v_head_dim),
+                            rows * (qk_head_dim + v_head_dim),
                             page_size, t, dtype) <= _VMEM_BUDGET)
 
 
@@ -643,7 +660,7 @@ def latent_reads_by_table(n_heads: int, kv_lora_rank: int,
     whole lane tiles (the rotary key's row is widened to them in the
     pool), a slot's ``t * H`` rows whole sublane tiles, and the fast
     memory within the budget."""
-    sublanes = 32 // jnp.dtype(dtype).itemsize
+    sublanes = _sublanes(dtype)
     kr_row = lane_tiled(qk_rope_head_dim)
     return (jax.default_backend() == "tpu"
             and page_size % sublanes == 0

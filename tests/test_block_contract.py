@@ -23,8 +23,8 @@ from deeplearning4j_tpu import MultiLayerNetwork, NeuralNetConfiguration
 from deeplearning4j_tpu.models.paged_kv import PagedSlotSession
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import (
-    EmbeddingSequenceLayer, GroupedQueryDecoderBlock, LatentDecoderBlock,
-    RnnOutputLayer, ShortConvDecoderBlock, ShortcutExpertBlock,
+    DeltaRuleDecoderBlock, EmbeddingSequenceLayer,
+    GroupedQueryDecoderBlock, LatentDecoderBlock, RnnOutputLayer, ShortConvDecoderBlock, ShortcutExpertBlock,
     StateSpaceDecoderBlock, TransformerEncoderLayer, layer_from_dict)
 from deeplearning4j_tpu.nn.conf.layers.paged import (PAGES, RING, STATE,
                                                      PagedCache, PagedLayer)
@@ -44,6 +44,14 @@ CASES = {
         residual_multiplier=0.22),
     "short_conv": lambda: ShortConvDecoderBlock(),
     "short_conv_experts": lambda: ShortConvDecoderBlock(**EXPERTS),
+    "delta_rule": lambda: DeltaRuleDecoderBlock(allow_neg_eigval=True),
+    # the Olmo family's: each branch's output normed, q and k normed
+    # over the whole projected width
+    "delta_rule_post": lambda: DeltaRuleDecoderBlock(
+        n_heads=3, key_head_dim=6, value_head_dim=10,
+        norm_placement="post"),
+    "gqa_post_width": lambda: GroupedQueryDecoderBlock(
+        qk_norm="width", norm_placement="post"),
     "encoder": lambda: TransformerEncoderLayer(n_heads=2, causal=True),
 }
 
@@ -51,6 +59,8 @@ _LATENT = "attn/Wkva attn/Wkvb attn/Wo attn/Wqa attn/Wqb attn/kv_gain " \
     "attn/q_gain"
 _NORMS = "norm1_gain norm2_gain"
 _ROUTED = "moe/Wd moe/Wg moe/Wr moe/Wu moe/br"
+_DELTA = "delta/A_log delta/Wa delta/Wb delta/Wg delta/Wk delta/Wo " \
+    "delta/Wq delta/Wv delta/conv_w delta/dt_bias delta/g"
 _F32 = "float32"
 _LATENT_POOL = {"ckv": ((13, PAGE, 16), _F32), "kr": ((13, PAGE, 128), _F32)}
 
@@ -92,6 +102,20 @@ WANT = {
     "short_conv_experts": (
         f"conv/W_in conv/W_out conv/conv_w {_ROUTED} {_NORMS}",
         PagedCache(STATE), True, CAP, {"conv": ((SLOTS, 2, D), _F32)}),
+    "delta_rule": (
+        f"Wd Wg Wu {_DELTA} {_NORMS}",
+        PagedCache(STATE, unrolls_chunk_rows=True), False, CAP,
+        {"conv": ((SLOTS, 3, 128), _F32),
+         "state": ((SLOTS, 4, 8, 16), _F32)}),
+    "delta_rule_post": (
+        f"Wd Wg Wu {_DELTA} {_NORMS}",
+        PagedCache(STATE, unrolls_chunk_rows=True), False, CAP,
+        {"conv": ((SLOTS, 3, 66), _F32),
+         "state": ((SLOTS, 3, 6, 10), _F32)}),
+    "gqa_post_width": (
+        "Wd Wg Wu attn/Wk attn/Wo attn/Wq attn/Wv attn/k_norm_gain "
+        f"attn/q_norm_gain {_NORMS}", PagedCache(PAGES), False, CAP,
+        {"k": ((13, PAGE, 16), _F32), "v": ((13, PAGE, 16), _F32)}),
     "encoder": (
         "W1 W2 attn/Wk attn/Wo attn/Wq attn/Wv attn/bo b1 b2 ln1_b ln1_g "
         "ln2_b ln2_g", PagedCache(PAGES), False, CAP,

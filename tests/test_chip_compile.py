@@ -573,6 +573,118 @@ def test_short_conv_expert_cell_step_fits_v5e(topo, as_tpu, t):
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 13e9
 
 
+@pytest.mark.parametrize("t", [2, 1], ids=["chunk_64x2", "decode_64x1"])
+def test_delta_rule_block_step_compiles_for_v5e(topo, t):
+    """``DeltaRuleDecoderBlock.apply_stream_paged`` at the widths of
+    the benchmark's ``olmo_hybrid_serve_reason`` cell (hidden 3840, 30
+    heads of 96 x 192, convolutions of 4, MLP of 11008, the norms
+    behind the branches) in bfloat16 over 64 slots, both step
+    programs. The float32 state pool is (64, 15, 96, 384), two heads
+    side by side: whole lane tiles, so the device holds the row's
+    2,211,840 B as counted ((64, 30, 96, 192) would be held 96 x 256 a
+    head, a third more). It is donated and updated in place, and TWO
+    instructions of the compiled step read it, whatever t: the 2 t
+    reductions ``S^T k`` / ``S^T q`` in one pass over the pool, and
+    the write, which needs their result, in a second. No copy of it
+    is made (temporaries under 8 MB), and what else lies between the
+    projections is whole-array products, sums and selects: no
+    contraction and no gather by row, and the two windowed reductions
+    (the L2 norms' sums over a head's 96 values, one each for q and
+    k) do not grow with t."""
+    from deeplearning4j_tpu import dtypes
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.conf.layers import DeltaRuleDecoderBlock
+    bf16, slots, d = jnp.bfloat16, 64, 3840
+    layer = DeltaRuleDecoderBlock(
+        n_in=d, n_heads=30, key_head_dim=96, value_head_dim=192,
+        conv_width=4, allow_neg_eigval=True, intermediate_size=11008,
+        norm_placement="post")
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), tree)
+    with dtypes.policy_scope(dtypes.Policy(
+            param_dtype=bf16, compute_dtype=bf16, output_dtype=bf16)):
+        params = place(jax.eval_shape(lambda: layer.initialize(
+            jax.random.PRNGKey(0), InputType.recurrent(d))[0]))
+    pool = place(jax.eval_shape(
+        lambda: layer.zero_pool(slots, 16, bf16)))
+    assert pool["state"].shape == (slots, 15, 96, 384)
+    assert pool["state"].dtype == jnp.float32
+    assert pool["conv"].shape == (slots, 3, 11520)
+    compiled = jax.jit(layer.apply_stream_paged, donate_argnums=(1,)).lower(
+        params, pool, sds((slots, 48), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots, t, d), bf16),
+        sds((slots,), jnp.int32) if t > 1 else None).compile()
+    mem = compiled.memory_analysis()
+    state = slots * 30 * 96 * 192 * 4
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < 8e6
+    text = compiled.as_text()
+    entry = text.split("ENTRY", 1)[1]
+    # held as counted: the pool's layout has no padded tile
+    name, tiles = re.findall(
+        r"%([\w.]+) = f32\[64,15,96,384\](\S*) parameter", entry)[0]
+    assert "T(8,128)" in tiles and 96 % 8 == 0 and 384 % 128 == 0
+    readers = [line for line in entry.splitlines()
+               if f"%{name}" in line and " parameter(" not in line]
+    assert len(readers) == 2, readers
+    small = re.findall(r" (dot|convolution|reduce-window|gather)\(.*"
+                       r'op_name="[^"]*/state/', text)
+    assert small == ["reduce-window"] * 2, small
+
+
+@pytest.mark.parametrize("t", [2, 1], ids=["chunk_64x2", "decode_64x1"])
+def test_delta_rule_hybrid_cell_step_fits_v5e(topo, as_tpu, t):
+    """The WHOLE id-returning step of the benchmark's
+    ``olmo_hybrid_serve_reason`` cell (``PagedSlotSession._step_ids``
+    over the configuration's own network: 16 layers at the published
+    widths in bfloat16, 64 slots of capacity 768, page 16), both step
+    programs: 12 state pools of 2.28 MB a slot beside 4 attention
+    layers of 30 heads in the allocator's 3,073 pages, every one read
+    by table through the grouped kernel (a slot's 30 or 60 rows
+    rounded up to 32 or 64: no gather of a whole table), in a chip's
+    16 GB."""
+    from benchmark.harness import spec
+    from deeplearning4j_tpu.models.paged_kv import PagedSlotSession
+    cell = spec.load("olmo_hybrid_serve_reason")
+    config, sv = cell.config, cell.traffic["server"]
+    builder = spec.load_module("builders", config["builder"])
+    with builder.policy(config):
+        net = builder.build(config).init()       # parameters as shapes
+    sess = PagedSlotSession(net, sv["slots"], sv["capacity"],
+                            sv["page_size"])
+    assert sess._state == [False] + [True, True, True, False] * 4 + [
+        False, False]
+    assert sess.unrolls_chunk_rows and not any(sess._ring)
+    assert sess.state_pool_bytes == 12 * 64 * 2_280_960
+    assert sess._pools[4]["k"].shape == (3073, 16, 30 * 128)
+    assert all(net.layers[i].paged_reads_by_table(16, t, jnp.bfloat16)
+               for i in (4, 8, 12, 16))
+    sess._make_step()
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), tree)
+    slots = sv["slots"]
+    compiled = sess._step_ids.lower(
+        place(net.params), net.state, place(sess._pools),
+        sds((slots, sess.pages_per_slot), jnp.int32),
+        sds((slots,), jnp.int32), sds((slots, t, 1), jnp.float32),
+        sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots,), bool)).compile()
+    assert _kernels_in(compiled) == 4
+    mem = compiled.memory_analysis()
+    # 8.20 GB of weights, 1.75 GB of state rows, 3.02 GB of pages; the
+    # pools are donated; the logits of 128 rows over 100,352 ids and
+    # the MLPs' activations stay under 1 GB
+    assert 12.9e9 < mem.argument_size_in_bytes < 13.1e9
+    assert mem.alias_size_in_bytes > 4.7e9
+    assert mem.temp_size_in_bytes < 1e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 14.5e9
+
+
 @pytest.mark.parametrize("d, w, routed, held, top_k", [
     (2048, 1536, 64, None, 4), (4096, 2048, 256, (0, 16), 8)],
     ids=["lfm2_24b_a2b", "mimo_v25_ep16"])

@@ -554,6 +554,43 @@ def _by_table(monkeypatch, holds=True):
                               "8_over_4_qk128_v128"])
 def test_grouped_by_table_kernel_matches_attend(monkeypatch, heads, t,
                                                 dtype):
+    _kernel_against_attend(monkeypatch, heads, t, dtype)
+
+
+@pytest.mark.parametrize("H, t, dtype", [
+    (6, 1, "float32"), (6, 2, "bfloat16"), (30, 1, "bfloat16"),
+    (30, 2, "bfloat16"), (30, 2, "float32")])
+def test_rows_of_no_whole_sublane_tile_read_by_table(monkeypatch, H, t,
+                                                     dtype):
+    """6 and 30 heads, each its own key head (Olmo-Hybrid's 30): a
+    slot's ``t * H`` rows are no whole sublane tiles (8 rows of
+    float32, 16 of bfloat16), so the wrapper rounds them up with zero
+    query rows and drops their output; the kernel's path is still
+    ``_attend``'s."""
+    _kernel_against_attend(monkeypatch, (H, H, 128, 128), t, dtype)
+
+
+@pytest.mark.parametrize("H, padded", [(6, True), (30, True), (8, False),
+                                       (16, False), (32, False)])
+def test_only_rows_of_no_whole_tile_are_padded(H, padded):
+    """Head counts that were whole tiles lower as they did: no pad,
+    no slice; 6 and 30 heads gain one of each."""
+    from deeplearning4j_tpu.ops import paged_attention as PA
+    S, P, ps, d = 2, 2, 16, 128
+    q = jnp.zeros((S, 2, H, d), jnp.bfloat16)
+    pool = jnp.zeros((S * P + 1, ps, H * d), jnp.bfloat16)
+    ints = jnp.zeros((S,), jnp.int32)
+    text = str(jax.make_jaxpr(lambda *a: PA.pallas_paged_attention_grouped(
+        *a, n_heads=H, n_kv_heads=H, interpret=True))(
+            q, pool, pool, jnp.zeros((S, P), jnp.int32), ints, ints))
+    before = text[:text.index("pallas_call")]
+    assert ("= pad[" in before) == padded
+    rows = PA._whole_tiles(2 * H, jnp.bfloat16)
+    assert (rows != 2 * H) == padded and rows % 16 == 0
+    assert f"bf16[{S},{rows},{d}]" in before
+
+
+def _kernel_against_attend(monkeypatch, heads, t, dtype):
     """``apply_stream_paged`` of a global layer, the kernel's path
     against the gather's, on one pool, table and chunk: a free slot, a
     slot with one token, slots that end on a page's last row and
@@ -622,10 +659,11 @@ def test_grouped_by_table_kernel_matches_attend(monkeypatch, heads, t,
     ({}, 16, "tpu", True), ({}, 16, "cpu", False), ({}, 4, "tpu", False),
     ({"window": 128}, 16, "tpu", False), ({"sink": True}, 16, "tpu", False),
     ({"v_head_dim": 96}, 16, "tpu", True),
-    ({"n_heads": 4}, 16, "tpu", False)],
+    ({"n_heads": 4}, 16, "tpu", True),
+    ({"n_heads": 2048}, 16, "tpu", False)],
     ids=["mimo_global", "off_a_tpu", "page_no_whole_tile", "window",
          "sink", "value_head_padded_to_a_lane_tile",
-         "rows_no_sublane_tile"])
+         "rows_rounded_to_a_sublane_tile", "rows_past_the_fast_memory"])
 def test_the_predicate_is_of_the_shapes_and_the_window(monkeypatch, changed,
                                                        page, backend,
                                                        want):
