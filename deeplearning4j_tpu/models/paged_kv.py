@@ -1044,6 +1044,18 @@ class PagedSlotSession:
                                                self._dtype)
             for i in self._aux_layers)
 
+    def experts_carry_rows(self, t: int) -> bool:
+        """Has the network expert layers, and does EVERY one of them
+        carry the ``slots * t`` rows of a step at ``t`` rows a slot on
+        weights its held experts' pass reads anyway (the block's
+        ``experts_carry_rows``)? One layer that pays for its rows sets
+        what the step's rows cost. The batcher asks, for the row
+        budget of its wide chunk program."""
+        return bool(self._aux_layers) and all(
+            self.net.layers[i].experts_carry_rows(self.slots * t,
+                                                  self._dtype)
+            for i in self._aux_layers)
+
     def _register_program(self, kind: str, t: int, jitted, args) -> None:
         """Tell ``observability.programs`` of a step program about to
         run on ``args`` for the first time, as ``<kind>/t=<rows a

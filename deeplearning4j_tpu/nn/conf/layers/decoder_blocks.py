@@ -150,6 +150,14 @@ class _DecoderBlock(MixerCacheLayer, BaseLayer):
         moe = self._ensure_parts()[1]
         return moe is not None and moe.takes_grouped_pass(rows, dtype)
 
+    def experts_carry_rows(self, rows: int, dtype) -> bool:
+        """Does a serving step of ``rows`` rows carry them through
+        this block's experts on weights the pass reads anyway
+        (``SparseExpertsLayer.carries_rows``)? The paged session asks,
+        for the batcher's wide chunk program."""
+        moe = self._ensure_parts()[1]
+        return moe is not None and moe.carries_rows(rows, dtype)
+
     def apply_stream_paged(self, params, pool, table, pos, x,
                            n_valid=None):
         h, pool, _ = self.apply_stream_paged_aux(

@@ -158,6 +158,15 @@ class SparseExpertsLayer(BaseLayer):
             rows, self.top_k, self.router_width, self.n_in,
             self.expert_width, dtype)
 
+    def carries_rows(self, rows: int, dtype) -> bool:
+        """Does a serving step of ``rows`` rows carry them on the
+        weights the held experts' pass reads anyway: the grouped pass,
+        at a row count where the kernel's time is still its weights'
+        (``ops.grouped_experts.weight_bound``)?"""
+        return (self.takes_grouped_pass(rows, dtype)
+                and grouped_experts.weight_bound(
+                    rows, self.n_in, self.expert_width))
+
     def apply_counted(self, params, x, active=None, stream=False):
         """(out, counts): ``counts`` (held,) int32, how many tokens
         each held expert served. ``active`` marks the rows that carry

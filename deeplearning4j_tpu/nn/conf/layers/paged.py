@@ -61,7 +61,8 @@ class PagedLayer:
     - ``stream_aux``: the step returns counts beside its output,
       through ``apply_stream_paged_aux(params, pool, table, pos, x,
       active=None, n_valid=None) -> (out, pool, counts)``; such a
-      layer also says ``experts_grouped(rows, dtype)``.
+      layer also says ``experts_grouped(rows, dtype)`` and
+      ``experts_carry_rows(rows, dtype)``.
 
     A block around a mixer is a ``MixerCacheLayer``; a decoder block
     is ``decoder_blocks._NormedBlock``'s fields, key, scope and

@@ -51,7 +51,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-__all__ = ["grouped_pass", "pallas_grouped_experts"]
+__all__ = ["grouped_pass", "pallas_grouped_experts", "weight_bound"]
 
 _F32 = jnp.float32
 # rows of an expert's group a tile: the MXU holds a 128 x 128 weight
@@ -70,6 +70,10 @@ _WIDTH_TILE = 512
 # bytes of fast memory the kernel may ask for (``_vmem_bytes``): a v5e
 # core has 128 MiB
 _VMEM_BUDGET = 96 << 20
+# the most 128 x 128 weight-tile passes the kernel's unrolled body may
+# spell out and still run in its weights' streaming time
+# (``weight_bound``): the largest count read flat
+_WEIGHT_BOUND_PASSES = 2048
 # the share of the held experts no row is expected to pick from which
 # the kernel pays under an MXU tile of rows (``grouped_pass``): three
 # times what it costs over the dense pass when every expert is hit
@@ -135,6 +139,42 @@ def grouped_pass(n: int, top_k: int, router_width: int, d: int, w: int,
             and _vmem_bytes(n, d, w, 2) <= _VMEM_BUDGET
             and (n > _ROW_TILE
                  or math.exp(-n * top_k / router_width) >= _UNHIT_SHARE))
+
+
+def _mxu_passes(n: int, d: int, w: int) -> int:
+    """The 128 x 128 weight-tile passes ``_kernel``'s body spells out
+    for a grid step at ``n`` rows: the body is unrolled over the row
+    tiles, and each tile's branch holds the gather (``sel @ x``:
+    ``n / 128`` by ``d / 128`` passes), the gate and up projections and
+    the down projection (``d / 128`` by a width tile's lanes each)."""
+    return (n // _ROW_TILE) * (d // 128) * (
+        n // 128 + 3 * (_width_tile(w) // 128))
+
+
+def weight_bound(n: int, d: int, w: int) -> bool:
+    """Is the kernel's time at ``n`` rows through experts of ``(d,
+    w)`` still the time its hit experts' weights take to stream, so
+    that rows up to ``n`` ride on weights the pass reads anyway? Past a
+    point the time TURNS: 55-90 us more an expert at once, whatever
+    the expert count and the width. Read, ms a call over the weights'
+    time at 819 GB/s (``tools/measure_expert_pass.py`` and the kernel
+    alone on made-up shapes, my chip runs, PR 46; PERF.md section 6
+    has the tables), the last rows flat / the first turned, by ``d``:
+    1024: 1,024 rows (1.35) / 1,536 (6.6); 2048: 768 (1.14) / 896
+    (3.6); 3072: 640 (1.21) / 768 (3.2); 4096: 512 (1.20) / 640 (2.3);
+    6144 and 7168: 256 (1.20, 1.29) / 384 (1.54, 1.83); 8192: 256
+    (1.18) / 384 (1.68). The kernel's ask of fast memory does NOT
+    order them (``_vmem_bytes`` 85 MiB flat at ``d`` 8192, 45 MiB
+    turned at 2048), nor do the rows' bytes (4 MiB flat at 4096 and
+    8192, 3.5 MiB turned at 2048). What does is the size of the
+    unrolled body, ``_mxu_passes``: every reading up to 2,048 passes
+    is flat (``mimo_v25_ep16`` at 512 rows is 2,048; ``lfm2_24b_a2b``
+    there 1,024) and every one from 2,128 turned (``longcat_ep32`` at
+    512 rows is 3,072), which reads like the body outgrowing the
+    core's instruction memory; that was not looked into, and a body
+    that loops over its row tiles would move the line (ROADMAP S15
+    (b))."""
+    return _mxu_passes(n, d, w) <= _WEIGHT_BOUND_PASSES
 
 
 def _groups(sel, comb):

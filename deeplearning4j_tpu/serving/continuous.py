@@ -15,9 +15,10 @@ slot feeds its next ``min(t, tokens left)`` prompt tokens, a slot in
 decode its one token, and the chunk that carries a prompt's last token
 emits the request's first output token; a step whose slots all decode
 is (slots, 1, 1). ``t`` follows from the pool (``chunk_width``), and a
-pool of many slots holds a second chunk program twice as wide
-(``wide_chunk_width``), which a step runs only when the prompt rows
-its slots have on offer would not fit the narrow one (``_plan_step``).
+pool of many slots holds a second, wider chunk program
+(``wide_chunk_width``, at the row budget ``wide_chunk_rows`` reads off
+the session), which a step runs only when the prompt rows its slots
+have on offer would not fit the narrow one (``_plan_step``).
 Over the dense session every step is (slots, 1, 1) and a prompt takes
 a step a token.
 
@@ -101,14 +102,30 @@ CHUNK_ROWS = 128
 # compute-bound on a v5e (197 TFLOP/s over 819 GB/s is 240 FLOP a byte,
 # and a row costs one FLOP a weight byte), so up to here rows ride on
 # weights the step reads anyway. A step at this width costs more than a
-# narrow one (an expert layer's dense pass puts every row through every
-# held expert), so it runs only when the rows on offer fill it
+# narrow one, so it runs only when the rows on offer fill it
 # (``_plan_step``). Read on the chip at 128 / 256 / 512 rows in every
 # chunk step of four serving cells (PERF.md section 6, PR 42): where
 # most slot-steps feed prompts 256 gives 1.28 / 1.11 / 1.05 times the
-# tokens/s of 128 for a step 1.21-1.30 times as long, and 512 less
-# than 128 in all four (a step 2.1-2.3 times as long).
+# tokens/s of 128 for a step 1.21-1.30 times as long. 512 read less
+# than 128 in all four THEN, under the expert layers' dense pass (every
+# row through every held expert: a step 2.1-2.3 times as long); it is
+# the budget of every pool whose experts still pay for a row.
 WIDE_CHUNK_ROWS = 256
+
+# The same where every expert layer of the session carries the rows on
+# weights it reads anyway (``PagedSlotSession.experts_carry_rows``: the
+# grouped pass over the selected pairs, whose time is its hit experts'
+# weights for as long as the kernel stays weight-bound,
+# ``ops.grouped_experts.weight_bound``). Read parent against change, two
+# pairs a cell (my chip runs, PR 46; PERF.md section 6):
+# ``lfm2_serve_agent`` 1,844 / 1,833 -> 2,367 / 2,364 tokens/s for a
+# step of 14.5 -> 15.4 ms (the kernel 1.604 ms a call where 1.599),
+# ``mimo_serve_mixedlen`` 2,634 / 2,683 -> 2,755 / 2,782 (attention and
+# the dense MLP grow with the rows there). ``longcat_serve_tooluse``,
+# whose kernel turns at 384 rows, read 1,069 -> 797 at this budget
+# (builders, PR 43) and keeps 256. Not read past 512: the next width,
+# 1,024 rows, is past the turn in every configuration.
+GROUPED_CHUNK_ROWS = 512
 
 _NON_FINITE = ("non-finite probabilities in decode step (device fault "
                "or poisoned model output)")
@@ -127,14 +144,31 @@ def chunk_width(slots: int, capacity: int,
     return t
 
 
-def wide_chunk_width(slots: int, capacity: int, page_size: int) -> int:
+def wide_chunk_rows(session, slots: int, capacity: int) -> int:
+    """The row budget of a pool's second chunk program, read off its
+    session: ``GROUPED_CHUNK_ROWS`` where the session says that every
+    expert layer carries the rows of the step that budget gives on
+    weights it reads anyway (``experts_carry_rows``), else
+    ``WIDE_CHUNK_ROWS``: a network without expert layers, one whose
+    experts take the dense pass at that width (off a TPU, float32, the
+    kernel over its fast memory) and one whose kernel's time turns
+    there."""
+    t = chunk_width(slots, capacity, GROUPED_CHUNK_ROWS)
+    return (GROUPED_CHUNK_ROWS if session.experts_carry_rows(t)
+            else WIDE_CHUNK_ROWS)
+
+
+def wide_chunk_width(slots: int, capacity: int, page_size: int,
+                     rows: Optional[int] = None) -> int:
     """The width of a pool's second chunk program, or 0 where it holds
-    none: ``chunk_width`` under ``WIDE_CHUNK_ROWS``, where that is
-    wider than the narrow program and the narrow one feeds a slot less
-    than a page a step (at 8 slots t is 16 already, and a prompt is a
-    small part of a request's steps)."""
+    none: ``chunk_width`` under ``rows`` (``WIDE_CHUNK_ROWS`` unless
+    given: the batcher gives ``wide_chunk_rows`` of its session), where
+    that is wider than the narrow program and the narrow one feeds a
+    slot less than a page a step (at 8 slots t is 16 already, and a
+    prompt is a small part of a request's steps)."""
     t_lo = chunk_width(slots, capacity)
-    t_hi = chunk_width(slots, capacity, WIDE_CHUNK_ROWS)
+    t_hi = chunk_width(slots, capacity,
+                       WIDE_CHUNK_ROWS if rows is None else rows)
     return t_hi if t_lo < min(t_hi, page_size) else 0
 
 
@@ -371,7 +405,10 @@ class ContinuousBatcher(ServingBackend):
             # chunk's rows keeps the one width
             if not self.session.unrolls_chunk_rows:
                 self._wide_t = wide_chunk_width(
-                    slots, widest, self.session.page_size)
+                    slots, widest, self.session.page_size,
+                    wide_chunk_rows(self.session, slots, widest))
+            self._steps.holds_chunk_rows(slots * self._chunk_t,
+                                         slots * self._wide_t)
         if self._wide_t:
             self._steps.holds_wide_program()
         # the widths whose program runs its expert layers as the
@@ -1274,10 +1311,11 @@ class ContinuousBatcher(ServingBackend):
         (a slot in decode 1 row, one in prefill up to the wide width
         of the rows it has left) would not fit in the narrow step's
         ``slots * t`` rows: at twice the width at least half of the
-        wide step's rows then do work. A decoding slot feeds its last
-        token: from ``out`` where it was delivered, else by
-        ``use_prev`` from the ids the step in flight leaves on the
-        device."""
+        wide step's rows then do work, at four times it (a pool whose
+        session says its expert layers carry the rows) a quarter. A
+        decoding slot feeds its last token: from ``out`` where it was
+        delivered, else by ``use_prev`` from the ids the step in
+        flight leaves on the device."""
         live = [(i, s) for i, s in enumerate(self._slots)
                 if s is not None and not s.parked]
         if not live:

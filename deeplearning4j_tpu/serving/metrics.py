@@ -252,7 +252,10 @@ class BatcherStepMetrics:
     device. A pool that holds a second, wider chunk program counts its
     steps under ``chunk`` too, and again in
     ``serving_wide_steps_total``, a series only such a pool has
-    (``holds_wide_program``). ``serving_moe_grouped_steps_total``
+    (``holds_wide_program``); the gauge ``serving_chunk_rows`` says,
+    by ``program`` (``narrow``, ``wide``), how many rows a step of
+    each chunk program the pool holds carries (``holds_chunk_rows``).
+    ``serving_moe_grouped_steps_total``
     counts the steps whose program runs its expert layers' held
     experts as the grouped pass over the selected pairs, a series
     only a session that holds such a program has
@@ -351,6 +354,21 @@ class BatcherStepMetrics:
             "serving_wide_steps_total",
             help="device steps that ran the wide chunk program",
             labels={"endpoint": self._name})
+
+    def holds_chunk_rows(self, narrow: int, wide: int) -> None:
+        """What the pool's chunk programs ARE, in rows a step (slots x
+        t): the gauge ``serving_chunk_rows`` by ``program``,
+        ``narrow`` and, where the pool holds a second one (``wide``
+        not 0), ``wide``."""
+        for program, rows in (("narrow", narrow), ("wide", wide)):
+            if rows:
+                # a set value, no callback: nothing of the batcher is
+                # held by the series
+                labels = {"endpoint": self._name, "program": program}
+                self._reg.gauge(
+                    "serving_chunk_rows",
+                    help="rows (slots x t) of a step of the pool's "
+                         "chunk program", labels=labels).set(rows)
 
     def holds_grouped_program(self) -> None:
         """Some step program of the batcher's session runs its expert

@@ -370,17 +370,22 @@ def test_shortcut_expert_block_step_compiles_for_v5e(topo, as_tpu, t):
     assert mem.temp_size_in_bytes < (60e6 if t == 8 else 50e6)
 
 
-@pytest.mark.parametrize("t", [4, 2, 1],
-                         ids=["wide_64x4", "chunk_64x2", "decode_64x1"])
-def test_grouped_query_window_cell_step_fits_v5e(topo, t):
+@pytest.mark.parametrize("t", [8, 4, 2, 1], ids=[
+    "wide_64x8_as_tpu", "wide_64x4", "chunk_64x2", "decode_64x1"])
+def test_grouped_query_window_cell_step_fits_v5e(topo, request, t):
     """The WHOLE id-returning step of the benchmark's
     ``mimo_serve_mixedlen`` cell (``PagedSlotSession._step_ids`` over
     the configuration's own network: 7 layers at the published widths
     in bfloat16, 64 slots of capacity 2,048, page 16), both step
     programs: global layers over the allocator's 8,193 pages, window
-    layers over 64 rings of 9 pages, in a chip's 16 GB."""
+    layers over 64 rings of 9 pages, in a chip's 16 GB. At t = 8, the
+    wide width on a TPU, the dispatch is the TPU's: two global layers
+    by table and every expert layer grouped at the 512 rows the
+    session says they carry."""
     from benchmark.harness import spec
     from deeplearning4j_tpu.models.paged_kv import PagedSlotSession
+    if t == 8:
+        request.getfixturevalue("as_tpu")
     cell = spec.load("mimo_serve_mixedlen")
     config, sv = cell.config, cell.traffic["server"]
     builder = spec.load_module("builders", config["builder"])
@@ -402,6 +407,8 @@ def test_grouped_query_window_cell_step_fits_v5e(topo, t):
         sds((slots,), jnp.int32), sds((slots, t, 1), jnp.float32),
         sds((slots,), jnp.int32), sds((slots,), jnp.int32),
         sds((slots,), bool)).compile()
+    assert sess.experts_carry_rows(t) == (t == 8)
+    assert _kernels_in(compiled) == (2 + len(sess._aux_layers)) * (t == 8)
     mem = compiled.memory_analysis()
     # 6.86 GB of weights, 0.67 GB of global pages, 0.24 GB of rings;
     # the pools are donated; the gathers of two global layers' whole
@@ -521,8 +528,8 @@ def test_short_conv_block_step_compiles_for_v5e(topo, t):
     assert len(between_the_projections(5)) == len(run)
 
 
-@pytest.mark.parametrize("t", [4, 2, 1],
-                         ids=["wide_64x4", "chunk_64x2", "decode_64x1"])
+@pytest.mark.parametrize("t", [8, 2, 1],
+                         ids=["wide_64x8", "chunk_64x2", "decode_64x1"])
 def test_short_conv_expert_cell_step_fits_v5e(topo, as_tpu, t):
     """The WHOLE id-returning step of the benchmark's
     ``lfm2_serve_agent`` cell (``PagedSlotSession._step_ids`` over the
@@ -559,10 +566,12 @@ def test_short_conv_expert_cell_step_fits_v5e(topo, as_tpu, t):
         sds((slots,), jnp.int32), sds((slots, t, 1), jnp.float32),
         sds((slots,), jnp.int32), sds((slots,), jnp.int32),
         sds((slots,), bool)).compile()
-    # two attention kernels, and at the wide width's 256 rows the
-    # grouped kernel of each of the eight expert layers
-    assert sess.runs_grouped_experts(t) == (t == 4)
-    assert _kernels_in(compiled) == 2 + 8 * (t == 4)
+    # two attention kernels, and at the wide width's 512 rows, which
+    # the session says every expert layer carries, the grouped kernel
+    # of each of the eight
+    assert sess.runs_grouped_experts(t) == (t == 8)
+    assert sess.experts_carry_rows(t) == (t == 8)
+    assert _kernels_in(compiled) == 2 + 8 * (t == 8)
     mem = compiled.memory_analysis()
     # 10.62 GB of weights and 0.81 GB of pages; the pools are donated;
     # the dense pass of 128 rows through 64 experts stays under 1 GB
@@ -690,7 +699,9 @@ def test_delta_rule_hybrid_cell_step_fits_v5e(topo, as_tpu, t):
     ids=["lfm2_24b_a2b", "mimo_v25_ep16"])
 def test_grouped_expert_pass_compiles_for_v5e(topo, as_tpu, d, w, routed,
                                               held, top_k):
-    """The expert layer of the wide step (64 slots x 4 = 256 rows) at
+    """The expert layer of the wide step (64 slots x 8 = 512 rows,
+    which the layer says it carries on weights it reads anyway; 256
+    rows too, the wide step of a pool that keeps that budget) at
     ``lfm2_24b_a2b``'s and ``mimo_v25_ep16``'s own widths in bfloat16,
     as a serving step calls it: the predicate admits it, the grouped
     kernel is in the compiled text under its own name, and nothing
@@ -714,17 +725,48 @@ def test_grouped_expert_pass_compiles_for_v5e(topo, as_tpu, d, w, routed,
                 jax.random.PRNGKey(0), InputType.recurrent(d))[0]))
     step = jax.jit(lambda p, x, a: layer.apply_tallied(p, x, a, True))
     texts = {}
-    for t in (4, 2):
-        assert layer.takes_grouped_pass(64 * t, bf16) == (t == 4)
+    for t in (8, 4, 2):
+        assert layer.takes_grouped_pass(64 * t, bf16) == (t >= 4)
+        assert layer.carries_rows(64 * t, bf16) == (t >= 4)
         texts[t] = step.lower(params, sds((64, t, d), bf16),
                               sds((64, t), bool)).compile().as_text()
-    assert texts[4].count("tpu_custom_call") == 1
-    assert "pallas_grouped_experts" in texts[4]
     assert "tpu_custom_call" not in texts[2]
-    moved = re.findall(r" (gather|scatter|sort|dynamic-slice|"
-                       r'dynamic-update-slice)\(.*op_name="[^"]*'
-                       r'moe/experts', texts[4])
-    assert not moved, moved
+    for t in (8, 4):
+        assert texts[t].count("tpu_custom_call") == 1
+        assert "pallas_grouped_experts" in texts[t]
+        moved = re.findall(r" (gather|scatter|sort|dynamic-slice|"
+                           r'dynamic-update-slice)\(.*op_name="[^"]*'
+                           r'moe/experts', texts[t])
+        assert not moved, (t, moved)
+
+
+@pytest.mark.parametrize("config, d, w, routed, top_k, slots, want", [
+    ("lfm2_24b_a2b", 2048, 1536, 64, 4, 64, 512),
+    ("mimo_v25_ep16", 4096, 2048, 256, 8, 64, 512),
+    ("longcat_ep32", 6144, 2048, 768, 12, 32, 256),
+    ("axk1_ep16", 7168, 2048, 192, 8, 64, 256)])
+def test_the_wide_budget_of_the_published_shapes(as_tpu, config, d, w,
+                                                 routed, top_k, slots,
+                                                 want):
+    """What an expert layer of each serving configuration's widths
+    says of the 512 rows a wide step would carry: ``lfm2_24b_a2b`` and
+    ``mimo_v25_ep16`` carry them; ``longcat_ep32`` runs them grouped
+    past the kernel's turn, and ``axk1_ep16`` dense (the kernel would
+    pass its fast memory), so both answer 256, where all four are
+    grouped and under the turn."""
+    from deeplearning4j_tpu.nn.conf.layers import SparseExpertsLayer
+    from deeplearning4j_tpu.serving.continuous import (
+        GROUPED_CHUNK_ROWS, WIDE_CHUNK_ROWS, chunk_width)
+    bf16 = jnp.bfloat16
+    layer = SparseExpertsLayer(n_in=d, n_routed_experts=routed,
+                               held=(0, 8), top_k=top_k, expert_width=w)
+    rows = slots * chunk_width(slots, 1024, GROUPED_CHUNK_ROWS)
+    assert rows == GROUPED_CHUNK_ROWS == 512
+    assert layer.carries_rows(rows, bf16) == (want == 512)
+    assert layer.takes_grouped_pass(rows, bf16) == (
+        config != "axk1_ep16")
+    assert layer.carries_rows(WIDE_CHUNK_ROWS, bf16)
+    assert not layer.carries_rows(rows, jnp.float32)
 
 
 # ---- four chips: the kernels on a mesh -----------------------------------

@@ -11,8 +11,11 @@ same ids. A pool of many slots holds a second chunk program twice as
 wide: which pools do, the rule that picks a step's width on hand-made
 pools either side of its turning point, an export at either width, and
 the three programs warm before the first token (the ids where widths
-alternate are held in tests/test_serve_lookahead.py). The session's
-own parity is in tests/chunk_parity.py."""
+alternate are held in tests/test_serve_lookahead.py). The wide
+program's row budget follows the session: what it says of hand-made
+expert layers, the widths that follow, the rule at t 2 / 8, and the
+gauge of the two widths. The session's own parity is in
+tests/chunk_parity.py."""
 
 import functools
 import json
@@ -316,38 +319,50 @@ def test_a_migration_offered_mid_prefill_resumes(net, four_rows,
 # the second, wider chunk program
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("slots, capacity, page, want", [
-    (64, 2048, 16, (2, 4)),     # lfm2_serve_agent
-    (64, 1024, 16, (2, 4)),     # axk1_serve_decode
-    (32, 1024, 16, (4, 8)),     # longcat_serve_tooluse
-    (64, 16, 16, (2, 4)),       # mimo_serve_mixedlen: a ring caps a
-                                # slot's rows at a page
-    (8, 1024, 16, (16, 0)),     # gpt2m_serve_closed: a page a step
-    (16, 1024, 16, (8, 16)),
-    (16, 1024, 8, (8, 0)),      # the narrow width is a page already
-    (128, 1024, 16, (1, 2)),
-    (256, 1024, 16, (1, 0)),
-    (4, 4, 4, (4, 0)),          # the benchmark's tiny presets
-    (64, 2, 16, (2, 0)),        # no wider than a slot
+@pytest.mark.parametrize("slots, capacity, page, rows, want", [
+    (64, 2048, 16, 256, (2, 4)),    # axk1_serve_decode (capacity 1024
+                                    # gives the same)
+    (64, 1024, 16, 256, (2, 4)),
+    (32, 1024, 16, 256, (4, 8)),    # longcat_serve_tooluse
+    (64, 16, 16, 256, (2, 4)),      # a ring caps a slot's rows at a
+                                    # page
+    (8, 1024, 16, 256, (16, 0)),    # gpt2m_serve_closed: a page a step
+    (16, 1024, 16, 256, (8, 16)),
+    (16, 1024, 8, 256, (8, 0)),     # the narrow width is a page already
+    (128, 1024, 16, 256, (1, 2)),
+    (256, 1024, 16, 256, (1, 0)),
+    (4, 4, 4, 256, (4, 0)),         # the benchmark's tiny presets
+    (64, 2, 16, 256, (2, 0)),       # no wider than a slot
+    (64, 2048, 16, 512, (2, 8)),    # lfm2_serve_agent
+    (64, 16, 16, 512, (2, 8)),      # mimo_serve_mixedlen: under its
+                                    # ring's page
+    (64, 4, 16, 512, (2, 4)),       # a page of 4 caps the ring at 4
+    (32, 1024, 16, 512, (4, 16)),
+    (8, 1024, 16, 512, (16, 0)),    # a page a step already
+    (128, 1024, 16, 512, (1, 4)),
+    (4, 4, 4, 512, (4, 0)),
 ], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
-def test_the_widths_follow_the_pool(slots, capacity, page, want):
-    """(t_lo, t_hi) at the budgets of 128 and 256 rows; 0: the pool
-    holds no wide program."""
+def test_the_widths_follow_the_pool(slots, capacity, page, rows, want):
+    """(t_lo, t_hi) at the budgets of 128 and of ``rows``, the
+    session's 256 or 512; 0: the pool holds no wide program."""
     assert (chunk_width(slots, capacity),
-            wide_chunk_width(slots, capacity, page)) == want
-    # the wide width is the same function under the wide budget
-    assert want[1] in (0, chunk_width(slots, capacity,
-                                      continuous.WIDE_CHUNK_ROWS))
+            wide_chunk_width(slots, capacity, page, rows)) == want
+    # the wide width is the same function under the wide budget, and
+    # 256 is what is taken where no session is asked
+    assert want[1] in (0, chunk_width(slots, capacity, rows))
+    if rows == continuous.WIDE_CHUNK_ROWS:
+        assert wide_chunk_width(slots, capacity, page) == want[1]
 
 
-def _block_net(block):
-    conf = (NeuralNetConfiguration.builder().set_seed(0)
-            .updater(updaters.sgd(0.0)).list()
-            .layer(EmbeddingSequenceLayer(n_in=V, n_out=16))
-            .layer(block)
-            .layer(RnnOutputLayer(n_out=V, loss="mcxent"))
-            .set_input_type(InputType.recurrent(V, 256)).build())
-    return MultiLayerNetwork(conf).init()
+def _block_net(*blocks, width=16):
+    b = (NeuralNetConfiguration.builder().set_seed(0)
+         .updater(updaters.sgd(0.0)).list()
+         .layer(EmbeddingSequenceLayer(n_in=V, n_out=width)))
+    for block in blocks:
+        b = b.layer(block)
+    return MultiLayerNetwork(
+        b.layer(RnnOutputLayer(n_out=V, loss="mcxent"))
+        .set_input_type(InputType.recurrent(V, 256)).build()).init()
 
 
 @pytest.mark.parametrize("kind, page, want", [
@@ -377,15 +392,114 @@ def test_the_session_says_what_caps_the_widths(kind, page, want):
         cb.shutdown(drain=True)
 
 
+def _expert_blocks(*widths):
+    """A network of width 128 (a lane tile) with one short-convolution
+    block a width: experts of that width, or none at 0."""
+    return _block_net(*(
+        ShortConvDecoderBlock(n_routed_experts=8, top_k=2,
+                              expert_width=w) if w
+        else ShortConvDecoderBlock() for w in widths), width=128)
+
+
+@pytest.fixture
+def a_small_turn(monkeypatch):
+    """A TPU is asked for, and the kernel's turn is moved down between
+    the sizes of its unrolled body at 512 rows of width 128 through
+    experts 128 wide (28 passes) and 512 wide (64)."""
+    import jax
+    from deeplearning4j_tpu.ops import grouped_experts
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    size = lambda w: grouped_experts._mxu_passes(512, 128, w)
+    assert size(128) < 40 < size(512)
+    monkeypatch.setattr(grouped_experts, "_WEIGHT_BOUND_PASSES", 40)
+
+
+@pytest.mark.parametrize("case, widths, dtype, slots, want", [
+    ("no_experts", (0, 0), "bfloat16", 64, 256),
+    ("dense_in_float32", (128,), "float32", 64, 256),
+    ("dense_where_rows_make_no_tiles", (128,), "bfloat16", 40, 256),
+    ("grouped_under_the_turn", (128, 0, 128), "bfloat16", 64, 512),
+    ("grouped_at_32_slots", (128,), "bfloat16", 32, 512),
+    ("grouped_over_the_turn", (512,), "bfloat16", 64, 256),
+    ("one_layer_of_three_over_it", (128, 512, 128), "bfloat16", 64, 256),
+])
+def test_the_session_says_what_a_wide_step_may_carry(
+        a_small_turn, case, widths, dtype, slots, want):
+    """``wide_chunk_rows`` off ``PagedSlotSession.experts_carry_rows``:
+    512 only where the network has expert layers and EVERY one runs
+    the grouped pass at the rows the 512 budget gives the pool, under
+    the kernel's turn."""
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.models.paged_kv import PagedSlotSession
+    sess = PagedSlotSession(_expert_blocks(*widths), slots, 256, 16,
+                            dtype=jnp.dtype(dtype))
+    t = chunk_width(slots, 256, continuous.GROUPED_CHUNK_ROWS)
+    assert sess.experts_carry_rows(t) == (want == 512)
+    assert continuous.wide_chunk_rows(sess, slots, 256) == want
+
+
+def test_off_a_tpu_every_session_keeps_256():
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.models.paged_kv import PagedSlotSession
+    sess = PagedSlotSession(_expert_blocks(128), 64, 256, 16,
+                            dtype=jnp.bfloat16)
+    assert not sess.experts_carry_rows(8)
+    assert continuous.wide_chunk_rows(sess, 64, 256) == 256
+
+
+@pytest.mark.parametrize("widths, window, want", [
+    ((128,), None, (2, 8)), ((512,), None, (2, 4)),
+    ((128,), 8, (2, 8))])
+def test_the_batcher_takes_the_sessions_rows(a_small_turn, widths,
+                                             window, want):
+    """The batcher's two widths at 64 slots, and the gauge that says
+    what they are in rows; a ring's page of 16 still holds t = 8."""
+    import jax.numpy as jnp
+    net = _expert_blocks(*widths)
+    if window:
+        net = _block_net(GroupedQueryDecoderBlock(
+            window=window, n_routed_experts=8, top_k=2,
+            expert_width=widths[0]), width=128)
+    cb = ContinuousBatcher(net, slots=64, capacity=256, page_size=16,
+                           kv_mode="paged", metrics=ServingMetrics(),
+                           name="rows", dtype=jnp.bfloat16)
+    try:
+        assert (cb._chunk_t, cb._wide_t) == want
+        snap = cb.metrics.registry.snapshot()
+        rows = lambda program: snap[
+            f'serving_chunk_rows{{endpoint="rows",program="{program}"}}']
+        assert (rows("narrow"), rows("wide")) == (128, 64 * want[1])
+    finally:
+        cb.shutdown(drain=True)
+
+
+def test_the_gauge_has_no_wide_series_where_no_wide_program():
+    m = ServingMetrics()
+    m.batcher_steps("one").holds_chunk_rows(128, 0)
+    m.batcher_steps("two").holds_chunk_rows(128, 512)
+    snap = m.registry.snapshot()
+    series = {k: v for k, v in snap.items() if "serving_chunk_rows" in k}
+    assert series == {
+        'serving_chunk_rows{endpoint="one",program="narrow"}': 128,
+        'serving_chunk_rows{endpoint="two",program="narrow"}': 128,
+        'serving_chunk_rows{endpoint="two",program="wide"}': 512}
+
+
 WIDE_SLOTS, T_LO, T_HI = 16, 2, 4
+
+
+def _pin_widths(monkeypatch, t_hi):
+    """Row budgets that give 16 slots chunks of 2 and of ``t_hi``
+    tokens, whatever the session says of its experts."""
+    monkeypatch.setattr(continuous, "CHUNK_ROWS", WIDE_SLOTS * T_LO)
+    for budget in ("WIDE_CHUNK_ROWS", "GROUPED_CHUNK_ROWS"):
+        monkeypatch.setattr(continuous, budget, WIDE_SLOTS * t_hi)
 
 
 @pytest.fixture
 def two_widths(monkeypatch):
     """Row budgets that give 16 slots chunks of 2 and of 4 tokens."""
-    monkeypatch.setattr(continuous, "CHUNK_ROWS", WIDE_SLOTS * T_LO)
-    monkeypatch.setattr(continuous, "WIDE_CHUNK_ROWS",
-                        WIDE_SLOTS * T_HI)
+    _pin_widths(monkeypatch, T_HI)
 
 
 def _gated(net, name):
@@ -430,11 +544,10 @@ POOLS = {
 }
 
 
-@pytest.mark.parametrize("pool", list(POOLS))
-def test_the_plan_goes_wide_when_the_rows_on_offer_fill_it(
-        net, two_widths, pool):
-    made, want_rows = POOLS[pool]
-    b = _gated(net, "plan")
+def _check_plan(b, made, want_rows, t_lo, t_hi):
+    """``_plan_step`` over slots made by hand is the rule, written
+    out: the wide step where what it would feed fills the narrow
+    one's rows."""
     cb = b.cb
     try:
         cb._slots = [_hand_made(**kw) for kw in made] + [None] * (
@@ -443,11 +556,10 @@ def test_the_plan_goes_wide_when_the_rows_on_offer_fill_it(
         need = [1 if kw.get("decoding") else
                 1 + kw["left"] - int(kw.get("export", False))
                 for kw in made]
-        # the rule, written out
-        offered = sum(min(T_HI, n) for n in need)
+        offered = sum(min(t_hi, n) for n in need)
         assert want_rows == (
             1 if not any(kw.get("left") for kw in made) else
-            T_HI if offered >= WIDE_SLOTS * T_LO else T_LO)
+            t_hi if offered >= WIDE_SLOTS * t_lo else t_lo)
         assert st.x.shape == (WIDE_SLOTS, want_rows, 1)
         # each slot feeds what it has, up to the step's width: an
         # export stops one token short at either width
@@ -460,6 +572,52 @@ def test_the_plan_goes_wide_when_the_rows_on_offer_fill_it(
     finally:
         cb._slots = [None] * WIDE_SLOTS
         assert b.close()
+
+
+@pytest.mark.parametrize("pool", list(POOLS))
+def test_the_plan_goes_wide_when_the_rows_on_offer_fill_it(
+        net, two_widths, pool):
+    made, want_rows = POOLS[pool]
+    _check_plan(_gated(net, "plan"), made, want_rows, T_LO, T_HI)
+
+
+T_8 = 8
+
+# the same 16 slots at t 2 and 8, the widths of a 512-row pool of 64:
+# the wide step still runs from 32 rows on offer, and then carries up
+# to 128
+POOLS_OF_8 = {
+    "4_of_16_in_prefill": ([_LONG] * 4 + [_DECODE] * 7, T_8),     # 39
+    "4_alone": ([_LONG] * 4, T_8),                                # 32
+    "3_and_7_decoding": ([_LONG] * 3 + [_DECODE] * 7, T_LO),      # 31
+    "3_and_8_decoding": ([_LONG] * 3 + [_DECODE] * 8, T_8),       # 32
+    "tails_of_3": ([dict(left=2)] * 10, T_LO),                    # 30
+    "tails_of_3_fill_it": ([dict(left=2)] * 10 + [_DECODE] * 2,
+                           T_8),                                  # 32
+    "exports_of_8": ([dict(left=8, export=True)] * 4, T_8),       # 32
+    "exports_of_7": ([dict(left=7, export=True)] * 4
+                     + [_DECODE] * 3, T_LO),                      # 31
+    "all_decode": ([_DECODE] * 16, 1),
+}
+
+
+@pytest.mark.parametrize("pool", list(POOLS_OF_8) + ["a_ring_of_4"])
+def test_the_plan_at_a_quarter_of_the_wide_width(net, monkeypatch, pool):
+    """The rule is the same at t 2 / 8: it asks whether the narrow
+    step's rows are filled, so a wide step a quarter full runs. A
+    ring's page of 4 caps the wide width at 4 under the same
+    budgets."""
+    _pin_widths(monkeypatch, T_8)
+    if pool == "a_ring_of_4":
+        b = Batcher(_block_net(GroupedQueryDecoderBlock(window=8)),
+                    "ring", slots=WIDE_SLOTS, queue_limit=256)
+        assert b.cb.session.chunk_rows_max == PS
+        assert (b.cb._chunk_t, b.cb._wide_t) == (T_LO, PS)
+        return _check_plan(b, [_LONG] * 8, PS, T_LO, PS)          # 32
+    b = Batcher(net, "plan8", slots=WIDE_SLOTS, queue_limit=256)
+    assert (b.cb._chunk_t, b.cb._wide_t) == (T_LO, T_8)
+    made, want_rows = POOLS_OF_8[pool]
+    _check_plan(b, made, want_rows, T_LO, T_8)
 
 
 @pytest.mark.parametrize("n_requests, wide", [(3, False), (10, True)])
@@ -654,21 +812,27 @@ def test_the_grouped_steps_reader_over_two_snapshots():
                       "mimo_serve_mixedlen", "lfm2_serve_agent"]}
 
 
-@pytest.mark.parametrize("cell, reading", [
-    ("mimo_serve_mixedlen", 2), ("lfm2_serve_agent", 2),
-    ("axk1_serve_decode", 8), ("longcat_serve_tooluse", 4)])
+@pytest.mark.parametrize("cell, reading, widths", [
+    ("mimo_serve_mixedlen", 2, (2, 8)), ("lfm2_serve_agent", 2, (2, 8)),
+    ("axk1_serve_decode", 8, (2, 4)),
+    ("longcat_serve_tooluse", 4, (4, 8))])
 def test_the_paged_kernels_admit_the_wide_width(monkeypatch, cell,
-                                                reading):
-    """At the wide width every attention layer of the cell's published
-    shapes that reads its pages by table at the narrow one still does
-    (the predicates of ``ops.paged_attention``: the tile conditions
-    and ``_vmem_bytes`` within ``_VMEM_BUDGET``; the backend asked for
-    a TPU here): a silent fall back to the gather would cost the wide
-    steps what the by-table kernels won. ``reading``: the layers that
-    read by table (MiMo's window layers keep their rings)."""
+                                                reading, widths):
+    """The widths the cell's pool takes on a TPU (512 rows where every
+    expert layer of the published shapes carries them: ``lfm2_24b_a2b``
+    and ``mimo_v25_ep16``; 256 for ``axk1_ep16``, whose kernel would
+    pass its fast memory, and ``longcat_ep32``, whose kernel's time
+    turns), and at the wide one every attention layer that reads its
+    pages by table at the narrow one still does (the predicates of
+    ``ops.paged_attention``: the tile conditions and ``_vmem_bytes``
+    within ``_VMEM_BUDGET``; the backend asked for a TPU here): a
+    silent fall back to the gather would cost the wide steps what the
+    by-table kernels won. ``reading``: the layers that read by table
+    (MiMo's window layers keep their rings)."""
     import jax
     import jax.numpy as jnp
     from benchmark.harness import spec
+    from deeplearning4j_tpu.models.paged_kv import PagedSlotSession
     from deeplearning4j_tpu.nn.conf.layers.paged import PagedLayer
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     c = spec.load(cell)
@@ -680,9 +844,17 @@ def test_the_paged_kernels_admit_the_wide_width(monkeypatch, cell,
         sv["page_size"] for layer in net.layers
         if isinstance(layer, PagedLayer)
         and layer.paged_cache(sv["page_size"]).ring_pages])
+    # the session's statement without its pools (gigabytes of zeros)
+    sess = object.__new__(PagedSlotSession)
+    sess.net, sess.slots, sess._dtype = net, sv["slots"], jnp.bfloat16
+    sess._aux_layers = [i for i, layer in enumerate(net.layers)
+                        if getattr(layer, "stream_aux", False)]
+    assert sess._aux_layers
     t_lo = chunk_width(sv["slots"], cap)
-    t_hi = wide_chunk_width(sv["slots"], cap, sv["page_size"])
-    assert t_hi == 2 * t_lo
+    t_hi = wide_chunk_width(
+        sv["slots"], cap, sv["page_size"],
+        continuous.wide_chunk_rows(sess, sv["slots"], cap))
+    assert (t_lo, t_hi) == widths
     by_table = [layer for layer in net.layers
                 if hasattr(layer, "paged_reads_by_table")
                 and layer.paged_reads_by_table(sv["page_size"], t_lo,

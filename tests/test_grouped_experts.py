@@ -208,6 +208,38 @@ def test_the_predicate_is_of_the_shapes(monkeypatch, backend, rows, dtype,
                                         jnp.dtype(dtype)) is want
 
 
+@pytest.mark.parametrize("rows, d, w, want", [
+    (512, 2048, 1536, True),        # lfm2_24b_a2b: 1,024 passes
+    (768, 2048, 1536, True),        # 1,728, the last read flat there
+    (896, 2048, 1536, False),       # 2,128, the first read turned
+    (512, 4096, 2048, True),        # mimo_v25_ep16: 2,048, read flat
+    (640, 4096, 2048, False),       # 2,720, read turned
+    (256, 6144, 2048, True),        # longcat_ep32: 1,344
+    (384, 6144, 2048, False),       # 2,160, read turned
+    (512, 6144, 2048, False),       # 3,072
+    (256, 7168, 2048, True),        # axk1_ep16: 1,568
+    (512, 7168, 2048, False),       # 3,584 (and past the fast memory)
+    (256, 8192, 2048, True),        # 1,792, read flat at 85 MiB asked
+    (1024, 1024, 2048, True),       # 1,280
+    (1536, 1024, 2048, False),      # 2,304, read turned
+])
+def test_the_kernel_says_where_its_time_turns(monkeypatch, rows, d, w,
+                                              want):
+    """``weight_bound`` is of the unrolled body's size, every shape
+    here as it was read on the chip (PERF.md section 6, PR 46), and the
+    layer's ``carries_rows`` is it where the pass is grouped at all:
+    nowhere off a TPU or in float32."""
+    bf16 = jnp.dtype("bfloat16")
+    assert grouped_experts.weight_bound(rows, d, w) is want
+    layer = SparseExpertsLayer(n_in=d, expert_width=w,
+                               n_routed_experts=64, held=(0, 8), top_k=4)
+    assert not layer.carries_rows(rows, bf16)            # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert layer.carries_rows(rows, bf16) is (
+        want and layer.takes_grouped_pass(rows, bf16))
+    assert not layer.carries_rows(rows, jnp.dtype("float32"))
+
+
 def test_apply_keeps_the_dense_pass_under_grad(monkeypatch):
     """``apply``, which ``fit`` differentiates, never reaches the
     kernel (a Mosaic call without a VJP), whatever the predicate

@@ -1,25 +1,30 @@
 #!/usr/bin/env python3
 """A serving cell read at several row budgets of the chunked-prefill
-step (``deeplearning4j_tpu.serving.continuous.CHUNK_ROWS`` and
-``WIDE_CHUNK_ROWS``: a slot in prefill feeds up to t tokens a step,
-slots * t <= rows, and a pool may hold a second program at the wide
-budget, run only in the steps whose prompt rows fill it):
+step (``deeplearning4j_tpu.serving.continuous.CHUNK_ROWS``,
+``WIDE_CHUNK_ROWS`` and ``GROUPED_CHUNK_ROWS``: a slot in prefill
+feeds up to t tokens a step, slots * t <= rows, and a pool may hold a
+second program at a wide budget, run only in the steps whose prompt
+rows fill it):
 
     python3 tools/measure_chunk_rows.py <workload> \\
-        <seconds> <seed> <rows>[:<wide rows>] [...]
+        <seconds> <seed> auto|<rows>[:<wide rows>] [...]
 
-``<rows>`` alone sets both budgets to it (one chunk program at that
-width in every chunk step, as before PR 42), ``128:256`` is the
-program's own pair. For each one untraced window through the driver
-itself, in one process (one set-up of the chip, the weights made anew
-each time), and one JSON line: the budgets, the widths ``t_lo`` and
-``t_hi`` the batcher gave the cell's pool (``t_hi`` 0: no wide
-program), the driver's result, and the batcher's own counters over
-the window (the readers of benchmark/layer_metrics that need no
-trace; ``wide_steps_pct.serve`` is the wide steps' share). The program
-has no option for the budgets; this script sets the module's
-constants, which is how PERF.md's readings were taken. A budget of 1
-is token-by-token prefill.
+``auto`` leaves the program's own choice: 128 rows, and the wide
+budget ``continuous.wide_chunk_rows`` reads off the cell's session
+(256, or 512 where every expert layer carries the rows). ``<rows>``
+alone sets every budget to it (one chunk program at that width in
+every chunk step, as before PR 42); ``128:512`` forces the pair,
+whatever the session says. For each one untraced window through the
+driver itself, in one process (one set-up of the chip, the weights
+made anew each time), and one JSON line: the budgets as asked, the
+widths ``t_lo`` and ``t_hi`` the batcher gave the cell's pool
+(``t_hi`` 0: no wide program) and the rows a step of each carries,
+the driver's result, and the batcher's own counters over the window
+(the readers of benchmark/layer_metrics that need no trace;
+``wide_steps_pct.serve`` is the wide steps' share). The program has no
+option for the budgets; this script sets the module's constants, which
+is how PERF.md's readings were taken. A budget of 1 is token-by-token
+prefill.
 """
 
 import json
@@ -52,8 +57,15 @@ def main(workload, seconds, seed, budgets):
         return warm(cb)
 
     continuous.ContinuousBatcher._warm_programs = noting
-    for rows, wide in budgets:
-        continuous.CHUNK_ROWS, continuous.WIDE_CHUNK_ROWS = rows, wide
+    own = (continuous.CHUNK_ROWS, continuous.WIDE_CHUNK_ROWS,
+           continuous.GROUPED_CHUNK_ROWS)
+    for budget in budgets:
+        forced = budget != "auto"
+        rows, wide = budget if forced else (own[0], "auto")
+        # a forced wide budget is both of the session's answers
+        (continuous.CHUNK_ROWS, continuous.WIDE_CHUNK_ROWS,
+         continuous.GROUPED_CHUNK_ROWS) = (
+            (rows, wide, wide) if forced else own)
         s = session.Session(cell, seed, seconds, 0, time.perf_counter())
         result = driver.run(s)
         read = {}
@@ -61,9 +73,12 @@ def main(workload, seconds, seed, budgets):
             v = spec.load_module("layer_metrics", name).read(s.obs)
             if v is not None:
                 read[name] = v
+        t_lo, t_hi = widths[-1]
+        slots = cell.traffic["server"]["slots"]
         print(json.dumps({
-            "rows": rows, "wide_rows": wide, "t_lo": widths[-1][0],
-            "t_hi": widths[-1][1], "seed": seed,
+            "rows": rows, "wide_rows": wide, "t_lo": t_lo,
+            "t_hi": t_hi, "step_rows": [slots * t_lo, slots * t_hi],
+            "seed": seed,
             "correct": result["correct"], "failed": result["failed"],
             "checks": {c["name"]: c["value"] for c in s.checks},
             "metrics": {k: v["value"]
@@ -74,5 +89,6 @@ def main(workload, seconds, seed, budgets):
 
 if __name__ == "__main__":
     main(sys.argv[1], float(sys.argv[2]), int(sys.argv[3]),
-         [tuple(int(r) for r in (a.split(":") * 2)[:2])
+         [a if a == "auto" else
+          tuple(int(r) for r in (a.split(":") * 2)[:2])
           for a in sys.argv[4:]])
