@@ -39,6 +39,17 @@ side on the minor axis, the fewest that make it whole lane tiles
 bytes held and moved). Every per-head value is spread over its head's
 ``dv`` lanes by a select (``_spread``), which fuses into the pass over
 the state; nothing of the state's size is ever transposed.
+
+The serving step's write needs the result of its reads, so in
+``jax.numpy`` the pool crosses the memory three times a step (XLA makes
+one fusion of the reads and a second, which reads the pool again, of
+the write). On a TPU, for the shapes ``ops.delta_state
+.delta_state_pass`` admits, the step between the convolutions and the
+gated norm is one kernel (``pallas_delta_state``) that holds a slot's
+tile in fast memory between the reads and the write: the pool is read
+once and written once. The ``jax.numpy`` form is that kernel's oracle
+and the path everywhere else (the CPU, a ``p`` with no whole lane
+tile, ``apply``, ``fit``).
 """
 
 from __future__ import annotations
@@ -59,6 +70,7 @@ from deeplearning4j_tpu.nn.conf.layers.base import (BaseLayer,
 from deeplearning4j_tpu.nn.conf.layers.paged import (STATE, PagedCache,
                                                      PagedLayer)
 from deeplearning4j_tpu.nn.conf.layers.state_space import carried_window
+from deeplearning4j_tpu.ops import delta_state
 
 __all__ = ["GatedDeltaMixerLayer"]
 
@@ -308,10 +320,14 @@ class GatedDeltaMixerLayer(PagedLayer, BaseLayer):
           S_t = G_t S_0 + sum_j (G_t / G_j) k_j u_j^T
 
         A row past ``n_valid`` has ``alpha = 1``, ``beta = 0`` and
-        changes nothing. Every product is elementwise in float32; the
-        2 t reductions over ``S_0`` are each over the state's own
-        shape, so that XLA may fuse them into one pass over the pool,
-        and the write is a second."""
+        changes nothing. Every product is elementwise in float32.
+        Where ``delta_state_pass`` admits the pool (a TPU, a float32
+        state of whole lane tiles) the three lines are one kernel
+        over a tile held in fast memory, the pool read once and
+        written once; elsewhere they are ``jax.numpy``: the 2 t
+        reductions over ``S_0`` are each over the state's own shape,
+        so that XLA fuses them into one pass over the pool, and the
+        write, which needs their result, is a second."""
         S, t, _ = x.shape
         if n_valid is None:
             n_valid = jnp.where(table[:, 0] > 0, t, 0)
@@ -327,41 +343,53 @@ class GatedDeltaMixerLayer(PagedLayer, BaseLayer):
             q, k, v, xs = self._conv(params, window, u)
             valid = (jnp.arange(t)[None, :] < n_valid[:, None])[..., None]
             alpha, beta = self._gates(params, a, b)
-            al = self._spread(jnp.where(valid, alpha, 1.0))  # (S,t,H/p,W)
-            be = self._spread(jnp.where(valid, beta, 0.0))
-            kx = [self._spread(k[:, i], 1) for i in range(t)]
-            read = lambda m: jnp.where(
-                restart, 0.0, jnp.sum(state * m, axis=-2))
-            # (k_j . k_i) and (k_j . q_i) of every pair of rows at
-            # [:, j, i], over their head's lanes
-            pairs = lambda m: self._spread(jnp.sum(
-                k[:, :, None] * m[:, None], axis=-1))   # (S,t,t,H/p,W)
-            kk, kq = pairs(k), pairs(q)
-            # row by row; what the rows before left is one sum over
-            # them. G = G_i and since[:, j] = G_i / G_j, the alphas
-            # after row j up to row i
-            one = jnp.ones_like(al[:, :1])
-            G, since, us, os = 1.0, None, [], []
-            for i in range(t):
-                G = G * al[:, i]
-                since = one if since is None else jnp.concatenate(
-                    [since * al[:, i, None], one], axis=1)
-                u_i = v[:, i] - G * read(kx[i])
-                if i:
-                    u_i = u_i - jnp.sum(since[:, :i] * kk[:, :i, i]
-                                        * jnp.stack(us, axis=1), axis=1)
-                us.append(be[:, i] * u_i)
-                os.append(G * read(self._spread(q[:, i], 1)) + jnp.sum(
-                    since * kq[:, :i + 1, i] * jnp.stack(us, axis=1),
-                    axis=1))
-            new = jnp.where(restart[..., None], 0.0,
-                            G[:, :, None] * state)
-            for j in range(t):
-                new = new + (since[:, j] * us[j])[:, :, None] * kx[j]
             # the window the next step finds: the K - 1 inputs before
             # row n_valid, its own where the slot fed nothing
-            pool = {"state": jnp.where(fed[:, None, None, None], new,
-                                       state),
-                    "conv": carried_window(pool["conv"], xs, n_valid)}
-            y = self._gate_norm(params, jnp.stack(os, axis=1), z)
+            after = lambda new: {"state": new, "conv": carried_window(
+                pool["conv"], xs, n_valid)}
+            if delta_state.delta_state_pass(*state.shape, t, state.dtype):
+                masked = lambda x, off: jnp.where(valid, x, off)
+                # the rows' products a head, (S,t,t,H) at [:, j, i]
+                pairs = lambda m: jnp.sum(
+                    k[:, :, None] * m[:, None], axis=-1)
+                o, new = delta_state.pallas_delta_state(
+                    state, k, q, v, masked(alpha, 1.0), masked(beta, 0.0),
+                    pairs(k), pairs(q), fresh, fed)
+                pool = after(new)
+            else:
+                al = self._spread(jnp.where(valid, alpha, 1.0))  # (S,t,H/p,W)
+                be = self._spread(jnp.where(valid, beta, 0.0))
+                kx = [self._spread(k[:, i], 1) for i in range(t)]
+                read = lambda m: jnp.where(
+                    restart, 0.0, jnp.sum(state * m, axis=-2))
+                # (k_j . k_i) and (k_j . q_i) of every pair of rows at
+                # [:, j, i], over their head's lanes
+                pairs = lambda m: self._spread(jnp.sum(
+                    k[:, :, None] * m[:, None], axis=-1))   # (S,t,t,H/p,W)
+                kk, kq = pairs(k), pairs(q)
+                # row by row; what the rows before left is one sum over
+                # them. G = G_i and since[:, j] = G_i / G_j, the alphas
+                # after row j up to row i
+                one = jnp.ones_like(al[:, :1])
+                G, since, us, os = 1.0, None, [], []
+                for i in range(t):
+                    G = G * al[:, i]
+                    since = one if since is None else jnp.concatenate(
+                        [since * al[:, i, None], one], axis=1)
+                    u_i = v[:, i] - G * read(kx[i])
+                    if i:
+                        u_i = u_i - jnp.sum(since[:, :i] * kk[:, :i, i]
+                                            * jnp.stack(us, axis=1), axis=1)
+                    us.append(be[:, i] * u_i)
+                    os.append(G * read(self._spread(q[:, i], 1)) + jnp.sum(
+                        since * kq[:, :i + 1, i] * jnp.stack(us, axis=1),
+                        axis=1))
+                new = jnp.where(restart[..., None], 0.0,
+                                G[:, :, None] * state)
+                for j in range(t):
+                    new = new + (since[:, j] * us[j])[:, :, None] * kx[j]
+                pool = after(jnp.where(fed[:, None, None, None], new,
+                                       state))
+                o = jnp.stack(os, axis=1)
+            y = self._gate_norm(params, o, z)
         return y @ params["Wo"], pool

@@ -17,6 +17,20 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture
+def own_programs():
+    """``observability.programs``' registry is the process's: a step
+    program an earlier test of this worker registered and never built
+    is built by the next ``scope_tables()``, and one registered for a
+    step that raised while tracing raises again there. A test that
+    reads the registry, or leaves such a program in it, starts from an
+    empty one and leaves an empty one."""
+    from deeplearning4j_tpu.observability import programs
+    programs.PROGRAMS.clear()
+    yield programs
+    programs.PROGRAMS.clear()
+
+
 # ---------------------------------------------------------------------------
 # test tiering: smoke (`pytest -m "not slow"`) vs full. Heavy files are
 # marked wholesale; a few heavyweight classes are marked in place.

@@ -583,7 +583,7 @@ def test_short_conv_expert_cell_step_fits_v5e(topo, as_tpu, t):
 
 
 @pytest.mark.parametrize("t", [2, 1], ids=["chunk_64x2", "decode_64x1"])
-def test_delta_rule_block_step_compiles_for_v5e(topo, t):
+def test_delta_rule_block_step_compiles_for_v5e(topo, as_tpu, t):
     """``DeltaRuleDecoderBlock.apply_stream_paged`` at the widths of
     the benchmark's ``olmo_hybrid_serve_reason`` cell (hidden 3840, 30
     heads of 96 x 192, convolutions of 4, MLP of 11008, the norms
@@ -591,15 +591,29 @@ def test_delta_rule_block_step_compiles_for_v5e(topo, t):
     programs. The float32 state pool is (64, 15, 96, 384), two heads
     side by side: whole lane tiles, so the device holds the row's
     2,211,840 B as counted ((64, 30, 96, 192) would be held 96 x 256 a
-    head, a third more). It is donated and updated in place, and TWO
-    instructions of the compiled step read it, whatever t: the 2 t
-    reductions ``S^T k`` / ``S^T q`` in one pass over the pool, and
-    the write, which needs their result, in a second. No copy of it
-    is made (temporaries under 8 MB), and what else lies between the
-    projections is whole-array products, sums and selects: no
-    contraction and no gather by row, and the two windowed reductions
-    (the L2 norms' sums over a head's 96 values, one each for q and
-    k) do not grow with t."""
+    head, a third more). It is donated and updated in place, in ONE
+    pass whatever t: ONE instruction of the compiled step reads it,
+    the kernel's custom call (``ops/delta_state.py``: the 2 t
+    reductions ``S^T k`` / ``S^T q``, the forward substitution and the
+    write over a tile held in fast memory), and the pool is aliased
+    through it, the call's second result in its sixth operand's
+    buffer. No copy of it is made (temporaries under 8 MB), and what
+    else lies between the projections is whole-array products, sums
+    and selects: no contraction and no gather by row. The one
+    reduction that is not over an array's own axis is the gated norm's
+    mean over a head's OWN 192 of the pack's 384 lanes
+    (``_head_mean``: a masked sum a head of the pack, two, spread back
+    over the lanes; the op_name is its ``jit(_where)``), and how XLA
+    emits the two depends on the rows it finds ``o`` in. At t = 2
+    they are two windowed reductions over (64, 2, 15, 384) (a window
+    of 767 over the 384 lanes: the sum at every lane), as at both
+    widths while ``o`` came out of XLA's own fusion; at t = 1 the
+    kernel hands ``o`` over as (64, 1, 15, 384) and they are two plain
+    reductions to (64, 15), spread back by the fusion that reads
+    them. Two either way: they do not grow with t. (Until PR 47 this
+    docstring took them for the L2 norms' sums over a head's 96
+    values; those are plain reductions over the last axis, then and
+    now.)"""
     from deeplearning4j_tpu import dtypes
     from deeplearning4j_tpu.nn.conf.inputs import InputType
     from deeplearning4j_tpu.nn.conf.layers import DeltaRuleDecoderBlock
@@ -637,10 +651,21 @@ def test_delta_rule_block_step_compiles_for_v5e(topo, t):
     assert "T(8,128)" in tiles and 96 % 8 == 0 and 384 % 128 == 0
     readers = [line for line in entry.splitlines()
                if f"%{name}" in line and " parameter(" not in line]
-    assert len(readers) == 2, readers
+    assert len(readers) == 1, readers
+    assert 'custom_call_target="tpu_custom_call"' in readers[0]
+    assert "/delta/state/" in readers[0] and \
+        "pallas_delta_state" in readers[0]
+    assert readers[0].split("custom-call(")[1].split(")")[0].split(
+        ", ")[5].endswith(f"%{name}")
+    assert "output_to_operand_aliasing={{1}: (5, {})}" in readers[0]
+    assert _kernels_in(compiled) == 1
     small = re.findall(r" (dot|convolution|reduce-window|gather)\(.*"
                        r'op_name="[^"]*/state/', text)
-    assert small == ["reduce-window"] * 2, small
+    # the gated norm's two head means: windowed at t = 2, plain at 1
+    assert small == ["reduce-window"] * {2: 2, 1: 0}[t], small
+    plain = re.findall(r"= f32\[64,15\]\S* reduce\(.*"
+                       r'op_name="[^"]*/state/', text)
+    assert len(plain) == {2: 0, 1: 2}[t], plain
 
 
 @pytest.mark.parametrize("t", [2, 1], ids=["chunk_64x2", "decode_64x1"])
@@ -652,8 +677,9 @@ def test_delta_rule_hybrid_cell_step_fits_v5e(topo, as_tpu, t):
     programs: 12 state pools of 2.28 MB a slot beside 4 attention
     layers of 30 heads in the allocator's 3,073 pages, every one read
     by table through the grouped kernel (a slot's 30 or 60 rows
-    rounded up to 32 or 64: no gather of a whole table), in a chip's
-    16 GB."""
+    rounded up to 32 or 64: no gather of a whole table), every state
+    pool read and written in one pass by ``pallas_delta_state``: 16
+    kernels a step, in a chip's 16 GB."""
     from benchmark.harness import spec
     from deeplearning4j_tpu.models.paged_kv import PagedSlotSession
     cell = spec.load("olmo_hybrid_serve_reason")
@@ -682,7 +708,9 @@ def test_delta_rule_hybrid_cell_step_fits_v5e(topo, as_tpu, t):
         sds((slots,), jnp.int32), sds((slots, t, 1), jnp.float32),
         sds((slots,), jnp.int32), sds((slots,), jnp.int32),
         sds((slots,), bool)).compile()
-    assert _kernels_in(compiled) == 4
+    assert _kernels_in(compiled) == 16          # 4 attention + 12 state
+    assert compiled.as_text().count(
+        'pallas_delta_state/pallas_call') >= 12
     mem = compiled.memory_analysis()
     # 8.20 GB of weights, 1.75 GB of state rows, 3.02 GB of pages; the
     # pools are donated; the logits of 128 rows over 100,352 ids and

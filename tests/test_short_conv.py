@@ -703,13 +703,13 @@ def test_batcher_serves_the_network_paged_and_ahead(tiny_net):
         read("serving_moe_expert_hits_total") > 0
 
 
-def test_the_step_names_the_mixer_and_its_window(tiny_net):
+def test_the_step_names_the_mixer_and_its_window(tiny_net, own_programs):
     """The paged step's ops carry the block's scopes, which the
     benchmark's ``conv_time_pct.serve`` reads from the program's own
     table: ``conv`` around the mixer, ``conv/window`` around what lies
     between its two projections, ``mlp`` / ``moe/*`` and
     ``attn/global`` as in the other blocks."""
-    from deeplearning4j_tpu.observability import programs
+    programs = own_programs
     sess = _session(tiny_net, slots=2, capacity=32)
     sess.bind(0, sess.reserve(_ids(5), 1))
     x = np.zeros((2, 2, 1), np.float32)

@@ -524,13 +524,13 @@ def test_batcher_serves_the_hybrid_network_paged_and_ahead(tiny_net):
     assert not [k for k in snap if "kv_ring" in k]
 
 
-def test_the_step_names_the_mixer_and_its_state(tiny_net):
+def test_the_step_names_the_mixer_and_its_state(tiny_net, own_programs):
     """The paged step's ops carry the block's scopes, which the
     benchmark's ``ssm_time_pct.serve`` / ``ssm_state_time_pct.serve``
     read from the program's own table: ``ssm`` around the mixer,
     ``ssm/state`` around what lies between its two projections,
     ``mlp`` and ``attn/global`` as in the other blocks."""
-    from deeplearning4j_tpu.observability import programs
+    programs = own_programs
     sess = _session(tiny_net, slots=2, capacity=32)
     sess.bind(0, sess.reserve(_ids(5), 1))
     x = np.zeros((2, 2, 1), np.float32)

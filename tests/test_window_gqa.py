@@ -265,7 +265,9 @@ def test_a_slot_let_again_never_sees_the_last_tenants_rows(tiny_net):
         RING * PAGE, -1)[40:])
 
 
-def test_a_chunk_wider_than_the_ring_raises(tiny_net):
+def test_a_chunk_wider_than_the_ring_raises(tiny_net, own_programs):
+    """The step that raises has registered its program by then
+    (``own_programs`` takes it out of the registry again)."""
     sess = _session(tiny_net)
     assert sess.chunk_rows_max == PAGE
     ids = _ids(40)
