@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import contextlib
 import copy
+import functools
 import time
 from typing import Any, Dict, Sequence, Tuple
 
@@ -249,6 +250,12 @@ class KStepExecutorMixin:
     # a class default so both executors inherit it without touching
     # their __init__s
     _mesh_ctx = None
+    # does the k=1 train step return the held experts' counts beside
+    # the loss (``MultiLayerNetwork.counts_experts``)? Not the fused
+    # window's, whose steps are counted by nobody
+    counts_experts = False
+    # counts of enqueued steps, still on the device (``_tally_counts``)
+    _pending_counts = ()
 
     def use_mesh(self, mesh_spec, devices=None, *,
                  respect_existing: bool = False):
@@ -319,10 +326,27 @@ class KStepExecutorMixin:
         n_out = 2 if self._health_enabled else 1
         return self._mesh_ctx.step_out_shardings(self, n_out)
 
+    def _train_step_jit_kwargs(self) -> dict:
+        """``jax.jit`` options of the k=1 train program: the fused
+        window's, with one more replicated output where the step
+        returns the experts' counts."""
+        pinned = self._mesh_out_shardings()
+        if pinned is not None and self.counts_experts:
+            pinned += pinned[-1:]
+        return _carry_jit_kwargs(pinned)
+
     def _train_step_fn(self):
-        """The Python function of the k=1 train program."""
+        """The Python function of the k=1 train program: ``(params,
+        state, opt_state, loss[, health][, counts])``, the counts
+        last and only where the network ``counts_experts``. One
+        program and one maker (``_make_train_step``, which the
+        benchmark's tools replace to break or to read the step), so a
+        caller that wants the carry and the loss takes the first
+        four (``ParallelWrapper._train_batch``)."""
         import jax
         core = self._train_core
+        if self.counts_experts:
+            core = functools.partial(core, extras=True)
 
         def train_step(params, state, opt_state, batch, base_rng, step):
             # step arrives as a traced scalar; folding inside the jit
@@ -337,8 +361,8 @@ class KStepExecutorMixin:
         # to the placed model's: GSPMD must not drift a carry
         # sharding and recompile every step
         import jax
-        return jax.jit(self._train_step_fn(), **_carry_jit_kwargs(
-            self._mesh_out_shardings()))
+        return jax.jit(self._train_step_fn(),
+                       **self._train_step_jit_kwargs())
 
     def _without_arrays(self):
         """A shallow copy of this executor that can trace its
@@ -369,7 +393,8 @@ class KStepExecutorMixin:
         self._registered[name] = jitted
         programs.register(
             name, fn_of(self._without_arrays()),
-            _carry_jit_kwargs(self._mesh_out_shardings()), args)
+            self._train_step_jit_kwargs() if name == "train_step"
+            else _carry_jit_kwargs(self._mesh_out_shardings()), args)
 
     def _fit_epoch(self, data_iter, k: int, tbptt) -> None:
         """One epoch's batch loop (shared by both executors' ``fit``):
@@ -432,6 +457,7 @@ class KStepExecutorMixin:
                 if len(pending) == k:
                     self._flush_window(pending, k)
         self._flush_window(pending, k)
+        self._tally_counts(wait=True)
 
     def _pull_batch(self, data_iter, tbptt, ahead: bool):
         """The iterator's next batch as ``(batch object, data wait
@@ -498,6 +524,9 @@ class KStepExecutorMixin:
                     KStepExecutorMixin._train_step_fn, args)
             with trace.span("enqueue"):
                 out = self._step_fn_for(batch)(*args)
+        if self.counts_experts:
+            *out, counts = out
+            self._pending_counts += (counts,)
         if self._health_enabled:
             (self.params, self.state, self.opt_state,
              loss, self._last_health) = out
@@ -517,6 +546,32 @@ class KStepExecutorMixin:
                 lst.iteration_done(self, self.iteration_count,
                                    self.score_value, ds.num_examples())
         self.iteration_count += 1
+        self._tally_counts()
+
+    def _tally_counts(self, wait: bool = False) -> None:
+        """Add the held experts' counts of the steps that have ended
+        to ``train_moe_pairs_total`` (the (row, held expert) pairs the
+        steps computed), ``train_moe_pairs_busiest_expert_total`` (of
+        those, the pairs of each step's busiest held expert) and
+        ``train_moe_steps_total`` (the steps counted). A step whose
+        score a listener has read has ended, and its counts are read
+        without a wait; one still running keeps them until a later
+        call, or the epoch's end (``wait``)."""
+        if not self._pending_counts:
+            return
+        from deeplearning4j_tpu.observability.registry import REGISTRY
+        pairs = REGISTRY.counter("train_moe_pairs_total")
+        busiest = REGISTRY.counter("train_moe_pairs_busiest_expert_total")
+        steps = REGISTRY.counter("train_moe_steps_total")
+        ended = [wait or c.is_ready() for c in self._pending_counts]
+        pending, self._pending_counts = self._pending_counts, tuple(
+            c for c, done in zip(self._pending_counts, ended) if not done)
+        for counts, done in zip(pending, ended):
+            if done:
+                counts = np.asarray(counts)
+                pairs.inc(int(counts.sum()))
+                busiest.inc(int(counts.max()))
+                steps.inc()
 
     def fit_batches(self, batches, *, steps_per_device_call=1):
         """Train on a list of batches in one listener-visible pass

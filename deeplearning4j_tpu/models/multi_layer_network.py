@@ -165,10 +165,15 @@ class MultiLayerNetwork(KStepExecutorMixin):
     # forward (reference feedForward :863-975)
     # ------------------------------------------------------------------
     def _forward(self, params, state, x, *, training, rng, fmask=None,
-                 upto: Optional[int] = None, collect=False, carries=None):
+                 upto: Optional[int] = None, collect=False, carries=None,
+                 train_step=None):
         """carries: optional per-layer recurrent (h, c) initial states —
         used by tBPTT to carry hidden state across chunks (reference
-        rnnActivateUsingStoredState :2219). Returns new carries too."""
+        rnnActivateUsingStoredState :2219). Returns new carries too.
+        ``train_step``: a list the k=1 train step passes; every expert
+        layer's held counts are appended to it, and under the
+        configuration's ``recompute`` each layer's ``apply`` is wrapped
+        in ``jax.checkpoint``."""
         acts = []
         new_states = []
         new_carries = [None] * len(self.layers)
@@ -188,31 +193,64 @@ class MultiLayerNetwork(KStepExecutorMixin):
                 lrng = jax.random.fold_in(rng, i)
             # the layer's name on its device ops (metadata only): a
             # profiler trace then splits fusion time by layer
-            with layer_error_context(f"layer {i}", layer, x), \
-                    jax.named_scope(f"{i}_{type(layer).__name__}"):
-                if carries is not None and isinstance(layer,
-                                                     BaseRecurrentLayer):
-                    c0 = carries[i]
-                    if c0 is None:
-                        c0 = layer.zero_state(x.shape[0])
-                    xd = layer.apply_input_dropout(x, training=training,
-                                                   rng=lrng)
-                    x, c1 = layer.apply_rnn(params[i], xd, c0,
-                                            training=training,
-                                            rng=lrng, mask=fmask)
+            scope = jax.named_scope(f"{i}_{type(layer).__name__}")
+            recurrent = carries is not None and isinstance(
+                layer, BaseRecurrentLayer)
+            with layer_error_context(f"layer {i}", layer, x):
+                if train_step is not None and not recurrent:
+                    # the scope goes inside what may be recomputed:
+                    # its backward ops then carry the layer's name too
+                    x, s, counts = self._apply_in_train_step(
+                        layer, scope, params[i], state[i], x, lrng,
+                        fmask)
+                    train_step.extend(counts)
+                elif recurrent:
+                    with scope:
+                        c0 = carries[i]
+                        if c0 is None:
+                            c0 = layer.zero_state(x.shape[0])
+                        xd = layer.apply_input_dropout(
+                            x, training=training, rng=lrng)
+                        x, c1 = layer.apply_rnn(params[i], xd, c0,
+                                                training=training,
+                                                rng=lrng, mask=fmask)
                     new_carries[i] = c1
                     s = state[i]
                 else:
-                    x, s = layer.apply(params[i], state[i], x,
-                                       training=training,
-                                       rng=lrng, mask=fmask)
+                    with scope:
+                        x, s = layer.apply(params[i], state[i], x,
+                                           training=training,
+                                           rng=lrng, mask=fmask)
             new_states.append(s)
             if collect:
                 acts.append(x)
         return x, new_states, acts, new_carries
 
+    def _apply_in_train_step(self, layer, scope, params, state, x, rng,
+                             fmask):
+        """``layer.apply`` under its ``scope`` as the train step runs
+        it: ``(out, state, [held counts] of a layer with experts)``,
+        computed again in the backward pass where the configuration
+        says ``recompute``. The counts leave through the wrapped
+        function's outputs, so they are the step's own values and not
+        the recomputation's."""
+        counted = getattr(layer, "apply_with_counts", None)
+
+        def run(params, state, x):
+            with scope:
+                if counted is None:
+                    return *layer.apply(params, state, x, training=True,
+                                        rng=rng, mask=fmask), []
+                y, s, counts = counted(params, state, x, training=True,
+                                       rng=rng, mask=fmask)
+            return y, s, [] if counts is None else [counts]
+
+        if self.conf.conf.recompute == "layers":
+            run = jax.checkpoint(run)
+        return run(params, state, x)
+
     def _loss(self, params, state, batch, rng, *, training=True,
-              carries=None):
+              carries=None, train_step=None):
         x, labels, fmask, lmask = batch
         out_idx = len(self.layers) - 1
         out_layer = self.layers[out_idx]
@@ -221,7 +259,7 @@ class MultiLayerNetwork(KStepExecutorMixin):
                              "LossLayer for fit()")
         h, new_states, _, new_carries = self._forward(
             params, state, x, training=training, rng=rng, fmask=fmask,
-            upto=out_idx, carries=carries)
+            upto=out_idx, carries=carries, train_step=train_step)
         if out_idx in self.conf.preprocessors:
             h = self.conf.preprocessors[out_idx](h)
         orng = jax.random.fold_in(rng, out_idx) if rng is not None else None
@@ -245,23 +283,41 @@ class MultiLayerNetwork(KStepExecutorMixin):
     # ------------------------------------------------------------------
     # jitted train step (replaces Solver.optimize + SGD.optimize)
     # ------------------------------------------------------------------
-    def _train_core(self, params, state, opt_state, batch, rng):
+    @property
+    def counts_experts(self) -> bool:
+        """Does the k=1 train step return the held experts' counts
+        beside the loss? Where the network has expert layers that all
+        hold as many experts (the counts are summed over the
+        layers)."""
+        return len({getattr(layer, "held_experts", 0)
+                    for layer in self.layers} - {0}) == 1
+
+    def _train_core(self, params, state, opt_state, batch, rng,
+                    extras=False):
         """Traced single-step training math: loss → grads → updates →
         constraints (+ the fused health vector when a health listener
         is attached). Shared verbatim by the k=1 jitted step and the
         k-step ``lax.scan`` body (models/kstep.py), so the fused and
-        per-step programs compute bit-identical updates."""
+        per-step programs compute bit-identical updates. A network
+        with ``recompute`` or with expert layers runs its layers
+        through ``_apply_in_train_step``; any other's step is what it
+        was. ``extras`` (the k=1 step of a network that
+        ``counts_experts``): the held experts' counts summed over the
+        layers, (held,) int32, are the last output."""
         from deeplearning4j_tpu.train.gradnorm import (
             apply_gradient_normalization)
         optimizer = self._optimizer
 
         def loss_fn(p):
+            counts = ([] if self.counts_experts
+                      or self.conf.conf.recompute else None)
             loss, new_states = self._loss(p, state, batch, rng,
-                                          training=True)
-            return loss, new_states
+                                          training=True,
+                                          train_step=counts)
+            return loss, (new_states, counts)
 
         with self._mesh_scope():
-            (loss, new_states), grads = jax.value_and_grad(
+            (loss, (new_states, counts)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
         with jax.named_scope("updater"):
             grads = apply_gradient_normalization(self.layers, grads)
@@ -278,8 +334,12 @@ class MultiLayerNetwork(KStepExecutorMixin):
             from deeplearning4j_tpu.observability.health import (
                 fused_health)
             health = fused_health(loss, grads, updates, new_params)
-            return new_params, new_states, new_opt_state, loss, health
-        return new_params, new_states, new_opt_state, loss
+            out = new_params, new_states, new_opt_state, loss, health
+        else:
+            out = new_params, new_states, new_opt_state, loss
+        if extras:
+            out += (sum(counts),)
+        return out
 
     def _sync_health_mode(self) -> None:
         """Compile the fused health check into the train step iff a

@@ -51,6 +51,10 @@ class NeuralNetConfiguration:
         self.gradient_clip: Optional[dict] = None   # {"type": "norm"|"value"|
                                                     #  "norm_per_param", "v":x}
         self.tbptt: Optional[dict] = None   # {"fwd_length": n, "bwd_length": n}
+        # what a training step keeps of its forward pass for the
+        # backward pass: everything (None), or each layer's input
+        # alone, the layer computed again ("layers")
+        self.recompute: Optional[str] = None
 
     # ---- fluent builder (mirrors Builder method names, snake_cased) ----
     @staticmethod
@@ -124,6 +128,15 @@ class NeuralNetConfiguration:
             self.tbptt = {"fwd_length": fwd_length, "bwd_length": bwd_length}
         return self
 
+    def recompute_layers(self, on: bool = True):
+        """A training step of ``MultiLayerNetwork.fit`` keeps each
+        layer's input and computes the layer again in the backward
+        pass (``jax.checkpoint`` around every layer's ``apply``):
+        activation memory of one layer at a time, about a third more
+        arithmetic. Off, the step is what it was."""
+        self.recompute = "layers" if on else None
+        return self
+
     # ---- terminals ----
     def list(self) -> "ListBuilder":
         return ListBuilder(self)
@@ -158,7 +171,7 @@ class NeuralNetConfiguration:
         return layer
 
     def global_to_dict(self) -> dict:
-        return {
+        d = {
             "seed": self.seed,
             "updater": self.updater_cfg,
             "defaults": self.defaults,
@@ -167,6 +180,9 @@ class NeuralNetConfiguration:
             "gradient_clip": self.gradient_clip,
             "tbptt": self.tbptt,
         }
+        if self.recompute is not None:      # absent: as it was written
+            d["recompute"] = self.recompute
+        return d
 
     @staticmethod
     def global_from_dict(d: dict) -> "NeuralNetConfiguration":
@@ -179,6 +195,10 @@ class NeuralNetConfiguration:
                                     "stochastic_gradient_descent")
         c.gradient_clip = d.get("gradient_clip")
         c.tbptt = d.get("tbptt")
+        c.recompute = d.get("recompute")
+        if c.recompute not in (None, "layers"):
+            raise ValueError(f"recompute {c.recompute!r}: None or "
+                             "'layers'")
         return c
 
 

@@ -417,16 +417,21 @@ class GroupedQueryAttentionLayer(PagedLayer, BaseLayer):
     for all key heads, ``k_norm_gain``; ``qk_norm_eps``), so the cache
     holds normed keys; ``"width"`` norms the whole projected width
     instead (all heads' values as one vector, gains of ``H dq`` and
-    ``K dq``: the Olmo family's).
+    ``K dq``: the Olmo family's). ``out_gate``: the heads' output is
+    multiplied by ``sigmoid(x Wgate)``, ``x`` the layer's input and
+    ``Wgate`` (C, H dv), before the output projection (``_out``).
 
     Two forms of one mathematics: ``apply`` attends over the whole
     sequence, ``apply_stream_paged`` over a paged cache that holds
-    rotated keys and scaled values. Both go through ``_project`` and
-    ``_attend`` (exact einsum, float32 scores and softmax: the flash
-    kernels take one head size and equal head counts); on a TPU a
-    layer without ``window`` and ``sink`` reads its paged cache
-    through the grouped by-table kernel of ``ops/paged_attention.py``,
-    the same mathematics page by page.
+    rotated keys and scaled values. Both go through ``_project``,
+    ``_attend`` (exact einsum, float32 scores and softmax) and
+    ``_out``. On a TPU ``apply`` takes the flash kernels' band
+    (``ops/attention.py``: the window's tiles alone, a key head read
+    by its query heads without being repeated) where ``_takes_flash``
+    admits the shapes, and a layer without ``window`` and ``sink``
+    reads its paged cache through the grouped by-table kernel of
+    ``ops/paged_attention.py``, the same mathematics page by page;
+    ``_attend`` is the oracle of both.
 
     A layer with a ``window`` keeps its cache in a RING: the session
     gives it ``slots * ring_pages + 1`` pages and slot ``s`` owns
@@ -449,6 +454,7 @@ class GroupedQueryAttentionLayer(PagedLayer, BaseLayer):
     softmax_scale: Optional[float] = None
     qk_norm: Union[bool, str] = False
     qk_norm_eps: float = 1e-6
+    out_gate: bool = False
 
     def __post_init__(self):
         if self.qk_norm not in (False, True, "width"):
@@ -482,6 +488,9 @@ class GroupedQueryAttentionLayer(PagedLayer, BaseLayer):
              "Wv": w(ks[2], d, K * dv), "Wo": w(ks[3], H * dv, d)}
         if self.sink:
             p["sink"] = jnp.zeros((H,), dtypes.policy().param_dtype)
+        if self.out_gate:
+            p["Wgate"] = self._sample_w(jax.random.fold_in(key, 4),
+                                        (d, H * dv), d, H * dv)
         if self.qk_norm:
             wide = self.qk_norm == "width"
             for name, n in (("q_norm_gain", H), ("k_norm_gain", K)):
@@ -534,7 +543,8 @@ class GroupedQueryAttentionLayer(PagedLayer, BaseLayer):
         values v (B,N,K,dv) that hold the positions ``k_pos`` (B,N):
         key ``j`` is visible to query ``i`` iff ``0 <= k_pos[j] <=
         q_pos[i]`` and, with a window, ``k_pos[j] > q_pos[i] -
-        window``. Returns (B,t,C)."""
+        window``. Returns the heads' output side by side,
+        (B,t,H dv)."""
         from deeplearning4j_tpu.ops.attention import _NEG_INF
         B, t, H, dq = q.shape
         K = self.n_kv_heads
@@ -558,7 +568,29 @@ class GroupedQueryAttentionLayer(PagedLayer, BaseLayer):
         if self.sink:
             z = z + jnp.exp(b - m)
         o = jnp.einsum("bkgtn,bnkd->btkgd", (e / z).astype(v.dtype), v)
-        return o.reshape(B, t, -1) @ params["Wo"]
+        return o.reshape(B, t, -1)
+
+    def _out(self, params, o, x):
+        """The heads' output ``o`` (B,t,H dv) -> (B,t,C): gated by the
+        layer's input ``x`` where ``out_gate`` (float32 gate, rounded
+        once), then projected."""
+        if self.out_gate:
+            gate = jax.nn.sigmoid(einsum_f32(
+                "btc,cn->btn", x.astype(params["Wgate"].dtype),
+                params["Wgate"]))
+            o = (o * gate).astype(o.dtype)
+        return o @ params["Wo"]
+
+    def _takes_flash(self, T: int) -> bool:
+        """Does ``apply`` over ``T`` positions go through the flash
+        kernels? On a TPU, for one head size, no sink, and a sequence
+        of whole tiles of 128 or more (``ops.attention._auto_block``);
+        the score scale, where the layer has its own, goes into q."""
+        from deeplearning4j_tpu.ops.attention import (_auto_block,
+                                                      _use_pallas)
+        block = _auto_block(T, self.qk_head_dim)
+        return (not self.sink and self.qk_head_dim == self.v_head_dim
+                and block >= 128 and _use_pallas(T, block, block))
 
     # ---- full sequence ----
     def apply(self, params, state, x, *, training=False, rng=None,
@@ -571,7 +603,15 @@ class GroupedQueryAttentionLayer(PagedLayer, BaseLayer):
         B, T, _ = x.shape
         pos = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
         q, k, v = self._project(params, x, pos)
-        return self._attend(params, q, k, v, pos, pos), state
+        if self._takes_flash(T):
+            from deeplearning4j_tpu.ops.attention import flash_attention
+            if self.softmax_scale is not None:
+                q = q * (self.softmax_scale * self.qk_head_dim ** 0.5)
+            o = flash_attention(q, k, v, causal=True,
+                                window=self.window).reshape(B, T, -1)
+        else:
+            o = self._attend(params, q, k, v, pos, pos)
+        return self._out(params, o, x), state
 
     # ---- paged cache ----
     def paged_cache(self, page_size: int) -> PagedCache:
@@ -691,13 +731,14 @@ class GroupedQueryAttentionLayer(PagedLayer, BaseLayer):
             if lanes != dv:
                 o = o.reshape(S, t, self.n_heads, lanes)[..., :dv] \
                     .reshape(S, t, -1)
-            return o @ params["Wo"], {"k": k_pool, "v": v_pool}
+            return self._out(params, o, x), {"k": k_pool, "v": v_pool}
         n = k_pos.shape[1]
         keys = rows(k_pool).reshape(S, n, K, self.qk_head_dim)
         values = rows(v_pool).reshape(S, n, K, lanes)
         if lanes != dv:
             values = values[..., :dv]
-        out = self._attend(params, q, keys, values, wpos, k_pos)
+        out = self._out(params, self._attend(params, q, keys, values,
+                                             wpos, k_pos), x)
         return out, {"k": k_pool, "v": v_pool}
 
 

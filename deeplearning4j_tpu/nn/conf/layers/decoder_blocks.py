@@ -6,7 +6,8 @@ Mixer(norm(x)); y = h + m F(norm(h))`` with ``F`` the dense SiLU-gated
 MLP or the expert layer (``moe.SparseExpertsLayer``), or, where the
 block's ``norm_placement`` is ``"post"``, each branch's OUTPUT normed
 (``h = x + m norm(Mixer(x)); y = h + m norm(F(h))``: the Olmo
-family's). A class gives what a configuration adds: the two
+family's), or with ``"both"`` a norm on either side of each branch
+(four gains: Trinity's and the Gemma line's). A class gives what a configuration adds: the two
 sub-layers' own fields, flat, so that the block round-trips through
 JSON like every DSL layer; the mixer's key in the parameters and its
 ``named_scope``; and ``_ensure_parts() -> (mixer, experts or None)``.
@@ -15,7 +16,9 @@ JSON like every DSL layer; the mixer's key in the parameters and its
 - ``GroupedQueryDecoderBlock``: grouped-query attention, global or a
   sliding window with a learned sink, and a sigmoid router with a
   correction bias and no shared expert: MiMo-V2's layer; with
-  ``qk_norm``, LFM2's attention layer.
+  ``qk_norm``, LFM2's attention layer; with ``qk_norm``, ``out_gate``,
+  ``n_shared_experts`` and ``norm_placement`` ``"both"``, Trinity's
+  (``afmoe``).
 - ``StateSpaceDecoderBlock``: a Mamba-2 mixer (``state_space.py``) and
   the dense MLP; with ``GroupedQueryDecoderBlock`` and a
   ``residual_multiplier`` on both branches, Granite-4.0-H's two kinds
@@ -79,35 +82,59 @@ def _residual(h, f, multiplier=1.0):
     return (h.astype(_F32) + multiplier * f.astype(_F32)).astype(h.dtype)
 
 
+NORM_PLACEMENTS = ("pre", "post", "both")
+
+
+def _pre(params, i, placement, eps, z):
+    """The input of branch ``i`` (1 the mixer's, 2 the
+    feed-forward's): ``z`` normed by ``norm<i>_gain``, or ``z`` itself
+    where the branch's only norm sits behind it (``"post"``)."""
+    if placement == "post":
+        return z
+    return rms_norm(z, params[f"norm{i}_gain"], eps)
+
+
+def _post(params, i, placement, eps, f):
+    """Branch ``i``'s output ``f`` as it joins the residual stream:
+    as it is (``"pre"``), normed by ``norm<i>_gain`` (``"post"``) or
+    by ``norm<i>_post_gain`` (``"both"``)."""
+    if placement == "pre":
+        return f
+    return rms_norm(f, params[f"norm{i}_gain" if placement == "post"
+                              else f"norm{i}_post_gain"], eps)
+
+
 def _ffn_half(params, h, moe, eps, active=None, multiplier=1.0,
-              stream=False, post=False):
+              stream=False, placement="pre"):
     """The second half of an RMS-normed decoder block,
-    ``(h + multiplier * F(norm(h)), counts or None)``, or with
-    ``post`` ``h + multiplier * norm(F(h))``: ``F`` is the expert
-    layer ``moe`` (parameters ``params["moe"]``) or, where that is
-    None, the dense SiLU-gated MLP ``Wg, Wu, Wd``. ``stream``: a
-    serving step's call (``SparseExpertsLayer.apply_tallied``)."""
-    norm = lambda v: rms_norm(v, params["norm2_gain"], eps)
-    z = h if post else norm(h)
+    ``(h + multiplier * F(norm(h)), counts or None)`` with the norms
+    where ``placement`` puts them (:func:`_pre`, :func:`_post`): ``F``
+    is the expert layer ``moe`` (parameters ``params["moe"]``) or,
+    where that is None, the dense SiLU-gated MLP ``Wg, Wu, Wd``.
+    ``stream``: a serving step's call
+    (``SparseExpertsLayer.apply_tallied``)."""
+    z = _pre(params, 2, placement, eps, h)
+    post = lambda f: _post(params, 2, placement, eps, f)
     if moe is None:
         with jax.named_scope("mlp"):
             f = swiglu(z, params["Wg"], params["Wu"], params["Wd"])
-            return _residual(h, norm(f) if post else f, multiplier), None
+            return _residual(h, post(f), multiplier), None
     f, counts = moe.apply_counted(params["moe"], z, active, stream)
-    return _residual(h, norm(f) if post else f, multiplier), counts
+    return _residual(h, post(f), multiplier), counts
 
 
 def _biased_sigmoid_experts(block, held, common):
     """The expert layer of ``block``'s flat fields over the ``held``
     share, or None where it has no routed experts: a sigmoid router
     with its selection-only correction bias, the selected weights
-    normalised, no shared expert (MiMo-V2's and LFM2's)."""
+    normalised, and a shared expert only where the block has the
+    field and sets it (MiMo-V2's and LFM2's have none)."""
     if not block.n_routed_experts:
         return None
     return SparseExpertsLayer(
         n_routed_experts=block.n_routed_experts, held=held,
         top_k=block.top_k, expert_width=block.expert_width,
-        n_shared_experts=0,
+        n_shared_experts=getattr(block, "n_shared_experts", 0),
         routed_scaling_factor=block.routed_scaling_factor,
         norm_topk_prob=True, scoring_func="sigmoid", router_bias=True,
         **common)
@@ -138,6 +165,20 @@ class _DecoderBlock(MixerCacheLayer, BaseLayer):
         return self._ensure_parts()[0]
 
     @property
+    def held_experts(self) -> int:
+        """How many routed experts the block holds: the width of the
+        counts ``apply_with_counts`` gives; 0 for a dense block."""
+        if not self.n_routed_experts:
+            return 0
+        held = getattr(self, "held", None)
+        return held[1] if held else self.n_routed_experts
+
+    def apply(self, params, state, x, *, training=False, rng=None,
+              mask=None):
+        return self.apply_with_counts(
+            params, state, x, training=training, rng=rng, mask=mask)[:2]
+
+    @property
     def stream_aux(self) -> bool:
         """Does a decode step of this block return counts beside its
         output (``apply_stream_paged_aux``)? The paged session asks."""
@@ -165,15 +206,15 @@ class _DecoderBlock(MixerCacheLayer, BaseLayer):
         return h, pool
 
 
-NORM_PLACEMENTS = ("pre", "post")
-
-
 class _NormedBlock(_DecoderBlock):
     """``h = x + m Mixer(norm(x)); y = h + m F(norm(h))``, ``m`` the
     ``residual_multiplier``, or with ``norm_placement`` ``"post"``
     ``h = x + m norm(Mixer(x)); y = h + m norm(F(h))`` (the mixer and
     the feed-forward read the residual stream as it is, each branch's
-    output is normed before it joins): the equations, the parameters
+    output is normed before it joins), or with ``"both"`` ``h = x +
+    m norm(Mixer(norm(x)))`` and the like for ``F`` (gains
+    ``norm1_gain``, ``norm1_post_gain``, ``norm2_gain``,
+    ``norm2_post_gain``): the equations, the parameters
     and both forms (whole sequence, paged step), once. A subclass
     gives the fields, ``mixer`` (the mixer's key in the parameters),
     ``scope`` (its ``named_scope``; ``mixer`` unless it says
@@ -202,6 +243,9 @@ class _NormedBlock(_DecoderBlock):
         p = {"norm1_gain": jnp.ones((d,), pd),
              "norm2_gain": jnp.ones((d,), pd),
              self.mixer: mixer.initialize(ka, t)[0]}
+        if self.norm_placement == "both":
+            p.update(norm1_post_gain=jnp.ones((d,), pd),
+                     norm2_post_gain=jnp.ones((d,), pd))
         if moe is not None:
             p["moe"] = moe.initialize(km, t)[0]
         else:
@@ -212,24 +256,27 @@ class _NormedBlock(_DecoderBlock):
 
     def _block(self, params, x, mix, active=None, stream=False):
         """The block's equations; ``mix(z)`` is the mixer over ``z``
-        (the normed input, or the input itself where the norm sits
-        behind the mixer); ``stream``: a serving step's call."""
+        (the normed input, or the input itself where the only norm
+        sits behind the mixer); ``stream``: a serving step's call."""
         x = x.astype(params["norm1_gain"].dtype)
-        post = self.norm_placement == "post"
-        norm = lambda v: rms_norm(v, params["norm1_gain"], self.eps)
         with jax.named_scope(self.scope):
-            a = norm(mix(x)) if post else mix(norm(x))
+            a = _post(params, 1, self.norm_placement, self.eps, mix(
+                _pre(params, 1, self.norm_placement, self.eps, x)))
         m = self.residual_multiplier
         return _ffn_half(params, _residual(x, a, m),
                          self._ensure_parts()[1], self.eps, active, m,
-                         stream, post)
+                         stream, self.norm_placement)
 
-    def apply(self, params, state, x, *, training=False, rng=None,
-              mask=None):
+    def apply_with_counts(self, params, state, x, *, training=False,
+                          rng=None, mask=None):
+        """``apply`` with a third value, the (held,) tokens each held
+        expert took (None for a dense block): what a train step asks
+        for (``MultiLayerNetwork._apply_in_train_step``)."""
         mix = lambda z: self._mixer().apply(
             params[self.mixer], {}, z, training=training, rng=rng,
             mask=mask)[0]
-        return self._block(params, x, mix)[0], state
+        h, counts = self._block(params, x, mix)
+        return h, state, counts
 
     def apply_stream_paged_aux(self, params, pool, table, pos, x,
                                active=None, n_valid=None):
@@ -406,13 +453,14 @@ class ShortcutExpertBlock(_DecoderBlock):
             h = h + mlp(1, norm(h, "norm_f1_gain")) + m
         return h, tally
 
-    def apply(self, params, state, x, *, training=False, rng=None,
-              mask=None):
+    def apply_with_counts(self, params, state, x, *, training=False,
+                          rng=None, mask=None):
         attn, _ = self._ensure_parts()
         attend = lambda i, z: attn.apply(
             params[f"attn{i}"], {}, z, training=training, rng=rng,
             mask=mask)[0]
-        return self._forward(params, x, attend)[0], state
+        h, tally = self._forward(params, x, attend)
+        return h, state, tally["held"]
 
     # ---- paged decode: both attentions are one layer object over
     #      two pools of one shape, so its declaration and its
@@ -447,10 +495,12 @@ class ShortcutExpertBlock(_DecoderBlock):
 class GroupedQueryDecoderBlock(_NormedBlock):
     """``_NormedBlock`` over grouped-query attention
     (``GroupedQueryAttentionLayer``: global, or with ``window`` a
-    sliding window whose paged cache is a slot-owned ring), then a
-    dense SiLU-gated MLP (``n_routed_experts == 0``) or the expert
-    layer with a sigmoid router, its selection-only correction bias,
-    the selected weights normalised and no shared expert."""
+    sliding window whose paged cache is a slot-owned ring; with
+    ``out_gate`` its output gate), then a dense SiLU-gated MLP
+    (``n_routed_experts == 0``) or the expert layer with a sigmoid
+    router, its selection-only correction bias, the selected weights
+    normalised and ``n_shared_experts`` shared experts (none unless
+    set)."""
 
     n_in: Optional[int] = None
     eps: float = 1e-5
@@ -480,6 +530,8 @@ class GroupedQueryDecoderBlock(_NormedBlock):
     # (True) or over the whole projected width ("width")
     qk_norm: Union[bool, str] = False
     norm_placement: str = "pre"
+    out_gate: bool = False
+    n_shared_experts: int = 0
 
     mixer = "attn"
 
@@ -497,7 +549,8 @@ class GroupedQueryDecoderBlock(_NormedBlock):
                 rope_theta=self.rope_theta, window=self.window,
                 sink=self.sink, value_scale=self.value_scale,
                 softmax_scale=self.softmax_scale,
-                qk_norm=self.qk_norm, qk_norm_eps=self.eps, **common)
+                qk_norm=self.qk_norm, qk_norm_eps=self.eps,
+                out_gate=self.out_gate, **common)
             self._moe = _biased_sigmoid_experts(self, self.held, common)
         return self._attn, self._moe
 

@@ -98,6 +98,10 @@ class SparseExpertsLayer(BaseLayer):
         return self.held or (0, self.n_routed_experts)
 
     @property
+    def held_experts(self) -> int:
+        return self.held_range()[1]
+
+    @property
     def router_width(self) -> int:
         return self.n_routed_experts + self.n_zero_experts
 
@@ -182,11 +186,15 @@ class SparseExpertsLayer(BaseLayer):
         the ``active`` rows, ``{"held": (held,) tokens a held expert,
         "zero": () (row, zero expert) pairs, "selected": () (row,
         selected expert) pairs}``, int32. The held experts' part has
-        two forms that differ by the order of a float32 sum: the
-        DENSE pass, every row through every held expert, and, in a
+        three forms that differ by the order of a float32 sum: the
+        DENSE pass, every row through every held expert; in a
         serving step (``stream``: nothing differentiates it) whose
-        shapes ``takes_grouped_pass`` admits, the GROUPED pass over
-        the selected pairs alone (``ops.grouped_experts``)."""
+        shapes ``takes_grouped_pass`` admits, the GROUPED pass, a
+        kernel over the selected pairs alone; and off a serving step
+        past an MXU tile of rows (``pairs_pass``) the PAIRS pass,
+        sorted pairs through grouped matrix products, which
+        differentiates and drops no pair at any skew
+        (``ops.grouped_experts``)."""
         shape = x.shape
         x = x.reshape(-1, shape[-1]).astype(params["Wr"].dtype)
         ids, w = self.route(params, x)
@@ -206,6 +214,11 @@ class SparseExpertsLayer(BaseLayer):
                 out = grouped_experts.pallas_grouped_experts(
                     x, jnp.any(hit, axis=1), comb, params["Wg"],
                     params["Wu"], params["Wd"])
+            elif not stream and grouped_experts.pairs_pass(x.shape[0]):
+                out = grouped_experts.pairs_experts(
+                    x, jnp.where(jnp.any(hit, axis=2), ids - first,
+                                 count), w, params["Wg"], params["Wu"],
+                    params["Wd"])
             else:
                 # every token goes through every held expert and the
                 # weight picks: up to an MXU tile of rows the
@@ -239,7 +252,16 @@ class SparseExpertsLayer(BaseLayer):
                                          jnp.int32)}
         return out.astype(x.dtype).reshape(shape), tally
 
+    def apply_with_counts(self, params, state, x, *, training=False,
+                          rng=None, mask=None):
+        """``apply`` with the held counts of the call as a third
+        value: what a train step asks a layer with experts for
+        (``MultiLayerNetwork._apply_in_train_step``)."""
+        x = self.apply_input_dropout(x, training=training, rng=rng)
+        out, counts = self.apply_counted(params, x)
+        return out, state, counts
+
     def apply(self, params, state, x, *, training=False, rng=None,
               mask=None):
-        x = self.apply_input_dropout(x, training=training, rng=rng)
-        return self.apply_counted(params, x)[0], state
+        return self.apply_with_counts(params, state, x,
+                                      training=training, rng=rng)[:2]

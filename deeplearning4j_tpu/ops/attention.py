@@ -21,6 +21,17 @@ Grids put the contraction dimension innermost ('arbitrary' =
 sequential) with VMEM scratch carrying the accumulators across steps —
 the double-buffering pattern from the Pallas guide.
 
+A ``window`` and grouped heads (``H`` query heads over ``K`` key/value
+heads, query head ``i`` on key head ``i // (H / K)``) take the same
+kernels over a BAND (:class:`_Band`): the grid's sequential dimension
+spans only the tiles a row of tiles can see (the window's width, not
+the sequence's), tiles outside fetch nothing (the index map stays on
+the band's last tile) and the edge tiles are masked. Key/value blocks
+are indexed by ``bh // G``, so no key head is repeated in memory; the
+dk/dv kernel walks the ``G`` query heads of its key head in turn and
+sums them in its scratch. Without a window and with equal head counts
+the kernels trace as they did before there was a band.
+
 ``precision`` selects the MXU mode: 'default' (bf16 passes — what XLA
 gives a plain f32 ``jnp.einsum``, so flash-vs-naive benches are
 apples-to-apples) or 'highest' (exact f32, 6-pass).
@@ -69,10 +80,74 @@ def _causal_mask(qi, ki, block_q, block_k):
     return k_pos <= q_pos
 
 
+def _pick(of_ints, of_traced, a, b):
+    return of_ints(a, b) if isinstance(a, int) else of_traced(a, b)
+
+
+class _Band:
+    """The tiles of a causal attention with an optional ``window``
+    (query ``i`` sees keys ``j``, ``i - window < j <= i``; None: every
+    ``j <= i``), static sizes and traced tile indices. A row of query
+    tiles ``qi`` sees the key tiles ``first_k(qi) .. last_k(qi)``, at
+    most ``k_steps`` of them; a column of key tiles ``kb`` is seen by
+    the query tiles ``first_q(kb) .. last_q(kb)``, at most
+    ``q_steps``."""
+
+    def __init__(self, window, block_q, block_k, T):
+        self.window, self.bq, self.bk = window, block_q, block_k
+        self.nq, self.nk = T // block_q, T // block_k
+        self.k_steps = max(self.last_k(i) - self.first_k(i) + 1
+                           for i in range(self.nq))
+        self.q_steps = max(self.last_q(j) - self.first_q(j) + 1
+                           for j in range(self.nk))
+
+    # tile indices are plain ints (the static step counts) or traced
+    # int32 (a kernel's and an index map's)
+    def first_k(self, qi):
+        if self.window is None:
+            return qi * 0
+        return _pick(max, jnp.maximum,
+                     qi * self.bq - (self.window - 1), 0) // self.bk
+
+    def last_k(self, qi):
+        return (qi * self.bq + self.bq - 1) // self.bk
+
+    def first_q(self, kb):
+        return (kb * self.bk) // self.bq
+
+    def last_q(self, kb):
+        if self.window is None:
+            return kb * 0 + (self.nq - 1)
+        return _pick(
+            min, jnp.minimum,
+            (kb * self.bk + self.bk - 1 + self.window - 1) // self.bq,
+            self.nq - 1)
+
+    def key_tiles(self, G, H):
+        """Index maps of a (bh, q tile, band step) grid for the key /
+        value blocks (a key head serves ``G`` query heads) and for the
+        key-padding mask's: past the band's last tile the index stays
+        on it, so nothing is fetched."""
+        kt = lambda qi, ki: jnp.minimum(self.first_k(qi) + ki,
+                                        self.last_k(qi))
+        return (lambda bh, qi, ki: (bh // G, kt(qi, ki), 0),
+                lambda bh, qi, ki: (bh // H, 0, kt(qi, ki)))
+
+    def mask(self, qi, ki):
+        q_pos = qi * self.bq + jax.lax.broadcasted_iota(
+            jnp.int32, (self.bq, self.bk), 0)
+        k_pos = ki * self.bk + jax.lax.broadcasted_iota(
+            jnp.int32, (self.bq, self.bk), 1)
+        seen = k_pos <= q_pos
+        if self.window is not None:
+            seen = seen & (k_pos > q_pos - self.window)
+        return seen
+
+
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, masked,
-                block_q, block_k, nk, precision):
+                block_q, block_k, nk, precision, band=None):
     from jax.experimental import pallas as pl
 
     if masked:      # optional (8, block_k) key-padding mask operand
@@ -92,8 +167,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, masked,
 
     # causal tile skipping: a (qi, ki) tile entirely ABOVE the
     # diagonal (every key after every query) contributes nothing —
-    # skip both matmuls. ~2x for long causal sequences.
-    if causal:
+    # skip both matmuls. ~2x for long causal sequences. Over a band
+    # step ``ki`` is the band's ki-th tile of this row of tiles.
+    if band is not None:
+        kt = band.first_k(qi) + ki
+        needed = kt <= band.last_k(qi)
+    elif causal:
         needed = ki * block_k <= qi * block_q + block_q - 1
     else:
         needed = ki >= 0          # trivially true, keeps one codepath
@@ -108,7 +187,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, masked,
                                 preferred_element_type=jnp.float32,
                                 precision=precision) * scale
 
-        if causal:
+        if band is not None:
+            s = jnp.where(band.mask(qi, kt), s, _NEG_INF)
+        elif causal:
             s = jnp.where(_causal_mask(qi, ki, block_q, block_k),
                           s, _NEG_INF)
         if masked:
@@ -163,17 +244,31 @@ def _lanes8(x, B, T):
     return jnp.broadcast_to(x[:, None, :], (B, 8, T))
 
 
+def _band_of(causal, window, block_q, block_k, T, G):
+    """The band of a call, or None for the kernels as they were
+    before there was one: no window and equal head counts."""
+    if window is None and G == 1:
+        return None
+    if not causal:
+        raise ValueError("a window or grouped heads need causal=True")
+    return _Band(window, block_q, block_k, T)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("causal", "block_q", "block_k",
                                     "interpret", "precision",
-                                    "return_lse"))
+                                    "return_lse", "window"))
 def pallas_flash_attention(q, k, v, kv_mask=None, *,
                            causal: bool = False,
                            block_q: int = 128, block_k: int = 128,
                            interpret: bool = False,
                            precision: str = "default",
-                           return_lse: bool = False):
-    """q,k,v: (B, T, H, D) → (B, T, H, D) [, lse (B, H, T)]. T must be
+                           return_lse: bool = False,
+                           window=None):
+    """q: (B, T, H, D), k,v: (B, T, K, D) with ``H`` a multiple of
+    ``K`` (query head ``i`` reads key/value head ``i // (H / K)``) →
+    (B, T, H, D) [, lse (B, H, T)]. ``window`` (with ``causal``):
+    query ``i`` sees keys ``i - window < j <= i``. T must be
     divisible by the block sizes (the layer wrapper pads). precision:
     'default' = bf16 MXU passes (what XLA gives plain f32 einsum);
     'highest' = exact f32 (6-pass MXU, ~2.5x slower). ``kv_mask``:
@@ -184,29 +279,37 @@ def pallas_flash_attention(q, k, v, kv_mask=None, *,
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, H, D = q.shape
+    G = H // k.shape[2]
     scale = 1.0 / math.sqrt(D)
-    # (B,T,H,D) -> (B*H, T, D)
+    # (B,T,N,D) -> (B*N, T, D)
     def to_bht(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+        return x.transpose(0, 2, 1, 3).reshape(-1, T, D)
     qb, kb, vb = to_bht(q), to_bht(k), to_bht(v)
     nq = T // block_q
     nk = T // block_k
     masked = kv_mask is not None
     vma = _vma_of(q, k, v)
+    band = _band_of(causal, window, block_q, block_k, T, G)
+    if band is None:
+        key_tile = lambda bh, qi, ki: (bh, ki, 0)
+        mask_tile = lambda bh, qi, ki: (bh // H, 0, ki)
+    else:
+        nk = band.k_steps
+        key_tile, mask_tile = band.key_tiles(G, H)
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                masked=masked, block_q=block_q,
                                block_k=block_k, nk=nk,
-                               precision=_prec(precision))
+                               precision=_prec(precision),
+                               **({} if band is None else {"band": band}))
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-        pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
-        pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
+        pl.BlockSpec((1, block_k, D), key_tile),
+        pl.BlockSpec((1, block_k, D), key_tile),
     ]
     operands = [qb, kb, vb]
     if masked:
-        in_specs.append(pl.BlockSpec(
-            (1, 8, block_k), lambda bh, qi, ki: (bh // H, 0, ki)))
+        in_specs.append(pl.BlockSpec((1, 8, block_k), mask_tile))
         operands.append(_lanes8(kv_mask.astype(jnp.float32), B, T))
     out, lse = pl.pallas_call(
         kernel,
@@ -238,7 +341,7 @@ def pallas_flash_attention(q, k, v, kv_mask=None, *,
 # --------------------------------------------------------------- backward
 
 def _recompute_p(q, k, lse, scale, causal, qi, ki, block_q, block_k,
-                 precision, kmask=None):
+                 precision, kmask=None, band=None):
     """Recompute the (bq, bk) probability tile from q, k and the saved
     per-row logsumexp — exact softmax weights, no running max needed.
     ``kmask``: (1, bk) lane-oriented 0/1 — keys masked in the forward
@@ -250,7 +353,9 @@ def _recompute_p(q, k, lse, scale, causal, qi, ki, block_q, block_k,
     p = jnp.exp(s - lse[:, None])
     # rows that saw no keys have lse = -inf (clamped): exp would blow up
     p = jnp.where(lse[:, None] <= _NEG_INF / 2, 0.0, p)
-    if causal:
+    if band is not None:
+        p = jnp.where(band.mask(qi, ki), p, 0.0)
+    elif causal:
         p = jnp.where(_causal_mask(qi, ki, block_q, block_k), p, 0.0)
     if kmask is not None:
         p = jnp.where(kmask > 0, p, 0.0)
@@ -264,7 +369,8 @@ def _row_delta(do, o):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
-               scale, causal, masked, block_q, block_k, nk, precision):
+               scale, causal, masked, block_q, block_k, nk, precision,
+               band=None):
     from jax.experimental import pallas as pl
 
     if masked:
@@ -282,7 +388,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
         delta_scr[:] = jnp.broadcast_to(
             _row_delta(do_ref[0], o_ref[0])[:, None], delta_scr.shape)
 
-    if causal:      # tiles fully above the diagonal: p = 0, skip
+    kt = ki
+    if band is not None:    # step ki is the band's ki-th tile
+        kt = band.first_k(qi) + ki
+        needed = kt <= band.last_k(qi)
+    elif causal:    # tiles fully above the diagonal: p = 0, skip
         needed = ki * block_k <= qi * block_q + block_q - 1
     else:
         needed = ki >= 0
@@ -296,9 +406,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
         lse = lse_ref[0][:, 0]                    # (bq,)
         delta = delta_scr[:, 0]
 
-        p = _recompute_p(q, k, lse, scale, causal, qi, ki,
+        p = _recompute_p(q, k, lse, scale, causal, qi, kt,
                          block_q, block_k, precision,
-                         kmask_ref[0][0:1, :] if masked else None)
+                         kmask_ref[0][0:1, :] if masked else None,
+                         **({} if band is None else {"band": band}))
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32,
                                  precision=precision)
@@ -314,7 +425,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
                 scale, causal, masked, block_q, block_k, nq,
-                precision):
+                precision, band=None):
     from jax.experimental import pallas as pl
 
     if masked:
@@ -331,7 +442,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    if causal:      # queries entirely before this key block: p = 0
+    qt = qi
+    if band is not None:
+        # the sequential dimension walks the key head's G query heads
+        # in turn, ``q_steps`` tiles each; dk and dv sum over them
+        qt = band.first_q(kb) + qi % band.q_steps
+        needed = qt <= band.last_q(kb)
+    elif causal:    # queries entirely before this key block: p = 0
         needed = (qi + 1) * block_q - 1 >= kb * block_k
     else:
         needed = qi >= 0
@@ -345,9 +462,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
         lse = lse_ref[0][:, 0]
         delta = _row_delta(do, o_ref[0])          # per q tile — cheap
 
-        p = _recompute_p(q, k, lse, scale, causal, qi, kb,
+        p = _recompute_p(q, k, lse, scale, causal, qt, kb,
                          block_q, block_k, precision,
-                         kmask_ref[0][0:1, :] if masked else None)
+                         kmask_ref[0][0:1, :] if masked else None,
+                         **({} if band is None else {"band": band}))
         # dv += p^T @ do
         dv_scr[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -369,13 +487,15 @@ def _dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
 
 @functools.partial(jax.jit,
                    static_argnames=("causal", "block_q", "block_k",
-                                    "interpret", "precision"))
+                                    "interpret", "precision", "window"))
 def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
                                causal: bool = False,
                                block_q: int = 128, block_k: int = 128,
                                interpret: bool = False,
-                               precision: str = "default"):
-    """Backward pass: (q,k,v,o,lse,do) → (dq, dk, dv), all (B,T,H,D)
+                               precision: str = "default",
+                               window=None):
+    """Backward pass: (q,k,v,o,lse,do) → (dq, dk, dv), dq (B,T,H,D),
+    dk and dv (B,T,K,D) summed over the query heads of a key head
     (lse: (B,H,T) from the forward). Standard flash backward:
     delta = rowsum(do·o), p recomputed per tile from the saved lse.
     ``kv_mask``: the forward's (B, T) key-padding mask — masked keys
@@ -384,10 +504,12 @@ def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, H, D = q.shape
+    K = k.shape[2]
+    G = H // K
     scale = 1.0 / math.sqrt(D)
 
     def to_bht(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+        return x.transpose(0, 2, 1, 3).reshape(-1, T, D)
     qb, kb, vb = to_bht(q), to_bht(k), to_bht(v)
     ob, dob = to_bht(o), to_bht(do)
     # rows-on-sublanes layout with an 8-wide lane dim (see _fwd note)
@@ -401,11 +523,19 @@ def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
     maskb = (_lanes8(kv_mask.astype(jnp.float32), B, T)
              if masked else None)
 
+    band = _band_of(causal, window, block_q, block_k, T, G)
+    banded = {} if band is None else {"band": band}
+    if band is None:
+        key_tile = lambda bh, qi, ki: (bh, ki, 0)
+        mask_tile = lambda bh, qi, ki: (bh // H, 0, ki)
+    else:
+        nk = band.k_steps
+        key_tile, mask_tile = band.key_tiles(G, H)
+
     qspec = pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0))
-    kspec = pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0))
+    kspec = pl.BlockSpec((1, block_k, D), key_tile)
     rowq = pl.BlockSpec((1, block_q, 8), lambda bh, qi, ki: (bh, qi, 0))
-    rowk = pl.BlockSpec((1, 8, block_k),
-                        lambda bh, qi, ki: (bh // H, 0, ki))
+    rowk = pl.BlockSpec((1, 8, block_k), mask_tile)
 
     in_specs = [qspec, kspec, kspec, qspec, qspec, rowq]
     operands = [qb, kb, vb, ob, dob, lseb]
@@ -415,7 +545,8 @@ def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           masked=masked, block_q=block_q,
-                          block_k=block_k, nk=nk, precision=prec),
+                          block_k=block_k, nk=nk, precision=prec,
+                          **banded),
         out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype, vma=vma),
         grid=(B * H, nq, nk),
         in_specs=in_specs,
@@ -427,12 +558,23 @@ def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
         interpret=interpret,
     )(*operands)
 
-    # dk/dv grid: (bh, k block, q block) — q innermost, sequential
-    qspec2 = pl.BlockSpec((1, block_q, D), lambda bh, ki, qi: (bh, qi, 0))
+    # dk/dv grid: (bh, k block, q block) — q innermost, sequential;
+    # over a band (key head, k block, G x the band's q tiles)
+    nk = T // block_k
+    if band is None:
+        query_tile = lambda bh, ki, qi: (bh, qi, 0)
+        mask_tile2 = lambda bh, ki, qi: (bh // H, 0, ki)
+    else:
+        nq = G * band.q_steps
+        query_tile = lambda bh, ki, j: (
+            bh * G + j // band.q_steps,
+            jnp.minimum(band.first_q(ki) + j % band.q_steps,
+                        band.last_q(ki)), 0)
+        mask_tile2 = lambda bh, ki, j: (bh // K, 0, ki)
+    qspec2 = pl.BlockSpec((1, block_q, D), query_tile)
     kspec2 = pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0))
-    rowq2 = pl.BlockSpec((1, block_q, 8), lambda bh, ki, qi: (bh, qi, 0))
-    rowk2 = pl.BlockSpec((1, 8, block_k),
-                         lambda bh, ki, qi: (bh // H, 0, ki))
+    rowq2 = pl.BlockSpec((1, block_q, 8), query_tile)
+    rowk2 = pl.BlockSpec((1, 8, block_k), mask_tile2)
     in_specs2 = [qspec2, kspec2, kspec2, qspec2, qspec2, rowq2]
     operands2 = [qb, kb, vb, ob, dob, lseb]
     if masked:
@@ -441,10 +583,11 @@ def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           masked=masked, block_q=block_q,
-                          block_k=block_k, nq=nq, precision=prec),
-        out_shape=[jax.ShapeDtypeStruct((B * H, T, D), k.dtype, vma=vma),
-                   jax.ShapeDtypeStruct((B * H, T, D), v.dtype, vma=vma)],
-        grid=(B * H, nk, nq),
+                          block_k=block_k, nq=nq, precision=prec,
+                          **banded),
+        out_shape=[jax.ShapeDtypeStruct((B * K, T, D), k.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((B * K, T, D), v.dtype, vma=vma)],
+        grid=(B * K, nk, nq),
         in_specs=in_specs2,
         out_specs=[kspec2, kspec2],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
@@ -455,7 +598,7 @@ def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
     )(*operands2)
 
     def from_bht(x):
-        return x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+        return x.reshape(B, -1, T, D).transpose(0, 2, 1, 3)
     return from_bht(dq), from_bht(dk), from_bht(dv)
 
 
@@ -495,37 +638,60 @@ def _use_pallas_masked(T, block_q, block_k):
             and (block_k % 128 == 0 or block_k == T))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, block_q, block_k, precision):
+def _exact_band(q, k, v, window):
+    """Exact causal attention of ``H`` query heads over ``K``
+    key/value heads with an optional ``window`` (materializes the
+    scores): the path off a TPU and the kernels' oracle in tests."""
+    B, T, H, D = q.shape
+    K = k.shape[2]
+    s = jnp.einsum("btkgd,bnkd->bkgtn",
+                   q.reshape(B, T, K, H // K, D).astype(jnp.float32),
+                   k.astype(jnp.float32)) / math.sqrt(D)
+    i, j = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+    seen = j <= i
+    if window is not None:
+        seen = seen & (j > i - window)
+    p = jax.nn.softmax(jnp.where(seen, s, _NEG_INF), axis=-1)
+    o = jnp.einsum("bkgtn,bnkd->btkgd", p, v.astype(jnp.float32))
+    return o.reshape(B, T, H, D).astype(q.dtype)
+
+
+def _fallback(q, k, v, causal, block_k, window):
+    if window is None and q.shape[2] == k.shape[2]:
+        return _blockwise(q, k, v, causal, min(max(block_k, 8),
+                                               q.shape[1]))
+    return _exact_band(q, k, v, window)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, block_q, block_k, precision, window=None):
     if _use_pallas(q.shape[1], block_q, block_k):
         return pallas_flash_attention(q, k, v, causal=causal,
                                       block_q=block_q, block_k=block_k,
-                                      precision=precision)
-    return _blockwise(q, k, v, causal, min(max(block_k, 8), q.shape[1]))
+                                      precision=precision, window=window)
+    return _fallback(q, k, v, causal, block_k, window)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, precision):
+def _flash_fwd(q, k, v, causal, block_q, block_k, precision, window):
     if _use_pallas(q.shape[1], block_q, block_k):
         o, lse = pallas_flash_attention(
             q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-            precision=precision, return_lse=True)
+            precision=precision, return_lse=True, window=window)
         return o, (q, k, v, o, lse)
-    o = _blockwise(q, k, v, causal, min(max(block_k, 8), q.shape[1]))
-    return o, (q, k, v, None, None)
+    return _fallback(q, k, v, causal, block_k, window), (q, k, v, None,
+                                                         None)
 
 
-def _flash_bwd(causal, block_q, block_k, precision, res, g):
+def _flash_bwd(causal, block_q, block_k, precision, window, res, g):
     q, k, v, o, lse = res
     if lse is not None:
         return pallas_flash_attention_bwd(
             q, k, v, o, lse, g, causal=causal, block_q=block_q,
-            block_k=block_k, precision=precision)
+            block_k=block_k, precision=precision, window=window)
     # non-TPU fallback: recompute through the memory-efficient pure-jnp
     # blockwise formulation (no (T, T) scores live past a block)
-    T = q.shape[1]
     _, vjp = jax.vjp(
-        lambda a, b, c: _blockwise(a, b, c, causal,
-                                   min(max(block_k, 8), T)),
+        lambda a, b, c: _fallback(a, b, c, causal, block_k, window),
         q, k, v)
     return vjp(g)
 
@@ -624,8 +790,9 @@ def mesh_island(fn, mesh, q, k, v, kv_mask=None, *, seq_axis=None):
         size = mesh.shape.get(name, 1)
         return name if size > 1 and n % size == 0 else None
 
+    # grouped heads: the key heads divide, so the query heads do
     qspec = P(axis("data", q.shape[0]), seq_axis,
-              axis("model", q.shape[2]), None)
+              axis("model", k.shape[2]), None)
     operands, in_specs = (q, k, v), (qspec,) * 3
     if kv_mask is not None:
         operands += (kv_mask,)
@@ -636,7 +803,8 @@ def mesh_island(fn, mesh, q, k, v, kv_mask=None, *, seq_axis=None):
 
 def flash_attention(q, k, v, *, causal: bool = False,
                     block_q: int = 0, block_k: int = 0,
-                    precision: str = "default", kv_mask=None):
+                    precision: str = "default", kv_mask=None,
+                    window=None):
     """Dispatch: Pallas kernels on TPU (forward AND backward — the lse
     is persisted from the forward and p is recomputed per tile), the
     pure-jnp blockwise formulation elsewhere. Backend is decided
@@ -646,7 +814,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
     key-padding mask — variable-length batches KEEP the kernel
     (round-3 verdict weak #7); masked keys leave the softmax, padded
     query rows are the caller's to zero (reference masking contract,
-    nn/api/Layer.java:317).
+    nn/api/Layer.java:317). ``window`` (causal, no ``kv_mask``): query
+    ``i`` sees keys ``i - window < j <= i``; ``k`` and ``v`` may have
+    fewer heads than ``q`` (grouped heads): both go through the
+    kernels' band on a TPU and the exact einsum elsewhere.
 
     The choice is recorded, not silent: a ``flash_attention/<impl>``
     named scope around the call and a debug log line at trace time.
@@ -660,6 +831,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
         block_q = _auto_block(T, q.shape[3])
     if block_k <= 0:
         block_k = _auto_block(T, q.shape[3])
+    banded = window is not None or q.shape[2] != k.shape[2]
+    if banded and (kv_mask is not None or not causal):
+        raise ValueError("a window or grouped heads need causal=True "
+                         "and no kv_mask")
     if kv_mask is not None:
         kv_mask = float_kv_mask(kv_mask)
         impl = ("pallas" if _use_pallas_masked(T, block_q, block_k)
@@ -670,10 +845,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
                                  block_k, precision)
     else:
         impl = ("pallas" if _use_pallas(T, block_q, block_k)
-                else "blockwise")
+                else "exact_band" if banded else "blockwise")
 
         def fn(q, k, v):
-            return _flash(q, k, v, causal, block_q, block_k, precision)
+            return _flash(q, k, v, causal, block_q, block_k, precision,
+                          window)
 
     mesh = current_mesh()
     island = (mesh is not None and mesh.size > 1

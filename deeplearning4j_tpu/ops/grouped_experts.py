@@ -40,7 +40,22 @@ ids are that position compared against an iota and summed.
 
 ``grouped_pass`` says for which calls the layer takes this kernel; the
 dense pass stays the oracle, the path off a TPU and the path of every
-other call.
+other small call.
+
+That kernel keeps all rows in fast memory and has no backward pass: a
+serving step's. Off a serving step, past an MXU tile of rows
+(``pairs_pass``), the held experts' part is ``pairs_experts``: the
+(row, pick) pairs sorted by expert, the picked rows gathered, three
+grouped matrix products over the groups (``jax.lax.ragged_dot``: read
+on the chip beside the grouped-matmul kernels JAX ships, it took 7.86
+ms a layer against their 7.15 and needs no second route off a TPU),
+the result weighted and scatter-added in float32. It differentiates
+(rows, combine weights, all three weights), keeps nothing between its
+forward and backward pass but its inputs, and has no capacity: the
+sorted pairs are walked in chunks of ``N`` pairs, as many as the
+routing filled: one under even routing, up to ``min(top_k, held)``
+where every row picks only held experts, so no pair is dropped at any
+skew.
 """
 
 from __future__ import annotations
@@ -51,7 +66,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-__all__ = ["grouped_pass", "pallas_grouped_experts", "weight_bound"]
+__all__ = ["grouped_pass", "pallas_grouped_experts", "weight_bound",
+           "pairs_pass", "pairs_experts"]
 
 _F32 = jnp.float32
 # rows of an expert's group a tile: the MXU holds a 128 x 128 weight
@@ -330,3 +346,118 @@ def pallas_grouped_experts(x, sel, comb, w_gate, w_up, w_down, *,
         name="pallas_grouped_experts",
     )(expert, tile, counts, pos[:, None, :], rowid[:, None, :],
       comb[:, None, :], x, w_gate, w_up, w_down)
+
+
+# ---------------------------------------------------------------------
+# the pairs pass: whole sequences, forward and backward
+# ---------------------------------------------------------------------
+
+def pairs_pass(n: int) -> bool:
+    """Does a call of ``n`` rows that is no serving step run the held
+    experts over the selected pairs alone? Past one MXU tile of rows:
+    up to there the dense pass costs its weights' streaming time,
+    past it ``held / (top_k held / router_width)`` times the
+    arithmetic (16x at 8 of 128 held and 8 picks a row) and as many
+    ``(held, n, w)`` float32 temporaries."""
+    return n > _ROW_TILE
+
+
+def _plan(local, held: int, size: int):
+    """``local`` (N, k) int32, a pick's held expert or ``held`` for a
+    pick this share does not compute -> the pairs in expert order
+    (N k, whole chunks of ``size`` = N); the groups' bounds in that
+    order (held + 1,); how many chunks the share's pairs fill (one at
+    least)."""
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat).astype(jnp.int32)
+    counts = jnp.sum(flat[:, None] == jnp.arange(held, dtype=flat.dtype),
+                     axis=0, dtype=jnp.int32)
+    bounds = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                              jnp.cumsum(counts)])
+    return order, bounds, jnp.maximum(-(-bounds[-1] // size), 1)
+
+
+def _chunk(i, order, bounds, size: int, k: int):
+    """Chunk ``i`` of the sorted pairs: their rows, their places in
+    the (N k,) combine weights, which of them are pairs at all, and
+    the groups' sizes inside the chunk."""
+    lo = i * size
+    pair = jax.lax.dynamic_slice(order, (lo,), (size,))
+    valid = lo + jnp.arange(size, dtype=jnp.int32) < bounds[-1]
+    cut = jnp.clip(bounds, lo, lo + size)
+    return pair // k, pair, valid, cut[1:] - cut[:-1]
+
+
+def _chunk_rows(x, w, w_gate, w_up, w_down, rows, pair, valid, sizes):
+    """One chunk of pairs -> (chunk, d) float32, each pair's expert
+    over its row times its combine weight: what is added to the
+    result's rows ``rows``."""
+    keep = valid[:, None]
+
+    def dot(lhs, rhs):
+        # rows past the groups' end are no group's: on a TPU the
+        # product leaves them as the buffer was, and so does its
+        # transpose in the backward pass (read on the chip: a first
+        # gradient 5e6 times the reference's). Masked on both sides,
+        # so neither pass reads them.
+        return jnp.where(keep, jax.lax.ragged_dot(
+            jnp.where(keep, lhs, 0), rhs, sizes,
+            preferred_element_type=_F32), 0)
+
+    xs = x[rows]
+    h = (jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)).astype(x.dtype)
+    return dot(h, w_down) * jnp.where(
+        valid, w.reshape(-1)[pair], 0.0)[:, None]
+
+
+def _over_chunks(x, local, held: int, body, init):
+    """``body(chunk's places, carry)`` over the chunks the routing
+    filled (:func:`_plan`, :func:`_chunk`). The first chunk, the only
+    one unless the routing is skewed past ``N`` pairs, is in the
+    caller's own computation and not in a loop's body (a profiler
+    trace names its ops with the step's); the loop over the others
+    then runs no turn."""
+    size, k = x.shape[0], local.shape[1]
+    order, bounds, n_chunks = _plan(local, held, size)
+    turn = lambda i, carry: body(_chunk(i, order, bounds, size, k),
+                                 carry)
+    return jax.lax.fori_loop(1, n_chunks, turn, turn(0, init))
+
+
+@jax.custom_vjp
+def pairs_experts(x, local, w, w_gate, w_up, w_down):
+    """``x`` (N, d); ``local`` (N, k) int32, each pick's held expert
+    (0 .. E-1) or E for a pick to leave out (an absent expert's, an
+    inactive row's); ``w`` (N, k) float32 combine weights; ``w_gate``,
+    ``w_up`` (E, d, w) and ``w_down`` (E, w, d) -> (N, d) float32:
+    ``sum_j w[n, j] swiglu_{local[n, j]}(x[n])`` over the picks kept.
+    ``einsum_f32``'s arithmetic: float32 sums and activation,
+    ``silu(g) * u`` rounded once to ``x``'s dtype."""
+    def body(where, out):
+        return out.at[where[0]].add(_chunk_rows(
+            x, w, w_gate, w_up, w_down, *where))
+
+    return _over_chunks(x, local, w_gate.shape[0], body,
+                        jnp.zeros(x.shape, _F32))
+
+
+def _pairs_fwd(x, local, w, w_gate, w_up, w_down):
+    return (pairs_experts(x, local, w, w_gate, w_up, w_down),
+            (x, local, w, w_gate, w_up, w_down))
+
+
+def _pairs_bwd(res, g):
+    x, local, w, w_gate, w_up, w_down = res
+    diff = (x, w, w_gate, w_up, w_down)
+
+    def body(where, acc):
+        _, vjp = jax.vjp(lambda *a: _chunk_rows(*a, *where), *diff)
+        return jax.tree_util.tree_map(jnp.add, acc, vjp(g[where[0]]))
+
+    dx, dw, dg, du, dd = _over_chunks(
+        x, local, w_gate.shape[0], body,
+        jax.tree_util.tree_map(jnp.zeros_like, diff))
+    return dx, None, dw, dg, du, dd
+
+
+pairs_experts.defvjp(_pairs_fwd, _pairs_bwd)

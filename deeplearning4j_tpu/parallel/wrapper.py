@@ -795,11 +795,14 @@ class ParallelWrapper:
             # GSPMD over this mesh: announce it for whenever the call
             # traces, so kernel calls wrap themselves for it (the
             # manual seq step sets its own scope inside)
+            # (a network with expert layers returns their counts
+            # behind the loss: ``_train_step_fn``; the wrapper tallies
+            # none)
             with gspmd_mesh(self.mesh):
                 model.params, model.state, model.opt_state, loss = \
                     step(model.params, model.state, model.opt_state,
                          batch, model._rng_key,
-                         np.int32(model.iteration_count))
+                         np.int32(model.iteration_count))[:4]
         model.score_value = loss
         for lst in model.listeners:
             lst.iteration_done(model, model.iteration_count, loss, n)

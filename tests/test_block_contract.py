@@ -52,12 +52,22 @@ CASES = {
         norm_placement="post"),
     "gqa_post_width": lambda: GroupedQueryDecoderBlock(
         qk_norm="width", norm_placement="post"),
+    # Trinity's (afmoe): a norm on both sides of both branches, the
+    # attention's output gated; a window layer with a shared expert
+    "gqa_both_gated": lambda: GroupedQueryDecoderBlock(
+        qk_norm=True, out_gate=True, norm_placement="both"),
+    "gqa_window_shared": lambda: GroupedQueryDecoderBlock(
+        window=12, rotary_dim=8, qk_norm=True, out_gate=True,
+        norm_placement="both", n_shared_experts=1, **EXPERTS),
     "encoder": lambda: TransformerEncoderLayer(n_heads=2, causal=True),
 }
 
 _LATENT = "attn/Wkva attn/Wkvb attn/Wo attn/Wqa attn/Wqb attn/kv_gain " \
     "attn/q_gain"
 _NORMS = "norm1_gain norm2_gain"
+_NORMS4 = _NORMS + " norm1_post_gain norm2_post_gain"
+_GATED = "attn/Wgate attn/Wk attn/Wo attn/Wq attn/Wv attn/k_norm_gain " \
+    "attn/q_norm_gain"
 _ROUTED = "moe/Wd moe/Wg moe/Wr moe/Wu moe/br"
 _DELTA = "delta/A_log delta/Wa delta/Wb delta/Wg delta/Wk delta/Wo " \
     "delta/Wq delta/Wv delta/conv_w delta/dt_bias delta/g"
@@ -116,6 +126,13 @@ WANT = {
         "Wd Wg Wu attn/Wk attn/Wo attn/Wq attn/Wv attn/k_norm_gain "
         f"attn/q_norm_gain {_NORMS}", PagedCache(PAGES), False, CAP,
         {"k": ((13, PAGE, 16), _F32), "v": ((13, PAGE, 16), _F32)}),
+    "gqa_both_gated": (
+        f"Wd Wg Wu {_GATED} {_NORMS4}", PagedCache(PAGES), False, CAP,
+        {"k": ((13, PAGE, 16), _F32), "v": ((13, PAGE, 16), _F32)}),
+    "gqa_window_shared": (
+        f"{_GATED} {_ROUTED} moe/Wsd moe/Wsg moe/Wsu {_NORMS4}",
+        PagedCache(RING, ring_pages=3), True, PAGE,
+        {"k": ((10, PAGE, 16), _F32), "v": ((10, PAGE, 16), _F32)}),
     "encoder": (
         "W1 W2 attn/Wk attn/Wo attn/Wq attn/Wv attn/bo b1 b2 ln1_b ln1_g "
         "ln2_b ln2_g", PagedCache(PAGES), False, CAP,

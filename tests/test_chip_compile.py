@@ -127,6 +127,59 @@ def test_small_auto_block_compiles_for_v5e(topo):
     assert _kernels_in(jax.jit(loss).lower(x, x, x).compile()) == 3
 
 
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_band_compiles_for_v5e(topo, window, direction):
+    """The band kernels at ``trinity_train_8k``'s own shapes: one
+    sequence of 8,192, 32 query heads on 4 key heads of 128, float32,
+    tiles of 512; a window layer's grid walks 5 key tiles a row of
+    tiles, a full layer's all 16 (``tests/test_flash_band.py`` holds
+    the mathematics, interpreted)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    T, H, K, D = 8192, 32, 4, 128
+    blk = A._auto_block(T, D)
+    assert blk == 512
+    q = jax.ShapeDtypeStruct((1, T, H, D), jnp.float32, sharding=one)
+    k = jax.ShapeDtypeStruct((1, T, K, D), jnp.float32, sharding=one)
+    kw = dict(causal=True, block_q=blk, block_k=blk, window=window)
+    if direction == "fwd":
+        fn = lambda q, k, v: A.pallas_flash_attention(
+            q, k, v, return_lse=True, **kw)
+        args, want = (q, k, k), 1
+    else:
+        lse = jax.ShapeDtypeStruct((1, H, T), jnp.float32, sharding=one)
+        fn = lambda q, k, v, o, l, do: A.pallas_flash_attention_bwd(
+            q, k, v, o, l, do, **kw)
+        args, want = (q, k, k, q, lse, q), 2
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert _kernels_in(compiled) == want
+    out = compiled.output_shardings        # dk, dv a KEY head
+    assert len(jax.tree_util.tree_leaves(out)) == (2 if want == 1 else 3)
+
+
+def test_pairs_pass_compiles_for_v5e(topo, as_tpu):
+    """The held experts' pairs pass at ``trinity_mini_ep16``'s widths
+    (8,192 rows of 2048 through 8 held experts of 1024, float32
+    parameters), value and gradient, compiles for the chip as
+    ragged products, without a dense ``(held, rows, width)`` one."""
+    from deeplearning4j_tpu.ops import grouped_experts as ge
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one)
+    n, d, w, held, k = 8192, 2048, 1024, 8, 8
+    assert ge.pairs_pass(n)
+
+    def loss(x, local, cw, wg, wu, wd):
+        return jnp.sum(ge.pairs_experts(x, local, cw, wg, wu, wd) ** 2)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 2, 3, 4, 5))).lower(
+        sds((n, d)), sds((n, k), jnp.int32), sds((n, k)),
+        sds((held, d, w)), sds((held, d, w)), sds((held, w, d))
+    ).compile().as_text()
+    assert "ragged-dot" in text        # the chip's own grouped product
+    assert f"f32[{held},{n},{w}]" not in text
+
+
 @pytest.mark.parametrize("heads, head_dim, slots, t, dtype, by_table", [
     (16, 64, 8, 1, jnp.float32, True), (16, 64, 8, 16, jnp.float32, True),
     (16, 64, 64, 2, jnp.float32, True), (16, 64, 8, 16, jnp.bfloat16, True),

@@ -644,7 +644,7 @@ def test_every_new_field_round_trips_through_json(tiny_net):
     conf = tiny_net.conf
     assert MultiLayerConfiguration.from_json(
         conf.to_json()).to_json() == conf.to_json()
-    for bad in (dict(norm_placement="both"), dict(qk_norm="head")):
+    for bad in (dict(norm_placement="around"), dict(qk_norm="head")):
         with pytest.raises(ValueError):
             GroupedQueryDecoderBlock(n_in=16, **bad)._ensure_parts()
 
