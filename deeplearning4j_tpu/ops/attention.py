@@ -32,6 +32,19 @@ dk/dv kernel walks the ``G`` query heads of its key head in turn and
 sums them in its scratch. Without a window and with equal head counts
 the kernels trace as they did before there was a band.
 
+Operands arrive as ``(B, T, N, D)``. Where a head is whole lane tiles
+(``D % 128 == 0``) the query side is read and written where XLA leaves
+it, so no query-sized array is copied around a call: o and do are the
+``(B, T, H * D)`` arrays of the matmuls beside them, a block ``(1,
+block, D)`` at (batch, tile, head); q and dq are the same array with T
+last, the layout XLA runs per-head norms and rotations in, and the
+kernels turn the tile (q once a row of tiles, dq once at its row's end;
+the dk/dv kernel, which meets a new q tile a step, contracts it as it
+arrives). The key side, and every operand of a narrower head, which is
+part of a lane tile and cannot be a block of its own, is transposed to
+``(B * N, T, D)`` and back (:func:`_head_operands`). Grids, tiles and
+the arithmetic on a tile are the same in every form.
+
 ``precision`` selects the MXU mode: 'default' (bf16 passes — what XLA
 gives a plain f32 ``jnp.einsum``, so flash-vs-naive benches are
 apples-to-apples) or 'highest' (exact f32, 6-pass).
@@ -123,15 +136,12 @@ class _Band:
             (kb * self.bk + self.bk - 1 + self.window - 1) // self.bq,
             self.nq - 1)
 
-    def key_tiles(self, G, H):
-        """Index maps of a (bh, q tile, band step) grid for the key /
-        value blocks (a key head serves ``G`` query heads) and for the
-        key-padding mask's: past the band's last tile the index stays
-        on it, so nothing is fetched."""
-        kt = lambda qi, ki: jnp.minimum(self.first_k(qi) + ki,
-                                        self.last_k(qi))
-        return (lambda bh, qi, ki: (bh // G, kt(qi, ki), 0),
-                lambda bh, qi, ki: (bh // H, 0, kt(qi, ki)))
+    def key_tile(self, qi, ki):
+        """The key tile of band step ``ki`` in the row of tiles
+        ``qi``, for the index maps of a (bh, q tile, band step) grid:
+        past the band's last tile the index stays on it, so nothing is
+        fetched."""
+        return jnp.minimum(self.first_k(qi) + ki, self.last_k(qi))
 
     def mask(self, qi, ki):
         q_pos = qi * self.bq + jax.lax.broadcasted_iota(
@@ -147,9 +157,11 @@ class _Band:
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, masked,
-                block_q, block_k, nk, precision, band=None):
+                block_q, block_k, nk, precision, band=None, turned=False):
     from jax.experimental import pallas as pl
 
+    if turned:      # the q tile arrives (d, bq): turned once a row of
+        *rest, q_scr = rest                     # tiles, into q_scr
     if masked:      # optional (8, block_k) key-padding mask operand
         kmask_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -164,6 +176,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, masked,
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
+        if turned:
+            q_scr[:] = q_ref[0].T
 
     # causal tile skipping: a (qi, ki) tile entirely ABOVE the
     # diagonal (every key after every query) contributes nothing —
@@ -179,7 +193,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, masked,
 
     @pl.when(needed)
     def _tile():
-        q = q_ref[0]                              # (bq, d)
+        q = q_scr[:] if turned else q_ref[0]      # (bq, d)
         k = k_ref[0]                              # (bk, d)
         v = v_ref[0]
 
@@ -244,6 +258,75 @@ def _lanes8(x, B, T):
     return jnp.broadcast_to(x[:, None, :], (B, 8, T))
 
 
+def _heads_are_columns(shape) -> bool:
+    """Do the kernels read query-side ``(B, T, H, D)`` operands (q, o,
+    do, dq) where the projections leave them? A head of whole lane
+    tiles (``D`` a multiple of 128) is a column block of the ``(B, T,
+    H * D)`` array; a narrower head is part of a lane tile and cannot
+    be a block of its own."""
+    return shape[3] % 128 == 0
+
+
+def _head_operands(shape, form="heads"):
+    """The kernels' view of ``(B, T, N, D)`` operands as ``(view,
+    back, tile, at)``: ``view`` makes the array a call takes and
+    ``back`` undoes it on an output; ``tile(rows)`` is the block of
+    ``rows`` positions of one head, and ``at(n)(i, t)`` its index for
+    head ``i`` of ``B * n`` at tile ``t``. A tile holds the same
+    numbers in every form.
+
+    ``"heads"``: ``(B * N, T, D)``, transposed in XLA, a block ``(1,
+    rows, D)`` at ``(i, t, 0)``: any head size, and the key side
+    always (a key-sized operand is small enough for XLA to keep in
+    fast memory, where a head's slab is read in place and a column
+    block would be copied tile by tile).
+    ``"columns"``: the projections' own ``(B, T, N * D)``, nothing
+    moves, a block ``(1, rows, D)`` at (batch, tile, head).
+    ``"turned"``: ``(B, N * D, T)``, a block ``(1, D, rows)`` at
+    (batch, head, tile) that the kernel turns: XLA runs the per-head
+    norm and the rotation with T on the lanes and a matmul writes or
+    reads that layout for nothing, so q and dq pass without a copy.
+    One sequence needs no division (a scalar ``//`` and ``%`` an
+    operand a grid step are some 60 bundles of a 3,000-bundle step)."""
+    B, T, _, D = shape
+    if form == "heads":
+        return (lambda x: x.transpose(0, 2, 1, 3).reshape(-1, T, D),
+                lambda y: y.reshape(B, -1, T, D).transpose(0, 2, 1, 3),
+                lambda rows: (1, rows, D),
+                lambda n: lambda i, t: (i, t, 0))
+    where = lambda n, i: (0, i) if B == 1 else (i // n, i % n)
+    if form == "columns":
+        def at(n):
+            def index(i, t):
+                b, h = where(n, i)
+                return b, t, h
+            return index
+        return (lambda x: x.reshape(B, T, -1),
+                lambda y: y.reshape(B, T, -1, D),
+                lambda rows: (1, rows, D), at)
+    return (lambda x: x.reshape(B, T, -1).transpose(0, 2, 1),
+            lambda y: y.transpose(0, 2, 1).reshape(B, T, -1, D),
+            lambda rows: (1, D, rows),
+            lambda n: lambda i, t: (*where(n, i), t))
+
+
+def _call_operands(q_shape, k_shape):
+    """The forms of one call: ``(columns, q and dq, o and do, key
+    side)``, each of the last three as :func:`_head_operands` gives
+    it; ``columns`` says whether the kernels meet q turned."""
+    columns = _heads_are_columns(q_shape)
+    return (columns,
+            _head_operands(q_shape, "turned" if columns else "heads"),
+            _head_operands(q_shape, "columns" if columns else "heads"),
+            _head_operands(k_shape))
+
+
+def _key_head(bh, G):
+    """The key head, of ``B * K``, that query head ``bh`` of ``B * H``
+    reads: a key head serves ``G`` query heads."""
+    return bh if G == 1 else bh // G
+
+
 def _band_of(causal, window, block_q, block_k, T, G):
     """The band of a call, or None for the kernels as they were
     before there was one: no window and equal head counts."""
@@ -279,33 +362,32 @@ def pallas_flash_attention(q, k, v, kv_mask=None, *,
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, H, D = q.shape
-    G = H // k.shape[2]
+    K = k.shape[2]
+    G = H // K
     scale = 1.0 / math.sqrt(D)
-    # (B,T,N,D) -> (B*N, T, D)
-    def to_bht(x):
-        return x.transpose(0, 2, 1, 3).reshape(-1, T, D)
-    qb, kb, vb = to_bht(q), to_bht(k), to_bht(v)
+    (columns, (q_view, _, q_tile, q_at), (view, back, tile, at),
+     (key_view, _, key_tile, key_at)) = _call_operands(q.shape, k.shape)
+    qb, kb, vb = q_view(q), key_view(k), key_view(v)
     nq = T // block_q
     nk = T // block_k
     masked = kv_mask is not None
     vma = _vma_of(q, k, v)
     band = _band_of(causal, window, block_q, block_k, T, G)
-    if band is None:
-        key_tile = lambda bh, qi, ki: (bh, ki, 0)
-        mask_tile = lambda bh, qi, ki: (bh // H, 0, ki)
-    else:
-        nk = band.k_steps
-        key_tile, mask_tile = band.key_tiles(G, H)
+    kt = lambda qi, ki: ki
+    if band is not None:
+        nk, kt = band.k_steps, band.key_tile
+    key_block = lambda bh, qi, ki: key_at(K)(_key_head(bh, G), kt(qi, ki))
+    mask_tile = lambda bh, qi, ki: (bh // H, 0, kt(qi, ki))
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                masked=masked, block_q=block_q,
                                block_k=block_k, nk=nk,
-                               precision=_prec(precision),
+                               precision=_prec(precision), turned=columns,
                                **({} if band is None else {"band": band}))
     in_specs = [
-        pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-        pl.BlockSpec((1, block_k, D), key_tile),
-        pl.BlockSpec((1, block_k, D), key_tile),
+        pl.BlockSpec(q_tile(block_q), lambda bh, qi, ki: q_at(H)(bh, qi)),
+        pl.BlockSpec(key_tile(block_k), key_block),
+        pl.BlockSpec(key_tile(block_k), key_block),
     ]
     operands = [qb, kb, vb]
     if masked:
@@ -314,25 +396,26 @@ def pallas_flash_attention(q, k, v, kv_mask=None, *,
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, D), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct(jax.eval_shape(view, q).shape, q.dtype,
+                                 vma=vma),
             jax.ShapeDtypeStruct((B * H, T, 8), jnp.float32, vma=vma),
         ],
         grid=(B * H, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec(tile(block_q), lambda bh, qi, ki: at(H)(bh, qi)),
             pl.BlockSpec((1, block_q, 8), lambda bh, qi, ki: (bh, qi, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),      # running max
             pltpu.VMEM((block_q, 128), jnp.float32),      # running denom
             pltpu.VMEM((block_q, D), jnp.float32),        # accumulator
-        ],
+        ] + [pltpu.VMEM((block_q, D), q.dtype)] * columns,    # q, turned
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
-    o = out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    o = back(out)
     if return_lse:
         return o, lse[:, :, 0].reshape(B, H, T)
     return o
@@ -341,13 +424,14 @@ def pallas_flash_attention(q, k, v, kv_mask=None, *,
 # --------------------------------------------------------------- backward
 
 def _recompute_p(q, k, lse, scale, causal, qi, ki, block_q, block_k,
-                 precision, kmask=None, band=None):
+                 precision, kmask=None, band=None, q_head=1):
     """Recompute the (bq, bk) probability tile from q, k and the saved
     per-row logsumexp — exact softmax weights, no running max needed.
     ``kmask``: (1, bk) lane-oriented 0/1 — keys masked in the forward
     must recompute to p = 0, or the backward would leak gradient
-    through them."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+    through them. ``q_head``: the axis of ``q`` that holds a head's
+    values (0 for a tile that arrives (d, bq))."""
+    s = jax.lax.dot_general(q, k, (((q_head,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32,
                             precision=precision) * scale
     p = jnp.exp(s - lse[:, None])
@@ -370,9 +454,11 @@ def _row_delta(do, o):
 
 def _dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
                scale, causal, masked, block_q, block_k, nk, precision,
-               band=None):
+               band=None, turned=False):
     from jax.experimental import pallas as pl
 
+    if turned:      # q arrives and dq leaves (d, bq): turned once a row
+        *rest, q_scr = rest
     if masked:
         kmask_ref, dq_ref, dq_scr, delta_scr = rest
     else:
@@ -385,6 +471,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
     @pl.when(ki == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        if turned:
+            q_scr[:] = q_ref[0].T
         delta_scr[:] = jnp.broadcast_to(
             _row_delta(do_ref[0], o_ref[0])[:, None], delta_scr.shape)
 
@@ -399,7 +487,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
 
     @pl.when(needed)
     def _tile():
-        q = q_ref[0]
+        q = q_scr[:] if turned else q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0]
@@ -420,12 +508,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
 
     @pl.when(ki == nk - 1)
     def _finish():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq = dq_scr[:].T if turned else dq_scr[:]
+        dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
                 scale, causal, masked, block_q, block_k, nq,
-                precision, band=None):
+                precision, band=None, turned=False):
     from jax.experimental import pallas as pl
 
     if masked:
@@ -455,6 +544,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
 
     @pl.when(needed)
     def _tile():
+        # a new q tile a step: (d, bq) where turned, contracted as it
+        # arrives (turning it first costs 33 bundles a step more)
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
@@ -465,6 +556,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
         p = _recompute_p(q, k, lse, scale, causal, qt, kb,
                          block_q, block_k, precision,
                          kmask_ref[0][0:1, :] if masked else None,
+                         q_head=int(not turned),
                          **({} if band is None else {"band": band}))
         # dv += p^T @ do
         dv_scr[:] += jax.lax.dot_general(
@@ -476,7 +568,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
         ds = p * (dp - delta[:, None]) * scale
         # dk += ds^T @ q
         dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, (((0,), (int(turned),)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
 
     @pl.when(qi == nq - 1)
@@ -508,10 +600,11 @@ def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
     G = H // K
     scale = 1.0 / math.sqrt(D)
 
-    def to_bht(x):
-        return x.transpose(0, 2, 1, 3).reshape(-1, T, D)
-    qb, kb, vb = to_bht(q), to_bht(k), to_bht(v)
-    ob, dob = to_bht(o), to_bht(do)
+    (columns, (q_view, q_back, q_tile, q_at), (view, _, tile, at),
+     (key_view, key_back, key_tile, key_at)) = _call_operands(q.shape,
+                                                              k.shape)
+    qb, kb, vb = q_view(q), key_view(k), key_view(v)
+    ob, dob = view(o), view(do)
     # rows-on-sublanes layout with an 8-wide lane dim (see _fwd note)
     lseb = jnp.broadcast_to(lse.reshape(B * H, T)[:, :, None],
                             (B * H, T, 8))
@@ -525,19 +618,22 @@ def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
 
     band = _band_of(causal, window, block_q, block_k, T, G)
     banded = {} if band is None else {"band": band}
-    if band is None:
-        key_tile = lambda bh, qi, ki: (bh, ki, 0)
-        mask_tile = lambda bh, qi, ki: (bh // H, 0, ki)
-    else:
-        nk = band.k_steps
-        key_tile, mask_tile = band.key_tiles(G, H)
+    kt = lambda qi, ki: ki
+    if band is not None:
+        nk, kt = band.k_steps, band.key_tile
 
-    qspec = pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0))
-    kspec = pl.BlockSpec((1, block_k, D), key_tile)
+    qspec = pl.BlockSpec(q_tile(block_q),           # q in, dq out
+                         lambda bh, qi, ki: q_at(H)(bh, qi))
+    ospec = pl.BlockSpec(tile(block_q),             # o, do
+                         lambda bh, qi, ki: at(H)(bh, qi))
+    kspec = pl.BlockSpec(key_tile(block_k),
+                         lambda bh, qi, ki: key_at(K)(_key_head(bh, G),
+                                                      kt(qi, ki)))
     rowq = pl.BlockSpec((1, block_q, 8), lambda bh, qi, ki: (bh, qi, 0))
-    rowk = pl.BlockSpec((1, 8, block_k), mask_tile)
+    rowk = pl.BlockSpec((1, 8, block_k),
+                        lambda bh, qi, ki: (bh // H, 0, kt(qi, ki)))
 
-    in_specs = [qspec, kspec, kspec, qspec, qspec, rowq]
+    in_specs = [qspec, kspec, kspec, ospec, ospec, rowq]
     operands = [qb, kb, vb, ob, dob, lseb]
     if masked:
         in_specs.append(rowk)
@@ -546,13 +642,14 @@ def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           masked=masked, block_q=block_q,
                           block_k=block_k, nk=nk, precision=prec,
-                          **banded),
-        out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype, vma=vma),
+                          turned=columns, **banded),
+        out_shape=jax.ShapeDtypeStruct(qb.shape, q.dtype, vma=vma),
         grid=(B * H, nq, nk),
         in_specs=in_specs,
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32),
-                        pltpu.VMEM((block_q, 128), jnp.float32)],
+                        pltpu.VMEM((block_q, 128), jnp.float32)]
+        + [pltpu.VMEM((block_q, D), q.dtype)] * columns,      # q, turned
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -562,20 +659,24 @@ def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
     # over a band (key head, k block, G x the band's q tiles)
     nk = T // block_k
     if band is None:
-        query_tile = lambda bh, ki, qi: (bh, qi, 0)
-        mask_tile2 = lambda bh, ki, qi: (bh // H, 0, ki)
+        query_tile = lambda bh, ki, qi: (bh, qi)
     else:
         nq = G * band.q_steps
         query_tile = lambda bh, ki, j: (
             bh * G + j // band.q_steps,
             jnp.minimum(band.first_q(ki) + j % band.q_steps,
-                        band.last_q(ki)), 0)
-        mask_tile2 = lambda bh, ki, j: (bh // K, 0, ki)
-    qspec2 = pl.BlockSpec((1, block_q, D), query_tile)
-    kspec2 = pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0))
-    rowq2 = pl.BlockSpec((1, block_q, 8), query_tile)
-    rowk2 = pl.BlockSpec((1, 8, block_k), mask_tile2)
-    in_specs2 = [qspec2, kspec2, kspec2, qspec2, qspec2, rowq2]
+                        band.last_q(ki)))
+    qspec2 = pl.BlockSpec(q_tile(block_q),
+                          lambda *g: q_at(H)(*query_tile(*g)))
+    ospec2 = pl.BlockSpec(tile(block_q),
+                          lambda *g: at(H)(*query_tile(*g)))
+    kspec2 = pl.BlockSpec(key_tile(block_k),
+                          lambda bh, ki, qi: key_at(K)(bh, ki))
+    rowq2 = pl.BlockSpec((1, block_q, 8),
+                         lambda *g: (*query_tile(*g), 0))
+    rowk2 = pl.BlockSpec((1, 8, block_k),
+                         lambda bh, ki, qi: (bh // K, 0, ki))
+    in_specs2 = [qspec2, kspec2, kspec2, ospec2, ospec2, rowq2]
     operands2 = [qb, kb, vb, ob, dob, lseb]
     if masked:
         in_specs2.append(rowk2)
@@ -584,9 +685,9 @@ def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           masked=masked, block_q=block_q,
                           block_k=block_k, nq=nq, precision=prec,
-                          **banded),
-        out_shape=[jax.ShapeDtypeStruct((B * K, T, D), k.dtype, vma=vma),
-                   jax.ShapeDtypeStruct((B * K, T, D), v.dtype, vma=vma)],
+                          turned=columns, **banded),
+        out_shape=[jax.ShapeDtypeStruct(kb.shape, k.dtype, vma=vma),
+                   jax.ShapeDtypeStruct(kb.shape, v.dtype, vma=vma)],
         grid=(B * K, nk, nq),
         in_specs=in_specs2,
         out_specs=[kspec2, kspec2],
@@ -597,9 +698,7 @@ def pallas_flash_attention_bwd(q, k, v, o, lse, do, kv_mask=None, *,
         interpret=interpret,
     )(*operands2)
 
-    def from_bht(x):
-        return x.reshape(B, -1, T, D).transpose(0, 2, 1, 3)
-    return from_bht(dq), from_bht(dk), from_bht(dv)
+    return q_back(dq), key_back(dk), key_back(dv)
 
 
 # --------------------------------------------------------------- dispatch
@@ -820,7 +919,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
     kernels' band on a TPU and the exact einsum elsewhere.
 
     The choice is recorded, not silent: a ``flash_attention/<impl>``
-    named scope around the call and a debug log line at trace time.
+    named scope around the call and a debug log line at trace time
+    (``pallas``: the kernels on operands transposed to ``(B * N, T,
+    D)``; ``pallas_columns``: the query side read where it lies, see
+    :func:`_heads_are_columns`).
     In a GSPMD-partitioned step that announced its mesh
     (``parallel/seq_context.current_mesh``) the call runs as a
     :func:`mesh_island`; inside somebody's ``shard_map`` it runs on
@@ -835,16 +937,18 @@ def flash_attention(q, k, v, *, causal: bool = False,
     if banded and (kv_mask is not None or not causal):
         raise ValueError("a window or grouped heads need causal=True "
                          "and no kv_mask")
+    # which operand form the kernels take, in the scope and the log
+    pallas = "pallas_columns" if _heads_are_columns(q.shape) else "pallas"
     if kv_mask is not None:
         kv_mask = float_kv_mask(kv_mask)
-        impl = ("pallas" if _use_pallas_masked(T, block_q, block_k)
+        impl = (pallas if _use_pallas_masked(T, block_q, block_k)
                 else "exact_masked")
 
         def fn(q, k, v, kv_mask):
             return _flash_masked(q, k, v, kv_mask, causal, block_q,
                                  block_k, precision)
     else:
-        impl = ("pallas" if _use_pallas(T, block_q, block_k)
+        impl = (pallas if _use_pallas(T, block_q, block_k)
                 else "exact_band" if banded else "blockwise")
 
         def fn(q, k, v):

@@ -7,7 +7,9 @@ partitioner would refuse on the chip is refused here, at no chip time
 these cases say nothing about results or times.
 
 Covered: the flash kernels of the main path at real widths, forward
-and backward, plain and key-masked; the same kernels under a
+and backward, plain and key-masked (a head of 64 through transposed
+operands, a head of 128 read where the projections leave it, with no
+relayout around the calls: ``_relayouts_in``); the same kernels under a
 four-device ``data`` mesh both ways a user reaches them —
 ``fit(mesh_spec=)``'s GSPMD step (a Mosaic call has no partitioning
 rule: ``flash_attention`` must open its own shard_map island) and
@@ -76,6 +78,25 @@ def _kernels_in(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+_ITEM = {"f32": 4, "bf16": 2}
+
+
+def _relayouts_in(compiled, at_least=1 << 20):
+    """The ``copy`` / ``transpose`` / ``reshape`` instructions of the
+    compiled module (fused ones too; a reshape that costs nothing is a
+    ``bitcast`` by now) over float arrays of ``at_least`` bytes, as
+    ``(op, bytes)``: what moves operands between the layout a producer
+    leaves and the one a kernel asks for."""
+    found = []
+    for dtype, dims, op in re.findall(
+            r" = (f32|bf16)\[([\d,]+)\]\S* (copy|transpose|reshape)\(",
+            compiled.as_text()):
+        size = _ITEM[dtype] * int(np.prod([int(d) for d in dims.split(",")]))
+        if size >= at_least:
+            found.append((op, size))
+    return found
+
+
 # ---- single chip: the kernels themselves ---------------------------------
 
 @pytest.mark.parametrize("shape", [(8, 1024, 16, 64), (2, 1024, 8, 128)],
@@ -107,6 +128,8 @@ def test_flash_kernel_compiles_for_v5e(topo, shape, masked, direction):
         want = 2                  # dq, fused dk/dv
     compiled = jax.jit(fn).lower(*args).compile()
     assert _kernels_in(compiled) == want
+    if D == 64:     # half a lane tile: the transposed operands, as before
+        assert _relayouts_in(compiled)
 
 
 def test_small_auto_block_compiles_for_v5e(topo):
@@ -134,25 +157,43 @@ def test_flash_band_compiles_for_v5e(topo, window, direction):
     sequence of 8,192, 32 query heads on 4 key heads of 128, float32,
     tiles of 512; a window layer's grid walks 5 key tiles a row of
     tiles, a full layer's all 16 (``tests/test_flash_band.py`` holds
-    the mathematics, interpreted)."""
+    the mathematics, interpreted). The operands come and go as the
+    layer's XLA side holds them: o and do ``(1, T, H * 128)``, what a
+    matmul reads and writes; q and dq the same array with T last, the
+    layout the per-head norm and the rotation run in; all split into
+    heads by a reshape. A head of 128 is a block of those arrays, so
+    nothing of 134 MB (a query-sized operand) is copied, transposed
+    or re-laid around the calls; the key side (17 MB an operand) is,
+    into the head-major slabs the kernels read from fast memory."""
     one = SingleDeviceSharding(topo.devices[0])
     T, H, K, D = 8192, 32, 4, 128
     blk = A._auto_block(T, D)
     assert blk == 512
-    q = jax.ShapeDtypeStruct((1, T, H, D), jnp.float32, sharding=one)
-    k = jax.ShapeDtypeStruct((1, T, K, D), jnp.float32, sharding=one)
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                              sharding=one)
+    q, o, k = sds(1, H * D, T), sds(1, T, H * D), sds(1, T, K * D)
     kw = dict(causal=True, block_q=blk, block_k=blk, window=window)
+    heads = lambda x: x.reshape(1, T, -1, D)
+    wide = lambda x: x.reshape(1, T, -1)
+    turn = lambda x: x.transpose(0, 2, 1)
     if direction == "fwd":
-        fn = lambda q, k, v: A.pallas_flash_attention(
-            q, k, v, return_lse=True, **kw)
+        def fn(q, k, v):
+            o, lse = A.pallas_flash_attention(
+                heads(turn(q)), heads(k), heads(v), return_lse=True, **kw)
+            return wide(o), lse
         args, want = (q, k, k), 1
     else:
-        lse = jax.ShapeDtypeStruct((1, H, T), jnp.float32, sharding=one)
-        fn = lambda q, k, v, o, l, do: A.pallas_flash_attention_bwd(
-            q, k, v, o, l, do, **kw)
-        args, want = (q, k, k, q, lse, q), 2
+        lse = sds(1, H, T)
+
+        def fn(q, k, v, o, l, do):
+            dq, dk, dv = A.pallas_flash_attention_bwd(
+                heads(turn(q)), heads(k), heads(v), heads(o), l, heads(do),
+                **kw)
+            return turn(wide(dq)), wide(dk), wide(dv)
+        args, want = (q, k, k, o, lse, o), 2
     compiled = jax.jit(fn).lower(*args).compile()
     assert _kernels_in(compiled) == want
+    assert not _relayouts_in(compiled, at_least=T * H * D * 4)
     out = compiled.output_shardings        # dk, dv a KEY head
     assert len(jax.tree_util.tree_leaves(out)) == (2 if want == 1 else 3)
 
@@ -880,6 +921,7 @@ def test_flash_under_gspmd_data_mesh(topo, as_tpu, masked):
     assert _kernels_in(compiled) == 3          # fwd, dq, dk/dv
     # the island really splits the batch: 8 examples -> 2 per device
     assert "bf16[2,1024,16,64]" in compiled.as_text()
+    assert _relayouts_in(compiled)      # a head of 64: transposed operands
 
 
 def test_flash_under_gspmd_dp_tp_mesh(topo, as_tpu):
@@ -921,7 +963,9 @@ def test_flash_inside_manual_shard_map(topo, as_tpu, masked):
     step = jax.shard_map(per_device, mesh=mesh, in_specs=specs,
                          out_specs=(P(), (P("data"),) * 3),
                          check_vma=True)
-    assert _kernels_in(jax.jit(step).lower(*args).compile()) == 3
+    compiled = jax.jit(step).lower(*args).compile()
+    assert _kernels_in(compiled) == 3
+    assert _relayouts_in(compiled)      # a head of 64: transposed operands
 
 
 def test_ring_island_on_dp_tp_sp_mesh(topo, as_tpu):
@@ -949,3 +993,4 @@ def test_ring_island_on_dp_tp_sp_mesh(topo, as_tpu):
     compiled = jax.jit(jax.grad(loss)).lower(params, x).compile()
     assert _kernels_in(compiled) >= 3
     assert "collective-permute" in compiled.as_text()
+    assert _relayouts_in(compiled)      # a head of 64: transposed operands
