@@ -93,8 +93,10 @@ class Phases:
               "seconds": round(time.perf_counter() - t0, 2),
               "compile_seconds": s["compile_secs"],
               "backend_compiles": s["backend_compiles"],
+              "cold_compiles": s["cold_compiles"],
               "cache_requests": s["cache_requests"],
               "persistent_cache_hits": s["persistent_cache_hits"],
+              "cache_hit": s["cache_hit"],
               "checked": checked})
         return checked
 
@@ -585,8 +587,10 @@ def main(argv=None) -> int:
     emit({"total_seconds": round(time.perf_counter() - t0, 2),
           "compile_seconds": s["compile_secs"],
           "backend_compiles": s["backend_compiles"],
+          "cold_compiles": s["cold_compiles"],
           "cache_requests": s["cache_requests"],
           "persistent_cache_hits": s["persistent_cache_hits"],
+          "cache_hit": s["cache_hit"],
           "compile_cache_dir": device["compile_cache_dir"]})
     result = {"ok": on_tpu and not args.rehearse,
               "device": {k: device[k]

@@ -536,7 +536,8 @@ def _cmd_serve(args):
     server.start()
     print(f"serving on http://{args.host}:{server.port}/ "
           f"(/v1/predict /v1/generate /v1/models /healthz /metrics "
-          f"/debug/requests /debug/slots /debug/traces; trace "
+          f"/debug/requests /debug/slots /debug/traces "
+          f"/debug/startup; trace "
           f"sampling {args.trace_sample:g}; ctrl-c drains and stops)")
     try:
         while True:
@@ -1446,11 +1447,15 @@ def main(argv=None):
     if args.trace:
         import atexit
 
-        from deeplearning4j_tpu.observability.tracing import trace
+        from deeplearning4j_tpu.observability.compile_watch import (
+            install_global_watch)
+        from deeplearning4j_tpu.observability.tracing import (
+            startup, trace)
         trace.enable()
+        install_global_watch()     # the set-up's xla/* spans
 
         def _dump(path=args.trace):
-            n = trace.export_chrome_trace(path)
+            n = trace.export_chrome_trace(path, also=(startup,))
             print(f"trace written: {path} ({n} events)")
 
         atexit.register(_dump)

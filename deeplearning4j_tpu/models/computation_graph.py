@@ -25,7 +25,8 @@ import optax
 
 from deeplearning4j_tpu.data.dataset import DataSet, MultiDataSet
 from deeplearning4j_tpu.nn.conf import updaters as updaters_mod
-from deeplearning4j_tpu.models.kstep import KStepExecutorMixin
+from deeplearning4j_tpu.models.kstep import (KStepExecutorMixin,
+                                             _tree_nbytes)
 from deeplearning4j_tpu.nn.conf.graph_conf import (
     ComputationGraphConfiguration,
 )
@@ -53,7 +54,7 @@ class ComputationGraph(KStepExecutorMixin):
         self._jit_train_step = None
         self._jit_tbptt_step = None
         # train programs told to observability.programs, by name
-        # (kstep._register_program)
+        # (kstep._first_call)
         self._registered: Dict[str, Any] = {}
         # k-step fused programs (models/kstep.py): dict k -> jitted
         # scan program, plus AOT-compiled executables keyed by batch
@@ -74,25 +75,30 @@ class ComputationGraph(KStepExecutorMixin):
 
     # ------------------------------------------------------------------
     def init(self, seed: Optional[int] = None) -> "ComputationGraph":
+        from deeplearning4j_tpu.observability.tracing import startup
         seed = self.conf.conf.seed if seed is None else seed
-        key = jax.random.PRNGKey(seed)
-        self._rng_key = jax.random.fold_in(key, 0xC6)
-        order = self.conf.topological_order()
-        params, states = {}, {}
-        keys = jax.random.split(key, max(len(order), 1))
-        for k, name in zip(keys, order):
-            obj, ins = self.conf.vertices[name]
-            if isinstance(obj, Layer):
-                it = self.conf.vertex_input_type(name)
-                p, s = obj.initialize(k, it)
-                params[name] = p
-                states[name] = s
-        self.params = params
-        self.state = states
-        self._build_optimizer()
+        with startup.span("setup/init") as sp:
+            key = jax.random.PRNGKey(seed)
+            self._rng_key = jax.random.fold_in(key, 0xC6)
+            order = self.conf.topological_order()
+            params, states = {}, {}
+            keys = jax.random.split(key, max(len(order), 1))
+            for k, name in zip(keys, order):
+                obj, ins = self.conf.vertices[name]
+                if isinstance(obj, Layer):
+                    it = self.conf.vertex_input_type(name)
+                    p, s = obj.initialize(k, it)
+                    params[name] = p
+                    states[name] = s
+            self.params = params
+            self.state = states
+            sp.set("layers", len(params))
+            sp.set("param_bytes", _tree_nbytes(params))
+            self._build_optimizer()
         return self
 
     def _build_optimizer(self):
+        from deeplearning4j_tpu.observability.tracing import startup
         global_cfg = self.conf.conf.updater_cfg or updaters_mod.sgd()
         overrides = {name: getattr(obj, "updater", None)
                      for name, (obj, _) in self.conf.vertices.items()
@@ -117,7 +123,9 @@ class ComputationGraph(KStepExecutorMixin):
             pre = (optax.clip_by_global_norm(clip["v"])
                    if clip["type"] == "norm" else optax.clip(clip["v"]))
             self._optimizer = optax.chain(pre, self._optimizer)
-        self.opt_state = self._optimizer.init(self.params)
+        with startup.span("setup/init/optimizer") as sp:
+            self.opt_state = self._optimizer.init(self.params)
+            sp.set("state_bytes", _tree_nbytes(self.opt_state))
         self._jit_train_step = None
         self._jit_tbptt_step = None
         self._jit_kstep = {}

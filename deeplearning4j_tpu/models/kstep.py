@@ -97,9 +97,11 @@ def stack_batches(batch_tuples: Sequence):
 
 
 def _tree_nbytes(tree) -> int:
-    """Bytes of a batch's arrays as they cross to the device."""
+    """Bytes of a tree's arrays (a batch as it crosses to the device,
+    a network's parameters); a leaf that is no array counts nothing."""
     import jax
-    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree))
+    return sum(int(getattr(x, "nbytes", 0))
+               for x in jax.tree_util.tree_leaves(tree))
 
 
 # the k=1 lookahead has pulled nothing (None is an exhausted iterator)
@@ -383,18 +385,26 @@ class KStepExecutorMixin:
                 setattr(shell, key, None)
         return shell
 
-    def _register_program(self, name: str, jitted, fn_of, args) -> None:
-        """Tell ``observability.programs`` of the train program
-        ``jitted`` (built by this executor) about to run on ``args``
-        for the first time. ``fn_of(executor)`` makes its Python
-        function: it is called on :meth:`_without_arrays`, so what the
-        registry keeps holds no parameter."""
+    def _first_call(self, name: str, jitted, fn_of, call, args):
+        """The first call of the train program ``jitted`` (built by
+        this executor), whole, under one ``setup/program`` span of the
+        set-up timeline: ``observability.programs`` is told of it,
+        then ``call(*args)`` runs it (trace, lowering, compile or
+        load, dispatch). ``fn_of(executor)`` makes the program's
+        Python function: it is called on :meth:`_without_arrays`, so
+        what the registry keeps holds no parameter."""
         from deeplearning4j_tpu.observability import programs
-        self._registered[name] = jitted
-        programs.register(
-            name, fn_of(self._without_arrays()),
-            self._train_step_jit_kwargs() if name == "train_step"
-            else _carry_jit_kwargs(self._mesh_out_shardings()), args)
+        from deeplearning4j_tpu.observability.tracing import (
+            startup, trace)
+        with startup.span("setup/program", {"program": name}):
+            self._registered[name] = jitted
+            programs.register(
+                name, fn_of(self._without_arrays()),
+                self._train_step_jit_kwargs() if name == "train_step"
+                else _carry_jit_kwargs(self._mesh_out_shardings()),
+                args)
+            with trace.span("enqueue"):
+                return call(*args)
 
     def _fit_epoch(self, data_iter, k: int, tbptt) -> None:
         """One epoch's batch loop (shared by both executors' ``fit``):
@@ -519,11 +529,13 @@ class KStepExecutorMixin:
                     self._rng_key, np.int32(self.iteration_count))
             if self._registered.get("train_step") \
                     is not self._jit_train_step:
-                self._register_program(
+                out = self._first_call(
                     "train_step", self._jit_train_step,
-                    KStepExecutorMixin._train_step_fn, args)
-            with trace.span("enqueue"):
-                out = self._step_fn_for(batch)(*args)
+                    KStepExecutorMixin._train_step_fn,
+                    self._step_fn_for(batch), args)
+            else:
+                with trace.span("enqueue"):
+                    out = self._step_fn_for(batch)(*args)
         if self.counts_experts:
             *out, counts = out
             self._pending_counts += (counts,)
@@ -674,17 +686,18 @@ class KStepExecutorMixin:
             args = (self.params, self.state, self.opt_state, window,
                     self._rng_key, np.int32(self.iteration_count))
             name = f"train_step_fused/k={k}"
-            if self._registered.get(name) is not self._jit_kstep.get(k):
-                health = self._health_enabled
-                self._register_program(
-                    name, self._jit_kstep[k],
-                    lambda net: kstep_fn(net._train_core, k, health),
-                    args)
             t1 = time.perf_counter()
             # without a mesh the window is still on the host here: its
             # copy rides the call
-            with trace.span("enqueue"):
-                out = fn(*args)
+            if self._registered.get(name) is not self._jit_kstep.get(k):
+                health = self._health_enabled
+                out = self._first_call(
+                    name, self._jit_kstep[k],
+                    lambda net: kstep_fn(net._train_core, k, health),
+                    fn, args)
+            else:
+                with trace.span("enqueue"):
+                    out = fn(*args)
             fused.set("steps", k)
         _h2d_wait(trace, window)
         health_host = None
@@ -735,6 +748,7 @@ def warmup_train_programs(model, batch_np, k: int) -> Dict[str, float]:
     ``_health_enabled`` and live ``params/state/opt_state/_rng_key``
     (call after ``init()``; the executor's ``warmup()`` method
     handles that)."""
+    from deeplearning4j_tpu.observability.tracing import startup
     out: Dict[str, float] = {}
     # under a mesh context the lowered batch/window signatures carry
     # the data shardings dispatch will use — a sharding-less lowering
@@ -745,7 +759,8 @@ def warmup_train_programs(model, batch_np, k: int) -> Dict[str, float]:
              model._rng_key, np.int32(0))
     key1 = ("train1", signature(batch_np))
     if key1 not in model._aot:
-        compiled, secs = aot_compile(model._jit_train_step, args1)
+        with startup.span("setup/program", {"program": "train_step"}):
+            compiled, secs = aot_compile(model._jit_train_step, args1)
         model._aot[key1] = compiled
         out["train_step"] = secs
     if k > 1:
@@ -758,7 +773,10 @@ def warmup_train_programs(model, batch_np, k: int) -> Dict[str, float]:
             window_ex = ctx.abstract_window(window) if ctx else window
             argsk = (model.params, model.state, model.opt_state,
                      window_ex, model._rng_key, np.int32(0))
-            compiled, secs = aot_compile(fn, argsk)
+            with startup.span(
+                    "setup/program",
+                    {"program": f"train_step_fused/k={k}"}):
+                compiled, secs = aot_compile(fn, argsk)
             model._aot[keyk] = compiled
             out[f"kstep_{k}"] = secs
     return out

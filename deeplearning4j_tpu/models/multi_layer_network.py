@@ -42,7 +42,8 @@ from deeplearning4j_tpu.nn.conf.layers.output import (
     CenterLossOutputLayer, OutputLayer,
 )
 from deeplearning4j_tpu.nn.conf.layers.recurrent import BaseRecurrentLayer
-from deeplearning4j_tpu.models.kstep import KStepExecutorMixin
+from deeplearning4j_tpu.models.kstep import (KStepExecutorMixin,
+                                             _tree_nbytes)
 from deeplearning4j_tpu.nn.conf.multi_layer import MultiLayerConfiguration
 from deeplearning4j_tpu.train.constraints import apply_layer_constraints
 
@@ -80,7 +81,7 @@ class MultiLayerNetwork(KStepExecutorMixin):
         self._jit_train_step = None
         self._jit_tbptt_step = None
         # train programs told to observability.programs, by name
-        # (kstep._register_program)
+        # (kstep._first_call)
         self._registered: Dict[str, Any] = {}
         # k-step fused programs (models/kstep.py): dict k -> jitted
         # scan program, plus AOT-compiled executables keyed by batch
@@ -108,28 +109,33 @@ class MultiLayerNetwork(KStepExecutorMixin):
     # init (reference MultiLayerNetwork.init :396-554)
     # ------------------------------------------------------------------
     def init(self, seed: Optional[int] = None) -> "MultiLayerNetwork":
+        from deeplearning4j_tpu.observability.tracing import startup
         seed = self.conf.conf.seed if seed is None else seed
-        key = jax.random.PRNGKey(seed)
-        self._rng_key = jax.random.fold_in(key, 0xD1)
-        params, states = [], []
-        t = self.conf.input_type
-        keys = jax.random.split(key, max(len(self.layers), 1))
-        for i, layer in enumerate(self.layers):
-            if t is not None and i in self.conf.preprocessors:
-                t = self.conf.preprocessors[i].output_type(t)
-            if t is not None:
-                layer.set_n_in(t)
-            p, s = layer.initialize(keys[i], t)
-            params.append(p)
-            states.append(s)
-            if t is not None:
-                t = layer.output_type(t)
-        self.params = params
-        self.state = states
-        self._build_optimizer()
+        with startup.span("setup/init",
+                          {"layers": len(self.layers)}) as sp:
+            key = jax.random.PRNGKey(seed)
+            self._rng_key = jax.random.fold_in(key, 0xD1)
+            params, states = [], []
+            t = self.conf.input_type
+            keys = jax.random.split(key, max(len(self.layers), 1))
+            for i, layer in enumerate(self.layers):
+                if t is not None and i in self.conf.preprocessors:
+                    t = self.conf.preprocessors[i].output_type(t)
+                if t is not None:
+                    layer.set_n_in(t)
+                p, s = layer.initialize(keys[i], t)
+                params.append(p)
+                states.append(s)
+                if t is not None:
+                    t = layer.output_type(t)
+            self.params = params
+            self.state = states
+            sp.set("param_bytes", _tree_nbytes(params))
+            self._build_optimizer()
         return self
 
     def _build_optimizer(self):
+        from deeplearning4j_tpu.observability.tracing import startup
         global_cfg = self.conf.conf.updater_cfg or updaters_mod.sgd()
         overrides = [getattr(l, "updater", None) for l in self.layers]
         if any(o is not None for o in overrides):
@@ -155,7 +161,9 @@ class MultiLayerNetwork(KStepExecutorMixin):
             else:
                 raise ValueError(clip)
             self._optimizer = optax.chain(pre, self._optimizer)
-        self.opt_state = self._optimizer.init(self.params)
+        with startup.span("setup/init/optimizer") as sp:
+            self.opt_state = self._optimizer.init(self.params)
+            sp.set("state_bytes", _tree_nbytes(self.opt_state))
         self._jit_train_step = None    # invalidate
         self._jit_tbptt_step = None
         self._jit_kstep = {}

@@ -448,6 +448,16 @@ class PagedSlotSession:
                  page_size: int = 16, n_pages: Optional[int] = None,
                  dtype=None):
         import jax
+        from deeplearning4j_tpu.observability.tracing import startup
+        with startup.span("setup/session", {
+                "slots": int(slots), "capacity": int(capacity)}) as sp:
+            self._build(net, slots, capacity, page_size, n_pages, dtype)
+            sp.set("pool_bytes", sum(
+                leaf.nbytes
+                for leaf in jax.tree_util.tree_leaves(self._pools)))
+
+    def _build(self, net, slots, capacity, page_size, n_pages, dtype):
+        import jax
         import jax.numpy as jnp
         for i, layer in enumerate(net.layers):
             if _no_paged_analog(layer):
@@ -514,7 +524,7 @@ class PagedSlotSession:
         # the next call's ``prev_ids``
         self._step_ids = None
         # (kind, t) of the step programs that have run
-        # (``_register_program``)
+        # (``_first_call``)
         self._registered = set()
         self._prev_ids = jnp.zeros((self.slots,), jnp.int32)
         cached = [i for i, c in enumerate(caches) if c]
@@ -1056,16 +1066,22 @@ class PagedSlotSession:
                                                   self._dtype)
             for i in self._aux_layers)
 
-    def _register_program(self, kind: str, t: int, jitted, args) -> None:
-        """Tell ``observability.programs`` of a step program about to
-        run on ``args`` for the first time, as ``<kind>/t=<rows a
-        slot>``. The closures of
-        ``_make_step`` hold the layers and nothing of ``net.params``,
-        so the registry keeps no array alive through them."""
+    def _first_call(self, kind: str, t: int, jitted, args):
+        """The first call of a step program, whole, under one
+        ``setup/program`` span of the set-up timeline:
+        ``observability.programs`` is told of it as ``<kind>/t=<rows
+        a slot>``, then ``jitted(*args)`` runs it (trace, lowering,
+        compile or load, dispatch). The closures of ``_make_step``
+        hold the layers and nothing of ``net.params``, so the registry
+        keeps no array alive through them."""
         from deeplearning4j_tpu.observability import programs
-        self._registered.add((kind, t))
-        programs.register(f"{kind}/t={t}", jitted.__wrapped__,
-                          _DONATE_POOLS, args)
+        from deeplearning4j_tpu.observability.tracing import startup
+        name = f"{kind}/t={t}"
+        with startup.span("setup/program", {"program": name}):
+            self._registered.add((kind, t))
+            programs.register(name, jitted.__wrapped__, _DONATE_POOLS,
+                              args)
+            return jitted(*args)
 
     def _note_kv_read(self, t: int, lengths) -> None:
         """``step_kv_positions`` of a step at ``t`` rows a slot, from
@@ -1163,11 +1179,13 @@ class PagedSlotSession:
         if self._aux_layers:
             args += (jnp.asarray(active),)
         if ("paged_step", 1) not in self._registered:
-            self._register_program("paged_step", 1, self._step, args)
-        if self._aux_layers:
-            h, self._pools, self.step_aux = self._step(*args)
+            out = self._first_call("paged_step", 1, self._step, args)
         else:
-            h, self._pools = self._step(*args)
+            out = self._step(*args)
+        if self._aux_layers:
+            h, self._pools, self.step_aux = out
+        else:
+            h, self._pools = out
         self._note_state(active)
         self.slot_pos = self.slot_pos + active.astype(
             self.slot_pos.dtype)
@@ -1222,9 +1240,10 @@ class PagedSlotSession:
                 jnp.asarray(self._table), jnp.asarray(pos), x, None,
                 jnp.asarray(n_valid))
         if ("paged_step_chunk", t) not in self._registered:
-            self._register_program("paged_step_chunk", t, self._step,
+            out = self._first_call("paged_step_chunk", t, self._step,
                                    args)
-        out = self._step(*args)
+        else:
+            out = self._step(*args)
         if self._aux_layers:
             h, self._pools, self.step_aux = out
         else:
@@ -1272,9 +1291,10 @@ class PagedSlotSession:
         args = (self.net.params, self.net.state, self._pools, table,
                 pos, x, n_valid, self._prev_ids, use_prev)
         if ("paged_step_ids", t) not in self._registered:
-            self._register_program("paged_step_ids", t, self._step_ids,
+            out = self._first_call("paged_step_ids", t, self._step_ids,
                                    args)
-        out = self._step_ids(*args)
+        else:
+            out = self._step_ids(*args)
         if self._aux_layers:
             ids, finite, self._pools, self.step_aux = out
         else:

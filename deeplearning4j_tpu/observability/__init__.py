@@ -7,8 +7,9 @@ tracing + compile/runtime-attribution subsystem TensorFlow
 (arXiv:1605.08695) treats as first-class):
 
 - ``tracing``        nested spans -> JSONL / Chrome trace (Perfetto)
-- ``compile_watch``  every XLA compile logged with shapes; cache
-                     hit/miss accounting; recompile-storm trip-wire
+- ``compile_watch``  every trace / lowering / compile of the process
+                     by function, persistent-cache loads apart from
+                     cold compiles; recompile-storm trip-wire
 - ``registry``       process-wide counters/gauges/histograms with
                      Prometheus text exposition
 - ``step_profile``   data-wait / dispatch / device decomposition +
@@ -33,7 +34,8 @@ from deeplearning4j_tpu.observability.alerts import (
     AlertManager, AlertRule,
 )
 from deeplearning4j_tpu.observability.compile_watch import (
-    CompileWatcher, RecompileStormError, install_global_watch, watch,
+    GlobalCompileStats, RecompileStormError, SteadyStateCompileError,
+    install_global_watch,
 )
 from deeplearning4j_tpu.observability.flight_recorder import (
     FlightRecorder,
@@ -53,16 +55,17 @@ from deeplearning4j_tpu.observability.step_profile import (
 )
 from deeplearning4j_tpu.observability.tracing import (
     RequestContext, Sampler, Tracer, current_context, get_tracer,
-    trace,
+    startup, trace,
 )
 
 __all__ = [
-    "AlertManager", "AlertRule", "CompileWatcher",
+    "AlertManager", "AlertRule", "GlobalCompileStats",
     "FlightRecorder", "HealthMonitor", "RecompileStormError",
+    "SteadyStateCompileError",
     "TrainingDivergedError", "fused_health", "install_global_watch",
-    "watch", "REGISTRY", "Counter", "Gauge", "Histogram",
+    "REGISTRY", "Counter", "Gauge", "Histogram",
     "MetricsRegistry", "ProfilerListener", "detect_peak_flops",
     "model_flops_utilization", "peak_flops_for_kind", "Tracer",
-    "get_tracer", "trace", "RequestContext", "Sampler",
+    "get_tracer", "startup", "trace", "RequestContext", "Sampler",
     "current_context", "SLO", "BurnWindow", "SLOMonitor",
 ]

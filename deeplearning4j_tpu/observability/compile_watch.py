@@ -1,27 +1,46 @@
-"""Recompile watchdog: compile logging, cache accounting, trip-wire.
+"""Compile observer: every trace, lowering and compile of the process,
+named by its function, from ``jax.monitoring``.
 
 The round-5 verdict's unverifiable failure was a *suspected* XLA
 compile-cache miss (a 441 s headline leg ≈ warm estimate + cold
-compile) that nothing could confirm — compiles were invisible. This
-module makes them visible two ways:
+compile) that nothing could confirm — compiles were invisible.
+``install_global_watch()`` hooks ``jax.monitoring`` once a process and
+returns the :class:`GlobalCompileStats` every reader shares: the
+benchmark's ``setup_compile_s`` and ``setup_*`` metrics,
+``chip_smoke.py``'s per-phase counts, ``zero_compile_scope`` around
+every measured window, ``GET /debug/startup``.
 
-1. ``watch(fn)`` wraps a jitted callable. Every call samples the
-   executable cache size (``fn._cache_size()``) before/after: a delta
-   is a compile — logged with the call's arg shapes and elapsed time,
-   counted as a miss (vs a hit). A configurable **trip-wire** fires on
-   recompile storms: N compiles of the SAME function within a window,
-   the shape-churn bug class (a new batch shape every step silently
-   recompiling forever).
+What jax 0.9.0 fires for ONE jitted function's first call, all on the
+calling thread (read from ``jax/_src/dispatch.py``, ``pjit.py``,
+``interpreters/pxla.py``, ``compiler.py``; confirmed on a TPU v5 lite,
+PERF.md section 6, PR 50):
 
-2. ``install_global_watch()`` hooks ``jax.monitoring`` so every
-   backend compile in the process — watched or not — is counted, with
-   persistent-compilation-cache hits/misses split out. The
-   benchmark's ``setup_compile_s`` and ``chip_smoke.py``'s per-phase
-   ``cache_hit`` read it.
+- ``jaxpr_trace_duration`` (``fun_name`` ``my_step``), with one more
+  INSIDE it for every jitted function the trace passes through
+  (``tanh``, ``matmul``);
+- ``jaxpr_to_mlir_module_duration`` (``jit(my_step)``);
+- ``backend_compile_duration`` (``jit(my_step)``), which wraps
+  ``compile_or_get_cached`` WHOLE: with the persistent cache on it
+  holds ``compile_requests_use_cache`` and then either ``cache_hits`` +
+  ``cache_retrieval_time_sec`` (a load: milliseconds to seconds) or
+  the compile and the write. So the event fires on a hit too, and
+  ``backend_compiles`` / ``compile_secs`` count loads and compiles
+  alike: on a warm cache ``compile_secs`` IS load seconds.
+  ``cold_compiles`` / ``cold_compile_secs`` are the ones the cache
+  did not serve.
 
-Both report through the unified metrics registry and (optionally)
-drop ``xla_compile`` instants on the tracer so compiles show up in
-the Perfetto timeline.
+Each duration has a scalar of the same name at its start; the stats
+keep a stack of them a thread, and only an event with none open around
+it (the outermost) is counted and written as a span, or the seconds
+would double. Spans go to ``tracing.startup`` as ``xla/trace``,
+``xla/lower`` and ``xla/compile`` under whatever span is open on the
+compiling thread.
+
+The storm rule (N compiles of one function inside a window: the
+shape-churn bug class, a new batch shape every step recompiling
+forever) lives on the same listener and so covers every jitted
+function unwrapped. Compiles under an open ``startup`` span are the
+expected ones and do not count.
 """
 
 from __future__ import annotations
@@ -29,25 +48,57 @@ from __future__ import annotations
 import collections
 import contextlib
 import logging
+import re
 import threading
 import time
-from dataclasses import dataclass, field
-from typing import Any, Deque, Dict, List, Optional, Tuple
+from typing import Deque, Dict, List, Optional, Tuple
 
 logger = logging.getLogger("deeplearning4j_tpu")
 
 __all__ = ["RecompileStormError", "SteadyStateCompileError",
-           "CompileEvent", "CompileWatcher", "watch",
            "install_global_watch", "GlobalCompileStats"]
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+_HIT = "/jax/compilation_cache/cache_hits"
+
+# the durations that have a scalar at their start
+_TIMED = (_TRACE, _LOWER, _COMPILE)
+# duration event -> (span name, its column of ``by_function``, its total)
+_OUTERMOST = {_TRACE: ("xla/trace", "trace_s", "trace_secs"),
+              _LOWER: ("xla/lower", "lower_s", "lower_secs")}
+
+_TOTALS = ("backend_compiles", "compile_secs", "cache_requests",
+           "persistent_cache_hits", "trace_secs", "lower_secs",
+           "cache_load_secs", "cold_compiles", "cold_compile_secs")
+
+# ``jit(my_step)`` / ``pmap(f)`` of the lowering and compile events
+_WRAPPED = re.compile(r"^\w+\((.*)\)$")
+
+
+def _plain(fun_name: str) -> str:
+    m = _WRAPPED.match(fun_name)
+    return m.group(1) if m else fun_name
+
+
+def _unasked() -> dict:
+    """What the persistent cache has said of a compile so far."""
+    return {"asked": False, "hit": False, "load_s": 0.0}
 
 
 class RecompileStormError(RuntimeError):
-    """Raised when a watched function recompiles ``storm_threshold``
-    times inside ``storm_window_s`` seconds — almost always shape
-    churn: un-bucketed batch sizes, python scalars promoted to fresh
-    weak types, or a config rebuilt per step."""
+    """Raised (where ``on_storm="raise"``) when one function compiles
+    ``storm_threshold`` times inside ``storm_window_s`` seconds —
+    almost always shape churn: un-bucketed batch sizes, python scalars
+    promoted to fresh weak types, or a config rebuilt per step.
+    ``events`` are the ``(monotonic time, seconds)`` of those
+    compiles; jax's own ``jax_explain_cache_misses`` names the
+    argument that changed."""
 
-    def __init__(self, msg: str, events: List["CompileEvent"]):
+    def __init__(self, msg: str, events: List[Tuple[float, float]]):
         super().__init__(msg)
         self.events = events
 
@@ -57,247 +108,119 @@ class SteadyStateCompileError(RuntimeError):
     scope that promised zero compiles (the post-AOT-warmup steady
     state) compiled anyway — a shape escaped the warmup set, or a
     program was invalidated after warming (listener/health toggle,
-    optimizer rebuild)."""
+    optimizer rebuild). The message and ``functions`` name what
+    compiled."""
 
-    def __init__(self, msg: str, stats: dict):
+    def __init__(self, msg: str, stats: dict,
+                 functions: Tuple[str, ...] = ()):
         super().__init__(msg)
         self.stats = stats
+        self.functions = functions
 
 
-def _describe(x) -> str:
-    shape = getattr(x, "shape", None)
-    if shape is None:
-        return type(x).__name__
-    dtype = getattr(x, "dtype", "?")
-    return f"{dtype}{list(shape)}"
+class GlobalCompileStats:
+    """Totals and a per-function table fed by ``jax.monitoring``
+    (module docstring):
 
+    - ``backend_compiles`` / ``compile_secs``: backend compile events,
+      a load from the persistent cache among them.
+    - ``cold_compiles`` / ``cold_compile_secs``: those the cache did
+      not serve (a miss, or the cache off).
+    - ``cache_requests`` / ``persistent_cache_hits`` /
+      ``cache_load_secs``: requests the persistent cache was asked,
+      those it served, and the seconds their retrieval took.
+    - ``trace_secs`` / ``lower_secs``: Python tracing and lowering to
+      MLIR, which no cache saves (the cache's key is computed from the
+      lowered text).
 
-def arg_signature(args: tuple, kwargs: dict) -> str:
-    """Human-readable shapes/dtypes of a call's arguments (pytrees
-    flattened), the thing you need to SEE to spot shape churn."""
-    try:
-        import jax
-        leaves = jax.tree_util.tree_leaves((args, kwargs))
-    except Exception:
-        leaves = list(args) + list(kwargs.values())
-    parts = [_describe(l) for l in leaves[:16]]
-    if len(leaves) > 16:
-        parts.append(f"...+{len(leaves) - 16}")
-    return "(" + ", ".join(parts) + ")"
+    ``cache_hit`` answers: was every request served from the cache?
+    """
 
-
-@dataclass
-class CompileEvent:
-    name: str
-    signature: str
-    elapsed_s: float
-    t: float = field(default_factory=time.monotonic)
-
-
-class _WatchedFunction:
-    """Callable proxy sampling the jit executable-cache size around
-    each call."""
-
-    def __init__(self, fn, name: str, watcher: "CompileWatcher"):
-        if not hasattr(fn, "_cache_size"):
-            raise TypeError(
-                "watch() needs a jitted callable (jax.jit result with "
-                f"_cache_size); got {type(fn).__name__}. Wrap the "
-                "function with jax.jit first.")
-        self.__wrapped__ = fn
-        self._name = name
-        self._watcher = watcher
-        self._storm: Deque[CompileEvent] = collections.deque(maxlen=256)
-        self._lock = threading.Lock()
-        self.compiles = 0
-        self.hits = 0
-
-    def __call__(self, *args, **kwargs):
-        fn = self.__wrapped__
-        before = fn._cache_size()
-        t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
-        elapsed = time.perf_counter() - t0
-        if fn._cache_size() > before:
-            self._on_compile(args, kwargs, elapsed)
-        else:
-            with self._lock:
-                self.hits += 1
-            self._watcher._count_hit()
-        return out
-
-    def _on_compile(self, args, kwargs, elapsed):
-        ev = CompileEvent(self._name, arg_signature(args, kwargs),
-                          elapsed)
-        with self._lock:
-            self.compiles += 1
-            self._storm.append(ev)
-            w = self._watcher
-            recent = [e for e in self._storm
-                      if e.t >= ev.t - w.storm_window_s]
-        w._count_compile(ev)
-        if len(recent) >= w.storm_threshold:
-            msg = (f"recompile storm: {self._name!r} compiled "
-                   f"{len(recent)} times in the last "
-                   f"{w.storm_window_s:.0f}s — shape churn? recent "
-                   "signatures:\n  " +
-                   "\n  ".join(f"{e.signature} ({e.elapsed_s:.3f}s)"
-                               for e in recent[-8:]))
-            if w.on_storm == "raise":
-                raise RecompileStormError(msg, recent)
-            logger.warning(msg)
-
-    def cache_stats(self) -> dict:
-        with self._lock:
-            return {"name": self._name, "compiles": self.compiles,
-                    "cache_hits": self.hits}
-
-    def __getattr__(self, item):
-        # lower/trace/clear_cache etc. pass through to the jit object
-        return getattr(self.__wrapped__, item)
-
-
-class CompileWatcher:
-    """Factory for watched callables sharing one storm policy +
-    registry wiring. The module-level ``watch()`` uses a default
-    instance (warn-only, so production training never dies to its own
-    telemetry); tests construct a raising one."""
-
-    def __init__(self, registry=None, tracer=None,
+    def __init__(self, registry=None, tracer=None, timeline=None,
                  storm_threshold: int = 8, storm_window_s: float = 30.0,
-                 on_storm: str = "warn", log_compiles: bool = True):
+                 on_storm: str = "warn"):
         if on_storm not in ("raise", "warn"):
             raise ValueError("on_storm must be 'raise' or 'warn'")
         if registry is None:
             from deeplearning4j_tpu.observability.registry import REGISTRY
             registry = REGISTRY
-        self.registry = registry
+        self._lock = threading.Lock()
+        self._tls = threading.local()
+        for key in _TOTALS:
+            setattr(self, key, 0.0 if key.endswith("_secs") else 0)
+        self._by_function: Dict[str, Dict[str, float]] = {}
+        # the hot-path tracer: one instant a compile while it is on
         self.tracer = tracer
+        # where the ``xla/*`` spans go (``tracing.startup``)
+        self.timeline = timeline
         self.storm_threshold = storm_threshold
         self.storm_window_s = storm_window_s
         self.on_storm = on_storm
-        self.log_compiles = log_compiles
-        # bounded: under a warn-mode storm (compile-per-step churn)
-        # an unbounded log would itself become the leak
-        self.log: Deque[CompileEvent] = collections.deque(maxlen=4096)
-        self._lock = threading.Lock()
-        self._compiles = registry.counter(
-            "xla_watched_compiles_total",
-            help="compiles observed by compile_watch.watch()")
-        self._hits = registry.counter(
-            "xla_watched_cache_hits_total",
-            help="watched calls served from the jit executable cache")
-
-    def watch(self, fn, name: Optional[str] = None) -> _WatchedFunction:
-        if name is None:
-            name = getattr(fn, "__name__", None) or repr(fn)
-        return _WatchedFunction(fn, name, self)
-
-    def _count_compile(self, ev: CompileEvent) -> None:
-        self._compiles.inc()
-        with self._lock:
-            self.log.append(ev)
-        if self.log_compiles:
-            logger.info("XLA compile: %s args=%s (%.3fs)", ev.name,
-                        ev.signature, ev.elapsed_s)
-        if self.tracer is not None:
-            self.tracer.instant("xla_compile",
-                                {"fn": ev.name,
-                                 "signature": ev.signature,
-                                 "elapsed_s": round(ev.elapsed_s, 4)})
-
-    def _count_hit(self) -> None:
-        self._hits.inc()
-
-
-_DEFAULT_WATCHER: Optional[CompileWatcher] = None
-_DEFAULT_LOCK = threading.Lock()
-
-
-def _default_watcher() -> CompileWatcher:
-    global _DEFAULT_WATCHER
-    with _DEFAULT_LOCK:
-        if _DEFAULT_WATCHER is None:
-            from deeplearning4j_tpu.observability.tracing import trace
-            _DEFAULT_WATCHER = CompileWatcher(tracer=trace)
-        return _DEFAULT_WATCHER
-
-
-def watch(fn, name: Optional[str] = None) -> _WatchedFunction:
-    """Wrap a jitted callable with the default (warn-on-storm)
-    watcher: per-call hit/miss accounting, compile logging with arg
-    shapes, storm warnings."""
-    return _default_watcher().watch(fn, name)
-
-
-# ---------------------------------------------------------------------------
-# process-wide compile accounting via jax.monitoring
-# ---------------------------------------------------------------------------
-
-class GlobalCompileStats:
-    """Totals fed by jax.monitoring events:
-
-    - ``backend_compiles`` / ``compile_secs``: actual XLA backend
-      compiles (a persistent-cache hit does NOT fire this).
-    - ``cache_requests``: compile requests eligible for the
-      persistent compilation cache.
-    - ``persistent_cache_hits``: requests served from it.
-
-    ``cache_hit`` answers: did this process reuse compiled
-    artifacts instead of cold-compiling?
-    """
-
-    def __init__(self, registry=None, tracer=None):
-        if registry is None:
-            from deeplearning4j_tpu.observability.registry import REGISTRY
-            registry = REGISTRY
-        self._lock = threading.Lock()
-        self.backend_compiles = 0
-        self.compile_secs = 0.0
-        self.cache_requests = 0
-        self.persistent_cache_hits = 0
-        self.tracer = tracer
+        self._storm: Dict[str, Deque[Tuple[float, float]]] = {}
         self._c_compiles = registry.counter(
             "xla_backend_compiles_total",
-            help="XLA backend compiles in this process")
+            help="XLA backend compile events in this process "
+                 "(persistent-cache loads among them)")
         self._c_secs = registry.counter(
             "xla_backend_compile_seconds_total",
-            help="wall seconds spent in XLA backend compiles")
+            help="wall seconds spent in XLA backend compile events")
         self._c_hits = registry.counter(
             "xla_persistent_cache_hits_total",
             help="compiles served from the persistent XLA cache")
 
+    # ---- jax.monitoring wiring ----
+    def install(self) -> "GlobalCompileStats":
+        import jax.monitoring as monitoring
+        monitoring.register_event_listener(self._on_event)
+        monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+        monitoring.register_scalar_listener(self._on_start)
+        return self
+
+    def uninstall(self) -> None:
+        import jax.monitoring as monitoring
+        monitoring.unregister_event_listener(self._on_event)
+        monitoring.unregister_event_duration_listener(self._on_duration)
+        monitoring.unregister_scalar_listener(self._on_start)
+
+    # ---- reading ----
     def mark(self) -> dict:
         """Snapshot for delta accounting (``summary(since=mark)``)."""
         with self._lock:
-            return {"backend_compiles": self.backend_compiles,
-                    "compile_secs": self.compile_secs,
-                    "cache_requests": self.cache_requests,
-                    "persistent_cache_hits": self.persistent_cache_hits}
+            return {key: getattr(self, key) for key in _TOTALS}
 
     def summary(self, since: Optional[dict] = None) -> dict:
         cur = self.mark()
         if since:
-            cur = {k: (round(cur[k] - since[k], 3)
-                       if isinstance(cur[k], float)
-                       else cur[k] - since[k]) for k in cur}
-        else:
-            cur["compile_secs"] = round(cur["compile_secs"], 3)
+            cur = {k: cur[k] - since.get(k, 0) for k in cur}
+        cur = {k: round(v, 3) if isinstance(v, float) else v
+               for k, v in cur.items()}
         cur["cache_hit"] = self._cache_hit(cur)
         return cur
 
     @staticmethod
     def _cache_hit(s: dict) -> Optional[bool]:
-        """True = every compile request was served from cache (zero
-        cold backend compiles); None when nothing compiled at all (no
-        evidence either way)."""
-        if s["backend_compiles"] == 0 and s["cache_requests"] == 0:
+        """True = every request the persistent cache was asked was
+        served from it; None where nothing was asked (nothing
+        compiled, or the cache is off)."""
+        if s["cache_requests"] == 0:
             return None
-        return s["backend_compiles"] == 0
+        return s["persistent_cache_hits"] == s["cache_requests"]
 
     @property
     def cache_hit(self) -> Optional[bool]:
         return self._cache_hit(self.mark())
+
+    def by_function(self) -> Dict[str, Dict[str, float]]:
+        """``{fun_name: {trace_s, lower_s, compile_s, load_s,
+        compiles, loads}}``: the outermost events of each function
+        (``jit(f)`` of the lowering and compile events is ``f``).
+        ``compile_s`` is the backend compile events' seconds, loads
+        among them; ``compiles`` counts the cold ones and ``loads``
+        the persistent-cache hits, whose retrieval took ``load_s``."""
+        with self._lock:
+            return {name: dict(row)
+                    for name, row in self._by_function.items()}
 
     @contextlib.contextmanager
     def zero_compile_scope(self, what: str = "steady state"):
@@ -306,57 +229,162 @@ class GlobalCompileStats:
         ``model.warmup()`` / ``ModelServer.warmup()`` pre-built every
         expected program, the fit loop or a serving request burst
         must run entirely on compiled executables. Raises
-        :class:`SteadyStateCompileError` with the compile deltas
-        otherwise."""
-        mark = self.mark()
+        :class:`SteadyStateCompileError` with the compile deltas and
+        the functions that compiled otherwise."""
+        def events():
+            return {name: row["compiles"] + row["loads"]
+                    for name, row in self.by_function().items()}
+
+        mark, before = self.mark(), events()
         yield self
         s = self.summary(mark)
         if s["backend_compiles"]:
+            names = tuple(sorted(
+                name for name, n in events().items()
+                if n > before.get(name, 0)))
             raise SteadyStateCompileError(
                 f"{what}: {s['backend_compiles']} XLA backend "
-                f"compile(s) ({s['compile_secs']:.2f}s) inside a "
+                f"compile(s) ({s['compile_secs']:.2f}s) of "
+                f"{', '.join(names) or '?'} inside a "
                 "scope that promised zero after AOT warmup — a shape "
                 "escaped the warmup set or a warmed program was "
-                "invalidated", s)
+                "invalidated", s, names)
 
-    # ---- listeners ----
+    # ---- listeners (all three run on the compiling thread) ----
+    def _on_start(self, event: str, value=None, **kw) -> None:
+        if event not in _TIMED:
+            return
+        tls = self._tls
+        if getattr(tls, "open", None) is None:
+            tls.open = []
+        tls.open.append(event)
+        if event == _COMPILE:
+            # filled in by the events between here and the duration
+            tls.cache = _unasked()
+
     def _on_event(self, event: str, **kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
+        if event == _REQUEST:
+            with self._lock:
+                self.cache_requests += 1
+            cache = getattr(self._tls, "cache", None)
+            if cache is not None:
+                cache["asked"] = True
+        elif event == _HIT:
             with self._lock:
                 self.persistent_cache_hits += 1
             self._c_hits.inc()
-        elif event == "/jax/compilation_cache/compile_requests_use_cache":
-            with self._lock:
-                self.cache_requests += 1
+            cache = getattr(self._tls, "cache", None)
+            if cache is not None:
+                cache["hit"] = True
 
     def _on_duration(self, event: str, duration: float, **kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
+        if event == _LOAD:
             with self._lock:
-                self.backend_compiles += 1
-                self.compile_secs += duration
-            self._c_compiles.inc()
-            self._c_secs.inc(duration)
-            if self.tracer is not None:
-                self.tracer.instant(
-                    "xla_backend_compile",
-                    {"elapsed_s": round(duration, 4)})
+                self.cache_load_secs += duration
+            cache = getattr(self._tls, "cache", None)
+            if cache is not None:
+                cache["load_s"] = duration
+            return
+        if event not in _TIMED:
+            return
+        now_ns = time.perf_counter_ns()
+        open_ = getattr(self._tls, "open", None)
+        if open_:
+            open_.pop()
+        fun = _plain(str(kw.get("fun_name", "?")))
+        if event == _COMPILE:
+            self._on_compile(fun, duration, now_ns, outermost=not open_)
+        elif not open_:
+            span, column, total = _OUTERMOST[event]
+            with self._lock:
+                setattr(self, total, getattr(self, total) + duration)
+                self._row(fun)[column] += duration
+            self._span(span, now_ns, duration, {"fun_name": fun})
+
+    def _on_compile(self, fun: str, duration: float, now_ns: int,
+                    outermost: bool) -> None:
+        cache = getattr(self._tls, "cache", None) or _unasked()
+        self._tls.cache = None
+        hit = cache["hit"]
+        with self._lock:
+            self.backend_compiles += 1
+            self.compile_secs += duration
+            if not hit:
+                self.cold_compiles += 1
+                self.cold_compile_secs += duration
+            row = self._row(fun)
+            row["compile_s"] += duration
+            row["loads" if hit else "compiles"] += 1
+            row["load_s"] += cache["load_s"]
+        self._c_compiles.inc()
+        self._c_secs.inc(duration)
+        if outermost:
+            self._span("xla/compile", now_ns, duration, {
+                "fun_name": fun,
+                "cache": ("hit" if hit else
+                          "miss" if cache["asked"] else "off"),
+                "load_s": cache["load_s"]})
+        if self.tracer is not None:
+            self.tracer.instant(
+                "xla_backend_compile",
+                {"fun_name": fun, "elapsed_s": round(duration, 4)})
+        if self.timeline is None or self.timeline.open_span_id() is None:
+            self._count_for_storm(fun, duration)
+
+    def _row(self, fun: str) -> Dict[str, float]:
+        row = self._by_function.get(fun)
+        if row is None:
+            row = self._by_function[fun] = {
+                "trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
+                "load_s": 0.0, "compiles": 0, "loads": 0}
+        return row
+
+    def _span(self, name: str, now_ns: int, duration: float,
+              attrs: dict) -> None:
+        if self.timeline is None:
+            return
+        dur_ns = int(duration * 1e9)
+        self.timeline.record_span(
+            name, now_ns - dur_ns, dur_ns, attrs=attrs,
+            parent_id=self.timeline.open_span_id())
+
+    def _count_for_storm(self, fun: str, duration: float) -> None:
+        now = time.monotonic()
+        with self._lock:
+            recent = self._storm.setdefault(
+                fun, collections.deque(maxlen=256))
+            recent.append((now, duration))
+            while recent[0][0] < now - self.storm_window_s:
+                recent.popleft()
+            if len(recent) < self.storm_threshold:
+                return
+            events = list(recent)
+            # the next report needs as many compiles again
+            recent.clear()
+        msg = (f"recompile storm: {fun!r} compiled {len(events)} times "
+               f"in the last {self.storm_window_s:.0f}s "
+               f"({sum(d for _, d in events):.3f}s) — shape churn? "
+               "jax_explain_cache_misses names the argument that "
+               "changed")
+        if self.on_storm == "raise":
+            raise RecompileStormError(msg, events)
+        logger.warning(msg)
 
 
 _GLOBAL_STATS: Optional[GlobalCompileStats] = None
+_GLOBAL_LOCK = threading.Lock()
 
 
 def install_global_watch(registry=None) -> GlobalCompileStats:
     """Idempotently hook jax.monitoring and return the process-wide
-    compile stats. jax's listener list has no per-listener removal, so
-    this installs exactly once per process."""
+    compile stats (warn-on-storm, so production training never dies
+    to its own telemetry)."""
     global _GLOBAL_STATS
-    with _DEFAULT_LOCK:
+    with _GLOBAL_LOCK:
         if _GLOBAL_STATS is None:
-            from deeplearning4j_tpu.observability.tracing import trace
-            stats = GlobalCompileStats(registry=registry, tracer=trace)
-            import jax.monitoring as monitoring
-            monitoring.register_event_listener(stats._on_event)
-            monitoring.register_event_duration_secs_listener(
-                stats._on_duration)
-            _GLOBAL_STATS = stats
+            from deeplearning4j_tpu.observability.tracing import (
+                startup, trace)
+            _GLOBAL_STATS = GlobalCompileStats(
+                registry=registry, tracer=trace,
+                timeline=startup).install()
         return _GLOBAL_STATS

@@ -36,6 +36,20 @@ trace's clock, and ``anchor.start_ns - <perf_counter_ns>`` is the exact
 offset from ``t_ns`` to that clock. ``jax`` is imported inside
 ``enable()`` only.
 
+The set-up timeline (ISSUE 50). ``startup`` is a second, always-on
+buffer of the same class for what happens once, before the first
+steady step::
+
+    setup/init                    (both executors' ``init``)
+      └─ setup/init/optimizer    (``optimizer.init``)
+    setup/batcher                 (``ModelServer.batcher_for`` builds one)
+      └─ setup/session           (``PagedSlotSession.__init__``)
+    setup/warm_programs           (``ContinuousBatcher._warm_programs``)
+      └─ setup/program           (a step program's first call, whole)
+           ├─ xla/trace          (``compile_watch``: the outermost
+           ├─ xla/lower           ``jax.monitoring`` duration a thread,
+           └─ xla/compile         with ``fun_name``; ``cache`` hit/miss/off)
+
 Request-scoped tracing (the serving observability PR) adds
 :class:`RequestContext`: one trace id minted at HTTP admission (or
 adopted from a W3C ``traceparent`` header, so a router→replica hop
@@ -72,7 +86,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-__all__ = ["Span", "Tracer", "trace", "get_tracer",
+__all__ = ["Span", "Tracer", "trace", "startup", "get_tracer",
            "RequestContext", "Sampler", "current_context"]
 
 
@@ -203,10 +217,14 @@ class Tracer:
     """
 
     def __init__(self, enabled: bool = False,
-                 buffer_limit: int = 200_000):
+                 buffer_limit: int = 200_000, annotate: bool = True):
         self._enabled = False
         self.buffer_limit = buffer_limit
         self._lock = threading.Lock()
+        # does ``enable()`` look for the profiler at all? A tracer
+        # that is born enabled with the module says no, and the module
+        # alone still imports no jax
+        self._annotate = annotate
         # jax.profiler.TraceAnnotation while enabled, else None
         self._annotation = None
         # one reading of both host clocks at enable():
@@ -251,7 +269,7 @@ class Tracer:
         ``export_chrome_trace``). Reads both host clocks once
         (``clock_anchor``) and writes the clock-anchor annotation
         into a running profiler capture."""
-        if self._annotation is None:
+        if self._annotate and self._annotation is None:
             try:
                 from jax.profiler import TraceAnnotation
                 self._annotation = TraceAnnotation
@@ -371,6 +389,14 @@ class Tracer:
         return self._origin_ns
 
     # ---- per-thread nesting ----
+    def open_span_id(self) -> Optional[str]:
+        """The innermost span open on the calling thread, or None:
+        the parent of whatever that thread records next
+        (``record_span(parent_id=...)`` from a callback that runs
+        inside someone else's ``with`` span)."""
+        stack = getattr(self._tls, "stack", None)
+        return stack[-1] if stack else None
+
     def _push(self, span_id: str):
         """Open a span on this thread: (its depth, its parent's id)."""
         stack = getattr(self._tls, "stack", None)
@@ -496,15 +522,22 @@ class Tracer:
                     if ev.get("trace_id") == trace_id]
 
     # ---- export ----
-    def export_chrome_trace(self, path: str) -> int:
+    def export_chrome_trace(self, path: str, also=()) -> int:
         """Write the buffered spans as Chrome trace-event JSON
-        ("X" complete events; open in Perfetto or chrome://tracing).
-        Returns the number of events written."""
+        ("X" complete events; open in Perfetto or chrome://tracing),
+        and with them those of the tracers in ``also`` (the set-up's
+        ``startup``), every one on this tracer's origin. Returns the
+        number of events written."""
         pid = os.getpid()
+        events, dropped = self.events(), self.dropped
+        for other in also:
+            events += other.events()
+            dropped += other.dropped
         out = []
-        for ev in self.events():
+        for ev in events:
             rec = {"name": ev["name"], "ph": "X", "pid": pid,
-                   "tid": ev["tid"], "ts": ev["ts_us"],
+                   "tid": ev["tid"],
+                   "ts": (ev["t_ns"] - self._origin_ns) / 1e3,
                    "dur": ev["dur_us"]}
             args = dict(ev.get("args") or {})
             # trace ids ride the args so Perfetto (and
@@ -518,7 +551,7 @@ class Tracer:
         with open(path, "w") as f:
             json.dump({"traceEvents": out,
                        "displayTimeUnit": "ms",
-                       "droppedEvents": self.dropped}, f)
+                       "droppedEvents": dropped}, f)
         return len(out)
 
     def write_jsonl(self, path: str) -> int:
@@ -532,6 +565,16 @@ class Tracer:
 
 # The process-wide tracer the executors / serving / CLI share.
 trace = Tracer(enabled=False)
+
+# The set-up's own buffer, always on: ``setup/*`` spans from
+# constructors, warm-ups and the first call of each step program, and
+# the ``xla/*`` spans ``compile_watch`` writes for every trace, lowering
+# and compile of the process. A second buffer and not ``trace``: a
+# reader of ``trace.events()`` takes the earliest ``t_ns`` as the start
+# of the traced part (the benchmark's clock fit does), and a span from
+# 40 s before it would be that start. Nothing on a steady step records
+# here; ``GET /debug/startup`` and the CLI's ``--trace`` export read it.
+startup = Tracer(enabled=True, buffer_limit=8192, annotate=False)
 
 
 def get_tracer() -> Tracer:

@@ -72,7 +72,7 @@ import numpy as np
 
 from deeplearning4j_tpu import chaos
 from deeplearning4j_tpu.observability.tracing import (RequestContext,
-                                                      trace)
+                                                      startup, trace)
 from deeplearning4j_tpu.serving import tiers
 from deeplearning4j_tpu.serving.errors import (KVLeaseError,
                                                KVPagePoolExhaustedError,
@@ -1223,10 +1223,12 @@ class ContinuousBatcher(ServingBackend):
             return
         idle = np.zeros((self.slots,), np.int32)
         try:
-            for t in widths:
-                self.session.step_ids(
-                    np.zeros((self.slots, t, 1), np.float32), idle,
-                    idle > 0)
+            with startup.span("setup/warm_programs",
+                              {"widths": list(widths)}):
+                for t in widths:
+                    self.session.step_ids(
+                        np.zeros((self.slots, t, 1), np.float32), idle,
+                        idle > 0)
         except BaseException:
             # the step donates the pools: rebuild them, and let the
             # first real step surface a persistent fault to its

@@ -37,6 +37,9 @@ Retrieval (``serve --index``; see ``serving/retrieval_backend.py``):
   ``?format=prometheus``, or an ``Accept`` header naming
   ``text/plain`` / ``openmetrics`` (what Prometheus scrapers send).
   The JSON default preserves the pre-observability contract.
+- ``GET  /debug/startup`` → the set-up timeline: the ``setup/*`` and
+  ``xla/*`` spans of ``observability.tracing.startup`` and the
+  compile seconds by function (``compile_watch.by_function()``)
 
 Error mapping is the typed-error contract from ``serving/errors.py``:
 QueueFullError → 429, DeadlineExceededError → 504, ModelNotFoundError
@@ -64,9 +67,12 @@ from urllib.parse import parse_qs, urlparse
 
 import numpy as np
 
+from deeplearning4j_tpu.observability.compile_watch import (
+    install_global_watch)
 from deeplearning4j_tpu.observability.tracing import (RequestContext,
                                                       Sampler,
-                                                      get_tracer)
+                                                      get_tracer,
+                                                      startup)
 from deeplearning4j_tpu.serving.continuous import (ContinuousBatcher,
                                                    MigrationOffer)
 from deeplearning4j_tpu.serving.errors import (CircuitOpenError,
@@ -279,6 +285,9 @@ class ModelServer:
         # on error), spans recorded on the process tracer
         self.sampler = Sampler(rate=sample_rate, routes=sample_routes)
         self.tracer = tracer if tracer is not None else get_tracer()
+        # every compile of the process by function, from here on
+        # (/debug/startup)
+        self.compiles = install_global_watch()
         self.slow_ms = float(slow_ms)
         self._inflight: Dict[int, dict] = {}
         self._inflight_lock = threading.Lock()
@@ -430,15 +439,17 @@ class ModelServer:
             raise ServingError(
                 f"model {name!r} does not support streaming "
                 "generation (no slot_streaming_session)")
-        b = self._get_or_create(
-            self._batchers, (name, version),
-            lambda: ContinuousBatcher(
-                model, slots=self.slots, capacity=self.capacity,
-                queue_limit=self.queue_limit, metrics=self.metrics,
-                name=f"generate/{name}/v{version}",
-                version=str(version), kv_mode=self.kv_mode,
-                page_size=self.page_size, kv_pages=self.kv_pages,
-                model_name=name))
+        def build():
+            with startup.span("setup/batcher", {"model": name}):
+                return ContinuousBatcher(
+                    model, slots=self.slots, capacity=self.capacity,
+                    queue_limit=self.queue_limit, metrics=self.metrics,
+                    name=f"generate/{name}/v{version}",
+                    version=str(version), kv_mode=self.kv_mode,
+                    page_size=self.page_size, kv_pages=self.kv_pages,
+                    model_name=name)
+
+        b = self._get_or_create(self._batchers, (name, version), build)
         return b, version
 
     def warmup(self, **kwargs) -> Dict[str, dict]:
@@ -545,6 +556,8 @@ class ModelServer:
                     self._send(200, server.debug_slots())
                 elif path == "/debug/traces":
                     self._send(200, server.debug_traces())
+                elif path == "/debug/startup":
+                    self._send(200, server.debug_startup())
                 else:
                     self._send(404, {"error": "not found"})
 
@@ -1110,6 +1123,17 @@ class ModelServer:
         return {"slow": slow[-50:],
                 "sample_rate": self.sampler.rate,
                 "slow_ms": self.slow_ms}
+
+    def debug_startup(self) -> dict:
+        """The set-up timeline: the ``setup/*`` and ``xla/*`` spans of
+        ``tracing.startup`` (constructors, warm-ups, each step
+        program's first call, every trace / lowering / compile with
+        its function and whether the persistent cache served it) and
+        the compile seconds by function."""
+        return {"events": startup.events(),
+                "dropped": startup.dropped,
+                "compiles": self.compiles.summary(),
+                "by_function": self.compiles.by_function()}
 
     # ---- health ----
     def health_payload(self) -> dict:

@@ -54,7 +54,7 @@ def main():
         EmbeddingSequenceLayer, RnnOutputLayer,
         TransformerEncoderLayer)
     from deeplearning4j_tpu.observability import (
-        ProfilerListener, install_global_watch, trace)
+        ProfilerListener, install_global_watch, startup, trace)
 
     trace.enable()
     compile_stats = install_global_watch()
@@ -111,10 +111,12 @@ def main():
           f"{sorted(sess._step_cache)}")
 
     s = compile_stats.summary()
-    print(f"compile watch: {s['backend_compiles']} backend compiles, "
-          f"{s['compile_secs']:.1f}s compiling, persistent cache "
+    print(f"compile watch: {s['backend_compiles']} backend compile "
+          f"events ({s['cold_compiles']} cold), "
+          f"{s['compile_secs']:.1f}s in them, {s['trace_secs']:.1f}s "
+          f"tracing, {s['lower_secs']:.1f}s lowering, persistent cache "
           f"hits {s['persistent_cache_hits']}/{s['cache_requests']}")
-    n_ev = trace.export_chrome_trace(args.trace)
+    n_ev = trace.export_chrome_trace(args.trace, also=(startup,))
     trace.disable()
     print(f"trace written: {args.trace} ({n_ev} events) — open in "
           "Perfetto / chrome://tracing")

@@ -11,7 +11,8 @@ Three contracts a cell:
   reports exactly its end-to-end metrics and names the platform;
 - a ``--trace 1`` rehearsal brings back every per-layer metric the
   program itself feeds (``source`` ``program_span`` /
-  ``program_counter``). The ``device_trace`` readers need the TPU's
+  ``program_counter``), the five ``setup_*`` readers of the set-up
+  timeline among them. The ``device_trace`` readers need the TPU's
   "XLA Ops" plane and are the chip's to show.
 
 A fourth, for the serving cells: a served token altered where it is
@@ -148,13 +149,20 @@ def test_cell_end_to_end_tiny(cell, tiny, capsys):
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_program_fed_layer_metrics_come_back(cell, tiny, capsys):
+def test_program_fed_layer_metrics_come_back(cell, tiny, capsys,
+                                             monkeypatch):
     c = spec.load(cell)
     want = {m["name"] for m in c.per_layer
             if m["source"] in ("program_span", "program_counter")}
-    assert want
-    s = session.Session(c, SEED, 2.0, 1, time.perf_counter(),
-                        find=_cpu_devices)
+    assert want >= {"setup_init_s", "setup_trace_lower_s",
+                    "setup_cache_load_s", "setup_programs_s",
+                    "setup_named_pct"}
+    # the ``setup_*`` readers bound the set-up by ``run.py``'s first
+    # reading of the clock, ``__main__.T_START``: here the session's
+    t_start = time.perf_counter()
+    monkeypatch.setattr(sys.modules["__main__"], "T_START", t_start,
+                        raising=False)
+    s = session.Session(c, SEED, 2.0, 1, t_start, find=_cpu_devices)
     driver = spec.load_module("drivers", c.traffic["driver"])
     # the CPU's profiler capture has no "XLA Ops" plane, so result()
     # stops at the device's busy share: after the window, with every
@@ -170,6 +178,13 @@ def test_program_fed_layer_metrics_come_back(cell, tiny, capsys):
     assert got.pop("moe_grouped_steps_pct.serve", None) is None
     missing = sorted(n for n, v in got.items() if v is None)
     assert not missing, (missing, capsys.readouterr().out[-4000:])
+    # the set-up timeline: ``init()`` and each step program's first
+    # call are inside it, every compile is traced and lowered first,
+    # and what is named is part of ``setup_s``
+    assert got["setup_init_s"] > 0 and got["setup_programs_s"] > 0
+    assert got["setup_trace_lower_s"] > 0
+    assert got["setup_cache_load_s"] >= 0
+    assert 0 < got["setup_named_pct"] <= 100
     # the CPU's paged step gathers: it reads what its tables span
     assert got.get("kv_read_pct.serve", 100.0) == 100.0
 

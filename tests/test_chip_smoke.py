@@ -124,6 +124,8 @@ jax.jit(lambda x: jnp.tanh(x) * 3 + 1)(jnp.ones((7, 5))).block_until_ready()
 s = stats.summary()
 print(json.dumps({"dir": used, "hits": s["persistent_cache_hits"],
                   "requests": s["cache_requests"],
+                  "events": s["backend_compiles"],
+                  "cold": s["cold_compiles"], "cache_hit": s["cache_hit"],
                   "config": jax.config.jax_compilation_cache_dir}))
 """
 
@@ -154,4 +156,11 @@ def test_second_run_from_another_directory_hits_the_cache(tmp_path):
     assert not (tmp_path / "other").exists()
     assert first["requests"] >= 1 and first["hits"] == 0
     assert second["hits"] >= 1
+    # ``backend_compiles`` counts the loads too (jax fires the event
+    # around a hit as well): ``cold_compiles`` and ``cache_hit`` are
+    # what tell a warm start from a cold one
+    assert first["events"] == first["cold"] == first["requests"]
+    assert first["cache_hit"] is False
+    assert second["events"] == first["events"] and second["cold"] == 0
+    assert second["cache_hit"] is True
     assert any(placed.iterdir())

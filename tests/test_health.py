@@ -82,26 +82,30 @@ class TestFusedHealthMonitor:
     def test_one_transfer_per_step_no_recompile(self):
         """The acceptance contract: the fused check costs ONE fetch
         per step (counted by the monitor — it never walks leaves) and
-        does not churn the jit cache (asserted by a raising
-        compile watcher around the live step function)."""
+        does not churn the jit cache (asserted by a raising compile
+        observer listening around the live steps)."""
         from deeplearning4j_tpu.observability.compile_watch import (
-            CompileWatcher)
+            GlobalCompileStats)
         net = tiny_classifier()
         mon = HealthMonitor(policy="warn")
         net.add_listeners(mon)
         batches = make_batches(3)
         net.fit(ListDataSetIterator(batches))        # compile once
         assert net._health_enabled and net._last_health is not None
-        watcher = CompileWatcher(registry=MetricsRegistry(),
-                                 storm_threshold=2, on_storm="raise")
-        watched = watcher.watch(net._jit_train_step, "train_step")
-        net._jit_train_step = watched
-        before = mon.device_fetches
-        net.fit(ListDataSetIterator(make_batches(5, seed=1)),
-                epochs=2)
-        # 10 more steps: all jit-cache hits, zero compiles
-        assert watched.compiles == 0
-        assert watched.hits == 10
+        more = make_batches(5, seed=1)
+        stats = GlobalCompileStats(registry=MetricsRegistry(),
+                                   storm_threshold=2,
+                                   on_storm="raise").install()
+        before, steps = mon.device_fetches, net.iteration_count
+        try:
+            net.fit(ListDataSetIterator(more), epochs=2)
+        finally:
+            stats.uninstall()
+        # 10 more steps: all jit-cache hits, nothing traced, lowered
+        # or compiled by any function
+        assert net.iteration_count - steps == 10
+        assert stats.by_function() == {}
+        assert stats.summary()["backend_compiles"] == 0
         # exactly one health fetch per step
         assert mon.device_fetches - before == 10
 

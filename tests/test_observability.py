@@ -9,6 +9,7 @@ committed docs pass the N.Nx-claims lint.
 """
 
 import json
+import logging
 import os
 import sys
 import threading
@@ -216,88 +217,131 @@ class TestRegistry:
 # recompile watchdog
 # ---------------------------------------------------------------------------
 
+@pytest.fixture
+def listening():
+    """A ``GlobalCompileStats`` of the test's own (registry, storm
+    policy) listening to ``jax.monitoring`` for the test's length."""
+    from deeplearning4j_tpu.observability.compile_watch import (
+        GlobalCompileStats)
+    from deeplearning4j_tpu.observability.registry import (
+        MetricsRegistry)
+    made = []
+
+    def make(**policy):
+        made.append(GlobalCompileStats(registry=MetricsRegistry(),
+                                       **policy).install())
+        return made[-1]
+
+    yield make
+    for stats in made:
+        stats.uninstall()
+
+
 class TestCompileWatch:
-    def test_hit_miss_accounting(self):
+    def test_hit_miss_accounting(self, listening):
+        """The per-function table: a call served from the jit's own
+        executable cache fires nothing, a new shape is one more
+        compile of that function."""
         import jax
         import jax.numpy as jnp
+        stats = listening()
 
-        from deeplearning4j_tpu.observability.compile_watch import (
-            CompileWatcher)
-        from deeplearning4j_tpu.observability.registry import (
-            MetricsRegistry)
-        w = CompileWatcher(registry=MetricsRegistry(),
-                           log_compiles=False)
-        f = w.watch(jax.jit(lambda x: x * 2), name="dbl")
+        def dbl(x):
+            return x * 2
+
+        f = jax.jit(dbl)
         f(jnp.ones(3))
         f(jnp.ones(3))
         f(jnp.ones(3))
-        assert f.cache_stats() == {"name": "dbl", "compiles": 1,
-                                   "cache_hits": 2}
+        row = stats.by_function()["dbl"]
+        assert row["compiles"] + row["loads"] == 1
         f(jnp.ones(5))                  # new shape: compile
-        assert f.cache_stats()["compiles"] == 2
-        assert w.log[0].name == "dbl"
-        assert "float32[3]" in w.log[0].signature
+        row = stats.by_function()["dbl"]
+        assert row["compiles"] + row["loads"] == 2
+        assert row["trace_s"] > 0 and row["lower_s"] > 0
+        assert row["compile_s"] > 0
+        s = stats.summary()
+        assert s["backend_compiles"] >= 2
+        assert s["cold_compiles"] + s["persistent_cache_hits"] == \
+            s["backend_compiles"]
 
-    def test_storm_tripwire_fires_on_shape_churn(self):
+    def test_storm_tripwire_fires_on_shape_churn(self, listening):
         """The shape-churn bug class: a fresh batch shape every call
-        recompiling forever. The trip-wire must fire AND name the
-        shapes so the bug is diagnosable from the error alone."""
+        recompiling forever. The trip-wire must fire out of the call
+        that compiled AND name the function."""
         import jax
         import jax.numpy as jnp
 
         from deeplearning4j_tpu.observability.compile_watch import (
-            CompileWatcher, RecompileStormError)
-        from deeplearning4j_tpu.observability.registry import (
-            MetricsRegistry)
-        w = CompileWatcher(registry=MetricsRegistry(),
-                           storm_threshold=4, storm_window_s=60.0,
-                           on_storm="raise", log_compiles=False)
-        f = w.watch(jax.jit(lambda x: x + 1), name="churny")
+            RecompileStormError)
+        listening(storm_threshold=4, storm_window_s=60.0,
+                  on_storm="raise")
+
+        def churny(x):
+            return x + 1
+
+        f = jax.jit(churny)
         with pytest.raises(RecompileStormError) as ei:
             for n in range(2, 40):
-                f(jnp.ones(n))          # every call a new shape
+                # numpy's: ``jnp.ones`` compiles too, a shape a call
+                f(np.ones(n, np.float32))   # every call a new shape
         msg = str(ei.value)
-        assert "churny" in msg and "4 times" in msg
-        assert "float32[" in msg        # shapes are in the report
+        assert "'churny'" in msg and "4 times" in msg
         assert len(ei.value.events) == 4
 
-    def test_storm_warn_mode_does_not_raise(self):
+    def test_storm_warn_mode_does_not_raise(self, listening, caplog):
         import jax
         import jax.numpy as jnp
+        stats = listening(storm_threshold=2, storm_window_s=60.0,
+                          on_storm="warn")
 
-        from deeplearning4j_tpu.observability.compile_watch import (
-            CompileWatcher)
-        from deeplearning4j_tpu.observability.registry import (
-            MetricsRegistry)
-        w = CompileWatcher(registry=MetricsRegistry(),
-                           storm_threshold=2, storm_window_s=60.0,
-                           on_storm="warn", log_compiles=False)
-        f = w.watch(jax.jit(lambda x: x + 1))
-        for n in range(2, 8):
-            f(jnp.ones(n))
-        assert f.cache_stats()["compiles"] == 6
+        def warned(x):
+            return x + 1
 
-    def test_stable_shapes_never_trip(self):
+        f = jax.jit(warned)
+        with caplog.at_level(logging.WARNING, "deeplearning4j_tpu"):
+            for n in range(2, 8):
+                f(np.ones(n, np.float32))
+        row = stats.by_function()["warned"]
+        assert row["compiles"] + row["loads"] == 6
+        # a report every second compile, not one a compile
+        assert sum("'warned' compiled 2 times" in r.message
+                   for r in caplog.records) == 3
+
+    def test_stable_shapes_never_trip(self, listening):
         import jax
         import jax.numpy as jnp
+        stats = listening(storm_threshold=2, on_storm="raise")
 
-        from deeplearning4j_tpu.observability.compile_watch import (
-            CompileWatcher)
-        from deeplearning4j_tpu.observability.registry import (
-            MetricsRegistry)
-        w = CompileWatcher(registry=MetricsRegistry(),
-                           storm_threshold=2, on_storm="raise",
-                           log_compiles=False)
-        f = w.watch(jax.jit(lambda x: x + 1))
+        def stable(x):
+            return x + 1
+
+        f = jax.jit(stable)
         for _ in range(50):
-            f(jnp.ones(4))
-        assert f.cache_stats() == {"name": "<lambda>", "compiles": 1,
-                                   "cache_hits": 49}
+            f(np.ones(4, np.float32))
+        row = stats.by_function()["stable"]
+        assert row["compiles"] + row["loads"] == 1
 
-    def test_watch_rejects_unjitted(self):
-        from deeplearning4j_tpu.observability.compile_watch import watch
-        with pytest.raises(TypeError):
-            watch(lambda x: x)
+    def test_compiles_under_a_setup_span_are_expected(self, listening):
+        """The storm rule leaves the set-up alone: what compiles
+        under an open span of the stats' timeline does not count."""
+        import jax
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.observability.tracing import Tracer
+        timeline = Tracer(enabled=True, annotate=False)
+        stats = listening(timeline=timeline, storm_threshold=2,
+                          on_storm="raise")
+
+        def warming(x):
+            return x - 1
+
+        f = jax.jit(warming)
+        with timeline.span("setup/warm_programs"):
+            for n in range(2, 8):
+                f(np.ones(n, np.float32))
+        assert stats.by_function()["warming"]["compiles"] + \
+            stats.by_function()["warming"]["loads"] == 6
 
     def test_global_stats_counts_backend_compiles(self):
         import jax
