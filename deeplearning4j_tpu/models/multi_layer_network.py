@@ -45,11 +45,16 @@ from deeplearning4j_tpu.nn.conf.layers.recurrent import BaseRecurrentLayer
 from deeplearning4j_tpu.models.kstep import (KStepExecutorMixin,
                                              _tree_nbytes)
 from deeplearning4j_tpu.nn.conf.multi_layer import MultiLayerConfiguration
+from deeplearning4j_tpu.ops.attention import FLASH_KEPT
 from deeplearning4j_tpu.train.constraints import apply_layer_constraints
 
 logger = logging.getLogger("deeplearning4j_tpu")
 
 __all__ = ["MultiLayerNetwork"]
+
+# what a recomputed layer keeps beside its input (one object: jax keys
+# its caches of traced functions by the policy)
+_KEEPS_FLASH = jax.checkpoint_policies.save_only_these_names(*FLASH_KEPT)
 
 
 def _as_iterator(data, labels=None, batch_size=None) -> DataSetIterator:
@@ -239,9 +244,11 @@ class MultiLayerNetwork(KStepExecutorMixin):
         """``layer.apply`` under its ``scope`` as the train step runs
         it: ``(out, state, [held counts] of a layer with experts)``,
         computed again in the backward pass where the configuration
-        says ``recompute``. The counts leave through the wrapped
-        function's outputs, so they are the step's own values and not
-        the recomputation's."""
+        says ``recompute``, all but a flash call's output and row
+        statistics (``ops.attention.FLASH_KEPT``), which are kept: the
+        forward kernel runs once a step. The counts leave through the
+        wrapped function's outputs, so they are the step's own values
+        and not the recomputation's."""
         counted = getattr(layer, "apply_with_counts", None)
 
         def run(params, state, x):
@@ -254,7 +261,7 @@ class MultiLayerNetwork(KStepExecutorMixin):
             return y, s, [] if counts is None else [counts]
 
         if self.conf.conf.recompute == "layers":
-            run = jax.checkpoint(run)
+            run = jax.checkpoint(run, policy=_KEEPS_FLASH)
         return run(params, state, x)
 
     def _loss(self, params, state, batch, rng, *, training=True,

@@ -130,10 +130,15 @@ class NeuralNetConfiguration:
 
     def recompute_layers(self, on: bool = True):
         """A training step of ``MultiLayerNetwork.fit`` keeps each
-        layer's input and computes the layer again in the backward
-        pass (``jax.checkpoint`` around every layer's ``apply``):
-        activation memory of one layer at a time, about a third more
-        arithmetic. Off, the step is what it was."""
+        layer's input and, where the layer's ``apply`` went through
+        the flash kernels, that call's output and row statistics
+        (``ops.attention.FLASH_KEPT``: 0.68 GB over the five layers of
+        ``trinity_train_8k``, 134 MB of ``o`` and 1 MB of ``lse``
+        each), and computes the rest of the layer again in the
+        backward pass (``jax.checkpoint`` around every layer's
+        ``apply``): activation memory of one layer at a time, about a
+        third more arithmetic, and the forward kernel once a step. Off,
+        the step is what it was."""
         self.recompute = "layers" if on else None
         return self
 

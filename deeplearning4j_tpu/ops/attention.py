@@ -62,9 +62,19 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 __all__ = ["flash_attention", "pallas_flash_attention",
-           "pallas_flash_attention_bwd"]
+           "pallas_flash_attention_bwd", "FLASH_OUT", "FLASH_LSE",
+           "FLASH_KEPT"]
+
+# The forward kernel's two results under ``jax.checkpoint``: a policy
+# over these names (``MultiLayerNetwork._apply_in_train_step``) keeps
+# them, so a recomputed layer's backward kernels read what the first
+# run wrote and the forward kernel does not run a second time.
+FLASH_OUT = "flash_attention/o"
+FLASH_LSE = "flash_attention/lse"
+FLASH_KEPT = (FLASH_OUT, FLASH_LSE)
 
 logger = logging.getLogger("deeplearning4j_tpu")
 
@@ -771,11 +781,26 @@ def _flash(q, k, v, causal, block_q, block_k, precision, window=None):
     return _fallback(q, k, v, causal, block_k, window)
 
 
+def _kept(o, lse):
+    """The kernel's results under their names, given BEFORE the
+    residuals are built: the primal output and the residual are then
+    the same named value, and a policy that keeps the names keeps the
+    kernel from running again (naming ``o`` after the call names a
+    copy and leaves the residual to be recomputed). ``o`` is named as
+    ``(B, T, H * D)``, the array the kernel writes where heads are
+    columns and the one the projection behind it reads: a kept value
+    is a buffer of its own, and XLA tiles a 4-d one over ``(H, D)``
+    and re-lays it for every reader."""
+    B, T = o.shape[:2]
+    wide = checkpoint_name(o.reshape(B, T, -1), FLASH_OUT)
+    return wide.reshape(o.shape), checkpoint_name(lse, FLASH_LSE)
+
+
 def _flash_fwd(q, k, v, causal, block_q, block_k, precision, window):
     if _use_pallas(q.shape[1], block_q, block_k):
-        o, lse = pallas_flash_attention(
+        o, lse = _kept(*pallas_flash_attention(
             q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-            precision=precision, return_lse=True, window=window)
+            precision=precision, return_lse=True, window=window))
         return o, (q, k, v, o, lse)
     return _fallback(q, k, v, causal, block_k, window), (q, k, v, None,
                                                          None)
@@ -836,9 +861,9 @@ def _flash_masked(q, k, v, kv_mask, causal, block_q, block_k,
 def _flash_masked_fwd(q, k, v, kv_mask, causal, block_q, block_k,
                       precision):
     if _use_pallas_masked(q.shape[1], block_q, block_k):
-        o, lse = pallas_flash_attention(
+        o, lse = _kept(*pallas_flash_attention(
             q, k, v, kv_mask, causal=causal, block_q=block_q,
-            block_k=block_k, precision=precision, return_lse=True)
+            block_k=block_k, precision=precision, return_lse=True))
         return o, (q, k, v, kv_mask, o, lse)
     return (_exact_masked(q, k, v, kv_mask, causal),
             (q, k, v, kv_mask, None, None))

@@ -198,6 +198,51 @@ def test_flash_band_compiles_for_v5e(topo, window, direction):
     assert len(jax.tree_util.tree_leaves(out)) == (2 if want == 1 else 3)
 
 
+def test_a_recomputed_layer_keeps_its_flash_calls_results(topo, as_tpu):
+    """A recomputing stack's compiled train step at
+    ``trinity_train_8k``'s attention shapes (a window layer and a full
+    one, 32 query heads on 4 key heads of 128 over 8,192 positions,
+    float32, per-head norms, rotation, output gate) holds ONE forward
+    flash call a layer and two backward ones: ``o`` and ``lse`` are
+    kept (``ops.attention.FLASH_KEPT``), everything else of the layer
+    is computed again. Recomputed whole, the step held two forward
+    calls a layer."""
+    from deeplearning4j_tpu import (MultiLayerNetwork,
+                                    NeuralNetConfiguration)
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.conf.layers import (
+        GroupedQueryDecoderBlock, RnnOutputLayer)
+    T, C, layers = 8192, 2048, 2
+    b = NeuralNetConfiguration.builder().recompute_layers().list()
+    for window in (2048, None):
+        b = b.layer(GroupedQueryDecoderBlock(
+            n_heads=32, n_kv_heads=4, qk_head_dim=128, v_head_dim=128,
+            rotary_dim=128 if window else 0, window=window, qk_norm=True,
+            out_gate=True, norm_placement="both", intermediate_size=256))
+    conf = (b.layer(RnnOutputLayer(n_out=128, loss="mcxent"))
+            .set_input_type(InputType.recurrent(C, T)).build())
+    held = {}
+
+    def shapes():
+        held["net"] = net = MultiLayerNetwork(conf).init()
+        return net.params, net.state, net.opt_state
+
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one)
+    carry = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype),
+                                   jax.eval_shape(shapes))
+    batch = (sds((1, T, C)), sds((1, T, 128)), None, None)
+    text = jax.jit(held["net"]._train_step_fn(),
+                   donate_argnums=(0, 1, 2)).lower(
+        *carry, batch, sds((2,), jnp.uint32), sds((), jnp.int32)
+    ).compile().as_text()
+    calls = re.findall(r"%(pallas_flash_attention[\w.]*) = ", text)
+    forward = [name for name in calls if "_bwd" not in name]
+    assert len(forward) == layers
+    assert len(calls) - len(forward) == 2 * layers
+
+
 def test_pairs_pass_compiles_for_v5e(topo, as_tpu):
     """The held experts' pairs pass at ``trinity_mini_ep16``'s widths
     (8,192 rows of 2048 through 8 held experts of 1024, float32
